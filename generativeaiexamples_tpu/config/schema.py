@@ -567,7 +567,7 @@ class EngineConfig(ConfigWizard):
         "decode_runahead",
         default=4,
         help_txt="Decode blocks dispatched ahead of host readback. Hides "
-        "device->host latency (dominant on tunneled/remote TPUs); bounds "
+        "device->host readback latency; bounds "
         "wasted steps after a sequence stops at decode_runahead * "
         "decode_block.",
     )
@@ -576,7 +576,7 @@ class EngineConfig(ConfigWizard):
         default=8,
         help_txt="Decode steps fused into one dispatch (lax.scan); one "
         "device->host readback returns a [block, batch] token slab. Amortizes "
-        "per-dispatch RPC latency; 1 disables blocking for lowest per-token "
+        "per-dispatch launch and readback cost; 1 disables blocking for lowest per-token "
         "latency.",
     )
     stream_timeout_s: float = configfield(

@@ -346,6 +346,9 @@ def main() -> None:
     parser.add_argument("--host", default="0.0.0.0")
     parser.add_argument("--port", type=int, default=8000)
     args = parser.parse_args()
+    from generativeaiexamples_tpu.utils import jax_env
+
+    jax_env.bootstrap()
     web.run_app(create_model_server_app(), host=args.host, port=args.port)
 
 

@@ -41,6 +41,7 @@ import numpy as np
 
 from generativeaiexamples_tpu.engine import spec_decode as spec_decode_mod
 from generativeaiexamples_tpu.utils import get_logger
+from generativeaiexamples_tpu.utils import jax_env
 
 logger = get_logger(__name__)
 
@@ -150,7 +151,7 @@ class DraftRuntime:
         # --- draft weights (dense — a small model never needs packing)
         params = None
         ckpt = getattr(cfg, "spec_draft_checkpoint_path", "")
-        with jax.default_device(jax.devices("cpu")[0]):
+        with jax.default_device(jax_env.host_device()):
             if ckpt:
                 from generativeaiexamples_tpu.models.hf_loader import load_params
 

@@ -28,6 +28,7 @@ import numpy as np
 
 from generativeaiexamples_tpu.models.llama import LlamaConfig, Params
 from generativeaiexamples_tpu.utils import get_logger
+from generativeaiexamples_tpu.utils import jax_env
 
 logger = get_logger(__name__)
 
@@ -262,7 +263,7 @@ def load_params_layered_streaming(
     # the default (accelerator) device before place() shards them —
     # exactly the single-chip materialization streaming exists to avoid.
     # place()'s explicit device/sharding targets override the default.
-    cpu = jax.devices("cpu")[0]
+    cpu = jax_env.host_device()
     with jax.default_device(cpu):
         for key, group in iter_param_groups(path, cfg, stats=stream_stats):
             if key == "embed":
@@ -377,7 +378,7 @@ def load_params_pp_streaming(
 
     buffers: Dict[str, object] = {}
     out: Params = {}
-    cpu = jax.devices("cpu")[0]
+    cpu = jax_env.host_device()
 
     def sub_spec(spec):
         # staged spec minus the leading (pipe, layer-slot) axes: the

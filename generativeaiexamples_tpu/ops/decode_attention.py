@@ -60,12 +60,6 @@ from jax.experimental.pallas import tpu as pltpu
 
 _LANE = 128
 _NEG_INF = -1e30
-# jax renamed TPUCompilerParams -> CompilerParams across the versions
-# the CPU containers and TPU hosts carry; accept either spelling (same
-# shim as ops/page_attention.py).
-_COMPILER_PARAMS = getattr(
-    pltpu, "CompilerParams", getattr(pltpu, "TPUCompilerParams", None)
-)
 # int8 VMEM tiles are (32, 128): S blocks sit on the sublane axis in
 # multiples of 32. 256 keeps k+v double-buffered blocks at ~1 MB for
 # Hkv=8 while still letting short sequences skip most of the cache.
@@ -211,7 +205,7 @@ def decode_attention(
         ),
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((B, Hq, Dh), q.dtype),
-        compiler_params=_COMPILER_PARAMS(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")
         ),
         interpret=interpret,

@@ -144,14 +144,9 @@ def _vmem(shape):
 def _compiler_params():
     from jax.experimental.pallas import tpu as pltpu
 
-    try:
-        return pltpu.CompilerParams(
-            dimension_semantics=("parallel", "parallel", "parallel", "arbitrary")
-        )
-    except TypeError:  # older jax spells it TPUCompilerParams
-        return pltpu.TPUCompilerParams(
-            dimension_semantics=("parallel", "parallel", "parallel", "arbitrary")
-        )
+    return pltpu.CompilerParams(
+        dimension_semantics=("parallel", "parallel", "parallel", "arbitrary")
+    )
 
 
 def supported(T: int, D: int) -> bool:

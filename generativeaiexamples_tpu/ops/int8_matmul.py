@@ -108,14 +108,7 @@ def _mm_compiler_params():
     already does; env-gated for on-chip A/B)."""
     if os.environ.get("GENAI_TPU_INT8_NO_SEMANTICS", "").lower() in ("1", "true"):
         return None
-    try:
-        return pltpu.CompilerParams(
-            dimension_semantics=("parallel", "arbitrary")
-        )
-    except TypeError:  # older jax spells it TPUCompilerParams
-        return pltpu.TPUCompilerParams(
-            dimension_semantics=("parallel", "arbitrary")
-        )
+    return pltpu.CompilerParams(dimension_semantics=("parallel", "arbitrary"))
 
 
 @functools.partial(jax.jit, static_argnames=("out_features", "interpret"))

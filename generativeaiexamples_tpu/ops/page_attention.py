@@ -59,11 +59,6 @@ from jax.experimental.pallas import tpu as pltpu
 
 _LANE = 128
 _NEG_INF = -1e30
-# jax renamed TPUCompilerParams -> CompilerParams across the versions
-# the CPU containers and TPU hosts carry; accept either spelling.
-_COMPILER_PARAMS = getattr(
-    pltpu, "CompilerParams", getattr(pltpu, "TPUCompilerParams", None)
-)
 # VMEM running-softmax scratch is [T*Hq, 128] f32 (m and l) plus the
 # [T*Hq, Dh] accumulator; 512 rows caps the trio near ~1 MB at Dh=128.
 MAX_QUERY_ROWS = 512
@@ -260,7 +255,7 @@ def paged_attention(
         ),
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((B, T, Hq, Dh), q.dtype),
-        compiler_params=_COMPILER_PARAMS(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")
         ),
         interpret=interpret,

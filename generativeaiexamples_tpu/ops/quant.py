@@ -293,9 +293,19 @@ def init_packed_params_int8(cfg, seed: int = 0, dtype=jnp.bfloat16, tp_shards: i
         f_dim = sum(s[0][-1] for s in shapes)
         K_dst, F_dst, blocks = _shard_blocks(k_dim, f_dim, tp_shards, kind)
         qarr = np.zeros((*lead, K_dst, F_dst), np.int8)
-        draw = rng.integers(
-            -127, 128, size=(*lead, k_dim, f_dim), dtype=np.int16
-        ).astype(np.int8)
+        # One draw per NAMED weight, in the unfused order: the fused
+        # (tp_shards=1) and per-shard (TP) packs then hold the same
+        # logical model, so engines of different TP width can be
+        # compared on random-init weights (chip_smoke.py --chips 4).
+        draw = np.concatenate(
+            [
+                rng.integers(
+                    -127, 128, size=(*lead, k_dim, s[0][-1]), dtype=np.int16
+                ).astype(np.int8)
+                for s in shapes
+            ],
+            axis=-1,
+        )
         for (dk, df, sk, sf) in blocks:
             qarr[..., dk[0] : dk[1], df[0] : df[1]] = draw[
                 ..., sk[0] : sk[1], sf[0] : sf[1]

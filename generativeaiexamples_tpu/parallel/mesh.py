@@ -88,30 +88,14 @@ def tier_submeshes(mesh: Mesh) -> tuple:
 
 
 def shard_map(f, mesh: Mesh, in_specs, out_specs, check_vma=None):
-    """Portable ``shard_map``: ``jax.shard_map`` where it exists (jax
-    promoted it out of experimental in 0.6), else the
-    ``jax.experimental.shard_map`` original — jax 0.4.x containers (CPU
-    CI images pin older wheels than the TPU hosts) raise
-    ``AttributeError`` on the promoted name. ``check_vma`` maps onto the
-    old API's ``check_rep`` (same replication-check semantics under its
-    pre-varying-manual-axes name)."""
-    fn = getattr(jax, "shard_map", None)
-    if fn is not None:
-        kwargs = {} if check_vma is None else {"check_vma": check_vma}
-    else:
-        from jax.experimental.shard_map import shard_map as fn
-
-        kwargs = {} if check_vma is None else {"check_rep": check_vma}
-    return fn(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs, **kwargs)
+    """``jax.shard_map`` with the mesh passed by keyword and the
+    replication check left at jax's default unless stated."""
+    kwargs = {} if check_vma is None else {"check_vma": check_vma}
+    return jax.shard_map(
+        f, mesh=mesh, in_specs=in_specs, out_specs=out_specs, **kwargs
+    )
 
 
 def mesh_context(mesh: Mesh):
-    """Portable mesh-scope context: ``jax.set_mesh(mesh)`` where it
-    exists (sharding-in-types era), else the classic ``with mesh:``
-    context — ``Mesh`` has been a context manager since the xmap days,
-    so jax 0.4.x containers (CPU CI images pin older wheels than the
-    TPU hosts) can still construct and run the serving engine."""
-    setter = getattr(jax, "set_mesh", None)
-    if setter is not None:
-        return setter(mesh)
-    return mesh
+    """Mesh scope for sharded construction and dispatch."""
+    return jax.set_mesh(mesh)

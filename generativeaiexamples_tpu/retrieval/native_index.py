@@ -11,6 +11,7 @@ a compiler.
 from __future__ import annotations
 
 import ctypes
+import hashlib
 import os
 import subprocess
 import threading
@@ -24,8 +25,13 @@ logger = get_logger(__name__)
 
 _REPO_ROOT = os.path.dirname(os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 _NATIVE_DIR = os.path.join(_REPO_ROOT, "native")
-_SO_PATH = os.path.join(_NATIVE_DIR, "build", "libvecindex.so")
 _SRC_PATH = os.path.join(_NATIVE_DIR, "vecindex.cpp")
+# No -march=native: the build directory is gitignored but travels with
+# copies of the tree, and a library tuned to the build host's CPU can
+# SIGILL on the next one. The artefact's NAME carries a digest of the
+# source and the flags, so a stale or foreign-flag build is never
+# loaded — mtimes do not survive a copy and are not consulted.
+_CXXFLAGS = ("-O3", "-ffast-math", "-fPIC", "-shared", "-std=c++17")
 
 _BUILD_LOCK = threading.Lock()
 _LIB: Optional[ctypes.CDLL] = None
@@ -38,40 +44,44 @@ class NativeUnavailable(RuntimeError):
     pass
 
 
-def _needs_build() -> bool:
-    if not os.path.exists(_SO_PATH):
-        return True
-    return os.path.getmtime(_SO_PATH) < os.path.getmtime(_SRC_PATH)
+def _so_path() -> str:
+    h = hashlib.sha256(" ".join(_CXXFLAGS).encode())
+    with open(_SRC_PATH, "rb") as fh:
+        h.update(fh.read())
+    return os.path.join(
+        _NATIVE_DIR, "build", f"libvecindex-{h.hexdigest()[:16]}.so"
+    )
 
 
 def ensure_built() -> str:
-    """Compile the shared library if stale; returns its path."""
+    """Compile the shared library unless the artefact for exactly this
+    source and these flags exists; returns its path."""
     with _BUILD_LOCK:
-        if _needs_build():
-            if not os.path.exists(_SRC_PATH):
-                raise NativeUnavailable(f"missing source {_SRC_PATH}")
-            os.makedirs(os.path.dirname(_SO_PATH), exist_ok=True)
+        if not os.path.exists(_SRC_PATH):
+            raise NativeUnavailable(f"missing source {_SRC_PATH}")
+        so_path = _so_path()
+        if not os.path.exists(so_path):
+            os.makedirs(os.path.dirname(so_path), exist_ok=True)
+            # Build beside the target and rename: a concurrent process
+            # never loads a half-written library.
+            tmp_path = f"{so_path}.{os.getpid()}.tmp"
             cmd = [
                 os.environ.get("CXX", "g++"),
-                "-O3",
-                "-march=native",
-                "-ffast-math",
-                "-fPIC",
-                "-shared",
-                "-std=c++17",
+                *_CXXFLAGS,
                 "-o",
-                _SO_PATH,
+                tmp_path,
                 _SRC_PATH,
             ]
             logger.info("Building native vecindex: %s", " ".join(cmd))
             try:
                 subprocess.run(cmd, check=True, capture_output=True, timeout=300)
+                os.replace(tmp_path, so_path)
             except (subprocess.CalledProcessError, FileNotFoundError, subprocess.TimeoutExpired) as exc:
                 detail = getattr(exc, "stderr", b"")
                 raise NativeUnavailable(
                     f"native build failed: {exc}: {detail[:500] if detail else ''}"
                 ) from exc
-    return _SO_PATH
+    return so_path
 
 
 def _load_lib() -> ctypes.CDLL:

@@ -31,6 +31,9 @@ def main() -> None:
 
     from generativeaiexamples_tpu.config import get_config
     from generativeaiexamples_tpu.router.app import create_router_app
+    from generativeaiexamples_tpu.utils import jax_env
+
+    jax_env.bootstrap()
 
     config = get_config()
     if args.policy:

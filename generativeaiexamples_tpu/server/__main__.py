@@ -10,6 +10,7 @@ import os
 from aiohttp import web
 
 from generativeaiexamples_tpu.server.api import create_app
+from generativeaiexamples_tpu.utils import jax_env
 
 
 def main() -> None:
@@ -30,6 +31,7 @@ def main() -> None:
 
         AppConfig.print_help(sys.stdout.write)
         return
+    jax_env.bootstrap()
     web.run_app(create_app(), host=args.host, port=args.port)
 
 

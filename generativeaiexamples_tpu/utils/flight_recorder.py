@@ -477,10 +477,12 @@ def finish(rec: Optional[RequestRecord], outcome: str = "finish") -> None:
     _maybe_capture_slow(rec)
 
 
-def finish_rid(rid: int, outcome: str = "finish") -> None:
+def finish_rid(rid: int, outcome: str = "finish", **attrs: Any) -> None:
     """Engine-side completion for one rid. Engine-owned records retire
     here; server-owned records only unmap the rid (the server retires
-    them after the SSE stream closes)."""
+    them after the SSE stream closes). ``attrs`` ride the server-owned
+    record's ``engine_finish`` event (the engine stamps the token count
+    and what ended the stream)."""
     if not _ENABLED:
         return
     with _LOCK:
@@ -492,7 +494,7 @@ def finish_rid(rid: int, outcome: str = "finish") -> None:
         return
     # Server-owned record: stamp the engine completion and unmap the
     # rid only — total latency (and retirement) stay server-owned.
-    rec.event("engine_finish", rid=rid, outcome=outcome)
+    rec.event("engine_finish", rid=rid, outcome=outcome, **attrs)
     with _LOCK:
         if _BY_RID.get(rid) is rec:
             _BY_RID.pop(rid, None)

@@ -1,24 +1,106 @@
 """Hardware peak constants + roofline/MFU arithmetic, in ONE place.
 
-bench.py historically owned the v5e peak numbers and the MFU/HBM-
-roofline formulas; the live utilization estimator
-(engine/telemetry.py) needs the same math on-line, and two copies of
-"2 * matmul_params FLOPs per token" WILL drift. Both consumers import
-from here, and the env overrides keep their bench-era names
-(``BENCH_PEAK_TFLOPS`` / ``BENCH_PEAK_HBM_GBPS``) so existing A/B
-scripts for other TPU parts keep working.
+bench.py and the live utilization estimator (engine/telemetry.py) share
+this math so the offline and on-line numbers cannot drift. Peaks are
+PUBLISHED per-chip numbers in one table keyed by jax's ``device_kind``,
+each with its source; the engine resolves the attached device against
+it at start-up (:func:`configure_peaks`). On the ``tpu`` backend a kind
+that is not in the table is an error, not a default — add the part with
+its source, or state the peaks explicitly through the
+``BENCH_PEAK_TFLOPS`` / ``BENCH_PEAK_HBM_GBPS`` overrides. A non-TPU
+backend (CPU rehearsals, tests) keeps the reference part's numbers so
+the arithmetic stays defined; what it yields there is a count, never a
+device metric.
 
 Everything here is pure host arithmetic — no jax import, so the
 metric-name linter and pure-host tests can load it freely.
 """
 from __future__ import annotations
 
+import dataclasses
 import os
+from typing import Dict
 
-# v5e single-chip peaks (How to Scale Your Model / public TPU specs):
-# 197 bf16 TFLOP/s, ~819 GB/s HBM. Overridable for other parts.
-PEAK_TFLOPS = float(os.environ.get("BENCH_PEAK_TFLOPS", "197"))
-PEAK_HBM_GBPS = float(os.environ.get("BENCH_PEAK_HBM_GBPS", "819"))
+
+@dataclasses.dataclass(frozen=True)
+class DevicePeaks:
+    bf16_tflops: float
+    hbm_gbps: float
+    hbm_bytes: float
+    source: str
+
+
+# Keyed by ``jax.devices()[0].device_kind``.
+DEVICE_PEAKS: Dict[str, DevicePeaks] = {
+    "TPU v5 lite": DevicePeaks(
+        bf16_tflops=197.0,
+        hbm_gbps=819.0,
+        hbm_bytes=16e9,
+        source="Google Cloud documentation, 'TPU v5e': 197 TFLOP/s bf16, "
+        "16 GB HBM2e at 819 GB/s per chip",
+    ),
+}
+REFERENCE_KIND = "TPU v5 lite"
+
+PEAK_TFLOPS = float(
+    os.environ.get("BENCH_PEAK_TFLOPS", DEVICE_PEAKS[REFERENCE_KIND].bf16_tflops)
+)
+PEAK_HBM_GBPS = float(
+    os.environ.get("BENCH_PEAK_HBM_GBPS", DEVICE_PEAKS[REFERENCE_KIND].hbm_gbps)
+)
+
+
+def peaks_for(platform: str, device_kind: str) -> DevicePeaks:
+    """The table row for an attached device. Unknown TPU kinds raise;
+    non-TPU platforms get the reference part (see module docstring)."""
+    row = DEVICE_PEAKS.get(device_kind)
+    if row is not None:
+        return row
+    if platform == "tpu":
+        raise ValueError(
+            f"no published peaks for TPU device_kind {device_kind!r} in "
+            f"utils/hardware.DEVICE_PEAKS (known: {sorted(DEVICE_PEAKS)}); "
+            "add the part with its source"
+        )
+    return DEVICE_PEAKS[REFERENCE_KIND]
+
+
+def configure_peaks(platform: str, device_kind: str) -> None:
+    """Point the module-level peaks at the attached device (the engine
+    calls this once at start-up). A peak stated through its env override
+    stays as stated; with both stated the table is not consulted, so an
+    unlisted part can still be served."""
+    global PEAK_TFLOPS, PEAK_HBM_GBPS
+    want_flops = "BENCH_PEAK_TFLOPS" not in os.environ
+    want_hbm = "BENCH_PEAK_HBM_GBPS" not in os.environ
+    if not (want_flops or want_hbm):
+        return
+    row = peaks_for(platform, device_kind)
+    if want_flops:
+        PEAK_TFLOPS = row.bf16_tflops
+    if want_hbm:
+        PEAK_HBM_GBPS = row.hbm_gbps
+
+
+def device_hbm_bytes(device) -> float:
+    """Per-device memory the allocator may use. ``GENAI_TPU_HBM_BYTES``
+    overrides (tests / fit-planning for another part). On the ``tpu``
+    backend the allocator's own ``bytes_limit`` is the only source and
+    its absence is an error; other backends report no limit, so fit
+    plans rehearsed there use the reference part's published size."""
+    env = os.environ.get("GENAI_TPU_HBM_BYTES")
+    if env:
+        return float(env)
+    if device.platform == "tpu":
+        stats = device.memory_stats()
+        if not stats or "bytes_limit" not in stats:
+            raise RuntimeError(
+                f"{device} reports no memory_stats()['bytes_limit']; refusing "
+                "to plan against an assumed HBM size (set GENAI_TPU_HBM_BYTES "
+                "to state it)"
+            )
+        return float(stats["bytes_limit"])
+    return DEVICE_PEAKS[REFERENCE_KIND].hbm_bytes
 
 
 def matmul_params(model_cfg) -> int:

@@ -3,7 +3,7 @@
 Every performance record carries WHERE it came from: the git SHA (and
 whether the tree was dirty), a fingerprint of the configuration that
 produced it, and whether the model served random-init weights — so the
-trajectory tooling (tools/check_perf_regression.py, BENCH_r*.json
+trajectory tooling (tools/check_perf_regression.py, bench-record
 comparisons) can refuse to compare numbers measured under different
 conditions instead of silently charting noise. bench has always run
 random-init weights silently (ROADMAP item 5); the flag makes that

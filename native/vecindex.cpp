@@ -15,7 +15,7 @@
 //          1 = squared L2 (returned negated so "higher is better" holds
 //              for both metrics).
 //
-// Build: make -C native   (g++ -O3 -march=native -shared -fPIC)
+// Build: make -C native   (flags live in retrieval/native_index.py)
 
 #include <algorithm>
 #include <cmath>

@@ -1,0 +1,197 @@
+"""The main path's Pallas kernels compile for the real chip, at
+Llama-3-8B widths, without a chip.
+
+The TPU's compiler is installed on CPU-only machines and compiles for a
+device that is DESCRIBED, not attached (``v5e:2x2``). Interpret-mode
+tests cannot see what it refuses: a slice off the tiling grid, a kernel
+over its fast-memory budget, a program that cannot be partitioned. Each
+test asserts the kernel is really in the program (``tpu_custom_call``).
+A compile that passes is not a chip run — ``chip_smoke.py`` is.
+
+Only one process may load libtpu, and it keeps it until exit, so the
+topology is described INSIDE a module-scoped fixture of this one file:
+never at import, in a ``skipif``, in ``parametrize`` or in conftest.py
+(xdist workers would collect different tests and run none), and every
+compile happens in this process (a child could not load the library).
+"""
+import os
+
+import jax
+import jax.numpy as jnp
+import pytest
+from jax.sharding import Mesh, NamedSharding, PartitionSpec as P, SingleDeviceSharding
+
+from generativeaiexamples_tpu.ops import (
+    decode_attention,
+    flash_attention,
+    int8_matmul,
+    page_attention,
+)
+
+# Llama-3-8B (models/llama.py LlamaConfig defaults)
+HIDDEN, MLP, VOCAB = 4096, 14336, 128256
+HQ, HKV, DH = 32, 8, 128
+PAGE = 128
+
+
+@pytest.fixture(scope="module")
+def topo():
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    from jax.experimental import topologies
+
+    try:
+        return topologies.get_topology_desc(platform="tpu", topology_name="v5e:2x2")
+    except Exception as exc:  # noqa: BLE001 - no libtpu / lock held elsewhere
+        pytest.skip(f"no v5e:2x2 topology can be described here: {exc}")
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    return SingleDeviceSharding(topo.devices[0])
+
+
+@pytest.fixture(scope="module")
+def no_persistent_cache():
+    """A compile for a described device is written to the persistent
+    cache but cannot be read back without a chip (the next one warns and
+    recompiles) — keep these compiles out of it."""
+    from jax.experimental.compilation_cache import compilation_cache
+
+    prev = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield
+    jax.config.update("jax_enable_compilation_cache", prev)
+    compilation_cache.reset_cache()
+
+
+def _compiled_text(fn, *args) -> str:
+    return jax.jit(fn).lower(*args).compile().as_text()
+
+
+def _pack_shapes(sharding, K, F):
+    """A packed int8 weight as ops/quant.py lays it out (F padded to the
+    kernel's block) plus its logical-F scale row."""
+    f_pad = -(-F // int8_matmul.F_BLK) * int8_matmul.F_BLK
+    return (
+        jax.ShapeDtypeStruct((K, f_pad), jnp.int8, sharding=sharding),
+        jax.ShapeDtypeStruct((1, F), jnp.float32, sharding=sharding),
+    )
+
+
+@pytest.mark.parametrize(
+    "M,K,F",
+    [
+        (16, HIDDEN, HIDDEN + 2 * HKV * DH),  # fused QKV
+        (16, HIDDEN, 2 * MLP),  # fused gate|up
+        (64, MLP, HIDDEN),  # down
+        (16, HIDDEN, VOCAB),  # lm_head (F padded to the block)
+    ],
+)
+def test_int8_matmul_compiles(one_chip, no_persistent_cache, M, K, F):
+    x = jax.ShapeDtypeStruct((M, K), jnp.bfloat16, sharding=one_chip)
+    q, scale = _pack_shapes(one_chip, K, F)
+    text = _compiled_text(int8_matmul.int8_matmul, x, q, scale)
+    assert "tpu_custom_call" in text
+
+
+def test_int8_w8a8_matmul_compiles(one_chip, no_persistent_cache):
+    x = jax.ShapeDtypeStruct((16, MLP), jnp.bfloat16, sharding=one_chip)
+    q, scale = _pack_shapes(one_chip, MLP, HIDDEN)
+    text = _compiled_text(int8_matmul.int8_w8a8_matmul, x, q, scale)
+    assert "tpu_custom_call" in text
+
+
+def test_decode_attention_compiles(one_chip, no_persistent_cache):
+    B, S = 16, 4096
+
+    def s(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    text = _compiled_text(
+        decode_attention.decode_attention,
+        s((B, HQ, DH), jnp.bfloat16),
+        s((B, HKV, S, DH), jnp.int8), s((B, HKV, 1, S), jnp.float32),
+        s((B, HKV, S, DH), jnp.int8), s((B, HKV, 1, S), jnp.float32),
+        s((B,), jnp.int32),
+    )
+    assert "tpu_custom_call" in text
+
+
+def _paged_args(sharding, T, kv_dtype, head_spec=None, B=16, pages_per_row=32):
+    """Shapes of one paged-attention read. ``head_spec`` shards the head
+    axes for the TP variant."""
+    def s(shape, dtype, spec=None):
+        sh = sharding(spec) if callable(sharding) else sharding
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=sh)
+
+    n_pages = B * pages_per_row + 1
+    quantized = kv_dtype == jnp.int8
+    h = head_spec
+    args = [
+        s((B, T, HQ, DH), jnp.bfloat16, P(None, None, h, None)),
+        s((n_pages, PAGE, HKV, DH), kv_dtype, P(None, None, h, None)),
+        s((n_pages, PAGE, HKV, DH), kv_dtype, P(None, None, h, None)),
+        s((B, pages_per_row), jnp.int32, P()),
+        s((B,), jnp.int32, P()),
+    ]
+    if quantized:
+        args += [
+            s((n_pages, PAGE, HKV), jnp.float32, P(None, None, h)),
+            s((n_pages, PAGE, HKV), jnp.float32, P(None, None, h)),
+        ]
+    return args
+
+
+@pytest.mark.parametrize(
+    "T,kv_dtype",
+    [(1, jnp.int8), (1, jnp.bfloat16), (5, jnp.int8)],
+    ids=["decode-int8", "decode-bf16", "verify5-int8"],
+)
+def test_paged_attention_compiles(one_chip, no_persistent_cache, T, kv_dtype):
+    assert page_attention.supports_geometry(
+        PAGE, DH, HQ, HKV, T,
+        kv_dtype="int8" if kv_dtype == jnp.int8 else "bfloat16",
+    )
+    text = _compiled_text(
+        page_attention.paged_attention, *_paged_args(one_chip, T, kv_dtype)
+    )
+    assert "tpu_custom_call" in text
+
+
+@pytest.mark.parametrize("T", [512, 2048])
+def test_flash_attention_compiles(one_chip, no_persistent_cache, T):
+    def s(heads):
+        return jax.ShapeDtypeStruct((1, T, heads, DH), jnp.bfloat16, sharding=one_chip)
+
+    text = _compiled_text(flash_attention.flash_attention_causal, s(HQ), s(HKV), s(HKV))
+    assert "tpu_custom_call" in text
+
+
+def test_paged_attention_tp_compiles_over_four_chips(topo, no_persistent_cache):
+    """The shard_map head-sharded page kernel over a 4-device ``model``
+    mesh: kernel tiles on every device, no gather of the pool."""
+    import numpy as np
+
+    from generativeaiexamples_tpu.parallel import tp_kernels
+    from generativeaiexamples_tpu.parallel.mesh import (
+        DATA_AXIS, MODEL_AXIS, PIPE_AXIS, SEQ_AXIS,
+    )
+
+    mesh = Mesh(
+        np.array(topo.devices[:4]).reshape(1, 1, 1, 4),
+        (PIPE_AXIS, DATA_AXIS, SEQ_AXIS, MODEL_AXIS),
+    )
+    tp = tp_kernels.TPContext(mesh, 4, interpret=False)
+    args = _paged_args(
+        lambda spec: NamedSharding(mesh, spec), 1, jnp.int8, head_spec=MODEL_AXIS
+    )
+
+    def fn(q, k, v, tables, pos, ks, vs):
+        return tp_kernels.paged_attention_tp(
+            q, k, v, tables, pos, ks, vs, tp=tp, interpret=False
+        )
+
+    text = _compiled_text(fn, *args)
+    assert "tpu_custom_call" in text
+    assert "all-gather" not in text

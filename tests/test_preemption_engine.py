@@ -118,9 +118,18 @@ def test_mid_decode_drain_then_restore_token_identical(peng):
     assert len(baseline) == 20
 
     spooled_before = snap_mod._M_PREEMPTED.labels(mode="snapshot").value
-    req = peng.submit(PROMPT, params)
-    got = _pull(req, 6)
-    summary = peng.drain()
+    # Throttled (delay fault per dispatch pass, as in the lifecycle test
+    # below): unthrottled, a fast host dispatches all 20 tokens' blocks
+    # and frees the slot before drain() is even called — the outcome
+    # then depends on the machine, not on the code.
+    faults.reset()
+    faults.configure("engine.dispatch", "delay", at=1, count=0, value=0.05)
+    try:
+        req = peng.submit(PROMPT, params)
+        got = _pull(req, 6)
+        summary = peng.drain()
+    finally:
+        faults.reset()
     tail = _rest(req)  # terminates with the preemption sentinel
     assert isinstance(req.error, RequestPreempted)
     sid = req.error.snapshot_id

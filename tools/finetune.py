@@ -122,6 +122,9 @@ def batches(
 def main(argv: List[str] | None = None) -> int:
     args = parse_args(sys.argv[1:] if argv is None else argv)
 
+    from generativeaiexamples_tpu.utils import jax_env
+
+    jax_env.bootstrap()
     from generativeaiexamples_tpu.engine.tokenizer import load_tokenizer
     from generativeaiexamples_tpu.models import hf_loader, llama, lora
     from generativeaiexamples_tpu.models.checkpoint import CheckpointManager

@@ -10,7 +10,7 @@ random-init weights, runs the full warmup walk, and leaves the compiled
 artifacts in ``JAX_COMPILATION_CACHE_DIR`` — after which a real
 deployment of the same config reaches serving-ready in seconds instead
 of minutes (an 8B bucket compile is ~40 s; an 80-layer 70B-shard bucket
-exceeded 15 min — BASELINE.md).
+far longer).
 
 Usage (flags mirror the APP_ENGINE_* config fields):
 
@@ -46,15 +46,13 @@ def main(argv=None) -> int:
         help="comma-separated sub-chunk buckets to warm monolithically "
         "(longer prompts ride the bounded chunked set)",
     )
-    ap.add_argument(
-        "--cache-dir",
-        default=os.path.join(os.path.dirname(os.path.dirname(os.path.abspath(__file__))), ".jax_cache"),
-        help="XLA compile-cache directory to populate",
-    )
     args = ap.parse_args(argv)
 
-    os.makedirs(args.cache_dir, exist_ok=True)
-    os.environ.setdefault("JAX_COMPILATION_CACHE_DIR", args.cache_dir)
+    # The one compile-cache rule (utils/jax_env.py): the environment's
+    # JAX_COMPILATION_CACHE_DIR where set, <checkout>/.jax_cache otherwise.
+    from generativeaiexamples_tpu.utils import jax_env
+
+    args.cache_dir = jax_env.bootstrap()
 
     from generativeaiexamples_tpu.config import EngineConfig
     from generativeaiexamples_tpu.engine.llm_engine import LLMEngine
