@@ -197,12 +197,14 @@ class Span:
     __slots__ = (
         "seq", "kind", "category", "thread", "t_wall", "lock_wait_s",
         "run_s", "gap_s", "rows", "tokens", "steps", "path", "rids",
+        "counters",
     )
 
     def __init__(self, kind: str, category: str, thread: str,
                  t_wall: float, lock_wait_s: float, run_s: float,
                  gap_s: float, rows: int, tokens: int, steps: int,
-                 path: Optional[str], rids: Tuple[int, ...]):
+                 path: Optional[str], rids: Tuple[int, ...],
+                 counters: Optional[Dict[str, int]] = None):
         self.seq = 0  # assigned under _LOCK at record time
         self.kind = kind
         self.category = category  # dispatch | stall | readback | compile
@@ -216,6 +218,9 @@ class Span:
         self.steps = steps
         self.path = path
         self.rids = rids
+        # kind-specific counts, shown as top-level fields of the view
+        # (decode: kv_pages_walked / kv_pages_grid)
+        self.counters = counters
 
     @property
     def t_end(self) -> float:
@@ -239,6 +244,8 @@ class Span:
             out["path"] = self.path
         if self.rids:
             out["rids"] = list(self.rids)
+        if self.counters:
+            out.update(self.counters)
         return out
 
 
@@ -365,6 +372,7 @@ def record_span(
     path: Optional[str] = None,
     rids: Sequence[int] = (),
     queued: bool = True,
+    counters: Optional[Dict[str, int]] = None,
 ) -> None:
     """One compiled-program launch: ``t_wall`` is the enqueue wall
     clock (lock requested), ``lock_wait_s`` the dispatch-lock wait,
@@ -372,7 +380,8 @@ def record_span(
     — on TPU the async dispatch returns early and xplane is truth).
     ``queued`` gates gap attribution: the host gap since this thread's
     previous dispatch counts as bubble only when work was available the
-    whole time."""
+    whole time. ``counters`` are extra counts of this kind of launch,
+    shown as fields of the span's view."""
     if not _ENABLED:
         return
     thread = threading.current_thread().name
@@ -385,7 +394,7 @@ def record_span(
         Span(
             kind, "dispatch", thread, t_wall, max(0.0, lock_wait_s),
             max(0.0, run_s), gap_s, int(rows), int(tokens),
-            max(1, int(steps)), path, tuple(rids)[:_RID_CAP],
+            max(1, int(steps)), path, tuple(rids)[:_RID_CAP], counters,
         ),
         observe_gap=queued,
     )

@@ -169,7 +169,7 @@ _M_PAGED_ATTN = _REG.counter(
     "genai_engine_paged_attn_dispatches_total",
     "Paged-layout attention dispatches by serving path: path='kernel' "
     "(the ragged Pallas page-attention kernel, ops/page_attention.py — "
-    "per-row DMA grids clamped to live pages) vs path='gather' (the "
+    "walks each row's live pages only) vs path='gather' (the "
     "XLA dequant-gather fallback reading the bucketed window). A paged "
     "engine whose geometry the kernel refuses logs the fallback loudly "
     "at startup and shows every decode dispatch under 'gather' here.",
@@ -2495,6 +2495,23 @@ class LLMEngine:
             self.model_config, tokens, self._kv_byte_width
         )
 
+    def _kernel_pages_walked(self) -> Dict[str, int]:
+        """What the page kernel walks at the FIRST step of the decode
+        dispatch about to launch, against the dense ``slots x Pmax``
+        grid it replaced — ops/page_attention.page_work_list's count
+        from the host's position shadow (caller holds the lock; no
+        readback): a live row's pages up to its query position, one
+        scratch page per empty slot."""
+        page = self.engine_config.page_size
+        last = self.max_seq_len - 1
+        walked = sum(
+            min(p, last) // page + 1 for p in self._slot_pos.values()
+        ) + self.num_slots - len(self._slot_pos)
+        return {
+            "kv_pages_walked": walked,
+            "kv_pages_grid": self.num_slots * self._max_pages_per_slot,
+        }
+
     def submit(
         self, prompt_ids: Sequence[int], params: Optional[SamplingParams] = None
     ) -> _Request:
@@ -4377,6 +4394,10 @@ class LLMEngine:
             ragged_bytes = (
                 self._ragged_read_bytes() if self._paged else 0
             )
+            kv_pages = (
+                self._kernel_pages_walked()
+                if self._paged and self._paged_kernel else None
+            )
             for slot in self._slot_pos:
                 self._slot_pos[slot] += self._decode_block
             self._update_occupancy_gauges()
@@ -4430,7 +4451,7 @@ class LLMEngine:
             tokens=self._decode_block * len(live_slots),
             weight_passes=self._decode_block,
             # Charge what the serving path actually reads: the ragged
-            # kernel clamps each row's DMA grid to its live pages
+            # kernel walks each row's live pages only
             # (kv_read_bytes_ragged — each live row's page-rounded
             # length), while the XLA gather — paged or fixed — reads
             # the bucketed window for every row. Before the kernel the
@@ -4465,6 +4486,7 @@ class LLMEngine:
                     if self._paged else None
                 ),
                 rids=[r.rid for _, r in snapshot],
+                counters=kv_pages,
             )
         # Start the device→host transfer NOW so readbacks overlap both the
         # compute of later steps and each other.

@@ -1321,9 +1321,9 @@ def decode_layers(
 # The attention READ has two servers behind one interface: the XLA
 # gather below (every geometry; reads a bucketed W per row) and the
 # ragged Pallas kernel in ops/page_attention.py (``page_kernel`` param;
-# clamps each row's DMA grid to its own live pages via the
-# scalar-prefetched page table, so cache traffic tracks true
-# page-rounded lengths). The engine picks per executable through
+# walks a scalar-prefetched work list of each row's live pages, so
+# cache traffic and grid steps track true page-rounded lengths). The
+# engine picks per executable through
 # ``page_attention.supports_geometry`` and falls back loudly.
 #
 # Physical page 0 is the SCRATCH page: dead rows and value-masked
@@ -1423,22 +1423,25 @@ def write_prefill_pages(
 
 def _paged_kernel_read(
     q, ck, cv, tables, positions, cks=None, cvs=None, *,
-    interpret: bool, tp=None,
+    interpret: bool, tp=None, work=None,
 ):
     """Route one ragged-kernel attention read: single-device pallas_call
     or, under a pure-TP mesh, the shard_map head-sharded variant
     (parallel/tp_kernels.paged_attention_tp). The engine only sets
     ``page_kernel`` with ``tp`` when ``supports_geometry(...,
-    shards=tp.shards)`` accepted the LOCAL tile geometry."""
+    shards=tp.shards)`` accepted the LOCAL tile geometry. ``work`` is
+    the step's ``page_attention.page_work_list``, built once by the
+    caller and shared by every layer's read."""
     if tp is not None:
         from generativeaiexamples_tpu.parallel import tp_kernels
 
         return tp_kernels.paged_attention_tp(
             q, ck, cv, tables, positions, cks, cvs, tp=tp,
-            interpret=interpret,
+            interpret=interpret, work=work,
         )
     return page_attention.paged_attention(
-        q, ck, cv, tables, positions, cks, cvs, interpret=interpret
+        q, ck, cv, tables, positions, cks, cvs, interpret=interpret,
+        work=work,
     )
 
 
@@ -1466,8 +1469,8 @@ def _chunk_layers_paged(
 
     ``page_kernel`` (None | 'compiled' | 'interpret') swaps the
     attention READ for the ragged Pallas kernel
-    (ops/page_attention.py): same post-write pools, per-row DMA grids
-    clamped to live pages instead of the bucketed-W gather. Writes are
+    (ops/page_attention.py): same post-write pools, a walk over each
+    row's live pages instead of the bucketed-W gather. Writes are
     identical either way. The engine only passes it for chunk widths
     ``supports_geometry`` accepts (spec verify; prefill-length extends
     stay on the gather)."""
@@ -1489,6 +1492,11 @@ def _chunk_layers_paged(
     phys = jnp.take_along_axis(row_tables, positions // page_size, axis=1)
     phys = jnp.where((valid > 0)[:, None], phys, 0)  # dead rows -> scratch
     sip = positions % page_size
+    # one ragged work list per dispatch, shared by every layer's read
+    work = (
+        page_attention.page_work_list(row_tables, offsets, C, page_size)
+        if page_kernel else None
+    )
     new_caches = []
     for lp, c in zip(params["layers"], caches):
         def attn(q, k, v, c=c):
@@ -1515,6 +1523,7 @@ def _chunk_layers_paged(
                     out = _paged_kernel_read(
                         q, ck, cv, row_tables, offsets, cks, cvs,
                         interpret=(page_kernel == "interpret"), tp=tp,
+                        work=work,
                     ).astype(q.dtype)
                     return out, ()
                 # same dequant math as the fixed chunk path (int->f32,
@@ -1550,6 +1559,7 @@ def _chunk_layers_paged(
                     out = _paged_kernel_read(
                         q, ck, cv, row_tables, offsets,
                         interpret=(page_kernel == "interpret"), tp=tp,
+                        work=work,
                     ).astype(q.dtype)
                     return out, ()
                 out = _attention(
@@ -1649,7 +1659,7 @@ def decode_layers_paged(
 
     ``page_kernel`` (None | 'compiled' | 'interpret') serves the read
     through ops/page_attention.py instead of the XLA gather: identical
-    pool writes, per-row DMA grids clamped to live pages, online
+    pool writes, a walk over each row's live pages, online
     softmax in f32 — same dequant formula, blockwise accumulation
     order (float-tolerance vs the gather; the bench A/B is the
     token-identity gate on hardware)."""
@@ -1669,6 +1679,11 @@ def decode_layers_paged(
     phys = jnp.where(live[:, None], phys, 0)
     sip = pos2 % page_size
     mask = jnp.arange(W, dtype=jnp.int32)[None, None, :] <= pos2[:, :, None]
+    # one ragged work list per step, shared by every layer's read
+    work = (
+        page_attention.page_work_list(tables, positions, 1, page_size)
+        if page_kernel else None
+    )
     new_caches = []
     for lp, c in zip(params["layers"], caches):
         def attn(q, k, v, c=c):
@@ -1684,6 +1699,7 @@ def decode_layers_paged(
                     out = _paged_kernel_read(
                         q, ck, cv, tables, positions, cks, cvs,
                         interpret=(page_kernel == "interpret"), tp=tp,
+                        work=work,
                     ).astype(q.dtype)
                     return out, ()
                 # decode_attention_xla's math over the gathered window:
@@ -1719,6 +1735,7 @@ def decode_layers_paged(
                     out = _paged_kernel_read(
                         q, ck, cv, tables, positions,
                         interpret=(page_kernel == "interpret"), tp=tp,
+                        work=work,
                     ).astype(q.dtype)
                     return out, ()
                 out = _attention(

@@ -23,8 +23,8 @@ Layout scope: both entry points here read the FIXED per-slot cache
 layout (``[B, Hkv, S, Dh]`` dense strips, one per decode slot). The
 paged layout (``kv_layout=paged``, docs/paged_kv.md) has its own ragged
 kernel — ``ops/page_attention.py``, this module's per-slot clamp made
-page-granular: each row's DMA grid is clamped to its own live PAGES via
-the scalar-prefetched page table, with the XLA dequant gather in
+page-granular: it walks a scalar-prefetched work list of each row's
+live PAGES only, with the XLA dequant gather in
 models/llama.py ``decode_layers_paged`` as the every-geometry fallback.
 
 Layouts (head-major so each slot streams contiguous rows):

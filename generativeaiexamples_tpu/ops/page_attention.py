@@ -7,11 +7,11 @@ power-of-two window ``W`` of pages per row — the whole batch pays the
 longest live sequence, exactly the padded-window traffic the paged
 design exists to remove. This is the ragged analogue of
 ``ops/decode_attention.py``'s per-slot clamp (PAPERS.md: "Ragged Paged
-Attention" is this kernel for TPU): the page table and positions are
-scalar-prefetched, and each batch row's DMA grid is clamped to its own
-LIVE pages — the index map re-points every block past the row's last
-live page at that last page, so Mosaic elides the re-fetch and cache
-traffic tracks each sequence's true page-rounded length
+Attention" is this kernel for TPU): the kernel walks a flat WORK LIST
+of the live (row, page) pairs only (``page_work_list``: each row's
+pages up to its last query position, flattened in row order), handed
+over by scalar prefetch, so both the cache traffic and the number of
+grid steps track each sequence's true page-rounded length
 (``utils/hardware.kv_read_bytes_ragged`` is this kernel's operand math).
 
 Differences from the fixed-layout kernel:
@@ -39,17 +39,33 @@ Differences from the fixed-layout kernel:
   ``<= positions[b] + t``). Long chunks (prefill extend) stay on the
   XLA gather — ``supports_geometry`` refuses them.
 
-Grid: ``(B, Pmax)`` — one grid step DMAs ONE page (all KV heads) of one
-row; softmax running max/sum carried in VMEM scratch across the
-innermost (arbitrary) page dimension, as in the fixed kernel. Dead rows
-(position 0 pointing at the scratch page) compute finite garbage that
-the engine discards, identical to the fixed kernel's contract.
+Grid: one dimension of ``n_work = sum_b live_pages(b)`` steps, a
+DYNAMIC bound (``PrefetchScalarGridSpec`` takes a traced scalar; Mosaic
+compiles the loop with a run-time trip count). Step ``i`` DMAs pool page
+``phys[i]`` (all KV heads) for row ``row[i]``; the running softmax
+max/sum/accumulator live in VMEM scratch, reset where an item is the
+first page of its row and normalised into the row's output block where
+it is the last. The output (and query) block index is the row, so a
+block moves once per row. A row's pages stay in ascending order: the
+accumulation order, and so every output bit, is that of the
+``(B, Pmax)`` grid this walk replaced. That grid took all ``B * Pmax``
+steps whatever the rows held (an index-map clamp elided only a dead
+page's DMA) and cost ~0.3 us a dead step: at 64 slots x 32 pages with
+5-6 live pages a row, two thirds of the kernel's time (PERF.md, PR 27).
+A dead row (position 0 pointing at the scratch page) keeps one item, so
+its output block is still written: finite garbage that the engine
+discards, identical to the fixed kernel's contract.
+
+The flat grid is ``arbitrary``: it gives up the ``parallel`` row
+dimension of the old grid. On v5e (one TensorCore a chip) that costs
+nothing; a two-core chip would want the list split in two halves of
+about equal work, one per core.
 """
 from __future__ import annotations
 
 import functools
 import math
-from typing import Optional
+from typing import NamedTuple, Optional
 
 import jax
 import jax.numpy as jnp
@@ -81,8 +97,8 @@ def _unpack_nibbles(u):
 
 
 def _kernel(
-    tbl_ref, pos_ref, q_ref, *refs,
-    scale: float, page: int, n_pages: int, hq: int, hkv: int, g: int,
+    row_ref, page_ref, phys_ref, pos_ref, q_ref, *refs,
+    scale: float, page: int, hq: int, hkv: int, g: int,
     t: int, s_max: int, quantized: bool, packed: bool,
 ):
     if quantized:
@@ -90,9 +106,10 @@ def _kernel(
     else:
         ks_ref = vs_ref = None
         k_ref, v_ref, o_ref, m_ref, l_ref, acc_ref = refs
-    b = pl.program_id(0)
-    j = pl.program_id(1)
-    p_first = pos_ref[b]
+    del phys_ref  # consumed by the pool index maps only
+    i = pl.program_id(0)
+    j = page_ref[i]  # logical page of row row_ref[i]; ascending per row
+    p_first = pos_ref[row_ref[i]]
     last_tok = jnp.minimum(p_first + t - 1, s_max - 1)
     rows = t * hq
     cols = page * hkv
@@ -104,66 +121,105 @@ def _kernel(
         l_ref[...] = jnp.zeros_like(l_ref)
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
-    # Pages wholly past this row's last live token hold no attendable
-    # rows; their DMA was already elided by the clamped index maps.
-    @pl.when(j * page <= last_tok)
-    def _compute():
-        q = q_ref[0].reshape(rows, dh)  # [T*Hq, Dh] (leading-dim merge)
-        if packed:
-            # int4 pool: nibble-unpack to exact bf16 integers in [-7, 7]
-            # before the dot — the same exact-operand discipline as int8
-            k_cat = _unpack_nibbles(k_ref[0].reshape(cols, dh // 2))
-        else:
-            k_cat = k_ref[0].reshape(cols, dh).astype(jnp.bfloat16)
-        sc = lax.dot_general(
-            q, k_cat, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32,
-        )  # [rows, page*Hkv]; column c = (token-in-page)*Hkv + kv-head
-        if quantized:
-            # page-granular K scales fold in AFTER the int8/int4 dot
-            # (small integers convert to bf16 exactly, so the MXU saw
-            # exact operands)
-            sc = sc * (ks_ref[0].reshape(1, cols) * scale)
-        else:
-            sc = sc * scale
-        col_iota = lax.broadcasted_iota(jnp.int32, (rows, cols), 1)
-        row_iota = lax.broadcasted_iota(jnp.int32, (rows, 1), 0)
-        tok = j * page + col_iota // hkv
-        col_head = col_iota % hkv
-        row_head = (row_iota % hq) // g
-        # per-query-row causal clamp: query t attends <= positions + t
-        q_pos = jnp.minimum(p_first + row_iota // hq, s_max - 1)
-        live = (tok <= q_pos) & (col_head == row_head)
-        sc = jnp.where(live, sc, _NEG_INF)
+    # every work item is a live page: page_work_list emits none past
+    # the row's last live token
+    q = q_ref[0].reshape(rows, dh)  # [T*Hq, Dh] (leading-dim merge)
+    if packed:
+        # int4 pool: nibble-unpack to exact bf16 integers in [-7, 7]
+        # before the dot — the same exact-operand discipline as int8
+        k_cat = _unpack_nibbles(k_ref[0].reshape(cols, dh // 2))
+    else:
+        k_cat = k_ref[0].reshape(cols, dh).astype(jnp.bfloat16)
+    sc = lax.dot_general(
+        q, k_cat, (((1,), (1,)), ((), ())),
+        preferred_element_type=jnp.float32,
+    )  # [rows, page*Hkv]; column c = (token-in-page)*Hkv + kv-head
+    if quantized:
+        # page-granular K scales fold in AFTER the int8/int4 dot
+        # (small integers convert to bf16 exactly, so the MXU saw
+        # exact operands)
+        sc = sc * (ks_ref[0].reshape(1, cols) * scale)
+    else:
+        sc = sc * scale
+    col_iota = lax.broadcasted_iota(jnp.int32, (rows, cols), 1)
+    row_iota = lax.broadcasted_iota(jnp.int32, (rows, 1), 0)
+    tok = j * page + col_iota // hkv
+    col_head = col_iota % hkv
+    row_head = (row_iota % hq) // g
+    # per-query-row causal clamp: query t attends <= positions + t
+    q_pos = jnp.minimum(p_first + row_iota // hq, s_max - 1)
+    live = (tok <= q_pos) & (col_head == row_head)
+    sc = jnp.where(live, sc, _NEG_INF)
 
-        m_prev = m_ref[:, :1]  # [rows, 1]
-        m_new = jnp.maximum(m_prev, jnp.max(sc, axis=1, keepdims=True))
-        prob = jnp.exp(sc - m_new)  # dead/foreign-head columns -> 0
-        alpha = jnp.exp(m_prev - m_new)
-        l_ref[...] = jnp.broadcast_to(
-            alpha * l_ref[:, :1] + jnp.sum(prob, axis=1, keepdims=True),
-            l_ref.shape,
-        )
-        m_ref[...] = jnp.broadcast_to(m_new, m_ref.shape)
-        if quantized:
-            prob = prob * vs_ref[0].reshape(1, cols)
-        if packed:
-            v_cat = _unpack_nibbles(v_ref[0].reshape(cols, dh // 2))
-        else:
-            v_cat = v_ref[0].reshape(cols, dh).astype(jnp.bfloat16)
-        out = lax.dot_general(
-            prob.astype(jnp.bfloat16), v_cat, (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32,
-        )  # [rows, Dh]
-        acc_ref[...] = acc_ref[...] * alpha + out
+    m_prev = m_ref[:, :1]  # [rows, 1]
+    m_new = jnp.maximum(m_prev, jnp.max(sc, axis=1, keepdims=True))
+    prob = jnp.exp(sc - m_new)  # dead/foreign-head columns -> 0
+    alpha = jnp.exp(m_prev - m_new)
+    l_ref[...] = jnp.broadcast_to(
+        alpha * l_ref[:, :1] + jnp.sum(prob, axis=1, keepdims=True),
+        l_ref.shape,
+    )
+    m_ref[...] = jnp.broadcast_to(m_new, m_ref.shape)
+    if quantized:
+        prob = prob * vs_ref[0].reshape(1, cols)
+    if packed:
+        v_cat = _unpack_nibbles(v_ref[0].reshape(cols, dh // 2))
+    else:
+        v_cat = v_ref[0].reshape(cols, dh).astype(jnp.bfloat16)
+    out = lax.dot_general(
+        prob.astype(jnp.bfloat16), v_cat, (((1,), (0,)), ((), ())),
+        preferred_element_type=jnp.float32,
+    )  # [rows, Dh]
+    acc_ref[...] = acc_ref[...] * alpha + out
 
-    @pl.when(j == n_pages - 1)
+    # the row's last live page: its successor would start past last_tok
+    @pl.when((j + 1) * page > last_tok)
     def _finish():
         l = l_ref[:, :1]
         l = jnp.where(l == 0.0, 1.0, l)  # paranoia: never divide by 0
         o_ref[0] = (
             (acc_ref[...] / l).reshape(t, hq, dh).astype(o_ref.dtype)
         )
+
+
+class PageWork(NamedTuple):
+    """The ragged work list of one attention read: item ``i < n_work[0]``
+    is logical page ``page[i]`` of row ``row[i]``, stored in pool page
+    ``phys[i]``. Rows ascend, pages ascend inside a row, every row has
+    at least one item. Entries past ``n_work`` are in-bounds padding."""
+
+    n_work: jax.Array  # [1] int32
+    row: jax.Array  # [B * Pmax] int32
+    page: jax.Array  # [B * Pmax] int32
+    phys: jax.Array  # [B * Pmax] int32
+
+
+def page_work_list(
+    tables: jax.Array,  # [B, Pmax] int32
+    positions: jax.Array,  # [B] int32 — first query token's position
+    query_len: int,
+    page_size: int,
+) -> PageWork:
+    """Flatten each row's LIVE pages into one list the kernel walks.
+
+    Row ``b`` holds ``n_b = min(pos_b + T - 1, S - 1) // page + 1``
+    items, so the list has ``sum(n_b)`` of them — not ``B * Pmax`` —
+    and a dead row (position 0) keeps exactly one (its scratch page),
+    which is what writes its output block. Pure ``jnp``: the paged
+    model computes it once per step and every layer's read shares it.
+    """
+    B, Pmax = tables.shape
+    pos = positions.astype(jnp.int32)
+    n = jnp.minimum(pos + query_len - 1, Pmax * page_size - 1) // page_size + 1
+    ends = jnp.cumsum(n)
+    item = jnp.arange(B * Pmax, dtype=jnp.int32)
+    row = jnp.minimum(
+        jnp.sum(item[:, None] >= ends[None, :], axis=1, dtype=jnp.int32), B - 1
+    )
+    page = jnp.minimum(item - (ends - n)[row], n[row] - 1)
+    return PageWork(
+        ends[-1:], row, page, tables.astype(jnp.int32)[row, page]
+    )
 
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
@@ -177,15 +233,20 @@ def paged_attention(
     v_scale: Optional[jax.Array] = None,
     *,
     interpret: bool = False,
+    work: Optional[PageWork] = None,
 ) -> jax.Array:
     """Attention output ``[B, T, Hq, Dh]`` over each row's live pages.
 
     Query token ``t`` of row ``b`` sits at absolute position
     ``positions[b] + t`` and attends cache rows at positions ``<= that``
     (the chunk's own rows must already be written to the pool — the
-    paged model passes post-update pools, models/llama.py). Rows whose
-    table entries past their live length point at the scratch page are
-    never read: the DMA grid is clamped to ``positions[b] + T - 1``.
+    paged model passes post-update pools, models/llama.py). Table
+    entries past a row's live length (they point at the scratch page)
+    are never read: the work list stops at ``positions[b] + T - 1``.
+
+    ``work`` is ``page_work_list(tables, positions, T, page)``; a caller
+    that reads many layers at the same positions passes it so the list
+    is built once, otherwise it is built here.
     """
     B, T, Hq, Dh = q.shape
     P, page, Hkv, Dh_pool = k.shape
@@ -204,43 +265,39 @@ def paged_attention(
     S = Pmax * page
     scale = 1.0 / math.sqrt(Dh)
     pos = positions.astype(jnp.int32)
-    tbl = tables.astype(jnp.int32)
-
-    def last_page(pos_ref, b, t=T):
-        # Clamp: dead slots carry position 0; never index past capacity.
-        return jnp.minimum(pos_ref[b] + t - 1, S - 1) // page
+    if work is None:
+        work = page_work_list(tables, pos, T, page)
 
     def pool_spec():
         return pl.BlockSpec(
             (1, page, Hkv, Dh_pool),
-            lambda b, j, tbl, pos: (
-                tbl[b, jnp.minimum(j, last_page(pos, b))], 0, 0, 0
-            ),
+            lambda i, row, pg, phys, pos: (phys[i], 0, 0, 0),
         )
 
     def scale_spec():
         return pl.BlockSpec(
-            (1, page, Hkv),
-            lambda b, j, tbl, pos: (
-                tbl[b, jnp.minimum(j, last_page(pos, b))], 0, 0
-            ),
+            (1, page, Hkv), lambda i, row, pg, phys, pos: (phys[i], 0, 0)
         )
 
-    q_spec = pl.BlockSpec((1, T, Hq, Dh), lambda b, j, tbl, pos: (b, 0, 0, 0))
+    def row_spec():
+        # q and the output follow the item's row: fetched / written
+        # back only where the row changes
+        return pl.BlockSpec(
+            (1, T, Hq, Dh), lambda i, row, pg, phys, pos: (row[i], 0, 0, 0)
+        )
+
     if quantized:
-        in_specs = [q_spec, pool_spec(), scale_spec(), pool_spec(), scale_spec()]
-        operands = (tbl, pos, q, k, k_scale, v, v_scale)
+        in_specs = [row_spec(), pool_spec(), scale_spec(), pool_spec(), scale_spec()]
+        operands = (q, k, k_scale, v, v_scale)
     else:
-        in_specs = [q_spec, pool_spec(), pool_spec()]
-        operands = (tbl, pos, q, k, v)
+        in_specs = [row_spec(), pool_spec(), pool_spec()]
+        operands = (q, k, v)
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,
-        grid=(B, Pmax),
+        num_scalar_prefetch=4,
+        grid=(work.n_work[0],),
         in_specs=in_specs,
-        out_specs=pl.BlockSpec(
-            (1, T, Hq, Dh), lambda b, j, tbl, pos: (b, 0, 0, 0)
-        ),
+        out_specs=row_spec(),
         scratch_shapes=[
             pltpu.VMEM((T * Hq, _LANE), jnp.float32),
             pltpu.VMEM((T * Hq, _LANE), jnp.float32),
@@ -249,17 +306,16 @@ def paged_attention(
     )
     out = pl.pallas_call(
         functools.partial(
-            _kernel, scale=scale, page=page, n_pages=Pmax, hq=Hq,
-            hkv=Hkv, g=G, t=T, s_max=S, quantized=quantized,
-            packed=packed,
+            _kernel, scale=scale, page=page, hq=Hq, hkv=Hkv, g=G, t=T,
+            s_max=S, quantized=quantized, packed=packed,
         ),
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((B, T, Hq, Dh), q.dtype),
         compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel", "arbitrary")
+            dimension_semantics=("arbitrary",)
         ),
         interpret=interpret,
-    )(*operands)
+    )(work.row, work.page, work.phys, pos, *operands)
     return out
 
 

@@ -194,7 +194,7 @@ def decode_attention_supported(cfg, shards: int, S: int) -> bool:
 
 def paged_attention_tp(
     q, k, v, tables, positions, k_scale=None, v_scale=None,
-    *, tp: TPContext, interpret: bool = False,
+    *, tp: TPContext, interpret: bool = False, work=None,
 ):
     """Ragged page-attention with the head axis sharded over ``model``.
 
@@ -211,35 +211,32 @@ def paged_attention_tp(
 
     ``interpret`` is threaded separately from ``tp.interpret`` so the
     engine's ``paged_kernel=interpret`` override reaches the kernel the
-    same way it does on a single device.
+    same way it does on a single device. ``work`` is the caller's
+    ``page_attention.page_work_list`` (built here when absent).
     """
     hspec = P(None, None, MODEL_AXIS, None)
     sspec = P(None, None, MODEL_AXIS)
     run_interpret = interpret or tp.interpret
+    if work is None:
+        work = page_attention.page_work_list(
+            tables, positions, q.shape[1], k.shape[1]
+        )
+    # the work list replicates with the tables: every device walks the
+    # same (row, page) items over its own heads
+    rep = page_attention.PageWork(P(None), P(None), P(None), P(None))
+    scales = () if k_scale is None else (k_scale, v_scale)
 
-    if k_scale is not None:
-        in_specs = (hspec, hspec, hspec, P(None, None), P(None), sspec, sspec)
-
-        def body(ql, kl, vl, tbl, posl, ksl, vsl):
-            return page_attention.paged_attention(
-                ql, kl, vl, tbl, posl, ksl, vsl, interpret=run_interpret
-            )
-
-        operands = (q, k, v, tables, positions, k_scale, v_scale)
-    else:
-        in_specs = (hspec, hspec, hspec, P(None, None), P(None))
-
-        def body(ql, kl, vl, tbl, posl):
-            return page_attention.paged_attention(
-                ql, kl, vl, tbl, posl, interpret=run_interpret
-            )
-
-        operands = (q, k, v, tables, positions)
+    def body(ql, kl, vl, tbl, posl, wl, *sl):
+        return page_attention.paged_attention(
+            ql, kl, vl, tbl, posl, *sl, interpret=run_interpret, work=wl
+        )
 
     return shard_map(
-        body, mesh=tp.mesh, in_specs=in_specs, out_specs=hspec,
-        check_vma=False,
-    )(*operands)
+        body, mesh=tp.mesh,
+        in_specs=(hspec, hspec, hspec, P(None, None), P(None), rep)
+        + (sspec,) * len(scales),
+        out_specs=hspec, check_vma=False,
+    )(q, k, v, tables, positions, work, *scales)
 
 
 def decode_attention_tp(q, k_q, k_s, v_q, v_s, positions, tp: TPContext):

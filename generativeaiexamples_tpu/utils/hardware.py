@@ -170,7 +170,10 @@ def kv_read_bytes_ragged(model_cfg, live_tokens: int, kv_bytes: float) -> int:
     batch x padded-window product above. This is what the paged engine
     feeds the utilization estimator, so the roofline gauges charge the
     bytes the ragged kernel actually reads instead of phantom
-    padded-window traffic."""
+    padded-window traffic. The kernel's TIME follows the same sum: it
+    walks one grid step per live page (ops/page_attention.page_work_list),
+    not a dense slots x max_pages grid, so bytes and steps move
+    together."""
     # exactly the per-step formula at batch=1 x live_tokens "window" —
     # one expression, so the fixed and paged accounting cannot drift
     return kv_read_bytes_per_step(model_cfg, 1, live_tokens, kv_bytes)

@@ -2,7 +2,7 @@
 
 Every entry point that may compile (chain-server, engine server,
 router, bench.py, tools/precompile.py, chip_smoke.py) calls
-:func:`bootstrap` before jax initializes a backend. Two rules:
+:func:`bootstrap` before jax initializes a backend. Three rules:
 
 - **Compile cache.** Where ``JAX_COMPILATION_CACHE_DIR`` is set the
   deployment owns the location and nothing here touches it; where it is
@@ -10,6 +10,14 @@ router, bench.py, tools/precompile.py, chip_smoke.py) calls
   directory is part of the cache key, so it is never a temporary,
   per-user, per-process or per-time path — a directory that moves never
   hits.
+- **Small executables are kept too.** jax persists only what took a
+  second or more to compile; a serving start compiles ~300 programs
+  under that (one page-table scatter per wave size, the eager fills
+  and casts of engine build and warm-up), ~20 s of every start with an
+  otherwise warm cache. On an accelerator the threshold is set to 0
+  unless the deployment set ``JAX_PERSISTENT_CACHE_MIN_COMPILE_TIME_SECS``
+  itself; a CPU-only process (the test suite: thousands of cheap
+  programs) keeps jax's default.
 - **Host staging.** Weights are initialized/quantized on the host and
   device-put once (8B in bf16 would not fit a 16 GB chip), which needs
   jax's ``cpu`` backend. A ``JAX_PLATFORMS`` list restricted to the
@@ -27,6 +35,7 @@ import sys
 
 CACHE_ENV = "JAX_COMPILATION_CACHE_DIR"
 PLATFORMS_ENV = "JAX_PLATFORMS"
+MIN_COMPILE_ENV = "JAX_PERSISTENT_CACHE_MIN_COMPILE_TIME_SECS"
 
 
 def checkout_root() -> str:
@@ -59,6 +68,19 @@ def configure_compile_cache() -> str:
     return path
 
 
+def persist_small_executables() -> None:
+    """Keep executables that compiled in under a second as well (see
+    module docstring); a set variable or a CPU-first platform list is
+    left as found."""
+    names = [p.strip() for p in os.environ.get(PLATFORMS_ENV, "").split(",")]
+    if MIN_COMPILE_ENV in os.environ or names[0] == "cpu":
+        return
+    os.environ[MIN_COMPILE_ENV] = "0"
+    jax = sys.modules.get("jax")
+    if jax is not None:
+        jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
+
+
 def ensure_host_platform() -> str:
     """Keep the ``cpu`` backend reachable next to a restricted platform
     list (see module docstring). Returns the list in force ('' = jax's
@@ -77,6 +99,7 @@ def ensure_host_platform() -> str:
 
 def bootstrap() -> str:
     """Entry-point preamble; returns the compile-cache directory."""
+    persist_small_executables()
     ensure_host_platform()
     return configure_compile_cache()
 

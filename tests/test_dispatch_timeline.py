@@ -348,6 +348,22 @@ def test_perfetto_xplane_events_replace_estimate_track():
         _fresh()
 
 
+def test_span_counters_show_as_fields_of_the_view():
+    """Kind-specific counts (the decode span's kv_pages_walked /
+    kv_pages_grid) ride the span and surface as top-level fields;
+    a span recorded without them has none."""
+    _fresh()
+    try:
+        _span("decode", rows=2, counters={"kv_pages_walked": 7, "kv_pages_grid": 64})
+        _span("prefill", rows=1)
+        decode, prefill = dtl.spans_since(0)[0]
+        assert decode["kv_pages_walked"] == 7 and decode["kv_pages_grid"] == 64
+        assert decode["kv_pages_walked"] <= decode["kv_pages_grid"]
+        assert "kv_pages_walked" not in prefill and "counters" not in prefill
+    finally:
+        _fresh()
+
+
 # --------------------------------------------------------------------------- #
 # GET /internal/timeline
 

@@ -98,3 +98,31 @@ def test_entry_points_share_the_helper():
         assert 'JAX_COMPILATION_CACHE_DIR"' not in src.replace(
             'os.environ.get("JAX_COMPILATION_CACHE_DIR")', ""
         ), rel
+
+
+@pytest.mark.parametrize(
+    "platforms,preset,want",
+    [
+        ("tpu,cpu", None, "0"),  # the chip machine: sub-second programs are kept
+        ("tpu", None, "0"),
+        ("", None, "0"),  # jax's own order: the accelerator comes first
+        ("cpu", None, None),  # the test suite keeps jax's default
+        (" cpu ,tpu", None, None),
+        ("tpu,cpu", "2.5", "2.5"),  # the deployment's own choice stands
+    ],
+)
+def test_small_executables_are_persisted_on_an_accelerator(monkeypatch, platforms, preset, want):
+    import jax
+
+    prev = jax.config.jax_persistent_cache_min_compile_time_secs
+    monkeypatch.setenv(jax_env.PLATFORMS_ENV, platforms)
+    monkeypatch.delenv(jax_env.MIN_COMPILE_ENV, raising=False)
+    if preset is not None:
+        monkeypatch.setenv(jax_env.MIN_COMPILE_ENV, preset)
+    try:
+        jax_env.persist_small_executables()
+        assert os.environ.get(jax_env.MIN_COMPILE_ENV) == want
+        changed = want == "0"
+        assert jax.config.jax_persistent_cache_min_compile_time_secs == (0.0 if changed else prev)
+    finally:
+        jax.config.update("jax_persistent_cache_min_compile_time_secs", prev)

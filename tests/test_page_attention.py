@@ -5,6 +5,7 @@ rows, multi-query causal chunks), plus the geometry-predicate matrix —
 so the kernel's logic is tier-1-tested without TPU hardware (the
 compiled path's tiling is what ``supports_geometry`` guards)."""
 import math
+import zlib
 
 import numpy as np
 import pytest
@@ -30,40 +31,38 @@ def _ragged_tables(rng):
     return jnp.asarray(tables)
 
 
-def _bf16_pool(rng):
-    k = jnp.asarray(rng.standard_normal((POOL, PAGE, Hkv, Dh)), jnp.bfloat16)
-    v = jnp.asarray(rng.standard_normal((POOL, PAGE, Hkv, Dh)), jnp.bfloat16)
+def _bf16_pool(rng, pool=POOL, hkv=Hkv):
+    k = jnp.asarray(rng.standard_normal((pool, PAGE, hkv, Dh)), jnp.bfloat16)
+    v = jnp.asarray(rng.standard_normal((pool, PAGE, hkv, Dh)), jnp.bfloat16)
     return k, v
 
 
-def _int8_pool(rng):
-    kq = jnp.asarray(rng.integers(-127, 128, (POOL, PAGE, Hkv, Dh)), jnp.int8)
-    vq = jnp.asarray(rng.integers(-127, 128, (POOL, PAGE, Hkv, Dh)), jnp.int8)
-    ks = jnp.asarray(rng.uniform(0.005, 0.02, (POOL, PAGE, Hkv)), jnp.float32)
-    vs = jnp.asarray(rng.uniform(0.005, 0.02, (POOL, PAGE, Hkv)), jnp.float32)
+def _int8_pool(rng, pool=POOL, hkv=Hkv):
+    kq = jnp.asarray(rng.integers(-127, 128, (pool, PAGE, hkv, Dh)), jnp.int8)
+    vq = jnp.asarray(rng.integers(-127, 128, (pool, PAGE, hkv, Dh)), jnp.int8)
+    ks = jnp.asarray(rng.uniform(0.005, 0.02, (pool, PAGE, hkv)), jnp.float32)
+    vs = jnp.asarray(rng.uniform(0.005, 0.02, (pool, PAGE, hkv)), jnp.float32)
     return kq, vq, ks, vs
 
 
 def _reference(q, k, v, tables, pos, ks=None, vs=None):
     """Pure-jnp gather-all-pages + position mask — the same semantics
     models/llama.py's paged XLA paths compute (f32 softmax over the
-    full gathered window)."""
-    nb, t = q.shape[:2]
-    g = k[tables].reshape(nb, S, Hkv, Dh)
-    gv = v[tables].reshape(nb, S, Hkv, Dh)
+    full gathered window). Geometry is read from the shapes."""
+    nb, t, hq, dh = q.shape
+    hkv = k.shape[2]
+    s_max = tables.shape[1] * k.shape[1]
+    g = k[tables].reshape(nb, s_max, hkv, dh).astype(jnp.float32)
+    gv = v[tables].reshape(nb, s_max, hkv, dh).astype(jnp.float32)
     if ks is not None:
-        g = g.astype(jnp.float32) * ks[tables].reshape(nb, S, Hkv)[..., None]
-        gv = gv.astype(jnp.float32) * vs[tables].reshape(nb, S, Hkv)[..., None]
-    qg = q.reshape(nb, t, Hkv, Hq // Hkv, Dh).astype(jnp.float32)
-    sc = jnp.einsum(
-        "btkgd,bskd->bkgts", qg, g.astype(jnp.float32)
-    ) / math.sqrt(Dh)
-    qpos = jnp.minimum(pos[:, None] + jnp.arange(t)[None, :], S - 1)
-    mask = jnp.arange(S)[None, None, :] <= qpos[:, :, None]
-    sc = jnp.where(mask[:, None, None], sc, -1e30)
-    p = jax.nn.softmax(sc, axis=-1)
-    out = jnp.einsum("bkgts,bskd->btkgd", p, gv.astype(jnp.float32))
-    return out.reshape(nb, t, Hq, Dh)
+        g = g * ks[tables].reshape(nb, s_max, hkv)[..., None]
+        gv = gv * vs[tables].reshape(nb, s_max, hkv)[..., None]
+    qg = q.reshape(nb, t, hkv, hq // hkv, dh).astype(jnp.float32)
+    sc = jnp.einsum("btkgd,bskd->bkgts", qg, g) / math.sqrt(dh)
+    qpos = jnp.minimum(pos[:, None] + jnp.arange(t)[None, :], s_max - 1)
+    mask = jnp.arange(s_max)[None, None, :] <= qpos[:, :, None]
+    p = jax.nn.softmax(jnp.where(mask[:, None, None], sc, -1e30), axis=-1)
+    return jnp.einsum("bkgts,bskd->btkgd", p, gv).reshape(nb, t, hq, dh)
 
 
 def _assert_close(out, ref, atol=0.02):
@@ -172,6 +171,114 @@ def test_dead_row_output_is_finite_garbage():
     assert bool(jnp.isfinite(out.astype(jnp.float32)).all())
 
 
+# ------------------------------------------------------------------ //
+# the ragged walk: a one-dimensional grid over live (row, page) items
+# (page_work_list) instead of (B, Pmax); every edge of the list against
+# the XLA gather
+
+
+# first-query positions of four rows; a row at 0 is dead (its table is
+# all scratch), every other row owns ceil-many distinct pool pages
+WALK_CASES = {
+    "dead_row_between_live_rows": [11, 0, 37, 5],
+    "page_boundary_last_and_first_token": [PAGE - 1, PAGE, 3 * PAGE - 1, 3 * PAGE],
+    "row_at_capacity": [S - 1, 2, S - 2, 20],
+    "all_rows_dead": [0, 0, 0, 0],
+}
+WALK_POOL = 40
+
+
+def _walk_tables(pos, t):
+    tables = np.zeros((len(pos), PMAX), np.int32)
+    nxt = 1
+    for b, p in enumerate(pos):
+        if p == 0:
+            continue  # dead: scratch entries only
+        n = min(p + t - 1, S - 1) // PAGE + 1
+        tables[b, :n] = np.arange(nxt, nxt + n)
+        nxt += n
+    assert nxt <= WALK_POOL
+    return jnp.asarray(tables)
+
+
+def _walk_pool(rng, kind, hkv):
+    """(kernel operands, reference operands) of one pool dtype."""
+    if kind == "bf16":
+        pool = _bf16_pool(rng, WALK_POOL, hkv)
+        return pool, pool
+    if kind == "int8":
+        pool = _int8_pool(rng, WALK_POOL, hkv)
+        return pool, pool
+    kq, vq, ks, vs = _int4_pool(rng, WALK_POOL, hkv)
+    return (kq, vq, ks, vs), (_unpack_pool(kq), _unpack_pool(vq), ks, vs)
+
+
+@pytest.mark.parametrize("heads", [(32, 8), (8, 8)], ids=["gqa32-8", "mha8-8"])
+@pytest.mark.parametrize("pool", ["int8", "int4", "bf16"])
+@pytest.mark.parametrize("t", [1, 4], ids=["decode", "verify4"])
+@pytest.mark.parametrize("case", sorted(WALK_CASES))
+def test_ragged_walk_matches_gather(case, t, pool, heads):
+    hq, hkv = heads
+    pos = WALK_CASES[case]
+    rng = np.random.default_rng(zlib.crc32(repr((case, t, pool, heads)).encode()))
+    tables = _walk_tables(pos, t)
+    kernel_pool, ref_pool = _walk_pool(rng, pool, hkv)
+    q = jnp.asarray(rng.standard_normal((len(pos), t, hq, Dh)), jnp.bfloat16)
+    posj = jnp.asarray(pos, jnp.int32)
+    k, v, *scales = kernel_pool
+    out = pa.paged_attention(q, k, v, tables, posj, *scales, interpret=True)
+    assert bool(jnp.isfinite(out.astype(jnp.float32)).all())
+    rk, rv, *rscales = ref_pool
+    _assert_close(out, _reference(q, rk, rv, tables, posj, *rscales))
+
+
+@pytest.mark.parametrize("t", [1, 4])
+@pytest.mark.parametrize("case", sorted(WALK_CASES))
+def test_page_work_list_holds_live_pages_only(case, t):
+    """The list alone (pure jnp): as many items as live pages, a dead
+    row keeps one, rows ascend, a row's pages ascend from 0 to its last
+    live page — so each row starts and finishes exactly once — and
+    every item names the pool page its table entry holds."""
+    pos = np.asarray(WALK_CASES[case])
+    tables = _walk_tables(list(pos), t)
+    work = pa.page_work_list(tables, jnp.asarray(pos, jnp.int32), t, PAGE)
+    live = np.minimum(pos + t - 1, S - 1) // PAGE + 1
+    n = int(work.n_work[0])
+    assert n == live.sum()
+    assert work.row.shape == work.page.shape == work.phys.shape == (len(pos) * PMAX,)
+    if case != "row_at_capacity":
+        assert n < len(pos) * PMAX  # never the dense grid for short rows
+    row, page = np.asarray(work.row)[:n], np.asarray(work.page)[:n]
+    want_row = np.repeat(np.arange(len(pos)), live)
+    want_page = np.concatenate([np.arange(m) for m in live])
+    np.testing.assert_array_equal(row, want_row)
+    np.testing.assert_array_equal(page, want_page)
+    for b, m in enumerate(live):
+        assert (page[row == b] == 0).sum() == 1  # _init fires once
+        assert (page[row == b] == m - 1).sum() == 1  # _finish fires once
+    np.testing.assert_array_equal(
+        np.asarray(work.phys)[:n], np.asarray(tables)[row, page]
+    )
+    # the padding past n_work stays inside the tables
+    assert int(work.row.max()) < len(pos) and int(work.page.max()) < PMAX
+
+
+def test_shared_work_list_equals_the_one_built_inside():
+    """A caller's pre-built list (one per step, shared by the layers)
+    gives the bits the kernel gets when it builds its own."""
+    rng = np.random.default_rng(30)
+    pos = jnp.asarray(WALK_CASES["dead_row_between_live_rows"], jnp.int32)
+    tables = _walk_tables(WALK_CASES["dead_row_between_live_rows"], 1)
+    (k, v, ks, vs), _ = _walk_pool(rng, "int8", Hkv)
+    q = jnp.asarray(rng.standard_normal((4, 1, Hq, Dh)), jnp.bfloat16)
+    work = pa.page_work_list(tables, pos, 1, PAGE)
+    got = pa.paged_attention(q, k, v, tables, pos, ks, vs, interpret=True, work=work)
+    want = pa.paged_attention(q, k, v, tables, pos, ks, vs, interpret=True)
+    np.testing.assert_array_equal(
+        np.asarray(got, np.float32), np.asarray(want, np.float32)
+    )
+
+
 @pytest.mark.parametrize(
     "kw,expect",
     [
@@ -219,11 +326,11 @@ def test_supports_geometry_interpret_relaxes_tiling_only():
 # packed int4 pools (two values per byte, split-halves codec)
 
 
-def _int4_pool(rng):
+def _int4_pool(rng, pool=POOL, hkv=Hkv):
     """Quantize a random f32 pool through the engine codec: packed
-    uint8 [POOL, PAGE, Hkv, Dh//2] + page-granular f32 scales."""
-    kf = rng.standard_normal((POOL, PAGE, Hkv, Dh)).astype(np.float32)
-    vf = rng.standard_normal((POOL, PAGE, Hkv, Dh)).astype(np.float32)
+    uint8 [pool, PAGE, hkv, Dh//2] + page-granular f32 scales."""
+    kf = rng.standard_normal((pool, PAGE, hkv, Dh)).astype(np.float32)
+    vf = rng.standard_normal((pool, PAGE, hkv, Dh)).astype(np.float32)
     kq, ks = llama.quantize_kv_int4(jnp.asarray(kf))
     vq, vs = llama.quantize_kv_int4(jnp.asarray(vf))
     return kq, vq, ks, vs
@@ -423,3 +530,49 @@ def test_paged_attention_tp_int4_matches_single_device(tp_ctx):
     np.testing.assert_array_equal(
         np.asarray(got, np.float32), np.asarray(want, np.float32)
     )
+
+
+# ------------------------------------------------------------------ //
+# the counter the walk brings: decode spans of the dispatch timeline
+
+
+def test_decode_span_carries_pages_walked_and_the_dense_grid():
+    """A served decode dispatch records what the kernel walks at its
+    first step (live rows' pages up to the query position, one scratch
+    page per empty slot — page_work_list's count, from the host's
+    position shadow) beside the slots x Pmax grid it replaced."""
+    from generativeaiexamples_tpu.config import EngineConfig
+    from generativeaiexamples_tpu.engine import dispatch_timeline as dtl
+    from generativeaiexamples_tpu.engine.llm_engine import LLMEngine, SamplingParams
+
+    dtl.reset()
+    dtl.configure(enable=True)
+    eng = LLMEngine(EngineConfig(
+        model_config_name="debug", max_batch_size=3, max_seq_len=64,
+        prefill_chunk=16, decode_block=4, decode_runahead=1,
+        tensor_parallelism=1, page_size=8, kv_layout="paged",
+        paged_kernel="interpret", watchdog_stall_s=0.0,
+    ))
+    try:
+        assert eng._paged_kernel == "interpret"
+        prompt = list(range(5, 25))  # 20 tokens: first decode query at 20
+        params = SamplingParams(temperature=0.0, max_tokens=9, seed=1)
+        assert len(list(eng.iter_ids(prompt, params, timeout=300))) == 9
+        spans, _ = dtl.spans_since(0)
+        decode = [v for v in spans if v["kind"] == "decode"]
+        assert decode and all(v["path"] == "kernel" for v in decode)
+        for v in decode:
+            assert v["kv_pages_grid"] == 3 * (64 // 8)
+            assert 3 <= v["kv_pages_walked"] <= v["kv_pages_grid"]
+        # one live row at position 20 (3 pages of 8) + two empty slots;
+        # the next block starts 4 positions on, in the fourth page
+        assert [v["kv_pages_walked"] for v in decode[:2]] == [5, 6]
+        tables = jnp.zeros((3, 8), jnp.int32)
+        for pos0, v in zip((20, 24), decode):
+            work = pa.page_work_list(tables, jnp.asarray([pos0, 0, 0]), 1, 8)
+            assert int(work.n_work[0]) == v["kv_pages_walked"]
+        # other kinds of span carry no such field
+        assert all("kv_pages_walked" not in v for v in spans if v["kind"] != "decode")
+    finally:
+        eng.shutdown()
+        dtl.reset()
