@@ -1,18 +1,20 @@
 """The server child of the benchmark: the program's own chain server,
 started unchanged, on a configuration read from a file.
 
-The engine resolves an architecture only through ``llama.PRESETS``, so
-this launcher registers the configuration file's published sizes there
-under the configuration's name (in its own process) and then calls
-``generativeaiexamples_tpu.server.__main__.main()``. Everything else —
-engine settings, chain, embedder, store — arrives as the ``APP_*``
-environment the parent built from the same file.
+The configuration file names its adapter (``"adapter"``, a module under
+``perfbench/arch/``; the contract is in ``perfbench/arch/__init__.py``).
+This launcher imports it, has it register the file's published sizes
+with the engine under the configuration's name (in its own process) and
+then calls ``generativeaiexamples_tpu.server.__main__.main()``.
+Everything else — engine settings, chain, embedder, store — arrives as
+the ``APP_*`` environment the parent built from the same file. No model
+is named here: what is particular to an architecture is the adapter's.
 
 Beside the server it runs two threads. One, once the engine's warm-up
-is done, compares the engine with the plain float32 reference
-(``perfbench/reference.py``) on the host CPU device and writes
-``reference.json`` into the work directory (the clients ramp up
-meanwhile; the parent opens no window before that file exists). The other brackets the traced interval: the
+is done, compares the engine with the adapter's plain float32 reference
+on the host CPU device and writes ``reference.json`` into the work
+directory (the clients ramp up meanwhile; the parent opens no window
+before that file exists). The other brackets the traced interval: the
 parent creates ``trace.start`` and ``trace.stop`` in the work directory
 and this process starts and stops ``jax.profiler`` with the Python
 tracer OFF (the program's own ``POST /internal/profile/start`` leaves
@@ -64,49 +66,10 @@ def memory_peak_bytes() -> int:
     return peak
 
 
-def llama_config(cfg: dict):
-    from generativeaiexamples_tpu.models.llama import LlamaConfig
-
-    return LlamaConfig(
-        vocab_size=cfg["vocab_size"], hidden_size=cfg["hidden_size"],
-        intermediate_size=cfg["intermediate_size"], num_layers=cfg["num_hidden_layers"],
-        num_heads=cfg["num_attention_heads"], num_kv_heads=cfg["num_key_value_heads"],
-        head_dim=cfg["head_dim"], rope_theta=float(cfg["rope_theta"]),
-        norm_eps=float(cfg["rms_norm_eps"]), max_seq_len=cfg["max_position_embeddings"],
-        tie_embeddings=bool(cfg["tie_word_embeddings"]),
-    )
-
-
-def engine_prefill_logits(eng, prompts, on_tpu: bool):
-    """Last-prompt-position logits through the engine's own prefill
-    forward with the kernel flags the engine resolved (as chip_smoke.py
-    obtains them for its TP comparison)."""
-    import jax
-    import jax.numpy as jnp
-    import numpy as np
-
-    from generativeaiexamples_tpu.models import llama
-    from generativeaiexamples_tpu.parallel.mesh import mesh_context
-
-    T = max(128, -(-max(len(p) for p in prompts) // 128) * 128)
-    tok = np.zeros((len(prompts), T), np.int32)
-    for i, p in enumerate(prompts):
-        tok[i, : len(p)] = p
-    lengths = np.asarray([len(p) for p in prompts], np.int32)
-    use_flash = None if (eng._mesh.size == 1 or eng._tp is not None) else False
-
-    def fwd(params, tokens, lens):
-        return llama.prefill_layers(
-            params, eng.model_config, tokens, lens, use_flash=use_flash,
-            quant_kernel=eng._quant_kernel, tp=eng._tp, interpret=not on_tpu,
-        )[0]
-
-    with mesh_context(eng._mesh):
-        return np.asarray(jax.jit(fwd)(eng.params, jnp.asarray(tok), jnp.asarray(lengths)), np.float32)
-
-
-def reference_check(cfg: dict, work: str, tp: int = 1) -> None:
-    """Runs on its own thread; never raises (a failure is a result)."""
+def reference_check(cfg: dict, adapter, work: str, tp: int = 1) -> None:
+    """The protocol of the comparison; the three steps that know the
+    model are the adapter's. Runs on its own thread; never raises (a
+    failure is a result)."""
     out = {"ok": False}
     t0 = time.time()
     try:
@@ -116,8 +79,6 @@ def reference_check(cfg: dict, work: str, tp: int = 1) -> None:
             time.sleep(0.5)
         eng = llm_engine._ENGINE
         t0 = time.time()
-        import numpy as np
-
         from generativeaiexamples_tpu.engine.llm_engine import SamplingParams
         from generativeaiexamples_tpu.utils import jax_env
         from perfbench import reference
@@ -135,25 +96,15 @@ def reference_check(cfg: dict, work: str, tp: int = 1) -> None:
         # (i) logits of the short prompts from the engine's prefill forward;
         # (ii) every prompt, the one longer than prefill_chunk included, is
         # decoded by the SERVED programs (prefill, extend, int8 paged KV, decode)
-        eng_logits = list(engine_prefill_logits(eng, prompts[:n_logits], on_tpu))
+        eng_logits = list(adapter.engine_prefill_logits(eng, prompts[:n_logits], on_tpu))
         eng_logits += [None] * (len(prompts) - n_logits)
         greedy = SamplingParams(temperature=0.0, max_tokens=int(ref_cfg["decode_tokens"]))
         eng_tokens = [list(eng.iter_ids(p, greedy, timeout=900)) for p in prompts]
-        params = eng.params
-        head = params.get("lm_head")
-        lm_head = (
-            reference.unpack(head, cfg["hidden_size"], cfg["vocab_size"], tp=tp)
-            if isinstance(head, dict)
-            else np.asarray(head if head is not None else np.asarray(params["embed"]).T, np.float32)
+        ref_logits = adapter.reference_logits(
+            eng, cfg, [list(p) + list(t) for p, t in zip(prompts, eng_tokens)], tp,
+            jax_env.host_device(),
         )
-        ref_logits = reference.forward(
-            [list(p) + list(t) for p, t in zip(prompts, eng_tokens)], cfg,
-            np.asarray(params["embed"], np.float32),
-            lambda i: reference.engine_layer_weights(params, cfg, i, tp),
-            np.asarray(params["final_norm"], np.float32), lm_head,
-            device=jax_env.host_device(),
-        )
-        out = reference.compare(prompts, eng_logits, eng_tokens, ref_logits)
+        out = reference.compare(prompts, eng_logits, eng_tokens, ref_logits, adapter.TOLERANCE)
         out["decode_tokens"] = [len(t) for t in eng_tokens]
     except Exception:  # noqa: BLE001 - reported to the parent, which fails the run
         out["error"] = traceback.format_exc()
@@ -181,7 +132,9 @@ def trace_on_request(work: str) -> None:
             time.sleep(0.02)
         t2 = time.time()
         jax.profiler.stop_trace()
-        out = {"ok": True, "start_call_s": t1 - t0, "traced_s": t2 - t1, "stop_call_s": time.time() - t2}
+        out = {"ok": True, "start_call_s": t1 - t0, "traced_s": t2 - t1, "stop_call_s": time.time() - t2,
+               "capture_bytes": sum(os.path.getsize(os.path.join(d, f))
+                                    for d, _, fs in os.walk(os.path.join(work, "trace")) for f in fs)}
     except Exception:  # noqa: BLE001 - reported to the parent
         out["error"] = traceback.format_exc()
     write_json(os.path.join(work, "trace.done"), out)
@@ -210,11 +163,13 @@ def main() -> int:
         print(f"the cell needs {args.chips} chips, jax reports {facts}", flush=True)
         return 3
 
-    from generativeaiexamples_tpu.models import llama
+    from perfbench import arch
 
-    llama.PRESETS[cfg["name"]] = llama_config(cfg)
+    adapter = arch.load(cfg)
+    adapter.register(cfg)
     threading.Thread(
-        target=reference_check, args=(cfg, args.work, args.chips), daemon=True, name="perfbench-reference",
+        target=reference_check, args=(cfg, adapter, args.work, args.chips), daemon=True,
+        name="perfbench-reference",
     ).start()
 
     if args.trace:

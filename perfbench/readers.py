@@ -1,10 +1,14 @@
 """Per-layer metrics: a handful of generic readers, chosen by name.
 
 A per-layer metric is a small data file under ``perfbench/layer_metrics``
-that names one of the readers below and its parameters. A later PR adds
-a metric by adding a file; it touches nothing here unless it needs a
-new *kind* of source. A reader that finds nothing to read returns None
-and the harness leaves the metric out of the line.
+that names its reader and the reader's parameters: either one of the
+generic readers below (``"reader": "span_mean"``, a key of ``READERS``)
+or a function of any module of the benchmark, as
+``"reader": "perfbench.arch.<module>:<function>"`` with the same
+``(ctx, params)`` signature (``resolve``). A later PR adds a metric by
+adding a file, and a new *kind* of source by adding a function to its
+adapter; it touches nothing here. A reader that finds nothing to read
+returns None and the harness leaves the metric out of the line.
 
 The context every reader gets (``ctx``):
 
@@ -16,15 +20,16 @@ The context every reader gets (``ctx``):
 - ``metrics_before`` / ``metrics_after``: parsed ``/metrics`` text;
 - ``trace``: ``trace_reduce.reduce_events`` summary of the traced
   interval, or None when the run was not traced;
-- ``config``, ``peaks`` (this device's row of ``peaks.json``), and
-  ``read(name)``: another metric's value (for a share over a time).
+- ``config``, ``adapter`` (the configuration's module under
+  ``perfbench/arch``), ``peaks`` (this device's row of ``peaks.json``),
+  and ``read(name)``: another metric's value (for a share over a time).
 """
 from __future__ import annotations
 
 import re
-from typing import Any, Callable, Dict, List, Optional, Tuple
+from typing import Any, Callable, Dict, List, Optional, Sequence, Tuple
 
-from perfbench import reduce, shapes, trace_reduce
+from perfbench import arch, reduce, trace_reduce
 
 Metrics = Dict[Tuple[str, frozenset], float]
 
@@ -119,6 +124,13 @@ def client_tpot_percentile(ctx, p) -> Optional[float]:
     return reduce.percentile(reduce.tpots_ms(ctx["requests"], t0, t1), p.get("q", 50))
 
 
+def client_gap_percentile(ctx, p) -> Optional[float]:
+    """Percentile of the gaps between consecutive frames of one stream,
+    over every gap whose later frame arrived inside the window, ms."""
+    t0, t1 = ctx["window"]
+    return reduce.percentile(reduce.gaps_ms(ctx["requests"], t0, t1), p["q"])
+
+
 def span_mean(ctx, p) -> Optional[float]:
     """Mean of one field over dispatch spans of one kind (a count)."""
     vals = [
@@ -160,46 +172,59 @@ def device_idle_share(ctx, p) -> Optional[float]:
     return 100.0 * (1.0 - tr["busy_s"] / tr["window_s"])
 
 
-def mean_live_tokens(ctx) -> Optional[float]:
-    """Rows per decode dispatch times the mean context of a row while it
-    decodes (its prompt plus half its answer), over the window."""
-    rows = span_mean(ctx, {"kind": "decode", "field": "rows"})
+def mean_decode_context(ctx) -> Optional[float]:
+    """Mean context of a row while it decodes (its prompt plus half its
+    answer), over the requests that finished in the window."""
     ctxs = []
     for tl in ctx["flight"]:
         prompt = event_attr(tl, "submit", "prompt_tokens")
         gen = event_attr(tl, "engine_finish", "generated")
         if prompt is not None and gen is not None:
             ctxs.append(prompt + gen / 2.0)
-    if rows is None or not ctxs:
-        return None
-    return rows * sum(ctxs) / len(ctxs)
+    return sum(ctxs) / len(ctxs) if ctxs else None
 
 
 def decode_roofline_share(ctx, p) -> Optional[float]:
     """The least time the chip could take for one decode step — the
-    larger of bytes over peak bandwidth and operations over peak rate —
-    over the measured device time of a step, percent."""
+    adapter's ``decode_step_floor_s`` for the rows per decode dispatch
+    and the mean context of a decoding row — over the measured device
+    time of a step, percent."""
     step_ms = ctx["read"](p["time_metric"])
     rows = span_mean(ctx, {"kind": "decode", "field": "rows"})
-    live = mean_live_tokens(ctx)
-    if not step_ms or rows is None or live is None:
+    context = mean_decode_context(ctx)
+    if not step_ms or rows is None or context is None:
         return None
-    cfg, peaks = ctx["config"], ctx["peaks"]
-    t_bytes = shapes.decode_step_bytes(cfg, rows, live) / peaks["hbm_bytes_per_s"]
-    t_flops = shapes.decode_step_flops(cfg, rows, live) / peaks["int8_ops_per_s"]
-    return 100.0 * max(t_bytes, t_flops) / (step_ms / 1000.0)
+    floor_s = ctx["adapter"].decode_step_floor_s(ctx["config"], ctx["peaks"], rows, context)
+    return 100.0 * floor_s / (step_ms / 1000.0)
 
 
 READERS: Dict[str, Callable[[Dict[str, Any], Dict[str, Any]], Optional[float]]] = {
     "flight_phase_percentile": flight_phase_percentile,
     "client_other_percentile": client_other_percentile,
     "client_tpot_percentile": client_tpot_percentile,
+    "client_gap_percentile": client_gap_percentile,
     "span_mean": span_mean,
     "device_module_ms": device_module_ms,
     "device_op_busy_share": device_op_busy_share,
     "device_idle_share": device_idle_share,
     "decode_roofline_share": decode_roofline_share,
 }
+
+
+def resolve(name: str, roots: Sequence[str]) -> Callable[[Dict[str, Any], Dict[str, Any]], Optional[float]]:
+    """The reader a metric file names: a key of ``READERS``, or
+    ``<module>:<function>`` of a module whose file lies under one of
+    ``roots`` (the manifest's ``paths``: the yardstick stays where a PR
+    that claims a gain cannot change it). Anything else raises."""
+    if name in READERS:
+        return READERS[name]
+    module_name, sep, function = name.partition(":")
+    if not sep or not function:
+        raise ValueError(f"reader {name!r} is neither a key of readers.READERS nor '<module>:<function>'")
+    reader = getattr(arch.module_under(module_name, roots), function, None)
+    if not callable(reader):
+        raise ValueError(f"reader {name!r}: {module_name} has no callable {function!r}")
+    return reader
 
 
 def join_in_order(requests: List[Dict[str, Any]], flight: List[Dict[str, Any]],

@@ -14,8 +14,9 @@ What each end-to-end metric measures is fixed here and nowhere else:
 - ``ttft_*``: first content frame minus the send (open loop: minus the
   due instant), over every request whose first frame arrived inside the
   window.
-- ``itl_p99_ms``: gaps between consecutive content frames of one stream,
-  over every gap whose later frame arrived inside the window.
+- ``itl_p99_ms`` / ``itl_p995_ms``: gaps between consecutive content
+  frames of one stream, over every gap whose later frame arrived inside
+  the window; the 99th and the 99.5th percentile of all of them.
 """
 from __future__ import annotations
 
@@ -101,11 +102,13 @@ def end_to_end(requests: Sequence[Dict[str, Any]], t0: float, t1: float) -> Dict
     """Every end-to-end metric the client can compute; the manifest
     decides which of them a cell reports."""
     tt = ttfts_ms(requests, t0, t1)
+    gaps = gaps_ms(requests, t0, t1)
     return {
         "out_tok_s": out_tok_s(requests, t0, t1),
         "ttft_p50_ms": percentile(tt, 50),
         "ttft_p90_ms": percentile(tt, 90),
-        "itl_p99_ms": percentile(gaps_ms(requests, t0, t1), 99),
+        "itl_p99_ms": percentile(gaps, 99),
+        "itl_p995_ms": percentile(gaps, 99.5),
     }
 
 

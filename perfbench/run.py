@@ -13,6 +13,14 @@ the plain reference, and opens the measured window after both. Everything before
 ``setup_s``. ``--trace 0`` reports the cell's end-to-end metrics from
 the client's frame log; ``--trace 1`` has the launcher's profiler thread capture the
 last few seconds of the window and reports the cell's per-layer metrics.
+A traced run reports no end-to-end metric, so its window is as long as
+the traffic file's ``traced_run_window_s`` says (``--seconds`` where it
+says nothing): it has to end inside the driver's limit for one run.
+
+Nothing here knows a model. The configuration file names its adapter
+(``perfbench/arch/``), a per-layer metric file names its reader
+(``readers.resolve``), and the configuration may ADD to what ``correct``
+requires of the kernel-path line and of the counters (``"correct"``).
 
 The last line of stdout is one JSON object: ``correct``, ``attempted``,
 ``failed``, ``metrics``, ``device`` (and ``breakdown`` when traced).
@@ -33,6 +41,7 @@ import signal
 import socket
 import subprocess
 import sys
+import threading
 import time
 
 T_PROCESS_START = time.time()
@@ -42,7 +51,7 @@ ROOT = os.path.dirname(BENCH)
 if ROOT not in sys.path:
     sys.path.insert(0, ROOT)
 
-from perfbench import loadgen, readers, reduce, trace_reduce  # noqa: E402
+from perfbench import arch, loadgen, readers, reduce, trace_reduce  # noqa: E402
 from perfbench.tokenizer_file import write_tokenizer  # noqa: E402
 
 READY_TIMEOUT_S = 1100.0  # launch -> ready, cold compile included
@@ -108,7 +117,11 @@ def wait_until(pred, what: str, timeout: float, alive) -> float:
 
 
 def scrape_all(host: str, port: int, path: str, key: str, since: int):
-    """Follow a ``?since=<cursor>`` endpoint to its end."""
+    """Follow a ``?since=<cursor>`` endpoint to its end. The cursor the
+    server returns is the NEWEST in the process, not the last it sent, so
+    a full page is followed from the ``seq`` of its last item (followed
+    from the returned cursor it lost every span past the 500th: the last
+    ~8 s of a 51 s window, until PR 28)."""
     items, cursor = [], since
     while True:
         status, payload = loadgen.http_call(host, port, "GET", f"{path}?since={cursor}&limit=500")
@@ -117,9 +130,10 @@ def scrape_all(host: str, port: int, path: str, key: str, since: int):
         doc = json.loads(payload)
         batch = doc.get(key, [])
         items.extend(batch)
-        new_cursor = int(doc.get("cursor", cursor))
+        newest = int(doc.get("cursor", cursor))
+        new_cursor = max((int(it["seq"]) for it in batch if "seq" in it), default=newest)
         if len(batch) < 500 or new_cursor == cursor:
-            return items, new_cursor
+            return items, newest
         cursor = new_cursor
 
 
@@ -130,28 +144,126 @@ def cursor_of(host: str, port: int, path: str) -> int:
     return int(json.loads(payload).get("cursor", 0))
 
 
-def check_server_log(text: str, on_tpu: bool) -> list:
+# What every cell's counters have to show between the two ``/metrics``
+# scrapes around the window. A configuration adds to these lists under
+# ``"correct"``; it cannot take one away.
+MUST_GROW = [{"metric": "genai_engine_paged_attn_dispatches_total", "labels": {"path": "kernel"}}]
+MUST_NOT_GROW = [{"metric": "genai_engine_paged_attn_dispatches_total", "labels": {"path": "gather"}},
+                 {"metric": "genai_engine_hot_path_compiles_total"}]
+
+
+def required_kernel_paths(cfg: dict, on_tpu: bool) -> list:
+    """``(key, value)`` pairs the engine's ``resolved kernel paths:`` line
+    has to show. On a TPU every cell needs the compiled page kernel, and a
+    configuration served in a quantised format the matmul kernel of that
+    format (the engine prints True for int8 and the format's own name
+    otherwise); the configuration's ``correct.kernel_paths`` come on top."""
+    need = []
+    if on_tpu:
+        need.append(("paged_kernel", "compiled"))
+        fmt = cfg.get("server_env", {}).get("APP_ENGINE_QUANTIZATION", "none")
+        if fmt not in ("", "none"):
+            need.append(("quant_kernel", "True" if fmt == "int8" else fmt))
+    need += sorted((k, str(v)) for k, v in cfg.get("correct", {}).get("kernel_paths", {}).items())
+    return need
+
+
+def check_server_log(text: str, on_tpu: bool, cfg: dict) -> list:
     problems = []
     if "Traceback (most recent call last)" in text:
         problems.append("server log holds a traceback")
     if "COMPILE ON HOT PATH" in text:
         problems.append("server log reports a compile on the hot path")
-    m = re.search(r"resolved kernel paths: quant_kernel=(\S+) kv_kernel=\S+ paged_kernel=(\S+)", text)
+    m = re.search(r"resolved kernel paths: (.*)", text)
     if not m:
         problems.append("server log has no 'resolved kernel paths' line")
-    elif on_tpu and (m.group(1) != "True" or m.group(2) != "compiled"):
-        problems.append(f"kernels not compiled: quant_kernel={m.group(1)} paged_kernel={m.group(2)}")
+        return problems
+    resolved = dict(re.findall(r"(\w+)=([^\s,()]+)", m.group(1)))
+    wrong = [f"{k}={resolved.get(k, '<absent>')} (want {v})"
+             for k, v in required_kernel_paths(cfg, on_tpu) if resolved.get(k) != v]
+    if wrong:
+        problems.append("kernel paths not as required: " + " ".join(wrong))
     return problems
 
 
-def layer_metric_file(name: str) -> str:
-    """``layer_metrics/<name>.json``; a manifest name ``<base>.<suffix>``
-    without a file of its own reads ``<base>.json``, so one reader file
-    serves the entries that differ only in ``moves`` and ``workloads``."""
-    path = os.path.join(BENCH, "layer_metrics", name + ".json")
-    if not os.path.exists(path) and "." in name:
-        path = os.path.join(BENCH, "layer_metrics", name.rsplit(".", 1)[0] + ".json")
-    return path
+def counter_label(spec: dict) -> str:
+    labels = ",".join(f'{k}="{v}"' for k, v in sorted(spec.get("labels", {}).items()))
+    return spec["metric"] + (f"{{{labels}}}" if labels else "")
+
+
+def check_counters(before: dict, after: dict, cfg: dict):
+    """Problems and the readings behind them: every counter of MUST_GROW
+    and of the configuration's ``counters_must_grow`` grew between the
+    two scrapes, none of MUST_NOT_GROW and ``counters_must_not_grow`` did."""
+    extra = cfg.get("correct", {})
+    problems, readings = [], []
+    for specs, must_grow in ((MUST_GROW + list(extra.get("counters_must_grow", [])), True),
+                             (MUST_NOT_GROW + list(extra.get("counters_must_not_grow", [])), False)):
+        for spec in specs:
+            labels = spec.get("labels", {})
+            grew = (readers.metric_sum(after, spec["metric"], **labels)
+                    - readers.metric_sum(before, spec["metric"], **labels))
+            readings.append(f"{counter_label(spec)} grew by {grew:g} ({'more than' if must_grow else 'limit'} 0)")
+            if must_grow and grew <= 0:
+                problems.append(f"{counter_label(spec)} did not grow in the window")
+            elif not must_grow and grew > 0:
+                problems.append(f"{counter_label(spec)} grew by {grew:g} in the window")
+    return problems, readings
+
+
+class Heartbeat(threading.Thread):
+    """Sleeps 20 ms at a time and keeps its longest gap. A window in which
+    the HOST stood still shows here (and the clients, threads of this
+    process, stood still with it); a stall of the server child or of the
+    device does not. Run-to-run stalls of several seconds have been seen
+    (PERF.md section 6); this says whose they are."""
+
+    def __init__(self):
+        super().__init__(daemon=True, name="perfbench-heartbeat")
+        self.max_gap_s = 0.0
+        self._halt = threading.Event()
+
+    def run(self):
+        last = time.monotonic()
+        while not self._halt.wait(0.02):
+            now = time.monotonic()
+            self.max_gap_s = max(self.max_gap_s, now - last)
+            last = now
+
+    def stop(self) -> float:
+        self._halt.set()
+        self.join(timeout=5)
+        return self.max_gap_s
+
+
+def window_seconds(traffic: dict, seconds: float, trace: int) -> float:
+    """The measured window of an untraced run is ``--seconds``. A traced
+    run reports no end-to-end metric: the traffic file may give it a
+    shorter window (``traced_run_window_s``), of which the last
+    ``trace_window_s`` are traced."""
+    return float(traffic.get("traced_run_window_s", seconds)) if trace else float(seconds)
+
+
+def spans_in_window(spans: list, t_open_wall: float, seconds: float) -> list:
+    """Dispatch spans recorded inside the window (``t_wall`` is the
+    server's wall clock; a span without one is kept)."""
+    return [sp for sp in spans if t_open_wall <= sp.get("t_wall", t_open_wall) < t_open_wall + seconds]
+
+
+def layer_metric_file(name: str, beside: str = "") -> str:
+    """``layer_metrics/<name>.json``, looked for beside the manifest
+    first (``beside``) and then in ``perfbench/``; a manifest name
+    ``<base>.<suffix>`` without a file of its own reads ``<base>.json``,
+    so one reader file serves the entries that differ only in ``moves``
+    and ``workloads``."""
+    names = [name] + ([name.rsplit(".", 1)[0]] if "." in name else [])
+    dirs = ([os.path.join(beside, "layer_metrics")] if beside else []) + [os.path.join(BENCH, "layer_metrics")]
+    for d in dirs:
+        for n in names:
+            path = os.path.join(d, n + ".json")
+            if os.path.exists(path):
+                return path
+    return os.path.join(dirs[-1], name + ".json")
 
 
 def main() -> int:
@@ -173,6 +285,9 @@ def main() -> int:
     traffic = load_json(os.path.join(BENCH, "traffic", cell["traffic"] + ".json"))
     peaks_table = load_json(os.path.join(BENCH, "peaks.json"))
     rehearsal = bool(cfg.get("rehearsal"))
+    roots = [os.path.join(ROOT, p) for p in manifest["paths"]]
+    adapter = arch.load(cfg, roots)  # a configuration without a sound adapter fails before anything starts
+    window_s = window_seconds(traffic, args.seconds, args.trace)
 
     def in_cell(metric: dict) -> bool:
         return "workloads" not in metric or cell["name"] in metric["workloads"]
@@ -191,7 +306,7 @@ def main() -> int:
     host, port = "127.0.0.1", free_port()
 
     say(f"perfbench: cell={cell['name']} config={cfg['name']} traffic={cell['traffic']} "
-        f"seed={args.seed} seconds={args.seconds:g} trace={args.trace}")
+        f"seed={args.seed} seconds={args.seconds:g} window={window_s:g} trace={args.trace}")
     env = server_env(cfg, cell, work, bool(args.trace))
     client = None
     proc = None
@@ -268,18 +383,21 @@ def main() -> int:
             metrics_before = readers.parse_metrics(payload.decode(errors="replace")) if status == 200 else {}
 
             # ---- the measured window ------------------------------------ #
+            heartbeat = Heartbeat()
+            heartbeat.start()
             t_open = time.monotonic()
             setup_s = time.time() - T_PROCESS_START
-            t_close = t_open + args.seconds
+            t_close = t_open + window_s
             trace_dir = os.path.join(work, "trace")
             if args.trace:
                 # the LAST seconds of the window are traced, so that writing
                 # the capture out falls after the window has closed
-                span = min(float(traffic.get("trace_window_s", 5.0)), args.seconds * 0.8)
+                span = min(float(traffic.get("trace_window_s", 5.0)), window_s * 0.8)
                 time.sleep(max(0.0, t_close - span - time.monotonic()))
                 open(os.path.join(work, "trace.start"), "w").close()
             time.sleep(max(0.0, t_close - time.monotonic()))
             t_close = time.monotonic()
+            heartbeat_gap_s = heartbeat.stop()
             if args.trace:
                 open(os.path.join(work, "trace.stop"), "w").close()
             status, payload = loadgen.http_call(host, port, "GET", "/metrics")
@@ -313,10 +431,15 @@ def main() -> int:
                 "out_tok_s_median_of_tenths": reduce.percentile(tenths, 50),
                 "tok_s_per_second_bin": [round(x) for x in seconds],
                 "generator_lateness_p99_ms": reduce.percentile([x * 1000 for x in client.lateness_s], 99),
+                "host_heartbeat_max_gap_ms": heartbeat_gap_s * 1000.0,
             }))
 
-            # the server's view of the requests that finished in the window
+            # the server's view of the requests that finished in the window, and
+            # of the dispatches made in it (a traced run goes on serving while the
+            # profiler writes its capture, and that starves the server: those
+            # spans are not the window's)
             t_open_wall = time.time() - (time.monotonic() - t_open)
+            spans = spans_in_window(spans, t_open_wall, w1)
             fin = []
             for tl in flight:
                 if not any(e.get("event") == "http_request" and e.get("path") == "/generate"
@@ -367,26 +490,22 @@ def main() -> int:
             if any(len(r["frames_s"]) > r["max_tokens"] for r in done_ok):
                 problems.append("a stream delivered more tokens than asked")
 
-            def grew(metric: str, **labels: str) -> float:
-                return (readers.metric_sum(metrics_after, metric, **labels)
-                        - readers.metric_sum(metrics_before, metric, **labels))
-
-            kernel = grew("genai_engine_paged_attn_dispatches_total", path="kernel")
-            gather = grew("genai_engine_paged_attn_dispatches_total", path="gather")
-            hot = grew("genai_engine_hot_path_compiles_total")
-            if kernel <= 0:
-                problems.append("no page-attention kernel dispatch in the window")
-            if gather > 0:
-                problems.append(f"{gather:g} paged dispatches took the XLA gather path")
-            if hot > 0:
-                problems.append(f"{hot:g} compiles on the hot path inside the window")
+            counter_problems, counter_readings = check_counters(metrics_before, metrics_after, cfg)
+            problems += counter_problems
             if n["attempted"] == 0:
                 problems.append("no request finished inside the window")
-            say(f"counters: paged_attn kernel={kernel:g} gather={gather:g} hot_path_compiles={hot:g}")
+            say("counters: " + "; ".join(counter_readings))
+            compared = [
+                f"reference prefill_rel_err {ref.get('prefill_rel_err')} (limit {ref.get('tolerance')})",
+                f"reference decode_margin_max {ref.get('decode_margin_max')} over "
+                f"{ref.get('decode_tokens_checked')} tokens (limit {ref.get('tolerance')})",
+                f"requests finished in the window: client {len(delivered)}, server {len(generated)}, "
+                f"without a partner {len(lonely)} (limit 0)",
+            ] + counter_readings
             result = dict(n=n, e2e=e2e, problems=problems, device=device, on_tpu=on_tpu, peaks=peaks,
                           reqs=reqs, fin=fin, spans=spans, metrics_before=metrics_before,
                           metrics_after=metrics_after, window=(w0, w1), log_len=log_len,
-                          trace_dir=trace_dir)
+                          trace_dir=trace_dir, compared=compared)
         except RunFailure as exc:
             say(f"FAIL: {exc}")
             for ln in log_text().splitlines()[-40:]:
@@ -404,7 +523,7 @@ def main() -> int:
     if result is None:
         return proc.returncode if proc.returncode not in (0, None, -15) else 1
 
-    problems = result["problems"] + check_server_log(log_text()[: result["log_len"]], result["on_tpu"])
+    problems = result["problems"] + check_server_log(log_text()[: result["log_len"]], result["on_tpu"], cfg)
     device = dict(result["device"])
     final_path = os.path.join(work, "device_final.json")
     device["memory_peak_bytes"] = load_json(final_path).get("memory_peak_bytes", 0) if os.path.exists(final_path) else 0
@@ -444,14 +563,15 @@ def main() -> int:
                 reduce.finished_in(result["reqs"], *result["window"]), result["fin"]),
             "spans": result["spans"], "metrics_before": result["metrics_before"],
             "metrics_after": result["metrics_after"], "trace": trace_summary,
-            "config": cfg, "peaks": result["peaks"] or {},
+            "config": cfg, "adapter": adapter, "peaks": result["peaks"] or {},
         }
         cache = {}
+        beside = os.path.dirname(os.path.abspath(args.manifest))
 
         def read(name: str):
             if name not in cache:
-                spec = load_json(layer_metric_file(name))
-                cache[name] = readers.READERS[spec["reader"]](ctx, spec.get("params", {}))
+                spec = load_json(layer_metric_file(name, beside))
+                cache[name] = readers.resolve(spec["reader"], roots)(ctx, spec.get("params", {}))
             return cache[name]
 
         ctx["read"] = read
@@ -487,6 +607,9 @@ def main() -> int:
         line["device"]["window_s"] = trace_summary["window_s"]
         line["breakdown"] = trace_reduce.breakdown(trace_summary)
     say(json.dumps(line))
+    # each number compared beside its limit, as the last lines of stderr
+    print("\n".join(["compared:"] + result["compared"] + [f"not correct: {p}" for p in problems]),
+          file=sys.stderr, flush=True)
     if not rehearsal:
         shutil.rmtree(work, ignore_errors=True)
     return 0 if correct else 1

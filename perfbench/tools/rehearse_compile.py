@@ -1,5 +1,5 @@
 #!/usr/bin/env python3
-"""Rehearsal 3 for a configuration: compile its decode program and its
+"""Rehearsal 3 for a configuration of the Mistral adapter: compile its decode program and its
 longest chunked-prefill (extend) rung for a DESCRIBED v5e chip, without
 a chip, and print ``memory_analysis()``. A compile, never a run.
 
@@ -26,7 +26,7 @@ from jax.experimental import topologies  # noqa: E402
 from jax.sharding import SingleDeviceSharding  # noqa: E402
 
 from generativeaiexamples_tpu.models import llama  # noqa: E402
-from perfbench.launcher import llama_config  # noqa: E402
+from perfbench.arch.mistral import llama_config  # noqa: E402
 
 
 def main(path: str) -> None:

@@ -81,6 +81,7 @@ def test_cell_names_a_configuration_and_a_traffic_file_that_exist(cell):
     traffic = load(os.path.join(BENCH, "traffic", w["traffic"] + ".json"))
     assert traffic["kind"] in ("closed", "poisson", "sessions")
     assert body["chips"] == w["chips"]
+    assert body["adapter"].startswith("perfbench.arch.")  # a cell's adapter lives with the benchmark
 
 
 @pytest.mark.parametrize("cell", CELLS)
@@ -107,7 +108,9 @@ def test_per_layer_metric_has_a_reader_file_and_moves_a_metric_its_cells_report(
     from perfbench.run import layer_metric_file
 
     spec = load(layer_metric_file(metric))
-    assert spec["name"] in (metric, metric.rsplit(".", 1)[0]) and spec["reader"] in readers.READERS
+    assert spec["name"] in (metric, metric.rsplit(".", 1)[0])
+    # a key of READERS, or module:function of a module under the manifest's paths
+    assert callable(readers.resolve(spec["reader"], [os.path.join(ROOT, p) for p in M["paths"]]))
     target = next(x for x in M["end_to_end"] if x["name"] == m["moves"])
     assert set(cells_of(m)) <= set(cells_of(target))
     same_layer = {x["layer"] for x in M["per_layer"]}

@@ -48,7 +48,9 @@ def test_ttft_counts_requests_whose_first_frame_arrived_in_the_window():
 def test_gaps_are_taken_over_every_stream_by_the_later_frame():
     gaps = sorted(reduce.gaps_ms(LOG, 0.0, 10.0))
     assert gaps == pytest.approx([100.0, 100.0, 1000.0, 1000.0, 1000.0, 8100.0])
-    assert reduce.end_to_end(LOG, 0.0, 10.0)["itl_p99_ms"] == pytest.approx(reduce.percentile(gaps, 99))
+    e2e = reduce.end_to_end(LOG, 0.0, 10.0)
+    assert e2e["itl_p99_ms"] == pytest.approx(reduce.percentile(gaps, 99))
+    assert e2e["itl_p995_ms"] == pytest.approx(reduce.percentile(gaps, 99.5)) and e2e["itl_p995_ms"] > e2e["itl_p99_ms"]
 
 
 def test_attempted_and_failed_count_requests_that_ended_in_the_window():
@@ -102,6 +104,7 @@ def test_generic_readers_read_their_sources_and_return_none_on_nothing():
     assert R["span_mean"](ctx, {"kind": "decode", "field": "rows"}) == 14
     assert readers.metric_sum(ctx["metrics_after"], "genai_x_total", path="kernel") == 9
     assert R["client_tpot_percentile"](ctx, {"q": 50}) == pytest.approx(1800.0)
+    assert R["client_gap_percentile"](ctx, {"q": 99}) == pytest.approx(1800.0)  # one gap: 102.9 -> 104.7
     for name in ("device_module_ms", "device_op_busy_share", "device_idle_share"):
         assert R[name](ctx, {"match": "x"}) is None
     assert R["span_mean"](ctx, {"kind": "spec", "field": "rows"}) is None

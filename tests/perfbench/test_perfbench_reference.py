@@ -1,14 +1,16 @@
 """The plain float32 reference against the engine at the tiny preset:
 prefill logits and greedy decode through the cache, as the launcher
 compares them on the chip at the published widths. The tolerance and
-its reason are in perfbench/reference.py."""
+its reason are in the adapter, perfbench/arch/mistral.py; the comparison
+itself (perfbench/reference.py) is shared by every adapter."""
 import json
 import os
 
 import numpy as np
 import pytest
 
-from perfbench import launcher, reference
+from perfbench import reference
+from perfbench.arch import mistral
 from perfbench.tokenizer_file import CHAT_MARKERS, write_tokenizer
 
 BENCH = os.path.join(os.path.dirname(os.path.dirname(os.path.dirname(os.path.abspath(__file__)))), "perfbench")
@@ -24,9 +26,8 @@ def cfg():
 def engine(cfg):
     from generativeaiexamples_tpu.config import EngineConfig
     from generativeaiexamples_tpu.engine.llm_engine import LLMEngine
-    from generativeaiexamples_tpu.models import llama
 
-    llama.PRESETS[cfg["name"]] = launcher.llama_config(cfg)
+    mistral.register(cfg)
     eng = LLMEngine(EngineConfig(
         model_config_name=cfg["name"], quantization="int8", kv_cache_dtype="int8",
         tensor_parallelism=1, max_batch_size=4, max_seq_len=256, prefill_chunk=64,
@@ -43,12 +44,12 @@ def compared(cfg, engine):
     # the third prompt is longer than prefill_chunk (64): it reaches the
     # extend program and is compared through the served path only
     prompts = reference.seeded_prompts([24, 40, 80], 250, seed=5)
-    eng_logits = list(launcher.engine_prefill_logits(engine, prompts[:2], on_tpu=False)) + [None]
+    eng_logits = list(mistral.engine_prefill_logits(engine, prompts[:2], on_tpu=False)) + [None]
     greedy = SamplingParams(temperature=0.0, max_tokens=4)
     tokens = [list(engine.iter_ids(p, greedy, timeout=300)) for p in prompts]
     params = engine.params
-    weights = lambda i: reference.engine_layer_weights(params, cfg, i)  # noqa: E731
-    head = reference.unpack(params["lm_head"], cfg["hidden_size"], cfg["vocab_size"])
+    weights = lambda i: mistral.engine_layer_weights(params, cfg, i)  # noqa: E731
+    head = mistral.unpack(params["lm_head"], cfg["hidden_size"], cfg["vocab_size"])
     args = (np.asarray(params["embed"], np.float32), weights,
             np.asarray(params["final_norm"], np.float32), head)
     full = [list(p) + list(t) for p, t in zip(prompts, tokens)]
@@ -57,20 +58,20 @@ def compared(cfg, engine):
 
 def test_prefill_logits_and_decode_through_the_cache_agree_with_the_reference(cfg, compared):
     prompts, eng_logits, tokens, full, args = compared
-    ref = reference.forward(full, cfg, *args)
-    out = reference.compare(prompts, list(eng_logits), tokens, ref)
+    ref = mistral.forward(full, cfg, *args)
+    out = reference.compare(prompts, list(eng_logits), tokens, ref, mistral.TOLERANCE)
     assert out["ok"], out
-    assert max(out["prefill_rel_err"]) <= reference.TOLERANCE
+    assert max(out["prefill_rel_err"]) <= mistral.TOLERANCE
     assert len(out["prefill_rel_err"]) == 2  # the served-only prompt has no logits to compare
-    assert out["decode_tokens_checked"] == 12 and out["decode_margin_max"] <= reference.TOLERANCE
+    assert out["decode_tokens_checked"] == 12 and out["decode_margin_max"] <= mistral.TOLERANCE
     assert all(len(t) == 4 for t in tokens)
 
 
 def test_a_served_path_that_delivers_no_token_does_not_pass(cfg, compared):
     prompts, eng_logits, tokens, full, args = compared
-    ref = reference.forward(full, cfg, *args)
-    assert not reference.compare(prompts, list(eng_logits), [[] for _ in tokens], ref)["ok"]
-    assert not reference.compare(prompts, list(eng_logits), tokens[:2] + [[]], ref)["ok"]
+    ref = mistral.forward(full, cfg, *args)
+    assert not reference.compare(prompts, list(eng_logits), [[] for _ in tokens], ref, mistral.TOLERANCE)["ok"]
+    assert not reference.compare(prompts, list(eng_logits), tokens[:2] + [[]], ref, mistral.TOLERANCE)["ok"]
 
 
 @pytest.mark.parametrize("fault", ["dropped_layer", "wrong_rotary_base", "int4_weights"])
@@ -86,8 +87,8 @@ def test_the_tolerance_is_tight_enough_to_catch_a_different_model(cfg, compared,
             w = weights(i)
             return {k: ((v[0] // 16 * 16).astype(np.int8), v[1]) if isinstance(v, tuple) else v
                     for k, v in w.items()}
-    ref = reference.forward(full, bad_cfg, embed, bad_weights, final_norm, head)
-    out = reference.compare(prompts, list(eng_logits), tokens, ref)
+    ref = mistral.forward(full, bad_cfg, embed, bad_weights, final_norm, head)
+    out = reference.compare(prompts, list(eng_logits), tokens, ref, mistral.TOLERANCE)
     assert not out["ok"], out
 
 
@@ -107,7 +108,7 @@ def test_causal_mask_and_grouped_heads_by_hand():
          "w_down": rng.normal(size=(32, 16)).astype(np.float32) * 0.2}
     embed = rng.normal(size=(10, 16)).astype(np.float32)
     head = rng.normal(size=(16, 10)).astype(np.float32)
-    a, b = reference.forward([[1, 2, 3, 4], [1, 2, 3, 9]], cfg, embed, lambda i: w, np.ones(16, np.float32), head)
+    a, b = mistral.forward([[1, 2, 3, 4], [1, 2, 3, 9]], cfg, embed, lambda i: w, np.ones(16, np.float32), head)
     np.testing.assert_allclose(a[:3], b[:3], rtol=1e-5, atol=1e-6)
     assert np.max(np.abs(a[3] - b[3])) > 1e-3
 
@@ -142,8 +143,8 @@ def test_unpack_undoes_the_engines_kernel_layout(tp, kind):
     plain = rng.integers(-127, 128, (k, f)).astype(np.int8)
     scale = rng.uniform(0.5, 1.5, (1, f)).astype(np.float32)
     pack = {"q": np.asarray(quant._layout(plain, tp, kind)), "scale": scale}
-    q, s = reference.unpack(pack, k, f, tp=tp, kind=kind)
+    q, s = mistral.unpack(pack, k, f, tp=tp, kind=kind)
     np.testing.assert_array_equal(q, plain)
     np.testing.assert_array_equal(s, scale)
-    q2, s2 = reference.unpack(pack, k, 100, lo=50, tp=tp, kind=kind)
+    q2, s2 = mistral.unpack(pack, k, 100, lo=50, tp=tp, kind=kind)
     np.testing.assert_array_equal(q2, plain[:, 50:150])

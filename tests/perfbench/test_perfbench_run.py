@@ -45,6 +45,15 @@ def test_cpu_run_ends_correct_false_non_zero_with_no_number_under_a_metric_name(
     assert '"ok": true' in next(ln for ln in proc.stdout.splitlines() if ln.startswith("reference:"))
 
 
+def test_an_untraced_run_measures_for_seconds_and_a_traced_one_for_the_traffic_files_window(rehearsal_runs):
+    seconds = {}
+    for trace, proc in rehearsal_runs.items():
+        line = next(ln for ln in proc.stdout.splitlines() if ln.startswith("window: "))
+        seconds[trace] = json.loads(line[len("window: "):])["seconds"]
+    assert 3.9 < seconds[0] < 4.6   # --seconds 4, whatever rehearsal_closed.json says of traced runs
+    assert 1.9 < seconds[1] < 2.6   # traced_run_window_s 2
+
+
 def test_frame_log_is_written_with_every_arrival(rehearsal_runs):
     assert rehearsal_runs[0].returncode == 1
     import glob
@@ -66,13 +75,13 @@ def test_real_manifest_without_a_tpu_prints_no_result_and_fails():
 
 
 def test_final_line_shape_of_a_chip_run():
-    """The last line a chip run printed (kept verbatim from PR 24's first
-    call) has the keys the driver reads."""
+    """The last line a chip run printed (kept verbatim from PR 28's second
+    call, seed 1123581321) has the keys the driver reads."""
     line = json.loads(
-        '{"correct": true, "attempted": 114, "failed": 0, "metrics": {"out_tok_s": {"value": 913.7284055118678, '
-        '"unit": "tokens/s"}, "itl_p99_ms": {"value": 579.5656399999995, "unit": "ms"}, "setup_s": {"value": '
-        '324.21304726600647, "unit": "s"}}, "device": {"platform": "tpu", "kind": "TPU v5 lite", "count": 1, '
-        '"memory_peak_bytes": 13325309952}}'
+        '{"correct": true, "attempted": 160, "failed": 0, "metrics": {"out_tok_s": {"value": 1202.2140676700647, '
+        '"unit": "tokens/s"}, "itl_p995_ms": {"value": 458.63495000000177, "unit": "ms"}, "setup_s": {"value": '
+        '226.0498538017273, "unit": "s"}}, "device": {"platform": "tpu", "kind": "TPU v5 lite", "count": 1, '
+        '"memory_peak_bytes": 13325876224}}'
     )
     with open(os.path.join(ROOT, "BENCHMARK.json"), encoding="utf-8") as fh:
         m = json.load(fh)

@@ -1,10 +1,11 @@
-"""The shapes functions against hand counts for Mistral-7B-v0.3."""
+"""The Mistral adapter's counts of bytes and operations against hand counts for Mistral-7B-v0.3."""
 import json
 import os
 
 import pytest
 
-from perfbench import readers, shapes
+from perfbench import readers
+from perfbench.arch import mistral as shapes
 
 BENCH = os.path.join(os.path.dirname(os.path.dirname(os.path.dirname(os.path.abspath(__file__)))), "perfbench")
 with open(os.path.join(BENCH, "configs", "mistral-7b-v0.3-int8.json"), encoding="utf-8") as fh:
@@ -38,7 +39,7 @@ def test_decode_step_bytes_and_roofline_share():
     assert flops == 2.0 * (rows * (32 * 218_103_808 + 4096 * 32768) + 2 * 32 * 32 * 128 * live)
     peaks = {"hbm_bytes_per_s": 819e9, "int8_ops_per_s": 393e12}
     ctx = {
-        "config": CFG, "peaks": peaks, "read": lambda name: 38.0,
+        "config": CFG, "adapter": shapes, "peaks": peaks, "read": lambda name: 38.0,
         "spans": [{"kind": "decode", "category": "dispatch", "rows": rows}],
         "flight": [{"timeline": [{"event": "submit", "t_s": 0, "prompt_tokens": 258},
                                  {"event": "engine_finish", "t_s": 1, "generated": 384}]}],
