@@ -31,6 +31,7 @@ the metric linters and pure-host tier-1 tests load it freely.
 """
 from __future__ import annotations
 
+import dataclasses
 import threading
 from typing import Dict, List, Optional, Sequence
 
@@ -73,6 +74,12 @@ _M_FRAGMENTATION = _REG.gauge(
     "Internal fragmentation: fraction of live requests' allocated page "
     "tokens not (yet) holding sequence state — bounded below one page "
     "plus the reserved generation budget per request.",
+)
+_M_FIXED_STATE_BYTES = _REG.gauge(
+    "genai_engine_fixed_state_bytes",
+    "Device bytes of per-slot state that is not paged (recurrent states, "
+    "window rings) and sits beside the page pool; 0 for a model whose "
+    "every layer is paged.",
 )
 _M_REQUEST_PAGES = _REG.histogram(
     "genai_engine_kv_request_pages",
@@ -146,6 +153,42 @@ def page_bytes(
     if quantized:
         nbytes += 2 * layers * page_size * kv_heads * 4
     return nbytes
+
+
+@dataclasses.dataclass(frozen=True)
+class CachePlan:
+    """The two kinds of cache one engine can hold (docs/model_registry.md):
+    ``paged_bytes`` grow with the sequences (the pool this allocator
+    hands out page by page), ``fixed_bytes`` are a model's per-slot
+    state that no sequence length changes (a recurrent state, a window
+    ring) and that lives beside the pool, indexed by slot, never by
+    page. A model whose every layer is paged has ``fixed_bytes == 0``."""
+
+    pool_pages: int
+    page_size: int
+    slots: int
+    paged_bytes_per_token: int
+    fixed_bytes_per_slot: int
+
+    @property
+    def paged_bytes(self) -> int:
+        return self.pool_pages * self.page_size * self.paged_bytes_per_token
+
+    @property
+    def fixed_bytes(self) -> int:
+        return self.slots * self.fixed_bytes_per_slot
+
+    @property
+    def total_bytes(self) -> int:
+        return self.paged_bytes + self.fixed_bytes
+
+
+def cache_plan(pool_pages: int, page_size: int, slots: int,
+               paged_bytes_per_token: int, fixed_bytes_per_slot: int = 0) -> CachePlan:
+    """The cache plan of one engine; publishes the fixed share as a gauge."""
+    plan = CachePlan(pool_pages, page_size, slots, paged_bytes_per_token, fixed_bytes_per_slot)
+    _M_FIXED_STATE_BYTES.set(plan.fixed_bytes)
+    return plan
 
 
 def pages_needed(

@@ -175,6 +175,30 @@ _M_PAGED_ATTN = _REG.counter(
     "at startup and shows every decode dispatch under 'gather' here.",
     ("path",),
 )
+_M_PREFILL_TOKENS = _REG.counter(
+    "genai_engine_prefill_tokens_total",
+    "Prompt tokens run through the prefill and extend programs "
+    "(padding and cached-prefix tokens excluded).",
+)
+_M_STATE_RESETS = _REG.counter(
+    "genai_engine_state_slot_resets_total",
+    "Slots whose fixed per-slot state (recurrent state, window ring) "
+    "was reset at admission, inside the prefill or first extend "
+    "program; stays 0 for a model whose every layer is paged.",
+)
+_M_CROSS_SKIPPED = _REG.counter(
+    "genai_engine_prefill_cross_skipped_tokens_total",
+    "Prompt tokens for which the layers past the shared-KV layer were "
+    "NOT computed (a decoder-hybrid-decoder model computes them for a "
+    "chunk's last position only).",
+)
+_M_SSM_DISPATCHES = _REG.counter(
+    "genai_engine_ssm_dispatches_total",
+    "Program launches that advanced a recurrent state, by path: "
+    "'scan' (prefill and extend: a selective scan over the chunk) or "
+    "'step' (decode: one fused update a step).",
+    ("path",),
+)
 _M_PREFIX_COPY = _REG.counter(
     "genai_engine_prefix_copy_dispatches_total",
     "Compiled gather/update copy programs dispatched by the FIXED KV "
@@ -386,12 +410,27 @@ class LLMEngine:
         self._compile_watch = compile_watch_mod.CompileWatch()
 
         # --- model config + weights --------------------------------------
+        from generativeaiexamples_tpu.models import registry as model_registry
+
         model_cfg = None
         if cfg.checkpoint_path:
             model_cfg = config_from_hf(cfg.checkpoint_path)
         if model_cfg is None:
-            model_cfg = llama.PRESETS[cfg.model_config_name]
+            family, model_cfg = model_registry.resolve(cfg.model_config_name)
+        else:
+            family = model_registry.family_of(model_cfg)
         self.model_config = model_cfg
+        # The model family (models/registry.py): the paged step programs,
+        # the cache pytree and the memory plan all go through it. What the
+        # pools hold (layers, KV heads, head size) is the family's to say:
+        # a fixed-state family pages ONE layer and keeps the rest per slot.
+        self._family = family
+        self._kv_shape = family.paged_kv_shape(model_cfg)
+        self._fixed_state = bool(family.fixed_state)
+        self._span_fields = dict(family.span_fields(model_cfg))
+        if self._fixed_state:
+            cfg = self._validate_fixed_state(cfg, mesh)
+            self.engine_config = cfg
         self.tokenizer = tokenizer or load_tokenizer(cfg.tokenizer_path or cfg.checkpoint_path)
         # Sample only ids the tokenizer can represent: with the byte-level
         # fallback tokenizer (~260 ids) under a 128k-vocab head (random-init
@@ -638,7 +677,7 @@ class LLMEngine:
                     "LLM engine running with random-init weights (no checkpoint)."
                 )
             else:
-                params = llama.init_params_fast(model_cfg, 0, dtype)
+                params = family.init_params(model_cfg, 0, dtype)
                 logger.warning(
                     "LLM engine running with random-init weights (no checkpoint)."
                 )
@@ -698,11 +737,9 @@ class LLMEngine:
             # per layer on device (HBM-to-HBM slices).
             device = self._mesh.devices.reshape(-1)[0]
             params = jax.device_put(params, device)
-            # consume_split_params_layers consumes params (pops stacked leaves as
-            # they split); drop the local ref so each stacked buffer
-            # frees immediately — peak HBM stays ~1x weights, which is
-            # what lets 8B-int8 fit a 16 GB chip.
-            self.params = llama.consume_split_params_layers(params)
+            # (a family's place_params may consume params; drop the local
+            # ref so each buffer it pops frees immediately)
+            self.params = family.place_params(params)
             del params
         else:
             with mesh_context(self._mesh):
@@ -724,9 +761,9 @@ class LLMEngine:
             kv_pages_mod.validate_runtime(
                 cfg.page_size, self.max_seq_len, self._pool_pages
             )
-            pool = llama.init_kv_pool(
-                model_cfg, self._pool_pages, cfg.page_size, dtype,
-                quantized=self._kv_quant, packed=self._kv_packed,
+            pool = family.init_paged_cache(
+                model_cfg, self._pool_pages, cfg.page_size, self.num_slots,
+                dtype, quantized=self._kv_quant, packed=self._kv_packed,
             )
             if self._mesh.size > 1:
                 from generativeaiexamples_tpu.parallel.sharding import (
@@ -765,6 +802,25 @@ class LLMEngine:
                 self._pool_pages, cfg.page_size,
                 (self._pool_pages - 1) // self._max_pages_per_slot,
             )
+            if self._fixed_state:
+                plan = kv_pages_mod.cache_plan(
+                    self._pool_pages, cfg.page_size, self.num_slots,
+                    paged_bytes_per_token=kv_pages_mod.page_bytes(
+                        self._kv_shape.num_layers, 1,
+                        self._kv_shape.num_kv_heads, self._kv_shape.head_dim,
+                        quantized=False,
+                    ),
+                    fixed_bytes_per_slot=family.fixed_state_bytes_per_slot(
+                        model_cfg
+                    ),
+                )
+                logger.info(
+                    "fixed per-slot state beside the pool: %.1f MB a slot x "
+                    "%d slots = %.2f GB (pool %.2f GB for %d paged layer(s))",
+                    plan.fixed_bytes_per_slot / 1e6, self.num_slots,
+                    plan.fixed_bytes / 1e9, plan.paged_bytes / 1e9,
+                    self._kv_shape.num_layers,
+                )
         elif self._layered and self._mesh.size > 1:
             from generativeaiexamples_tpu.parallel.sharding import (
                 shard_kv_cache_layered,
@@ -864,6 +920,55 @@ class LLMEngine:
         self._init_spec_proposer(cfg)
         self._init_prefix_cache(cfg, model_cfg, dtype)
         self._init_scheduler_state(cfg)
+
+    def _validate_fixed_state(self, cfg: EngineConfig, mesh) -> EngineConfig:
+        """Refuse, at engine build, everything that assumes "a slot's
+        state is its pages" for a family that declares fixed per-slot
+        state (a recurrent state, a window ring: models/registry.py,
+        docs/model_registry.md). One clear error each; no silent
+        fallback to a path that would serve such a model wrongly.
+        Request snapshots are refused where they are taken (``drain``,
+        ``restore_snapshot``): the spool directory always has a default,
+        so its being set says nothing. Returns the config with
+        ``tensor_parallelism=-1`` resolved to 1 (one device serves it).
+        """
+        import dataclasses as _dc
+        import os as _os
+
+        name = f"{self._family.name} model {cfg.model_config_name!r}"
+
+        def refuse(what: str, knob: str) -> None:
+            raise ValueError(
+                f"{name} keeps a fixed per-slot state beside the page "
+                f"pool, which {what} cannot carry; {knob}"
+            )
+
+        if cfg.kv_layout == "fixed":
+            refuse("the fixed KV layout", "set kv_layout='paged' (or 'auto')")
+        if cfg.serving_layout == "scan":
+            refuse("the scan serving layout",
+                   "set serving_layout='layered' (or 'auto')")
+        if _os.environ.get("GENAI_TPU_DECODE_SLAB", "0").lower() in ("1", "true", "yes"):
+            refuse("the slab decode loop", "unset GENAI_TPU_DECODE_SLAB")
+        mesh_size = mesh.size if mesh is not None else 1
+        if cfg.pipeline_parallelism > 1 or (mesh is not None and dict(mesh.shape).get("pipe", 1) > 1):
+            refuse("pipeline-parallel serving", "set pipeline_parallelism=1")
+        if cfg.tensor_parallelism > 1 or mesh_size > 1:
+            refuse("a sharded mesh", "set tensor_parallelism=1")
+        prefix_cache_mod.require_paged_state(name, cfg)
+        spec_decode_mod.require_paged_state(name, cfg)
+        if cfg.quantization not in ("", "none"):
+            refuse(f"quantization={cfg.quantization!r} (no packed walk)",
+                   "set quantization='none'")
+        if cfg.kv_cache_dtype != "bfloat16":
+            refuse(f"kv_cache_dtype={cfg.kv_cache_dtype!r}",
+                   "set kv_cache_dtype='bfloat16'")
+        if cfg.chunked_prefill == "off":
+            refuse("monolithic-only prefill (its state goes from chunk "
+                   "to chunk)", "set chunked_prefill='auto'")
+        if cfg.tensor_parallelism == -1:
+            cfg = _dc.replace(cfg, tensor_parallelism=1)
+        return cfg
 
     def _draft_ladder(self) -> Tuple[List[int], List[int]]:
         """(row rungs, chunk-window rungs) the draft-model runtime's
@@ -991,9 +1096,10 @@ class LLMEngine:
             )
             return
         kind = "interpret" if interpret else "compiled"
+        kv_shape = self._kv_shape  # the pools' own geometry (models/registry.py)
         geom = (
-            cfg.page_size, model_cfg.head_dim, model_cfg.num_heads,
-            model_cfg.num_kv_heads,
+            cfg.page_size, kv_shape.head_dim, kv_shape.num_heads,
+            kv_shape.num_kv_heads,
         )
         kv_dtype = cfg.kv_cache_dtype if self._kv_quant else "bfloat16"
         if page_attention.supports_geometry(
@@ -1017,8 +1123,8 @@ class LLMEngine:
             )
             flight_recorder.event(
                 "paged_kernel_fallback", reason="geometry",
-                page_size=cfg.page_size, head_dim=model_cfg.head_dim,
-                heads=model_cfg.num_heads, kv_heads=model_cfg.num_kv_heads,
+                page_size=cfg.page_size, head_dim=kv_shape.head_dim,
+                heads=kv_shape.num_heads, kv_heads=kv_shape.num_kv_heads,
                 kv_dtype=kv_dtype, shards=shards,
             )
             return
@@ -1032,7 +1138,7 @@ class LLMEngine:
             logger.info(
                 "spec-verify chunks (%d query rows x %d heads) exceed "
                 "the page kernel's row cap; verify dispatches stay on "
-                "the XLA gather", verify_rows, model_cfg.num_heads,
+                "the XLA gather", verify_rows, kv_shape.num_heads,
             )
 
     def _init_scheduler_state(self, cfg: EngineConfig) -> None:
@@ -1215,7 +1321,10 @@ class LLMEngine:
             if getattr(self, "_kv_quant", False) else 2
         )
         self._telemetry = telemetry_mod.UtilizationEstimator(
-            matmul_params=hardware.matmul_params(self.model_config),
+            matmul_params=(
+                self._family.count_logical_params(self.model_config)
+                - self.model_config.vocab_size * self.model_config.hidden_size
+            ),
             weight_stream_bytes=wbytes,
             devices=self._mesh.size,
         )
@@ -1581,7 +1690,7 @@ class LLMEngine:
         int8 KV cache. A config that cannot fit logs a clear budget line
         instead of dying later in a fragmented device OOM.
         """
-        from generativeaiexamples_tpu.models.llama import serving_memory_bytes
+        serving_memory_bytes = self._family.serving_memory_bytes
 
         wbytes = 1 if cfg.quantization in ("int8", "w8a8") else 2
         kvbytes = hardware.kv_bytes_per_element(cfg.kv_cache_dtype)
@@ -2163,10 +2272,12 @@ class LLMEngine:
             last_h = jnp.where((valid > 0)[:, None], cand, last_h)
             return last_h, caches
 
+        fam = self._family
+        # the kernel paths this engine resolved; a family takes what it knows
+        paths = dict(quant_kernel=quant_kernel, tp=tp)
+
         def finish_batch(params, last_h, lengths, temps, topps, seeds):
-            logits = llama._head(
-                params, last_h[:, None, :], cfg, quant_kernel, tp=tp
-            )[:, 0, :]
+            logits = fam.head(params, cfg, last_h, **paths)
             keys = sample_keys(base_key, seeds, lengths)
             return sample_tokens(logits[:, :V], keys, temps, topps)
 
@@ -2263,8 +2374,11 @@ class LLMEngine:
             "spec_verify",
             jax.jit(spec_verify, donate_argnums=(1,), static_argnums=(10,)),
         )
-        self._spec_available = True
-        self._spec_enabled = ecfg.spec_decode_enable == "on"
+        # a family without a verify walk has no speculative program
+        self._spec_available = fam.verify_paged is not None
+        self._spec_enabled = (
+            self._spec_available and ecfg.spec_decode_enable == "on"
+        )
         if self._spec_enabled and kv_kernel:
             # Verify scores the int8 cache through the XLA dequant
             # attention (extend-style multi-token chunks; the Pallas
@@ -2282,10 +2396,15 @@ class LLMEngine:
         # --- paged overrides (kv_layout='paged', docs/paged_kv.md) ----
         # Same scheduler-facing contracts as the fixed-layout programs
         # above, with cache coordinates routed through the per-slot page
-        # tables (one extra [B, Pmax] int32 operand) and the attention
-        # window GATHERED from the shared page pool. The gathered window
-        # holds the same W tokens in the same order as the fixed [:W]
-        # slice, and models/llama.py's paged passes mirror the fixed
+        # tables (one extra [B, Pmax] int32 operand). These programs
+        # reach the model through its family alone (models/registry.py):
+        # three walks over an opaque cache pytree — page pools and, for
+        # a fixed-state family, per-slot arrays the walks index by slot
+        # (reset at admission inside the prefill / first extend program,
+        # carried from chunk to chunk, untouched by a dead decode row).
+        # For llama the gathered window holds the same W tokens in the
+        # same order as the fixed [:W] slice, and models/llama.py's
+        # paged passes mirror the fixed
         # math op for op — streams are token-identical between layouts.
         # The ragged Pallas kernel (resolved per family by
         # _resolve_paged_kernel) replaces the gather READ where geometry
@@ -2300,14 +2419,10 @@ class LLMEngine:
             # as the fixed path (prefill_layers never touches a cache),
             # then one pool scatter per layer via the page tables — so
             # first-token logits match the fixed layout bitwise.
-            logits, kvs = llama.prefill_layers(
-                params, cfg, tokens, lengths,
+            logits, new_caches = fam.prefill_paged(
+                params, cfg, caches, tokens, lengths, slots, tables, page,
                 use_flash=None if (self._mesh.size == 1 or tp is not None) else False,
-                quant_kernel=quant_kernel,
-                tp=tp,
-            )
-            new_caches = llama.write_prefill_pages(
-                caches, kvs, tables[slots], page
+                **paths,
             )
             keys = sample_keys(base_key, seeds, lengths)
             first = sample_tokens(logits[:, :V], keys, temps, topps)
@@ -2319,11 +2434,9 @@ class LLMEngine:
 
             def body(carry, _):
                 tokens, positions, caches = carry
-                logits, caches = llama.decode_layers_paged(
-                    params, cfg, tokens, positions, live, tables, caches,
-                    window=window, page_size=page,
-                    quant_kernel=quant_kernel, tp=tp,
-                    page_kernel=page_kernel,
+                logits, caches = fam.decode_paged(
+                    params, cfg, caches, tokens, positions, live, tables,
+                    window, page, page_kernel=page_kernel, **paths,
                 )
                 keys = sample_keys(
                     base_key, seeds, jnp.minimum(positions + 1, max_pos)
@@ -2339,11 +2452,15 @@ class LLMEngine:
 
         def extend_batch_paged(params, caches, tokens, offsets, valid,
                                slots, last_h, tables, window):
-            cand, caches = llama.extend_layers_paged(
-                params, cfg, tokens, offsets, valid, slots, tables,
-                caches, window, page, quant_kernel=quant_kernel, tp=tp,
+            cand, caches = fam.extend_paged(
+                params, cfg, caches, tokens, offsets, valid, slots, tables,
+                window, page, **paths,
             )
-            last_h = jnp.where((valid > 0)[:, None], cand, last_h)
+            # (a family may hand back a wider hidden state than the
+            # carried one; the carry keeps ONE dtype, so ONE executable)
+            last_h = jnp.where(
+                (valid > 0)[:, None], cand.astype(last_h.dtype), last_h
+            )
             return last_h, caches
 
         def spec_verify_paged(params, caches, tokens, positions, temps,
@@ -2357,10 +2474,9 @@ class LLMEngine:
             chunk = jnp.concatenate([tokens[:, None], draft], axis=1)
             valid = jnp.where(live, 1 + draft_len, 0)
             slot_ids = jnp.arange(B, dtype=jnp.int32)
-            logits, caches = llama.verify_layers_paged(
-                params, cfg, chunk, offsets, valid, slot_ids, tables,
-                caches, window, page, quant_kernel=quant_kernel, tp=tp,
-                page_kernel=verify_kernel,
+            logits, caches = fam.verify_paged(
+                params, cfg, caches, chunk, offsets, valid, slot_ids, tables,
+                window, page, page_kernel=verify_kernel, **paths,
             )  # [B, K+1, V]
             pos_grid = jnp.minimum(
                 offsets[:, None] + 1
@@ -2475,7 +2591,7 @@ class LLMEngine:
         """KV bytes one decode step reads over the whole batch at this
         attention window (utils/hardware.py owns the formula)."""
         return hardware.kv_read_bytes_per_step(
-            self.model_config, self.num_slots, window, self._kv_byte_width
+            self._kv_shape, self.num_slots, window, self._kv_byte_width
         )
 
     def _ragged_read_bytes(self) -> int:
@@ -2492,7 +2608,7 @@ class LLMEngine:
             for p in self._slot_pos.values()
         )
         return hardware.kv_read_bytes_ragged(
-            self.model_config, tokens, self._kv_byte_width
+            self._kv_shape, tokens, self._kv_byte_width
         )
 
     def _kernel_pages_walked(self) -> Dict[str, int]:
@@ -2846,6 +2962,7 @@ class LLMEngine:
         Runs on the caller's (HTTP) thread; bounded by
         ``engine.drain_timeout_s`` unless ``timeout`` overrides it.
         Returns the summary the router's drain report consumes."""
+        request_snapshot_mod.require_paged_state(self, "drain")
         budget_s = float(
             self.engine_config.drain_timeout_s if timeout is None else timeout
         )
@@ -3037,6 +3154,7 @@ class LLMEngine:
         sampling, re-delivered from the start). Raises
         ``SnapshotMismatch`` on config-fingerprint or KV-geometry
         drift and ``EngineOverloaded`` while this engine drains."""
+        request_snapshot_mod.require_paged_state(self, "restore_snapshot")
         t0 = time.time()
         self._spool.check_fingerprint(snap)
         request_snapshot_mod.check_geometry(self, snap)
@@ -3355,6 +3473,17 @@ class LLMEngine:
         _M_WEDGED.set(1)
         ENGINE_WEDGED.set()
         logger.error("engine wedged: %s", reason)
+        # Where every thread stands: a wedge is a thread that waits for
+        # another, and the log is what is left when the machine is gone.
+        import sys
+        import traceback
+
+        names = {t.ident: t.name for t in threading.enumerate()}
+        for ident, frame in sys._current_frames().items():
+            logger.error(
+                "wedged: thread %s\n%s", names.get(ident, ident),
+                "".join(traceback.format_stack(frame, limit=12)),
+            )
         # Anomaly black box: a wedged dispatch loop is exactly the
         # moment whose state an investigation needs (utils/blackbox.py;
         # one boolean read when disabled, runs on the watchdog thread).
@@ -3799,6 +3928,9 @@ class LLMEngine:
                     self._telemetry.record_dispatch(
                         "prefill", tokens=int(lengths.sum()), rows=N
                     )
+                    state_fields = self._state_counters(
+                        "prefill", N, int(lengths[:N].sum()), 0, resets=N
+                    )
                     _dtl = self._dtl
                     if _dtl is not None:
                         _dtl_wall = time.time()
@@ -3840,6 +3972,7 @@ class LLMEngine:
                             rows=N,
                             tokens=int(lengths.sum()),
                             rids=[r.rid for r in group],
+                            counters=state_fields,
                         )
                 # Inject into the device-resident batch state — dispatched, not
                 # synced; token values reach the host via the reader.
@@ -3924,10 +4057,10 @@ class LLMEngine:
                                 budget=budget,
                                 pages=pages,
                                 nbytes=len(pages) * kv_pages_mod.page_bytes(
-                                    self.model_config.num_layers,
+                                    self._kv_shape.num_layers,
                                     self.engine_config.page_size,
-                                    self.model_config.num_kv_heads,
-                                    self.model_config.head_dim,
+                                    self._kv_shape.num_kv_heads,
+                                    self._kv_shape.head_dim,
                                     quantized=self._kv_quant,
                                     kv_width=self._kv_byte_width,
                                 ),
@@ -4233,6 +4366,14 @@ class LLMEngine:
                         last_h,
                         W,
                     )
+            n_real = len(reqs) if reqs is not None else Np
+            live_rows = valid[:n_real] > 0
+            state_fields = self._state_counters(
+                "prefill_chunk", int(live_rows.sum()),
+                int(valid[:n_real].sum()),
+                int(live_rows.sum()) * min(k * C, self._span_fields.get("window", 0)),
+                resets=int(live_rows.sum()) if k == 0 else 0,
+            )
             if _dtl is not None:
                 _dtl.record_span(
                     "prefill_chunk",
@@ -4244,11 +4385,12 @@ class LLMEngine:
                     rids=(
                         [r.rid for r in reqs] if reqs is not None else ()
                     ),
+                    counters=state_fields,
                 )
             self._telemetry.record_dispatch(
                 "prefill", tokens=int(valid.sum()),
                 cache_bytes=hardware.kv_read_bytes_per_step(
-                    self.model_config, Np, W, self._kv_byte_width
+                    self._kv_shape, Np, W, self._kv_byte_width
                 ),
                 rows=int((valid > 0).sum()),
             )
@@ -4270,13 +4412,48 @@ class LLMEngine:
         _M_PREFILL_CHUNKS.inc(K - k0)
         return first
 
+    def _state_counters(self, kind: str, rows: int, tokens: int,
+                        ring_tokens: int, resets: int = 0) -> Optional[Dict[str, int]]:
+        """Span fields and counters of one launch of a fixed-state
+        family (None for a family whose every layer is paged):
+        ``state_rows`` rows whose per-slot state advanced, ``kv_readers``
+        layers that read the one paged K/V, ``window_tokens_read`` ring
+        rows the window layers read (first step of a decode block) and,
+        on prefill and extend, ``cross_skipped_tokens``: tokens the
+        layers past the shared-KV layer never saw (all but one a row)."""
+        if kind != "decode":
+            _M_PREFILL_TOKENS.inc(tokens)  # every family: the skipped share's denominator
+        if not self._fixed_state:
+            return None
+        fields = {
+            "state_rows": rows,
+            "kv_readers": self._span_fields["kv_readers"],
+            "window_tokens_read": ring_tokens * self._span_fields["window_layers"],
+        }
+        if kind == "decode":
+            _M_SSM_DISPATCHES.labels(path="step").inc()
+        else:
+            fields["cross_skipped_tokens"] = max(0, tokens - rows)
+            _M_SSM_DISPATCHES.labels(path="scan").inc()
+            _M_CROSS_SKIPPED.inc(fields["cross_skipped_tokens"])
+            _M_STATE_RESETS.inc(resets)
+        return fields
+
     def _prefill_bucket(self, n: int) -> int:
         chunk = self.engine_config.prefill_chunk
         bucket = ((n + chunk - 1) // chunk) * chunk
         return min(bucket, self.max_seq_len)
 
     def _max_wave_rows(self, bucket: int) -> int:
-        """Max prefill rows for this bucket under prefill_wave_tokens."""
+        """Max prefill rows for this bucket under prefill_wave_tokens.
+
+        A fixed-state family gets ONE row a wave, monolithic or chunked:
+        on the chip its chunk walk over several LIVE rows now and then
+        never ended (PERF.md section 6, PR 29: four runs of ten, cause
+        not found), every one-row wave did. No wider program is built
+        or warmed, so no setting can reach one."""
+        if getattr(self, "_fixed_state", False):
+            return 1
         budget = getattr(self.engine_config, "prefill_wave_tokens", 16384)
         return max(1, min(self.num_slots, budget // max(1, bucket)))
 
@@ -4398,6 +4575,15 @@ class LLMEngine:
                 self._kernel_pages_walked()
                 if self._paged and self._paged_kernel else None
             )
+            state_fields = self._state_counters(
+                "decode", len(live_slots), 0,
+                sum(
+                    min(p + 1, self._span_fields.get("window", 0))
+                    for p in self._slot_pos.values()
+                ),
+            )
+            if state_fields is not None:
+                kv_pages = dict(kv_pages or {}, **state_fields)
             for slot in self._slot_pos:
                 self._slot_pos[slot] += self._decode_block
             self._update_occupancy_gauges()
@@ -5332,9 +5518,12 @@ class LLMEngine:
             # The reader's own count and stop reason: the eager
             # decode_leave event fires at dispatch time, before the
             # reader has counted the block's tokens.
+            # ``generated`` here is what the stream DELIVERED: the stop
+            # id that ends an answer is sampled and counted in
+            # req.generated (positions follow it) but is never a frame.
             flight_recorder.finish_rid(
                 req.rid, "abort" if req.cancelled else "finish",
-                generated=req.generated,
+                generated=req.generated - (token in stop_ids),
                 stop=(
                     "eos" if token in stop_ids
                     else "max_tokens" if req.generated >= req.params.max_tokens

@@ -90,6 +90,20 @@ def metrics_snapshot() -> Dict[str, float]:
     }
 
 
+def require_paged_state(model: str, cfg) -> None:
+    """A prefix hit maps cached PAGES into a new request's table and
+    skips the cached chunks' prefill. A model with fixed per-slot state
+    (a recurrent state, a window ring: models/registry.py) would start
+    its suffix from a state nobody saved — refuse it at engine build."""
+    if cfg.prefix_cache_enable != "off" and cfg.prefix_cache_slots > 0:
+        raise ValueError(
+            f"{model} keeps a fixed per-slot state beside the page pool, "
+            "which prefix-cache reuse cannot carry (an entry holds pages, "
+            "not the recurrent state at the prefix's end); set "
+            "prefix_cache_enable='off'"
+        )
+
+
 class _Node:
     __slots__ = ("children", "entry", "parent")
 

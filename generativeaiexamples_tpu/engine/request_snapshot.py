@@ -108,6 +108,19 @@ class SnapshotMismatch(SnapshotError):
 # Codec: numpy arrays <-> JSON-safe documents
 
 
+def require_paged_state(engine, what: str) -> None:
+    """A snapshot's payload is a request's pages. A model with fixed
+    per-slot state (models/registry.py) has more than that, and a
+    restore from pages alone would resume it on another tenant's
+    recurrent state — refuse to take or restore one."""
+    if getattr(engine, "_fixed_state", False):
+        raise SnapshotError(
+            f"{what} refused: model {engine.engine_config.model_config_name!r} "
+            "keeps a fixed per-slot state beside the page pool, which a "
+            "request snapshot cannot carry"
+        )
+
+
 def _encode_array(arr: np.ndarray) -> Dict[str, Any]:
     return {
         "dtype": arr.dtype.name,

@@ -197,6 +197,21 @@ class AdaptiveK:
         return self.k_max
 
 
+def require_paged_state(model: str, cfg) -> None:
+    """Speculative verify advances a row by up to K+1 positions and
+    rolls the rejected ones back by position alone — sound when a
+    slot's state is pages, not when it is a recurrent state that the
+    rejected tokens have already gone through. Refused at engine build
+    for a fixed-state model (models/registry.py)."""
+    if cfg.spec_decode_enable == "on":
+        raise ValueError(
+            f"{model} keeps a fixed per-slot state beside the page pool, "
+            "which speculative verify cannot carry (a rejected draft "
+            "token cannot be taken back out of a recurrent state); set "
+            "spec_decode_enable='off'"
+        )
+
+
 def validate_config(cfg) -> None:
     """Engine-config validation for the spec-decode knobs (pure host, so
     tier-1 tests cover it without building an engine). Raises ValueError

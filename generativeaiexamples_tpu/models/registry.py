@@ -1,0 +1,209 @@
+"""Model families: the one door through which the engine reaches a model.
+
+``resolve(model_config_name)`` returns ``(family, model_config)``. A
+family is a small record of functions over an OPAQUE cache pytree — the
+engine's paged step programs (``engine/llm_engine.py``
+``_build_steps_layered``) call these and nothing of a model module:
+
+- ``config_type``: the class of the family's configuration objects
+  (what ``family_of`` tells families apart by);
+- ``init_params(cfg, seed, dtype)``: seeded random weights on the host;
+- ``place_params(params)``: from what ``init_params`` (or a checkpoint)
+  gives, already on the device, to the layout the walks read (the
+  identity by default; ``llama`` splits its stacked leaves per layer);
+- ``init_paged_cache(cfg, pool_pages, page_size, num_slots, dtype,
+  quantized, packed)``: the cache pytree (page pools and, for a
+  fixed-state family, the per-slot arrays beside them);
+- ``prefill_paged(params, cfg, caches, tokens, lengths, slots, tables,
+  page_size, **paths) -> (logits [N, V], caches)``;
+- ``extend_paged(params, cfg, caches, tokens, offsets, valid, slots,
+  tables, window, page_size, **paths) -> (hidden [N, D], caches)``;
+- ``decode_paged(params, cfg, caches, tokens, positions, live, tables,
+  window, page_size, **paths) -> (logits [B, V], caches)``;
+- ``verify_paged(...)`` or None (no speculative verify program);
+- ``head(params, cfg, hidden [N, D], **paths) -> logits [N, V]``;
+- the memory plan: ``serving_memory_bytes``, ``count_logical_params``,
+  ``paged_kv_shape`` (the geometry of what IS paged: layers, KV heads
+  and head size of the pools, query heads of the page kernel's read)
+  and ``fixed_state_bytes_per_slot``.
+
+``paths`` are the kernel paths the engine resolved (``use_flash``,
+``quant_kernel``, ``tp``, ``page_kernel``); a family takes what it knows
+and ignores the rest.
+
+``fixed_state`` declares that a slot holds state that is NOT pages (a
+recurrent state, a window ring). Everything in the engine that assumes
+"a slot's state is its pages" — prefix-cache reuse, speculative verify,
+request snapshots, the fixed / slab / scan / pipeline layouts, tensor
+parallelism, quantised weights and KV — refuses such a family at engine
+build (docs/model_registry.md); ``span_fields`` are the constant counts
+it adds to the dispatch-timeline spans.
+
+``llama`` registers through the same door: its presets dict is
+``llama.PRESETS`` itself (so a preset written there at run time, as the
+benchmark's Mistral adapter does, resolves), its walks are
+``models/llama.py``'s, untouched.
+"""
+from __future__ import annotations
+
+import dataclasses
+from typing import Any, Callable, Dict, Optional, Tuple
+
+
+@dataclasses.dataclass(frozen=True)
+class PagedKVShape:
+    """What the page pools hold and the page kernel reads."""
+
+    num_layers: int
+    num_kv_heads: int
+    head_dim: int
+    num_heads: int
+
+
+@dataclasses.dataclass(frozen=True)
+class ModelFamily:
+    name: str
+    presets: Dict[str, Any]
+    config_type: type
+    fixed_state: bool
+    init_params: Callable[..., Any]
+    init_paged_cache: Callable[..., Any]
+    prefill_paged: Callable[..., Tuple[Any, Any]]
+    extend_paged: Callable[..., Tuple[Any, Any]]
+    decode_paged: Callable[..., Tuple[Any, Any]]
+    verify_paged: Optional[Callable[..., Tuple[Any, Any]]]
+    head: Callable[..., Any]
+    serving_memory_bytes: Callable[..., Dict[str, int]]
+    count_logical_params: Callable[[Any], int]
+    paged_kv_shape: Callable[[Any], PagedKVShape]
+    fixed_state_bytes_per_slot: Callable[..., int] = lambda cfg, kv_bytes=2: 0
+    span_fields: Callable[[Any], Dict[str, int]] = lambda cfg: {}
+    place_params: Callable[[Any], Any] = lambda params: params
+
+
+_FAMILIES: Dict[str, ModelFamily] = {}
+
+
+def register_family(family: ModelFamily) -> None:
+    _FAMILIES[family.name] = family
+
+
+def families() -> Dict[str, ModelFamily]:
+    _load_builtin()
+    return dict(_FAMILIES)
+
+
+def register_preset(family: str, name: str, cfg: Any) -> None:
+    """Add (or replace) a named configuration of a family, e.g. from a
+    configuration file's published sizes."""
+    families()[family].presets[name] = cfg
+
+
+def resolve(name: str) -> Tuple[ModelFamily, Any]:
+    """The family and configuration ``model_config_name`` names."""
+    fams = families()
+    for fam in fams.values():
+        if name in fam.presets:
+            return fam, fam.presets[name]
+    known = sorted(n for f in fams.values() for n in f.presets)
+    raise KeyError(f"unknown model_config_name {name!r}; known: {known}")
+
+
+def family_of(cfg: Any) -> ModelFamily:
+    """The family a configuration OBJECT belongs to (a checkpoint's
+    config never passed through ``resolve``)."""
+    for fam in families().values():
+        if isinstance(cfg, fam.config_type):
+            return fam
+    raise KeyError(f"no model family owns a configuration of type {type(cfg).__name__}")
+
+
+# --------------------------------------------------------------------- //
+# The built-in families
+
+
+def _llama_family() -> ModelFamily:
+    from generativeaiexamples_tpu.models import llama
+
+    def init_paged_cache(cfg, pool_pages, page_size, num_slots, dtype, quantized=False, packed=False):
+        del num_slots  # every layer's state is pages
+        return llama.init_kv_pool(cfg, pool_pages, page_size, dtype, quantized=quantized, packed=packed)
+
+    def prefill_paged(params, cfg, caches, tokens, lengths, slots, tables, page_size, *,
+                      use_flash=None, quant_kernel=None, tp=None, **_):
+        # the SAME fresh-K/V forward as the fixed layout (prefill_layers
+        # never touches a cache), then one pool scatter per layer via the
+        # page tables: first-token logits match the fixed layout bitwise
+        logits, kvs = llama.prefill_layers(
+            params, cfg, tokens, lengths, use_flash=use_flash, quant_kernel=quant_kernel, tp=tp,
+        )
+        return logits, llama.write_prefill_pages(caches, kvs, tables[slots], page_size)
+
+    def extend_paged(params, cfg, caches, tokens, offsets, valid, slots, tables, window, page_size, *,
+                     quant_kernel=None, tp=None, **_):
+        return llama.extend_layers_paged(
+            params, cfg, tokens, offsets, valid, slots, tables, caches, window, page_size,
+            quant_kernel=quant_kernel, tp=tp,
+        )
+
+    def decode_paged(params, cfg, caches, tokens, positions, live, tables, window, page_size, *,
+                     quant_kernel=None, tp=None, page_kernel=None, **_):
+        return llama.decode_layers_paged(
+            params, cfg, tokens, positions, live, tables, caches, window=window, page_size=page_size,
+            quant_kernel=quant_kernel, tp=tp, page_kernel=page_kernel,
+        )
+
+    def verify_paged(params, cfg, caches, tokens, offsets, valid, slots, tables, window, page_size, *,
+                     quant_kernel=None, tp=None, page_kernel=None, **_):
+        return llama.verify_layers_paged(
+            params, cfg, tokens, offsets, valid, slots, tables, caches, window, page_size,
+            quant_kernel=quant_kernel, tp=tp, page_kernel=page_kernel,
+        )
+
+    def head(params, cfg, hidden, *, quant_kernel=None, tp=None, **_):
+        return llama._head(params, hidden[:, None, :], cfg, quant_kernel, tp=tp)[:, 0, :]
+
+    return ModelFamily(
+        name="llama", presets=llama.PRESETS, config_type=llama.LlamaConfig, fixed_state=False,
+        init_params=llama.init_params_fast, init_paged_cache=init_paged_cache,
+        # consumes params (pops stacked leaves as they split), so each
+        # stacked buffer frees at once: peak HBM stays ~1x weights, which
+        # is what lets 8B-int8 fit a 16 GB chip
+        place_params=llama.consume_split_params_layers,
+        prefill_paged=prefill_paged, extend_paged=extend_paged, decode_paged=decode_paged,
+        verify_paged=verify_paged, head=head,
+        serving_memory_bytes=llama.serving_memory_bytes,
+        count_logical_params=llama.count_logical_params,
+        paged_kv_shape=lambda cfg: PagedKVShape(cfg.num_layers, cfg.num_kv_heads, cfg.head_dim, cfg.num_heads),
+    )
+
+
+def _phi4flash_family() -> ModelFamily:
+    from generativeaiexamples_tpu.models import phi4flash as m
+
+    def init_paged_cache(cfg, pool_pages, page_size, num_slots, dtype, quantized=False, packed=False):
+        if quantized or packed:
+            raise ValueError("phi4flash keeps its one paged layer and its fixed state in bfloat16")
+        return m.init_paged_cache(cfg, pool_pages, page_size, num_slots, dtype)
+
+    return ModelFamily(
+        name="phi4flash", presets=m.PRESETS, config_type=m.Phi4FlashConfig, fixed_state=True,
+        init_params=m.init_params_fast, init_paged_cache=init_paged_cache,
+        prefill_paged=m.prefill_paged, extend_paged=m.extend_paged, decode_paged=m.decode_paged,
+        verify_paged=None, head=lambda params, cfg, hidden, **_: m.head(params, cfg, hidden),
+        serving_memory_bytes=m.serving_memory_bytes, count_logical_params=m.count_logical_params,
+        # ONE paged layer, in the pair layout the page kernel reads
+        paged_kv_shape=lambda cfg: PagedKVShape(1, cfg.pair_kv_heads, cfg.pair_dim, cfg.num_heads),
+        fixed_state_bytes_per_slot=m.fixed_state_bytes_per_slot,
+        span_fields=lambda cfg: {
+            "kv_readers": 1 + len(cfg.layers_of("cross")),
+            "window_layers": len(cfg.layers_of("window")),
+            "window": cfg.sliding_window,
+        },
+    )
+
+
+def _load_builtin() -> None:
+    if not _FAMILIES:
+        register_family(_llama_family())
+        register_family(_phi4flash_family())

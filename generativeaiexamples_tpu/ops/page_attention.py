@@ -100,6 +100,7 @@ def _kernel(
     row_ref, page_ref, phys_ref, pos_ref, q_ref, *refs,
     scale: float, page: int, hq: int, hkv: int, g: int,
     t: int, s_max: int, quantized: bool, packed: bool,
+    head_major: bool = False,
 ):
     if quantized:
         k_ref, ks_ref, v_ref, vs_ref, o_ref, m_ref, l_ref, acc_ref = refs
@@ -143,8 +144,12 @@ def _kernel(
         sc = sc * scale
     col_iota = lax.broadcasted_iota(jnp.int32, (rows, cols), 1)
     row_iota = lax.broadcasted_iota(jnp.int32, (rows, 1), 0)
-    tok = j * page + col_iota // hkv
-    col_head = col_iota % hkv
+    if head_major:  # a page is [Hkv, page, Dh]: column c = kv-head * page + token
+        tok = j * page + col_iota % page
+        col_head = col_iota // page
+    else:
+        tok = j * page + col_iota // hkv
+        col_head = col_iota % hkv
     row_head = (row_iota % hq) // g
     # per-query-row causal clamp: query t attends <= positions + t
     q_pos = jnp.minimum(p_first + row_iota // hq, s_max - 1)
@@ -222,7 +227,7 @@ def page_work_list(
     )
 
 
-@functools.partial(jax.jit, static_argnames=("interpret",))
+@functools.partial(jax.jit, static_argnames=("interpret", "head_major"))
 def paged_attention(
     q: jax.Array,  # [B, T, Hq, Dh] bf16 — T query tokens per row
     k: jax.Array,  # [P, page, Hkv, Dh] int8 or bf16 page pool
@@ -234,8 +239,17 @@ def paged_attention(
     *,
     interpret: bool = False,
     work: Optional[PageWork] = None,
+    head_major: bool = False,
 ) -> jax.Array:
     """Attention output ``[B, T, Hq, Dh]`` over each row's live pages.
+
+    ``head_major`` reads a pool laid out ``[P, Hkv, page, Dh]`` (bf16
+    only). A token-major pool whose KV-head count is not a multiple of
+    the sublane tile (16 for bf16) has no compact DEFAULT layout on the
+    chip: XLA keeps it in a permuted one and copies the whole pool into
+    the padded default around every kernel call. With the heads ahead
+    of the tokens the two minor dims are ``(page, Dh)``, which tile
+    exactly, and the same ``[Hkv * page, Dh]`` merge feeds the dots.
 
     Query token ``t`` of row ``b`` sits at absolute position
     ``positions[b] + t`` and attends cache rows at positions ``<= that``
@@ -249,7 +263,11 @@ def paged_attention(
     is built once, otherwise it is built here.
     """
     B, T, Hq, Dh = q.shape
-    P, page, Hkv, Dh_pool = k.shape
+    if head_major:
+        assert k_scale is None, "the head-major pool is bf16 only"
+        P, Hkv, page, Dh_pool = k.shape
+    else:
+        P, page, Hkv, Dh_pool = k.shape
     Pmax = tables.shape[1]
     assert Hq % Hkv == 0, (Hq, Hkv)
     G = Hq // Hkv
@@ -270,7 +288,7 @@ def paged_attention(
 
     def pool_spec():
         return pl.BlockSpec(
-            (1, page, Hkv, Dh_pool),
+            (1, Hkv, page, Dh_pool) if head_major else (1, page, Hkv, Dh_pool),
             lambda i, row, pg, phys, pos: (phys[i], 0, 0, 0),
         )
 
@@ -308,6 +326,7 @@ def paged_attention(
         functools.partial(
             _kernel, scale=scale, page=page, hq=Hq, hkv=Hkv, g=G, t=T,
             s_max=S, quantized=quantized, packed=packed,
+            head_major=head_major,
         ),
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((B, T, Hq, Dh), q.dtype),

@@ -32,7 +32,12 @@ def main() -> None:
         AppConfig.print_help(sys.stdout.write)
         return
     jax_env.bootstrap()
-    web.run_app(create_app(), host=args.host, port=args.port)
+    # On SIGTERM, streams still open get 15 s (aiohttp's default is 60) and
+    # are then cancelled: an answer of thousands of tokens does not finish
+    # inside any grace period (planned handover is POST /internal/drain),
+    # and a handler still waiting for its first token would hold the
+    # process, and whoever waits for its exit, for the whole of it.
+    web.run_app(create_app(), host=args.host, port=args.port, shutdown_timeout=15.0)
 
 
 if __name__ == "__main__":

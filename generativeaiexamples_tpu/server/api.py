@@ -424,6 +424,14 @@ async def _aiter_threaded(
                 q.get_nowait()
             except queue_mod.Empty:
                 break
+        # A handler cancelled while it waited for an item (server shutdown
+        # before a stream's first token) leaves an executor worker parked
+        # in q.get() for good; nobody else reads this queue, so hand it
+        # the sentinel.
+        try:
+            q.put_nowait(_SENTINEL)
+        except queue_mod.Full:
+            pass
 
 
 @web.middleware

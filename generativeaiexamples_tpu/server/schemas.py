@@ -44,7 +44,10 @@ class Prompt(BaseModel):
     use_knowledge_base: bool = Field(...)
     temperature: float = Field(0.2, ge=0.1, le=1.0)
     top_p: float = Field(0.7, ge=0.1, le=1.0)
-    max_tokens: int = Field(1024, ge=0, le=1024)
+    # The reference caps an answer at 1024 tokens (server.py:85); a reasoning
+    # model answers in thousands. The engine still ends a request at its slot's
+    # capacity (max_seq_len - prompt), whatever is asked here.
+    max_tokens: int = Field(1024, ge=0, le=32768)
     stop: List[str] = Field(default=[], max_length=256)
     # Additive (non-reference): per-request deadline budget override in
     # milliseconds; the X-Request-Deadline-Ms header wins over this, the

@@ -253,3 +253,42 @@ def test_warmup_tolerates_malformed_config(clean_app_env):
         assert start_background_warmup() is None
     finally:
         runtime.reset_runtime()
+
+
+def test_a_cancelled_stream_releases_the_executor_worker_that_waited_for_it():
+    """A handler cancelled before its stream's first item (server
+    shutdown) must not leave a default-executor worker parked in
+    ``q.get()``: with one worker, the next executor job still runs."""
+    import threading
+    from concurrent.futures import ThreadPoolExecutor
+
+    from generativeaiexamples_tpu.server.api import _aiter_threaded
+
+    never = threading.Event()
+
+    def silent():
+        never.wait(30)
+        yield "late"
+
+    async def _run():
+        loop.set_default_executor(ThreadPoolExecutor(max_workers=1))
+
+        async def consume():
+            async for _ in _aiter_threaded(silent(), None, None):
+                pass
+
+        task = asyncio.ensure_future(consume())
+        await asyncio.sleep(0.2)  # the one worker is now parked in q.get()
+        task.cancel()
+        try:
+            await task
+        except asyncio.CancelledError:
+            pass
+        return await asyncio.wait_for(loop.run_in_executor(None, lambda: "free"), timeout=5)
+
+    loop = asyncio.new_event_loop()  # (asyncio.run would join the executor, and hang where this fails)
+    try:
+        assert loop.run_until_complete(_run()) == "free"
+    finally:
+        never.set()
+        loop.close()
