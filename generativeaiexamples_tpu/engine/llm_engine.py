@@ -24,6 +24,7 @@ Architecture (TPU-first):
 """
 from __future__ import annotations
 
+import collections
 import dataclasses
 import itertools
 import queue
@@ -31,7 +32,7 @@ import random
 import threading
 import time
 import weakref
-from typing import Any, Dict, Generator, List, Optional, Sequence, Tuple
+from typing import Any, Callable, Dict, Generator, List, Optional, Sequence, Tuple
 
 import numpy as np
 
@@ -44,7 +45,12 @@ from generativeaiexamples_tpu.engine import request_snapshot as request_snapshot
 from generativeaiexamples_tpu.engine import scheduler as scheduler_mod
 from generativeaiexamples_tpu.engine import spec_decode as spec_decode_mod
 from generativeaiexamples_tpu.engine import telemetry as telemetry_mod
-from generativeaiexamples_tpu.engine.tokenizer import Tokenizer, load_tokenizer
+from generativeaiexamples_tpu.engine.tokenizer import (
+    IncrementalDecoder,
+    TokenBlock,
+    Tokenizer,
+    load_tokenizer,
+)
 from generativeaiexamples_tpu.utils import faults as faults_mod
 from generativeaiexamples_tpu.utils import flight_recorder
 from generativeaiexamples_tpu.utils import get_logger
@@ -73,6 +79,17 @@ _M_REQUESTS = _REG.counter(
 )
 _M_TOKENS = _REG.counter(
     "genai_engine_generated_tokens_total", "Tokens emitted by the decode loop."
+)
+_M_HANDOFFS = _REG.counter(
+    "genai_stream_handoffs_total",
+    "Items a token stream handed to its consumer (one per wake-up of "
+    "the stream; an SSE handler writes each with one send).",
+)
+_M_HANDOFF_TOKENS = _REG.counter(
+    "genai_stream_handoff_tokens_total",
+    "Token ids those hand-offs carried: over genai_stream_handoffs_total "
+    "it is tokens a hand-off, towards decode_block when the block "
+    "hand-off engages, 1 when every token wakes the stream.",
 )
 _M_DECODE_STEPS = _REG.counter(
     "genai_engine_decode_steps_total",
@@ -229,14 +246,49 @@ class SamplingParams:
     spec_decode: Optional[bool] = None
 
 
+class _TokenQueue:
+    """A request's ``out_queue``: generated ids in order, closed by
+    ``_END``. The reader puts one request's tokens of one readback in
+    ONE ``put_many`` and a stream takes whatever is held in ONE
+    ``take_all``, so the hand-off between the two threads is a block by
+    construction, not by luck of thread timing. ``get`` hands out one
+    item at a time (``queue.Empty`` on a timeout, as a queue.Queue)."""
+
+    def __init__(self) -> None:
+        self._items: "collections.deque[Optional[int]]" = collections.deque()
+        self._ready = threading.Condition(threading.Lock())
+
+    def put(self, item: Optional[int]) -> None:
+        self.put_many((item,))
+
+    def put_many(self, items: Sequence[Optional[int]]) -> None:
+        with self._ready:
+            self._items.extend(items)
+            self._ready.notify()
+
+    def _wait(self, timeout: Optional[float]) -> None:
+        if not self._ready.wait_for(lambda: self._items, timeout):
+            raise queue.Empty
+
+    def get(self, timeout: Optional[float] = None) -> Optional[int]:
+        with self._ready:
+            self._wait(timeout)
+            return self._items.popleft()
+
+    def take_all(self, timeout: Optional[float] = None) -> List[Optional[int]]:
+        with self._ready:
+            self._wait(timeout)
+            items = list(self._items)
+            self._items.clear()
+            return items
+
+
 @dataclasses.dataclass
 class _Request:
     rid: int
     prompt_ids: List[int]
     params: SamplingParams
-    out_queue: "queue.Queue[Optional[int]]" = dataclasses.field(
-        default_factory=lambda: queue.Queue()
-    )
+    out_queue: _TokenQueue = dataclasses.field(default_factory=_TokenQueue)
     slot: int = -1
     # Effective sampling seed: params.seed when given, else a fresh random
     # draw at submit time — unseeded requests must NOT share a key stream
@@ -274,6 +326,11 @@ class _Request:
     # emitted[-1] is exactly the token whose KV row has not been
     # written yet (engine/request_snapshot.py).
     emitted: List[int] = dataclasses.field(default_factory=list)
+    # The stream's backlog in two integers: ids the reader has put on
+    # out_queue, and ids a handler has written to its socket (None until
+    # a handler reports: a consumer without one has no backlog to read).
+    queued: int = 0
+    written: Optional[int] = None
     cancelled: bool = False
     finished: bool = False  # set by the reader thread once _END is queued
     error: Optional[BaseException] = None
@@ -282,15 +339,16 @@ class _Request:
 _END = None  # sentinel on out_queue
 
 
-def _next_stream_item(out_q, stall_s, deadline):
-    """One bounded wait for the next streamed item (iter_ids and
-    _stream_from). ``stall_s`` bounds the wait for THIS item only — the
-    stream_timeout_s stall semantics, where a healthy long stream never
-    times out. ``deadline`` is an absolute whole-stream budget
-    (per-request deadlines): expiry is checked BEFORE waiting, because a
-    decode emitting tokens faster than any get() floor never sees
-    queue.Empty and would otherwise outrun its budget to max_tokens.
-    Exactly one of the two is non-None."""
+def _next_stream_items(out_q, stall_s, deadline):
+    """One bounded wait for everything the stream holds next (iter_ids
+    and _stream_from): a list of ids, ``_END`` last when the stream is
+    over. ``stall_s`` bounds THIS wait only — the stream_timeout_s stall
+    semantics, where a healthy long stream never times out. ``deadline``
+    is an absolute whole-stream budget (per-request deadlines): expiry
+    is checked BEFORE waiting, because a decode emitting tokens faster
+    than any wait's floor never sees queue.Empty and would otherwise
+    outrun its budget to max_tokens. Exactly one of the two is
+    non-None."""
     if deadline is None:
         wait = stall_s
     else:
@@ -298,7 +356,7 @@ def _next_stream_item(out_q, stall_s, deadline):
         if wait <= 0:
             raise TimeoutError("LLM engine timed out")
     try:
-        return out_q.get(timeout=wait)
+        return out_q.take_all(timeout=wait)
     except queue.Empty:
         raise TimeoutError("LLM engine timed out") from None
 
@@ -1207,6 +1265,11 @@ class LLMEngine:
 
         self._free_slots = list(range(self.num_slots))  # guarded by self._lock
         self._slot_req: Dict[int, _Request] = {}  # guarded by self._lock
+        # Open text streams by id(req), from a stream's first wake-up to
+        # its close: what stream_backlog_tokens sums over. A stream
+        # outlives its slot while its consumer still drains. Single dict
+        # operations only, so no lock.
+        self._streams: Dict[int, _Request] = {}
         # FIFO admission queue (a deque lets unadmitted requests stay at
         # the FRONT across one-wave admission rounds).
         self._pending: "collections.deque[_Request]" = collections.deque()  # guarded by self._lock
@@ -2628,6 +2691,16 @@ class LLMEngine:
             "kv_pages_grid": self.num_slots * self._max_pages_per_slot,
         }
 
+    def _stream_backlog_tokens(self) -> int:
+        """Tokens the reader has emitted that no handler has written yet,
+        over the open streams whose handler reports (docs/streaming.md):
+        two integers a stream, read without a lock."""
+        return sum(
+            r.queued - r.written
+            for r in list(self._streams.values())
+            if r.written is not None
+        )
+
     def submit(
         self, prompt_ids: Sequence[int], params: Optional[SamplingParams] = None
     ) -> _Request:
@@ -2785,17 +2858,17 @@ class LLMEngine:
         deadline = None if timeout is None else time.time() + timeout
         try:
             while True:
-                item = _next_stream_item(req.out_queue, stall_s, deadline)
-                if item is _END:
-                    if req.error is not None:
-                        if isinstance(req.error, RequestPreempted):
-                            # Typed pass-through: the stream layer needs
-                            # the snapshot id to advertise a restore
-                            # target instead of a bare 5xx.
-                            raise req.error
-                        raise RuntimeError("LLM engine failed") from req.error
-                    return
-                yield item
+                for item in _next_stream_items(req.out_queue, stall_s, deadline):
+                    if item is _END:
+                        if req.error is not None:
+                            if isinstance(req.error, RequestPreempted):
+                                # Typed pass-through: the stream layer needs
+                                # the snapshot id to advertise a restore
+                                # target instead of a bare 5xx.
+                                raise req.error
+                            raise RuntimeError("LLM engine failed") from req.error
+                        return
+                    yield item
         finally:
             self.abort(req)
 
@@ -2833,65 +2906,58 @@ class LLMEngine:
         timeout: Optional[float],
         prior_ids: Optional[Sequence[int]] = None,
     ) -> Generator[str, None, None]:
-        out_q = req.out_queue
-        # Restored requests pre-seed the decode context with the tokens
-        # the dead engine already emitted, while `emitted` starts empty:
-        # the first delta therefore yields the full spooled prefix plus
-        # the new token with exact tokenization boundaries (decode over
-        # the complete id list — no seam artifacts at the restore
-        # point). The router trims the re-delivered prefix against its
-        # forwarded-character offset.
-        ids: List[int] = list(prior_ids) if prior_ids else []
-        emitted = ""
-        stops = [s for s in params.stop if s]
+        """One item per wake-up: a ``TokenBlock`` of everything the
+        reader handed over since the last one, a delta a token, at the
+        same cost for the 3000th token as for the first
+        (docs/streaming.md). Restored requests pre-seed the decode
+        context with the tokens the dead engine already emitted, with
+        nothing delivered: the first delta yields the full spooled
+        prefix plus the new token with exact tokenization boundaries;
+        the router trims it against its forwarded-character offset."""
+        dec = IncrementalDecoder(self.tokenizer, prior_ids or (), params.stop)
         stall_s = (
             float(self.engine_config.stream_timeout_s) if timeout is None else None
         )
         deadline = None if timeout is None else time.time() + timeout
+
+        def written(n: int) -> None:
+            req.written = (req.written or 0) + n
+
+        taken = 0  # ids consumed since the last hand-off (one may add no text yet)
+        self._streams[id(req)] = req
         try:
             while True:
-                item = _next_stream_item(out_q, stall_s, deadline)
-                if item is _END:
-                    if req.error is not None:
-                        if isinstance(req.error, RequestPreempted):
-                            raise req.error
-                        raise RuntimeError("LLM engine failed") from req.error
-                    # Flush the held-back tail: a stream whose last bytes
-                    # form an incomplete UTF-8 sequence was suppressed by
-                    # the mid-codepoint guard below — without this flush
-                    # such answers arrive EMPTY (random-weight serving
-                    # ends mid-codepoint ~1/3 of the time; real chat
-                    # models can too when max_tokens truncates).
-                    text = self.tokenizer.decode(ids)
-                    if len(text) > len(emitted):
-                        found = [text.find(s) for s in stops]
-                        found = [i for i in found if i != -1]
-                        cut = min(found) if found else len(text)
-                        if cut > len(emitted):
-                            yield text[len(emitted):cut]
-                    break
-                ids.append(item)
-                text = self.tokenizer.decode(ids)
-                if text.endswith("�"):  # mid-codepoint; wait for more bytes
-                    continue
-                delta = text[len(emitted):]
-                if not delta:
-                    continue
-                candidate = emitted + delta
-                found = [candidate.find(s) for s in stops]
-                found = [i for i in found if i != -1]
-                hit = min(found) if found else -1
-                if hit != -1:
-                    final = candidate[:hit]
-                    if len(final) > len(emitted):
-                        yield final[len(emitted):]
+                items = _next_stream_items(req.out_queue, stall_s, deadline)
+                ended = items[-1] is _END
+                if ended:
+                    items.pop()
+                taken += len(items)
+                deltas = list(map(dec.push, items))
+                if ended and req.error is None:
+                    # Flush the held-back tail: a stream that ends inside
+                    # a multi-byte sequence (random weights do ~1/3 of
+                    # the time; max_tokens can truncate a real model
+                    # there) would otherwise arrive without it.
+                    deltas.append(dec.flush())
+                pieces = [d for d in deltas if d]
+                if pieces:
+                    _M_HANDOFFS.inc()
+                    _M_HANDOFF_TOKENS.inc(taken)
+                    yield TokenBlock(pieces, taken, written)
+                    taken = 0
+                if dec.stopped:
                     return
-                emitted = candidate
-                yield delta
+                if ended:
+                    if isinstance(req.error, RequestPreempted):
+                        raise req.error
+                    if req.error is not None:
+                        raise RuntimeError("LLM engine failed") from req.error
+                    return
         finally:
             # Consumer gone (disconnect/timeout/stop hit): abort releases
             # the slot and any prefix pins at the next dispatch pass
             # instead of burning steps to max_tokens.
+            self._streams.pop(id(req), None)
             self.abort(req)
 
     def chat(
@@ -3608,10 +3674,7 @@ class LLMEngine:
                 # pre-scheduler order); disagg imports completed
                 # handoffs from the prefill tier instead.
                 self.scheduler.admit()
-                with self._lock:
-                    busy = bool(self._slot_req)
-                if busy:
-                    self._decode_once()
+                self._decode_if_busy()
             except Exception as exc:  # noqa: BLE001
                 logger.exception("decode loop error: %s", exc)
                 with self._lock:
@@ -3621,6 +3684,15 @@ class LLMEngine:
                         req.out_queue.put(_END)
                         flight_recorder.finish_rid(req.rid, "error")
                         self._release(slot, req)
+
+    def _decode_if_busy(self) -> None:
+        """The loop's decode step: one block (or speculative round)
+        while any slot holds a request. The unified policy runs the same
+        step between the chunks of a wave (docs/scheduler.md)."""
+        with self._lock:
+            busy = bool(self._slot_req)
+        if busy:
+            self._decode_once()
 
     def _drain_releases(self) -> None:
         while True:
@@ -3781,6 +3853,7 @@ class LLMEngine:
         bucket: int,
         use_chunked: bool,
         register: bool = True,
+        between_chunks: Optional[Callable[[], None]] = None,
     ) -> List[object]:
         """Run one claimed wave's prefill mechanics.
 
@@ -3800,6 +3873,9 @@ class LLMEngine:
         the slot/position/budget shadows, the proposer context, and
         the KV pages whose ownership crosses to the decode tier; the
         decode loop registers them in ``_import_handoff``.
+
+        ``between_chunks`` is the policy's: what the dispatch thread
+        does between two chunk dispatches of a chunked wave.
         """
         import jax
         import jax.numpy as jnp
@@ -3917,7 +3993,7 @@ class LLMEngine:
                 if use_chunked:
                     first_tokens = self._prefill_chunked(
                         tokens, lengths, slots, temps, topps, seeds, cached,
-                        reqs=group,
+                        reqs=group, between_chunks=between_chunks,
                     )
                 else:
                     for req in group:
@@ -4281,7 +4357,7 @@ class LLMEngine:
             self._update_occupancy_gauges()
 
     def _prefill_chunked(self, tokens, lengths, slots, temps, topps, seeds,
-                         cached=None, reqs=None):
+                         cached=None, reqs=None, between_chunks=None):
         """Prefill a mixed-length wave as fixed-shape chunk dispatches.
 
         Each chunk k extends every row by up to prefill_chunk tokens at
@@ -4302,6 +4378,12 @@ class LLMEngine:
         ``reqs`` (the admitted wave, aligned with the first rows of
         ``tokens``) feeds the flight recorder one ``prefill_chunk``
         event per dispatched chunk per live row.
+
+        ``between_chunks`` is called between two chunk dispatches (not
+        after the last): the scheduler policy's step there, on this
+        thread. The wave's rows are not live yet, and the loop re-reads
+        ``self._cache`` inside the dispatch lock, so whatever it
+        dispatches joins the one cache chain.
         """
         import jax.numpy as jnp
 
@@ -4401,6 +4483,8 @@ class LLMEngine:
                             req.rid, "prefill_chunk", chunk=k, window=W,
                             tokens=int(valid[i]),
                         )
+            if between_chunks is not None and k < K - 1:
+                between_chunks()
         first = self._finish_fn(
             self.params,
             last_h,
@@ -4582,8 +4666,10 @@ class LLMEngine:
                     for p in self._slot_pos.values()
                 ),
             )
-            if state_fields is not None:
-                kv_pages = dict(kv_pages or {}, **state_fields)
+            span_counts = dict(
+                kv_pages or {}, **(state_fields or {}),
+                stream_backlog_tokens=self._stream_backlog_tokens(),
+            )
             for slot in self._slot_pos:
                 self._slot_pos[slot] += self._decode_block
             self._update_occupancy_gauges()
@@ -4672,7 +4758,7 @@ class LLMEngine:
                     if self._paged else None
                 ),
                 rids=[r.rid for _, r in snapshot],
-                counters=kv_pages,
+                counters=span_counts,
             )
         # Start the device→host transfer NOW so readbacks overlap both the
         # compute of later steps and each other.
@@ -5418,25 +5504,15 @@ class LLMEngine:
                 # token-by-token, exactly like slab overrun.
                 out_np, acc_np = handle
                 for slot, req in slots:
-                    if req.finished:
-                        continue
-                    for token in out_np[slot, : int(acc_np[slot]) + 1]:
-                        if req.finished:
-                            break
-                        req.position += 1
-                        self._emit(req, int(token))
+                    if not req.finished:
+                        self._emit(req, out_np[slot, : int(acc_np[slot]) + 1])
                 continue
             if kind == "spec_block":
                 # Zero-draft fallback slab, pre-fetched by the dispatch
                 # thread (which observed the real wait under
                 # kind="spec_block"): emit like a decode slab without
                 # injecting a bogus ~0 s decode-readback sample.
-                for row in handle:
-                    for slot, req in slots:
-                        if req.finished:
-                            continue
-                        req.position += 1
-                        self._emit(req, int(row[slot]))
+                self._emit_slab(np.asarray(handle), slots)
                 continue
             if kind == "drain_barrier":
                 # Drain quiesce point (the drain thread enqueues this
@@ -5472,49 +5548,68 @@ class LLMEngine:
                 values = np.atleast_1d(values)
                 for row, req in slots:
                     if not req.finished:
-                        self._emit(req, int(values[row]))
+                        self._emit(req, values[row : row + 1], advance=False)
                 continue
-            # decode: values is a [block, batch] slab, oldest step first.
-            for row in values:
-                for slot, req in slots:
-                    if req.finished:
-                        continue  # overran past this request's stop
-                    req.position += 1
-                    self._emit(req, int(row[slot]))
+            self._emit_slab(values, slots)
 
-    def _emit(self, req: _Request, token: int) -> None:
-        """Reader-thread token accounting; queues _END + frees the slot."""
+    def _emit_slab(self, slab: np.ndarray, slots) -> None:
+        """A decode slab ``[block, batch]``, oldest step first, walked
+        request by request: a row's tokens of one slab reach its stream
+        in ONE put. A request that finished in an earlier slab overran
+        past its stop and is skipped."""
+        for slot, req in slots:
+            if not req.finished:
+                self._emit(req, slab[:, slot])
+
+    def _emit(self, req: _Request, tokens: np.ndarray, advance: bool = True) -> None:
+        """Reader-thread accounting of one request's tokens of one
+        readback, in order: each counted as before (position, stop ids,
+        ``max_tokens``, the latency histograms), all handed to the
+        stream in one put; queues _END + frees the slot. Tokens past the
+        one that ends the request are dropped. ``advance=False``: a
+        prefill's first token, whose position the admission counted."""
         stop_ids = self._stop_ids
-        req.generated += 1
-        req.emitted.append(int(token))
-        _M_TOKENS.inc()
-        now = time.time()
-        if req.generated == 1 and req.t_submit:
-            ttft = now - req.t_submit
-            _M_TTFT.observe(ttft, trace_id=req.trace_hex)
-            _M_PREFILL_WAIT.observe(
-                now - (req.t_admit or req.t_submit), trace_id=req.trace_hex
+        block: List[Optional[int]] = []
+        done = False
+        for token in tokens.tolist():
+            if advance:
+                req.position += 1
+            req.generated += 1
+            req.emitted.append(token)
+            now = time.time()
+            if req.generated == 1 and req.t_submit:
+                ttft = now - req.t_submit
+                _M_TTFT.observe(ttft, trace_id=req.trace_hex)
+                _M_PREFILL_WAIT.observe(
+                    now - (req.t_admit or req.t_submit), trace_id=req.trace_hex
+                )
+                slo_mod.observe_latency("ttft_p95", ttft)
+                flight_recorder.event_rid(
+                    req.rid, "first_token", ttft_s=round(ttft, 6)
+                )
+            elif req.t_last_token:
+                itl = now - req.t_last_token
+                _M_TOKEN_LATENCY.observe(itl, trace_id=req.trace_hex)
+                slo_mod.observe_latency("inter_token_p95", itl)
+            req.t_last_token = now
+            done = (
+                token in stop_ids
+                or req.generated >= req.params.max_tokens
+                or req.position >= self.max_seq_len - 1
+                or req.cancelled
             )
-            slo_mod.observe_latency("ttft_p95", ttft)
-            flight_recorder.event_rid(
-                req.rid, "first_token", ttft_s=round(ttft, 6)
-            )
-        elif req.t_last_token:
-            itl = now - req.t_last_token
-            _M_TOKEN_LATENCY.observe(itl, trace_id=req.trace_hex)
-            slo_mod.observe_latency("inter_token_p95", itl)
-        req.t_last_token = now
-        done = (
-            token in stop_ids
-            or req.generated >= req.params.max_tokens
-            or req.position >= self.max_seq_len - 1
-            or req.cancelled
-        )
-        if token not in stop_ids:
-            req.out_queue.put(token)
+            if token not in stop_ids:
+                block.append(token)
+            if done:
+                break
+        req.queued += len(block)
+        _M_TOKENS.inc(len(block) + (done and token in stop_ids))
         if done:
             req.finished = True
-            req.out_queue.put(_END)
+            block.append(_END)
+        if block:
+            req.out_queue.put_many(block)
+        if done:
             # The reader's own count and stop reason: the eager
             # decode_leave event fires at dispatch time, before the
             # reader has counted the block's tokens.

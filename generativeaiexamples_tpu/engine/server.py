@@ -211,22 +211,28 @@ class ModelServer:
 
         resp = web.StreamResponse(headers={"Content-Type": "text/event-stream"})
         await resp.prepare(request)
+        from generativeaiexamples_tpu.engine.tokenizer import pieces_of
         from generativeaiexamples_tpu.server.api import _aiter_threaded
 
         first = True
         async for chunk in _aiter_threaded(gen):
-            delta: Dict[str, Any] = {"content": chunk}
-            if first:
-                delta["role"] = "assistant"
-                first = False
-            frame = {
-                "id": rid,
-                "object": "chat.completion.chunk",
-                "created": _now(),
-                "model": self._model_name,
-                "choices": [{"index": 0, "delta": delta, "finish_reason": None}],
-            }
-            await resp.write(f"data: {json.dumps(frame)}\n\n".encode())
+            # one frame a token, a hand-off's frames in one write
+            # (docs/streaming.md)
+            frames = []
+            for piece in pieces_of(chunk):
+                delta: Dict[str, Any] = {"content": piece}
+                if first:
+                    delta["role"] = "assistant"
+                    first = False
+                frame = {
+                    "id": rid,
+                    "object": "chat.completion.chunk",
+                    "created": _now(),
+                    "model": self._model_name,
+                    "choices": [{"index": 0, "delta": delta, "finish_reason": None}],
+                }
+                frames.append(f"data: {json.dumps(frame)}\n\n")
+            await resp.write("".join(frames).encode())
         final = {
             "id": rid,
             "object": "chat.completion.chunk",

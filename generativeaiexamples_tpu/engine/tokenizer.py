@@ -191,6 +191,91 @@ class HFTokenizer:
 # chains/runtime.py re-exports.
 
 
+class TokenBlock(str):
+    """The text of one stream hand-off that still knows its per-token
+    deltas. Its value is ``"".join(pieces)``, so a consumer that treats
+    a chunk as text (``"".join``, a chain's wrapper) needs no change;
+    the SSE handlers write one frame per piece. ``n_tokens`` ids went
+    into it (an id may add no text yet), and ``written()`` tells the
+    engine that a handler has put them on the wire."""
+
+    __slots__ = ("pieces", "n_tokens", "_ack")
+
+    def __new__(cls, pieces: Sequence[str], n_tokens: int = 0, ack=None):
+        self = super().__new__(cls, "".join(pieces))
+        self.pieces = pieces
+        self.n_tokens = n_tokens
+        self._ack = ack
+        return self
+
+    def written(self) -> None:
+        if self._ack is not None:
+            self._ack(self.n_tokens)
+
+
+def pieces_of(chunk: str) -> Sequence[str]:
+    """A chunk's per-token deltas: a ``TokenBlock``'s pieces, and a
+    plain ``str`` is its own one piece (one SSE frame each)."""
+    return getattr(chunk, "pieces", None) or (chunk,)
+
+
+class IncrementalDecoder:
+    """The text of a growing id list at constant cost a token: the usual
+    two-window scheme. ``ids[prefix:read]`` is text already delivered
+    that gives the new ids their context (a leading space, a cleanup
+    rule, a byte of a split character); a delta is what
+    ``decode(ids[prefix:])`` adds to ``decode(ids[prefix:read])``, and
+    both offsets advance only when the text does not end in U+FFFD (an
+    incomplete multi-byte sequence is held back). ``prior_ids`` seed the
+    context with nothing delivered, so the first delta carries their
+    text too (a restored stream's contract). The text ends before the
+    first of ``stops``: the search looks at the new delta behind the
+    last ``max(len(stop)) - 1`` delivered characters, in which a stop
+    string may have begun; after it ``stopped`` is set and nothing more
+    is delivered."""
+
+    def __init__(self, tokenizer: "Tokenizer", prior_ids: Sequence[int] = (),
+                 stops: Sequence[str] = ()):
+        self._decode = tokenizer.decode
+        self.ids: List[int] = list(prior_ids)
+        self._prefix = 0
+        self._read = 0
+        self._stops = [s for s in stops if s]
+        self._keep = max(map(len, self._stops), default=1) - 1
+        self._tail = ""
+        self.stopped = False
+
+    def push(self, token: int) -> str:
+        """Add one id; the text it completes, or "" while it is held."""
+        self.ids.append(token)
+        return self._delta(False)
+
+    def flush(self) -> str:
+        """The held-back tail at the end of a stream (an answer that
+        ends inside a multi-byte character still delivers it)."""
+        return self._delta(True)
+
+    def _delta(self, flush: bool) -> str:
+        if self.stopped:
+            return ""
+        ids = self.ids
+        seen = len(self._decode(ids[self._prefix:self._read]))
+        text = self._decode(ids[self._prefix:])
+        if len(text) <= seen or (text.endswith("\ufffd") and not flush):
+            return ""
+        self._prefix, self._read = self._read, len(ids)
+        if not self._stops:
+            return text[seen:]
+        text = self._tail + text[seen:]
+        hits = [i for i in (text.find(s) for s in self._stops) if i != -1]
+        if hits:
+            self.stopped = True
+            return text[len(self._tail):min(hits)]
+        delta = text[len(self._tail):]
+        self._tail = text[-self._keep:] if self._keep else ""
+        return delta
+
+
 @functools.lru_cache(maxsize=512)
 def _encode_lru(tokenizer, text: str, add_bos: bool) -> Tuple[int, ...]:
     return tuple(tokenizer.encode(text, add_bos=add_bos))

@@ -21,8 +21,10 @@ its own thread inside the engine and is never blocked by slow SSE consumers.
 from __future__ import annotations
 
 import asyncio
+import html
+import json
 import os
-import queue as queue_mod
+import re
 import threading
 from pathlib import Path
 from typing import Any, AsyncIterator, Callable, Generator, Optional, Type
@@ -43,6 +45,7 @@ from generativeaiexamples_tpu.server.schemas import (
     DocumentSearchResponse,
     DocumentsResponse,
     HealthResponse,
+    MAX_CONTENT_LEN,
     Message,
     Prompt,
 )
@@ -55,6 +58,7 @@ from generativeaiexamples_tpu.server.observability import (
     metrics_middleware,
 )
 from generativeaiexamples_tpu.engine import dispatch_timeline
+from generativeaiexamples_tpu.engine.tokenizer import pieces_of
 from generativeaiexamples_tpu.utils import blackbox
 from generativeaiexamples_tpu.utils import faults as faults_mod
 from generativeaiexamples_tpu.utils import flight_recorder
@@ -95,18 +99,38 @@ def _sse_frame(resp: ChainResponse) -> str:
     return "data: " + resp.model_dump_json(exclude_none=True) + "\n\n"
 
 
-def _chunk_frame(resp_id: str, chunk: str, finish_reason: str = "") -> str:
-    resp = ChainResponse(
-        id=resp_id,
-        choices=[
-            ChainResponseChoices(
-                index=0,
-                message=Message(role="assistant", content=chunk),
-                finish_reason=finish_reason,
-            )
-        ],
-    )
-    return _sse_frame(resp)
+# What the Message schema's sanitizer (bleach) rewrites: markup and
+# html5lib's control characters. Text without them is its own clean form.
+_SANITIZER_REWRITES = re.compile("[\x00-\x08\x0b-\x1f&<>\x7f-\x9f\ud800-\udfff]")
+
+
+def _wire_text(piece: str) -> str:
+    """An answer piece as the Message schema holds it, at the schema's
+    cost only where it would differ. One wire change (docs/streaming.md):
+    a piece the sanitizer erases WHOLE (a token that reads as one tag,
+    "<unk>") goes out escaped, as it does when it comes as three tokens."""
+    if len(piece) <= MAX_CONTENT_LEN and not _SANITIZER_REWRITES.search(piece):
+        return piece
+    content = Message(role="assistant", content=piece).content
+    return content or Message(role="assistant", content=html.escape(piece)).content
+
+
+def _chunk_frames(resp_id: str) -> Callable[[Any], bytes]:
+    """A response's frame builder: a chunk in, the bytes of its frames
+    out, one per token (a ``TokenBlock``'s pieces; a plain ``str`` is
+    one). The id is fixed, so a frame is the head and tail of the
+    schema's own JSON around the text's."""
+    head, tail = _sse_frame(ChainResponse(id=resp_id, choices=[ChainResponseChoices(
+        index=0, message=Message(role="assistant", content="@"), finish_reason="",
+    )])).rsplit('"@"', 1)
+
+    def frames(chunk: Any) -> bytes:
+        return "".join(
+            head + json.dumps(_wire_text(piece), ensure_ascii=False) + tail
+            for piece in pieces_of(chunk)
+        ).encode()
+
+    return frames
 
 
 def _warning_frame(resp_id: str, warning: str) -> str:
@@ -256,8 +280,9 @@ async def restore_snapshot_handler(request: web.Request) -> web.StreamResponse:
     resp_id = str(uuid4())
     try:
         gen = eng.stream_restored(req, params, prior_ids)
+        frames = _chunk_frames(resp_id)
         async for chunk in _aiter_threaded(gen, trace_ctx, flight_rec=rec):
-            await resp.write(_chunk_frame(resp_id, chunk).encode())
+            await resp.write(frames(chunk))
         await resp.write(
             _sse_frame(
                 ChainResponse(
@@ -360,24 +385,30 @@ async def _aiter_threaded(
 ) -> AsyncIterator[Any]:
     """Drive a synchronous generator on a worker thread, yielding via asyncio.
 
-    The bounded queue applies backpressure to the producer when the SSE
-    consumer is slow, without ever blocking the event loop. If the consumer
-    goes away mid-stream (client disconnect), the stop flag unblocks the
-    producer and the generator is closed so chain/engine resources are
-    released rather than leaking a parked thread per disconnect.
+    Items reach the event loop by ``call_soon_threadsafe``: no executor
+    worker waits per stream (docs/streaming.md). A bounded count of
+    items in flight applies backpressure to the producer when the SSE
+    consumer is slow, without ever blocking the event loop. If the
+    consumer goes away mid-stream (client disconnect, a cancelled
+    handler), the stop flag unblocks the producer and the generator is
+    closed so chain/engine resources are released rather than leaking a
+    parked thread per disconnect.
     """
     loop = asyncio.get_running_loop()
-    q: queue_mod.Queue = queue_mod.Queue(maxsize=64)
+    q: asyncio.Queue = asyncio.Queue()
+    room = threading.Semaphore(64)  # items handed over and not yet taken
     stop = threading.Event()
 
     def _put(item: Any) -> bool:
-        while not stop.is_set():
-            try:
-                q.put(item, timeout=0.1)
-                return True
-            except queue_mod.Full:
-                continue
-        return False
+        room.acquire()
+        if stop.is_set():
+            room.release()  # pass the wake-up on: no later put may wait
+            return False
+        try:
+            loop.call_soon_threadsafe(q.put_nowait, item)
+        except RuntimeError:  # the loop closed under a stream (shutdown)
+            return False
+        return True
 
     def _produce() -> None:
         get_tracer().attach_context(trace_ctx)
@@ -411,27 +442,20 @@ async def _aiter_threaded(
     thread.start()
     try:
         while True:
-            item = await loop.run_in_executor(None, q.get)
+            item = await q.get()
+            room.release()
             if item is _SENTINEL:
                 return
             if isinstance(item, BaseException):
                 raise item
             yield item
+            # the handler has written it: a TokenBlock tells the engine
+            written = getattr(item, "written", None)
+            if written is not None:
+                written()
     finally:
         stop.set()
-        while not q.empty():  # unblock a producer parked on a full queue
-            try:
-                q.get_nowait()
-            except queue_mod.Empty:
-                break
-        # A handler cancelled while it waited for an item (server shutdown
-        # before a stream's first token) leaves an executor worker parked
-        # in q.get() for good; nobody else reads this queue, so hand it
-        # the sentinel.
-        try:
-            q.put_nowait(_SENTINEL)
-        except queue_mod.Full:
-            pass
+        room.release()  # wake a producer that waits for room
 
 
 @web.middleware
@@ -766,6 +790,7 @@ class ChainServer:
         )
         await resp.prepare(request)
         resp_id = str(uuid4())
+        frames = _chunk_frames(resp_id)
         degraded_seen = False
         try:
             if generator:
@@ -784,9 +809,12 @@ class ChainServer:
                         )
                         continue
                     if span is not None:
-                        # per-token events, reference: opentelemetry_callback.py:248
-                        span.add_event("llm.new_token", {"length": len(chunk)})
-                    await resp.write(_chunk_frame(resp_id, chunk).encode())
+                        # one event a hand-off with its token count (one a
+                        # token in the reference: opentelemetry_callback.py:248)
+                        span.add_event("llm.new_token", {
+                            "length": len(chunk), "count": len(pieces_of(chunk)),
+                        })
+                    await resp.write(frames(chunk))
                 await resp.write(
                     _sse_frame(
                         ChainResponse(

@@ -17,6 +17,8 @@ import threading
 import time
 import types
 
+import numpy as np
+
 from aiohttp.test_utils import TestClient, TestServer
 
 from generativeaiexamples_tpu.chains.echo import EchoChain
@@ -276,8 +278,8 @@ def test_engine_histograms_carry_trace_exemplars():
             _release_q=queue.Queue(),
             _lock=threading.Condition(),
         )
-        llm_engine.LLMEngine._emit(stub, req, 5)
-        llm_engine.LLMEngine._emit(stub, req, 6)
+        llm_engine.LLMEngine._emit(stub, req, np.array([5]))
+        llm_engine.LLMEngine._emit(stub, req, np.array([6]))
         for hist in (
             llm_engine._M_QUEUE_WAIT,
             llm_engine._M_TTFT,

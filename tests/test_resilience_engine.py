@@ -202,23 +202,56 @@ def test_aiter_threaded_disconnect_aborts_engine_request(eng):
     )
 
 
+def test_engine_stream_hands_over_blocks_and_its_spans_carry_the_backlog(eng):
+    """The real engine behind _aiter_threaded (docs/streaming.md): with
+    decode_block=4 a stream's tokens arrive several a hand-off, every
+    id is reported written once its handler comes back, and decode
+    dispatch spans carry stream_backlog_tokens."""
+    from generativeaiexamples_tpu.engine import dispatch_timeline as dtl
+    from generativeaiexamples_tpu.engine.tokenizer import TokenBlock
+    from generativeaiexamples_tpu.server.api import _aiter_threaded
+
+    since = dtl.cursor()
+    h0, t0 = llm_engine._M_HANDOFFS.value, llm_engine._M_HANDOFF_TOKENS.value
+    seen = []
+
+    async def drive():
+        gen = eng.stream_text(PROMPT, SamplingParams(temperature=0.0, max_tokens=40))
+        async for chunk in _aiter_threaded(gen):
+            seen.extend(eng._streams.values())
+            assert isinstance(chunk, TokenBlock)
+            await asyncio.sleep(0.005)  # a slow socket: the reader gets ahead
+
+    asyncio.run(drive())
+    req = seen[0]
+    assert all(r is req for r in seen)
+    handoffs = llm_engine._M_HANDOFFS.value - h0
+    tokens = llm_engine._M_HANDOFF_TOKENS.value - t0
+    assert req.queued == tokens == req.written  # nothing left behind
+    assert tokens >= 30 and tokens / handoffs >= 2  # blocks, not tokens
+    assert not eng._streams
+    spans, _ = dtl.spans_since(since)
+    backlogs = [v["stream_backlog_tokens"] for v in spans if v["kind"] == "decode"]
+    assert backlogs and all(0 <= b <= 40 for b in backlogs)
+
+
 def test_stream_timeout_modes_stall_vs_absolute():
     """timeout=None applies stream_timeout_s as a STALL deadline per
     awaited token — a healthy stream longer than the knob completes —
     while an explicit timeout is an absolute whole-stream budget that
     terminates even a fast, never-stalling stream (per-request
     deadlines). Pure host: drives _stream_from with a scripted queue."""
-    import queue as queue_mod
     from types import SimpleNamespace
 
     stub = LLMEngine.__new__(LLMEngine)
     stub.engine_config = SimpleNamespace(stream_timeout_s=1.0)
     stub.tokenizer = SimpleNamespace(decode=lambda ids: "x" * len(ids))
     stub.abort = lambda req: None
+    stub._streams = {}
     params = SamplingParams(temperature=0.0, max_tokens=8)
 
     def scripted_req(n_tokens, interval, end):
-        req = SimpleNamespace(out_queue=queue_mod.Queue(), error=None)
+        req = SimpleNamespace(out_queue=llm_engine._TokenQueue(), error=None)
 
         def feed():
             for _ in range(n_tokens):

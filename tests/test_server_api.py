@@ -255,20 +255,27 @@ def test_warmup_tolerates_malformed_config(clean_app_env):
         runtime.reset_runtime()
 
 
-def test_a_cancelled_stream_releases_the_executor_worker_that_waited_for_it():
+def test_a_cancelled_stream_leaves_no_worker_and_no_producer_behind():
     """A handler cancelled before its stream's first item (server
-    shutdown) must not leave a default-executor worker parked in
-    ``q.get()``: with one worker, the next executor job still runs."""
+    shutdown) holds nothing: no default-executor worker ever waited for
+    the stream (with one worker, the next executor job runs at once),
+    and the producer, once its generator moves, finds the stop flag
+    instead of a queue to wait on, closes the generator and ends."""
     import threading
     from concurrent.futures import ThreadPoolExecutor
 
     from generativeaiexamples_tpu.server.api import _aiter_threaded
 
     never = threading.Event()
+    closed = threading.Event()
 
     def silent():
-        never.wait(30)
-        yield "late"
+        try:
+            never.wait(30)
+            yield "late"
+            yield "later"
+        finally:
+            closed.set()
 
     async def _run():
         loop.set_default_executor(ThreadPoolExecutor(max_workers=1))
@@ -278,17 +285,19 @@ def test_a_cancelled_stream_releases_the_executor_worker_that_waited_for_it():
                 pass
 
         task = asyncio.ensure_future(consume())
-        await asyncio.sleep(0.2)  # the one worker is now parked in q.get()
+        await asyncio.sleep(0.2)  # the handler now waits for its first item
         task.cancel()
         try:
             await task
         except asyncio.CancelledError:
             pass
-        return await asyncio.wait_for(loop.run_in_executor(None, lambda: "free"), timeout=5)
+        free = await asyncio.wait_for(loop.run_in_executor(None, lambda: "free"), timeout=5)
+        never.set()
+        return free, await loop.run_in_executor(None, closed.wait, 5)
 
     loop = asyncio.new_event_loop()  # (asyncio.run would join the executor, and hang where this fails)
     try:
-        assert loop.run_until_complete(_run()) == "free"
+        assert loop.run_until_complete(_run()) == ("free", True)
     finally:
         never.set()
         loop.close()
