@@ -236,7 +236,7 @@ def check_metrics_text(text: str, gather_explained: bool = False, before=None) -
 
 
 _KERNEL_LINE = re.compile(
-    r"resolved kernel paths: quant_kernel=(\S+) kv_kernel=(\S+) "
+    r"resolved kernel paths: quant_kernel=(\S+) "
     r"paged_kernel=(\S+) paged_verify_kernel=(\S+) tp_kernels=(\S+) "
     r"\(backend=(\w+), devices=(\d+)\)"
 )
@@ -255,9 +255,9 @@ def check_server_log(text: str, want_compiled: bool, tp: int = 1) -> dict:
     check("COMPILE ON HOT PATH" not in text, "server log reports a hot-path compile")
     m = _KERNEL_LINE.search(text)
     check(m is not None, "server log has no 'resolved kernel paths' line")
-    quant, kv, paged, verify, tpk, backend, devices = m.groups()
+    quant, paged, verify, tpk, backend, devices = m.groups()
     paths = {
-        "quant_kernel": quant, "kv_kernel": kv, "paged_kernel": paged,
+        "quant_kernel": quant, "paged_kernel": paged,
         "paged_verify_kernel": verify, "tp_kernels": tpk,
         "backend": backend, "devices": int(devices),
     }
@@ -432,24 +432,13 @@ def phase_kernels(args) -> int:
         ref = mm.int8_matmul_xla(x, q, scale)
         report(f"int8_matmul M=16 K={K} F={F}", rel(out, ref), TOL_MM, time.time() - t0)
 
-    # 2. int8-KV decode attention (fixed head-major cache)
-    B, Hq, Hkv, Dh, S = (16, 32, 8, 128, 4096) if full else (2, 8, 2, 128, 256)
-    t0 = time.time()
-    qd = jnp.asarray(rng.standard_normal((B, Hq, Dh)), jnp.bfloat16)
-    kq = jnp.asarray(rng.integers(-127, 128, (B, Hkv, S, Dh)), jnp.int8)
-    vq = jnp.asarray(rng.integers(-127, 128, (B, Hkv, S, Dh)), jnp.int8)
-    ks = jnp.asarray(rng.uniform(0.005, 0.02, (B, Hkv, 1, S)), jnp.float32)
-    vs = jnp.asarray(rng.uniform(0.005, 0.02, (B, Hkv, 1, S)), jnp.float32)
-    pos = jnp.asarray(rng.integers(1, S, (B,)), jnp.int32)
-    out = da.decode_attention(qd, kq, ks, vq, vs, pos, interpret=interpret)
-    ref = da.decode_attention_xla(qd[:, None], kq, ks, vq, vs, pos[:, None])[:, 0]
-    report(f"decode_attention B={B} Hq={Hq} Hkv={Hkv} S={S}", rel(out, ref), TOL_ATTN, time.time() - t0)
-
-    # 3. ragged page attention over an int8 pool (the served layout),
+    # 2. ragged page attention over an int8 pool (the served cache),
     #    against the XLA gather the engine falls back to
+    B, Hq, Hkv, Dh, S = (16, 32, 8, 128, 4096) if full else (2, 8, 2, 128, 256)
     page = 128 if full else 16
     Pmax = S // page
     t0 = time.time()
+    pos = jnp.asarray(rng.integers(1, S, (B,)), jnp.int32)
     n_pages = B * Pmax + 1
     pk = jnp.asarray(rng.integers(-127, 128, (n_pages, page, Hkv, Dh)), jnp.int8)
     pv = jnp.asarray(rng.integers(-127, 128, (n_pages, page, Hkv, Dh)), jnp.int8)
@@ -470,7 +459,7 @@ def phase_kernels(args) -> int:
     )
     report(f"paged_attention int8 B={B} page={page} Pmax={Pmax}", rel(out, ref), TOL_ATTN, time.time() - t0)
 
-    # 4. flash prefill against the einsum attention
+    # 3. flash prefill against the einsum attention
     T = 2048 if full else 128
     t0 = time.time()
     fq = jnp.asarray(rng.standard_normal((1, T, Hq, Dh)), jnp.bfloat16)
@@ -999,7 +988,7 @@ def main() -> int:
             say(f"server phase: {stats['answers']} answered /generate; "
                 f"engine build {stats['engine_build_s']:.1f} s, warm-up/compile {stats['warmup_s']:.1f} s "
                 f"({'warm' if entries_before else 'cold'} cache, {entries_before} -> {entries_after} entries)")
-            say(f"resolved kernel paths: quant_kernel={stats['quant_kernel']} kv_kernel={stats['kv_kernel']} "
+            say(f"resolved kernel paths: quant_kernel={stats['quant_kernel']} "
                 f"paged_kernel={stats['paged_kernel']} paged_verify_kernel={stats['paged_verify_kernel']} "
                 f"(backend={stats['backend']}, devices={stats['devices']})")
             say(f"peak HBM after warm-up, from memory_stats(): {stats['device_memory']}")

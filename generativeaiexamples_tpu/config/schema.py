@@ -306,15 +306,6 @@ class EngineConfig(ConfigWizard):
         help_txt="Size of the model mesh axis; -1 uses all local devices "
         "(TPU analogue of NIM's INFERENCE_GPU_COUNT).",
     )
-    pipeline_parallelism: int = configfield(
-        "pipeline_parallelism",
-        default=1,
-        help_txt="Size of the pipe mesh axis (serving stage count; the "
-        "TPU analogue of NeMo's pipeline_model_parallel). 1 disables "
-        "pipelining; the engine also auto-selects PP when the "
-        "architecture caps tensor parallelism below the device count "
-        "and the TP-only fit would exceed HBM (parallel/pp_serving.py).",
-    )
     dtype: str = configfield(
         "dtype",
         default="bfloat16",
@@ -332,21 +323,11 @@ class EngineConfig(ConfigWizard):
     kv_cache_dtype: str = configfield(
         "kv_cache_dtype",
         default="bfloat16",
-        help_txt="KV cache storage: bfloat16, int8 (halves cache HBM, roughly "
-        "doubling slot capacity; served by the Pallas decode-attention kernel "
-        "with per-slot cache windows on a single TPU device, and by the XLA "
-        "dequant path on TP meshes), or int4 (paged layout only — packs two "
-        "values per byte in the page pool, halving KV bytes again; "
-        "page-granular scales, same exact-operand kernel discipline).",
-    )
-    serving_layout: str = configfield(
-        "serving_layout",
-        default="auto",
-        help_txt="Weight/cache layout for serving: 'layered' (per-layer "
-        "buffers, unrolled loop — no scan-slice HBM copies), 'scan' (stacked "
-        "buffers, one compiled layer body — faster compiles), or 'auto' "
-        "(layered on a single device or whenever kv_cache_dtype=int8, "
-        "scan otherwise).",
+        help_txt="KV page-pool storage: bfloat16, int8 (halves cache HBM, "
+        "roughly doubling slot capacity; read by the Pallas page-attention "
+        "kernel where the geometry allows, by the XLA dequant gather "
+        "elsewhere), or int4 (packs two values per byte, halving KV bytes "
+        "again; page-granular scales, same exact-operand kernel discipline).",
     )
     max_batch_size: int = configfield(
         "max_batch_size",
@@ -358,26 +339,11 @@ class EngineConfig(ConfigWizard):
         default=8192,
         help_txt="KV-cache sequence capacity per slot (Llama-3 native window).",
     )
-    kv_layout: str = configfield(
-        "kv_layout",
-        default="auto",
-        help_txt="KV-cache layout: 'auto' (the default — resolves to "
-        "'paged' whenever the layered serving layout with chunked "
-        "prefill is in play and the page geometry divides cleanly, "
-        "'fixed' otherwise: scan/PP paths, page-misaligned "
-        "max_seq_len/prefill_chunk), 'paged' (page-granular allocation "
-        "over a shared device pool with ragged attention served by the "
-        "Pallas page kernel where geometry allows — else the XLA "
-        "gather — per-request page tables, and zero-copy prefix-cache "
-        "sharing via refcounted pages — docs/paged_kv.md), or 'fixed' "
-        "(dense per-slot max_seq_len strips, the exact pre-paged "
-        "dispatch path). Streams are token-identical between layouts.",
-    )
     paged_kernel: str = configfield(
         "paged_kernel",
         default="auto",
-        help_txt="Ragged Pallas page-attention kernel under "
-        "kv_layout='paged' (ops/page_attention.py): 'auto' compiles it "
+        help_txt="Ragged Pallas page-attention kernel over the KV page "
+        "pool (ops/page_attention.py): 'auto' compiles it "
         "on a single TPU device — or shard_map-wrapped over the model "
         "mesh axis on a TP mesh (heads shard, page tables replicate) — "
         "when ops.page_attention.supports_geometry accepts the "
@@ -390,25 +356,31 @@ class EngineConfig(ConfigWizard):
     page_size: int = configfield(
         "page_size",
         default=128,
-        help_txt="Tokens per KV-cache page under kv_layout='paged': a "
+        help_txt="Tokens per KV-cache page (docs/paged_kv.md): a "
         "power of two <= 128 dividing prefill_chunk (chunk-aligned "
         "prefix-cache entries must be page-aligned for zero-copy "
-        "sharing) and the effective max_seq_len.",
+        "sharing) and the effective max_seq_len; any other geometry is "
+        "refused at start-up.",
     )
     kv_pool_pages: int = configfield(
         "kv_pool_pages",
         default=0,
-        help_txt="Device page-pool size (pages) under kv_layout="
-        "'paged'. 0 auto-sizes to HBM parity with the fixed layout: "
-        "one full-capacity strip per decode slot plus one per "
-        "prefix-cache store slot, plus the reserved scratch page. "
+        help_txt="Device page-pool size (pages). 0 auto-sizes to "
+        "one full-capacity strip of pages per decode slot plus one per "
+        "prefix-cache entry, plus the reserved scratch page. "
         "Larger pools admit more concurrent mixed-length requests at "
         "the same per-request capacity.",
     )
     prefill_chunk: int = configfield(
         "prefill_chunk",
         default=512,
-        help_txt="Prefill length bucket; prompts are right-padded to a multiple of this.",
+        help_txt="Prefill chunk (engine tokens): a prompt of one chunk "
+        "prefills in one padded dispatch; a longer one runs as repeated "
+        "fixed-shape chunk dispatches against its pages, so the "
+        "compiled-shape set is bounded (wave sizes x attention windows) "
+        "and NO prompt length can trigger an XLA compile inside a "
+        "request (reference analogue: TRT-LLM chunked context). A "
+        "multiple of page_size.",
     )
     warmup_prompt_lengths: str = configfield(
         "warmup_prompt_lengths",
@@ -421,29 +393,16 @@ class EngineConfig(ConfigWizard):
         "chains set this near the context-capped prompt size, e.g. "
         "'2048,2560'.",
     )
-    chunked_prefill: str = configfield(
-        "chunked_prefill",
-        default="auto",
-        help_txt="Chunked prefill ('auto' or 'off'). In auto, prompts "
-        "longer than prefill_chunk are prefilled as repeated fixed-shape "
-        "chunk dispatches against the slot cache instead of one "
-        "length-bucketed executable — the compiled-shape set becomes "
-        "bounded (wave sizes x attention windows), so NO prompt length "
-        "can trigger an XLA compile inside a request, and admission "
-        "waves can mix prompt lengths (reference analogue: TRT-LLM "
-        "chunked context). Applies to the layered serving layout.",
-    )
     prefix_cache_enable: str = configfield(
         "prefix_cache_enable",
         default="auto",
         help_txt="Automatic prefix KV-cache reuse ('auto' or 'off'). In "
         "auto, chunk-aligned prompt prefixes (shared RAG preambles, "
-        "multi-turn histories) are indexed in a radix cache over "
-        "reserved HBM slots; a warm request copies the cached rows into "
-        "its slot and chunk-prefills only the uncached suffix. Applies "
-        "to the layered serving layout with chunked prefill; 'off' "
-        "restores the exact unaugmented admission path "
-        "(docs/prefix_cache.md).",
+        "multi-turn histories) are indexed in a radix cache whose "
+        "entries hold refcounted pool pages; a warm request maps the "
+        "cached pages into its page table (zero copy) and "
+        "chunk-prefills only the uncached suffix; 'off' restores the "
+        "exact unaugmented admission path (docs/prefix_cache.md).",
     )
     prefix_cache_slots: int = configfield(
         "prefix_cache_slots",
@@ -465,7 +424,7 @@ class EngineConfig(ConfigWizard):
         "tokens-per-dispatch on copy-heavy RAG/multi-turn traffic. "
         "Greedy output stays token-identical to 'off'; temperature>0 "
         "rows fall back to normal single-token decode inside the same "
-        "dispatch. Applies to the layered serving layout; 'off' "
+        "dispatch. 'off' "
         "restores the exact unaugmented decode path "
         "(docs/spec_decode.md).",
     )
@@ -545,9 +504,8 @@ class EngineConfig(ConfigWizard):
         "spec_draft_kv_dtype",
         default="bfloat16",
         help_txt="Draft-model KV cache storage: bfloat16 or int8 "
-        "(halves the draft cache's HBM; the draft always uses the "
-        "fixed layered cache layout regardless of the target's "
-        "kv_layout).",
+        "(halves the draft cache's HBM; the draft keeps a private "
+        "per-slot cache of its own, not pages of the target's pool).",
     )
     prefill_wave_tokens: int = configfield(
         "prefill_wave_tokens",
@@ -651,8 +609,7 @@ class EngineConfig(ConfigWizard):
         "tier worker forms and prefills admission waves and streams "
         "finished KV pages to the decode tier through a bounded "
         "transfer queue, so long-prompt prefills stop stealing decode "
-        "dispatch slots; requires the paged KV layout on the "
-        "layered+chunked path).",
+        "dispatch slots; pages are the handoff unit).",
     )
     handoff_queue_depth: int = configfield(
         "handoff_queue_depth",

@@ -57,7 +57,7 @@ _M_COMPILE_SECONDS = _REG.histogram(
     "Wall time of the first dispatch of each distinct compiled-program "
     "signature (trace + XLA compile; execution is async), by program "
     "family (prefill, decode, extend, finish, spec_verify, "
-    "update_slots, prefix_copy, page_tables).",
+    "update_slots, page_tables).",
     ("program",),
     buckets=(0.01, 0.05, 0.1, 0.25, 0.5, 1.0, 2.5, 5.0, 10.0, 30.0,
              60.0, 120.0, 300.0, float("inf")),

@@ -1,12 +1,10 @@
-"""Host-side page allocator for the paged KV cache (``kv_layout=paged``).
+"""Host-side page allocator for the engine's KV cache (docs/paged_kv.md).
 
-The fixed layout allocates every decode slot a dense ``max_seq_len`` row
-strip (plus a second full-size strip per prefix-cache store slot), so a
-48-token chat answer and an 8k-token RAG prompt cost the same HBM, and a
-prefix-cache hit must COPY store rows into the slot strip. The paged
-layout (the TPU analogue of vLLM's PagedAttention; PAPERS.md "Ragged
-Paged Attention") breaks the cache into fixed-size pages owned by this
-allocator:
+A dense ``max_seq_len`` strip per decode slot would make a 48-token chat
+answer and an 8k-token RAG prompt cost the same HBM, and a prefix-cache
+hit would have to COPY rows into the slot strip. The cache (the TPU
+analogue of vLLM's PagedAttention; PAPERS.md "Ragged Paged Attention")
+is instead broken into fixed-size pages owned by this allocator:
 
 - a **free list** over a device-resident page pool (page 0 is reserved
   as the scratch page — masked/dead writes land there, so stale page
@@ -210,10 +208,9 @@ def pages_needed(
 
 
 def pool_pages(cfg, max_seq_len: int, prefix_slots: int = 0) -> int:
-    """Pool size in pages. ``kv_pool_pages`` when set; otherwise HBM
-    parity with the fixed layout — one full-capacity strip per decode
-    slot plus one per prefix-cache store slot (the paged layout has no
-    separate store: entries hold refcounted pool pages) — plus the
+    """Pool size in pages. ``kv_pool_pages`` when set; otherwise one
+    full-capacity strip of pages per decode slot plus one per
+    prefix-cache entry (entries hold refcounted pool pages) — plus the
     scratch page."""
     if cfg.kv_pool_pages > 0:
         return cfg.kv_pool_pages
@@ -222,18 +219,10 @@ def pool_pages(cfg, max_seq_len: int, prefix_slots: int = 0) -> int:
 
 
 def validate_config(cfg) -> None:
-    """Pure-host validation of the paged-KV knobs (engine init and
-    server startup share this). ``kv_layout='auto'`` (the default — it
-    resolves to paged on the layered+chunked serving path, fixed
-    everywhere else; see :func:`auto_layout_blockers`) is validated
-    leniently: a geometry that cannot page simply resolves fixed
-    instead of failing startup, while an EXPLICIT 'paged' still fails
-    loudly."""
-    if cfg.kv_layout not in ("auto", "fixed", "paged"):
-        raise ValueError(
-            f"kv_layout must be 'auto', 'fixed' or 'paged', got "
-            f"{cfg.kv_layout!r}"
-        )
+    """Pure-host validation of the KV-cache knobs (engine init and
+    server startup share this). A geometry that cannot page fails
+    start-up here or in :func:`validate_runtime`: there is no other
+    layout to serve it."""
     if cfg.kv_pool_pages < 0:
         raise ValueError(
             f"kv_pool_pages must be >= 0 (0 = auto-size), got "
@@ -246,8 +235,6 @@ def validate_config(cfg) -> None:
             f"paged_kernel must be auto|off|interpret, got "
             f"{cfg.paged_kernel!r}"
         )
-    if cfg.kv_layout != "paged":
-        return
     p = cfg.page_size
     if p <= 0 or (p & (p - 1)) != 0:
         raise ValueError(
@@ -256,9 +243,7 @@ def validate_config(cfg) -> None:
     if p > 128:
         # Attention windows are bucketed in power-of-two token rungs
         # starting at 128; a page larger than the smallest rung could
-        # not tile every rung, and the gathered window shape would
-        # diverge from the fixed layout's (breaking the layouts'
-        # token-identity contract).
+        # not tile every rung.
         raise ValueError(
             f"page_size must divide the 128-token attention-window rung "
             f"(<= 128), got {p}"
@@ -269,50 +254,6 @@ def validate_config(cfg) -> None:
             f"page_size ({p}) so chunk-aligned prefix-cache entries are "
             f"page-aligned (zero-copy sharing needs whole pages)"
         )
-    if cfg.chunked_prefill == "off":
-        raise ValueError(
-            "kv_layout='paged' requires chunked_prefill (the paged "
-            "admission path reserves pages per chunk-aligned prefix)"
-        )
-    if cfg.serving_layout == "scan":
-        raise ValueError(
-            "kv_layout='paged' requires the layered serving layout; "
-            "serving_layout='scan' keeps the fixed-slot cache"
-        )
-
-
-def auto_layout_blockers(cfg, layered: bool, max_seq_len: int) -> List[str]:
-    """Why ``kv_layout='auto'`` cannot resolve to paged for this config
-    (empty list = paged). One rule list shared with the explicit-paged
-    validators so auto can never resolve to a geometry an explicit
-    'paged' would refuse; callers log the reasons at the fallback site
-    (the engine) so the resolution is never silent."""
-    reasons: List[str] = []
-    if not layered:
-        reasons.append(
-            "serving layout resolved to 'scan' (paged needs per-layer "
-            "cache buffers)"
-        )
-    if cfg.chunked_prefill == "off":
-        reasons.append("chunked_prefill is off")
-    p = cfg.page_size
-    if p <= 0 or (p & (p - 1)) != 0 or p > 128:
-        reasons.append(f"page_size {p} is not a power of two <= 128")
-    elif cfg.prefill_chunk % p:
-        reasons.append(
-            f"prefill_chunk {cfg.prefill_chunk} is not a multiple of "
-            f"page_size {p}"
-        )
-    elif max_seq_len % p:
-        reasons.append(
-            f"effective max_seq_len {max_seq_len} is not a multiple of "
-            f"page_size {p}"
-        )
-    # (no separate window-rung check: a power of two <= 128 that divides
-    # max_seq_len necessarily divides min(128, max_seq_len), so
-    # validate_runtime's rung rule can never fire for an auto-accepted
-    # geometry)
-    return reasons
 
 
 def validate_runtime(page_size: int, max_seq_len: int, pool: int) -> None:
@@ -467,10 +408,8 @@ class PageAllocator:
     def occupancy(self, reset: bool = False) -> Dict[str, float]:
         """Live-page occupancy basis over the allocator's lifetime (or
         since the last ``reset=True`` read): transition-sampled mean and
-        peak pages-in-use. This is the mean-live basis bench's
-        fixed-vs-paged bytes/token comparison evaluates both layouts at
-        (``tools``/bench share it instead of each recomputing a prompt-
-        arithmetic estimate), and the peak is the same number the
+        peak pages-in-use (tools read it instead of each recomputing a
+        prompt-arithmetic estimate); the peak is the same number a
         mid-run pool sampler observes."""
         with self._lock:
             out = {

@@ -12,14 +12,14 @@ of that optimization for the TPU engine:
 - a **radix/trie index** over chunk-aligned token spans (one node per
   ``prefill_chunk``-sized span, keyed by the span's exact token tuple —
   content-addressed, no hash collisions);
-- **entries** mapping a trie depth to a reserved HBM store slot that
-  holds the prefix's KV rows (the device arrays live in
-  ``LLMEngine._prefix_store``; this module never touches jax);
-- **refcounts** pinning a matched entry across the match → fetch-copy
-  window, so LRU eviction can never rewrite store rows a pending fetch
-  dispatch is about to read (decode itself never reads the store — the
-  fetch copies rows into the request's own slot);
-- **LRU eviction** over unpinned entries when the reserved slots fill;
+- **entries** mapping a trie depth to the refcounted pool pages that
+  hold the prefix's KV rows (the engine sets ``entry.pages``; this
+  module never touches jax), each holding one of ``slots`` entry-count
+  tickets (``store_slot``) that bound the index;
+- **refcounts** pinning a matched entry across the match → page-map
+  window, so LRU eviction cannot drop an entry whose pages an
+  admission is about to retain;
+- **LRU eviction** over unpinned entries when the tickets run out;
 - optional **session hints** (``SamplingParams.prefix_hint``): a
   hint names the chain/session a request belongs to, giving O(1)
   recency bumps at submit time so an active session's prefix survives
@@ -115,10 +115,10 @@ class _Node:
 
 class PrefixEntry:
     """A cached prefix: ``length`` chunk-aligned tokens whose KV rows
-    live in reserved store slot ``store_slot`` (fixed KV layout) or in
-    the refcounted pool pages listed in ``pages`` (paged layout — the
-    engine sets it right after ``insert_entry`` returns; the allocator
-    refcount, not the store slot, is then what keeps the rows alive)."""
+    live in the refcounted pool pages listed in ``pages`` (the engine
+    sets it right after ``insert_entry`` returns; the allocator
+    refcount is what keeps the rows alive). ``store_slot`` is the
+    entry's ticket out of ``prefix_cache_slots``."""
 
     __slots__ = ("store_slot", "length", "refs", "last_use", "node", "pages")
 
@@ -128,11 +128,11 @@ class PrefixEntry:
         self.refs = 0
         self.last_use = 0
         self.node = node
-        self.pages = None  # paged layout: List[int] of pool pages
+        self.pages = None  # List[int] of pool pages
 
 
 class PrefixCache:
-    """Radix index over chunk-aligned token prefixes → store slots."""
+    """Radix index over chunk-aligned token prefixes → entries."""
 
     def __init__(self, chunk: int, slots: int, max_len: int,
                  on_drop=None) -> None:
@@ -289,33 +289,9 @@ class PrefixCache:
         with self._lock:
             entry.refs = max(0, entry.refs - 1)
 
-    def invalidate_slot(self, slot: int) -> bool:
-        """Drop the entry occupying ``slot`` (engine warmup is about to
-        scribble on its rows) and return the slot to the free list.
-        True when the slot is free afterwards; False if a pinned entry
-        holds it — the caller must then not touch the rows."""
-        with self._lock:
-            entry = next(
-                (e for e in self._entries if e.store_slot == slot), None
-            )
-            if entry is None:
-                return True
-            if entry.refs > 0:
-                return False
-            entry.node.entry = None
-            self._entries.remove(entry)
-            if self._on_drop is not None:
-                self._on_drop(entry)
-            for h in [h for h, e in self._hints.items() if e is entry]:
-                del self._hints[h]
-            self._free.append(slot)
-            _M_EVICTIONS.inc()
-            self._update_gauge()
-            return True
-
     def evict_lru(self) -> bool:
         """Drop the LRU unpinned entry and free its slot — page-pool
-        backpressure: the paged engine calls this when an admission
+        backpressure: the engine calls this when an admission
         cannot fund its page reservation, reclaiming pages held only by
         cold cached prefixes (the drop hook releases them). False when
         every entry is pinned (or the cache is empty)."""
@@ -339,9 +315,9 @@ class PrefixCache:
     def insert(self, ids: Sequence[int],
                hint: Optional[str] = None) -> Optional[Tuple[int, int]]:
         """Register ``ids``' chunk-aligned prefix after its prefill
-        completed. Returns (store_slot, length) for the engine to copy
-        rows into, or None when the prefix is already cached at full
-        depth, uncacheable, or every store slot is pinned."""
+        completed. Returns the new entry's (store_slot, length), or
+        None when the prefix is already cached at full depth,
+        uncacheable, or every ticket is pinned."""
         entry = self.insert_entry(ids, hint=hint)
         if entry is None:
             return None

@@ -184,8 +184,7 @@ class RequestSnapshot:
     ``emitted[-1]`` is the next decode input (its KV row is written by
     the first restored decode step — the engine's standing invariant).
     ``kv`` is the page-granular pool payload covering those rows, or
-    None for a replay-only snapshot (request never admitted, or a
-    non-paged engine). ``sampling_seed`` pins the device RNG stream:
+    None for a replay-only snapshot (request never admitted). ``sampling_seed`` pins the device RNG stream:
     sampling keys derive from (seed, position), so the continuation
     is token-identical for sampled requests too.
     """
@@ -288,7 +287,7 @@ def capture(engine, req, position: int, pages: Tuple[int, ...]) -> RequestSnapsh
     emitted = list(getattr(req, "emitted", ()) or ())
     kv_doc = None
     geometry = None
-    if getattr(engine, "_paged", False) and pages and position > 0:
+    if pages and position > 0:
         page = engine.engine_config.page_size
         n_payload = (position + page - 1) // page
         n_payload = min(n_payload, len(pages))

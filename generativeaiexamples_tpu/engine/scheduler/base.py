@@ -327,7 +327,7 @@ class SchedulerPolicy:
             # fragmenting into per-bucket waves. Engaged when ANY
             # claimable prompt exceeds one chunk — short-only backlogs
             # keep the flash-kernel monolithic prefill.
-            use_chunked = eng._chunked and any(
+            use_chunked = any(
                 eng._prefill_bucket(len(r.prompt_ids)) > chunk
                 for r in claimable
             )

@@ -31,9 +31,6 @@ still interleave on the device stream. Host bookkeeping stays under
 the engine condition lock exactly as in the unified policy; decode-
 side registration (``_slot_req`` et al.) happens only at import, on
 the dispatch thread, preserving the engine's single-writer rules.
-
-Requires the paged KV layout on the layered+chunked path (pages are
-the handoff unit); scan/PP layouts and fixed KV refuse loudly.
 """
 from __future__ import annotations
 
@@ -56,25 +53,6 @@ class DisaggPolicy(SchedulerPolicy):
     def __init__(self, engine) -> None:
         super().__init__(engine)
         cfg = engine.engine_config
-        if engine._pp is not None:
-            raise ValueError(
-                "scheduler_policy='disagg' is not supported on the "
-                "pipeline-parallel serving path (use 'unified')"
-            )
-        if not getattr(engine, "_chunked", False):
-            raise ValueError(
-                "scheduler_policy='disagg' requires chunked prefill on "
-                "the layered serving layout (the prefill tier streams "
-                "chunk-aligned KV); this config resolved chunked "
-                "prefill off"
-            )
-        if not getattr(engine, "_paged", False):
-            raise ValueError(
-                "scheduler_policy='disagg' requires the paged KV layout "
-                "(pages are the handoff unit); this config resolved "
-                "kv_layout='fixed' — set kv_layout='paged' or fix the "
-                "page geometry (see kv_pages.auto_layout_blockers)"
-            )
         depth = cfg.handoff_queue_depth or 2 * engine.num_slots
         # The engine condition IS the tier coordination fabric: the
         # transfer queue, the inflight counter, and every tier wait
@@ -88,8 +66,8 @@ class DisaggPolicy(SchedulerPolicy):
         mc = engine.model_config
         self._page_nbytes = kv_pages_mod.page_bytes(
             mc.num_layers, cfg.page_size, mc.num_kv_heads, mc.head_dim,
-            quantized=getattr(engine, "_kv_quant", False),
-            kv_width=getattr(engine, "_kv_byte_width", None),
+            quantized=engine._kv_quant,
+            kv_width=engine._kv_byte_width,
         )
         # Tier topology plan (parallel/mesh.py): single-device meshes
         # share the device AND the pool (the zero-copy path this policy

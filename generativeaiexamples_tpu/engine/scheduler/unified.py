@@ -38,16 +38,15 @@ class UnifiedPolicy(SchedulerPolicy):
         decision (PR 30): between two chunks of a wave the loop's own
         decode step runs, so a decoding stream waits behind ONE extend
         program, not the wave; each chunk costs the wave's rows one
-        more block before their first token (docs/scheduler.md). Paged
-        layout only: there a block writes a dead row's scratch page,
-        while on the fixed layout it writes the first positions of the
-        row's own strip, which a half-prefilled prompt already holds."""
+        more block before their first token (docs/scheduler.md). A
+        block writes only the scratch page for the wave's rows, which
+        are not live yet, so their half-prefilled pages stay intact."""
         plan = self.claim_wave()
         if plan is not None:
             eng = self.engine
             eng._prefill_wave(
                 plan.admitted, plan.bucket, plan.use_chunked,
-                between_chunks=eng._decode_if_busy if eng._paged else None,
+                between_chunks=eng._decode_if_busy,
             )
 
     def ingest_window(self, timeout: float) -> bool:

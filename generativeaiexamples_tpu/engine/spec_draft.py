@@ -1,9 +1,9 @@
 """Resident draft-model runtime for speculative decoding.
 
 ``spec_proposer='draft_model'`` (or ``'combined'``) builds a SECOND,
-small Llama next to the serving target — own weights, own fixed-layout
-layered KV cache, sharded on the same mesh — and drafts K tokens for
-the whole decode wave in ONE batched compiled dispatch per spec round
+small Llama next to the serving target — own weights, own private
+per-slot KV cache (per-layer strips, not pages of the target's pool),
+sharded on the same mesh — and drafts K tokens for the whole decode wave in ONE batched compiled dispatch per spec round
 (models/llama.py ``draft_propose_layers``: a catch-up chunk feeding the
 tokens the target emitted since each row's draft frontier, fused with a
 ``lax.scan`` of K-1 greedy draft steps). The engine then issues its
@@ -15,11 +15,10 @@ draft-model section, PAPERS.md).
 
 Design notes:
 
-- the draft KV cache is always FIXED-layout layered
-  (``init_kv_cache_layers``), independent of the target's fixed/paged
-  choice: at draft scale the dense per-slot strips are a rounding error
-  next to the target pool, and fixed keeps the draft programs off the
-  page-table plumbing entirely;
+- the draft KV cache is dense per-slot strips, one set per layer
+  (``llama.init_kv_cache_layers``), not pages: at draft scale the
+  strips are a rounding error next to the target pool, and they keep
+  the draft programs off the page-table plumbing entirely;
 - all host bookkeeping (the per-slot draft frontier and its
   acceptance-rewind arithmetic) lives in
   ``spec_decode.DraftTracker`` — pure host, tier-1-testable;

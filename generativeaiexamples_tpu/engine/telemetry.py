@@ -1,8 +1,7 @@
-"""Live engine-utilization telemetry: the on-line version of bench.py's
-offline roofline/MFU lines.
+"""Live engine-utilization telemetry: on-line roofline/MFU estimates.
 
-bench computes MFU and HBM-roofline utilization once, after the fact,
-from hardcoded constants; nothing in-process knows how close the live
+An offline benchmark computes MFU and HBM-roofline utilization once,
+after the fact; nothing in-process then knows how close the live
 decode loop runs to the hardware ceiling. ``UtilizationEstimator``
 closes that gap: the engine's dispatch thread records one cheap host
 entry per compiled-program launch (kind, live rows, tokens produced,
@@ -11,8 +10,8 @@ reader thread records per-kind readback stalls, and a rolling window
 over those records feeds three registry families:
 
 - ``genai_engine_mfu_ratio`` — forward tokens/sec x 2 FLOPs/matmul-param
-  against the mesh's aggregate peak (same formula as bench, imported
-  from ``utils/hardware.py`` so the two can never drift);
+  against the mesh's aggregate peak (``utils/hardware.py`` owns the
+  formula);
 - ``genai_engine_hbm_bw_ratio`` — weight streaming + KV cache reads per
   second against the aggregate HBM roofline;
 - ``genai_engine_step_time_seconds`` — per-decode-step wall time
@@ -38,7 +37,7 @@ _M_MFU = _REG.gauge(
     "genai_engine_mfu_ratio",
     "Rolling-window model-FLOPs utilization of the serving mesh "
     "(forward tokens/sec x 2 FLOPs per matmul parameter vs aggregate "
-    "peak TFLOP/s; same formula as bench.py via utils/hardware.py).",
+    "peak TFLOP/s; utils/hardware.py owns the formula).",
 )
 _M_HBM = _REG.gauge(
     "genai_engine_hbm_bw_ratio",
@@ -182,8 +181,7 @@ class UtilizationEstimator:
 
     # ------------------------------------------------------------------ #
     def snapshot(self) -> Dict[str, float]:
-        """Current rolling-window view (the bench JSON line and
-        ``/internal/slo`` read this): gauge values plus the raw
+        """Current rolling-window view (``/internal/slo`` reads this): gauge values plus the raw
         tokens/sec and per-kind readback averages."""
         now = time.monotonic()
         with self._lock:

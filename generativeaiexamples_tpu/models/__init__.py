@@ -1,23 +1,15 @@
 from generativeaiexamples_tpu.models.llama import (
     PRESETS,
-    KVCache,
     LlamaConfig,
-    decode_step,
     forward,
-    init_kv_cache,
     init_params,
-    prefill,
 )
 from generativeaiexamples_tpu.models.sampling import sample_tokens
 
 __all__ = [
     "LlamaConfig",
     "PRESETS",
-    "KVCache",
     "forward",
-    "prefill",
-    "decode_step",
     "init_params",
-    "init_kv_cache",
     "sample_tokens",
 ]

@@ -317,176 +317,6 @@ def load_params_layered_streaming(
     return out
 
 
-def load_params_pp_streaming(
-    path: str,
-    cfg: LlamaConfig,
-    dtype=jnp.bfloat16,
-    *,
-    quantization: str = "none",
-    ctx,
-    stats: Optional[dict] = None,
-) -> Params:
-    """Stream a checkpoint straight into the PP x TP stage-stacked layout.
-
-    The pipeline-parallel capacity path exists exactly when the model is
-    too big — which is also when "materialize the whole checkpoint in
-    host RAM, then stage" (the old PP load) is impossible: a 70B-class
-    load needs ~140 GB of host RAM that way (reference sizes it at
-    320 GB of GPU memory, docs/support-matrix.md:43-46). Instead this
-    allocates the staged [stages, L/stages, ...] device buffers once
-    (sharded zeros, built shard-wise via jit out_shardings so no single
-    device ever holds a full leaf), then scatters each layer into its
-    (stage, slot) slice the moment its 9 tensors complete — quantized
-    on host first when ``quantization`` asks for int8/w8a8, in the same
-    per-shard Megatron tiles ops/quant.quantize_params_int8 builds.
-    Peak host memory is ~one safetensors shard (iter_param_groups),
-    reported via ``stats["peak_host_bytes"]``.
-
-    Returns the tree parallel/pp_serving.stage_params would have built.
-    """
-    import functools
-
-    import jax
-    from jax.sharding import NamedSharding, PartitionSpec as P
-
-    from generativeaiexamples_tpu.ops.quant import (
-        PACK_KINDS,
-        _quantize_int8_host,
-    )
-    from generativeaiexamples_tpu.parallel import pp_serving
-    from generativeaiexamples_tpu.parallel.mesh import MODEL_AXIS
-
-    mesh = ctx.mesh
-    stages, tp = ctx.stages, ctx.tp
-    Ls = cfg.num_layers // stages
-    q8 = quantization in ("int8", "w8a8")
-    lspecs = pp_serving._staged_layer_specs()
-    stream_stats: dict = stats if stats is not None else {}
-
-    def ns(spec):
-        return NamedSharding(mesh, spec)
-
-    def sharded_zeros(shape, zdtype, spec):
-        return jax.jit(
-            lambda: jnp.zeros(shape, zdtype), out_shardings=ns(spec)
-        )()
-
-    @functools.partial(jax.jit, donate_argnums=0)
-    def _scatter(buf, leaf, s, j):
-        idx = (s, j) + (0,) * (buf.ndim - 2)
-        return jax.lax.dynamic_update_slice(buf, leaf[None, None], idx)
-
-    buffers: Dict[str, object] = {}
-    out: Params = {}
-    cpu = jax_env.host_device()
-
-    def sub_spec(spec):
-        # staged spec minus the leading (pipe, layer-slot) axes: the
-        # placement of a single layer's update operand (replicated on
-        # pipe — it is one layer — feature axes on model)
-        return P(*spec[2:])
-
-    def alloc_like(key, leaf):
-        spec = lspecs[key]
-        if isinstance(leaf, dict):
-            packs = pp_serving._staged_pack_specs(spec)
-            return {
-                k2: sharded_zeros(
-                    (stages, Ls) + v.shape, v.dtype, packs[k2]
-                )
-                for k2, v in leaf.items()
-            }
-        return sharded_zeros((stages, Ls) + leaf.shape, dtype, spec)
-
-    def scatter(key, leaf, s, j):
-        spec = lspecs[key]
-        if isinstance(leaf, dict):
-            packs = pp_serving._staged_pack_specs(spec)
-            for k2, v in leaf.items():
-                dev = jax.device_put(v, ns(sub_spec(packs[k2])))
-                buffers[key][k2] = _scatter(buffers[key][k2], dev, s, j)
-        else:
-            dev = jax.device_put(leaf, ns(sub_spec(spec)))
-            buffers[key] = _scatter(buffers[key], dev, s, j)
-
-    with jax.default_device(cpu):
-        for key, group in iter_param_groups(path, cfg, stats=stream_stats):
-            if key == "embed":
-                # PP shards embed on the HIDDEN axis (pp_serving.
-                # stage_params: gathers rebuild [B, D] via all_gather)
-                out["embed"] = jax.device_put(
-                    jnp.asarray(group, dtype), ns(P(None, MODEL_AXIS))
-                )
-            elif key == "final_norm":
-                out["final_norm"] = jax.device_put(
-                    jnp.asarray(group, dtype), ns(P(None))
-                )
-            elif key == "lm_head":
-                if q8:
-                    pk = _quantize_int8_host(group, tp, "column")
-                    out["lm_head"] = {
-                        "q": jax.device_put(pk["q"], ns(P(None, MODEL_AXIS))),
-                        "scale": jax.device_put(
-                            pk["scale"], ns(P(None, MODEL_AXIS))
-                        ),
-                    }
-                else:
-                    out["lm_head"] = jax.device_put(
-                        jnp.asarray(group, dtype), ns(P(None, MODEL_AXIS))
-                    )
-            else:  # (layer_idx, {key: tensor})
-                idx = key
-                if q8:
-                    lp: Dict[str, object] = {
-                        "attn_norm": jnp.asarray(group["attn_norm"], dtype),
-                        "mlp_norm": jnp.asarray(group["mlp_norm"], dtype),
-                        "wo": _quantize_int8_host(group["wo"], tp, "row"),
-                        "w_down": _quantize_int8_host(
-                            group["w_down"], tp, "row"
-                        ),
-                    }
-                    if tp <= 1:
-                        lp["wqkv"] = _quantize_int8_host(
-                            np.concatenate(
-                                [group["wq"], group["wk"], group["wv"]],
-                                axis=-1,
-                            ),
-                            tp, "column",
-                        )
-                        lp["w_gateup"] = _quantize_int8_host(
-                            np.concatenate(
-                                [group["w_gate"], group["w_up"]], axis=-1
-                            ),
-                            tp, "column",
-                        )
-                    else:  # unfused under TP: shards align with heads
-                        for name in ("wq", "wk", "wv", "w_gate", "w_up"):
-                            lp[name] = _quantize_int8_host(
-                                group[name], tp, PACK_KINDS[name]
-                            )
-                else:
-                    lp = {k: jnp.asarray(v, dtype) for k, v in group.items()}
-                if not buffers:
-                    buffers.update(
-                        {k: alloc_like(k, v) for k, v in lp.items()}
-                    )
-                s, j = idx // Ls, idx % Ls
-                for k, v in lp.items():
-                    scatter(k, v, s, j)
-                del lp, group
-    out["layers"] = buffers
-    if "lm_head" not in out and not cfg.tie_embeddings:
-        logger.warning("No lm_head in checkpoint; tying to embeddings.")
-    logger.info(
-        "Streamed checkpoint %s into PP x TP (%d x %d) stage-stacked "
-        "layout: %d layers%s, peak host %.2f GB",
-        path, stages, tp, cfg.num_layers,
-        ", int8 quantize-on-load" if q8 else "",
-        stream_stats.get("peak_host_bytes", 0) / 1e9,
-    )
-    return out
-
-
 def write_hf_checkpoint(
     cfg: LlamaConfig, path: str, seed: int = 0, n_shards: int = 2
 ) -> None:

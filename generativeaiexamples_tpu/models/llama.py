@@ -10,17 +10,22 @@ compiled by XLA and sharded with ``jax.sharding.NamedSharding`` over a
 collectives rather than NCCL.
 
 Design notes (TPU-first):
-- all layer parameters are stacked on a leading ``num_layers`` axis and the
-  transformer body is a single ``lax.scan`` — one compiled layer body,
-  fast tracing/compilation, friendly to pipeline sharding later;
+- ``forward`` (training, the tests' cache-free reference): all layer
+  parameters stacked on a leading ``num_layers`` axis, the transformer
+  body a single ``lax.scan`` — one compiled layer body, fast
+  tracing/compilation;
 - attention/MLP matmuls stay [B*T, D] x [D, F] shaped so XLA tiles them
   onto the MXU; params and activations are bfloat16, RMSNorm/softmax/rope
   accumulate in float32;
-- the KV cache is a dense [L, B, S, H_kv, Dh] ring the decode step updates
-  functionally (donated by the engine's jit, so XLA updates it in place);
-  slot index == absolute position, which makes the causal mask a simple
-  position comparison. The Pallas paged-attention path (ops/) swaps in
-  behind the same interface.
+- serving (``*_layers_paged``, reached through models/registry.py):
+  per-layer weight buffers, unrolled layers, K/V in a shared page pool
+  per layer that the engine's jit donates, so XLA updates it in place;
+  read by the ragged Pallas kernel (ops/page_attention.py) or the XLA
+  gather behind the same interface;
+- ``decode_layers``, ``extend_layers``, ``_chunk_layers``,
+  ``init_kv_cache_layers`` and ``draft_propose_layers`` are the resident
+  DRAFT model's private cache walks (engine/spec_draft.py): dense
+  per-slot strips, one set per layer, no page tables.
 """
 from __future__ import annotations
 
@@ -35,7 +40,6 @@ from jax import lax
 from generativeaiexamples_tpu.ops import flash_attention, int8_matmul, page_attention
 
 Params = Dict[str, Any]
-KVCache = Dict[str, jax.Array]
 
 
 @dataclasses.dataclass(frozen=True)
@@ -266,15 +270,6 @@ def init_params_fast(
     return _assemble_params(cfg, normal, dtype)
 
 
-def init_kv_cache(
-    cfg: LlamaConfig, batch: int, max_seq_len: Optional[int] = None, dtype: jnp.dtype = jnp.bfloat16
-) -> KVCache:
-    """Dense decode cache: slot index == absolute token position."""
-    S = max_seq_len or cfg.max_seq_len
-    shape = (cfg.num_layers, batch, S, cfg.num_kv_heads, cfg.head_dim)
-    return {"k": jnp.zeros(shape, dtype), "v": jnp.zeros(shape, dtype)}
-
-
 def rms_norm(x: jax.Array, weight: jax.Array, eps: float) -> jax.Array:
     x32 = x.astype(jnp.float32)
     rms = jax.lax.rsqrt(jnp.mean(x32 * x32, axis=-1, keepdims=True) + eps)
@@ -447,74 +442,16 @@ def forward(
     cfg: LlamaConfig,
     tokens: jax.Array,  # [B, T] int32
     positions: jax.Array,  # [B, T] int32 absolute positions
-    cache: Optional[KVCache] = None,
     remat: bool = False,
     lora: Optional[Params] = None,
     lora_scale: float = 1.0,
-    window: Optional[int] = None,
     quant_kernel: Optional[bool] = None,
-) -> Tuple[jax.Array, Optional[KVCache]]:
-    """Run the decoder; returns (logits [B, T, V], updated cache).
-
-    With ``cache`` given, K/V for the T new tokens are scattered into their
-    absolute-position slots and attention runs over the whole cache (prefill
-    and decode are the same code path: T=prompt_len or T=1). Without a
-    cache, plain causal attention over T (training / compile checks).
-
-    ``window`` (static int) restricts attention to the first ``window``
-    cache rows. The caller must guarantee every query position is
-    < window; then the result is EXACT while HBM cache traffic scales
-    with the live sequence length instead of the allocated capacity (a
-    static prefix slice fuses into the attention reads — no copy). The
-    serving engine picks a power-of-two bucket per decode dispatch.
-    """
-    B, T = tokens.shape
+) -> Tuple[jax.Array, None]:
+    """Run the decoder cache-free: plain causal attention over T
+    (training, compile checks, the tests' reference for the serving
+    walks). Returns (logits [B, T, V], None)."""
     h = params["embed"][tokens]  # gather: [B, T, D]
 
-    if cache is not None:
-        # Cached path (decode / chunked prefill). The whole [L, B, S, Hkv,
-        # Dh] cache flows through the layer scan as CARRY, and each layer
-        # scatters its T new K/V rows in place. Carrying (vs. the obvious
-        # per-layer xs->ys pattern) matters enormously on TPU: scan outputs
-        # are fresh buffers, so emitting the cache as ys forces XLA to copy
-        # the full cache every step (~2x decode time measured at B=16,
-        # S=1024); carry buffers alias in/out, so the scatter is the only
-        # cache write.
-        S = cache["k"].shape[2]
-        W = min(window or S, S)
-        kv_positions = jnp.arange(W, dtype=jnp.int32)
-        # attend to any slot at an absolute position <= the query's position
-        mask = kv_positions[None, None, :] <= positions[:, :, None]
-        batch_idx = jnp.arange(B, dtype=jnp.int32)[:, None]
-
-        def cached_layer(carry, xs):
-            h, ck_all, cv_all = carry
-            li = xs["li"]
-
-            def attn(q, k, v):
-                nonlocal ck_all, cv_all
-                ck_all = ck_all.at[li, batch_idx, positions].set(k)
-                cv_all = cv_all.at[li, batch_idx, positions].set(v)
-                return _attention(q, ck_all[li, :, :W], cv_all[li, :, :W], mask), ()
-
-            h, _ = _block(
-                h, xs["params"], cfg, positions, attn,
-                lora=xs.get("lora"), lora_scale=lora_scale,
-                quant_kernel=quant_kernel,
-            )
-            return (h, ck_all, cv_all), ()
-
-        xs: Dict[str, Any] = {
-            "params": params["layers"],
-            "li": jnp.arange(cfg.num_layers, dtype=jnp.int32),
-        }
-        if lora is not None:
-            xs["lora"] = lora
-        body = jax.checkpoint(cached_layer) if remat else cached_layer
-        (h, ck, cv), _ = lax.scan(body, (h, cache["k"], cache["v"]), xs)
-        return _head(params, h, cfg, quant_kernel), {"k": ck, "v": cv}
-
-    # Cache-free path (training / compile checks): plain causal attention.
     mask = positions[:, :, None] >= positions[:, None, :]
 
     def layer(h, xs):
@@ -537,76 +474,6 @@ def forward(
     return _head(params, h, cfg, quant_kernel), None
 
 
-def prefill(
-    params: Params,
-    cfg: LlamaConfig,
-    tokens: jax.Array,  # [B, T] right-padded prompts
-    lengths: jax.Array,  # [B] true prompt lengths
-    cache: KVCache,
-    use_flash: Optional[bool] = None,
-    interpret: bool = False,
-    quant_kernel: Optional[bool] = None,
-) -> Tuple[jax.Array, KVCache]:
-    """Prefill the cache; returns (last-token logits [B, V], cache).
-
-    A fresh sequence's cache is empty, so prefill attends causally over
-    just the T prompt tokens (T×T, Pallas flash kernel when shapes allow)
-    instead of the full cache length S, then scatters K/V into
-    ``cache[:, :, :T]``. The lm_head matmul runs on the single last-token
-    hidden state, not all T positions — with a 128k vocab that matmul
-    dominates prefill otherwise. Right-padding rows are garbage but are
-    (a) never read (logits taken at ``lengths-1``) and (b) overwritten in
-    place by subsequent decode steps before the causal mask ever exposes
-    them.
-    """
-    B, T = tokens.shape
-    positions = jnp.broadcast_to(jnp.arange(T, dtype=jnp.int32), (B, T))
-    if use_flash is None:
-        use_flash = flash_attention.preferred(T, cfg.head_dim)
-    h = params["embed"][tokens]
-    mask = None if use_flash else positions[:, :, None] >= positions[:, None, :]
-
-    def layer(h, lp):
-        def attn(q, k, v):
-            if use_flash:
-                out = flash_attention.flash_attention_causal(
-                    q, k, v, interpret=interpret
-                )
-            else:
-                out = _attention(q, k, v, mask)
-            return out, (k, v)
-
-        return _block(h, lp, cfg, positions, attn, quant_kernel=quant_kernel)
-
-    h, (ks, vs) = lax.scan(layer, h, params["layers"])  # ks/vs: [L, B, T, Hkv, Dh]
-
-    last_h = jnp.take_along_axis(h, (lengths - 1)[:, None, None], axis=1)  # [B, 1, D]
-    last = _head(params, last_h, cfg, quant_kernel)[:, 0, :]  # [B, V]
-
-    cache = {
-        "k": lax.dynamic_update_slice(cache["k"], ks.astype(cache["k"].dtype), (0, 0, 0, 0, 0)),
-        "v": lax.dynamic_update_slice(cache["v"], vs.astype(cache["v"].dtype), (0, 0, 0, 0, 0)),
-    }
-    return last, cache
-
-
-def decode_step(
-    params: Params,
-    cfg: LlamaConfig,
-    tokens: jax.Array,  # [B] current token per sequence
-    positions: jax.Array,  # [B] absolute position of that token
-    cache: KVCache,
-    window: Optional[int] = None,
-    quant_kernel: Optional[bool] = None,
-) -> Tuple[jax.Array, KVCache]:
-    """One decode step for the whole batch; returns (logits [B, V], cache)."""
-    logits, cache = forward(
-        params, cfg, tokens[:, None], positions[:, None], cache, window=window,
-        quant_kernel=quant_kernel,
-    )
-    return logits[:, 0, :], cache
-
-
 def count_params(params: Params) -> int:
     return sum(int(x.size) for x in jax.tree.leaves(params))
 
@@ -614,7 +481,7 @@ def count_params(params: Params) -> int:
 def count_logical_params(cfg: LlamaConfig) -> int:
     """Parameter count from the architecture alone (independent of
     storage: int8 packs pad K/F, so counting buffer elements over- and
-    double-counts). Used for MFU math in bench.py."""
+    double-counts). Used for MFU math (engine/telemetry.py)."""
     n = sum(math.prod(shape) for shape, _ in init_spec(cfg).values())
     n += cfg.num_layers * 2 * cfg.hidden_size + cfg.hidden_size  # RMSNorm weights
     return n
@@ -647,15 +514,17 @@ def serving_memory_bytes(
 
 
 # --------------------------------------------------------------------- //
-# Layered serving path (single-device engine).
+# Per-layer (unrolled) walks.
 #
-# The scan-based forward above slices its stacked [L, ...] params/cache
-# per layer; when those slices feed Pallas calls (opaque to XLA fusion)
-# the compiler materializes HBM copies first — measured ~20% of decode
-# step time at B=32 for llama3-1b-proxy. The serving engine therefore
-# stores weights and KV caches as per-layer pytrees and unrolls the layer
-# loop: every Pallas operand is a whole buffer, no slicing anywhere.
-# Training and multi-device meshes keep the scan (compile time, GSPMD).
+# The scan-based forward above slices its stacked [L, ...] params per
+# layer; when those slices feed Pallas calls (opaque to XLA fusion) the
+# compiler materializes HBM copies first — measured ~20% of decode step
+# time at B=32 for llama3-1b-proxy. Serving therefore stores weights and
+# KV as per-layer pytrees and unrolls the layer loop: every Pallas
+# operand is a whole buffer, no slicing anywhere. Training keeps the
+# scan (compile time). ``prefill_layers`` is the cache-free prompt
+# forward the paged prefill wraps; the walks over ``init_kv_cache_layers``
+# strips below it are the DRAFT model's (see the module docstring).
 
 
 def consume_split_params_layers(params: Params) -> Params:
@@ -714,14 +583,10 @@ def init_kv_cache_layers(
     dtype: jnp.dtype = jnp.bfloat16,
     quantized: bool = False,
 ) -> list:
-    """Per-layer KV caches for the unrolled serving path.
-
-    bf16 layout matches the scan cache per layer: [B, S, Hkv, Dh].
-    Quantized layout is head-major [B, Hkv, S, Dh] int8 with per-token
-    per-head scales [B, Hkv, 1, S] — the geometry ops/decode_attention.py
-    streams (each (slot, head) reads contiguous rows; the unit scale axis
-    satisfies Mosaic's sublane block rule).
-    """
+    """Dense per-slot KV strips, one set per layer (the draft model's
+    private cache): bf16 [B, S, Hkv, Dh]; quantized head-major
+    [B, Hkv, S, Dh] int8 with per-token per-head scales [B, Hkv, 1, S],
+    the layout ``ops/decode_attention.decode_attention_xla`` reads."""
     S = max_seq_len or cfg.max_seq_len
     B, Hkv, Dh = batch, cfg.num_kv_heads, cfg.head_dim
 
@@ -795,8 +660,13 @@ def prefill_layers(
     tp=None,
 ) -> Tuple[jax.Array, list]:
     """Unrolled prefill; returns (last-token logits [B, V], per-layer
-    (k, v) [B, T, Hkv, Dh] for the engine to write into slot caches).
-    Same semantics as ``prefill`` (models/llama.py:439). With ``tp``
+    (k, v) [B, T, Hkv, Dh] for the caller to write into its cache). A
+    fresh sequence's cache is empty, so prefill attends causally over
+    just the T prompt tokens (the Pallas flash kernel when shapes allow)
+    and the lm_head runs on the single last-token hidden state.
+    Right-padding rows are garbage but never read (logits are taken at
+    ``lengths - 1``) and overwritten by decode before the causal mask
+    ever exposes them. With ``tp``
     (parallel/tp_kernels.TPContext) the flash kernel runs head-sharded
     via shard_map and packed matmuls on per-shard tiles."""
     B, T = tokens.shape
@@ -884,40 +754,6 @@ def extend_layers(
     return last_h, new_caches
 
 
-def verify_layers(
-    params: Params,
-    cfg: LlamaConfig,
-    tokens: jax.Array,  # [N, C] — last accepted token ++ K draft tokens
-    offsets: jax.Array,  # [N] absolute write position of each row's chunk
-    valid: jax.Array,  # [N] real tokens in this chunk (0..C; 0 = dead row)
-    slots: jax.Array,  # [N] target cache slots
-    caches: list,
-    window: int,  # static: power-of-two >= max(offsets) + C
-    quant_kernel: Optional[bool] = None,
-    tp=None,
-) -> Tuple[jax.Array, list]:
-    """Speculative-decoding verify: the chunked extend pass with logits
-    at EVERY chunk position, returning ([N, C, V], updated caches).
-
-    Position j's logits are the model's next-token distribution after
-    the prefix ending at ``offsets + j`` — exactly what ``decode_layers``
-    would produce for that prefix one token at a time — so scoring K
-    draft tokens plus the carried last token costs ONE dispatch instead
-    of K+1 (prompt-lookup decoding; the engine accepts the longest
-    greedy-matching draft prefix per row). Cache-write/masking semantics
-    are ``extend_layers``'s: positions past ``valid`` are value-masked
-    no-ops, so rejected draft rows are garbage above the accepted
-    frontier and the next verify chunk overwrites them before any query
-    can attend that far.
-    """
-    h, new_caches = _chunk_layers(
-        params, cfg, tokens, offsets, valid, slots, caches, window,
-        quant_kernel=quant_kernel, tp=tp,
-    )
-    logits = _head(params, h, cfg, quant_kernel, tp=tp)  # [N, C, V]
-    return logits, new_caches
-
-
 def _chunk_layers(
     params: Params,
     cfg: LlamaConfig,
@@ -930,7 +766,7 @@ def _chunk_layers(
     quant_kernel: Optional[bool] = None,
     tp=None,
 ) -> Tuple[jax.Array, list]:
-    """Shared chunk body for ``extend_layers``/``verify_layers``: write
+    """Chunk body of ``extend_layers`` and ``draft_propose_layers``: write
     the chunk's K/V rows at [slot, offset:offset+C] (value-masked by
     ``valid``), attend the [:window] cache prefix + within-chunk causal,
     and return (hidden states [N, C, D], updated caches)."""
@@ -1011,7 +847,7 @@ def draft_propose_layers(
     tokens: jax.Array,  # [B, C0] catch-up chunk (tokens past each row's frontier)
     offsets: jax.Array,  # [B] each row's draft-KV frontier (absolute position)
     valid: jax.Array,  # [B] catch-up tokens in this chunk (0 = dead row)
-    caches: list,  # the DRAFT model's per-layer fixed-layout caches
+    caches: list,  # the DRAFT model's per-layer strips (init_kv_cache_layers)
     window: int,  # static: power-of-two covering frontier + C0 + draft_k
     draft_k: int,  # static: proposal width K (spec_decode.effective_draft_len)
     vocab: int,  # static: argmax slice — the TARGET's sampling vocab
@@ -1068,7 +904,7 @@ def draft_propose_layers(
         tok, p, caches = carry
         lg, caches = decode_layers(
             params, cfg, tok, p, caches, window=window,
-            quant_kernel=quant_kernel, kv_kernel=False, tp=tp,
+            quant_kernel=quant_kernel, tp=tp,
         )
         nt = jnp.argmax(lg[:, :vocab], axis=-1).astype(jnp.int32)
         np_ = jnp.where(live, jnp.minimum(p + 1, S - 1), 0)
@@ -1081,136 +917,6 @@ def draft_propose_layers(
     return drafts, caches
 
 
-def _attention_merged(
-    q: jax.Array,  # [B, 1, Hq, Dh]
-    kc: jax.Array,  # [B, W, Hkv, Dh] cache window (rows < start_pos live)
-    vc: jax.Array,  # [B, W, Hkv, Dh]
-    mask_c: jax.Array,  # [B, 1, W] bool
-    ks: jax.Array,  # [B, BLK, Hkv, Dh] in-block slab rows
-    vs: jax.Array,  # [B, BLK, Hkv, Dh]
-    mask_s: jax.Array,  # [1, 1, BLK] bool (batch-uniform: row j <= step)
-) -> jax.Array:
-    """GQA attention over (cache window ++ slab) WITHOUT concatenating
-    K/V: scores are computed per source and joined for one exact
-    softmax — the score concat is [B, Hq, W+BLK] (tiny) while a K/V
-    concat would copy the whole cache window every step, which is the
-    copy traffic this path exists to remove."""
-    B, T, Hq, Dh = q.shape
-    Hkv = kc.shape[2]
-    group = Hq // Hkv
-    q5 = q.reshape(B, T, Hkv, group, Dh)
-    sc = jnp.einsum("btkgd,bskd->bkgts", q5, kc, preferred_element_type=jnp.float32)
-    ss = jnp.einsum("btkgd,bskd->bkgts", q5, ks, preferred_element_type=jnp.float32)
-    inv = 1.0 / math.sqrt(Dh)
-    sc = jnp.where(mask_c[:, None, None, :, :], sc * inv, -1e30)
-    ss = jnp.where(mask_s[:, None, None, :, :], ss * inv, -1e30)
-    W = kc.shape[1]
-    probs = jax.nn.softmax(jnp.concatenate([sc, ss], axis=-1), axis=-1)
-    pc, ps = probs[..., :W], probs[..., W:]
-    out = jnp.einsum("bkgts,bskd->btkgd", pc.astype(vc.dtype), vc)
-    out = out + jnp.einsum("bkgts,bskd->btkgd", ps.astype(vs.dtype), vs)
-    return out.reshape(B, T, Hq, Dh)
-
-
-def init_kv_slabs(
-    cfg: LlamaConfig, batch: int, block: int, dtype: jnp.dtype = jnp.bfloat16
-) -> list:
-    """Per-layer in-block K/V slabs for ``decode_layers_slab``: the rows
-    a decode block produces before they are scattered into the slot
-    caches ([B, block, Hkv, Dh] per layer — a few MB, vs the full caches
-    the plain block loop carries through ``lax.scan``)."""
-    B, Hkv, Dh = batch, cfg.num_kv_heads, cfg.head_dim
-    return [
-        {
-            "k": jnp.zeros((B, block, Hkv, Dh), dtype),
-            "v": jnp.zeros((B, block, Hkv, Dh), dtype),
-        }
-        for _ in range(cfg.num_layers)
-    ]
-
-
-def decode_layers_slab(
-    params: Params,
-    cfg: LlamaConfig,
-    tokens: jax.Array,  # [B]
-    positions: jax.Array,  # [B] current query positions (start + step)
-    caches: list,  # per-layer bf16 {"k","v"} — READ-ONLY here
-    slabs: list,  # per-layer {"k","v"} [B, BLK, Hkv, Dh] block rows
-    step: jax.Array,  # scalar int32: index of this step within the block
-    start_positions: jax.Array,  # [B] positions at block start
-    window: Optional[int] = None,
-    quant_kernel: Optional[bool] = None,
-    tp=None,
-) -> Tuple[jax.Array, list]:
-    """One decode step with the KV caches as loop CONSTANTS.
-
-    The round-3 device profile (BASELINE.md, tools/profile_decode.py)
-    attributes ~28% of per-op decode time to ``lax.scan`` double-buffer
-    copies of the full caches carried through the block loop. This path
-    removes the caches from the carry entirely: each step writes its
-    fresh K/V row into a small per-layer slab (the only carried cache
-    state), and attention joins (cache-window scores ++ slab scores) in
-    one exact softmax. The engine scatters the slabs into the donated
-    caches ONCE per block dispatch (llm_engine._build_steps_layered).
-
-    Cache rows >= a slot's block-start position are stale by definition
-    (this block's rows live in the slab), so the cache mask is strictly
-    ``kv_pos < start_position`` and the slab mask is ``row <= step``.
-    """
-    B = tokens.shape[0]
-    S = caches[0]["k"].shape[1]
-    W = min(window or S, S)
-    h = params["embed"][tokens[:, None]]
-    pos2 = positions[:, None]
-    mask_c = (
-        jnp.arange(W, dtype=jnp.int32)[None, None, :]
-        < start_positions[:, None, None]
-    )  # [B, 1, W]
-    BLK = slabs[0]["k"].shape[1]
-    mask_s = (
-        jnp.arange(BLK, dtype=jnp.int32)[None, None, :] <= step
-    )  # [1, 1, BLK]
-    new_slabs = []
-    for lp, c, s in zip(params["layers"], caches, slabs):
-        def attn(q, k, v, c=c, s=s):
-            sk = jax.lax.dynamic_update_slice(s["k"], k.astype(s["k"].dtype),
-                                              (0, step, 0, 0))
-            sv = jax.lax.dynamic_update_slice(s["v"], v.astype(s["v"].dtype),
-                                              (0, step, 0, 0))
-            new_slabs.append({"k": sk, "v": sv})
-            out = _attention_merged(
-                q, c["k"][:, :W], c["v"][:, :W], mask_c, sk, sv, mask_s
-            )
-            return out, ()
-
-        h, _ = _block(h, lp, cfg, pos2, attn, quant_kernel=quant_kernel, tp=tp)
-    logits = _head(params, h, cfg, quant_kernel, tp=tp)
-    return logits[:, 0, :], new_slabs
-
-
-def scatter_kv_slabs(
-    caches: list,
-    slabs: list,
-    start_positions: jax.Array,  # [B]
-) -> list:
-    """Write a block's slab rows into the slot caches: rows
-    ``[b, start_pos_b + j] = slab[b, j]``, clamped at capacity (the
-    budget accounting upstream stops streams before the clamp matters).
-    One scatter per cache buffer per dispatch — with the caches donated,
-    XLA aliases these in place."""
-    B, BLK = slabs[0]["k"].shape[:2]
-    S = caches[0]["k"].shape[1]
-    batch_idx = jnp.arange(B, dtype=jnp.int32)[:, None]
-    pos_grid = start_positions[:, None] + jnp.arange(BLK, dtype=jnp.int32)[None, :]
-    pos_grid = jnp.minimum(pos_grid, S - 1)  # [B, BLK]
-    new_caches = []
-    for c, s in zip(caches, slabs):
-        ck = c["k"].at[batch_idx, pos_grid].set(s["k"])
-        cv = c["v"].at[batch_idx, pos_grid].set(s["v"])
-        new_caches.append({"k": ck, "v": cv})
-    return new_caches
-
-
 def decode_layers(
     params: Params,
     cfg: LlamaConfig,
@@ -1219,36 +925,20 @@ def decode_layers(
     caches: list,
     window: Optional[int] = None,
     quant_kernel: Optional[bool] = None,
-    kv_kernel: Optional[bool] = None,
     tp=None,
 ) -> Tuple[jax.Array, list]:
-    """One decode step over per-layer caches; returns (logits [B, V],
-    updated caches). With int8 caches the attention runs through
-    ops/decode_attention.py (Pallas kernel when ``kv_kernel``, the XLA
-    dequant path otherwise); bf16 caches use the einsum attention over a
-    static ``window`` prefix, as in ``forward`` (models/llama.py:344).
-    With ``tp`` the kernel runs head-sharded (tp_kernels) and packed
-    matmuls on per-shard tiles."""
+    """One decode step over per-layer strips; returns (logits [B, V],
+    updated caches). int8 caches attend through
+    ``ops/decode_attention.decode_attention_xla``; bf16 caches use the
+    einsum attention over a static ``window`` prefix (the caller
+    guarantees every query position is < window, so the result is
+    exact). With ``tp`` packed matmuls run on per-shard tiles."""
     from generativeaiexamples_tpu.ops import decode_attention as da
 
     B = tokens.shape[0]
     quantized = "ks" in caches[0]
     S = caches[0]["k"].shape[2] if quantized else caches[0]["k"].shape[1]
     W = min(window or S, S)
-    if kv_kernel is None:
-        if tp is not None:
-            from generativeaiexamples_tpu.parallel import tp_kernels
-
-            kv_kernel = quantized and tp_kernels.decode_attention_supported(
-                cfg, tp.shards, S
-            )
-        else:
-            kv_kernel = (
-                quantized
-                and jax.default_backend() == "tpu"
-                and jax.device_count() == 1
-                and da.supported(S, cfg.head_dim, cfg.num_heads, cfg.num_kv_heads)
-            )
     h = params["embed"][tokens[:, None]]
     pos2 = positions[:, None]
     batch_idx = jnp.arange(B, dtype=jnp.int32)[:, None]
@@ -1274,20 +964,9 @@ def decode_layers(
                 cks = c["ks"].at[b3, h3, z3, p3].set(ksn)
                 cvs = c["vs"].at[b3, h3, z3, p3].set(vsn)
                 new_caches.append({"k": ck, "v": cv, "ks": cks, "vs": cvs})
-                if kv_kernel and tp is not None:
-                    from generativeaiexamples_tpu.parallel import tp_kernels
-
-                    out = tp_kernels.decode_attention_tp(
-                        q[:, 0], ck, cks, cv, cvs, positions, tp
-                    )[:, None]
-                elif kv_kernel:
-                    out = da.decode_attention(
-                        q[:, 0], ck, cks, cv, cvs, positions
-                    )[:, None]
-                else:
-                    out = da.decode_attention_xla(
-                        q, ck, cks, cv, cvs, pos2, window=W
-                    )
+                out = da.decode_attention_xla(
+                    q, ck, cks, cv, cvs, pos2, window=W
+                )
             else:
                 ck = c["k"].at[batch_idx, pos2].set(k)
                 cv = c["v"].at[batch_idx, pos2].set(v)
@@ -1301,7 +980,7 @@ def decode_layers(
 
 
 # --------------------------------------------------------------------- //
-# Paged KV cache (kv_layout='paged', docs/paged_kv.md).
+# The serving KV cache: a page pool (docs/paged_kv.md).
 #
 # Instead of one dense [B, S, ...] strip per decode slot, K/V rows live
 # in a shared page pool [P, page, Hkv, Dh]; a host-side allocator
@@ -1311,12 +990,11 @@ def decode_layers(
 # hit maps the shared pages, refcounted, into the new table) and let the
 # admission planner fund mixed-length requests at page granularity.
 #
-# Exactness contract: the gathered window is the same W tokens in the
-# same order as the fixed layout's [:W] slice, holding bitwise-equal
-# written values, and the attention math below mirrors the fixed paths
-# op for op (einsum attention for bf16; ops/decode_attention.py's XLA
-# dequant formula for int8) — so paged streams are token-identical to
-# fixed ones, pinned by tests/test_paged_kv.py and the bench A/B.
+# Exactness: the gathered window holds a row's first W tokens in
+# order, and the attention over it is the plain math (einsum attention
+# for bf16; ops/decode_attention.py's XLA dequant formula for int8), so
+# greedy streams equal a cache-free ``forward`` decode token for token
+# (tests/test_paged_kv.py, tests/test_one_serving_path.py).
 #
 # The attention READ has two servers behind one interface: the XLA
 # gather below (every geometry; reads a bucketed W per row) and the
@@ -1342,8 +1020,7 @@ def init_kv_pool(
 ) -> list:
     """Per-layer page pools: [pool, page_size, Hkv, Dh] token-major (the
     int8 variant carries per-(token, head) scales [pool, page_size,
-    Hkv] — same quantize_kv values as the fixed head-major layout, laid
-    out page-contiguous). ``packed`` selects the int4 pool: uint8
+    Hkv] — quantize_kv's values, laid out page-contiguous). ``packed`` selects the int4 pool: uint8
     [pool, page_size, Hkv, Dh//2] holding two values per byte
     (quantize_kv_int4's split-halves codec) with the same scale planes —
     readers detect it by the uint8 dtype."""
@@ -1390,7 +1067,7 @@ def write_prefill_pages(
     page_size: int,
 ) -> list:
     """Scatter a monolithic prefill wave's fresh K/V rows into the page
-    pool (the paged analogue of the fixed path's slot scatter). Garbage
+    pool through the wave rows' page tables. Garbage
     right-padding rows land in the rows' own reserved pages (overwritten
     by decode before any query attends them) or, past the reservation,
     on the scratch page."""
@@ -1526,9 +1203,8 @@ def _chunk_layers_paged(
                         work=work,
                     ).astype(q.dtype)
                     return out, ()
-                # same dequant math as the fixed chunk path (int->f32,
-                # scale multiply, cast) over the gathered token-major
-                # window — bitwise-equal inputs into the same _attention
+                # plain dequant math (int->f32, scale multiply, cast)
+                # over the gathered token-major window into _attention
                 gk = _gather_page_window(ck, row_tables, Pw, page_size)
                 gv = _gather_page_window(cv, row_tables, Pw, page_size)
                 if packed:
@@ -1622,7 +1298,14 @@ def verify_layers_paged(
     tp=None,
     page_kernel: Optional[str] = None,
 ) -> Tuple[jax.Array, list]:
-    """``verify_layers`` over the page pool (spec-decode verify).
+    """Speculative-decoding verify: the chunked extend pass with logits
+    at EVERY chunk position, returning ([N, C, V], updated caches).
+    Position j's logits are the model's next-token distribution after
+    the prefix ending at ``offsets + j``, so scoring K draft tokens
+    plus the carried last token costs ONE dispatch instead of K+1.
+    Positions past ``valid`` are value-masked no-ops, so rejected draft
+    rows are garbage above the accepted frontier and the next verify
+    chunk overwrites them before any query can attend that far.
 
     ``page_kernel`` runs the K+1-wide verify chunk through the ragged
     kernel's multi-query rows when the engine's geometry probe allows
@@ -1651,18 +1334,16 @@ def decode_layers_paged(
     page_kernel: Optional[str] = None,
 ) -> Tuple[jax.Array, list]:
     """One decode step over the page pool; returns (logits [B, V],
-    updated pools). bf16 mirrors ``decode_layers``'s einsum attention;
-    int8 mirrors ``ops/decode_attention.decode_attention_xla``'s dequant
-    formula over the gathered window — bitwise the fixed path's math on
-    bitwise-equal rows, so greedy and seeded-sampled streams match the
-    fixed layout token for token. Dead rows write the scratch page.
+    updated pools). bf16 runs the einsum attention; int8 runs
+    ``ops/decode_attention.decode_attention_xla``'s dequant formula
+    over the gathered window. Dead rows write the scratch page.
 
     ``page_kernel`` (None | 'compiled' | 'interpret') serves the read
     through ops/page_attention.py instead of the XLA gather: identical
     pool writes, a walk over each row's live pages, online
     softmax in f32 — same dequant formula, blockwise accumulation
-    order (float-tolerance vs the gather; the bench A/B is the
-    token-identity gate on hardware)."""
+    order (float-tolerance vs the gather; chip_smoke.py and
+    tests/test_page_attention.py hold the two together)."""
     B = tokens.shape[0]
     quantized = "ks" in caches[0]
     packed = quantized and caches[0]["k"].dtype == jnp.uint8

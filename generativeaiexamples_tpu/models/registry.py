@@ -3,7 +3,7 @@
 ``resolve(model_config_name)`` returns ``(family, model_config)``. A
 family is a small record of functions over an OPAQUE cache pytree — the
 engine's paged step programs (``engine/llm_engine.py``
-``_build_steps_layered``) call these and nothing of a model module:
+``_build_steps``) call these and nothing of a model module:
 
 - ``config_type``: the class of the family's configuration objects
   (what ``family_of`` tells families apart by);
@@ -131,9 +131,8 @@ def _llama_family() -> ModelFamily:
 
     def prefill_paged(params, cfg, caches, tokens, lengths, slots, tables, page_size, *,
                       use_flash=None, quant_kernel=None, tp=None, **_):
-        # the SAME fresh-K/V forward as the fixed layout (prefill_layers
-        # never touches a cache), then one pool scatter per layer via the
-        # page tables: first-token logits match the fixed layout bitwise
+        # one fresh-K/V forward (prefill_layers never touches a cache),
+        # then one pool scatter per layer via the page tables
         logits, kvs = llama.prefill_layers(
             params, cfg, tokens, lengths, use_flash=use_flash, quant_kernel=quant_kernel, tp=tp,
         )

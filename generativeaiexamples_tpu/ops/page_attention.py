@@ -1,27 +1,26 @@
 """Pallas TPU kernel: ragged page-attention over the paged KV pool.
 
-The paged layout (``kv_layout=paged``, docs/paged_kv.md) stores K/V in a
-shared page pool ``[P, page, Hkv, Dh]`` with per-slot page tables; until
-this kernel, decode served it through an XLA dequant-gather that reads a
-power-of-two window ``W`` of pages per row — the whole batch pays the
-longest live sequence, exactly the padded-window traffic the paged
-design exists to remove. This is the ragged analogue of
-``ops/decode_attention.py``'s per-slot clamp (PAPERS.md: "Ragged Paged
-Attention" is this kernel for TPU): the kernel walks a flat WORK LIST
+The engine's KV cache (docs/paged_kv.md) stores K/V in a shared page
+pool ``[P, page, Hkv, Dh]`` with per-slot page tables; the XLA
+dequant-gather that serves it everywhere reads a power-of-two window
+``W`` of pages per row — the whole batch pays the longest live
+sequence, exactly the padded-window traffic the paged design exists to
+remove. This kernel is the ragged read (PAPERS.md: "Ragged Paged
+Attention" is this kernel for TPU): it walks a flat WORK LIST
 of the live (row, page) pairs only (``page_work_list``: each row's
 pages up to its last query position, flattened in row order), handed
 over by scalar prefetch, so both the cache traffic and the number of
 grid steps track each sequence's true page-rounded length
 (``utils/hardware.kv_read_bytes_ragged`` is this kernel's operand math).
 
-Differences from the fixed-layout kernel:
+Design:
 
 - **token-major pages.** The pool keeps pages ``[page, Hkv, Dh]``
   token-major (one page is the write unit), not head-major strips, so
   the head-fused wide-dot trick runs over the MERGED ``[page*Hkv, Dh]``
   leading dims: ONE ``[rows, Dh] x [Dh, page*Hkv]`` MXU dot scores every
   (query row, token, kv head) triple — Hkv-fold redundant FLOPs on a
-  ~99%-idle MXU, same bargain as the fixed kernel — and each query row's
+  ~99%-idle MXU — and each query row's
   own-head columns are selected by a lane mask folded into the softmax
   masking (non-matching columns sit at -inf and underflow to exact 0
   probability), so no lane shuffle ever reorders the interleaved
@@ -29,10 +28,9 @@ Differences from the fixed-layout kernel:
 - **page-granular scales.** The int8 variant's per-(token, head) scales
   live page-contiguous (``[P, page, Hkv]``, engine/kv_pages.py /
   models/llama.py); they fold into the score/prob matrices after the
-  int8 dots exactly as the fixed kernel folds its head-major planes.
-- **bf16 AND int8.** The fixed kernel only pays off for int8 (bf16
-  fixed strips stream fine through XLA); here the ragged clamp is the
-  win, so both pool dtypes get the kernel.
+  int8 dots.
+- **bf16 AND int8.** The ragged walk is the win, not the dequant in
+  VMEM alone, so every pool dtype gets the kernel.
 - **multi-query rows.** ``q`` is ``[B, T, Hq, Dh]``: T=1 is block
   decode; small T (spec verify's K+1 chunk) runs the same kernel with a
   per-query-row causal clamp (query t of row b attends tokens
@@ -396,7 +394,6 @@ def supports_geometry(
         # the stricter int8 grid uniformly so all pool dtypes share one
         # predicate)
         and (page_size * num_kv_heads) % 32 == 0
-        # scratch/reshapes assume an 8-sublane [rows, 128] layout, as
-        # in ops/decode_attention.py
+        # scratch/reshapes assume an 8-sublane [rows, 128] layout
         and num_heads % 8 == 0
     )

@@ -11,9 +11,7 @@ from generativeaiexamples_tpu.parallel.ring_attention import (
 )
 from generativeaiexamples_tpu.parallel.sharding import (
     activation_spec,
-    kv_cache_specs,
     param_specs,
-    shard_kv_cache,
     shard_params,
     token_spec,
 )
@@ -25,11 +23,9 @@ __all__ = [
     "create_mesh",
     "single_device_mesh",
     "param_specs",
-    "kv_cache_specs",
     "activation_spec",
     "token_spec",
     "shard_params",
-    "shard_kv_cache",
     "ring_attention",
     "reference_attention",
 ]

@@ -25,7 +25,7 @@ def create_mesh(
     tensor_parallelism: int = -1,
     data_parallelism: int = 1,
     seq_parallelism: int = 1,
-    pipeline_parallelism: int = 1,
+    pipe_parallelism: int = 1,
     devices: Optional[Sequence[jax.Device]] = None,
 ) -> Mesh:
     """Build a (pipe, data, seq, model) mesh from the available devices.
@@ -39,11 +39,11 @@ def create_mesh(
     """
     devices = list(devices if devices is not None else jax.devices())
     n = len(devices)
-    other = data_parallelism * seq_parallelism * pipeline_parallelism
+    other = data_parallelism * seq_parallelism * pipe_parallelism
     if tensor_parallelism == -1:
         if n % other:
             raise ValueError(
-                f"{n} devices not divisible by pipe={pipeline_parallelism} * "
+                f"{n} devices not divisible by pipe={pipe_parallelism} * "
                 f"data={data_parallelism} * seq={seq_parallelism}"
             )
         tensor_parallelism = n // other
@@ -51,7 +51,7 @@ def create_mesh(
     if total > n:
         raise ValueError(f"Mesh wants {total} devices; only {n} available")
     grid = np.array(devices[:total]).reshape(
-        pipeline_parallelism, data_parallelism, seq_parallelism, tensor_parallelism
+        pipe_parallelism, data_parallelism, seq_parallelism, tensor_parallelism
     )
     return Mesh(grid, (PIPE_AXIS, DATA_AXIS, SEQ_AXIS, MODEL_AXIS))
 

@@ -75,7 +75,7 @@ def initialize_distributed(
 
 def create_hybrid_mesh(
     dcn_data_parallelism: int = -1,
-    dcn_pipeline_parallelism: int = 1,
+    dcn_pipe_parallelism: int = 1,
     ici_tensor_parallelism: int = -1,
     ici_seq_parallelism: int = 1,
     devices: Optional[Sequence] = None,
@@ -99,11 +99,11 @@ def create_hybrid_mesh(
     per_granule = len(devices) // num_granules
 
     if dcn_data_parallelism == -1:
-        dcn_data_parallelism = num_granules // dcn_pipeline_parallelism
+        dcn_data_parallelism = num_granules // dcn_pipe_parallelism
     if ici_tensor_parallelism == -1:
         ici_tensor_parallelism = per_granule // ici_seq_parallelism
 
-    dcn_shape = (dcn_pipeline_parallelism, dcn_data_parallelism, 1, 1)
+    dcn_shape = (dcn_pipe_parallelism, dcn_data_parallelism, 1, 1)
     ici_shape = (1, 1, ici_seq_parallelism, ici_tensor_parallelism)
 
     if num_granules == 1:

@@ -49,12 +49,6 @@ def param_specs() -> Dict[str, Any]:
     }
 
 
-def kv_cache_specs() -> Dict[str, Any]:
-    # [L, B, S, H_kv, Dh]
-    spec = P(None, DATA_AXIS, None, MODEL_AXIS, None)
-    return {"k": spec, "v": spec}
-
-
 def activation_spec(seq_sharded: bool = False) -> P:
     """[B, T, D] activations: batch on data, optionally sequence on seq."""
     return P(DATA_AXIS, SEQ_AXIS if seq_sharded else None, None)
@@ -90,12 +84,6 @@ def shard_params(params: Dict[str, Any], mesh: Mesh) -> Dict[str, Any]:
     specs = _prune_to(param_specs(), params)
     return jax.tree.map(
         lambda x, s: jax.device_put(x, NamedSharding(mesh, s)), params, specs
-    )
-
-
-def shard_kv_cache(cache: Dict[str, Any], mesh: Mesh) -> Dict[str, Any]:
-    return jax.tree.map(
-        lambda x, s: jax.device_put(x, NamedSharding(mesh, s)), cache, kv_cache_specs()
     )
 
 
@@ -150,10 +138,12 @@ def shard_params_layered(params: Dict[str, Any], mesh: Mesh) -> Dict[str, Any]:
 
 
 def kv_cache_layer_specs(quantized: bool) -> Dict[str, P]:
-    """One layer's cache leaf specs (init_kv_cache_layers layouts):
-    bf16 [B, S, Hkv, Dh]; int8 head-major [B, Hkv, S, Dh] with
+    """One layer's leaf specs of the resident DRAFT model's private
+    cache (engine/spec_draft.py; init_kv_cache_layers layouts): bf16
+    [B, S, Hkv, Dh]; int8 head-major [B, Hkv, S, Dh] with
     [B, Hkv, 1, S] scales. KV heads ride the model axis, slots the
-    data axis."""
+    data axis, as the target's pool shards its heads — so draft
+    dispatches ride the same mesh collectives as the target's."""
     if quantized:
         qspec = P(DATA_AXIS, MODEL_AXIS, None, None)
         return {"k": qspec, "v": qspec, "ks": qspec, "vs": qspec}
@@ -161,7 +151,9 @@ def kv_cache_layer_specs(quantized: bool) -> Dict[str, P]:
     return {"k": spec, "v": spec}
 
 
-def shard_kv_cache_layered(caches, mesh: Mesh, quantized: bool):
+def shard_draft_kv_cache(caches, mesh: Mesh, quantized: bool):
+    """Device-put the draft model's per-layer caches with
+    :func:`kv_cache_layer_specs`."""
     specs = kv_cache_layer_specs(quantized)
     return [
         {
@@ -170,25 +162,6 @@ def shard_kv_cache_layered(caches, mesh: Mesh, quantized: bool):
         }
         for layer in caches
     ]
-
-
-def draft_kv_cache_specs(quantized: bool) -> Dict[str, P]:
-    """Specs for the resident DRAFT model's KV cache (speculative
-    decoding, engine/spec_draft.py): the draft cache is a second,
-    smaller ``init_kv_cache_layers`` tree laid out exactly like the
-    target's — KV heads on the model axis, slots on data — so draft
-    dispatches ride the same mesh collectives as the target's and the
-    two models never disagree about where a slot's rows live."""
-    return kv_cache_layer_specs(quantized)
-
-
-def shard_draft_kv_cache(caches, mesh: Mesh, quantized: bool):
-    """Device-put the draft model's per-layer caches with
-    :func:`draft_kv_cache_specs`. A named seam that DELEGATES to the
-    target's layered-cache rule — one implementation, so a layout
-    change can never leave the draft cache sharded differently from
-    the target the docstring above promises it matches."""
-    return shard_kv_cache_layered(caches, mesh, quantized)
 
 
 def kv_pool_specs(quantized: bool) -> Dict[str, P]:

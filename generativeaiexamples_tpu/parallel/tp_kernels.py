@@ -5,8 +5,8 @@ count — INFERENCE_GPU_COUNT merely widens TRT-LLM's tensor parallelism
 (reference: deploy/compose/docker-compose-nim-ms.yaml:20). A pallas_call
 is opaque to the GSPMD partitioner, so on a sharded mesh plain jit either
 replicates the kernel's operands or (as rounds 1-2 did) falls back to XLA
-paths, losing the int8 weight-streaming, flash-prefill, and int8-KV
-decode wins exactly on the flagship v5e-8 topology.
+paths, losing the int8 weight-streaming, flash-prefill, and page-attention
+wins exactly on the flagship v5e-8 topology.
 
 This module closes that gap the shard_map way: every kernel runs
 per-device on its local Megatron tile, with an explicit ``psum`` over the
@@ -24,8 +24,8 @@ Layout contracts (axis names from parallel/mesh.py):
   over ``model`` in f32; output replicated.
 - flash prefill attention: q/k/v sharded on the head axis; attention is
   head-local under GQA as long as shards divide both head counts.
-- int8-KV decode attention: head-major caches sharded on the KV-head
-  axis, queries on the query-head axis; per-slot positions replicated.
+- ragged page attention: pools sharded on the KV-head axis, queries on
+  the query-head axis; page tables and positions replicated.
 
 Only PURE tensor-parallel meshes are served (mesh.size == model axis
 size — the serving engine's topology); hybrid data/seq meshes keep the
@@ -42,7 +42,6 @@ import jax
 from jax.sharding import Mesh, PartitionSpec as P
 
 from generativeaiexamples_tpu.ops import (
-    decode_attention,
     flash_attention,
     int8_matmul,
     page_attention,
@@ -177,21 +176,6 @@ def flash_attention_tp(q, k, v, tp: TPContext):
     )(q, k, v)
 
 
-def decode_attention_supported(cfg, shards: int, S: int) -> bool:
-    """Whether the int8-KV decode kernel can run head-sharded: the LOCAL
-    geometry (heads divided by shards) must satisfy the kernel's tiling
-    (ops/decode_attention.supported — e.g. local Hq % 8; 70B TP=8 keeps
-    8 local query heads and qualifies, 8B TP=8 drops to 4 and falls back
-    to the XLA dequant path)."""
-    return (
-        cfg.num_heads % shards == 0
-        and cfg.num_kv_heads % shards == 0
-        and decode_attention.supported(
-            S, cfg.head_dim, cfg.num_heads // shards, cfg.num_kv_heads // shards
-        )
-    )
-
-
 def paged_attention_tp(
     q, k, v, tables, positions, k_scale=None, v_scale=None,
     *, tp: TPContext, interpret: bool = False, work=None,
@@ -237,28 +221,3 @@ def paged_attention_tp(
         + (sspec,) * len(scales),
         out_specs=hspec, check_vma=False,
     )(q, k, v, tables, positions, work, *scales)
-
-
-def decode_attention_tp(q, k_q, k_s, v_q, v_s, positions, tp: TPContext):
-    """One decode step of int8-KV attention, heads sharded over ``model``.
-
-    q [B, Hq, Dh]; caches head-major [B, Hkv, S, Dh] int8 with
-    [B, Hkv, 1, S] f32 scales (parallel/sharding.py kv_cache_layer_specs
-    already pins the Hkv axis to ``model``); positions [B] replicated.
-    Each device streams only its own KV heads' cache rows.
-    """
-    qs = P(None, MODEL_AXIS, None)
-    kvs = P(None, MODEL_AXIS, None, None)
-
-    def body(ql, kql, ksl, vql, vsl, pl):
-        return decode_attention.decode_attention(
-            ql, kql, ksl, vql, vsl, pl, interpret=tp.interpret
-        )
-
-    return shard_map(
-        body,
-        mesh=tp.mesh,
-        in_specs=(qs, kvs, kvs, kvs, kvs, P(None)),
-        out_specs=qs,
-        check_vma=False,
-    )(q, k_q, k_s, v_q, v_s, positions)

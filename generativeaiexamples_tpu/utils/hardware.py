@@ -1,7 +1,7 @@
 """Hardware peak constants + roofline/MFU arithmetic, in ONE place.
 
-bench.py and the live utilization estimator (engine/telemetry.py) share
-this math so the offline and on-line numbers cannot drift. Peaks are
+The live utilization estimator (engine/telemetry.py) and the engine's
+fit planner read this math. Peaks are
 PUBLISHED per-chip numbers in one table keyed by jax's ``device_kind``,
 each with its source; the engine resolves the attached device against
 it at start-up (:func:`configure_peaks`). On the ``tpu`` backend a kind
@@ -182,8 +182,7 @@ def kv_read_bytes_ragged(model_cfg, live_tokens: int, kv_bytes: float) -> int:
 def streamed_weight_bytes(params) -> int:
     """Bytes the decode step streams from HBM for weights each step:
     every param leaf except the embedding table (gathered rows only).
-    Tolerates any tree layout (layered / scan / PP stage-stacked) —
-    when no top-level ``embed`` leaf exists the total is returned."""
+    When no top-level ``embed`` leaf exists the total is returned."""
     import jax
 
     tree = params

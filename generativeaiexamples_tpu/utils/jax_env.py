@@ -1,7 +1,7 @@
 """Process-level JAX environment rules, in ONE place.
 
 Every entry point that may compile (chain-server, engine server,
-router, bench.py, tools/precompile.py, chip_smoke.py) calls
+router, tools/precompile.py, chip_smoke.py) calls
 :func:`bootstrap` before jax initializes a backend. Three rules:
 
 - **Compile cache.** Where ``JAX_COMPILATION_CACHE_DIR`` is set the
@@ -26,7 +26,7 @@ router, bench.py, tools/precompile.py, chip_smoke.py) calls
   therefore the default backend.
 
 No jax import at module level: parents that launch a chip-holding child
-(bench.py's e2e mode, tools/loadgen, chip_smoke.py) import this freely.
+(tools/loadgen, chip_smoke.py, perfbench/run.py) import this freely.
 """
 from __future__ import annotations
 

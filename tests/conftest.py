@@ -35,9 +35,7 @@ import pytest
 # the tier without per-test decorators.
 SLOW_MODULES = {
     "test_chunked_prefill",
-    "test_decode_attention",
     "test_engine",
-    "test_engine_pp",
     "test_engine_tp",
     "test_flash_attention",
     "test_hf_golden",
@@ -51,7 +49,6 @@ SLOW_MODULES = {
     "test_parallel",
     "test_preempt_restore_matrix",
     "test_pipeline_parallel",
-    "test_pp_serving",
     "test_prefix_cache",
     "test_quality_smoke",
     "test_retrieval_tier_e2e",

@@ -22,7 +22,6 @@ import pytest
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P, SingleDeviceSharding
 
 from generativeaiexamples_tpu.ops import (
-    decode_attention,
     flash_attention,
     int8_matmul,
     page_attention,
@@ -99,22 +98,6 @@ def test_int8_w8a8_matmul_compiles(one_chip, no_persistent_cache):
     x = jax.ShapeDtypeStruct((16, MLP), jnp.bfloat16, sharding=one_chip)
     q, scale = _pack_shapes(one_chip, MLP, HIDDEN)
     text = _compiled_text(int8_matmul.int8_w8a8_matmul, x, q, scale)
-    assert "tpu_custom_call" in text
-
-
-def test_decode_attention_compiles(one_chip, no_persistent_cache):
-    B, S = 16, 4096
-
-    def s(shape, dtype):
-        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
-
-    text = _compiled_text(
-        decode_attention.decode_attention,
-        s((B, HQ, DH), jnp.bfloat16),
-        s((B, HKV, S, DH), jnp.int8), s((B, HKV, 1, S), jnp.float32),
-        s((B, HKV, S, DH), jnp.int8), s((B, HKV, 1, S), jnp.float32),
-        s((B,), jnp.int32),
-    )
     assert "tpu_custom_call" in text
 
 

@@ -42,7 +42,7 @@ METRICS_HOT = METRICS_KERNEL + 'genai_engine_hot_path_compiles_total{program="ex
 LOG_OK = textwrap.dedent(
     """\
     2026-09-26 INFO x: ragged page-attention kernel serving paged decode (compiled, page_size=128)
-    2026-09-26 INFO x: resolved kernel paths: quant_kernel=True kv_kernel=False paged_kernel=compiled paged_verify_kernel=compiled tp_kernels=None (backend=tpu, devices=1)
+    2026-09-26 INFO x: resolved kernel paths: quant_kernel=True paged_kernel=compiled paged_verify_kernel=compiled tp_kernels=None (backend=tpu, devices=1)
     2026-09-26 INFO x: Engine warmup complete for prompt lengths [512] (engine build 61.5 s, warmup 244.0 s; device memory: dev0 in_use=9.90GB peak=11.20GB limit=16.91GB)
     """
 )
@@ -113,7 +113,7 @@ device = {device!r}
 m.run_child = lambda phase, args, log, timeout, extra_env=None: (0, [{{"device": device}}])
 m.phase_server = lambda args, preset: dict(
     answers=5, engine_build_s=1.0, warmup_s=2.0, quant_kernel="True",
-    kv_kernel="False", paged_kernel="compiled", paged_verify_kernel="compiled",
+    paged_kernel="compiled", paged_verify_kernel="compiled",
     backend=device["platform"], devices=1, device_memory="device memory: stub",
 )
 m.OUT = {out!r}
@@ -173,11 +173,11 @@ def test_sandbox_run_without_arguments_fails_fast(tmp_path):
 
 @pytest.mark.parametrize(
     "module",
-    ["bench", "tools.loadgen.runner", "tools.loadgen.fleet", "tools.loadgen.chaos",
+    ["tools.loadgen.runner", "tools.loadgen.fleet", "tools.loadgen.chaos",
      "generativeaiexamples_tpu.router.__main__"],
 )
 def test_server_launching_parents_import_without_jax(module):
-    """bench.py's e2e mode and the loadgen launchers start the server as
+    """The loadgen launchers start the server as
     a child: importing them must not touch jax (one process per chip)."""
     code = (
         "import importlib, sys; importlib.import_module(%r); "

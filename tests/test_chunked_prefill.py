@@ -4,8 +4,8 @@ XLA compile inside a request and admission waves mix prompt lengths.
 
 Reference analogue: TRT-LLM chunked context (docs/architecture.md:54-66).
 """
-import numpy as np
 import pytest
+from greedy_reference import reference_greedy
 
 from generativeaiexamples_tpu.config import EngineConfig
 from generativeaiexamples_tpu.engine.llm_engine import LLMEngine, SamplingParams
@@ -18,7 +18,7 @@ TINY = dict(
     decode_block=2,
     dtype="float32",
     tensor_parallelism=1,
-    serving_layout="layered",
+    page_size=16,
 )
 
 
@@ -32,24 +32,20 @@ def _greedy(engine, prompt, n):
 
 @pytest.fixture(scope="module")
 def golden():
-    """Monolithic-prefill greedy streams for several prompt lengths."""
-    eng = LLMEngine(EngineConfig(chunked_prefill="off", **TINY))
-    try:
-        prompts = {
-            "short": [1, 9, 27],  # < one chunk
-            "exact": list(range(2, 18)),  # == one chunk
-            "long": [(i * 7) % 250 + 1 for i in range(41)],  # 3 chunks
-        }
-        return prompts, {k: _greedy(eng, p, 6) for k, p in prompts.items()}
-    finally:
-        eng.shutdown()
+    """Cache-free greedy streams (``llama.forward``) for several prompt
+    lengths."""
+    prompts = {
+        "short": [1, 9, 27],  # < one chunk
+        "exact": list(range(2, 18)),  # == one chunk
+        "long": [(i * 7) % 250 + 1 for i in range(41)],  # 3 chunks
+    }
+    return prompts, {k: reference_greedy(p, 6) for k, p in prompts.items()}
 
 
-def test_chunked_greedy_matches_monolithic(golden):
+def test_chunked_greedy_matches_the_cache_free_forward(golden):
     prompts, ref = golden
-    eng = LLMEngine(EngineConfig(chunked_prefill="auto", **TINY))
+    eng = LLMEngine(EngineConfig(**TINY))
     try:
-        assert eng._chunked
         for name, prompt in prompts.items():
             assert _greedy(eng, prompt, 6) == ref[name], name
     finally:
@@ -61,7 +57,7 @@ def test_chunked_mixed_length_wave(golden):
     fragmentation fix): every request still decodes its own reference
     stream."""
     prompts, ref = golden
-    eng = LLMEngine(EngineConfig(chunked_prefill="auto", **TINY))
+    eng = LLMEngine(EngineConfig(**TINY))
     try:
         waves0 = eng.metrics.get("admission_waves", 0)
         with eng.hold_admissions():
@@ -91,23 +87,21 @@ def test_chunked_mixed_length_wave(golden):
 
 
 def test_chunked_int8_kv_chunking_invariant(golden):
-    """Chunked scatter/gather through the head-major int8 cache layout:
-    greedy tokens are EXACTLY invariant to the chunk size (per-row
-    quantization is independent of chunking — extend_layers docstring),
-    so a 3-chunk and a 2-chunk prefill of the same prompt must agree.
-    (Exact match vs the MONOLITHIC int8-KV engine is not required:
-    chunked queries attend dequantized rows, monolithic prefill attends
-    full-precision fresh K/V — logits differ by quantization error.)"""
+    """Chunked scatter/gather through the int8 page pool: greedy tokens
+    are EXACTLY invariant to the chunk size (per-row quantization is
+    independent of chunking), so a 3-chunk and a 2-chunk prefill of the
+    same prompt must agree. (Exact match vs the float forward is not
+    required: chunked queries attend dequantized rows — logits differ
+    by quantization error.)"""
     prompts, _ = golden
     cfg = dict(TINY)
     streams = {}
     for chunk in (16, 32):
         cfg["prefill_chunk"] = chunk
         eng = LLMEngine(
-            EngineConfig(chunked_prefill="auto", kv_cache_dtype="int8", **cfg)
+            EngineConfig(kv_cache_dtype="int8", **cfg)
         )
         try:
-            assert eng._chunked
             streams[chunk] = _greedy(eng, prompts["long"], 6)
         finally:
             eng.shutdown()
@@ -116,17 +110,19 @@ def test_chunked_int8_kv_chunking_invariant(golden):
 
 
 def test_warmup_covers_all_lengths():
-    """After warmup_chunked_shapes, serving any longer prompt adds NO new
-    extend/finish executables — the no-compile-inside-request property,
-    asserted via the jit cache sizes."""
-    eng = LLMEngine(EngineConfig(chunked_prefill="auto", **TINY))
+    """After warm-up, serving any longer prompt adds NO new executable
+    of any step program — the no-compile-inside-request property, read
+    from the compile watch every program dispatches through."""
+    eng = LLMEngine(EngineConfig(**TINY))
     try:
         eng.warmup(prompt_lengths=[8])
-        n_ext = eng._extend_fn._cache_size()
-        n_fin = eng._finish_fn._cache_size()
-        assert n_ext > 0 and n_fin > 0
+        before = eng._compile_watch.snapshot()
+        assert before["compile_executables_extend"] > 0 and before["compile_executables_finish"] > 0
         _greedy(eng, [(i * 5) % 200 + 1 for i in range(100)], 4)  # 7 chunks
-        assert eng._extend_fn._cache_size() == n_ext
-        assert eng._finish_fn._cache_size() == n_fin
+        after = eng._compile_watch.snapshot()
+        assert after["compile_hot_path_total"] == 0
+        assert {k: v for k, v in after.items() if k.startswith("compile_executables")} == {
+            k: v for k, v in before.items() if k.startswith("compile_executables")
+        }
     finally:
         eng.shutdown()

@@ -17,6 +17,7 @@ def engine():
         max_batch_size=4,
         max_seq_len=96,
         prefill_chunk=16,
+        page_size=16,
         tensor_parallelism=1,
     )
     eng = LLMEngine(cfg)
@@ -99,7 +100,7 @@ def test_openai_facade():
 
     cfg = EngineConfig(
         model_config_name="debug", max_batch_size=2, max_seq_len=64, prefill_chunk=16,
-        tensor_parallelism=1,
+        page_size=16, tensor_parallelism=1,
     )
     eng = LLMEngine(cfg)
     app = create_model_server_app(engine=eng, embedder=HashEmbedder(64))
@@ -219,15 +220,13 @@ def test_overlong_prompt_reserves_decode_budget(engine):
     assert len(out) >= 8
 
 
-@pytest.mark.parametrize("chunked", ["off", "auto"])
-def test_prefill_wave_token_budget_bounds_dispatches(chunked):
+def test_prefill_wave_token_budget_bounds_dispatches():
     """The compiled prefill's activation footprint stays bounded under
     prefill_wave_tokens (uncapped 16 x 2560-token 8B waves plan >17 GB
     and cannot compile on a v5e chip — observed as empty answers through
-    the whole RAG stack). Monolithic mode bounds it by SPLITTING long-
-    prompt admissions into 1-row waves; chunked mode bounds every
-    dispatch to rows x prefill_chunk tokens, so the same backlog fits
-    ONE wave of fixed-shape chunk dispatches."""
+    the whole RAG stack): every dispatch of a long-prompt wave is
+    rows x prefill_chunk tokens, so a backlog of long prompts fits ONE
+    wave of fixed-shape chunk dispatches."""
     from generativeaiexamples_tpu.config import EngineConfig
     from generativeaiexamples_tpu.engine.llm_engine import LLMEngine, SamplingParams
 
@@ -237,10 +236,10 @@ def test_prefill_wave_token_budget_bounds_dispatches(chunked):
             max_batch_size=4,
             max_seq_len=128,
             prefill_chunk=16,
-            prefill_wave_tokens=64,  # bucket 48 -> 1 monolithic row/wave
+            page_size=16,
+            prefill_wave_tokens=64,  # 4 rows x one 16-token chunk a dispatch
             tensor_parallelism=1,
             decode_block=2,
-            chunked_prefill=chunked,
         )
     )
     try:
@@ -259,49 +258,8 @@ def test_prefill_wave_token_budget_bounds_dispatches(chunked):
                 toks.append(item)
             assert len(toks) >= 1
             assert req.error is None
-        waves = eng.metrics["admission_waves"] - waves0
-        if chunked == "off":
-            assert waves >= 4  # split, not one oversized wave
-        else:
-            # one wave of 4 rows; 3 chunk dispatches each <= 64 tokens
-            assert waves == 1
-            assert eng.metrics.get("prefill_chunks", 0) >= 3
+        # one wave of 4 rows; 3 chunk dispatches each <= 64 tokens
+        assert eng.metrics["admission_waves"] - waves0 == 1
+        assert eng.metrics.get("prefill_chunks", 0) >= 3
     finally:
         eng.shutdown()
-
-
-@pytest.mark.parametrize("tp", [1, 2])
-def test_slab_decode_matches_carried_cache_decode(monkeypatch, tp):
-    """Slab decode (caches as loop constants + one donated scatter per
-    block, round-5 perf lever) produces the same greedy stream as the
-    carried-cache scan it replaces — across blocks, so the scatter's
-    rows are re-read as cache window by later dispatches. tp=2 covers
-    the GSPMD-sharded bf16-KV deployment, where slab decode is also
-    the default (int8-KV configs keep the kernel path)."""
-    prompt = [1, 17, 93, 5, 64]
-    outs = {}
-    for flag in ("0", "1"):
-        monkeypatch.setenv("GENAI_TPU_DECODE_SLAB", flag)
-        eng = LLMEngine(
-            EngineConfig(
-                model_config_name="debug",
-                max_batch_size=2,
-                max_seq_len=96,
-                prefill_chunk=16,
-                decode_block=4,
-                tensor_parallelism=tp,
-                serving_layout="layered",
-            )
-        )
-        try:
-            assert eng._slab_decode == (flag == "1")
-            outs[flag] = list(
-                eng.iter_ids(
-                    prompt,
-                    SamplingParams(temperature=0.0, max_tokens=12),
-                    timeout=300,
-                )
-            )
-        finally:
-            eng.shutdown()
-    assert outs["1"] == outs["0"]

@@ -1,12 +1,13 @@
-"""Engine on a multi-device mesh: the scan (non-layered) serving path.
+"""Engine on a multi-device mesh.
 
-Every other engine test runs tensor_parallelism=1 and therefore the
-single-device layered path; this exercises continuous batching with
-params/cache GSPMD-sharded over the virtual 8-device CPU mesh — the
+Every other engine test runs tensor_parallelism=1; this exercises
+continuous batching with params and page pool GSPMD-sharded over the
+virtual 8-device CPU mesh — the
 TPU analogue of the reference's multi-GPU NIM (INFERENCE_GPU_COUNT,
 docker-compose-nim-ms.yaml:20).
 """
 import pytest
+from greedy_reference import reference_greedy
 
 from generativeaiexamples_tpu.config import EngineConfig
 from generativeaiexamples_tpu.engine.llm_engine import LLMEngine, SamplingParams
@@ -19,6 +20,7 @@ def tp_engine():
         max_batch_size=4,
         max_seq_len=96,
         prefill_chunk=16,
+        page_size=16,
         tensor_parallelism=8,
         decode_block=4,
     )
@@ -27,8 +29,9 @@ def tp_engine():
     eng.shutdown()
 
 
-def test_tp_engine_uses_scan_path(tp_engine):
-    assert not tp_engine._layered
+def test_tp_engine_pages_its_kv_over_the_mesh(tp_engine):
+    assert tp_engine.paged_stats()["pages_capacity"] > 0
+    assert isinstance(tp_engine.params["layers"], list)  # per-layer weights
     assert tp_engine._mesh.size == 8
     assert dict(tp_engine._mesh.shape)["model"] == 8
 
@@ -61,10 +64,9 @@ def test_tp_engine_concurrent_requests(tp_engine):
         assert req.error is None
 
 
-def test_int8_kv_tp_serving_uses_layered_path():
-    """int8 KV on a TP mesh runs the layered layout for real (no bf16
-    fallback) — VERDICT r1 #4: the layered-path optimizations must not be
-    gated on mesh.size == 1."""
+def test_int8_kv_tp_serving():
+    """int8 KV on a TP mesh serves from an int8 pool (no bf16
+    fallback) — VERDICT r1 #4."""
     cfg = EngineConfig(
         model_config_name="debug-8dev",
         max_batch_size=2,
@@ -73,10 +75,10 @@ def test_int8_kv_tp_serving_uses_layered_path():
         tensor_parallelism=8,
         decode_block=4,
         kv_cache_dtype="int8",
+        page_size=16,
     )
     eng = LLMEngine(cfg)
     try:
-        assert eng._layered
         assert eng._kv_quant
         assert eng._mesh.size == 8
         params = SamplingParams(temperature=0.0, max_tokens=8)
@@ -91,7 +93,7 @@ def test_int8_kv_tp_serving_uses_layered_path():
 
 def test_int8_kv_tp_matches_single_device():
     """Greedy decode on the 8-way TP int8-KV engine reproduces the
-    single-device layered int8-KV engine token-for-token (same seed-0
+    single-device int8-KV engine token-for-token (same seed-0
     random init) — cross-mesh numerics evidence for the sharded path."""
     common = dict(
         model_config_name="debug-8dev",
@@ -100,6 +102,7 @@ def test_int8_kv_tp_matches_single_device():
         prefill_chunk=16,
         decode_block=4,
         kv_cache_dtype="int8",
+        page_size=16,
     )
     params = SamplingParams(temperature=0.0, max_tokens=8)
     eng1 = LLMEngine(EngineConfig(tensor_parallelism=1, **common))
@@ -116,86 +119,57 @@ def test_int8_kv_tp_matches_single_device():
     assert single == sharded
 
 
-def test_int8_kv_scan_layout_falls_back():
+def test_float_kv_on_tp_serves_the_cache_free_forwards_tokens():
+    """A float pool on a TP mesh (the mesh that used to serve the scan
+    layout): greedy tokens equal the cache-free forward's."""
     cfg = EngineConfig(
         model_config_name="debug-8dev",
         max_batch_size=2,
         max_seq_len=64,
         prefill_chunk=16,
-        tensor_parallelism=8,
-        kv_cache_dtype="int8",
-        serving_layout="scan",  # int8 KV needs layered -> bf16 fallback
-    )
-    eng = LLMEngine(cfg)
-    try:
-        assert not eng._kv_quant
-        assert not eng._layered
-        ids = eng.tokenizer.encode("fallback", add_bos=True)
-        out = list(eng.iter_ids(ids, SamplingParams(temperature=0.0, max_tokens=4), timeout=300))
-        assert len(out) >= 1
-    finally:
-        eng.shutdown()
-
-
-def test_forced_layered_layout_bf16_kv_on_tp():
-    """serving_layout='layered' with a bf16 cache on a TP mesh (the
-    explicit override path — auto only picks layered for int8 KV)."""
-    cfg = EngineConfig(
-        model_config_name="debug-8dev",
-        max_batch_size=2,
-        max_seq_len=64,
-        prefill_chunk=16,
+        page_size=16,
         tensor_parallelism=8,
         decode_block=4,
-        serving_layout="layered",
+        dtype="float32",
     )
     eng = LLMEngine(cfg)
     try:
-        assert eng._layered
         assert not eng._kv_quant
         assert eng._mesh.size == 8
         params = SamplingParams(temperature=0.0, max_tokens=6)
-        ids = eng.tokenizer.encode("layered bf16 tp", add_bos=True)
+        ids = eng.tokenizer.encode("float32 pool under tp", add_bos=True)
         a = list(eng.iter_ids(ids, params, timeout=300))
-        b = list(eng.iter_ids(ids, params, timeout=300))
-        assert len(a) >= 1
-        assert a == b
+        assert a == reference_greedy(ids, 6, preset="debug-8dev")
     finally:
         eng.shutdown()
 
 
-def test_chunked_prefill_on_tp_layered_matches():
-    """Chunked prefill on the TP layered path (extend_layers with a
-    shard_map TP context): a 3-chunk prompt greedy-matches the same TP
-    engine with chunking off — the sharded gather/scatter and packed
-    matmuls agree with the monolithic TP prefill."""
+def test_chunked_prefill_on_tp_matches_single_device():
+    """Chunked prefill on the TP path (the extend walk under a sharded
+    mesh): a 3-chunk prompt greedy-matches the single-device engine —
+    the sharded gather/scatter and packed matmuls agree with it."""
     common = dict(
         model_config_name="debug-8dev",
         max_batch_size=2,
         max_seq_len=96,
         prefill_chunk=16,
-        tensor_parallelism=8,
+        page_size=16,
         decode_block=4,
-        kv_cache_dtype="int8",  # auto -> layered on TP
+        kv_cache_dtype="int8",
     )
     prompt = [(i * 11) % 400 + 1 for i in range(41)]
     params = SamplingParams(temperature=0.0, max_tokens=6)
-    ref_eng = LLMEngine(EngineConfig(chunked_prefill="off", **common))
+    ref_eng = LLMEngine(EngineConfig(tensor_parallelism=1, **common))
     try:
-        assert ref_eng._layered
         ref = list(ref_eng.iter_ids(prompt, params, timeout=300))
     finally:
         ref_eng.shutdown()
-    eng = LLMEngine(EngineConfig(chunked_prefill="auto", **common))
+    eng = LLMEngine(EngineConfig(tensor_parallelism=8, **common))
     try:
-        assert eng._chunked
         got = list(eng.iter_ids(prompt, params, timeout=300))
         assert eng.metrics.get("prefill_chunks", 0) >= 3
     finally:
         eng.shutdown()
-    # int8 KV: chunked attends dequantized rows (see extend_layers), so
-    # allow the first token to differ only if quantization error flips
-    # it — for this seed/prompt the streams match exactly.
     assert got == ref
 
 
@@ -218,11 +192,9 @@ def test_paged_shard_map_kernel_serves_tp_decode(monkeypatch, kv_dtype):
         prefill_chunk=16,
         tensor_parallelism=8,
         decode_block=4,
-        kv_layout="paged",
         page_size=8,
         paged_kernel="interpret",
         kv_cache_dtype=kv_dtype,
-        serving_layout="layered",  # paged requires it; auto picks scan for bf16 TP
     )
     eng = LLMEngine(cfg)
     try:

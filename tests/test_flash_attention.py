@@ -72,7 +72,7 @@ def test_supported_gate():
 
 
 def test_prefill_flash_glue_matches_einsum():
-    """prefill(use_flash=True) through the kernel == einsum path (GQA glue)."""
+    """prefill_layers(use_flash=True) through the kernel == einsum path (GQA glue)."""
     from generativeaiexamples_tpu.models import llama
 
     cfg = llama.LlamaConfig(
@@ -85,16 +85,17 @@ def test_prefill_flash_glue_matches_einsum():
         head_dim=128,
         max_seq_len=64,
     )
-    params = llama.init_params(cfg, jax.random.PRNGKey(0), jnp.float32)
+    params = llama.consume_split_params_layers(
+        llama.init_params(cfg, jax.random.PRNGKey(0), jnp.float32)
+    )
     tokens = jax.random.randint(jax.random.PRNGKey(1), (1, 20), 0, 256)
     lengths = jnp.array([20], jnp.int32)
-    cache_a = llama.init_kv_cache(cfg, 1, 64, jnp.float32)
-    cache_b = llama.init_kv_cache(cfg, 1, 64, jnp.float32)
-    last_ein, cache_ein = llama.prefill(params, cfg, tokens, lengths, cache_a, use_flash=False)
-    last_fl, cache_fl = llama.prefill(
-        params, cfg, tokens, lengths, cache_b, use_flash=True, interpret=True
+    last_ein, kv_ein = llama.prefill_layers(params, cfg, tokens, lengths, use_flash=False)
+    last_fl, kv_fl = llama.prefill_layers(
+        params, cfg, tokens, lengths, use_flash=True, interpret=True
     )
     assert jnp.allclose(last_ein, last_fl, atol=1e-3), float(
         jnp.max(jnp.abs(last_ein - last_fl))
     )
-    assert jnp.allclose(cache_ein["k"][:, :, :20], cache_fl["k"][:, :, :20], atol=1e-3)
+    for (k_e, _), (k_f, _) in zip(kv_ein, kv_fl):
+        assert jnp.allclose(k_e[:, :20], k_f[:, :20], atol=1e-3)

@@ -272,7 +272,7 @@ TINY = dict(
     decode_block=4,
     dtype="float32",
     tensor_parallelism=1,
-    serving_layout="layered",
+    page_size=16,
     watchdog_stall_s=0.0,
 )
 

@@ -74,33 +74,25 @@ def test_llama_forward_matches_transformers(llama_fixture):
 
 
 def test_llama_prefill_decode_matches_transformers(llama_fixture):
-    """The serving path (prefill -> cached decode_step) reproduces torch's
-    next-token logits — catches cache-layout/position bugs the full
-    forward can't see."""
+    """The serving walks (paged prefill -> paged decode step) reproduce
+    torch's next-token logits — catches cache-layout/position bugs the
+    full forward can't see."""
+    from greedy_reference import served_walk_logits
+
     model, path = llama_fixture
     cfg = config_from_hf(path)
     params = load_params(path, cfg, dtype=jnp.float32)
 
     prompt = np.array([[1, 17, 93, 5, 64]])
     next_tok = 22
+    full = np.array([[*prompt[0], next_tok]])
     with torch.no_grad():
-        full = np.array([[*prompt[0], next_tok]])
         golden = model(torch.tensor(full)).logits.numpy()[:, -1, :]  # after next_tok
-
-    B, T = prompt.shape
-    cache = llama.init_kv_cache(cfg, B, 32, jnp.float32)
-    lengths = jnp.full((B,), T, jnp.int32)
-    last, cache = llama.prefill(
-        params, cfg, jnp.asarray(prompt, jnp.int32), lengths, cache, use_flash=False
-    )
-    # prefill's last-token logits must match torch at the prompt tail
-    with torch.no_grad():
         golden_prefill = model(torch.tensor(prompt)).logits.numpy()[:, -1, :]
-    np.testing.assert_allclose(np.asarray(last), golden_prefill, atol=2e-3, rtol=2e-3)
 
-    logits, _ = llama.decode_step(
-        params, cfg, jnp.asarray([next_tok], jnp.int32), jnp.asarray([T], jnp.int32), cache
-    )
+    last, (logits,) = served_walk_logits(params, cfg, jnp.asarray(full, jnp.int32), prompt.shape[1])
+    # prefill's last-token logits must match torch at the prompt tail
+    np.testing.assert_allclose(np.asarray(last), golden_prefill, atol=2e-3, rtol=2e-3)
     np.testing.assert_allclose(np.asarray(logits), golden, atol=2e-3, rtol=2e-3)
 
 
@@ -174,6 +166,7 @@ def test_int8_engine_matches_transformers_greedy(llama_fixture):
             max_batch_size=2,
             max_seq_len=64,
             prefill_chunk=16,
+            page_size=16,
             decode_block=1,
             quantization="int8",
         )
@@ -225,6 +218,7 @@ def test_w8a8_engine_matches_transformers(llama_fixture):
             max_batch_size=2,
             max_seq_len=64,
             prefill_chunk=16,
+            page_size=16,
             decode_block=1,
             quantization="w8a8",
         )
@@ -298,6 +292,7 @@ def test_engine_serves_hf_checkpoint(llama_fixture, tmp_path):
             max_batch_size=2,
             max_seq_len=64,
             prefill_chunk=16,
+            page_size=16,
             dtype="float32",
             decode_block=1,
         )

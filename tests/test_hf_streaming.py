@@ -18,7 +18,6 @@ from generativeaiexamples_tpu.models.hf_loader import (
     iter_param_groups,
     load_params,
     load_params_layered_streaming,
-    load_params_pp_streaming,
     write_hf_checkpoint,
 )
 from generativeaiexamples_tpu.ops import quant
@@ -144,13 +143,13 @@ def test_engine_streams_layered_checkpoint(ckpt):
             max_batch_size=2,
             max_seq_len=64,
             prefill_chunk=16,
+            page_size=16,
             decode_block=2,
             quantization="int8",
         )
     )
     try:
         assert eng._streamed_load
-        assert eng._layered
         assert "wqkv" in eng.params["layers"][0]  # fused int8 pack
         out = list(
             eng.iter_ids(
@@ -177,6 +176,7 @@ def test_engine_streams_w8a8_checkpoint_produces_packed_leaves(ckpt):
             max_batch_size=2,
             max_seq_len=64,
             prefill_chunk=16,
+            page_size=16,
             decode_block=2,
             quantization="w8a8",
         )
@@ -217,6 +217,7 @@ def test_engine_streams_checkpoint_under_tp_kernels(tmp_path, monkeypatch):
             max_batch_size=2,
             max_seq_len=64,
             prefill_chunk=16,
+            page_size=16,
             decode_block=2,
             quantization="int8",
         )
@@ -234,80 +235,3 @@ def test_engine_streams_checkpoint_under_tp_kernels(tmp_path, monkeypatch):
         assert len(out) >= 1
     finally:
         eng.shutdown()
-
-
-def test_pp_streaming_matches_staged_tree(ckpt):
-    """load_params_pp_streaming (VERDICT r4 #3) builds exactly the tree
-    pp_serving.stage_params builds from a full stacked load — dense f32
-    equality across every staged leaf — with bounded host memory."""
-    import jax
-
-    from generativeaiexamples_tpu.parallel import pp_serving
-    from generativeaiexamples_tpu.parallel.mesh import create_mesh
-
-    stages, tp = 2, 2
-    mesh = create_mesh(
-        tensor_parallelism=tp, pipeline_parallelism=stages,
-        devices=jax.devices()[: stages * tp],
-    )
-    ctx = pp_serving.PPContext(mesh=mesh, stages=stages, tp=tp)
-    stats: dict = {}
-    streamed = load_params_pp_streaming(
-        ckpt, CFG, dtype=jnp.float32, quantization="none", ctx=ctx,
-        stats=stats,
-    )
-    staged = pp_serving.stage_params(load_params(ckpt, CFG, jnp.float32), ctx)
-    assert stats["peak_host_bytes"] > 0
-    np.testing.assert_array_equal(
-        np.asarray(streamed["embed"]), np.asarray(staged["embed"])
-    )
-    np.testing.assert_array_equal(
-        np.asarray(streamed["lm_head"]), np.asarray(staged["lm_head"])
-    )
-    for key in staged["layers"]:
-        np.testing.assert_array_equal(
-            np.asarray(streamed["layers"][key]),
-            np.asarray(staged["layers"][key]),
-            err_msg=f"staged leaf {key}",
-        )
-
-
-def test_pp_streaming_int8_matches_staged_packs(ckpt):
-    """int8 quantize-on-load through the PP streaming loader equals the
-    stacked load -> quantize -> stage pipeline (per-shard Megatron tiles
-    at tp=2), and serves greedy tokens through the PP program."""
-    import jax
-
-    from generativeaiexamples_tpu.parallel import pp_serving
-    from generativeaiexamples_tpu.parallel.mesh import create_mesh
-
-    stages, tp = 2, 2
-    mesh = create_mesh(
-        tensor_parallelism=tp, pipeline_parallelism=stages,
-        devices=jax.devices()[: stages * tp],
-    )
-    ctx = pp_serving.PPContext(mesh=mesh, stages=stages, tp=tp)
-    streamed = load_params_pp_streaming(
-        ckpt, CFG, dtype=jnp.bfloat16, quantization="int8", ctx=ctx,
-    )
-    staged = pp_serving.stage_params(
-        quant.quantize_params_int8(
-            load_params(ckpt, CFG, jnp.float32), tp_shards=tp
-        ),
-        ctx,
-    )
-    for key in ("wq", "wk", "wv", "wo", "w_gate", "w_up", "w_down"):
-        np.testing.assert_array_equal(
-            np.asarray(streamed["layers"][key]["q"]),
-            np.asarray(staged["layers"][key]["q"]),
-            err_msg=f"{key} int8 values",
-        )
-        np.testing.assert_allclose(
-            np.asarray(streamed["layers"][key]["scale"]),
-            np.asarray(staged["layers"][key]["scale"]),
-            rtol=1e-6, err_msg=f"{key} scales",
-        )
-    np.testing.assert_array_equal(
-        np.asarray(streamed["lm_head"]["q"]),
-        np.asarray(staged["lm_head"]["q"]),
-    )

@@ -48,6 +48,7 @@ def test_quantized_engine_decodes():
         max_batch_size=2,
         max_seq_len=64,
         prefill_chunk=16,
+        page_size=16,
         tensor_parallelism=1,
         quantization="int8",
     )

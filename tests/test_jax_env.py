@@ -87,7 +87,7 @@ def test_entry_points_share_the_helper():
     """No entry point carries cache logic of its own."""
     repo = jax_env.checkout_root()
     for rel in (
-        "bench.py", "tools/precompile.py", "chip_smoke.py",
+        "tools/precompile.py", "chip_smoke.py",
         "generativeaiexamples_tpu/server/__main__.py",
         "generativeaiexamples_tpu/engine/server.py",
         "generativeaiexamples_tpu/router/__main__.py",

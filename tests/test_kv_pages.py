@@ -226,7 +226,7 @@ def test_occupancy_basis_mean_and_peak():
     st = a.stats()
     assert st["peak_live_pages"] == 12
     assert st["mean_live_pages"] == occ["mean_live_pages"]
-    # reset=True starts a fresh window (bench brackets its measured wave)
+    # reset=True starts a fresh window (a tool brackets its measured wave)
     a.occupancy(reset=True)
     a.release(p1)
     occ2 = a.occupancy()
@@ -237,14 +237,13 @@ def test_occupancy_basis_mean_and_peak():
 # --------------------------------------------------------------------- #
 # config validation
 def _paged_cfg(**kw):
-    base = dict(kv_layout="paged", page_size=16, prefill_chunk=64)
+    base = dict(page_size=16, prefill_chunk=64)
     base.update(kw)
     return EngineConfig(**base)
 
 
-def test_validate_config_accepts_default_fixed():
-    kv_pages.validate_config(EngineConfig())  # auto: lenient by design
-    kv_pages.validate_config(EngineConfig(kv_layout="fixed"))
+def test_validate_config_accepts_the_defaults():
+    kv_pages.validate_config(EngineConfig())  # 128-token pages tile a 512-token chunk
     kv_pages.validate_config(_paged_cfg())
 
 
@@ -255,51 +254,14 @@ def test_validate_config_paged_kernel_knob():
         kv_pages.validate_config(_paged_cfg(paged_kernel="always"))
 
 
-def test_auto_layout_blockers():
-    """kv_layout='auto' resolves paged exactly when the geometry tiles;
-    every blocker names its reason (the engine logs them — the
-    fall-back to fixed is never silent)."""
-    ok = EngineConfig(page_size=16, prefill_chunk=64)
-    assert kv_pages.auto_layout_blockers(ok, layered=True, max_seq_len=128) == []
-    # scan layout
-    r = kv_pages.auto_layout_blockers(ok, layered=False, max_seq_len=128)
-    assert any("scan" in b for b in r)
-    # chunked prefill off
-    r = kv_pages.auto_layout_blockers(
-        EngineConfig(page_size=16, prefill_chunk=64, chunked_prefill="off"),
-        layered=True, max_seq_len=128,
-    )
-    assert any("chunked" in b for b in r)
-    # page-misaligned chunk / capacity
-    r = kv_pages.auto_layout_blockers(
-        EngineConfig(page_size=128, prefill_chunk=48),
-        layered=True, max_seq_len=256,
-    )
-    assert any("prefill_chunk" in b for b in r)
-    r = kv_pages.auto_layout_blockers(
-        EngineConfig(page_size=16, prefill_chunk=64),
-        layered=True, max_seq_len=100,
-    )
-    assert any("max_seq_len" in b for b in r)
-    # explicit-paged validation and auto blockers can never disagree on
-    # a geometry auto would accept
-    cfg = EngineConfig(kv_layout="paged", page_size=16, prefill_chunk=64)
-    assert kv_pages.auto_layout_blockers(cfg, layered=True, max_seq_len=128) == []
-    kv_pages.validate_config(cfg)
-    kv_pages.validate_runtime(16, 128, kv_pages.pool_pages(cfg, 128))
-
-
 @pytest.mark.parametrize(
     "kw,match",
     [
-        (dict(kv_layout="bogus"), "kv_layout"),
         (dict(kv_pool_pages=-1), "kv_pool_pages"),
         (dict(page_size=0), "power of two"),
         (dict(page_size=24), "power of two"),
         (dict(page_size=256, prefill_chunk=256), "128"),
         (dict(page_size=32, prefill_chunk=48), "multiple of"),
-        (dict(chunked_prefill="off"), "chunked"),
-        (dict(serving_layout="scan"), "layered"),
     ],
 )
 def test_validate_config_rejections(kw, match):

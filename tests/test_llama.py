@@ -3,14 +3,12 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
+from greedy_reference import served_walk_logits
 
 from generativeaiexamples_tpu.models import (
     PRESETS,
-    decode_step,
     forward,
-    init_kv_cache,
     init_params,
-    prefill,
     sample_tokens,
 )
 
@@ -44,7 +42,8 @@ def test_causality(params):
 
 
 def test_prefill_decode_matches_full_forward(params):
-    """Incremental decode with KV cache == one-shot causal forward."""
+    """The served walks (paged prefill, then one paged decode step a
+    token) == one-shot causal forward."""
     key = jax.random.PRNGKey(2)
     T = 10
     tokens = jax.random.randint(key, (2, T), 0, CFG.vocab_size, jnp.int32)
@@ -53,33 +52,22 @@ def test_prefill_decode_matches_full_forward(params):
 
     # prefill the first 6 tokens, then decode 4 more one at a time
     P = 6
-    cache = init_kv_cache(CFG, batch=2, max_seq_len=32)
-    lengths = jnp.array([P, P], dtype=jnp.int32)
-    last, cache = prefill(params, CFG, tokens[:, :P], lengths, cache)
+    last, steps = served_walk_logits(params, CFG, tokens, P)
     np.testing.assert_allclose(last, full_logits[:, P - 1], rtol=3e-2, atol=3e-2)
-
-    for t in range(P, T):
-        step_logits, cache = decode_step(
-            params,
-            CFG,
-            tokens[:, t],
-            jnp.array([t, t], dtype=jnp.int32),
-            cache,
-        )
+    for t, step_logits in zip(range(P, T), steps):
         np.testing.assert_allclose(step_logits, full_logits[:, t], rtol=3e-2, atol=3e-2)
 
 
 def test_prefill_with_padding(params):
-    """Right-padded prompts of different lengths decode like unpadded ones."""
+    """Right-padded prompts of different lengths prefill like unpadded ones."""
+    from generativeaiexamples_tpu.models import llama
+
     key = jax.random.PRNGKey(3)
     toks = jax.random.randint(key, (1, 5), 0, CFG.vocab_size, jnp.int32)
-
-    cache1 = init_kv_cache(CFG, batch=1, max_seq_len=16)
-    last1, _ = prefill(params, CFG, toks, jnp.array([5], jnp.int32), cache1)
-
+    layered = llama.consume_split_params_layers(dict(params, layers=dict(params["layers"])))
+    last1, _ = llama.prefill_layers(layered, CFG, toks, jnp.array([5], jnp.int32), use_flash=False)
     padded = jnp.pad(toks, ((0, 0), (0, 3)))  # pad to length 8
-    cache2 = init_kv_cache(CFG, batch=1, max_seq_len=16)
-    last2, _ = prefill(params, CFG, padded, jnp.array([5], jnp.int32), cache2)
+    last2, _ = llama.prefill_layers(layered, CFG, padded, jnp.array([5], jnp.int32), use_flash=False)
     np.testing.assert_allclose(last1, last2, rtol=2e-4, atol=2e-4)
 
 
@@ -113,7 +101,8 @@ def test_byte_tokenizer_roundtrip():
 
 
 def test_decode_window_is_exact():
-    """A window >= position+1 must not change decode logits vs full cache."""
+    """A window >= position+1 must not change decode logits vs the full
+    window (the gather reads a power-of-two bucket of pages)."""
     import jax
     import jax.numpy as jnp
 
@@ -121,16 +110,17 @@ def test_decode_window_is_exact():
 
     cfg = llama.PRESETS["debug"]
     params = llama.init_params(cfg, jax.random.PRNGKey(0), jnp.float32)
-    cache = llama.init_kv_cache(cfg, 2, 64, jnp.float32)
+    layered = llama.consume_split_params_layers(dict(params, layers=dict(params["layers"])))
+    page = 8
+    tables = 1 + jnp.arange(16, dtype=jnp.int32).reshape(2, 8)
     prompt = jnp.array([[3, 4, 5, 6], [7, 8, 9, 10]], jnp.int32)
-    lengths = jnp.array([4, 4], jnp.int32)
-    _, cache = llama.prefill(params, cfg, prompt, lengths, cache, use_flash=False)
+    _, kvs = llama.prefill_layers(layered, cfg, prompt, jnp.array([4, 4], jnp.int32), use_flash=False)
+    caches = llama.write_prefill_pages(llama.init_kv_pool(cfg, 17, page, jnp.float32), kvs, tables, page)
     tokens = jnp.array([11, 12], jnp.int32)
     positions = jnp.array([4, 4], jnp.int32)
-    full, _ = llama.decode_step(params, cfg, tokens, positions, dict(cache))
-    windowed, _ = llama.decode_step(
-        params, cfg, tokens, positions, dict(cache), window=16
-    )
+    live = jnp.ones((2,), bool)
+    full, _ = llama.decode_layers_paged(layered, cfg, tokens, positions, live, tables, caches, 64, page)
+    windowed, _ = llama.decode_layers_paged(layered, cfg, tokens, positions, live, tables, caches, 16, page)
     assert jnp.allclose(full, windowed, atol=1e-5)
 
 

@@ -542,26 +542,6 @@ def test_gate_slo_sample_awareness():
     assert code == 0
 
 
-def test_gate_bench_contract_lines():
-    base_line = {
-        "metric": "e2e_decode_throughput", "value": 100.0, "unit": "tokens/s",
-        "provenance": provenance_mod.provenance(
-            config={"m": 1}, weights_random_init=True),
-    }
-    run_ok = dict(base_line, value=91.0)  # within the 10% default band
-    code, report = gate_mod.gate(run_ok, _baseline(base_line))
-    assert code == 0, report
-    run_bad = dict(base_line, value=85.0)
-    code, report = gate_mod.gate(run_bad, _baseline(base_line))
-    assert code == 1
-    # cross-provenance bench compares refuse like loadgen ones
-    run_other = dict(run_ok)
-    run_other["provenance"] = provenance_mod.provenance(
-        config={"m": 2}, weights_random_init=True)
-    code, _ = gate_mod.gate(run_other, _baseline(base_line))
-    assert code == 2
-
-
 def test_gate_cli_contract(tmp_path):
     """File-level CLI: --record writes the baseline, a clean re-run
     passes (exit 0), a perturbed run fails (exit 1), drift exits 2."""

@@ -298,7 +298,7 @@ def test_engine_histograms_carry_trace_exemplars():
 
 
 def test_legacy_metrics_dict_keys_derive_from_registry():
-    """bench.py / the tools / /internal/metrics read the flat dict view;
+    """The tools and /internal/metrics read the flat dict view;
     its keys must track the registry families."""
     from generativeaiexamples_tpu.engine import llm_engine
 

@@ -550,7 +550,7 @@ def test_decode_span_carries_pages_walked_and_the_dense_grid():
     eng = LLMEngine(EngineConfig(
         model_config_name="debug", max_batch_size=3, max_seq_len=64,
         prefill_chunk=16, decode_block=4, decode_runahead=1,
-        tensor_parallelism=1, page_size=8, kv_layout="paged",
+        tensor_parallelism=1, page_size=8,
         paged_kernel="interpret", watchdog_stall_s=0.0,
     ))
     try:

@@ -1,14 +1,18 @@
-"""Paged KV cache: engine-level layout contracts (slow tier).
+"""The paged KV cache: engine-level contracts (slow tier).
 
-The acceptance bar for ``kv_layout='paged'`` is token identity with the
-fixed layout everywhere: greedy and seeded-sampled streams, int8 KV,
-prefix-cache-warm admissions, and spec-decode on/off — plus the
-zero-copy contract (a paged prefix hit dispatches NO copy programs) and
-exact page accounting (everything released when the requests drain).
-Engines are tiny debug configs on the virtual CPU platform; builds
-still jit-compile the serving programs, hence the slow tier.
+The bar is the model's own greedy decode: what a float32 engine serves
+— plain, speculative, prefix-warm, in a mixed wave — equals a
+cache-free ``llama.forward`` decode (tests/greedy_reference.py).
+Quantised pools and sampled streams, which no float reference can pin,
+are held to run-to-run determinism, batch invariance, spec-on ==
+spec-off and kernel-against-gather first tokens — plus the zero-copy
+contract (a prefix hit maps pages) and exact page accounting
+(everything released when the requests drain). Engines are tiny debug
+configs on the virtual CPU platform; builds still jit-compile the
+serving programs, hence the slow tier.
 """
 import pytest
+from greedy_reference import reference_greedy
 
 from generativeaiexamples_tpu.config import EngineConfig
 from generativeaiexamples_tpu.engine.llm_engine import LLMEngine, SamplingParams
@@ -37,38 +41,53 @@ def collect(engine, prompts, params):
     return [list(engine.iter_ids(p, params, timeout=300)) for p in prompts]
 
 
-def build(layout, **overrides):
-    cfg = dict(BASE, kv_layout=layout)
-    cfg.update(overrides)
-    return LLMEngine(EngineConfig(**cfg))
+def build(**overrides):
+    return LLMEngine(EngineConfig(**dict(BASE, **overrides)))
+
+
+def wave(engine, prompts, params):
+    """One held admission wave; each row's stream."""
+    with engine.hold_admissions():
+        reqs = [engine.submit(p, params) for p in prompts]
+    outs = []
+    for r in reqs:
+        toks = []
+        while True:
+            item = r.out_queue.get(timeout=300)
+            if item is None:
+                break
+            toks.append(item)
+        outs.append(toks)
+    return outs
 
 
 @pytest.fixture(scope="module")
-def engines():
-    fixed = build("fixed")
-    paged = build("paged")
-    yield fixed, paged
-    fixed.shutdown()
-    paged.shutdown()
+def paged():
+    """A float32 engine, gather-served: what the reference can pin."""
+    eng = build(dtype="float32")
+    yield eng
+    eng.shutdown()
 
 
-def test_greedy_token_identity(engines):
-    fixed, paged = engines
+def test_greedy_token_identity(paged):
     params = SamplingParams(temperature=0.0, max_tokens=12, seed=5)
-    assert collect(fixed, PROMPTS, params) == collect(paged, PROMPTS, params)
+    assert collect(paged, PROMPTS, params) == [reference_greedy(p, 12) for p in PROMPTS]
 
 
-def test_sampled_token_identity(engines):
-    fixed, paged = engines
+def test_sampled_streams_are_a_function_of_seed_and_position(paged):
+    """A seeded sampled stream repeats run to run and does not depend
+    on which other rows share its wave or its decode batch."""
     params = SamplingParams(temperature=0.9, top_p=0.8, max_tokens=12, seed=11)
-    assert collect(fixed, PROMPTS, params) == collect(paged, PROMPTS, params)
+    solo = collect(paged, PROMPTS, params)
+    assert collect(paged, PROMPTS, params) == solo
+    assert wave(paged, PROMPTS, params) == solo
+    other = SamplingParams(temperature=0.9, top_p=0.8, max_tokens=12, seed=12)
+    assert collect(paged, PROMPTS[:1], other) != solo[:1]
 
 
-def test_prefix_warm_zero_copy(engines):
-    """A paged prefix hit maps pages (refcount bump) — zero copy-program
-    dispatches — and streams identically to both its own cold pass and
-    the fixed layout's warm pass (which DOES dispatch copies)."""
-    fixed, paged = engines
+def test_prefix_warm_zero_copy(paged):
+    """A prefix hit maps pages (refcount bump, no device work) and
+    streams identically to its own cold pass and to the reference."""
     params = SamplingParams(temperature=0.0, max_tokens=10, seed=3)
     prompt = PREAMBLE + [7]
 
@@ -76,22 +95,15 @@ def test_prefix_warm_zero_copy(engines):
     cold = list(paged.iter_ids(prompt, params, timeout=300))
     warm = list(paged.iter_ids(prompt, params, timeout=300))
     m1 = paged.metrics
-    assert warm == cold
+    assert warm == cold == reference_greedy(prompt, 10)
     assert m1["prefix_cache_hits"] - m0["prefix_cache_hits"] >= 1
-    assert m1["prefix_copy_dispatches"] == m0["prefix_copy_dispatches"]
     assert m1["kv_prefix_pages_mapped"] - m0["kv_prefix_pages_mapped"] >= 1
-
-    f_cold = list(fixed.iter_ids(prompt, params, timeout=300))
-    f_warm = list(fixed.iter_ids(prompt, params, timeout=300))
-    m2 = fixed.metrics
-    assert f_cold == cold and f_warm == warm
-    assert m2["prefix_copy_dispatches"] > m1["prefix_copy_dispatches"]
+    assert m1["prefill_chunks"] - m0["prefill_chunks"] < 2 * 3  # the warm pass skipped cached chunks
 
 
-def test_pages_released_when_drained(engines):
+def test_pages_released_when_drained(paged):
     """After every stream completes, the only pages still held belong to
     prefix-cache entries; live-request accounting returns to zero."""
-    _, paged = engines
     params = SamplingParams(temperature=0.0, max_tokens=8, seed=2)
     collect(paged, PROMPTS, params)
     stats = paged.paged_stats()
@@ -102,64 +114,50 @@ def test_pages_released_when_drained(engines):
     assert stats["pages_in_use"] + stats["pages_free"] == stats["pages_capacity"]
 
 
-def test_int8_kv_token_identity():
-    fixed = build("fixed", kv_cache_dtype="int8")
-    paged = build("paged", kv_cache_dtype="int8")
+def test_int8_kv_determinism_and_spec_identity():
+    """An int8 pool has no float reference (quantisation moves the
+    logits): its streams repeat run to run, on a second engine built
+    the same way, and with speculation on."""
+    first = build(kv_cache_dtype="int8")
+    second = build(kv_cache_dtype="int8")
     try:
         params = SamplingParams(temperature=0.0, max_tokens=12, seed=5)
-        fixed_outs = collect(fixed, PROMPTS, params)
-        assert fixed_outs == collect(paged, PROMPTS, params)
-        # spec decode on the paged int8 engine stays identical too
-        assert paged.set_spec_decode(True)
-        assert collect(paged, PROMPTS, params) == fixed_outs
+        outs = collect(first, PROMPTS, params)
+        assert all(len(o) == 12 for o in outs)
+        assert collect(first, PROMPTS, params) == outs
+        assert collect(second, PROMPTS, params) == outs
+        assert second.set_spec_decode(True)
+        assert collect(second, PROMPTS, params) == outs
     finally:
-        fixed.shutdown()
-        paged.shutdown()
+        first.shutdown()
+        second.shutdown()
 
 
-def test_spec_decode_token_identity(engines):
-    fixed, paged = engines
+def test_spec_decode_token_identity(paged):
     params = SamplingParams(temperature=0.0, max_tokens=12, seed=5)
-    plain = collect(paged, PROMPTS, params)
     assert paged.set_spec_decode(True)
     try:
-        assert collect(paged, PROMPTS, params) == plain
+        assert collect(paged, PROMPTS, params) == [reference_greedy(p, 12) for p in PROMPTS]
     finally:
         paged.set_spec_decode(False)
 
 
-def test_mixed_concurrent_wave_identity(engines):
+def test_mixed_concurrent_wave_identity(paged):
     """A full mixed-length wave submitted at once (held admissions) —
-    the page-granular admission path — matches the fixed layout."""
-    fixed, paged = engines
+    the page-granular admission path — serves each row its own greedy
+    decode."""
     params = SamplingParams(temperature=0.0, max_tokens=10, seed=9)
-    prompts = [PREAMBLE + [i] for i in range(3)]
-
-    def wave(engine):
-        with engine.hold_admissions():
-            reqs = [engine.submit(p, params) for p in prompts]
-        outs = []
-        for r in reqs:
-            toks = []
-            while True:
-                item = r.out_queue.get(timeout=300)
-                if item is None:
-                    break
-                toks.append(item)
-            outs.append(toks)
-        return outs
-
-    assert wave(fixed) == wave(paged)
+    prompts = [PREAMBLE + [i] for i in range(2)] + [[42, 43, 44]]
+    assert wave(paged, prompts, params) == [reference_greedy(p, 10) for p in prompts]
 
 
 def test_minimal_pool_self_pin_no_livelock():
     """A request whose own pinned prefix match holds the pages whose
     eviction would fund it must still admit: funding retains the shared
     pages and UNPINS before the evict-and-retry loop (the allocator
-    refcount, not the pin, protects shared pages on the paged layout).
+    refcount, not the pin, protects shared pages).
     Before that ordering, this shape spun the dispatch loop forever."""
     paged = build(
-        "paged",
         max_batch_size=1,
         kv_pool_pages=9,  # 1 scratch + exactly one full-length request
         decode_block=4,
@@ -183,29 +181,30 @@ def test_minimal_pool_self_pin_no_livelock():
         paged.shutdown()
 
 
-def test_kernel_path_serves_decode_and_verify(engines):
+def test_kernel_path_serves_decode_and_verify():
     """The ragged Pallas kernel path (interpret mode on CPU — the same
-    kernel logic the TPU compiles). The op-level math is pinned
-    tier-1 against a jnp reference (tests/test_page_attention.py);
-    exact stream identity vs fixed is the HARDWARE bench A/B's gate —
-    on CPU the random-init debug weights sit at argmax-tie flatness
-    where the kernel's blockwise (non-bitwise) softmax legitimately
-    flips ties. What IS invariant here: greedy determinism, bitwise
-    first tokens (prefill never runs the kernel), full budgets, spec-on
+    kernel logic the TPU compiles) against the gather. The op-level math
+    is pinned tier-1 against a jnp reference
+    (tests/test_page_attention.py); exact stream identity is the chip's
+    to show (chip_smoke.py, the benchmark's reference comparison) — on
+    CPU the random-init debug weights sit at argmax-tie flatness where
+    the kernel's blockwise (non-bitwise) softmax legitimately flips
+    ties. What IS invariant here: greedy determinism, bitwise first
+    tokens (prefill never runs the kernel), full budgets, spec-on
     operation, and every decode dispatch charged to the kernel path."""
-    fixed, _ = engines
-    kern = build("paged", paged_kernel="interpret")
+    gather = build()
+    kern = build(paged_kernel="interpret")
     try:
         assert kern._paged_kernel == "interpret"
         assert kern._paged_verify_kernel == "interpret"
-        m0 = kern.metrics
         params = SamplingParams(temperature=0.0, max_tokens=12, seed=5)
-        fixed_outs = collect(fixed, PROMPTS, params)
+        fixed_outs = collect(gather, PROMPTS, params)
+        m0 = kern.metrics  # the families are process-wide: count after the gather engine is done
         outs = collect(kern, PROMPTS, params)
         # deterministic under greedy decoding
         assert collect(kern, PROMPTS, params) == outs
         # first tokens come from prefill/extend logits the kernel never
-        # touches — bitwise-equal to the fixed layout
+        # touches — bitwise-equal to the gather-served engine's
         assert [o[0] for o in outs] == [o[0] for o in fixed_outs]
         assert all(len(o) == 12 for o in outs)
         # spec decode rides the multi-query kernel rows and still runs
@@ -227,10 +226,11 @@ def test_kernel_path_serves_decode_and_verify(engines):
         assert kern.paged_stats()["attn_path"] == "kernel"
     finally:
         kern.shutdown()
+        gather.shutdown()
 
 
 def test_kernel_path_int8_runs_deterministically():
-    kern = build("paged", kv_cache_dtype="int8", paged_kernel="interpret")
+    kern = build(kv_cache_dtype="int8", paged_kernel="interpret")
     try:
         params = SamplingParams(temperature=0.0, max_tokens=12, seed=5)
         outs = collect(kern, PROMPTS, params)
@@ -240,13 +240,11 @@ def test_kernel_path_int8_runs_deterministically():
         kern.shutdown()
 
 
-def test_auto_layout_resolves_paged_here():
-    """The default kv_layout='auto' pages this geometry (layered +
-    chunked + 8-token pages tile 64); the kernel stays off on CPU with
-    paged_kernel='auto' — gather-served, loudly accounted."""
-    eng = build("auto")
+def test_gather_serves_the_cpu():
+    """The kernel stays off on CPU with paged_kernel='auto' —
+    gather-served, loudly accounted."""
+    eng = build()
     try:
-        assert eng._paged
         assert eng._paged_kernel is None
         assert eng.paged_stats()["attn_path"] == "gather"
         params = SamplingParams(temperature=0.0, max_tokens=6, seed=1)
@@ -261,16 +259,10 @@ def test_auto_layout_resolves_paged_here():
         eng.shutdown()
 
 
-def test_paged_requires_layered():
-    with pytest.raises(ValueError, match="layered"):
-        build("paged", serving_layout="scan")
-
-
 def test_paged_warmup_compiles():
-    """warmup() on a paged engine walks the chunked + window rungs
-    (tables threaded through every program) without touching live
-    state."""
-    paged = build("paged")
+    """warmup() walks the chunked + window rungs (tables threaded
+    through every program) without touching live state."""
+    paged = build()
     try:
         paged.warmup(prompt_lengths=[8, 20])
         params = SamplingParams(temperature=0.0, max_tokens=6, seed=1)
@@ -289,14 +281,14 @@ def test_int4_kv_deterministic_and_kernel_serves():
     (interpret) serves every decode dispatch over the packed pool. The
     op-level kernel-vs-dequant parity is pinned tier-1
     (tests/test_page_attention.py); exact stream identity vs the gather
-    is the hardware bench A/B's gate — on CPU the random-init debug
+    is the chip's to show — on CPU the random-init debug
     weights sit at argmax-tie flatness where the kernel's blockwise
     softmax legitimately flips ties (same bar as the bf16/int8 kernel
     tests above). First tokens come from prefill the kernel never
     touches, so those ARE bitwise. (int4 is NOT compared against
     int8/bf16 streams: halving the stored bits changes the numerics.)"""
     params = SamplingParams(temperature=0.0, max_tokens=12, seed=5)
-    gather = build("paged", kv_cache_dtype="int4")
+    gather = build(kv_cache_dtype="int4")
     try:
         assert gather._kv_quant and gather._kv_packed
         pool = gather._cache[0]
@@ -305,7 +297,7 @@ def test_int4_kv_deterministic_and_kernel_serves():
         assert pool["k"].shape[-1] == dh // 2  # two values per byte
         a = collect(gather, PROMPTS, params)
         assert collect(gather, PROMPTS, params) == a
-        kern = build("paged", kv_cache_dtype="int4", paged_kernel="interpret")
+        kern = build(kv_cache_dtype="int4", paged_kernel="interpret")
         try:
             assert kern._paged_kernel == "interpret"
             m0 = kern.metrics
@@ -330,9 +322,9 @@ def test_int4_kv_deterministic_and_kernel_serves():
 
 def test_int4_prefix_warm_zero_copy_and_spec_identity():
     """The page-mapping prefix hit and spec decode both survive the
-    packed pool: warm streams match cold with zero copy dispatches, and
-    spec-on matches spec-off."""
-    paged = build("paged", kv_cache_dtype="int4")
+    packed pool: warm streams match cold, and spec-on matches
+    spec-off."""
+    paged = build(kv_cache_dtype="int4")
     try:
         params = SamplingParams(temperature=0.0, max_tokens=10, seed=3)
         prompt = PREAMBLE + [7]
@@ -342,7 +334,7 @@ def test_int4_prefix_warm_zero_copy_and_spec_identity():
         m1 = paged.metrics
         assert warm == cold
         assert m1["prefix_cache_hits"] - m0["prefix_cache_hits"] >= 1
-        assert m1["prefix_copy_dispatches"] == m0["prefix_copy_dispatches"]
+        assert m1["kv_prefix_pages_mapped"] - m0["kv_prefix_pages_mapped"] >= 1
 
         plain = collect(paged, PROMPTS, params)
         assert paged.set_spec_decode(True)
@@ -352,11 +344,6 @@ def test_int4_prefix_warm_zero_copy_and_spec_identity():
             paged.set_spec_decode(False)
     finally:
         paged.shutdown()
-
-
-def test_int4_requires_paged_layout():
-    with pytest.raises(ValueError, match="int4"):
-        build("fixed", kv_cache_dtype="int4")
 
 
 # --------------------------------------------------------------------------- #
@@ -370,13 +357,13 @@ def test_adaptive_k_token_identity_with_fixed_k():
     accounted: adaptive rounds equal verify dispatches, and the mean
     picked K stays inside [k_min, k_max]."""
     params = SamplingParams(temperature=0.0, max_tokens=12, seed=5)
-    fixed = build("paged", spec_decode_enable="on", spec_draft_len=4)
+    fixed = build(spec_decode_enable="on", spec_draft_len=4)
     try:
         fixed_outs = collect(fixed, PROMPTS, params)
     finally:
         fixed.shutdown()
     adap = build(
-        "paged", spec_decode_enable="on", spec_draft_len=4,
+        spec_decode_enable="on", spec_draft_len=4,
         spec_adaptive_k="on", spec_adaptive_k_min=1,
     )
     try:
@@ -398,7 +385,7 @@ def test_adaptive_k_warm_ladder_no_hot_compiles():
     acceptance trajectory can reach an uncompiled verify shape: serving
     with adaptive K after warmup adds zero executables."""
     eng = build(
-        "paged", spec_decode_enable="on", spec_draft_len=4,
+        spec_decode_enable="on", spec_draft_len=4,
         spec_adaptive_k="on", spec_adaptive_k_min=1,
     )
     try:
@@ -416,16 +403,16 @@ def test_adaptive_k_warm_ladder_no_hot_compiles():
 
 
 def test_int4_disagg_token_identity():
-    """int4 under the disaggregated scheduler: the paged handoff moves
+    """int4 under the disaggregated scheduler: the handoff moves
     packed pages between tiers, and streams stay identical to the
     unified scheduler on the same packed pool."""
     params = SamplingParams(temperature=0.0, max_tokens=10, seed=7)
-    uni = build("paged", kv_cache_dtype="int4")
+    uni = build(kv_cache_dtype="int4")
     try:
         want = collect(uni, PROMPTS, params)
     finally:
         uni.shutdown()
-    dis = build("paged", kv_cache_dtype="int4", scheduler_policy="disagg")
+    dis = build(kv_cache_dtype="int4", scheduler_policy="disagg")
     try:
         assert collect(dis, PROMPTS, params) == want
     finally:

@@ -246,7 +246,7 @@ def test_engine_serves_every_prompt_shape_as_the_models_own_argmax(engine):
     the whole-sequence forward's argmax. Nothing compiles after warm-up."""
     from generativeaiexamples_tpu.engine.llm_engine import SamplingParams
 
-    assert engine._family.name == "phi4flash" and engine._paged and engine._paged_kernel == "interpret"
+    assert engine._family.name == "phi4flash" and engine._paged_kernel == "interpret"
     rng = np.random.default_rng(1)
     prompts = [[int(t) for t in rng.integers(3, 500, size=n)] for n in (5, 16, 37, 50, 9)]
     greedy = SamplingParams(temperature=0.0, max_tokens=20)
@@ -320,15 +320,11 @@ def test_engine_counts_resets_skipped_tokens_and_state_dispatches(engine):
 
 
 REFUSED = {
-    "fixed_layout": (dict(kv_layout="fixed"), "fixed KV layout"),
-    "scan_layout": (dict(serving_layout="scan"), "scan serving layout"),
-    "pipeline": (dict(pipeline_parallelism=2), "pipeline-parallel"),
     "tensor_parallel": (dict(tensor_parallelism=2), "sharded mesh"),
     "prefix_cache": (dict(prefix_cache_enable="auto", prefix_cache_slots=2), "prefix-cache reuse"),
     "spec_decode": (dict(spec_decode_enable="on"), "speculative verify"),
     "int8_weights": (dict(quantization="int8"), "quantization='int8'"),
     "int8_kv": (dict(kv_cache_dtype="int8"), "kv_cache_dtype='int8'"),
-    "monolithic_only": (dict(chunked_prefill="off"), "chunk to chunk"),
 }
 
 
@@ -341,15 +337,6 @@ def test_engine_build_refuses_what_cannot_carry_a_fixed_state(feature):
     with pytest.raises(ValueError, match=message) as exc:
         LLMEngine(EngineConfig(**dict(BASE, **overrides)))
     assert "fixed per-slot state" in str(exc.value)
-
-
-def test_slab_decode_is_refused(monkeypatch):
-    from generativeaiexamples_tpu.config import EngineConfig
-    from generativeaiexamples_tpu.engine.llm_engine import LLMEngine
-
-    monkeypatch.setenv("GENAI_TPU_DECODE_SLAB", "1")
-    with pytest.raises(ValueError, match="slab decode"):
-        LLMEngine(EngineConfig(**BASE))
 
 
 @pytest.mark.parametrize("call", ["drain", "restore_snapshot"])

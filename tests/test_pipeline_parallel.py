@@ -34,7 +34,7 @@ def test_split_merge_roundtrip():
 
 
 def test_pipelined_forward_matches_reference():
-    mesh = create_mesh(tensor_parallelism=1, pipeline_parallelism=2, data_parallelism=1)
+    mesh = create_mesh(tensor_parallelism=1, pipe_parallelism=2, data_parallelism=1)
     params = llama.init_params(CFG, jax.random.PRNGKey(0), dtype=jnp.float32)
     B, T = 4, 8
     tokens = jnp.asarray(
@@ -51,7 +51,7 @@ def test_pipelined_forward_matches_reference():
 
 
 def test_pipelined_forward_under_jit_and_grad():
-    mesh = create_mesh(tensor_parallelism=1, pipeline_parallelism=2)
+    mesh = create_mesh(tensor_parallelism=1, pipe_parallelism=2)
     params = llama.init_params(CFG, jax.random.PRNGKey(1), dtype=jnp.float32)
     B, T = 2, 8
     tokens = jnp.ones((B, T), jnp.int32)
@@ -71,8 +71,8 @@ def test_pipelined_forward_under_jit_and_grad():
 
 
 def test_mesh_with_pipe_axis_composes_with_tp():
-    mesh = create_mesh(tensor_parallelism=2, pipeline_parallelism=2, data_parallelism=2)
+    mesh = create_mesh(tensor_parallelism=2, pipe_parallelism=2, data_parallelism=2)
     assert mesh.shape == {"pipe": 2, "data": 2, "seq": 1, "model": 2}
 
     with pytest.raises(ValueError, match="not divisible"):
-        create_mesh(tensor_parallelism=-1, pipeline_parallelism=3)
+        create_mesh(tensor_parallelism=-1, pipe_parallelism=3)

@@ -46,8 +46,6 @@ TINY_PAGED = dict(
     decode_block=4,
     dtype="float32",
     tensor_parallelism=1,
-    serving_layout="layered",
-    kv_layout="paged",
     page_size=8,
     watchdog_stall_s=0.0,
     drain_timeout_s=30.0,

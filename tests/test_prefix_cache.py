@@ -20,7 +20,7 @@ TINY = dict(
     decode_block=2,
     dtype="float32",
     tensor_parallelism=1,
-    serving_layout="layered",
+    page_size=16,
 )
 
 PRE = [(i * 7) % 250 + 1 for i in range(32)]  # 2 chunks, shared preamble
@@ -173,8 +173,8 @@ def test_mixed_wave_with_partial_hits(golden):
 
 
 def test_int8_kv_warm_matches_cold():
-    """Prefix reuse through the head-major int8 cache layout (quantized
-    rows + scales copied verbatim): warm greedy == cold greedy."""
+    """Prefix reuse through the int8 page pool (quantized rows and
+    scales shared in place): warm greedy == cold greedy."""
     cfg = dict(TINY)
     eng = LLMEngine(
         EngineConfig(prefix_cache_slots=2, kv_cache_dtype="int8", **cfg)
@@ -192,35 +192,6 @@ def test_int8_kv_warm_matches_cold():
             assert warm == _greedy(ref, PRE + TAILS["q2"])
         finally:
             ref.shutdown()
-    finally:
-        eng.shutdown()
-
-
-def test_bench_shared_prefix_pass_hit_rate():
-    """bench.py's shared-prefix pass on the tiny engine: hit-rate >= 0.9
-    (1 cold insert + 15 warm hits) and both TTFT stats recorded — the
-    numbers that ride the BENCH_*.json line."""
-    import bench
-
-    eng = LLMEngine(EngineConfig(prefix_cache_slots=2, **TINY))
-    try:
-        eng.warmup(prompt_lengths=[8])
-        stats = bench._prefix_cache_pass(eng, SamplingParams)
-        assert stats is not None
-        assert stats["hit_rate"] >= 0.9
-        assert stats["preamble_tokens"] % TINY["prefill_chunk"] == 0
-        assert stats["tokens_reused"] >= stats["preamble_tokens"] * 14
-        assert stats["ttft_cold_s"] > 0 and stats["ttft_warm_p50_s"] > 0
-    finally:
-        eng.shutdown()
-
-
-def test_disabled_engine_skips_bench_pass():
-    import bench
-
-    eng = LLMEngine(EngineConfig(prefix_cache_enable="off", **TINY))
-    try:
-        assert bench._prefix_cache_pass(eng, SamplingParams) is None
     finally:
         eng.shutdown()
 

@@ -191,21 +191,6 @@ def test_divergent_sibling_tails_not_inserted():
     assert cache.stats()["entries"] == 1
 
 
-def test_invalidate_slot_for_warmup():
-    cache = PrefixCache(chunk=4, slots=2, max_len=64)
-    a = ids(9)
-    res = cache.insert(a)
-    assert res is not None
-    slot = res[0]
-    pinned = cache.match(a)
-    assert cache.invalidate_slot(slot) is False  # pinned: caller must skip
-    cache.release(pinned[0])
-    assert cache.invalidate_slot(slot) is True  # dropped + slot freed
-    assert cache.match(a) is None
-    assert cache.stats()["free_slots"] == 2
-    assert cache.invalidate_slot(slot) is True  # idempotent on free slot
-
-
 def test_engine_order_keeps_one_slot_per_conversation():
     """Engine call order per turn is match -> release (post-fetch) ->
     insert: the previous turn's entry is unpinned by insert time, so

@@ -33,11 +33,10 @@ TINY_DISAGG = dict(
     max_batch_size=4,
     max_seq_len=128,
     prefill_chunk=16,
-    page_size=16,  # pages must tile the 16-token chunk (paged required)
+    page_size=16,  # pages must tile the 16-token chunk
     decode_block=4,
     dtype="float32",
     tensor_parallelism=1,
-    serving_layout="layered",
     scheduler_policy="disagg",
     watchdog_stall_s=0.0,
 )
@@ -70,10 +69,9 @@ def test_validate_config_matrix():
             scheduler_mod.validate_config(cfg)
 
 
-def test_disagg_requires_paged_layout():
-    # Default 128-token pages cannot tile a 16-token chunk -> kv_layout
-    # auto resolves to fixed -> disagg must refuse loudly, not serve a
-    # handoff protocol with no page unit.
+def test_disagg_refuses_geometry_that_cannot_page():
+    # Default 128-token pages cannot tile a 16-token chunk: start-up
+    # refuses, it does not serve a handoff protocol with no page unit.
     cfg = EngineConfig(
         model_config_name="debug",
         max_batch_size=2,
@@ -81,10 +79,9 @@ def test_disagg_requires_paged_layout():
         prefill_chunk=16,
         decode_block=4,
         tensor_parallelism=1,
-        serving_layout="layered",
         scheduler_policy="disagg",
     )
-    with pytest.raises(ValueError, match="paged"):
+    with pytest.raises(ValueError, match="page_size"):
         LLMEngine(cfg)
 
 
@@ -318,11 +315,9 @@ def test_disagg_serves_concurrent_mixed_load_with_handoffs(deng):
     assert m1["handoffs"] - m0["handoffs"] >= 6
     assert m1["handoff_pages"] > m0["handoff_pages"]
     assert m1["handoff_bytes"] > m0["handoff_bytes"]
-    # ZERO prefill recompute on handed-off pages, and zero compiled
-    # copy dispatches (the paged zero-copy discipline holds across the
-    # tier boundary).
+    # ZERO prefill recompute on handed-off pages (the zero-copy
+    # discipline holds across the tier boundary).
     assert m1["handoff_recompute"] == m0["handoff_recompute"] == 0.0
-    assert m1["prefix_copy_dispatches"] == m0["prefix_copy_dispatches"]
 
 
 def test_disagg_streams_match_unified(deng):

@@ -218,6 +218,7 @@ def test_engine_warmup_precompiles_buckets(clean_app_env):
     clean_app_env.setenv("APP_ENGINE_MAXBATCHSIZE", "2")
     clean_app_env.setenv("APP_ENGINE_MAXSEQLEN", "64")
     clean_app_env.setenv("APP_ENGINE_PREFILLCHUNK", "16")
+    clean_app_env.setenv("APP_ENGINE_PAGESIZE", "16")
     clean_app_env.setenv("APP_ENGINE_TENSORPARALLELISM", "1")
     clean_app_env.setenv("APP_ENGINE_WARMUPPROMPTLENGTHS", "16,32")
     runtime.reset_runtime()

@@ -24,6 +24,7 @@ def tp_server_env(clean_app_env, tmp_path):
     clean_app_env.setenv("APP_ENGINE_MAXBATCHSIZE", "2")
     clean_app_env.setenv("APP_ENGINE_MAXSEQLEN", "96")
     clean_app_env.setenv("APP_ENGINE_PREFILLCHUNK", "16")
+    clean_app_env.setenv("APP_ENGINE_PAGESIZE", "16")
     clean_app_env.setenv("APP_ENGINE_DECODEBLOCK", "4")
     clean_app_env.setenv("APP_ENGINE_TENSORPARALLELISM", "8")
     clean_app_env.setenv("APP_ENGINE_WARMUPPROMPTLENGTHS", "")

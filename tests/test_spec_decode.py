@@ -25,7 +25,7 @@ TINY = dict(
     decode_block=1,
     dtype="float32",
     tensor_parallelism=1,
-    serving_layout="layered",
+    page_size=16,
 )
 
 # Calibrated copy-heavy prompt: greedy decode of the debug model from
@@ -298,20 +298,6 @@ def test_draft_crossing_attention_window_boundary():
         eng.shutdown()
 
 
-def test_scan_layout_disables_spec():
-    """spec_decode_enable='on' on the scan layout logs + disables (no
-    verify step there); the engine still serves correctly."""
-    cfg = dict(TINY, serving_layout="scan")
-    eng = LLMEngine(EngineConfig(spec_decode_enable="on", **cfg))
-    try:
-        assert not eng._spec_available
-        assert not eng._spec_enabled
-        assert eng.set_spec_decode(True) is False
-        assert len(_greedy(eng, COPY_PROMPT, n=8)) == 8
-    finally:
-        eng.shutdown()
-
-
 def test_knob_validation_at_engine_init():
     with pytest.raises(ValueError, match="spec_decode_enable"):
         LLMEngine(EngineConfig(spec_decode_enable="always", **TINY))
@@ -319,38 +305,3 @@ def test_knob_validation_at_engine_init():
         LLMEngine(EngineConfig(spec_draft_len=0, **TINY))
     with pytest.raises(ValueError, match="spec_ngram_max"):
         LLMEngine(EngineConfig(spec_ngram_max=-1, **TINY))
-
-
-def test_bench_spec_pass_meets_acceptance_bar(spec_eng):
-    """bench.py's (now three-way) spec pass on the tiny lookup engine:
-    on the copy-heavy set the lookup leg clears >= 1.5 emitted tokens
-    per dispatch with strictly fewer dispatches than spec-off, streams
-    identical — the numbers that ride the BENCH_*.json line. (No draft
-    model is configured on this engine, so the draft leg is skipped
-    with explicit perf_claim provenance; the full three-way bar lives
-    in tests/test_spec_draft.py.)"""
-    import bench
-
-    stats = bench._spec_decode_pass(spec_eng, SamplingParams, n_requests=3)
-    assert stats is not None
-    assert stats["streams_identical"] is True
-    assert set(stats["legs"]) == {"off", "lookup"}
-    assert "skipped: no resident draft model" in stats["perf_claim"]
-    copy = stats["prompt_sets"]["copy_heavy"]
-    assert copy["lookup"]["tokens_per_dispatch"] >= 1.5
-    assert copy["lookup"]["dispatches"] < copy["off"]["dispatches"]
-    assert copy["lookup"]["steps"] < copy["off"]["steps"]
-    assert 0.0 < copy["lookup"]["acceptance_rate"] <= 1.0
-    assert copy["lookup"]["accepted"] <= copy["lookup"]["drafted"]
-    assert copy["lookup"]["draft_dispatches"] == 0  # host-only proposer
-
-
-def test_disabled_path_skips_bench_pass():
-    import bench
-
-    cfg = dict(TINY, serving_layout="scan")
-    eng = LLMEngine(EngineConfig(**cfg))
-    try:
-        assert bench._spec_decode_pass(eng, SamplingParams) is None
-    finally:
-        eng.shutdown()

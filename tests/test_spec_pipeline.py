@@ -23,7 +23,7 @@ TINY = dict(
     decode_block=1,
     dtype="float32",
     tensor_parallelism=1,
-    serving_layout="layered",
+    page_size=16,
 )
 
 # Calibrated copy-heavy ramp (test_spec_decode.py): greedy decode of
@@ -102,8 +102,8 @@ def test_identity_int8_kv():
     _assert_identical(kv_cache_dtype="int8")
 
 
-def test_identity_paged_layout():
-    _assert_identical(kv_layout="paged", page_size=16)
+def test_identity_small_pages():
+    _assert_identical(page_size=8)
 
 
 def test_identity_disagg_scheduler():

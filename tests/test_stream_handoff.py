@@ -631,11 +631,11 @@ def paged_engine():
 
     eng = LLMEngine(EngineConfig(
         model_config_name="debug", max_batch_size=4, max_seq_len=128, prefill_chunk=16,
-        decode_block=2, dtype="float32", tensor_parallelism=1, serving_layout="layered",
-        page_size=8, kv_layout="paged", decode_runahead=1, watchdog_stall_s=0.0,
-        prefix_cache_slots=4, chunked_prefill="auto",
+        decode_block=2, dtype="float32", tensor_parallelism=1,
+        page_size=8, decode_runahead=1, watchdog_stall_s=0.0,
+        prefix_cache_slots=4,
     ))
-    assert eng._chunked and eng._paged and eng._prefix is not None
+    assert eng._prefix is not None
     yield eng
     eng.shutdown()
 

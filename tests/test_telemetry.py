@@ -1,5 +1,5 @@
 """Live utilization estimator: rolling-window MFU/HBM math must match
-the shared hardware module (the same formulas bench.py reports), and
+the shared hardware module, and
 the gauges must decay to zero when the window empties."""
 import time
 

@@ -14,7 +14,7 @@ import jax
 import jax.numpy as jnp
 
 from generativeaiexamples_tpu.models import llama
-from generativeaiexamples_tpu.ops import decode_attention, int8_matmul, quant
+from generativeaiexamples_tpu.ops import int8_matmul, quant
 from generativeaiexamples_tpu.parallel import tp_kernels
 from generativeaiexamples_tpu.parallel.mesh import create_mesh
 
@@ -190,32 +190,6 @@ def test_flash_attention_tp_matches_einsum(tp):
     )
 
 
-def test_decode_attention_tp_matches_xla(tp):
-    B, S = 2, 256
-    Hq, Hkv, Dh = CFG.num_heads, CFG.num_kv_heads, CFG.head_dim
-    assert tp_kernels.decode_attention_supported(CFG, SHARDS, S)
-    rng = np.random.default_rng(5)
-    q = jnp.asarray(rng.standard_normal((B, Hq, Dh)), jnp.bfloat16)
-    k = jnp.asarray(rng.standard_normal((B, Hkv, S, Dh)).astype(np.float32))
-    v = jnp.asarray(rng.standard_normal((B, Hkv, S, Dh)).astype(np.float32))
-    kq, ks = llama.quantize_kv(k)
-    vq, vs = llama.quantize_kv(v)
-    # scales arrive as [B, Hkv, 1, S] (head-major cache layout)
-    ks4 = ks.reshape(B, Hkv, 1, S)
-    vs4 = vs.reshape(B, Hkv, 1, S)
-    positions = jnp.asarray([S - 1, 17], jnp.int32)
-    got = tp_kernels.decode_attention_tp(q, kq, ks4, vq, vs4, positions, tp)
-    want = decode_attention.decode_attention_xla(
-        q[:, None], kq, ks4, vq, vs4, positions[:, None]
-    )[:, 0]
-    np.testing.assert_allclose(
-        np.asarray(got, np.float32),
-        np.asarray(want, np.float32),
-        rtol=0.05,
-        atol=0.05,
-    )
-
-
 # ------------------------------------------------------------------ //
 # model-level: decode over per-layer caches, TP kernels vs XLA reference
 
@@ -240,11 +214,11 @@ def test_decode_layers_tp_matches_xla_reference(tp):
     positions = jnp.asarray([0, 0], jnp.int32)
     got, _ = llama.decode_layers(
         params_tp, cfg, tokens, positions, caches_a, window=128,
-        kv_kernel=True, tp=tp,
+        tp=tp,
     )
     want, _ = llama.decode_layers(
         params_ref, cfg, tokens, positions, caches_b, window=128,
-        quant_kernel=False, kv_kernel=False,
+        quant_kernel=False,
     )
     np.testing.assert_allclose(
         np.asarray(got), np.asarray(want), rtol=0.05, atol=0.05
@@ -265,17 +239,18 @@ def test_engine_selects_tp_kernel_paths(monkeypatch):
         max_batch_size=2,
         max_seq_len=256,
         prefill_chunk=16,
+        page_size=16,
         tensor_parallelism=8,
         decode_block=2,
         quantization="int8",
         kv_cache_dtype="int8",
+        paged_kernel="interpret",
     )
     eng = LLMEngine(cfg)
     try:
         assert eng._tp is not None, "TP kernel context must engage"
-        assert eng._layered
         assert eng._kv_quant
-        assert eng._kv_kernel, "int8-KV decode kernel must be selected"
+        assert eng._paged_kernel == "interpret", "page kernel must be selected"
         # per-shard pack layout: unfused projections, per-shard padding
         layer0 = eng.params["layers"][0]
         assert "wq" in layer0 and "wqkv" not in layer0
@@ -303,6 +278,7 @@ def test_engine_tp_kernels_off_by_default_on_cpu():
         max_batch_size=2,
         max_seq_len=64,
         prefill_chunk=16,
+        page_size=16,
         tensor_parallelism=8,
         decode_block=2,
         quantization="int8",

@@ -1,21 +1,16 @@
 #!/usr/bin/env python3
-"""Hard perf-regression gate over loadgen / bench JSON lines.
+"""Hard perf-regression gate over loadgen JSON lines.
 
 Compares one measurement record (the last parseable JSON line of the
-given run file — the loadgen and bench stdout contract) against a
-committed baseline file, using the tolerance bands declared in
-``tools/loadgen/schema.py``. Two record shapes are understood:
-
-- **loadgen summaries** (``{"kind": "loadgen", ...}``) — every numeric
-  leaf is flattened to a dotted path and must be claimed by exactly one
-  schema pattern; unclaimed paths are SCHEMA DRIFT (exit 2, the
-  check_metric_docs contract: you cannot add a measurement without
-  deciding how it is judged). Claimed paths are gated by direction
-  (``higher`` / ``lower`` / ``equal`` / ``info``) inside their band
-  (``base*rel_tol + abs_tol``).
-- **bench contract lines** (``{"metric", "value", "unit"}``) — the
-  headline value is gated by its unit's direction with the default
-  bench band.
+given run file — the loadgen stdout contract) against a committed
+baseline file, using the tolerance bands declared in
+``tools/loadgen/schema.py``. A record is a loadgen summary
+(``{"kind": "loadgen", ...}``): every numeric leaf is flattened to a
+dotted path and must be claimed by exactly one schema pattern;
+unclaimed paths are SCHEMA DRIFT (exit 2, the check_metric_docs
+contract: you cannot add a measurement without deciding how it is
+judged). Claimed paths are gated by direction (``higher`` / ``lower`` /
+``equal`` / ``info``) inside their band (``base*rel_tol + abs_tol``).
 
 Provenance (utils/provenance.py) is enforced before any number is
 compared: records measured under a different config fingerprint or
@@ -233,37 +228,6 @@ def slo_undersampled(run: Dict[str, Any]) -> List[str]:
     return out
 
 
-def compare_bench(
-    run: Dict[str, Any], base: Dict[str, Any], overrides: Dict[str, Dict]
-) -> Tuple[List[str], List[str]]:
-    """Bench contract line: gate the headline value by unit direction."""
-    regressions: List[str] = []
-    notes: List[str] = []
-    if run.get("metric") != base.get("metric"):
-        regressions.append(
-            f"metric mismatch: run {run.get('metric')!r} vs baseline "
-            f"{base.get('metric')!r}"
-        )
-        return regressions, notes
-    direction = schema_mod.BENCH_UNITS.get(str(run.get("unit")), "higher")
-    ov = _override_for(str(run.get("metric")), overrides) or {}
-    rel = float(ov.get("rel_tol", schema_mod.DEFAULT_BENCH_REL_TOL))
-    abs_ = float(ov.get("abs_tol", 0.0))
-    run_val, base_val = float(run.get("value", 0.0)), float(base.get("value", 0.0))
-    band = abs(base_val) * rel + abs_
-    if direction == "higher" and run_val < base_val - band:
-        regressions.append(
-            f"{run['metric']}: {run_val:g} {run.get('unit')} < baseline "
-            f"{base_val:g} - band {band:g}"
-        )
-    elif direction == "lower" and run_val > base_val + band:
-        regressions.append(
-            f"{run['metric']}: {run_val:g} {run.get('unit')} > baseline "
-            f"{base_val:g} + band {band:g}"
-        )
-    return regressions, notes
-
-
 # --------------------------------------------------------------------------- #
 # Gate entry (importable: tests drive gate() directly)
 
@@ -279,11 +243,9 @@ def gate(
     report: Dict[str, Any] = {
         "drift": [], "regressions": [], "notes": [], "undersampled": [],
     }
-    is_bench = "metric" in run and "value" in run
-    if not is_bench:
-        report["drift"] = schema_check(run)
-        if report["drift"]:
-            return 2, report
+    report["drift"] = schema_check(run)
+    if report["drift"]:
+        return 2, report
     if record:
         return 0, report
 
@@ -303,18 +265,15 @@ def gate(
             "to a commit"
         )
 
-    if is_bench:
-        regressions, notes = compare_bench(run, base_rec, overrides)
-    else:
-        if base_rec.get("schema_version") != run.get("schema_version"):
-            report["drift"] = [
-                f"schema_version mismatch: baseline "
-                f"{base_rec.get('schema_version')!r} vs run "
-                f"{run.get('schema_version')!r} — re-record the baseline"
-            ]
-            return 2, report
-        regressions, notes = compare_loadgen(run, base_rec, overrides)
-        report["undersampled"] = slo_undersampled(run)
+    if base_rec.get("schema_version") != run.get("schema_version"):
+        report["drift"] = [
+            f"schema_version mismatch: baseline "
+            f"{base_rec.get('schema_version')!r} vs run "
+            f"{run.get('schema_version')!r} — re-record the baseline"
+        ]
+        return 2, report
+    regressions, notes = compare_loadgen(run, base_rec, overrides)
+    report["undersampled"] = slo_undersampled(run)
     report["regressions"] = regressions
     report["notes"].extend(notes)
     return (1 if regressions else 0), report

@@ -111,11 +111,10 @@ _CPU_SMOKE_ENV = {
     "APP_ENGINE_MAXBATCHSIZE": "4",
     "APP_ENGINE_MAXSEQLEN": "128",
     "APP_ENGINE_PREFILLCHUNK": "16",
-    # kv_layout defaults to auto->paged, but the default 128-token page
-    # cannot tile this profile's 16-token prefill chunk (auto would
-    # quietly fall back to fixed): shrink the page so the smoke profile
-    # exercises the DEFAULT serving layout — paged, gather-served on
-    # CPU — and the summary carries the paged_attn dispatch split.
+    # The default 128-token page cannot tile this profile's 16-token
+    # prefill chunk (start-up would refuse it): shrink the page. The
+    # pool is gather-served on CPU, and the summary carries the
+    # paged_attn dispatch split.
     "APP_ENGINE_PAGESIZE": "16",
     "APP_ENGINE_DECODEBLOCK": "4",
     "APP_ENGINE_TENSORPARALLELISM": "1",
@@ -292,9 +291,9 @@ _FULL_ENV = {
     "APP_ENGINE_KVCACHEDTYPE": "int8",
     "APP_ENGINE_MAXBATCHSIZE": "16",
     "APP_ENGINE_MAXSEQLEN": "4096",
-    # 128-token pages tile both the chunk and the window: kv_layout's
-    # auto default resolves to paged, served by the ragged Pallas
-    # kernel on a single-chip host (the gather on TP meshes).
+    # 128-token pages tile both the chunk and the window; the pool is
+    # read by the ragged Pallas kernel on a single-chip host (the
+    # gather on TP meshes).
     "APP_ENGINE_PREFILLCHUNK": "512",
     "APP_ENGINE_WARMUPPROMPTLENGTHS": "2048,2560,3072",
     "LOGLEVEL": "WARNING",
@@ -321,6 +320,10 @@ _FLEET_SMOKE_ENV = dict(
     # into same-wave misses via queue buildup — charging placement for
     # speculation. Spec keeps its own gated coverage in cpu_smoke.
     APP_ENGINE_SPECDECODEENABLE="off",
+    # ... and no resident draft either: a draft proposer builds its
+    # runtime even with speculation off, and cpu_smoke's `debug` draft
+    # (a 128-token window) cannot mirror debug-1k's 1024 positions.
+    APP_ENGINE_SPECPROPOSER="lookup",
     APP_ENGINE_PREFIXCACHESLOTS="16",
     # A prefix-cache "hit" counts at >= one chunk of shared prefix, and
     # EVERY request of a chain shares its ~226-token preamble — at

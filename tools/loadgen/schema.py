@@ -237,16 +237,6 @@ REQUIRED_METRICS = (
 SKIP_SUBTREES = ("provenance", "slo")
 SKIP_LEAVES = ("seed", "schema_version", "spec_hash", "profile", "kind", "workload")
 
-# bench JSON contract lines ({"metric", "value", "unit", ...}): the
-# headline value is gated by unit direction; everything else in a bench
-# line is narrative detail recorded for humans.
-BENCH_UNITS: Dict[str, str] = {
-    "tokens/s": "higher",
-    "qps": "higher",
-    "x_fewer_dispatches": "higher",
-}
-DEFAULT_BENCH_REL_TOL = 0.10
-
 
 def path_matches(pattern: str, path: str) -> bool:
     """Dotted-path wildcard match: each ``.``-separated component of

@@ -1,6 +1,6 @@
 """Run summarization: percentile math + the one-JSON-line record.
 
-One loadgen run emits ONE JSON line (the bench.py contract) holding
+One loadgen run emits ONE JSON line holding
 everything a trajectory comparison needs: the workload identity
 (spec hash, seed, profile), run provenance (git SHA/dirty, config
 fingerprint, weights regime — utils/provenance.py), client-observed
@@ -145,8 +145,8 @@ def build_summary(
     out["hit_rates"] = telemetry.get("hit_rates") or {}
     out["utilization"] = telemetry.get("utilization")
     out["slo"] = telemetry.get("slo")
-    # kernel-vs-gather dispatch split (paged engines; omitted when the
-    # server dispatched neither — fixed layout or no scrape)
+    # kernel-vs-gather dispatch split (omitted when the server
+    # dispatched neither, or no scrape)
     if telemetry.get("paged_attn"):
         out["paged_attn"] = telemetry["paged_attn"]
     # speculative-decoding block (spec-on engines; omitted when nothing

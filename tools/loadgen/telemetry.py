@@ -367,9 +367,8 @@ def spec_from_deltas(deltas: Dict[str, float]) -> Optional[Dict]:
 
 
 def paged_attn_from_deltas(deltas: Dict[str, float]) -> Optional[Dict]:
-    """Kernel-vs-gather dispatch split over the run window (paged
-    engines only — a fixed-layout server shows zero dispatches of
-    either kind and the block is omitted). ``kernel_share`` is the
+    """Kernel-vs-gather dispatch split over the run window (a server
+    that dispatched neither kind omits the block). ``kernel_share`` is the
     gate-facing ratio: a paged-kernel deployment silently regressing to
     the XLA gather (geometry drift, env force-off) drops it to 0."""
     kernel = deltas.get("paged_attn_kernel_dispatches", 0.0)

@@ -39,7 +39,6 @@ def main(argv=None) -> int:
     ap.add_argument("--prefill-chunk", type=int, default=512)
     ap.add_argument("--decode-block", type=int, default=8)
     ap.add_argument("--tensor-parallelism", type=int, default=-1)
-    ap.add_argument("--pipeline-parallelism", type=int, default=1)
     ap.add_argument(
         "--warmup-prompt-lengths",
         default="",
@@ -68,7 +67,6 @@ def main(argv=None) -> int:
             prefill_chunk=args.prefill_chunk,
             decode_block=args.decode_block,
             tensor_parallelism=args.tensor_parallelism,
-            pipeline_parallelism=args.pipeline_parallelism,
         )
     )
     t_boot = time.time() - t0

@@ -1,7 +1,7 @@
 """Profile steady-state decode on the real TPU (VERDICT r2 next #2).
 
-Builds the same engine bench.py measures (same BENCH_* env knobs,
-including the paged/spec/scheduler-era surface), fills every slot, then
+Builds an engine from BENCH_* env knobs (model, batch, quantization,
+page kernel, speculation, scheduler policy), fills every slot, then
 wraps ~PROFILE_SECONDS of steady-state decode in ``jax.profiler.trace``
 and attributes device time across the decode step: Pallas
 weight-streaming calls, XLA fusions, cache scatters, copies/transposes,
@@ -50,10 +50,7 @@ def build_engine():
         decode_block=int(os.environ.get("BENCH_BLOCK", "8")),
         quantization=os.environ.get("BENCH_QUANT", "int8"),
         kv_cache_dtype=os.environ.get("BENCH_KV", "bfloat16"),
-        # Post-paged/spec/scheduler surface (PRs 8-13): profile the
-        # attention layout and policy actually deployed, not the
-        # engine's pre-paged defaults.
-        kv_layout=os.environ.get("BENCH_KV_LAYOUT", "auto"),
+        # Profile the attention server and policy actually deployed.
         paged_kernel=os.environ.get("BENCH_PAGED_KERNEL", "auto"),
         spec_decode_enable=os.environ.get("BENCH_SPEC", "off"),
         scheduler_policy=os.environ.get("BENCH_SCHED", "unified"),
