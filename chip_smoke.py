@@ -237,7 +237,8 @@ def check_metrics_text(text: str, gather_explained: bool = False, before=None) -
 
 _KERNEL_LINE = re.compile(
     r"resolved kernel paths: quant_kernel=(\S+) "
-    r"paged_kernel=(\S+) paged_verify_kernel=(\S+) tp_kernels=(\S+) "
+    r"paged_kernel=(\S+) paged_verify_kernel=(\S+) "
+    r"(?:paged_extend_kernel=\S+ )?tp_kernels=(\S+) "
     r"\(backend=(\w+), devices=(\d+)\)"
 )
 _WARMUP_LINE = re.compile(

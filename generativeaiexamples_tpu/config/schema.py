@@ -376,8 +376,11 @@ class EngineConfig(ConfigWizard):
         default=512,
         help_txt="Prefill chunk (engine tokens): a prompt of one chunk "
         "prefills in one padded dispatch; a longer one runs as repeated "
-        "fixed-shape chunk dispatches against its pages, so the "
-        "compiled-shape set is bounded (wave sizes x attention windows) "
+        "chunk dispatches against its pages, each over the rows that "
+        "hold tokens in it at the narrowest width rung that fits them "
+        "(powers of four down from prefill_chunk, whole pages), so the "
+        "compiled-shape set is bounded (wave sizes x attention windows "
+        "at the full width, wave sizes alone at a narrow one) "
         "and NO prompt length can trigger an XLA compile inside a "
         "request (reference analogue: TRT-LLM chunked context). A "
         "multiple of page_size.",

@@ -143,6 +143,10 @@ class CompileWatch:
             self._record_compile(key, program, dt, post_warmup)
             return out
 
+        # the jitted callable itself: its ``_cache_size()`` counts the
+        # executables jit really holds, which key on more than shapes
+        # (an operand's sharding, whether it is committed)
+        dispatched.__wrapped__ = fn
         return dispatched
 
     def _record_compile(

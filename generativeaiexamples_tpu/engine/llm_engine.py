@@ -203,6 +203,13 @@ _M_PREFILL_TOKENS = _REG.counter(
     "Prompt tokens run through the prefill and extend programs "
     "(padding and cached-prefix tokens excluded).",
 )
+_M_EXTEND_COMPUTED = _REG.counter(
+    "genai_engine_extend_tokens_computed_total",
+    "Token positions the prefill and extend programs computed: rows "
+    "dispatched x width of each launch, padding rows and padding "
+    "positions included. genai_engine_prefill_tokens_total over this "
+    "is the live share of prefill compute.",
+)
 _M_STATE_RESETS = _REG.counter(
     "genai_engine_state_slot_resets_total",
     "Slots whose fixed per-slot state (recurrent state, window ring) "
@@ -755,6 +762,7 @@ class LLMEngine:
         # fallback to the XLA dequant gather.
         self._paged_kernel: Optional[str] = None
         self._paged_verify_kernel: Optional[str] = None
+        self._paged_extend_kernel: Optional[str] = None
         self._resolve_paged_kernel(cfg, model_cfg)
 
         # One line naming every resolved kernel path: auto modes fall
@@ -762,10 +770,11 @@ class LLMEngine:
         # asserts the outcome from here (chip_smoke.py).
         logger.info(
             "resolved kernel paths: quant_kernel=%s "
-            "paged_kernel=%s paged_verify_kernel=%s tp_kernels=%s "
+            "paged_kernel=%s paged_verify_kernel=%s "
+            "paged_extend_kernel=%s tp_kernels=%s "
             "(backend=%s, devices=%d)",
             self._quant_kernel, self._paged_kernel,
-            self._paged_verify_kernel,
+            self._paged_verify_kernel, self._paged_extend_kernel,
             f"{self._tp.shards}-way" if self._tp is not None else None,
             jax.default_backend(), jax.device_count(),
         )
@@ -877,8 +886,10 @@ class LLMEngine:
     def _resolve_paged_kernel(self, cfg: EngineConfig, model_cfg) -> None:
         """Pick the paged attention server per executable family.
 
-        ``self._paged_kernel`` (block decode, single-query rows) and
-        ``self._paged_verify_kernel`` (spec verify, K+1-wide rows) each
+        ``self._paged_kernel`` (block decode, single-query rows),
+        ``self._paged_verify_kernel`` (spec verify, K+1-wide rows) and
+        ``self._paged_extend_kernel`` (the narrow rungs of chunked
+        prefill, folded under the kernel's row cap) each
         hold None (XLA dequant gather) or 'compiled'/'interpret' (the
         ragged Pallas kernel, ops/page_attention.py). The fallback is
         LOUD by contract: an eligible platform whose geometry the
@@ -977,6 +988,20 @@ class LLMEngine:
                 "the page kernel's row cap; verify dispatches stay on "
                 "the XLA gather", verify_rows, kv_shape.num_heads,
             )
+        # A prompt's tail (the width ladder's rungs under prefill_chunk)
+        # reads through the kernel when every such width folds into
+        # sub-rows the kernel serves: ONE executable a row rung, no
+        # window rung (the walk follows each row's live pages).
+        narrow = self._chunk_widths()[:-1]
+        if narrow and all(
+            page_attention.supports_geometry(
+                *geom,
+                page_attention.query_fold(w, kv_shape.num_heads // shards),
+                interpret=interpret, kv_dtype=kv_dtype, shards=shards,
+            )
+            for w in narrow
+        ):
+            self._paged_extend_kernel = kind
 
     def _init_scheduler_state(self, cfg: EngineConfig) -> None:
         """Slot bookkeeping + dispatch/reader threads."""
@@ -1620,17 +1645,26 @@ class LLMEngine:
             return tokens, positions, caches, token_slab
 
         # Chunked prefill (VERDICT r3 #4): prompts longer than one chunk
-        # run as repeated (N, C, W)-shaped extend dispatches — a BOUNDED
-        # executable set (wave rungs x window rungs) covering every
-        # prompt length, so no request can hit a cold-bucket compile
-        # (observed without it: p95 108 s on developer_rag e2e when
-        # retrieval crossed cold buckets, and 36 single-bucket waves for
-        # 48 mixed-length questions).
+        # run as repeated (rows, width, W)-shaped extend dispatches — a
+        # BOUNDED executable set (_extend_signatures: row rungs x window
+        # rungs at the full chunk width, row rungs alone at a narrow
+        # one) covering every prompt length, so no request can hit a
+        # cold-bucket compile (observed without it: p95 108 s on
+        # developer_rag e2e when retrieval crossed cold buckets, and 36
+        # single-bucket waves for 48 mixed-length questions).
+        chunk = ecfg.prefill_chunk
+        extend_kernel = self._paged_extend_kernel
+
         def extend_batch_paged(params, caches, tokens, offsets, valid,
                                slots, last_h, tables, window):
+            # a narrow width (a prompt's tail) reads through the page
+            # kernel where it resolved; a family without that read
+            # gathers the window it is given
+            narrow = tokens.shape[1] < chunk
             cand, caches = fam.extend_paged(
                 params, cfg, caches, tokens, offsets, valid, slots, tables,
-                window, page, **paths,
+                window, page,
+                page_kernel=extend_kernel if narrow else None, **paths,
             )
             # (a family may hand back a wider hidden state than the
             # carried one; the carry keeps ONE dtype, so ONE executable)
@@ -1639,10 +1673,20 @@ class LLMEngine:
             )
             return last_h, caches
 
-        def finish_batch(params, last_h, lengths, temps, topps, seeds):
-            logits = fam.head(params, cfg, last_h, **paths)
+        def finish_batch(params, last_h, src, lengths, temps, topps, seeds):
+            # `src` maps each wave row to the row whose hidden it
+            # samples: itself, or row 0 for a padding row (a copy of row
+            # 0 that no chunk computed, so the duplicate slot scatter of
+            # the admission stays one value)
+            logits = fam.head(params, cfg, last_h[src], **paths)
             keys = sample_keys(base_key, seeds, lengths)
             return sample_tokens(logits[:, :V], keys, temps, topps)
+
+        def put_rows(last_h, rows, sub_h):
+            # a chunk that ran on fewer rows than the wave holds hands
+            # its hidden states back (a padding row's index is out of
+            # range: dropped)
+            return last_h.at[rows].set(sub_h, mode="drop")
 
         # Speculative verify step (prompt-lookup decoding, docs/
         # spec_decode.md): score the last accepted token plus K host-
@@ -1751,6 +1795,8 @@ class LLMEngine:
             ),
         )
         self._finish_fn = wrap("finish", jax.jit(finish_batch))
+        self._put_rows_fn = wrap("put_rows", jax.jit(put_rows))
+        self._zero_carries: Dict[int, object] = {}  # _zero_hidden's, by rows
         self._spec_verify_fn = wrap(
             "spec_verify",
             jax.jit(
@@ -1786,6 +1832,8 @@ class LLMEngine:
             "decode_dispatches": _M_DECODE_DISPATCHES.value,
             "admission_waves": _M_WAVES.value,
             "prefill_chunks": _M_PREFILL_CHUNKS.value,
+            "prefill_tokens": _M_PREFILL_TOKENS.value,
+            "extend_tokens_computed": _M_EXTEND_COMPUTED.value,
             "queue_wait_sum": _M_QUEUE_WAIT.sum,
             "queue_wait_n": _M_QUEUE_WAIT.count,
             "ttft_sum": _M_TTFT.sum,
@@ -2472,24 +2520,18 @@ class LLMEngine:
 
     def warmup_chunked_shapes(self) -> None:
         """Compile the WHOLE chunked-prefill executable set directly:
-        one extend per (wave rung, window rung) plus one finish per wave
-        rung. Zero-valid rows make every dispatch a value-level no-op on
-        the caches, so this needs no scheduler involvement — and after
-        it, NO prompt length can compile inside a request (the chunked
-        set covers every length up to max_seq_len).
+        one extend per ``_extend_signatures`` entry (rows, width,
+        window), one finish per wave rung and one ``put_rows`` per pair
+        of a wave rung and a smaller one. Zero-valid rows make every
+        dispatch a value-level no-op on the caches, so this needs no
+        scheduler involvement — and after it, NO prompt length can
+        compile inside a request (the chunked set covers every length
+        up to max_seq_len).
         """
         import jax.numpy as jnp
 
-        C = self.engine_config.prefill_chunk
-        D = self.model_config.hidden_size
-        dtype = self.params["embed"].dtype
-        windows = sorted(
-            {
-                self._attention_window(min((k + 1) * C, self.max_seq_len))
-                for k in range((self.max_seq_len + C - 1) // C)
-            }
-        )
-        cap = self._max_wave_rows(C)
+        signatures = self._extend_signatures()
+        row_rungs = sorted({n for n, _, _ in signatures})
         with self._compile_watch.warmup_scope(), self.hold_admissions():
             # Quiesce live decode before dispatching from THIS thread:
             # _extend_fn donates self._cache, and the dispatch thread's
@@ -2514,23 +2556,27 @@ class LLMEngine:
                     self._lock.wait(timeout=0.2)
                 if not self._running:
                     return
-            for n in sorted({min(s, cap) for s in self._wave_sizes()}):
-                tok = jnp.zeros((n, C), jnp.int32)
-                off = jnp.zeros((n,), jnp.int32)
-                valid = jnp.zeros((n,), jnp.int32)
-                slots = jnp.zeros((n,), jnp.int32)
-                last_h = jnp.zeros((n, D), dtype)
-                for W in windows:
+            for n in row_rungs:
+                zeros_n = jnp.zeros((n,), jnp.int32)
+                last_h = self._zero_hidden(n)
+                for _, width, W in (s for s in signatures if s[0] == n):
                     # zero-valid rows route every write to the
                     # scratch page — value-level no-ops even when
                     # slot 0's table holds stale entries
                     last_h, self._cache = self._extend_fn(
-                        self.params, self._cache, tok, off, valid,
-                        slots, last_h, self._tables_dev, W,
+                        self.params, self._cache,
+                        jnp.zeros((n, width), jnp.int32), zeros_n, zeros_n,
+                        zeros_n, last_h, self._tables_dev, W,
+                    )
+                for m in (m for m in row_rungs if m < n):
+                    last_h = self._put_rows_fn(
+                        last_h, jnp.full((m,), n, jnp.int32),
+                        self._zero_hidden(m),
                     )
                 self._finish_fn(
                     self.params,
                     last_h,
+                    zeros_n,
                     jnp.ones((n,), jnp.int32),
                     jnp.zeros((n,), jnp.float32),
                     jnp.ones((n,), jnp.float32),
@@ -3091,6 +3137,7 @@ class LLMEngine:
                 state_fields = self._state_counters(
                     "prefill", N, int(lengths[:N].sum()), 0, resets=N
                 )
+                _M_EXTEND_COMPUTED.inc(Np * bucket)
                 _dtl = self._dtl
                 if _dtl is not None:
                     _dtl_wall = time.time()
@@ -3404,26 +3451,34 @@ class LLMEngine:
 
     def _prefill_chunked(self, tokens, lengths, slots, temps, topps, seeds,
                          cached=None, reqs=None, between_chunks=None):
-        """Prefill a mixed-length wave as fixed-shape chunk dispatches.
+        """Prefill a mixed-length wave as chunk dispatches shaped by
+        what each chunk holds.
 
-        Each chunk k extends every row by up to prefill_chunk tokens at
-        offset k*C (rows whose prompt ended earlier run with valid=0 —
-        value-level no-ops). The per-row last-token hidden accumulates
-        across chunks on device; one finish dispatch samples the first
-        tokens. Shapes seen by XLA: (Np, C) x window rung — all warmed by
-        warmup_chunked_shapes, so no compile can land inside a request.
+        Chunk k extends the rows that have tokens at offset k*C by up to
+        prefill_chunk of them. Its dispatch carries only those rows,
+        padded up the wave ladder, at the narrowest rung of the width
+        ladder that holds the longest (``_chunk_rung``): a prompt's
+        tail runs at a tail's width, over the rows that have one, and a
+        chunk no row reaches is not dispatched. Offsets stay k*C, so
+        only a row's LAST chunk can be narrow and cached prefixes stay
+        chunk- and page-aligned. The per-row last-token hidden
+        accumulates on device over the whole wave's rows (a chunk of
+        fewer rows hands its own back through ``_put_rows_fn``); one
+        finish dispatch samples the first tokens. Shapes seen by XLA:
+        ``_extend_signatures`` — all warmed by warmup_chunked_shapes, so
+        no compile can land inside a request.
 
         ``cached`` ([Np] int32, chunk-aligned) marks each row's prefix
-        rows already present in its slot cache (copied from the prefix
-        store at admission): chunks fully below a row's cached length
-        run with valid=0, and the loop starts at the wave-wide minimum
-        cached chunk — a warm wave dispatches strictly fewer chunk
-        steps than a cold one (cached <= T-1 guarantees every row's
-        final chunk still runs, producing its last-token hidden).
+        rows already present in its slot cache (mapped from the prefix
+        store at admission): chunks below a row's cached length do not
+        hold it, so a warm wave dispatches strictly fewer chunk steps
+        than a cold one (cached <= T-1 guarantees every row's final
+        chunk still runs, producing its last-token hidden).
 
         ``reqs`` (the admitted wave, aligned with the first rows of
-        ``tokens``) feeds the flight recorder one ``prefill_chunk``
-        event per dispatched chunk per live row.
+        ``tokens``; the rows past them are padding, copies of row 0 that
+        no chunk computes) feeds the flight recorder one
+        ``prefill_chunk`` event per dispatched chunk per live row.
 
         ``between_chunks`` is called between two chunk dispatches (not
         after the last): the scheduler policy's step there, on this
@@ -3435,24 +3490,38 @@ class LLMEngine:
 
         C = self.engine_config.prefill_chunk
         Np, Tmax = tokens.shape
+        n_real = len(reqs) if reqs is not None else Np
         K = (Tmax + C - 1) // C
-        k0 = 0
-        if cached is not None and len(cached):
-            k0 = int(cached.min()) // C
         annotate = self._annotate
-        last_h = jnp.zeros(
-            (Np, self.model_config.hidden_size), self.params["embed"].dtype
-        )
-        slots_j = jnp.asarray(slots)
-        for k in range(k0, K):
-            tok_k = np.zeros((Np, C), np.int32)
-            seg = tokens[:, k * C:(k + 1) * C]
-            tok_k[:, : seg.shape[1]] = seg
+        last_h = self._zero_hidden(Np)
+        dispatched = 0
+        for k in range(K):
             valid = np.clip(lengths - k * C, 0, C).astype(np.int32)
             if cached is not None:
                 valid = np.where(k * C < cached, 0, valid).astype(np.int32)
-            offsets = np.full((Np,), k * C, np.int32)
-            W = self._attention_window(min((k + 1) * C, self.max_seq_len))
+            valid[n_real:] = 0
+            shape = self._chunk_rung(valid, n_real)
+            if shape is None:
+                continue
+            live, n, width = shape
+            if dispatched and between_chunks is not None:
+                between_chunks()
+            dispatched += 1
+            # the whole wave in place where the chunk's rung is the
+            # wave's (rows without tokens here ride along dead, their
+            # carried hidden kept by the program); else the live rows
+            # first, padded with dead copies of themselves
+            n_live = len(live)
+            whole = n == Np
+            pick = np.arange(Np) if whole else np.resize(live, n)
+            valid_k = valid[pick]
+            if not whole:
+                valid_k[n_live:] = 0
+            tok_k = np.zeros((n, width), np.int32)
+            seg = tokens[pick, k * C:k * C + width]
+            tok_k[:, : seg.shape[1]] = seg
+            offsets = np.full((n,), k * C, np.int32)
+            W = self._extend_window(k, width)
             # Each _extend_fn call donates the current cache's buffers;
             # read self._cache and rebind INSIDE the dispatch lock so
             # (a) an exception between chunk dispatches never leaves
@@ -3471,64 +3540,99 @@ class LLMEngine:
             with self._dispatch_lock, annotate("engine.prefill_chunk"):
                 if _dtl is not None:
                     _dtl_t1 = time.perf_counter()
-                last_h, self._cache = self._extend_fn(
+                sub_h, self._cache = self._extend_fn(
                     self.params,
                     self._cache,
                     jnp.asarray(tok_k),
                     jnp.asarray(offsets),
-                    jnp.asarray(valid),
-                    slots_j,
-                    last_h,
+                    jnp.asarray(valid_k),
+                    jnp.asarray(slots[pick]),
+                    last_h if whole else self._zero_hidden(n),
                     self._tables_dev,
                     W,
                 )
-            n_real = len(reqs) if reqs is not None else Np
-            live_rows = valid[:n_real] > 0
-            state_fields = self._state_counters(
-                "prefill_chunk", int(live_rows.sum()),
-                int(valid[:n_real].sum()),
-                int(live_rows.sum()) * min(k * C, self._span_fields.get("window", 0)),
-                resets=int(live_rows.sum()) if k == 0 else 0,
-            )
+            if whole:
+                last_h = sub_h
+            else:
+                put = np.full((n,), Np, np.int32)
+                put[:n_live] = live
+                last_h = self._put_rows_fn(last_h, jnp.asarray(put), sub_h)
+            live_tokens = int(valid[live].sum())
+            _M_EXTEND_COMPUTED.inc(n * width)
+            fields = {
+                "rows_dispatched": n,
+                "width": width,
+                "pad_tokens": n * width - live_tokens,
+            }
+            fields.update(self._state_counters(
+                "prefill_chunk", n_live, live_tokens,
+                n_live * min(k * C, self._span_fields.get("window", 0)),
+                resets=n_live if k == 0 else 0,
+            ) or {})
             if _dtl is not None:
                 _dtl.record_span(
                     "prefill_chunk",
                     t_wall=_dtl_wall,
                     lock_wait_s=_dtl_t1 - _dtl_t0,
                     run_s=time.perf_counter() - _dtl_t1,
-                    rows=int((valid > 0).sum()),
-                    tokens=int(valid.sum()),
+                    rows=n_live,
+                    tokens=live_tokens,
                     rids=(
                         [r.rid for r in reqs] if reqs is not None else ()
                     ),
-                    counters=state_fields,
+                    counters=fields,
                 )
             self._telemetry.record_dispatch(
-                "prefill", tokens=int(valid.sum()),
+                "prefill", tokens=live_tokens,
                 cache_bytes=hardware.kv_read_bytes_per_step(
-                    self._kv_shape, Np, W, self._kv_byte_width
+                    self._kv_shape, n, W, self._kv_byte_width
                 ),
-                rows=int((valid > 0).sum()),
+                rows=n_live,
             )
             if reqs is not None and flight_recorder.enabled():
-                for i, req in enumerate(reqs):
-                    if valid[i] > 0:
-                        flight_recorder.event_rid(
-                            req.rid, "prefill_chunk", chunk=k, window=W,
-                            tokens=int(valid[i]),
-                        )
-            if between_chunks is not None and k < K - 1:
-                between_chunks()
+                for i in live:
+                    flight_recorder.event_rid(
+                        reqs[i].rid, "prefill_chunk", chunk=k, window=W,
+                        tokens=int(valid[i]), width=width,
+                    )
+        src = np.arange(Np, dtype=np.int32)
+        src[n_real:] = 0
         first = self._finish_fn(
             self.params,
             last_h,
+            jnp.asarray(src),
             jnp.asarray(lengths),
             jnp.asarray(temps),
             jnp.asarray(topps),
             jnp.asarray(seeds),
         )
-        _M_PREFILL_CHUNKS.inc(K - k0)
+        _M_PREFILL_CHUNKS.inc(dispatched)
         return first
+
+    def _zero_hidden(self, rows: int):
+        """The zero carry of ``rows`` last-token hidden states, of the
+        kind a step program hands on: COMMITTED to the device, as every
+        output of a program over the engine's weights is (this one reads
+        a row of the embedding and keeps none of it). jit keys an
+        executable on whether an operand is committed, so a plain
+        ``jnp.zeros`` selects ANOTHER executable of the same program
+        than the carry an extend output is: a multi-second load on the
+        hot path that no compile counter sees (the compile watch keys
+        on shapes; found on the chip in PR 32, four such loads at the
+        start of the ramp). Warm-up and serving take every carry from
+        here or from an extend output; no program donates it, so one
+        array a row count serves for good."""
+        carry = self._zero_carries.get(rows)
+        if carry is None:
+            import jax
+            import jax.numpy as jnp
+
+            carry = self._zero_carries[rows] = jax.jit(
+                lambda embed: jnp.broadcast_to(
+                    jnp.where(False, embed[0], 0), (rows, embed.shape[1])
+                )
+            )(self.params["embed"])
+        return carry
 
     def _state_counters(self, kind: str, rows: int, tokens: int,
                         ring_tokens: int, resets: int = 0) -> Optional[Dict[str, int]]:
@@ -3593,6 +3697,63 @@ class LLMEngine:
             if s >= n:
                 return s
         return self.num_slots
+
+    def _chunk_widths(self) -> List[int]:
+        """Width ladder of an extend dispatch, by the row ladder's rule:
+        powers of four down from ``prefill_chunk``, whole pages, never
+        under one ({128, 512} at a chunk of 512 over pages of 128). No
+        finer: every rung multiplies executables."""
+        page = self.engine_config.page_size
+        widths = [self.engine_config.prefill_chunk]
+        while widths[-1] % (4 * page) == 0:
+            widths.append(widths[-1] // 4)
+        return widths[::-1]
+
+    def _chunk_rung(
+        self, valid: Sequence[int], n_real: int
+    ) -> Optional[Tuple[List[int], int, int]]:
+        """(live rows, rows dispatched, width) of one chunk of a wave,
+        from what the chunk holds: the rows with tokens in THIS chunk
+        (the wave's padding rows, past ``n_real``, are never live),
+        padded up the wave ladder under the chunk's row cap, at the
+        narrowest width rung that holds the longest of them. None where
+        no row is live: such a chunk is not dispatched."""
+        live = [i for i in range(n_real) if valid[i] > 0]
+        if not live:
+            return None
+        rows = min(
+            self._wave_pad(len(live)),
+            self._max_wave_rows(self.engine_config.prefill_chunk),
+        )
+        need = max(int(valid[i]) for i in live)
+        width = next(w for w in self._chunk_widths() if w >= need)
+        return live, rows, width
+
+    def _extend_window(self, k: int, width: int) -> int:
+        """The static attention window of chunk ``k`` at ``width``. A
+        full chunk gathers the power-of-two window that covers it. A
+        narrow one has ONE rung, capacity: under the page kernel the
+        walk follows each row's live pages whatever the window says, and
+        on the gather 128 queries over 4096 keys cost what 512 over 1024
+        do, the least a full-width tail pays."""
+        C = self.engine_config.prefill_chunk
+        if width < C:
+            return self.max_seq_len
+        return self._attention_window(min((k + 1) * C, self.max_seq_len))
+
+    def _extend_signatures(self) -> List[Tuple[int, int, int]]:
+        """Every (rows, width, window) an extend dispatch can have —
+        what ``_chunk_rung`` and ``_extend_window`` can produce, and
+        what warm-up compiles: no other."""
+        C = self.engine_config.prefill_chunk
+        cap = self._max_wave_rows(C)
+        chunks = range((self.max_seq_len + C - 1) // C)
+        return sorted({
+            (n, w, self._extend_window(k, w))
+            for n in {min(s, cap) for s in self._wave_sizes()}
+            for w in self._chunk_widths()
+            for k in chunks
+        })
 
     def _attention_window(self, needed: int) -> int:
         """Power-of-two attention window (>=128) covering `needed` rows."""

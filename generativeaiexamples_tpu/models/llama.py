@@ -1148,9 +1148,16 @@ def _chunk_layers_paged(
     attention READ for the ragged Pallas kernel
     (ops/page_attention.py): same post-write pools, a walk over each
     row's live pages instead of the bucketed-W gather. Writes are
-    identical either way. The engine only passes it for chunk widths
-    ``supports_geometry`` accepts (spec verify; prefill-length extends
-    stay on the gather)."""
+    identical either way. A chunk wider than the kernel's query-row cap
+    (the narrow rung of chunked prefill; spec verify fits whole) reads
+    as ``C // fold`` sub-rows of ``page_attention.query_fold`` queries,
+    each at its own first position over its row's table: every sub-row's
+    K/V is in the pool before the layer's read and the kernel clamps per
+    query, so each query sees the keys the gather's mask gives it. A
+    sub-row past ``valid`` reads one page from position 0 (discarded).
+    The engine passes ``page_kernel`` only for widths whose fold
+    ``supports_geometry`` accepts; a full prefill chunk stays on the
+    gather."""
     N, C = tokens.shape
     quantized = "ks" in caches[0]
     packed = quantized and caches[0]["k"].dtype == jnp.uint8
@@ -1169,11 +1176,29 @@ def _chunk_layers_paged(
     phys = jnp.take_along_axis(row_tables, positions // page_size, axis=1)
     phys = jnp.where((valid > 0)[:, None], phys, 0)  # dead rows -> scratch
     sip = positions % page_size
-    # one ragged work list per dispatch, shared by every layer's read
-    work = (
-        page_attention.page_work_list(row_tables, offsets, C, page_size)
-        if page_kernel else None
-    )
+    if page_kernel:
+        heads = cfg.num_heads // (tp.shards if tp is not None else 1)
+        fold = page_attention.query_fold(C, heads)
+        read_tables, read_pos = row_tables, offsets
+        if fold < C:
+            sub = fold * jnp.arange(C // fold, dtype=jnp.int32)[None, :]
+            read_pos = jnp.where(
+                sub < valid[:, None], offsets[:, None] + sub, 0
+            ).reshape(-1)
+            read_tables = jnp.repeat(row_tables, C // fold, axis=0)
+        # one ragged work list per dispatch, shared by every layer's read
+        work = page_attention.page_work_list(
+            read_tables, read_pos, fold, page_size
+        )
+
+    def kernel_read(q, ck, cv, cks=None, cvs=None):
+        out = _paged_kernel_read(
+            q.reshape((-1, fold) + q.shape[2:]), ck, cv, read_tables,
+            read_pos, cks, cvs, interpret=(page_kernel == "interpret"),
+            tp=tp, work=work,
+        )
+        return out.reshape(q.shape).astype(q.dtype)
+
     new_caches = []
     for lp, c in zip(params["layers"], caches):
         def attn(q, k, v, c=c):
@@ -1197,12 +1222,7 @@ def _chunk_layers_paged(
                 cvs = c["vs"].at[phys, sip].set(row_vs)
                 new_caches.append({"k": ck, "v": cv, "ks": cks, "vs": cvs})
                 if page_kernel:
-                    out = _paged_kernel_read(
-                        q, ck, cv, row_tables, offsets, cks, cvs,
-                        interpret=(page_kernel == "interpret"), tp=tp,
-                        work=work,
-                    ).astype(q.dtype)
-                    return out, ()
+                    return kernel_read(q, ck, cv, cks, cvs), ()
                 # plain dequant math (int->f32, scale multiply, cast)
                 # over the gathered token-major window into _attention
                 gk = _gather_page_window(ck, row_tables, Pw, page_size)
@@ -1232,12 +1252,7 @@ def _chunk_layers_paged(
                 cv = c["v"].at[phys, sip].set(row_v)
                 new_caches.append({"k": ck, "v": cv})
                 if page_kernel:
-                    out = _paged_kernel_read(
-                        q, ck, cv, row_tables, offsets,
-                        interpret=(page_kernel == "interpret"), tp=tp,
-                        work=work,
-                    ).astype(q.dtype)
-                    return out, ()
+                    return kernel_read(q, ck, cv), ()
                 out = _attention(
                     q,
                     _gather_page_window(ck, row_tables, Pw, page_size),
@@ -1268,10 +1283,10 @@ def extend_layers_paged(
 ) -> Tuple[jax.Array, list]:
     """``extend_layers`` over the page pool (chunked prefill).
 
-    ``page_kernel`` plumbs through to the ragged read — in practice the
-    engine leaves it None here: prefill-chunk widths exceed the
-    kernel's query-row cap (``page_attention.supports_geometry``), and
-    flash attention already covers the fresh-chunk half."""
+    ``page_kernel`` serves the read through the ragged kernel; the
+    engine passes it for the narrow rungs of the width ladder (a
+    prompt's tail), folded into sub-rows under the kernel's query-row
+    cap, and leaves a full ``prefill_chunk`` on the gather."""
     C = tokens.shape[1]
     h, new_caches = _chunk_layers_paged(
         params, cfg, tokens, offsets, valid, slots, tables, caches,

@@ -139,10 +139,10 @@ def _llama_family() -> ModelFamily:
         return logits, llama.write_prefill_pages(caches, kvs, tables[slots], page_size)
 
     def extend_paged(params, cfg, caches, tokens, offsets, valid, slots, tables, window, page_size, *,
-                     quant_kernel=None, tp=None, **_):
+                     quant_kernel=None, tp=None, page_kernel=None, **_):
         return llama.extend_layers_paged(
             params, cfg, tokens, offsets, valid, slots, tables, caches, window, page_size,
-            quant_kernel=quant_kernel, tp=tp,
+            quant_kernel=quant_kernel, tp=tp, page_kernel=page_kernel,
         )
 
     def decode_paged(params, cfg, caches, tokens, positions, live, tables, window, page_size, *,

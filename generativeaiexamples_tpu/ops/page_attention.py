@@ -34,8 +34,10 @@ Design:
 - **multi-query rows.** ``q`` is ``[B, T, Hq, Dh]``: T=1 is block
   decode; small T (spec verify's K+1 chunk) runs the same kernel with a
   per-query-row causal clamp (query t of row b attends tokens
-  ``<= positions[b] + t``). Long chunks (prefill extend) stay on the
-  XLA gather — ``supports_geometry`` refuses them.
+  ``<= positions[b] + t``). ``supports_geometry`` refuses a T past the
+  VMEM row cap; a wider chunk reads as several sub-rows of
+  ``query_fold`` queries each (the narrow rung of chunked prefill,
+  models/llama.py), a full prefill chunk stays on the XLA gather.
 
 Grid: one dimension of ``n_work = sum_b live_pages(b)`` steps, a
 DYNAMIC bound (``PrefetchScalarGridSpec`` takes a traced scalar; Mosaic
@@ -334,6 +336,19 @@ def paged_attention(
         interpret=interpret,
     )(work.row, work.page, work.phys, pos, *operands)
     return out
+
+
+def query_fold(query_len: int, num_heads: int) -> int:
+    """Queries a kernel row of a ``query_len``-wide chunk holds: the
+    largest divisor of ``query_len`` whose rows (queries x heads) fit
+    ``MAX_QUERY_ROWS`` (0: the heads alone pass it). A chunk wider than
+    that reads as ``query_len // fold`` sub-rows of one cache row, each
+    at its own first position; the clamp is per query, so the split
+    changes no query's key set."""
+    t = min(query_len, MAX_QUERY_ROWS // max(1, num_heads))
+    while t > 1 and query_len % t:
+        t -= 1
+    return t
 
 
 def supports_geometry(

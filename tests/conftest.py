@@ -34,7 +34,6 @@ import pytest
 # `pytest -m ""`). Auto-marked here so new tests in these files inherit
 # the tier without per-test decorators.
 SLOW_MODULES = {
-    "test_chunked_prefill",
     "test_engine",
     "test_engine_tp",
     "test_flash_attention",

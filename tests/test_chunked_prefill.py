@@ -4,6 +4,8 @@ XLA compile inside a request and admission waves mix prompt lengths.
 
 Reference analogue: TRT-LLM chunked context (docs/architecture.md:54-66).
 """
+import functools
+
 import pytest
 from greedy_reference import reference_greedy
 
@@ -110,19 +112,207 @@ def test_chunked_int8_kv_chunking_invariant(golden):
 
 
 def test_warmup_covers_all_lengths():
-    """After warm-up, serving any longer prompt adds NO new executable
-    of any step program — the no-compile-inside-request property, read
-    from the compile watch every program dispatches through."""
-    eng = LLMEngine(EngineConfig(**TINY))
+    """After warm-up, NO prompt length 1..max_seq_len and no wave size
+    adds an executable of any step program — the no-compile-inside-
+    request property, read from the compile watch every program
+    dispatches through — and warm-up compiled no extend signature the
+    shape rule cannot produce."""
+    cfg = dict(TINY, max_seq_len=96, prefill_chunk=32, page_size=8)
+    eng = LLMEngine(EngineConfig(**cfg))
     try:
         eng.warmup(prompt_lengths=[8])
         before = eng._compile_watch.snapshot()
         assert before["compile_executables_extend"] > 0 and before["compile_executables_finish"] > 0
-        _greedy(eng, [(i * 5) % 200 + 1 for i in range(100)], 4)  # 7 chunks
+        warmed = {
+            (args[2][1][0], args[2][1][1], args[8][2])
+            for (prog, (args, _)) in eng._compile_watch._seen if prog == "extend"
+        }
+        assert warmed == set(eng._extend_signatures())
+        assert {w for _, w, _ in warmed} == {8, 32}
+        # what jit itself holds: it keys an executable on more than the
+        # shapes the compile watch sees (a carry that is not committed
+        # to the device selects another one than a carry that is), so
+        # serving must not add to it either
+        jits = {name: getattr(eng, f"_{name}_fn").__wrapped__ for name in ("extend", "finish", "put_rows")}
+        held = {name: fn._cache_size() for name, fn in jits.items()}
+        assert held["extend"] == len(warmed)
+        lengths = list(range(1, 95))
+        size = 0
+        while lengths:
+            size = size % 4 + 1  # waves of 1, 2, 3, 4 rows in turn
+            with eng.hold_admissions():
+                reqs = [
+                    eng.submit([(i * 5) % 200 + 1 for i in range(lengths.pop())],
+                               SamplingParams(temperature=0.0, max_tokens=2))
+                    for _ in range(min(size, len(lengths)))
+                ]
+            for req in reqs:
+                while req.out_queue.get(timeout=300) is not None:
+                    pass
         after = eng._compile_watch.snapshot()
         assert after["compile_hot_path_total"] == 0
+        assert {name: fn._cache_size() for name, fn in jits.items()} == held
         assert {k: v for k, v in after.items() if k.startswith("compile_executables")} == {
             k: v for k, v in before.items() if k.startswith("compile_executables")
         }
     finally:
         eng.shutdown()
+
+
+# --------------------------------------------------------------------- //
+# The shape rule: a prompt's tail runs at a tail's width, over the rows
+# that have one (chunk 64 over pages of 16: widths {16, 64}, rows {1, 4})
+
+LADDER = dict(TINY, model_config_name="debug-1k", max_seq_len=256, prefill_chunk=64, page_size=16)
+TAILS = [1, 15, 16, 17, 63]  # 1, page - 1, page, page + 1, chunk - 1
+
+
+def _prompt(n, salt):
+    return [(i * salt + 3) % 250 + 1 for i in range(n)]
+
+
+SHORT = {"b": _prompt(40, 11), "c": _prompt(30, 13), "d": _prompt(10, 17)}
+
+
+@functools.lru_cache(maxsize=None)
+def _ref(prompt):
+    return reference_greedy(list(prompt), 4, preset="debug-1k")
+
+
+def _agrees(eng, toks, ref):
+    """The served stream is the reference's, up to where the reference
+    samples a stop id (which ends an answer and is never delivered)."""
+    return toks == ref[:len(toks)] and (len(toks) == len(ref) or ref[len(toks)] in eng._stop_ids)
+
+
+def _serve_wave(eng, prompts, hint=None):
+    """One admission wave of ``prompts``; the greedy streams."""
+    waves0 = eng.metrics.get("admission_waves", 0)
+    params = SamplingParams(temperature=0.0, max_tokens=4, prefix_hint=hint)
+    with eng.hold_admissions():
+        reqs = [eng.submit(p, params) for p in prompts]
+    out = []
+    for req in reqs:
+        toks = []
+        while (item := req.out_queue.get(timeout=300)) is not None:
+            toks.append(item)
+        out.append(toks)
+    assert eng.metrics["admission_waves"] == waves0 + 1
+    return out
+
+
+def _waves(tail):
+    """name -> prompts of one wave: a 64 + tail prompt alone (one-row
+    rung), with three short rows that sit the tail chunk out (the tail
+    runs on one row of the four), with a second tail and two short rows
+    (the tail runs on the wave's four rows, two of them dead)."""
+    a, a2 = _prompt(64 + tail, 7), _prompt(64 + max(1, tail - 1), 5)
+    return {
+        "one_row": [a],
+        "tail_on_one_of_four": [SHORT["b"], a, SHORT["c"], SHORT["d"]],
+        "tail_on_four": [a, SHORT["b"], a2, SHORT["d"]],
+    }
+
+
+@pytest.fixture(scope="module")
+def ladder_engine():
+    # (no prefix reuse: the waves share prompts, and the token counts
+    # below are those of cold rows)
+    eng = LLMEngine(EngineConfig(prefix_cache_enable="off", **LADDER))
+    assert eng._chunk_widths() == [16, 64]
+    yield eng
+    eng.shutdown()
+
+
+@pytest.mark.parametrize("wave", ["one_row", "tail_on_one_of_four", "tail_on_four"])
+@pytest.mark.parametrize("tail", TAILS)
+def test_tail_chunks_match_the_cache_free_forward(ladder_engine, tail, wave):
+    prompts = _waves(tail)[wave]
+    computed0 = ladder_engine.metrics["extend_tokens_computed"]
+    got = _serve_wave(ladder_engine, prompts)
+    for p, toks in zip(prompts, got):
+        assert _agrees(ladder_engine, toks, _ref(tuple(p))), (wave, tail, len(p), toks)
+    # the tail chunk ran at the narrowest rung that holds it, over the
+    # rows that have one: not rows x prefill_chunk again
+    rows = {"one_row": 1, "tail_on_one_of_four": 1, "tail_on_four": 4}[wave]
+    width = 16 if tail <= 16 else 64
+    assert ladder_engine.metrics["extend_tokens_computed"] - computed0 == len(prompts) * 64 + rows * width
+
+
+@pytest.fixture(scope="module")
+def prefix_engine():
+    eng = LLMEngine(EngineConfig(prefix_cache_enable="auto", **LADDER))
+    first = _prompt(64 + 20, 7)
+    assert _agrees(eng, _serve_wave(eng, [first], hint="rag:test")[0], _ref(tuple(first)))
+    yield eng, first
+    eng.shutdown()
+
+
+@pytest.mark.parametrize("tail", TAILS)
+def test_tail_chunk_of_a_prefix_hit_row(prefix_engine, tail):
+    """A row whose first chunk is a prefix hit runs ONLY its tail, at
+    the tail's width: alone (a narrow chunk at offset 64 and nothing
+    else), and beside a cold row of two chunks."""
+    eng, first = prefix_engine
+    warm = first[:64] + _prompt(tail, 19)
+    cold = _prompt(64 + 5, 23 + tail)
+    for wave in ([warm], [warm, cold]):
+        hits0, computed0 = eng.metrics["prefix_cache_hits"], eng.metrics["extend_tokens_computed"]
+        got = _serve_wave(eng, wave, hint="rag:test")
+        assert eng.metrics["prefix_cache_hits"] - hits0 == 1
+        for p, toks in zip(wave, got):
+            assert _agrees(eng, toks, _ref(tuple(p))), (len(wave), len(p), toks)
+        if len(wave) == 1:
+            assert eng.metrics["extend_tokens_computed"] - computed0 == (16 if tail <= 16 else 64)
+
+
+@pytest.fixture(scope="module")
+def int8_streams():
+    """int8 KV: per-token quantization is independent of chunking, so an
+    engine whose width ladder has one rung (pages as large as the chunk)
+    must serve EXACTLY the tokens the two-rung engine serves."""
+    engines = {
+        rungs: LLMEngine(EngineConfig(kv_cache_dtype="int8", **dict(LADDER, page_size=page)))
+        for rungs, page in (("ladder", 16), ("fixed", 64))
+    }
+    assert engines["ladder"]._chunk_widths() == [16, 64] and engines["fixed"]._chunk_widths() == [64]
+    yield engines
+    for eng in engines.values():
+        eng.shutdown()
+
+
+@pytest.mark.parametrize("wave", ["one_row", "tail_on_one_of_four", "tail_on_four"])
+@pytest.mark.parametrize("tail", TAILS)
+def test_tail_chunks_int8_kv_equal_the_fixed_width_walk(int8_streams, tail, wave):
+    prompts = _waves(tail)[wave]
+    got = {name: _serve_wave(eng, prompts) for name, eng in int8_streams.items()}
+    assert got["ladder"] == got["fixed"]
+    assert any(len(toks) == 4 for toks in got["ladder"])
+
+
+@pytest.fixture(scope="module")
+def kernel_engine():
+    """The tail's read through the page kernel (interpreted), with the
+    kernel's row cap lowered so that a 16-wide tail of this model's four
+    heads folds into sub-rows of four queries, as a 128-wide tail of 32
+    heads folds into sub-rows of 16 on the chip."""
+    from generativeaiexamples_tpu.ops import page_attention
+
+    cap, page_attention.MAX_QUERY_ROWS = page_attention.MAX_QUERY_ROWS, 16
+    eng = LLMEngine(EngineConfig(prefix_cache_enable="off", paged_kernel="interpret", **LADDER))
+    try:
+        assert eng._paged_extend_kernel == "interpret"
+        # one narrow program a row rung, whatever the chunk: no window rung
+        assert [s for s in eng._extend_signatures() if s[1] == 16] == [(1, 16, 256), (4, 16, 256)]
+        yield eng
+    finally:
+        eng.shutdown()
+        page_attention.MAX_QUERY_ROWS = cap
+
+
+@pytest.mark.parametrize("wave", ["tail_on_one_of_four", "tail_on_four"])
+@pytest.mark.parametrize("tail", [1, 16])
+def test_tail_chunks_through_the_page_kernel(kernel_engine, tail, wave):
+    prompts = _waves(tail)[wave]
+    for p, toks in zip(prompts, _serve_wave(kernel_engine, prompts)):
+        assert _agrees(kernel_engine, toks, _ref(tuple(p))), (wave, tail, len(p), toks)

@@ -445,3 +445,82 @@ def test_validate_config_rejects_bad_knobs():
             dispatch_timeline_enable="on",
             dispatch_timeline_capacity=dtl.WINDOW_SPANS - 1,
         ))
+
+
+# --------------------------------------------------------------------------- #
+# The prefill_chunk span says what its dispatch computed (chunked
+# prefill's shape rule: engine/llm_engine.py _chunk_rung)
+
+import pytest  # noqa: E402
+
+
+@pytest.fixture(scope="module")
+def chunk_engine():
+    from generativeaiexamples_tpu.config import EngineConfig
+    from generativeaiexamples_tpu.engine.llm_engine import LLMEngine
+
+    eng = LLMEngine(EngineConfig(
+        model_config_name="debug-1k", max_batch_size=4, max_seq_len=256, prefill_chunk=64, page_size=16,
+        decode_block=2, dtype="float32", tensor_parallelism=1, prefix_cache_enable="off",
+    ))
+    yield eng
+    eng.shutdown()
+
+
+def _engine_counters():
+    from generativeaiexamples_tpu.utils import metrics as metrics_mod
+
+    out = {}
+    for line in metrics_mod.get_registry().render().splitlines():
+        if line.startswith("genai_engine_") and " " in line:
+            k, v = line.rsplit(" ", 1)
+            out[k] = float(v)
+    return out
+
+
+CHUNK_WAVES = {
+    # prompt lengths of one wave -> (rows, tokens, rows_dispatched, width, pad_tokens) of each chunk span
+    "tail_on_one_of_three": ([69, 40, 10], [(3, 114, 4, 64, 142), (1, 5, 1, 16, 11)]),
+    "two_tails": ([69, 40, 90, 64], [(4, 232, 4, 64, 24), (2, 31, 4, 64, 225)]),
+    "two_narrow_tails": ([69, 70, 10], [(3, 138, 4, 64, 118), (2, 11, 4, 16, 53)]),
+    "one_row_two_full_chunks_and_a_page": ([144], [(1, 64, 1, 64, 0), (1, 64, 1, 64, 0), (1, 16, 1, 16, 0)]),
+}
+
+
+@pytest.mark.parametrize("name", sorted(CHUNK_WAVES))
+def test_prefill_chunk_span_carries_rows_width_and_padding(chunk_engine, name):
+    from generativeaiexamples_tpu.engine.llm_engine import SamplingParams
+
+    lengths, expect = CHUNK_WAVES[name]
+    before, cursor = _engine_counters(), dtl.spans_since(0)[1]
+    with chunk_engine.hold_admissions():
+        reqs = [
+            chunk_engine.submit([(i * 7 + n) % 250 + 1 for i in range(n)],
+                                SamplingParams(temperature=0.0, max_tokens=2))
+            for n in lengths
+        ]
+    for req in reqs:
+        while req.out_queue.get(timeout=300) is not None:
+            pass
+    spans = [s for s in dtl.spans_since(cursor)[0] if s["kind"] == "prefill_chunk"]
+    got = [(s["rows"], s["tokens"], s["rows_dispatched"], s["width"], s["pad_tokens"]) for s in spans]
+    assert got == expect
+    for s in spans:
+        assert s["pad_tokens"] == s["rows_dispatched"] * s["width"] - s["tokens"] >= 0
+    after = _engine_counters()
+    grew = lambda k: after.get(k, 0.0) - before.get(k, 0.0)  # noqa: E731
+    assert grew("genai_engine_prefill_tokens_total") == sum(lengths)
+    assert grew("genai_engine_extend_tokens_computed_total") == sum(e[2] * e[3] for e in expect)
+    assert grew("genai_engine_prefill_chunks_total") == len(expect)
+
+
+def test_monolithic_prefill_counts_its_padded_bucket(chunk_engine):
+    from generativeaiexamples_tpu.engine.llm_engine import SamplingParams
+
+    before = _engine_counters()
+    list(chunk_engine.iter_ids([5] * 20, SamplingParams(temperature=0.0, max_tokens=2), timeout=300))
+    after = _engine_counters()
+    assert after["genai_engine_prefill_tokens_total"] - before["genai_engine_prefill_tokens_total"] == 20
+    # one row of one 64-token bucket: live share 20 / 64
+    assert (after["genai_engine_extend_tokens_computed_total"]
+            - before["genai_engine_extend_tokens_computed_total"]) == 64

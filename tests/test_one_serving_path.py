@@ -109,7 +109,8 @@ def test_an_engine_always_has_a_page_allocator_and_one_program_a_step_kind(engin
         assert not hasattr(engine, gone), gone
     snap = engine._compile_watch.snapshot()
     families = {k[len("compile_executables_"):] for k in snap if k.startswith("compile_executables_")}
-    assert families == {"prefill", "decode", "extend", "finish", "update_slots", "page_tables"}
+    # (put_rows: the tiny program that hands a chunk of fewer rows' hidden states back to its wave)
+    assert families == {"prefill", "decode", "extend", "finish", "put_rows", "update_slots", "page_tables"}
     # the gather serves the CPU: one decode program a window rung, and
     # nothing compiled after warm-up
     assert snap["compile_executables_decode"] == len(engine._window_rungs())

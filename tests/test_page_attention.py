@@ -576,3 +576,63 @@ def test_decode_span_carries_pages_walked_and_the_dense_grid():
     finally:
         eng.shutdown()
         dtl.reset()
+
+
+# --------------------------------------------------------------------- //
+# A chunk wider than the query-row cap reads as sub-rows (the narrow
+# rung of chunked prefill, models/llama.py _chunk_layers_paged)
+
+
+@pytest.mark.parametrize(
+    "query_len,heads,expect",
+    [
+        (128, 32, 16),  # the benchmark's tail: 8 sub-rows of 16 x 32 = 512 rows
+        (128, 8, 64),  # four-way sharded heads
+        (5, 32, 5),  # spec verify fits whole
+        (1, 32, 1),  # decode
+        (16, 4, 16),
+        (12, 100, 4),  # the largest DIVISOR under the cap (5 does not divide 12)
+        (8, 1024, 0),  # the heads alone pass the cap
+    ],
+)
+def test_query_fold(query_len, heads, expect):
+    fold = pa.query_fold(query_len, heads)
+    assert fold == expect
+    if fold:
+        assert query_len % fold == 0 and fold * heads <= pa.MAX_QUERY_ROWS
+
+
+@pytest.mark.parametrize("kv", ["float32", "int8"])
+@pytest.mark.parametrize("valid", [[16, 5, 0], [1, 9, 12]])
+def test_folded_extend_read_matches_the_gather(monkeypatch, kv, valid):
+    """``extend_layers_paged`` of a 16-wide chunk with the kernel's row
+    cap lowered to 16 (4 heads: sub-rows of 4 queries) against the same
+    walk on the gather: hidden states of the valid tokens' last
+    position and every pool byte written."""
+    monkeypatch.setattr(pa, "MAX_QUERY_ROWS", 16)
+    cfg = llama.PRESETS["debug"]
+    params = llama.consume_split_params_layers(llama.init_params_fast(cfg, 0, jnp.float32))
+    page, pmax, rows = 8, 8, 3
+    assert pa.query_fold(16, cfg.num_heads) == 4
+    rng = np.random.default_rng(7)
+    tables = jnp.asarray(1 + np.arange(rows * pmax).reshape(rows, pmax), jnp.int32)
+    slots = jnp.arange(rows, dtype=jnp.int32)
+    first = jnp.asarray(rng.integers(1, cfg.vocab_size, (rows, 16)), jnp.int32)
+    tail = jnp.asarray(rng.integers(1, cfg.vocab_size, (rows, 16)), jnp.int32)
+    full = jnp.full((rows,), 16, jnp.int32)
+    outs = {}
+    for kernel in (None, "interpret"):
+        caches = llama.init_kv_pool(cfg, 1 + rows * pmax, page, jnp.float32, quantized=kv == "int8")
+        _, caches = llama.extend_layers_paged(
+            params, cfg, first, jnp.zeros((rows,), jnp.int32), full, slots, tables, caches, 64, page,
+        )
+        outs[kernel] = llama.extend_layers_paged(
+            params, cfg, tail, full, jnp.asarray(valid, jnp.int32), slots, tables, caches, 64, page,
+            page_kernel=kernel,
+        )
+    (h_g, c_g), (h_k, c_k) = outs[None], outs["interpret"]
+    live = np.asarray(valid) > 0
+    # layer 0's K/V does not depend on the read; layer 1's does, through the hidden state
+    np.testing.assert_allclose(np.asarray(h_k)[live], np.asarray(h_g)[live], atol=2e-2, rtol=2e-2)
+    for a, b in zip(jax.tree.leaves(c_g[0]), jax.tree.leaves(c_k[0])):
+        assert bool(jnp.all(a[1:] == b[1:]))

@@ -132,6 +132,37 @@ def test_chunked_extend_carries_state_from_chunk_to_chunk(params, sequences, chu
     assert err(lg[1], again[0, 45]) < 5 * TOL
 
 
+@pytest.mark.parametrize("tail", [1, 5, 7, 8])
+def test_a_narrow_last_chunk_leaves_what_the_fixed_width_walk_leaves(params, sequences, tail):
+    """The engine runs a prompt's tail at a tail's width (the 583-token
+    prompt of the benchmark: a chunk of 512, then 71 tokens in a chunk of
+    128 at the capacity window instead of 512 at window 1024). At this
+    size: a chunk of 32, then ``tail`` tokens in a chunk of 8 (one
+    window, one page). Scan state, conv tails, rings, the slot's pages
+    and the logits equal what the fixed-width walk leaves."""
+    toks, full = sequences
+    n = 32 + tail
+    slot, ends = jnp.asarray([2], jnp.int32), {}
+    for name, width, window in (("fixed", 32, 64), ("narrow", 8, PAGE * PMAX)):
+        caches = dirty_caches()
+        _, caches = m.extend_paged(params, CFG, caches, toks[0:1, :32], jnp.asarray([0], jnp.int32),
+                                   jnp.asarray([32], jnp.int32), slot, TABLES, 32, PAGE)
+        seg = jnp.pad(toks[0:1, 32:n], ((0, 0), (0, width - tail)))
+        hidden, caches = m.extend_paged(params, CFG, caches, seg, jnp.asarray([32], jnp.int32),
+                                        jnp.asarray([tail], jnp.int32), slot, TABLES, window, PAGE)
+        ends[name] = (m.head(params, CFG, hidden)[0], caches)
+    (lg_f, c_f), (lg_n, c_n) = ends["fixed"], ends["narrow"]
+    assert err(lg_n, full[0, n - 1]) < TOL and err(lg_n, lg_f) < TOL
+    mine = np.asarray(TABLES[2])[: -(-n // PAGE)]  # the slot's live pages
+    for a, b in zip(jax.tree.leaves(c_f), jax.tree.leaves(c_n)):
+        if a.shape[0] == SLOTS:  # scan state, conv tails, rings: every slot
+            assert err(a, b) < TOL
+        else:  # the pool
+            assert err(a[mine], b[mine]) < TOL
+    lg, _ = decode(params, c_n, {2: (toks[0, n], n)})  # and decodes on from there
+    assert err(lg[2], full[0, n]) < 5 * TOL
+
+
 def test_a_row_with_nothing_valid_changes_nothing(params):
     before = dirty_caches()
     _, after = m.extend_paged(params, CFG, before, jnp.zeros((2, 16), jnp.int32), jnp.zeros((2,), jnp.int32),
@@ -258,6 +289,42 @@ def test_engine_serves_every_prompt_shape_as_the_models_own_argmax(engine):
     assert after["paged_attn_kernel_dispatches"] > before["paged_attn_kernel_dispatches"]
     assert after["paged_attn_gather_dispatches"] == before["paged_attn_gather_dispatches"]
     assert engine._compile_watch.snapshot().get("hot_path_compiles_total", 0) == 0
+
+
+@pytest.fixture(scope="module")
+def ladder_engine():
+    """A chunk of 32 over pages of 8: the width ladder has two rungs."""
+    from generativeaiexamples_tpu.config import EngineConfig
+    from generativeaiexamples_tpu.engine.llm_engine import LLMEngine
+
+    eng = LLMEngine(EngineConfig(**dict(BASE, prefill_chunk=32)))
+    eng.warmup([32])
+    yield eng
+    eng.shutdown()
+
+
+@pytest.mark.parametrize("n", [33, 37, 40, 41, 70])
+def test_engine_serves_a_narrow_last_chunk_as_the_models_own_argmax(ladder_engine, n):
+    """Tails of 1, 5, 8 (a narrow chunk of 8 at the capacity window), 9
+    (past the narrow rung: a full-width tail) and 6 after two full
+    chunks: every served token is the whole-sequence forward's argmax,
+    the family keeps one row a wave and one narrow program, and nothing
+    compiles after warm-up."""
+    from generativeaiexamples_tpu.engine import dispatch_timeline
+    from generativeaiexamples_tpu.engine.llm_engine import SamplingParams
+
+    eng = ladder_engine
+    assert eng._chunk_widths() == [8, 32]
+    assert [s for s in eng._extend_signatures() if s[1] == 8] == [(1, 8, eng.max_seq_len)]
+    prompt = [int(t) for t in np.random.default_rng(n).integers(3, 500, size=n)]
+    cursor = dispatch_timeline.spans_since(0)[1]
+    out = list(eng.iter_ids(prompt, SamplingParams(temperature=0.0, max_tokens=10), timeout=300))
+    assert len(out) == 10 and max(reference_margins(eng, prompt, out)) < 1e-4
+    chunks = [s for s in dispatch_timeline.spans_since(cursor)[0] if s["kind"] == "prefill_chunk"]
+    tail = n % 32
+    assert [(s["rows_dispatched"], s["width"]) for s in chunks] == [(1, 32)] * (n // 32) + [(1, 8 if tail <= 8 else 32)]
+    assert chunks[-1]["pad_tokens"] == chunks[-1]["width"] - tail
+    assert eng._compile_watch.snapshot()["compile_hot_path_total"] == 0
 
 
 def test_rows_served_together_equal_their_solo_runs(engine):

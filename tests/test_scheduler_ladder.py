@@ -13,13 +13,14 @@ from generativeaiexamples_tpu.config import EngineConfig
 from generativeaiexamples_tpu.engine.llm_engine import LLMEngine
 
 
-def make_sched(chunk=16, max_seq=128, slots=8, budget=16384):
+def make_sched(chunk=16, max_seq=128, slots=8, budget=16384, page=None):
     eng = LLMEngine.__new__(LLMEngine)  # scheduler helpers only
     eng.engine_config = EngineConfig(
         prefill_chunk=chunk,
         max_seq_len=max_seq,
         max_batch_size=slots,
         prefill_wave_tokens=budget,
+        page_size=page or min(chunk, 128),
     )
     eng.num_slots = slots
     eng.max_seq_len = max_seq
@@ -112,3 +113,87 @@ def test_attention_window_rungs(cfg):
         assert w == cap or (w & (w - 1)) == 0
         assert w >= prev  # monotone
         prev = w
+
+
+# --------------------------------------------------------------------- //
+# The shape of one extend dispatch: rows and width from what the chunk
+# holds (_chunk_rung), the two ladders, and the executable set
+
+
+@pytest.mark.parametrize(
+    "chunk,page,widths",
+    [
+        (512, 128, [128, 512]),  # both benchmark cells
+        (64, 16, [16, 64]),  # chip_smoke's debug preset
+        (16, 16, [16]),  # one page a chunk: one rung
+        (2048, 128, [128, 512, 2048]),
+        (256, 128, [256]),  # a quarter of the chunk would be under a page
+        (512, 64, [128, 512]),  # whole pages; powers of four, no finer
+    ],
+)
+def test_width_ladder(chunk, page, widths):
+    eng = make_sched(chunk=chunk, max_seq=4096, page=page)
+    assert eng._chunk_widths() == widths
+    assert all(w % page == 0 for w in widths)
+
+
+SHAPE_CASES = [
+    # (valid of the wave's rows, real rows) -> (live rows, rows dispatched, width)
+    ("tail_on_one_row", [0, 71, 0, 0], 4, ([1], 1, 128)),
+    ("tail_on_two_rows", [71, 0, 0, 71], 4, ([0, 3], 4, 128)),
+    ("full_chunk", [512, 330, 458, 512], 4, ([0, 1, 2, 3], 4, 512)),
+    ("tail_past_the_narrow_rung", [0, 129, 0, 0], 4, ([1], 1, 512)),
+    ("tail_of_a_page_exactly", [128, 0, 0, 0], 4, ([0], 1, 128)),
+    ("one_token", [0, 0, 1, 0], 4, ([2], 1, 128)),
+    # a wave of three pads to four: the fourth row is a copy of row 0 and never live
+    ("padding_row_is_not_live", [71, 0, 0, 71], 3, ([0], 1, 128)),
+    ("padding_rows_only", [0, 0, 0, 71], 3, None),
+    ("empty_chunk", [0, 0, 0, 0], 4, None),
+    ("one_row_wave", [71], 1, ([0], 1, 128)),
+]
+
+
+@pytest.mark.parametrize("name,valid,n_real,expect", SHAPE_CASES, ids=[c[0] for c in SHAPE_CASES])
+def test_chunk_rung_follows_what_the_chunk_holds(name, valid, n_real, expect):
+    eng = make_sched(chunk=512, max_seq=4096, slots=64, budget=2048)  # chat_decode_7b's geometry
+    assert eng._chunk_rung(valid, n_real) == expect
+
+
+def test_chunk_rung_of_a_fixed_state_family_is_one_row():
+    eng = make_sched(chunk=512, max_seq=4096, slots=64, budget=2048)
+    eng._fixed_state = True
+    assert eng._chunk_rung([71], 1) == ([0], 1, 128)
+    assert sorted({n for n, _, _ in eng._extend_signatures()}) == [1]
+    assert len(eng._extend_signatures()) == 4 + 1  # four windows at 512, capacity at 128
+
+
+@pytest.mark.parametrize("cfg", GRID + [dict(chunk=512, max_seq=4096, slots=64, budget=2048, page=128),
+                                        dict(chunk=64, max_seq=256, slots=4, page=16)])
+def test_extend_signatures_are_exactly_what_the_rule_can_produce(cfg):
+    """Every (rows, width, window) the rule gives for any chunk of any
+    wave is in the warmed set, and the set holds nothing else."""
+    eng = make_sched(**cfg)
+    C = cfg["chunk"]
+    cap = eng._max_wave_rows(C)
+    reachable = set()
+    for k in range(-(-cfg["max_seq"] // C)):
+        for need in sorted({1, *eng._chunk_widths(), *(w + 1 for w in eng._chunk_widths() if w < C)}):
+            for n_live in range(1, cap + 1):
+                live, rows, width = eng._chunk_rung([need] * n_live, n_live)
+                assert len(live) == n_live <= rows <= cap and rows in eng._wave_sizes() + [cap]
+                assert width >= need and width in eng._chunk_widths()
+                reachable.add((rows, width, eng._extend_window(k, width)))
+    assert reachable == set(eng._extend_signatures())
+    # a narrow chunk has ONE window, capacity; a full one the rung that covers it
+    for rows, width, window in reachable:
+        assert window == cfg["max_seq"] if width < C else window >= min(C, cfg["max_seq"])
+
+
+def test_executable_count_at_the_benchmark_geometry():
+    """Mistral's cell: rows {1, 4} x four windows at 512 and one program
+    a row rung at 128: 10 (8 before the width ladder); Phi-4-flash: 5."""
+    eng = make_sched(chunk=512, max_seq=4096, slots=64, budget=2048, page=128)
+    assert eng._extend_signatures() == [
+        (1, 128, 4096), (1, 512, 512), (1, 512, 1024), (1, 512, 2048), (1, 512, 4096),
+        (4, 128, 4096), (4, 512, 512), (4, 512, 1024), (4, 512, 2048), (4, 512, 4096),
+    ]
