@@ -222,6 +222,31 @@ _M_CROSS_SKIPPED = _REG.counter(
     "NOT computed (a decoder-hybrid-decoder model computes them for a "
     "chunk's last position only).",
 )
+_M_MOE_PAIRS = _REG.counter(
+    "genai_engine_moe_pairs_total",
+    "(token, expert) pairs an expert layer routed, at the one step a "
+    "dispatch reports (its last), by whether this chip HOLDS the expert "
+    "('true': computed here; 'false': another chip's, left out).",
+    ("held",),
+)
+_M_DSA_SELECTED = _REG.counter(
+    "genai_engine_dsa_selected_tokens_total",
+    "Cached tokens the learned selection of a sparse-attention layer "
+    "let its queries read, at the one step a dispatch reports.",
+)
+_M_DSA_CONTEXT = _REG.counter(
+    "genai_engine_dsa_context_tokens_total",
+    "Cached tokens those queries had before them (the denominator of "
+    "the selected share).",
+)
+# a family's step stats (models/registry.py ``stat_names``) that also feed
+# a counter, by the stat's name: the engine knows mechanisms, not models
+_STAT_COUNTERS = {
+    "moe_pairs_held": _M_MOE_PAIRS.labels(held="true"),
+    "moe_pairs_absent": _M_MOE_PAIRS.labels(held="false"),
+    "dsa_tokens_selected": _M_DSA_SELECTED,
+    "dsa_context_tokens": _M_DSA_CONTEXT,
+}
 _M_SSM_DISPATCHES = _REG.counter(
     "genai_engine_ssm_dispatches_total",
     "Program launches that advanced a recurrent state, by path: "
@@ -476,6 +501,10 @@ class LLMEngine:
         self._kv_shape = family.paged_kv_shape(model_cfg)
         self._fixed_state = bool(family.fixed_state)
         self._span_fields = dict(family.span_fields(model_cfg))
+        self._stat_names = tuple(family.stat_names)
+        # a family whose extend walk follows each row's own context is
+        # given ONE window, capacity (models/registry.py)
+        self._one_extend_window = not family.extend_reads_window
         if self._fixed_state:
             cfg = self._validate_fixed_state(cfg, mesh)
             self.engine_config = cfg
@@ -740,10 +769,13 @@ class LLMEngine:
         if self._fixed_state:
             plan = kv_pages_mod.cache_plan(
                 self._pool_pages, cfg.page_size, self.num_slots,
-                paged_bytes_per_token=kv_pages_mod.page_bytes(
-                    self._kv_shape.num_layers, 1,
-                    self._kv_shape.num_kv_heads, self._kv_shape.head_dim,
-                    quantized=False,
+                paged_bytes_per_token=(
+                    self._kv_shape.bytes_per_token
+                    or kv_pages_mod.page_bytes(
+                        self._kv_shape.num_layers, 1,
+                        self._kv_shape.num_kv_heads,
+                        self._kv_shape.head_dim, quantized=False,
+                    )
                 ),
                 fixed_bytes_per_slot=family.fixed_state_bytes_per_slot(
                     model_cfg
@@ -764,6 +796,18 @@ class LLMEngine:
         self._paged_verify_kernel: Optional[str] = None
         self._paged_extend_kernel: Optional[str] = None
         self._resolve_paged_kernel(cfg, model_cfg)
+        # kernels the family brings beside the engine's own
+        # (models/registry.py ``resolve_kernels``), by the same rule of
+        # platform: compiled on one TPU device, interpreted on request
+        self._family_kernels = dict(family.resolve_kernels(
+            model_cfg,
+            "interpret" if cfg.paged_kernel == "interpret"
+            else "compiled" if (
+                cfg.paged_kernel != "off"
+                and jax.default_backend() == "tpu"
+                and jax.device_count() == 1 and self._tp is None
+            ) else None,
+        ))
 
         # One line naming every resolved kernel path: auto modes fall
         # back to XLA quietly off-TPU (right for tests), so a smoke run
@@ -771,11 +815,12 @@ class LLMEngine:
         logger.info(
             "resolved kernel paths: quant_kernel=%s "
             "paged_kernel=%s paged_verify_kernel=%s "
-            "paged_extend_kernel=%s tp_kernels=%s "
+            "paged_extend_kernel=%s tp_kernels=%s%s "
             "(backend=%s, devices=%d)",
             self._quant_kernel, self._paged_kernel,
             self._paged_verify_kernel, self._paged_extend_kernel,
             f"{self._tp.shards}-way" if self._tp is not None else None,
+            "".join(f" {k}={v}" for k, v in sorted(self._family_kernels.items())),
             jax.default_backend(), jax.device_count(),
         )
         # --- compiled steps ---------------------------------------------
@@ -1595,7 +1640,10 @@ class LLMEngine:
         # allows; writes are identical either way.
         fam = self._family
         # the kernel paths this engine resolved; a family takes what it knows
-        paths = dict(quant_kernel=quant_kernel, tp=tp)
+        paths = dict(quant_kernel=quant_kernel, tp=tp, **self._family_kernels)
+        # a family's small counts of its last walk, handed back with the
+        # tokens (models/registry.py ``stat_names``); none: nothing added
+        n_stats = len(self._stat_names)
         page = ecfg.page_size
         page_kernel = self._paged_kernel
         verify_kernel = self._paged_verify_kernel
@@ -1642,6 +1690,17 @@ class LLMEngine:
             (tokens, positions, caches), token_slab = jax.lax.scan(
                 body, (tokens, positions, caches), None, length=block
             )
+            if n_stats:
+                # the last step's counts ride under the token slab as
+                # extra rows: one readback, no sync of their own
+                B = token_slab.shape[1]
+                stats = jnp.pad(
+                    fam.read_stats(caches).astype(token_slab.dtype),
+                    (0, -n_stats % B),
+                )
+                token_slab = jnp.concatenate(
+                    [token_slab, stats.reshape(-1, B)], axis=0
+                )
             return tokens, positions, caches, token_slab
 
         # Chunked prefill (VERDICT r3 #4): prompts longer than one chunk
@@ -1671,6 +1730,8 @@ class LLMEngine:
             last_h = jnp.where(
                 (valid > 0)[:, None], cand.astype(last_h.dtype), last_h
             )
+            if n_stats:
+                return last_h, caches, fam.read_stats(caches)
             return last_h, caches
 
         def finish_batch(params, last_h, src, lengths, temps, topps, seeds):
@@ -1797,6 +1858,7 @@ class LLMEngine:
         self._finish_fn = wrap("finish", jax.jit(finish_batch))
         self._put_rows_fn = wrap("put_rows", jax.jit(put_rows))
         self._zero_carries: Dict[int, object] = {}  # _zero_hidden's, by rows
+        self._wave_stats: list = []  # (span fields, device counts) of a wave's chunks
         self._spec_verify_fn = wrap(
             "spec_verify",
             jax.jit(
@@ -2563,7 +2625,7 @@ class LLMEngine:
                     # zero-valid rows route every write to the
                     # scratch page — value-level no-ops even when
                     # slot 0's table holds stale entries
-                    last_h, self._cache = self._extend_fn(
+                    last_h, self._cache, *_ = self._extend_fn(
                         self.params, self._cache,
                         jnp.zeros((n, width), jnp.int32), zeros_n, zeros_n,
                         zeros_n, last_h, self._tables_dev, W,
@@ -3330,9 +3392,11 @@ class LLMEngine:
                 self._update_occupancy_gauges()
             raise
         _start_host_copy(first_tokens)
-        self._readback.put(
-            ("prefill", first_tokens, [(i, req) for i, req in enumerate(group)])
-        )
+        wave_stats, self._wave_stats = self._wave_stats, []
+        self._readback.put((
+            "prefill", first_tokens,
+            [(i, req) for i, req in enumerate(group)], wave_stats,
+        ))
         # Insert completed prefills back into the radix cache
         # (dispatch-ordered after the chunk loop; decode only ever
         # appends at positions >= T, never rewriting [0:cached]).
@@ -3493,6 +3557,7 @@ class LLMEngine:
         n_real = len(reqs) if reqs is not None else Np
         K = (Tmax + C - 1) // C
         annotate = self._annotate
+        self._wave_stats = []
         last_h = self._zero_hidden(Np)
         dispatched = 0
         for k in range(K):
@@ -3540,7 +3605,7 @@ class LLMEngine:
             with self._dispatch_lock, annotate("engine.prefill_chunk"):
                 if _dtl is not None:
                     _dtl_t1 = time.perf_counter()
-                sub_h, self._cache = self._extend_fn(
+                sub_h, self._cache, *step_stats = self._extend_fn(
                     self.params,
                     self._cache,
                     jnp.asarray(tok_k),
@@ -3569,6 +3634,12 @@ class LLMEngine:
                 n_live * min(k * C, self._span_fields.get("window", 0)),
                 resets=n_live if k == 0 else 0,
             ) or {})
+            if step_stats:
+                # the chunk's counts land in its span when the wave's
+                # first tokens are read back (_note_stats)
+                fields.update(dict.fromkeys(self._stat_names, 0))
+                _start_host_copy(step_stats[0])
+                self._wave_stats.append((fields, step_stats[0]))
             if _dtl is not None:
                 _dtl.record_span(
                     "prefill_chunk",
@@ -3647,18 +3718,21 @@ class LLMEngine:
             _M_PREFILL_TOKENS.inc(tokens)  # every family: the skipped share's denominator
         if not self._fixed_state:
             return None
-        fields = {
-            "state_rows": rows,
-            "kv_readers": self._span_fields["kv_readers"],
-            "window_tokens_read": ring_tokens * self._span_fields["window_layers"],
-        }
+        sf = self._span_fields
+        fields = {"state_rows": rows}
+        if "kv_readers" in sf:
+            fields["kv_readers"] = sf["kv_readers"]
+        if "window_layers" in sf:
+            fields["window_tokens_read"] = ring_tokens * sf["window_layers"]
         if kind == "decode":
             _M_SSM_DISPATCHES.labels(path="step").inc()
         else:
-            fields["cross_skipped_tokens"] = max(0, tokens - rows)
             _M_SSM_DISPATCHES.labels(path="scan").inc()
-            _M_CROSS_SKIPPED.inc(fields["cross_skipped_tokens"])
             _M_STATE_RESETS.inc(resets)
+            if sf.get("last_position_only"):
+                # a family whose upper layers see a chunk's last position only
+                fields["cross_skipped_tokens"] = max(0, tokens - rows)
+                _M_CROSS_SKIPPED.inc(fields["cross_skipped_tokens"])
         return fields
 
     def _prefill_bucket(self, n: int) -> int:
@@ -3735,9 +3809,11 @@ class LLMEngine:
         narrow one has ONE rung, capacity: under the page kernel the
         walk follows each row's live pages whatever the window says, and
         on the gather 128 queries over 4096 keys cost what 512 over 1024
-        do, the least a full-width tail pays."""
+        do, the least a full-width tail pays. A family whose extend walk
+        follows each row's own context (``_one_extend_window``) has that
+        one rung at every width."""
         C = self.engine_config.prefill_chunk
-        if width < C:
+        if width < C or self._one_extend_window:
             return self.max_seq_len
         return self._attention_window(min((k + 1) * C, self.max_seq_len))
 
@@ -3849,6 +3925,9 @@ class LLMEngine:
                 kv_pages or {}, **(state_fields or {}),
                 stream_backlog_tokens=self._stream_backlog_tokens(),
             )
+            # the family's own counts of this dispatch's last step: keys
+            # now, values when the slab is read back (_note_stats)
+            span_counts.update(dict.fromkeys(self._stat_names, 0))
             for slot in self._slot_pos:
                 self._slot_pos[slot] += self._decode_block
             self._update_occupancy_gauges()
@@ -3925,7 +4004,7 @@ class LLMEngine:
         _start_host_copy(token_slab)
         # Blocks when decode_runahead results await readback — the only
         # backpressure on the dispatch thread.
-        self._readback.put(("decode", token_slab, snapshot))
+        self._readback.put(("decode", token_slab, snapshot, span_counts))
 
     def _spec_decode_once(self) -> None:
         """One speculative verify dispatch (prompt-lookup decoding).
@@ -4618,7 +4697,7 @@ class LLMEngine:
                             req.out_queue.put(_END)
                             flight_recorder.finish_rid(req.rid, "shutdown")
                 return
-            kind, handle, slots = item
+            kind, handle, slots, *extra = item
             if kind == "spec":
                 # Verify results arrive pre-fetched (the dispatch thread
                 # synced them for its proposer buffers): emit each row's
@@ -4672,8 +4751,28 @@ class LLMEngine:
                 for row, req in slots:
                     if not req.finished:
                         self._emit(req, values[row : row + 1], advance=False)
+                # the wave's chunks ran before its first tokens: their
+                # counts are on the host already
+                for fields, stats in (extra[0] if extra else ()):
+                    self._note_stats(fields, np.asarray(stats))
                 continue
+            if self._stat_names and extra:
+                block = self._decode_block
+                self._note_stats(extra[0], values[block:].reshape(-1))
+                values = values[:block]
             self._emit_slab(values, slots)
+
+    def _note_stats(self, fields: Dict[str, int], values: np.ndarray) -> None:
+        """A family's counts of one dispatch (models/registry.py
+        ``stat_names``), read back with its tokens: into the span's
+        fields (whose keys the dispatch wrote, so the dict keeps its
+        size under a concurrent scrape) and into the counters that
+        carry a stat's name."""
+        for name, value in zip(self._stat_names, values.tolist()):
+            fields[name] = int(value)
+            counter = _STAT_COUNTERS.get(name)
+            if counter is not None:
+                counter.inc(int(value))
 
     def _emit_slab(self, slab: np.ndarray, slots) -> None:
         """A decode slab ``[block, batch]``, oldest step first, walked

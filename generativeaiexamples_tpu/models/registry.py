@@ -24,12 +24,31 @@ engine's paged step programs (``engine/llm_engine.py``
 - ``head(params, cfg, hidden [N, D], **paths) -> logits [N, V]``;
 - the memory plan: ``serving_memory_bytes``, ``count_logical_params``,
   ``paged_kv_shape`` (the geometry of what IS paged: layers, KV heads
-  and head size of the pools, query heads of the page kernel's read)
+  and head size of the pools, query heads of the page kernel's read;
+  ``bytes_per_token`` where K and V are not two rows of that size each)
   and ``fixed_state_bytes_per_slot``.
 
 ``paths`` are the kernel paths the engine resolved (``use_flash``,
-``quant_kernel``, ``tp``, ``page_kernel``); a family takes what it knows
-and ignores the rest.
+``quant_kernel``, ``tp``, ``page_kernel``, and whatever the family's own
+``resolve_kernels`` named); a family takes what it knows and ignores
+the rest.
+
+``resolve_kernels(cfg, kind)`` names the kernels a family brings beside
+the engine's (``kind`` is ``'compiled'`` on one TPU device,
+``'interpret'`` where the engine's ``paged_kernel`` says so, else None):
+``{path name: kind}``, handed to every walk as keywords and printed on
+the engine's ``resolved kernel paths:`` line.
+
+``extend_reads_window=False`` says the family's extend walk follows each
+row's own context (a loop over its pages), so the engine builds one
+extend program a chunk width instead of one a window rung.
+
+``stat_names`` / ``read_stats(caches)``: small int32 counts a family's
+walks leave in the cache pytree (pairs routed to held experts, tokens a
+selection kept). The decode and extend programs hand them back with the
+tokens, and the engine writes them into the dispatch's span under these
+names (and into the counters of ``engine/llm_engine.py`` ``_STAT_COUNTERS``
+that carry the same names).
 
 ``fixed_state`` declares that a slot holds state that is NOT pages (a
 recurrent state, a window ring). Everything in the engine that assumes
@@ -58,6 +77,9 @@ class PagedKVShape:
     num_kv_heads: int
     head_dim: int
     num_heads: int
+    # paged bytes a cached token costs, where that is not K and V rows
+    # of ``num_kv_heads * head_dim`` each a layer (a latent row read as both)
+    bytes_per_token: Optional[int] = None
 
 
 @dataclasses.dataclass(frozen=True)
@@ -79,6 +101,13 @@ class ModelFamily:
     fixed_state_bytes_per_slot: Callable[..., int] = lambda cfg, kv_bytes=2: 0
     span_fields: Callable[[Any], Dict[str, int]] = lambda cfg: {}
     place_params: Callable[[Any], Any] = lambda params: params
+    resolve_kernels: Callable[[Any, Optional[str]], Dict[str, Optional[str]]] = lambda cfg, kind: {}
+    stat_names: Tuple[str, ...] = ()
+    # False: the extend walk follows each row's own context whatever
+    # ``window`` says, so the engine names ONE window (capacity) and builds
+    # one extend program a width, not one a power-of-two window rung
+    extend_reads_window: bool = True
+    read_stats: Optional[Callable[[Any], Any]] = None
 
 
 _FAMILIES: Dict[str, ModelFamily] = {}
@@ -198,7 +227,35 @@ def _phi4flash_family() -> ModelFamily:
             "kv_readers": 1 + len(cfg.layers_of("cross")),
             "window_layers": len(cfg.layers_of("window")),
             "window": cfg.sliding_window,
+            # the layers past the shared-KV layer see a chunk's last position only
+            "last_position_only": 1,
         },
+    )
+
+
+def _glm5next_family() -> ModelFamily:
+    from generativeaiexamples_tpu.models import glm5next as m
+
+    def init_paged_cache(cfg, pool_pages, page_size, num_slots, dtype, quantized=False, packed=False):
+        if quantized or packed:
+            raise ValueError("glm5next keeps its latent pool and its fixed state in bfloat16")
+        return m.init_paged_cache(cfg, pool_pages, page_size, num_slots, dtype)
+
+    return ModelFamily(
+        name="glm5next", presets=m.PRESETS, config_type=m.Glm5NextConfig, fixed_state=True,
+        init_params=m.init_params_fast, init_paged_cache=init_paged_cache,
+        prefill_paged=m.prefill_paged, extend_paged=m.extend_paged, decode_paged=m.decode_paged,
+        verify_paged=None, head=lambda params, cfg, hidden, **_: m.head(params, cfg, hidden),
+        serving_memory_bytes=m.serving_memory_bytes, count_logical_params=m.count_logical_params,
+        # ONE head-less latent row a token and sparse-attention layer,
+        # read as key and value by every query head
+        paged_kv_shape=lambda cfg: PagedKVShape(
+            len(cfg.layers_of("dsa")), 1, cfg.kv_lora_rank, cfg.num_heads,
+            bytes_per_token=m.kv_bytes_per_token(cfg)),
+        fixed_state_bytes_per_slot=m.fixed_state_bytes_per_slot,
+        span_fields=lambda cfg: {"kda_layers": len(cfg.layers_of("kda")), "index_topk": cfg.index_topk},
+        resolve_kernels=lambda cfg, kind: {"grouped_matmul": kind},
+        stat_names=m.STAT_NAMES, read_stats=m.read_stats, extend_reads_window=False,
     )
 
 
@@ -206,3 +263,4 @@ def _load_builtin() -> None:
     if not _FAMILIES:
         register_family(_llama_family())
         register_family(_phi4flash_family())
+        register_family(_glm5next_family())

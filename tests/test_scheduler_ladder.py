@@ -25,6 +25,7 @@ def make_sched(chunk=16, max_seq=128, slots=8, budget=16384, page=None):
     eng.num_slots = slots
     eng.max_seq_len = max_seq
     eng._fixed_state = False
+    eng._one_extend_window = False
     return eng
 
 
@@ -197,3 +198,17 @@ def test_executable_count_at_the_benchmark_geometry():
         (1, 128, 4096), (1, 512, 512), (1, 512, 1024), (1, 512, 2048), (1, 512, 4096),
         (4, 128, 4096), (4, 512, 512), (4, 512, 1024), (4, 512, 2048), (4, 512, 4096),
     ]
+
+
+@pytest.mark.parametrize("chunk,max_seq", [(512, 8192), (64, 256)])
+def test_a_family_that_reads_no_window_has_one_extend_program_a_width(chunk, max_seq):
+    """``extend_reads_window=False`` (models/registry.py): every chunk is
+    dispatched at capacity, so warm-up builds one program a width rung,
+    not one a power-of-two window."""
+    eng = make_sched(chunk=chunk, max_seq=max_seq, slots=64)
+    eng._fixed_state = True
+    windows = {w for _, _, w in eng._extend_signatures()}
+    assert len(windows) > 1
+    eng._one_extend_window = True
+    assert {w for _, _, w in eng._extend_signatures()} == {max_seq}
+    assert len(eng._extend_signatures()) == len(eng._chunk_widths())
