@@ -38,7 +38,7 @@ import random
 import threading
 import time
 import weakref
-from typing import Any, Callable, Dict, Generator, List, Optional, Sequence, Tuple
+from typing import Any, Callable, Dict, Generator, Iterable, List, Optional, Sequence, Tuple
 
 import numpy as np
 
@@ -109,6 +109,12 @@ _M_DECODE_DISPATCHES = _REG.counter(
     "Decode/verify dispatches issued (one compiled-program launch each; "
     "a decode dispatch runs decode_block steps, a spec verify dispatch "
     "runs one multi-token step).",
+)
+_M_SAMPLER_FULL = _REG.counter(
+    "genai_engine_sampler_full_vocab_dispatches_total",
+    "Decode/verify dispatches that held a live row with temperature > 0 "
+    "and top_p >= 1: the sampler's full-vocabulary draw ran in them "
+    "(models/sampling.py skips it in every other dispatch).",
 )
 _M_PREFILL_CHUNKS = _REG.counter(
     "genai_engine_prefill_chunks_total",
@@ -388,6 +394,20 @@ def _next_stream_items(out_q, stall_s, deadline):
         return out_q.take_all(timeout=wait)
     except queue.Empty:
         raise TimeoutError("LLM engine timed out") from None
+
+
+def _sampler_full_rows(requests: Iterable["_Request"]) -> int:
+    """Rows of the decode dispatch about to launch that force the
+    sampler's full-vocabulary draw (temperature > 0 and top_p >= 1, in
+    float32 as the device reads them), counted into
+    genai_engine_sampler_full_vocab_dispatches_total."""
+    rows = sum(
+        1 for r in requests
+        if np.float32(r.params.temperature) > 0 and np.float32(r.params.top_p) >= 1
+    )
+    if rows:
+        _M_SAMPLER_FULL.inc()
+    return rows
 
 
 def _update_slots(tokens, positions, temps, topps, seeds, slots, toks, poss, ts, ps, ss):
@@ -1683,7 +1703,12 @@ class LLMEngine:
                 keys = sample_keys(
                     base_key, seeds, jnp.minimum(positions + 1, max_pos)
                 )
-                next_tokens = sample_tokens(logits[:, :V], keys, temps, topps)
+                # a dead slot keeps its last request's temperature and
+                # top_p (1.0, 1.0 if never used): only live rows decide
+                # what the sampler reads the vocabulary for
+                next_tokens = sample_tokens(
+                    logits[:, :V], keys, temps, topps, live
+                )
                 positions = jnp.minimum(positions + 1, max_pos)
                 return (next_tokens, positions, caches), next_tokens
 
@@ -1805,6 +1830,7 @@ class LLMEngine:
                 keys,
                 jnp.repeat(temps, Kp1),
                 jnp.repeat(topps, Kp1),
+                jnp.repeat(live, Kp1),
             ).reshape(B, Kp1)
             # accepted = leading draft positions whose token matches the
             # model's own output at the same index (cumprod counts the
@@ -3924,6 +3950,7 @@ class LLMEngine:
             span_counts = dict(
                 kv_pages or {}, **(state_fields or {}),
                 stream_backlog_tokens=self._stream_backlog_tokens(),
+                sampler_full_rows=_sampler_full_rows(self._slot_req.values()),
             )
             # the family's own counts of this dispatch's last step: keys
             # now, values when the slab is read back (_note_stats)
@@ -4160,6 +4187,7 @@ class LLMEngine:
             _dtl_run = time.perf_counter() - _dtl_t1
         _M_DECODE_STEPS.inc(1)
         _M_DECODE_DISPATCHES.inc()
+        _sampler_full_rows(r for _, r in snapshot)
         with self._lock:
             # Dispatch-time truth: the position shadows advance at the
             # flush, so this reads the state the verify actually ran at
@@ -4513,6 +4541,7 @@ class LLMEngine:
             )
         _M_DECODE_STEPS.inc(self._decode_block)
         _M_DECODE_DISPATCHES.inc()
+        _sampler_full_rows(r for _, r in snapshot)
         with self._lock:
             block_bytes = (
                 self._ragged_read_bytes() if self._paged_kernel

@@ -151,6 +151,75 @@ def test_flash_attention_compiles(one_chip, no_persistent_cache, T):
     assert "tpu_custom_call" in text
 
 
+def _sampler_args(sharding, V, B=64):
+    def s(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=sharding(shape))
+
+    return (s((B, V), jnp.float32), s((B, 2), jnp.uint32), s((B,), jnp.float32),
+            s((B,), jnp.float32), s((B,), jnp.bool_))
+
+
+def _sort_widths(text):
+    import re
+
+    return [int(w) for w in re.findall(r"= \(?[a-z0-9]+\[\d+,(\d+)\]\S* (?:[^=]*)sort\(", text)]
+
+
+@pytest.mark.parametrize("V", [19360, 32768, 200064])
+def test_sampler_never_sorts_the_vocabulary(one_chip, no_persistent_cache, V):
+    """models/sampling.py at the cells' vocabularies, 64 rows: the chip's
+    compiler sorts nothing wider than the second rung's group maxima and
+    candidates (a lax.top_k whose result is sliced again is sorted WHOLE
+    unless a barrier keeps XLA from merging the slices: 18.7 ms a step
+    at 200,064 against 1.9, my chip runs, PR 36), and the first rung
+    reads the vocabulary in place: no operation writes a second copy."""
+    from generativeaiexamples_tpu.models import sampling
+
+    text = _compiled_text(sampling.sample_tokens, *_sampler_args(lambda shape: one_chip, V))
+    groups = sampling.top_k_groups(V, sampling.NUCLEUS_TOP_K)
+    assert groups == (128, 16)
+    widths = _sort_widths(text)
+    assert widths and max(widths) <= max(-(-V // 128), sampling.NUCLEUS_TOP_K * 16), widths
+    if V % 128 == 0:
+        assert not _vocabulary_copies(text, 64 * V)
+
+
+def _vocabulary_copies(text, elements):
+    """Instructions that write an array as large as the logits and are a
+    copy by opcode or by the fusion's name (``copy_bitcast_fusion``)."""
+    import math
+    import re
+
+    out = []
+    for name, dims, opcode in re.findall(r"^\s*(?:ROOT )?%(\S+) = f32\[([\d,]+)\]\S* ([a-z-]+)\(", text, re.M):
+        if math.prod(int(d) for d in dims.split(",")) == elements and (
+            opcode in ("copy", "reshape", "transpose")
+            or opcode == "fusion" and name.startswith(("copy", "transpose"))
+        ):  # (copy-start / copy-done of the logits is a prefetch of this test's own parameter)
+            out.append(name)
+    return out
+
+
+def test_sampler_compiles_with_the_vocabulary_sharded_over_four_chips(topo, no_persistent_cache):
+    import numpy as np
+
+    from generativeaiexamples_tpu.models import sampling
+    from generativeaiexamples_tpu.parallel.mesh import (
+        DATA_AXIS, MODEL_AXIS, PIPE_AXIS, SEQ_AXIS,
+    )
+
+    mesh = Mesh(
+        np.array(topo.devices[:4]).reshape(1, 1, 1, 4),
+        (PIPE_AXIS, DATA_AXIS, SEQ_AXIS, MODEL_AXIS),
+    )
+
+    def sharding(shape):
+        return NamedSharding(mesh, P(None, MODEL_AXIS) if shape[-1] == 128256 else P())
+
+    text = _compiled_text(sampling.sample_tokens, *_sampler_args(sharding, VOCAB, B=16))
+    assert max(_sort_widths(text)) < VOCAB // 4
+
+
 def test_paged_attention_tp_compiles_over_four_chips(topo, no_persistent_cache):
     """The shard_map head-sharded page kernel over a 4-device ``model``
     mesh: kernel tiles on every device, no gather of the pool."""
