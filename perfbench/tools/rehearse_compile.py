@@ -1,16 +1,25 @@
 #!/usr/bin/env python3
-"""Rehearsal 3 for a configuration of the Mistral adapter: compile its decode program and its
-longest chunked-prefill (extend) rung for a DESCRIBED v5e chip, without
-a chip, and print ``memory_analysis()``. A compile, never a run.
+"""Rehearsal 3 for any configuration of the benchmark: compile its decode
+program and its widest chunked-prefill (extend) dispatch for a DESCRIBED
+v5e chip, without a chip, and print ``memory_analysis()``. A compile,
+never a run.
 
     JAX_PLATFORMS=cpu python3 perfbench/tools/rehearse_compile.py perfbench/configs/<name>.json
 
-The programs are the model functions the engine jits
-(``llama.decode_layers_paged`` in a scan of ``decode_block`` steps,
-``llama.extend_layers_paged`` at ``prefill_wave_tokens / prefill_chunk``
-rows against the full window) on shapes built from the configuration
-file; sampling is replaced by an argmax, which adds no memory to speak of.
+The model comes in as it does in a run (``perfbench/launcher.py``): the
+configuration file names its adapter, the adapter's ``register`` makes
+the program's registry resolve the configuration's name, and the
+programs are the FAMILY's walks (``models/registry.py``: ``decode_paged``
+in a scan of ``decode_block`` steps, ``extend_paged`` at
+``prefill_wave_tokens / prefill_chunk`` rows, one row for a fixed-state
+family, against the full window) on shapes taken from the family's own
+``init_params`` / ``place_params`` / ``init_paged_cache`` under
+``jax.eval_shape``, so a new configuration is rehearsed without an edit
+here. Sampling is replaced by an argmax, which adds no memory to speak
+of. A family that draws its weights with numpy draws them for real
+(minutes and the weights' size in host memory at 4-7 B parameters).
 """
+import functools
 import json
 import os
 import sys
@@ -25,48 +34,57 @@ import jax.numpy as jnp  # noqa: E402
 from jax.experimental import topologies  # noqa: E402
 from jax.sharding import SingleDeviceSharding  # noqa: E402
 
-from generativeaiexamples_tpu.models import llama  # noqa: E402
-from perfbench.arch.mistral import llama_config  # noqa: E402
+from generativeaiexamples_tpu.models import registry  # noqa: E402
+from perfbench import arch  # noqa: E402
 
 
 def main(path: str) -> None:
     cfg = json.load(open(path, encoding="utf-8"))
     env, eng = cfg["server_env"], cfg["engine"]
-    mc = llama_config(cfg)
+    paths = json.load(open(os.path.join(ROOT, "BENCHMARK.json"), encoding="utf-8"))["paths"]
+    arch.load(cfg, [os.path.join(ROOT, p) for p in paths]).register(cfg)
+    family, mc = registry.resolve(cfg["name"])
     topo = topologies.get_topology_desc(platform="tpu", topology_name="v5e:2x2")
     dev = SingleDeviceSharding(topo.devices[0])
     S = lambda shape, dt: jax.ShapeDtypeStruct(shape, dt, sharding=dev)  # noqa: E731
-    pad = lambda n, m: -(-n // m) * m  # noqa: E731
-    pack = lambda k, f: {"q": S((pad(k, 128), pad(f, 512)), jnp.int8), "scale": S((1, f), jnp.float32)}  # noqa: E731
-    h, m, q, kv = mc.hidden_size, mc.intermediate_size, mc.q_dim, mc.kv_dim
-    layer = {"attn_norm": S((h,), jnp.bfloat16), "mlp_norm": S((h,), jnp.bfloat16),
-             "wqkv": pack(h, q + 2 * kv), "wo": pack(q, h), "w_gateup": pack(h, 2 * m), "w_down": pack(m, h)}
-    params = {"embed": S((mc.vocab_size, h), jnp.bfloat16), "layers": [layer] * mc.num_layers,
-              "final_norm": S((h,), jnp.bfloat16), "lm_head": pack(h, mc.vocab_size)}
-    page, pages = eng["page_size"], eng["kv_pool_pages"] + 1
-    B, seq, C = eng["max_batch_size"], eng["max_seq_len"], eng["prefill_chunk"]
-    cache = [{"k": S((pages, page, mc.num_kv_heads, mc.head_dim), jnp.int8),
-              "v": S((pages, page, mc.num_kv_heads, mc.head_dim), jnp.int8),
-              "ks": S((pages, page, mc.num_kv_heads), jnp.float32),
-              "vs": S((pages, page, mc.num_kv_heads), jnp.float32)}] * mc.num_layers
+    on_chip = lambda tree: jax.tree.map(lambda x: S(x.shape, x.dtype), tree)  # noqa: E731
+
+    dtype = jnp.bfloat16
+    quant = env.get("APP_ENGINE_QUANTIZATION", "none")
+    if quant in ("int8", "w8a8"):  # the engine draws packed int8 weights for a llama-family model
+        from generativeaiexamples_tpu.ops.quant import init_packed_params_int8
+
+        draw = functools.partial(init_packed_params_int8, mc, 0, dtype, tp_shards=1)
+    else:
+        draw = functools.partial(family.init_params, mc, 0, dtype)
+    params = on_chip(jax.eval_shape(lambda: family.place_params(draw())))
+    page, B, seq, C = eng["page_size"], eng["max_batch_size"], eng["max_seq_len"], eng["prefill_chunk"]
+    kv = env.get("APP_ENGINE_KVCACHEDTYPE", "bfloat16")
+    cache = on_chip(jax.eval_shape(functools.partial(
+        family.init_paged_cache, mc, eng["kv_pool_pages"], page, B, dtype,
+        quantized=kv in ("int8", "int4"), packed=kv == "int4")))
+    # the kernel paths an engine on ONE TPU device resolves
+    kernels = dict(quant_kernel={"int8": True, "w8a8": "w8a8"}.get(quant, False), tp=None,
+                   **family.resolve_kernels(mc, "compiled"))
     tables = S((B, seq // page), jnp.int32)
     block = eng["decode_block"]
 
     def decode(params, caches, tokens, positions, live, tables):
         def body(carry, _):
             tokens, positions, caches = carry
-            logits, caches = llama.decode_layers_paged(
-                params, mc, tokens, positions, live, tables, caches, window=seq,
-                page_size=page, quant_kernel=True, page_kernel="compiled")
+            logits, caches = family.decode_paged(
+                params, mc, caches, tokens, positions, live, tables, seq, page,
+                page_kernel="compiled", **kernels)
             return (jnp.argmax(logits, -1).astype(jnp.int32), positions + 1, caches), tokens
         (tokens, positions, caches), slab = jax.lax.scan(body, (tokens, positions, caches), None, length=block)
         return tokens, positions, caches, slab
 
-    rows = max(1, int(env.get("APP_ENGINE_PREFILLWAVETOKENS", 16384)) // C)
+    # a fixed-state family is sent one row a wave whatever the wave's tokens (engine/llm_engine.py _max_wave_rows)
+    rows = 1 if family.fixed_state else max(1, int(env.get("APP_ENGINE_PREFILLWAVETOKENS", 16384)) // C)
 
     def extend(params, caches, tokens, offsets, valid, slots, tables):
-        return llama.extend_layers_paged(params, mc, tokens, offsets, valid, slots, tables, caches,
-                                         seq, page, quant_kernel=True)
+        return family.extend_paged(params, mc, caches, tokens, offsets, valid, slots, tables, seq, page,
+                                   page_kernel=None, **kernels)
 
     i32 = jnp.int32
     jobs = {
@@ -77,6 +95,7 @@ def main(path: str) -> None:
             jax.jit(extend, donate_argnums=(1,)),
             (params, cache, S((rows, C), i32), S((rows,), i32), S((rows,), i32), S((rows,), i32), tables)),
     }
+    print(f"{cfg['name']}: adapter {cfg['adapter']}, family {family.name}", flush=True)
     for name, (fn, args) in jobs.items():
         t0 = time.time()
         compiled = fn.lower(*args).compile()

@@ -321,6 +321,35 @@ def test_configuration_engine_reference_and_memory_plan():
     assert 0.25 * 16.9e9 < plan["resident_bytes"] < 16.9e9
 
 
+CELL = "doc_reason_glm53flash"
+# the cell's per-layer entries as PR 35 appended them, in that order
+PER_LAYER_15 = (
+    "decode_rows_mean.glm53", "decode_step_dev_ms.glm53", "decode_step_roofline_share.glm53",
+    "tpot_chat_p50_ms.glm53", "device_idle_share.glm53", "stream_backlog_tokens_mean.glm53",
+    "state_rows_mean.glm53", "extend_dispatch_dev_ms.glm53", "moe_experts_hit_share",
+    "moe_pairs_per_expert_mean", "dsa_selected_share", "grouped_matmul_busy_share",
+    "grouped_matmul_roofline_share", "latent_attn_busy_share", "latent_attn_roofline_share",
+)
+
+
+def assert_manifest_entries_of_the_cell(manifest):
+    """The cell, its configuration and its entries, found by NAME: another
+    cell's entries may stand before or after them, and further entries of
+    this cell may follow anywhere after its 15."""
+    (cell,) = [w for w in manifest["workloads"] if w["name"] == CELL]
+    assert (cell["config"], cell["traffic"], cell["chips"]) == ("glm-5.3-flash-ep8-bf16", "doc_reason", 1)
+    (cfg,) = [c for c in manifest["configs"] if c["name"] == cell["config"]]
+    assert cfg["reduced"] == CFG["reduced"] and cfg["file"].endswith(os.path.basename(CONFIG))
+    names = [m["name"] for m in manifest["per_layer"]]
+    first = names.index(PER_LAYER_15[0])
+    assert tuple(names[first:first + 15]) == PER_LAYER_15  # contiguous, in the order they came in
+    mine = [m["name"] for m in manifest["per_layer"] if m.get("workloads") == [CELL]]
+    assert tuple(mine[:15]) == PER_LAYER_15  # what the cell gains later follows its 15
+    for e in manifest["end_to_end"]:
+        if e["name"] in ("out_tok_s", "itl_p995_ms"):
+            assert CELL in e["workloads"]
+
+
 def test_traffic_and_manifest_entries_are_as_the_issue_gives_them():
     traffic = load(os.path.join(BENCH, "traffic", "doc_reason.json"))
     assert traffic["kind"] == "closed" and traffic["clients"] == 64
@@ -328,13 +357,4 @@ def test_traffic_and_manifest_entries_are_as_the_issue_gives_them():
     assert traffic["question_bytes"] == [2048, 3072, 4096] and traffic["max_tokens"] == [1024, 2048, 3072]
     assert traffic["ramp"] == {"expected_request_s": 50.0, "cap_s": 60.0}
     assert traffic["traced_run_window_s"] == 20.0 and traffic["trace_window_s"] == 2.5
-    manifest = load(os.path.join(ROOT, "BENCHMARK.json"))
-    cell = manifest["workloads"][-1]
-    assert (cell["name"], cell["config"], cell["traffic"], cell["chips"]) == (
-        "doc_reason_glm53flash", "glm-5.3-flash-ep8-bf16", "doc_reason", 1)
-    assert manifest["configs"][-1]["reduced"] == CFG["reduced"] and manifest["configs"][-1]["file"].endswith(os.path.basename(CONFIG))
-    mine = [m for m in manifest["per_layer"] if m.get("workloads") == ["doc_reason_glm53flash"]]
-    assert len(mine) == 15 and manifest["per_layer"][-15:] == mine  # appended, nothing between
-    for e in manifest["end_to_end"]:
-        if e["name"] in ("out_tok_s", "itl_p995_ms"):
-            assert e["workloads"][-1] == "doc_reason_glm53flash"
+    assert_manifest_entries_of_the_cell(load(os.path.join(ROOT, "BENCHMARK.json")))
