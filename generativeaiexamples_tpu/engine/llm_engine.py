@@ -245,6 +245,12 @@ _M_DSA_CONTEXT = _REG.counter(
     "Cached tokens those queries had before them (the denominator of "
     "the selected share).",
 )
+_M_LATENT_READ = _REG.counter(
+    "genai_engine_latent_read_tokens_total",
+    "Cached tokens a dense latent-attention layer read for its queries "
+    "(every token up to each query's own), at the one step a dispatch "
+    "reports.",
+)
 # a family's step stats (models/registry.py ``stat_names``) that also feed
 # a counter, by the stat's name: the engine knows mechanisms, not models
 _STAT_COUNTERS = {
@@ -252,6 +258,7 @@ _STAT_COUNTERS = {
     "moe_pairs_absent": _M_MOE_PAIRS.labels(held="false"),
     "dsa_tokens_selected": _M_DSA_SELECTED,
     "dsa_context_tokens": _M_DSA_CONTEXT,
+    "latent_tokens_read": _M_LATENT_READ,
 }
 _M_SSM_DISPATCHES = _REG.counter(
     "genai_engine_ssm_dispatches_total",

@@ -259,8 +259,35 @@ def _glm5next_family() -> ModelFamily:
     )
 
 
+def _gigachat35_family() -> ModelFamily:
+    from generativeaiexamples_tpu.models import gigachat35 as m
+
+    def init_paged_cache(cfg, pool_pages, page_size, num_slots, dtype, quantized=False, packed=False):
+        if quantized or packed:
+            raise ValueError("gigachat35 keeps its latent pool and its fixed state in bfloat16")
+        return m.init_paged_cache(cfg, pool_pages, page_size, num_slots, dtype)
+
+    return ModelFamily(
+        name="gigachat35", presets=m.PRESETS, config_type=m.GigaChat35Config, fixed_state=True,
+        init_params=m.init_params_fast, init_paged_cache=init_paged_cache,
+        prefill_paged=m.prefill_paged, extend_paged=m.extend_paged, decode_paged=m.decode_paged,
+        verify_paged=None, head=lambda params, cfg, hidden, **_: m.head(params, cfg, hidden),
+        serving_memory_bytes=m.serving_memory_bytes, count_logical_params=m.count_logical_params,
+        # ONE head-less row a token and latent-attention layer, the padded
+        # [c | k_rope] the pool allocates; every query head reads it as key
+        # and its first kv_lora_rank columns as value
+        paged_kv_shape=lambda cfg: PagedKVShape(
+            len(cfg.layers_of("mla")), 1, cfg.latent_row, cfg.num_heads,
+            bytes_per_token=m.kv_bytes_per_token(cfg)),
+        fixed_state_bytes_per_slot=m.fixed_state_bytes_per_slot,
+        resolve_kernels=lambda cfg, kind: {"grouped_matmul": kind},
+        stat_names=m.STAT_NAMES, read_stats=m.read_stats, extend_reads_window=False,
+    )
+
+
 def _load_builtin() -> None:
     if not _FAMILIES:
         register_family(_llama_family())
         register_family(_phi4flash_family())
         register_family(_glm5next_family())
+        register_family(_gigachat35_family())

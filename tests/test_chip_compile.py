@@ -23,7 +23,9 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P, SingleDeviceSh
 
 from generativeaiexamples_tpu.ops import (
     flash_attention,
+    grouped_matmul,
     int8_matmul,
+    latent_attention,
     page_attention,
 )
 
@@ -149,6 +151,41 @@ def test_flash_attention_compiles(one_chip, no_persistent_cache, T):
 
     text = _compiled_text(flash_attention.flash_attention_causal, s(HQ), s(HKV), s(HKV))
     assert "tpu_custom_call" in text
+
+
+def test_dense_latent_attention_compiles_with_a_key_wider_than_the_value(one_chip, no_persistent_cache):
+    """The decode-side latent read of models/gigachat35.py at its published
+    widths: 64 slots x 64 heads against rows of [c 512 | k_rope 64] padded
+    to 640 columns, the value the first 512 (a slice on a lane tile)."""
+    B, H, W, R, pages = 64, 64, 640, 512, 64
+    assert page_attention.supports_geometry(PAGE, W, H, 1) and not page_attention.supports_geometry(PAGE, 576, H, 1)
+
+    def s(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    def read(q, pool, tables, positions):
+        return latent_attention.dense_latent_attention(q, pool, tables, positions, value_dim=R, scale=0.1053)
+
+    text = _compiled_text(read, s((B, H, W), jnp.bfloat16), s((B * pages + 1, PAGE, W), jnp.bfloat16),
+                          s((B, pages), jnp.int32), s((B,), jnp.int32))
+    assert "tpu_custom_call" in text
+
+
+@pytest.mark.parametrize("tokens", [64, 512], ids=["decode-16-row-tiles", "chunk-64-row-tiles"])
+def test_grouped_matmul_compiles_at_7168_wide_experts(one_chip, no_persistent_cache, tokens):
+    """``row_tile`` and the column blocks were chosen at 4096 x 2048
+    experts; the same kernels at 7168 x 2048, 16 held, 8 pairs a token."""
+    D, F, E, k = 7168, 2048, 16, 8
+
+    def s(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    def mlp(x, local, gates, w_gu, w_d):
+        return grouped_matmul.grouped_mlp(x, local, gates, w_gu, w_d, limit=10.0, kernel="compiled")
+
+    text = _compiled_text(mlp, s((tokens, D), jnp.bfloat16), s((tokens, k), jnp.int32), s((tokens, k), jnp.float32),
+                          s((E, D, 2 * F), jnp.bfloat16), s((E, F, D), jnp.bfloat16))
+    assert text.count("tpu_custom_call") >= 2
 
 
 def _sampler_args(sharding, V, B=64):
