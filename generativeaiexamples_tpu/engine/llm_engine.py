@@ -251,6 +251,13 @@ _M_LATENT_READ = _REG.counter(
     "(every token up to each query's own), at the one step a dispatch "
     "reports.",
 )
+_M_STATE_KERNEL_ROWS = _REG.counter(
+    "genai_engine_state_kernel_rows_total",
+    "Live rows whose recurrent state the step kernel advanced in place "
+    "(ops/delta_rule.py), at the one step a dispatch reports; stays 0 "
+    "where the XLA step serves. Beside the spans' state_rows it is the "
+    "share of decode steps the kernel engages on.",
+)
 # a family's step stats (models/registry.py ``stat_names``) that also feed
 # a counter, by the stat's name: the engine knows mechanisms, not models
 _STAT_COUNTERS = {
@@ -259,6 +266,7 @@ _STAT_COUNTERS = {
     "dsa_tokens_selected": _M_DSA_SELECTED,
     "dsa_context_tokens": _M_DSA_CONTEXT,
     "latent_tokens_read": _M_LATENT_READ,
+    "state_kernel_rows": _M_STATE_KERNEL_ROWS,
 }
 _M_SSM_DISPATCHES = _REG.counter(
     "genai_engine_ssm_dispatches_total",

@@ -12,7 +12,10 @@ reference (``perfbench/arch/gigachat35.py``):
 - **Gated DeltaNet**: 32 key heads feed 64 value heads (value head ``j``
   reads key head ``j // 2``), a ``[128, 128]`` float32 state a value
   head, ONE scalar decay a head and token. Decode is one delta-rule step
-  (``gdn_step``); prefill and extend compute the same recurrence
+  (``ops/delta_rule.py`` where the ``delta_step`` path resolved: the
+  state read once and written once, in place, the key heads mapped to
+  their value heads inside the kernel; ``gdn_step`` elsewhere); prefill
+  and extend compute the same recurrence
   block-wise (``gdn_chunk``). A scalar decay lets the block form use the
   pairwise decays ``exp(G_i - G_j) <= 1`` directly, so nothing is
   divided by a cumulative decay: no lower bound on the gate is needed
@@ -50,7 +53,7 @@ import jax.numpy as jnp
 from jax import lax
 
 from generativeaiexamples_tpu.models.glm5next import _mm, _write_rows, moe, rms_norm, swiglu_mlp
-from generativeaiexamples_tpu.ops import latent_attention, page_attention
+from generativeaiexamples_tpu.ops import delta_rule, latent_attention, page_attention
 
 Params = Dict[str, Any]
 Caches = Dict[str, Any]
@@ -60,7 +63,7 @@ _LANE = 128
 GDN_BLOCK = 64
 
 STAT_NAMES = ("moe_pairs_held", "moe_pairs_absent", "moe_experts_hit", "moe_experts_held",
-              "latent_tokens_read")
+              "latent_tokens_read", "state_kernel_rows")
 
 
 @dataclasses.dataclass(frozen=True)
@@ -658,23 +661,27 @@ def _chunk_walk(params: Params, cfg: GigaChat35Config, caches: Caches, tokens, o
         x, stats = mlp_sublayer(x, lp, mlp, cfg, tok_valid, grouped_matmul)
         if stats is not None:
             moe_stats = moe_stats + stats
-    new["stats"] = jnp.concatenate([moe_stats, latent_read[None]]).astype(jnp.int32)
+    # the block-wise recurrence advanced every state: the step kernel none
+    new["stats"] = jnp.concatenate([moe_stats, latent_read[None], jnp.zeros((1,), jnp.int32)]).astype(jnp.int32)
     return jnp.take_along_axis(x, last[:, None, None], axis=1)[:, 0], new
 
 
 def prefill_paged(params: Params, cfg: GigaChat35Config, caches: Caches, tokens, lengths, slots, tables,
-                  page_size: int, grouped_matmul: Optional[str] = None, **_paths):
+                  page_size: int, grouped_matmul: Optional[str] = None, delta_step: Optional[str] = None,
+                  **_paths):
     """A monolithic admission wave: (last-position logits [N, V], caches)."""
+    del delta_step  # the step kernel serves decode; a chunk walks block-wise
     hidden, caches = _chunk_walk(params, cfg, caches, tokens, jnp.zeros_like(lengths), lengths, slots,
                                  tables, page_size, grouped_matmul)
     return head(params, cfg, hidden), caches
 
 
 def extend_paged(params: Params, cfg: GigaChat35Config, caches: Caches, tokens, offsets, valid, slots,
-                 tables, window: int, page_size: int, grouped_matmul: Optional[str] = None, **_paths):
+                 tables, window: int, page_size: int, grouped_matmul: Optional[str] = None,
+                 delta_step: Optional[str] = None, **_paths):
     """One chunk of a chunked prefill: (the residual row [N, D] of each
     row's last valid position, caches)."""
-    del window  # the latent read follows each row's own context
+    del window, delta_step  # the latent read follows each row's own context; a chunk walks block-wise
     return _chunk_walk(params, cfg, caches, tokens, offsets, valid, slots, tables, page_size, grouped_matmul)
 
 
@@ -684,9 +691,11 @@ def extend_paged(params: Params, cfg: GigaChat35Config, caches: Caches, tokens, 
 
 def decode_paged(params: Params, cfg: GigaChat35Config, caches: Caches, tokens, positions, live, tables,
                  window: Optional[int], page_size: int, page_kernel: Optional[str] = None,
-                 grouped_matmul: Optional[str] = None, **_paths):
+                 grouped_matmul: Optional[str] = None, delta_step: Optional[str] = None, **_paths):
     """One token per slot: (logits [B, V], caches). A dead row leaves
-    every fixed state as it is and writes nothing to the pools."""
+    every fixed state as it is and writes nothing to the pools.
+    ``delta_step`` ('compiled' / 'interpret') advances the delta-rule
+    state with ``ops/delta_rule.py``, in place; None with ``gdn_step``."""
     del window
     B = tokens.shape[0]
     S = tables.shape[1] * page_size
@@ -712,9 +721,18 @@ def decode_paged(params: Params, cfg: GigaChat35Config, caches: Caches, tokens, 
                     proj = _mm(u, lp["wqkv"])
                     cat = jnp.concatenate([old_tail.astype(jnp.float32), proj[:, None]], axis=1)
                     q, k, v, beta, g, z = _gdn_inputs(u[:, None], cat, lp, cfg)
-                    o, S1 = gdn_step(old_S.astype(jnp.float32), q[:, 0], k[:, 0], v[:, 0], beta[:, 0], g[:, 0])
                     keep = live[:, None, None]
-                    new["gdn"][i] = jnp.where(keep[..., None], S1.astype(old_S.dtype), old_S)
+                    if delta_step:
+                        # the kernel maps a key head to the value heads it feeds: it takes each key head
+                        # once (repeat and slice fuse), and the one-a-head decay broadcast per channel
+                        ratio = cfg.linear_value_heads // cfg.linear_key_heads
+                        decay = jnp.broadcast_to(g[:, 0, :, None], g.shape[:1] + old_S.shape[1:3])
+                        o, new["gdn"][i] = delta_rule.delta_rule_step(
+                            old_S, q[:, 0, ::ratio], k[:, 0, ::ratio], v[:, 0], beta[:, 0], decay, live,
+                            interpret=(delta_step == "interpret"))
+                    else:
+                        o, S1 = gdn_step(old_S.astype(jnp.float32), q[:, 0], k[:, 0], v[:, 0], beta[:, 0], g[:, 0])
+                        new["gdn"][i] = jnp.where(keep[..., None], S1.astype(old_S.dtype), old_S)
                     new["conv"][i] = jnp.where(keep, cat[:, 1:].astype(old_tail.dtype), old_tail)
                     return _gdn_output(o, z[:, 0], lp, cfg)
         else:
@@ -748,5 +766,6 @@ def decode_paged(params: Params, cfg: GigaChat35Config, caches: Caches, tokens, 
         x, stats = mlp_sublayer(x, lp, mlp, cfg, live, grouped_matmul)
         if stats is not None:
             moe_stats = moe_stats + stats
-    new["stats"] = jnp.concatenate([moe_stats, latent_read[None]]).astype(jnp.int32)
+    kernel_rows = jnp.sum(live.astype(jnp.int32)) if delta_step and i_gdn else jnp.zeros((), jnp.int32)
+    new["stats"] = jnp.concatenate([moe_stats, latent_read[None], kernel_rows[None]]).astype(jnp.int32)
     return head(params, cfg, x), new

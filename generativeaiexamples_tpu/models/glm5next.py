@@ -12,7 +12,9 @@ reference (``perfbench/arch/glm5next.py``):
   ``RMSNorm(vec(X))``. float32.
 - **KDA** (Kimi delta attention, arXiv:2510.26692): 64 heads, a
   ``[128, 128]`` float32 state a head, per-channel decay. Decode is one
-  delta-rule step a token; prefill and extend compute the same
+  delta-rule step a token (``ops/delta_rule.py`` where the ``delta_step``
+  path resolved: the state read once and written once, in place;
+  ``kda_step`` elsewhere); prefill and extend compute the same
   recurrence block-wise in the WY / UT-transform form (``kda_chunk``):
   blocks of 16 tokens, matrix products, a 32-step loop over the blocks
   of a 512-token chunk carrying the state. 16, not 64: the form divides
@@ -37,8 +39,8 @@ reference (``perfbench/arch/glm5next.py``):
 per slot: KDA's state ``[slots, H, Dk, Dv]`` float32 and the three
 convolution tails, and the running sum of the open group's index keys.
 ``stats`` is a handful of int32 counts of the last walk (pairs held,
-experts hit, tokens selected), which the engine reads back with the
-tokens.
+experts hit, tokens selected, rows the step kernel advanced), which the
+engine reads back with the tokens.
 """
 from __future__ import annotations
 
@@ -51,6 +53,7 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
+from generativeaiexamples_tpu.ops import delta_rule
 from generativeaiexamples_tpu.ops import grouped_matmul as expert_ops
 from generativeaiexamples_tpu.ops import latent_attention, page_attention
 
@@ -61,7 +64,7 @@ _NEG = -1e30
 KDA_BLOCK = 16
 
 STAT_NAMES = ("moe_pairs_held", "moe_pairs_absent", "moe_experts_hit", "moe_experts_held",
-              "dsa_tokens_selected", "dsa_context_tokens")
+              "dsa_tokens_selected", "dsa_context_tokens", "state_kernel_rows")
 
 
 @dataclasses.dataclass(frozen=True)
@@ -770,14 +773,17 @@ def _chunk_walk(params: Params, cfg: Glm5NextConfig, caches: Caches, tokens, off
         X, stats = mlp_sublayer(X, lp, mlp, cfg, tok_valid, grouped_matmul)
         if stats is not None:
             moe_stats = moe_stats + stats
-    new["stats"] = jnp.concatenate([moe_stats, dsa_stats]).astype(jnp.int32)
+    # the block-wise recurrence advanced every state: the step kernel none
+    new["stats"] = jnp.concatenate([moe_stats, dsa_stats, jnp.zeros((1,), jnp.int32)]).astype(jnp.int32)
     h_last = jnp.take_along_axis(jnp.sum(X, axis=2), last[:, None, None], axis=1)[:, 0]
     return h_last, new
 
 
 def prefill_paged(params: Params, cfg: Glm5NextConfig, caches: Caches, tokens, lengths, slots, tables,
-                  page_size: int, grouped_matmul: Optional[str] = None, **_paths):
+                  page_size: int, grouped_matmul: Optional[str] = None, delta_step: Optional[str] = None,
+                  **_paths):
     """A monolithic admission wave: (last-position logits [N, V], caches)."""
+    del delta_step  # the step kernel serves decode; a chunk walks block-wise
     hidden, caches = _chunk_walk(params, cfg, caches, tokens, jnp.zeros_like(lengths), lengths, slots,
                                  tables, page_size, grouped_matmul)
     return head(params, cfg, hidden), caches
@@ -785,10 +791,10 @@ def prefill_paged(params: Params, cfg: Glm5NextConfig, caches: Caches, tokens, l
 
 def extend_paged(params: Params, cfg: Glm5NextConfig, caches: Caches, tokens, offsets, valid, slots,
                  tables, window: int, page_size: int, grouped_matmul: Optional[str] = None,
-                 capture: Optional[Dict[str, Any]] = None, **_paths):
+                 delta_step: Optional[str] = None, capture: Optional[Dict[str, Any]] = None, **_paths):
     """One chunk of a chunked prefill: (summed streams [N, D] of each
     row's last valid position, caches)."""
-    del window  # the latent read follows each row's own context
+    del window, delta_step  # the latent read follows each row's own context; a chunk walks block-wise
     return _chunk_walk(params, cfg, caches, tokens, offsets, valid, slots, tables, page_size,
                        grouped_matmul, capture)
 
@@ -799,9 +805,11 @@ def extend_paged(params: Params, cfg: Glm5NextConfig, caches: Caches, tokens, of
 
 def decode_paged(params: Params, cfg: Glm5NextConfig, caches: Caches, tokens, positions, live, tables,
                  window: Optional[int], page_size: int, page_kernel: Optional[str] = None,
-                 grouped_matmul: Optional[str] = None, **_paths):
+                 grouped_matmul: Optional[str] = None, delta_step: Optional[str] = None, **_paths):
     """One token per slot: (logits [B, V], caches). A dead row leaves
-    every fixed state as it is and writes nothing to the pools."""
+    every fixed state as it is and writes nothing to the pools.
+    ``delta_step`` ('compiled' / 'interpret') advances KDA's state with
+    ``ops/delta_rule.py``, in place; None with ``kda_step``."""
     del window
     B = tokens.shape[0]
     S = tables.shape[1] * page_size
@@ -827,9 +835,14 @@ def decode_paged(params: Params, cfg: Glm5NextConfig, caches: Caches, tokens, po
                     proj = _mm(x, lp["wqkv"])
                     cat = jnp.concatenate([old_tail.astype(jnp.float32), proj[:, None]], axis=1)
                     q, k, v, beta, g, gate = _kda_inputs(x[:, None], cat, lp, cfg)
-                    o, S1 = kda_step(old_S.astype(jnp.float32), q[:, 0], k[:, 0], v[:, 0], beta[:, 0], g[:, 0])
+                    step = (q[:, 0], k[:, 0], v[:, 0], beta[:, 0], g[:, 0])
                     keep = live[:, None, None]
-                    new["kda"][i] = jnp.where(keep[..., None], S1.astype(old_S.dtype), old_S)
+                    if delta_step:
+                        o, new["kda"][i] = delta_rule.delta_rule_step(
+                            old_S, *step, live, interpret=(delta_step == "interpret"))
+                    else:
+                        o, S1 = kda_step(old_S.astype(jnp.float32), *step)
+                        new["kda"][i] = jnp.where(keep[..., None], S1.astype(old_S.dtype), old_S)
                     new["conv"][i] = jnp.where(keep, cat[:, 1:].astype(old_tail.dtype), old_tail)
                     return _kda_output(o, gate[:, 0], lp, cfg)
         else:
@@ -871,7 +884,8 @@ def decode_paged(params: Params, cfg: Glm5NextConfig, caches: Caches, tokens, po
         X, stats = mlp_sublayer(X, lp, mlp, cfg, live, grouped_matmul)
         if stats is not None:
             moe_stats = moe_stats + stats
-    new["stats"] = jnp.concatenate([moe_stats, dsa_stats]).astype(jnp.int32)
+    kernel_rows = jnp.sum(live.astype(jnp.int32)) if delta_step and i_kda else jnp.zeros((), jnp.int32)
+    new["stats"] = jnp.concatenate([moe_stats, dsa_stats, kernel_rows[None]]).astype(jnp.int32)
     return head(params, cfg, jnp.sum(X, axis=1)), new
 
 

@@ -254,7 +254,7 @@ def _glm5next_family() -> ModelFamily:
             bytes_per_token=m.kv_bytes_per_token(cfg)),
         fixed_state_bytes_per_slot=m.fixed_state_bytes_per_slot,
         span_fields=lambda cfg: {"kda_layers": len(cfg.layers_of("kda")), "index_topk": cfg.index_topk},
-        resolve_kernels=lambda cfg, kind: {"grouped_matmul": kind},
+        resolve_kernels=lambda cfg, kind: {"grouped_matmul": kind, "delta_step": kind},
         stat_names=m.STAT_NAMES, read_stats=m.read_stats, extend_reads_window=False,
     )
 
@@ -280,7 +280,7 @@ def _gigachat35_family() -> ModelFamily:
             len(cfg.layers_of("mla")), 1, cfg.latent_row, cfg.num_heads,
             bytes_per_token=m.kv_bytes_per_token(cfg)),
         fixed_state_bytes_per_slot=m.fixed_state_bytes_per_slot,
-        resolve_kernels=lambda cfg, kind: {"grouped_matmul": kind},
+        resolve_kernels=lambda cfg, kind: {"grouped_matmul": kind, "delta_step": kind},
         stat_names=m.STAT_NAMES, read_stats=m.read_stats, extend_reads_window=False,
     )
 

@@ -22,6 +22,7 @@ import pytest
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P, SingleDeviceSharding
 
 from generativeaiexamples_tpu.ops import (
+    delta_rule,
     flash_attention,
     grouped_matmul,
     int8_matmul,
@@ -186,6 +187,61 @@ def test_grouped_matmul_compiles_at_7168_wide_experts(one_chip, no_persistent_ca
     text = _compiled_text(mlp, s((tokens, D), jnp.bfloat16), s((tokens, k), jnp.int32), s((tokens, k), jnp.float32),
                           s((E, D, 2 * F), jnp.bfloat16), s((E, F, D), jnp.bfloat16))
     assert text.count("tpu_custom_call") >= 2
+
+
+def _delta_rule_shapes(sharding, key_heads, per_channel, layers=None):
+    """The delta-rule step's operands at the two expert cells' shapes:
+    64 slots x 64 value heads x [128, 128] float32 a layer."""
+    N, H, D = 64, 64, 128
+
+    def s(*shape, dtype=jnp.float32):
+        shape = shape if layers is None else (layers,) + shape
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=sharding)
+
+    return (s(N, H, D, D), s(N, key_heads, D), s(N, key_heads, D), s(N, H, D), s(N, H),
+            s(N, H, D) if per_channel else s(N, H), jax.ShapeDtypeStruct((N,), jnp.bool_, sharding=sharding))
+
+
+def _delta_rule_step(S, q, k, v, beta, g, live):
+    if g.ndim == 2:  # one decay a head (Gated DeltaNet): handed over broadcast
+        g = jnp.broadcast_to(g[..., None], g.shape + S.shape[2:3])
+    return delta_rule.delta_rule_step(S, q, k, v, beta, g, live)
+
+
+@pytest.mark.parametrize("key_heads,per_channel", [(64, True), (32, False)], ids=["kda-64-key-heads", "gdn-32-key-heads"])
+def test_delta_rule_step_compiles_at_the_cells_shapes(one_chip, no_persistent_cache, key_heads, per_channel):
+    """models/glm5next.py's shape (per-channel decay, a key head a value
+    head) and models/gigachat35.py's (one decay a head broadcast, a key
+    head feeding two value heads through the block index map)."""
+    compiled = jax.jit(_delta_rule_step, donate_argnums=0).lower(
+        *_delta_rule_shapes(one_chip, key_heads, per_channel)).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+    state = 64 * 64 * 128 * 128 * 4
+    mem = compiled.memory_analysis()
+    assert mem.alias_size_in_bytes >= state and mem.temp_size_in_bytes < state // 8  # in place: no second state
+
+
+def test_delta_rule_decode_block_keeps_four_layers_of_state_in_place(one_chip, no_persistent_cache):
+    """The decode program's side of it: a ``lax.scan`` of two steps (the
+    expert cells' ``decode_block``) over four layers of donated state,
+    each advanced by the kernel: every layer's state is aliased from the
+    program's argument to its result, and no state-sized buffer appears
+    among the temporaries (1.07 GB of state on a chip that has 4 GB free)."""
+    layers, state = 4, 64 * 64 * 128 * 128 * 4
+
+    def block(states, q, k, v, beta, g, live):
+        def body(states, _):
+            outs = [_delta_rule_step(S, q[l], k[l], v[l], beta[l], g[l], live) for l, S in enumerate(states)]
+            return [S for _, S in outs], sum(o for o, _ in outs)
+
+        return jax.lax.scan(body, states, None, length=2)
+
+    S, *rest, live = _delta_rule_shapes(one_chip, 32, False, layers=layers)
+    one = jax.ShapeDtypeStruct(S.shape[1:], S.dtype, sharding=one_chip)
+    compiled = jax.jit(block, donate_argnums=0).lower([one] * layers, *rest, live).compile()
+    assert compiled.as_text().count("tpu_custom_call") >= layers
+    mem = compiled.memory_analysis()
+    assert mem.alias_size_in_bytes >= layers * state and mem.temp_size_in_bytes < state // 8
 
 
 def _sampler_args(sharding, V, B=64):

@@ -78,7 +78,8 @@ def _walks(kernel):
     ext = jax.jit(lambda params, caches, row, off, n, slot: m.extend_paged(
         params, CFG, caches, row, off, n, slot, TABLES, S, PAGE, grouped_matmul=kernel))
     dec = jax.jit(lambda params, caches, tok, pos, live: m.decode_paged(
-        params, CFG, caches, tok, pos, live, TABLES, S, PAGE, page_kernel=kernel, grouped_matmul=kernel))
+        params, CFG, caches, tok, pos, live, TABLES, S, PAGE, page_kernel=kernel, grouped_matmul=kernel,
+        delta_step=kernel))
     return ext, dec
 
 
@@ -335,6 +336,33 @@ def test_rows_decoding_together_equal_their_solo_runs(params, sequence):
     assert int(caches["stats"][4]) == 44 + 74
 
 
+def test_decode_through_the_step_kernel_equals_the_xla_step_in_logits_and_every_cache_leaf(params, sequence):
+    """``delta_step`` on against off, every other path the same: two
+    live rows and a dead one on dirty slots, three steps. The kernel maps
+    the 2 key heads to the 4 value heads itself and changes the summation
+    order over Dk, nothing else."""
+    toks, _ = sequence
+    _, caches = extend(params, dirty_caches(), toks, 0, 32, upto=40)
+    _, caches = extend(params, caches, toks, 2, 32, upto=70)
+    off = on = caches
+    walk = jax.jit(lambda caches, tok, pos, live, path: m.decode_paged(
+        params, CFG, caches, tok, pos, live, TABLES, S, PAGE, delta_step=path), static_argnums=4)
+    live = jnp.asarray([True, False, True])
+    for j in range(3):
+        tok = jnp.asarray([toks[40 + j], 0, toks[70 + j]], jnp.int32)
+        pos = jnp.asarray([40 + j, 0, 70 + j], jnp.int32)
+        logits_off, off = walk(off, tok, pos, live, None)
+        logits_on, on = walk(on, tok, pos, live, "interpret")
+        assert rel(logits_on[live], logits_off[live]) < 1e-5, j
+    assert int(on["stats"][-1]) == 2 and int(off["stats"][-1]) == 0 and m.STAT_NAMES[-1] == "state_kernel_rows"
+    assert np.array_equal(np.asarray(on["stats"][:-1]), np.asarray(off["stats"][:-1]))
+    for name in ("lat", "gdn", "conv"):
+        for a, b in zip(on[name], off[name]):
+            assert a.dtype == b.dtype and rel(a, b) < 1e-5, name
+    for a, b in zip(on["gdn"], caches["gdn"]):
+        assert a.dtype == jnp.float32 and np.array_equal(np.asarray(a[1]), np.asarray(b[1]))  # the dead row: bit-equal
+
+
 def test_registry_resolves_the_family_and_what_it_declares():
     fam, cfg = registry.resolve("gigachat35-debug")
     assert fam.name == "gigachat35" and fam.fixed_state and fam.verify_paged is None and cfg is CFG
@@ -343,7 +371,7 @@ def test_registry_resolves_the_family_and_what_it_declares():
     # every resolved kernel path is a keyword of the walks under the SAME name: one that a walk took under
     # another name would vanish in **_paths and the XLA path would serve (found on the chip, PR 35)
     resolved = fam.resolve_kernels(cfg, "compiled")
-    assert resolved == {"grouped_matmul": "compiled"}
+    assert resolved == {"grouped_matmul": "compiled", "delta_step": "compiled"}
     for walk in (m.prefill_paged, m.extend_paged, m.decode_paged):
         assert set(resolved) <= set(inspect.signature(walk).parameters)
     assert "page_kernel" in inspect.signature(m.decode_paged).parameters  # the engine's own, which serves the latent read
@@ -383,7 +411,7 @@ def test_engine_serves_every_prompt_shape_as_the_references_argmax(engine):
     from generativeaiexamples_tpu.engine.llm_engine import SamplingParams
 
     assert engine._family.name == "gigachat35" and engine._paged_kernel == "interpret"
-    assert engine._family_kernels == {"grouped_matmul": "interpret"}
+    assert engine._family_kernels == {"grouped_matmul": "interpret", "delta_step": "interpret"}
     rng = np.random.default_rng(1)
     prompts = [[int(t) for t in rng.integers(3, 250, size=n)] for n in (5, 64, 100, 150, 9)]
     before = engine.metrics
@@ -429,6 +457,10 @@ def test_engine_reads_the_familys_counts_back_with_the_tokens(engine):
     read = [s["latent_tokens_read"] for s in spans if s["kind"] == "prefill_chunk"]
     assert sum(range(1, 65)) in read and sum(range(65, 101)) in read and 100 < step["latent_tokens_read"] <= 109
     assert step["kv_pages_walked"] >= 7
+    # the step kernel engaged on every row a decode dispatch advanced, on none of a chunk's
+    assert step["state_kernel_rows"] == step["state_rows"] and chunk["state_kernel_rows"] == 0
+    assert grew("genai_engine_state_kernel_rows_total") >= 1
+    assert engine._compile_watch.snapshot().get("hot_path_compiles_total", 0) == 0
 
 
 REFUSED = {

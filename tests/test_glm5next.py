@@ -67,7 +67,8 @@ def _walks(kernel):
     ext = jax.jit(lambda params, caches, row, off, n, slot: m.extend_paged(
         params, CFG, caches, row, off, n, slot, TABLES, S, PAGE, grouped_matmul=kernel))
     dec = jax.jit(lambda params, caches, tok, pos, live: m.decode_paged(
-        params, CFG, caches, tok, pos, live, TABLES, S, PAGE, page_kernel=kernel, grouped_matmul=kernel))
+        params, CFG, caches, tok, pos, live, TABLES, S, PAGE, page_kernel=kernel, grouped_matmul=kernel,
+        delta_step=kernel))
     return ext, dec
 
 
@@ -342,18 +343,46 @@ def test_rows_decoding_together_equal_their_solo_runs(params, sequence):
         assert rel(logits[0], full[40 + j]) < TOL and rel(logits[2], full[70 + j]) < TOL
 
 
+def test_decode_through_the_step_kernel_equals_the_xla_step_in_logits_and_every_cache_leaf(params, sequence):
+    """``delta_step`` on against off, every other path the same: two
+    live rows and a dead one on dirty slots, three steps. The kernel
+    changes the summation order over Dk and nothing else."""
+    toks, _ = sequence
+    caches = dirty_caches()
+    _, caches = extend(params, caches, toks[0], 0, 32, upto=40)
+    _, caches = extend(params, caches, toks[0], 2, 32, upto=70)
+    off = on = caches
+    walk = jax.jit(lambda caches, tok, pos, live, path: m.decode_paged(
+        params, CFG, caches, tok, pos, live, TABLES, S, PAGE, delta_step=path), static_argnums=4)
+    live = jnp.asarray([True, False, True])
+    for j in range(3):
+        tok = jnp.asarray([toks[0, 40 + j], 0, toks[0, 70 + j]], jnp.int32)
+        pos = jnp.asarray([40 + j, 0, 70 + j], jnp.int32)
+        logits_off, off = walk(off, tok, pos, live, None)
+        logits_on, on = walk(on, tok, pos, live, "interpret")
+        assert rel(logits_on[live], logits_off[live]) < 1e-5, j
+    assert int(on["stats"][-1]) == 2 and int(off["stats"][-1]) == 0 and m.STAT_NAMES[-1] == "state_kernel_rows"
+    assert np.array_equal(np.asarray(on["stats"][:-1]), np.asarray(off["stats"][:-1]))
+    for name in ("lat", "idx", "idx_sum", "kda", "conv"):
+        for a, b in zip(on[name], off[name]):
+            assert a.dtype == b.dtype and rel(a, b) < 1e-5, name
+    for a, b in zip(on["kda"], caches["kda"]):
+        assert a.dtype == jnp.float32 and np.array_equal(np.asarray(a[1]), np.asarray(b[1]))  # the dead row: bit-equal
+
+
 def test_registry_resolves_the_family_and_what_it_declares():
     fam, cfg = registry.resolve("glm5next-debug")
     assert fam.name == "glm5next" and fam.fixed_state and fam.verify_paged is None and cfg is CFG
     shape = fam.paged_kv_shape(m.PRESETS["glm-5.3-flash-ep8"])
     assert (shape.num_layers, shape.num_kv_heads, shape.head_dim, shape.num_heads, shape.bytes_per_token) == (1, 1, 512, 64, 1088)
-    assert fam.resolve_kernels(cfg, "compiled") == {"grouped_matmul": "compiled"}
+    paths = fam.resolve_kernels(cfg, "compiled")
+    assert paths == {"grouped_matmul": "compiled", "delta_step": "compiled"}
     # the engine hands a resolved path to the walks as a keyword of the SAME name: a walk that took it
     # under another name would swallow it in **_paths and serve the XLA path (found on the chip, PR 35)
     import inspect
 
     for walk in (m.prefill_paged, m.extend_paged, m.decode_paged):
-        assert "grouped_matmul" in inspect.signature(walk).parameters
+        assert set(paths) <= set(inspect.signature(walk).parameters)
     assert fam.stat_names == m.STAT_NAMES and registry.family_of(CFG).name == "glm5next"
     assert registry.resolve("phi4flash-debug")[0].stat_names == () and registry.resolve("debug")[0].resolve_kernels(None, "compiled") == {}
 
@@ -387,7 +416,7 @@ def test_engine_serves_every_prompt_shape_as_the_models_own_argmax(engine):
     from generativeaiexamples_tpu.engine.llm_engine import SamplingParams
 
     assert engine._family.name == "glm5next" and engine._paged_kernel == "interpret"
-    assert engine._family_kernels == {"grouped_matmul": "interpret"}
+    assert engine._family_kernels == {"grouped_matmul": "interpret", "delta_step": "interpret"}
     rng = np.random.default_rng(1)
     prompts = [[int(t) for t in rng.integers(3, 250, size=n)] for n in (5, 64, 100, 150, 9)]
     before = engine.metrics
@@ -423,15 +452,19 @@ def test_engine_reads_the_familys_counts_back_with_the_tokens(engine):
     assert grew('genai_engine_moe_pairs_total{held="true"}') > 0 and grew('genai_engine_moe_pairs_total{held="false"}') > 0
     assert 0 < grew("genai_engine_dsa_selected_tokens_total") < grew("genai_engine_dsa_context_tokens_total")
     assert after["genai_engine_fixed_state_bytes"] == 3 * m.fixed_state_bytes_per_slot(CFG, 2)
-    # (the ring is the process's: another engine of this worker may have written to it since)
+    # (the ring is the process's: another family's engine of this worker may have written to it; newest first)
     spans = [s for s in dispatch_timeline.recent_spans(256)
-             if s.get("kind") in ("decode", "prefill_chunk") and "moe_experts_held" in s]
-    chunk = [s for s in spans if s["kind"] == "prefill_chunk"][-1]
-    step = [s for s in spans if s["kind"] == "decode"][-1]
+             if s.get("kind") in ("decode", "prefill_chunk") and "dsa_tokens_selected" in s]
+    chunk = [s for s in spans if s["kind"] == "prefill_chunk"][0]
+    step = [s for s in spans if s["kind"] == "decode"][0]
     for s in (chunk, step):
         assert s["state_rows"] == 1 and s["moe_experts_held"] == 8 and s["moe_experts_hit"] >= 1
         assert s["moe_pairs_held"] >= s["moe_experts_hit"] and "kv_readers" not in s and "cross_skipped_tokens" not in s
     assert step["dsa_tokens_selected"] <= 36 < step["dsa_context_tokens"]
+    # the step kernel engaged on every row a decode dispatch advanced, on none of a chunk's
+    assert step["state_kernel_rows"] == step["state_rows"] and chunk["state_kernel_rows"] == 0
+    assert grew("genai_engine_state_kernel_rows_total") >= 1
+    assert engine._compile_watch.snapshot().get("hot_path_compiles_total", 0) == 0
 
 
 REFUSED = {
