@@ -1,56 +1,69 @@
-"""Dispatch timeline profiler: per-launch spans + bubble attribution.
+"""Dispatch timeline: one span per launch, from enqueue to the device's
+completion, and what the host and jit did meanwhile.
 
-compile_watch proves steady state never recompiles and the flight
-recorder decomposes a *request's* latency into phases; this module
-decomposes the *engine's* wall time. Every compiled-program launch the
-engine issues (prefill wave, prefill chunk, decode block, spec verify,
-spec-block fallback) already funnels through one choke point — the
-``_dispatch_lock`` + ``telemetry.record_dispatch`` pairing — and this
-module rides that choke point with a bounded, lock-light ring of
-**dispatch spans**: program kind, tier thread, enqueue wall-clock,
-dispatch-lock wait, host-side run time (the device-time estimate on
-CPU; xplane is ground truth on TPU — ``utils/xplane.py``), batch
-geometry, attention path, and the rids in the wave. Reader-thread
-stalls and disagg handoff backpressure record as their own span
-categories, and hot-path compiles overlay as markers.
+compile_watch says what jit did and the flight recorder decomposes a
+*request's* latency; this module decomposes the *engine's* wall time.
+Every compiled-program launch with a span (prefill wave, prefill chunk,
+decode block, spec verify, spec-block fallback) is recorded at its one
+choke point, the ``_dispatch_lock``, into a bounded ring of **dispatch
+spans**: program kind, tier thread, wall clock, dispatch-lock wait, the
+enqueue call's own length (``enqueue_s``: host time inside the lock,
+jit's work included), batch geometry, attention path, rids.
+
+One chip runs the engine's programs in the order they were enqueued, and
+each such launch has a small output no program donates. ONE thread (the
+watcher, ``start_watcher``) awaits those outputs in enqueue order and
+stamps on each span the wall time at which its output was ready
+(``t_done``). From that and the enqueue time come ``device_s`` (the
+device's time on this launch while anything was queued behind the last
+one), ``starved_s`` (the device had nothing of the engine's to run) and
+``queued_s`` (how far ahead of the device the host was). Programs
+without a span of their own (finish, put_rows, update_slots,
+page_tables, an embed dispatch) are charged to the next span. Sums are
+conserved where single stamps are late. A launch held far longer than
+its program and rung ever take leaves ONE ``device_hold:<program>``
+record (``_hold_record``) of what the process can know about it.
 
 On top of the ring:
 
-- a **bubble analyzer** decomposing rolling-window engine-active wall
-  time into device-busy / lock-contention / host-gap-with-work-queued /
-  readback (the four components sum to 1.0 of the windowed active
-  wall), exposed as the ``genai_engine_bubble_*`` gauges and the
-  ``genai_engine_lock_wait_seconds`` / ``genai_engine_dispatch_gap_seconds``
-  distributions, and folded into ``LLMEngine.utilization_snapshot()``;
+- the **bubble decomposition** of rolling-window engine-active wall:
+  ``device`` (sum of ``device_s``), ``host_gap`` (sum of ``starved_s``),
+  ``lock_contention`` and ``readback`` (as measured on the host), in
+  ``bubble_snapshot()`` (``GET /internal/timeline``, ``/internal/slo``)
+  and cumulatively in ``counters_snapshot()`` (the loadgen scraper);
+- ``genai_engine_dispatch_device_seconds{program}``,
+  ``genai_engine_device_starved_seconds_total``,
+  ``genai_engine_lock_wait_seconds`` and
+  ``genai_engine_dispatch_gap_seconds`` (each launch's ``starved_s``);
 - ``GET /internal/timeline`` (server/observability.py) serving the ring
-  incrementally (``?since=<cursor>``, same contract as
-  ``/internal/requests``) and as Chrome-trace JSON
+  incrementally (``?since=<cursor>``) and as Chrome-trace JSON
   (``?format=perfetto``): one track per tier thread plus a device
-  track, flight-recorder lifecycle events overlaid, joinable to
-  stitched router traces by trace id;
-- recent span windows embedded in black-box bundles
-  (utils/blackbox.py) so an anomaly capture carries the dispatch
-  cadence around the incident.
+  track from the completion stamps, flight-recorder events overlaid;
+- recent span windows embedded in black-box bundles (utils/blackbox.py).
 
-Ring semantics mirror utils/flight_recorder.py: a module-level
-monotonic ``seq`` cursor, whole-window eviction (``WINDOW_SPANS`` spans
-drop together — a reader never sees a window that lost spans
-mid-window), a ``reset()`` test hook, the
-``configure``/``validate_config``/``configure_from_config`` trio wired
-to the ``observability`` config section, and the
-``GENAI_DISPATCH_TIMELINE=off`` process kill switch — the engine
-resolves it ONCE at init (the ``annotation_scope`` pattern), so 'off'
-restores the exact prior dispatch path.
+Ring semantics mirror utils/flight_recorder.py: a monotonic ``seq``
+cursor, whole-window eviction (``WINDOW_SPANS`` spans drop together), a
+``reset()`` test hook, the ``configure``/``validate_config``/
+``configure_from_config`` trio, and the ``GENAI_DISPATCH_TIMELINE=off``
+process kill switch, which turns the stamp off with the spans — the
+engine resolves it ONCE at init, so 'off' restores the exact prior
+dispatch path.
 """
 from __future__ import annotations
 
+import gc
 import os
+import queue
+import statistics
 import threading
 import time
 from collections import deque
 from typing import Any, Deque, Dict, List, Optional, Sequence, Tuple
 
 from generativeaiexamples_tpu.utils import metrics as metrics_mod
+from generativeaiexamples_tpu.utils.logging import get_logger
+
+logger = get_logger(__name__)
 
 __all__ = [
     "enabled",
@@ -63,6 +76,9 @@ __all__ = [
     "record_pipeline_flush",
     "record_rollback",
     "record_compile",
+    "note_jit",
+    "start_watcher",
+    "stamp_pending",
     "cursor",
     "spans_since",
     "recent_spans",
@@ -99,27 +115,24 @@ _M_LOCK_WAIT = _REG.histogram(
 )
 _M_GAP = _REG.histogram(
     "genai_engine_dispatch_gap_seconds",
-    "Host-side gap between a tier thread's consecutive dispatches "
-    "while work was queued (scheduling, sampling bookkeeping, "
-    "emission) — the host-bubble half of the decomposition.",
+    "Per launch, how long the device had nothing of the engine's to run "
+    "before it was enqueued (the span's starved_s: the last launch was "
+    "done and the host had not enqueued the next).",
     buckets=metrics_mod.FAST_SECONDS_BUCKETS,
 )
-_M_BUBBLE = _REG.gauge(
-    "genai_engine_bubble_ratio",
-    "Fraction of rolling-window engine-active wall time NOT spent in "
-    "device dispatches (lock contention + host gap + readback).",
+_M_DEVICE = _REG.histogram(
+    "genai_engine_dispatch_device_seconds",
+    "The device's time on one launch, from the later of its enqueue and "
+    "the previous launch's completion to its own completion (the "
+    "span's device_s; unspanned programs enqueued before it included), "
+    "by program kind.",
+    ("program",),
+    buckets=metrics_mod.FAST_SECONDS_BUCKETS,
 )
-_M_BUBBLE_COMPONENT = _REG.gauge(
-    "genai_engine_bubble_component_ratio",
-    "Rolling-window engine-active wall decomposition, by component "
-    "(device, lock_contention, host_gap, readback); the four "
-    "components sum to 1.0.",
-    ("component",),
-)
-_M_BUBBLE_WINDOW = _REG.gauge(
-    "genai_engine_bubble_window_seconds",
-    "Engine-active wall time covered by the current bubble-analyzer "
-    "rolling window (device + lock + gap + readback seconds).",
+_M_STARVED = _REG.counter(
+    "genai_engine_device_starved_seconds_total",
+    "Seconds the device had nothing of the engine's to run between two "
+    "launches (sum of the spans' starved_s).",
 )
 
 # --------------------------------------------------------------------------- #
@@ -147,22 +160,21 @@ _BUBBLE_WINDOW_S = 60.0
 # Per-span rid cap: a 96-row wave's ids matter less than its shape.
 _RID_CAP = 16
 
+# A long hold (constants, not configuration): a launch whose device_s
+# passes BOTH is recorded once as device_hold:<program>.
+HOLD_MIN_S = 1.0
+HOLD_TIMES_MEDIAN = 8.0
+_HOLD_HISTORY = 33  # device_s kept per (program, rung) for the median
+_HOLD_MIN_SAMPLES = 3
+HEARTBEAT_S = 0.02
+
 _LOCK = threading.Lock()
 _SPANS: Deque["Span"] = deque()  # guarded by _LOCK
 _SEQ = 0  # guarded by _LOCK; process-lifetime monotonic, reset() rewinds
-# Per-thread wall clock of the last span's host return, for gap
-# attribution (guarded by _LOCK).
-_LAST_RETURN: Dict[str, float] = {}
 # Cumulative component seconds (guarded by _LOCK) — the loadgen
 # telemetry scraper reads these as run-window deltas via the engine's
 # legacy flat `metrics` dict.
-_CUM = {
-    "spans": 0.0,
-    "device": 0.0,
-    "lock": 0.0,
-    "gap": 0.0,
-    "readback": 0.0,
-}
+_CUM = {"spans": 0.0, "device": 0.0, "lock": 0.0, "gap": 0.0, "readback": 0.0}
 
 # Per-MODE bubble split (guarded by _LOCK): the same four component
 # seconds plus a dispatch count, attributed to the serving mode that
@@ -192,19 +204,21 @@ def _mode_of(kind: str) -> str:
 
 class Span:
     """One recorded launch/stall/readback. Appends are deque.append
-    under the module lock; the record itself is immutable after that."""
+    under the module lock; after that only the watcher writes, once, the
+    completion fields of a dispatch span (and the engine ``tokens`` of a
+    verify whose count lands with its readback)."""
 
     __slots__ = (
         "seq", "kind", "category", "thread", "t_wall", "lock_wait_s",
-        "run_s", "gap_s", "rows", "tokens", "steps", "path", "rids",
-        "counters",
+        "run_s", "rows", "tokens", "steps", "path", "rids", "counters",
+        "t_done", "device_s", "starved_s", "queued_s", "jit_s", "jit_what",
     )
 
     def __init__(self, kind: str, category: str, thread: str,
                  t_wall: float, lock_wait_s: float, run_s: float,
-                 gap_s: float, rows: int, tokens: int, steps: int,
-                 path: Optional[str], rids: Tuple[int, ...],
-                 counters: Optional[Dict[str, int]] = None):
+                 rows: int = 0, tokens: int = 0, steps: int = 1,
+                 path: Optional[str] = None, rids: Tuple[int, ...] = (),
+                 counters: Optional[Dict[str, Any]] = None):
         self.seq = 0  # assigned under _LOCK at record time
         self.kind = kind
         self.category = category  # dispatch | stall | readback | compile
@@ -212,18 +226,22 @@ class Span:
         self.t_wall = t_wall
         self.lock_wait_s = lock_wait_s
         self.run_s = run_s
-        self.gap_s = gap_s
         self.rows = rows
         self.tokens = tokens
         self.steps = steps
         self.path = path
         self.rids = rids
         # kind-specific counts, shown as top-level fields of the view
-        # (decode: kv_pages_walked / kv_pages_grid)
+        # (decode: kv_pages_walked / kv_pages_grid; a hold: its record)
         self.counters = counters
+        self.t_done: Optional[float] = None  # the watcher's stamp
+        self.device_s = self.starved_s = self.queued_s = 0.0
+        self.jit_s = 0.0
+        self.jit_what: Optional[str] = None
 
     @property
-    def t_end(self) -> float:
+    def t_enq(self) -> float:
+        """Wall time at which the enqueue call returned."""
         return self.t_wall + self.lock_wait_s + self.run_s
 
     def view(self) -> Dict[str, Any]:
@@ -234,12 +252,23 @@ class Span:
             "thread": self.thread,
             "t_wall": round(self.t_wall, 6),
             "lock_wait_s": round(self.lock_wait_s, 6),
-            "device_est_s": round(self.run_s, 6),
-            "gap_s": round(self.gap_s, 6),
             "rows": self.rows,
             "tokens": self.tokens,
             "steps": self.steps,
         }
+        if self.category == "dispatch":
+            out["t_enq"] = round(self.t_enq, 6)
+            out["enqueue_s"] = round(self.run_s, 6)
+            if self.t_done is not None:
+                out["t_done"] = round(self.t_done, 6)
+                out["device_s"] = round(self.device_s, 6)
+                out["starved_s"] = round(self.starved_s, 6)
+                out["queued_s"] = round(self.queued_s, 6)
+            if self.jit_what is not None:
+                out["jit_s"] = round(self.jit_s, 6)
+                out["jit_what"] = self.jit_what
+        else:
+            out["duration_s"] = round(self.run_s, 6)
         if self.path is not None:
             out["path"] = self.path
         if self.rids:
@@ -325,7 +354,7 @@ def _evict_window_locked() -> None:
         _M_EVICTED.inc(dropped)
 
 
-def _append(span: Span, observe_gap: bool) -> None:
+def _append(span: Span) -> None:
     global _SEQ
     with _LOCK:
         _SEQ += 1
@@ -336,18 +365,9 @@ def _append(span: Span, observe_gap: bool) -> None:
         _CUM["spans"] += 1
         mode = _CUM_MODE[_mode_of(span.kind)]
         if span.category == "dispatch":
-            _CUM["device"] += span.run_s
             _CUM["lock"] += span.lock_wait_s
-            _CUM["gap"] += span.gap_s
-            mode["device"] += span.run_s
             mode["lock"] += span.lock_wait_s
-            mode["gap"] += span.gap_s
             mode["dispatches"] += 1
-            _LAST_RETURN[span.thread] = span.t_end
-        elif span.category == "stall":
-            _CUM["gap"] += span.run_s
-            mode["gap"] += span.run_s
-            _LAST_RETURN[span.thread] = span.t_end
         elif span.category == "readback":
             _CUM["readback"] += span.run_s
             mode["readback"] += span.run_s
@@ -356,8 +376,6 @@ def _append(span: Span, observe_gap: bool) -> None:
         _M_LOCK_WAIT.labels(kind=span.kind).observe(
             span.lock_wait_s, trace_id=None
         )
-        if observe_gap:
-            _M_GAP.observe(span.gap_s, trace_id=None)
 
 
 def record_span(
@@ -371,68 +389,61 @@ def record_span(
     steps: int = 1,
     path: Optional[str] = None,
     rids: Sequence[int] = (),
-    queued: bool = True,
     counters: Optional[Dict[str, int]] = None,
-) -> None:
-    """One compiled-program launch: ``t_wall`` is the enqueue wall
-    clock (lock requested), ``lock_wait_s`` the dispatch-lock wait,
-    ``run_s`` the host-side time inside the lock (device-time estimate
-    — on TPU the async dispatch returns early and xplane is truth).
-    ``queued`` gates gap attribution: the host gap since this thread's
-    previous dispatch counts as bubble only when work was available the
-    whole time. ``counters`` are extra counts of this kind of launch,
-    shown as fields of the span's view."""
+    handle: Any = None,
+) -> Optional[Span]:
+    """One compiled-program launch: ``t_wall`` is the wall clock at
+    which the lock was requested, ``lock_wait_s`` the dispatch-lock
+    wait, ``run_s`` the host time inside the lock (the view's
+    ``enqueue_s``: on a TPU the enqueue call returns early). ``handle``
+    is an output of the launch that no program donates: the watcher
+    awaits it and stamps the completion fields. ``counters`` are extra
+    counts of this kind of launch, shown as fields of the span's view.
+    jit work this thread did since its last span (``note_jit``) is
+    stamped on this one."""
     if not _ENABLED:
-        return
-    thread = threading.current_thread().name
-    gap_s = 0.0
-    if queued:
-        last = _LAST_RETURN.get(thread)
-        if last is not None:
-            gap_s = max(0.0, t_wall - last)
-    _append(
-        Span(
-            kind, "dispatch", thread, t_wall, max(0.0, lock_wait_s),
-            max(0.0, run_s), gap_s, int(rows), int(tokens),
-            max(1, int(steps)), path, tuple(rids)[:_RID_CAP], counters,
-        ),
-        observe_gap=queued,
+        return None
+    span = Span(
+        kind, "dispatch", threading.current_thread().name, t_wall,
+        max(0.0, lock_wait_s), max(0.0, run_s), int(rows), int(tokens),
+        max(1, int(steps)), path, tuple(rids)[:_RID_CAP], counters,
     )
+    jit = getattr(_JIT_TLS, "pending", None)
+    if jit is not None:
+        _JIT_TLS.pending = None
+        span.jit_s, span.jit_what = jit
+    _append(span)
+    if handle is not None:
+        _PENDING.put((span, handle, _EPOCH))
+    return span
+
+
+def _record(kind: str, category: str, duration_s: float, rows: int = 0,
+            rids: Sequence[int] = (), counters=None) -> None:
+    """A host-side fact that ended now and lasted ``duration_s``."""
+    _append(Span(
+        kind, category, threading.current_thread().name,
+        time.time() - duration_s, 0.0, float(duration_s), int(rows),
+        rids=tuple(rids)[:_RID_CAP], counters=counters,
+    ))
 
 
 def record_stall(
     kind: str, duration_s: float, rids: Sequence[int] = ()
 ) -> None:
     """A named host stall on a tier thread (disagg handoff
-    backpressure, transfer-queue waits): visible as its own span on the
-    thread's track and attributed to the host-gap bubble component."""
-    if not _ENABLED or duration_s <= 0:
-        return
-    thread = threading.current_thread().name
-    _append(
-        Span(
-            kind, "stall", thread, time.time() - duration_s, 0.0,
-            float(duration_s), 0.0, 0, 0, 1, None,
-            tuple(rids)[:_RID_CAP],
-        ),
-        observe_gap=False,
-    )
+    backpressure, transfer-queue waits): its own span on the thread's
+    track. What it cost the device shows as the next launch's
+    ``starved_s``."""
+    if _ENABLED and duration_s > 0:
+        _record(kind, "stall", duration_s, rids=rids)
 
 
 def record_readback(kind: str, stall_s: float) -> None:
     """A device→host sync stall (reader thread, or the spec paths'
     on-thread syncs), attributed to the readback bubble component."""
-    if not _ENABLED or stall_s < 0:
-        return
-    thread = threading.current_thread().name
-    _append(
-        Span(
-            f"readback:{kind}", "readback", thread,
-            time.time() - stall_s, 0.0, float(stall_s), 0.0, 0, 0, 1,
-            None, (),
-        ),
-        observe_gap=False,
-    )
+    if _ENABLED and stall_s >= 0:
+        _record(f"readback:{kind}", "readback", stall_s)
 
 
 def record_pipeline_flush(stall_s: float, rows: int = 0) -> None:
@@ -443,16 +454,8 @@ def record_pipeline_flush(stall_s: float, rows: int = 0) -> None:
     readback, shrunk by whatever host work overlapped the in-flight
     verify — under its own ``pipeline_flush`` kind so the before/after
     of the async pipeline is visible in the ring, not just the sums."""
-    if not _ENABLED or stall_s < 0:
-        return
-    thread = threading.current_thread().name
-    _append(
-        Span(
-            "pipeline_flush", "readback", thread, time.time() - stall_s,
-            0.0, float(stall_s), 0.0, int(rows), 0, 1, None, (),
-        ),
-        observe_gap=False,
-    )
+    if _ENABLED and stall_s >= 0:
+        _record("pipeline_flush", "readback", stall_s, rows)
 
 
 def record_rollback(
@@ -461,37 +464,209 @@ def record_rollback(
     """An optimistic-draft rollback: verify readback contradicted the
     acceptance assumption the runahead draft was proposed under, and
     the dispatch thread re-proposed from the true context. Stall
-    category (host-gap bubble) with its own ``rollback`` kind;
-    ``rows`` counts the rolled-back rows in the round."""
-    if not _ENABLED or duration_s < 0:
-        return
-    thread = threading.current_thread().name
-    _append(
-        Span(
-            "rollback", "stall", thread, time.time() - duration_s, 0.0,
-            float(duration_s), 0.0, int(rows), 0, 1, None,
-            tuple(rids)[:_RID_CAP],
-        ),
-        observe_gap=False,
-    )
+    category with its own ``rollback`` kind; ``rows`` counts the
+    rolled-back rows in the round."""
+    if _ENABLED and duration_s >= 0:
+        _record("rollback", "stall", duration_s, rows, rids)
 
 
 def record_compile(program: str, seconds: float, hot: bool = False) -> None:
-    """A compiled-program build (engine/compile_watch.py) as a timeline
-    marker. The build time already lands inside its dispatch span's
-    run_s, so compile spans are overlay-only: excluded from the bubble
-    sums and from gap bookkeeping."""
+    """jit work inside an engine program (engine/compile_watch.py) as a
+    timeline marker. The time already lands inside its dispatch span's
+    ``enqueue_s``, so compile spans are overlay-only: excluded from the
+    bubble sums."""
+    if _ENABLED:
+        _record(("hot_compile:" if hot else "compile:") + program,
+                "compile", seconds)
+
+
+# --------------------------------------------------------------------------- #
+# jit events (fed by engine/compile_watch.py's listeners)
+
+_JIT_TLS = threading.local()  # .pending: (seconds, what) since the last span
+_JIT_RANK = {None: 0, "trace": 1, "cache_load": 2, "compile": 3}
+# (t_end, program, what, seconds) of the newest jit work in engine
+# programs, for a hold's record
+_JIT_EVENTS: Deque[Tuple[float, str, str, float]] = deque(maxlen=64)
+
+
+def note_jit(program: str, what: str, seconds: float) -> None:
+    """jit traced, lowered, compiled or loaded an executable inside the
+    engine program ``program`` on this thread, ending now: kept for the
+    span this thread records next (the one it happened in, or the one an
+    unspanned program is charged to) and for a hold's record."""
     if not _ENABLED:
         return
-    thread = threading.current_thread().name
-    _append(
-        Span(
-            ("hot_compile:" if hot else "compile:") + program,
-            "compile", thread, time.time() - seconds, 0.0,
-            float(seconds), 0.0, 0, 0, 1, None, (),
-        ),
-        observe_gap=False,
+    _JIT_EVENTS.append((time.time(), program, what, round(seconds, 6)))
+    old_s, old_what = getattr(_JIT_TLS, "pending", None) or (0.0, None)
+    if _JIT_RANK[what] < _JIT_RANK[old_what]:
+        what = old_what
+    _JIT_TLS.pending = (old_s + seconds, what)
+
+
+# --------------------------------------------------------------------------- #
+# The completion stamp: ONE thread awaits the launches' outputs in
+# enqueue order. It does nothing else — the reader's own work between
+# two readbacks (a slab of 64 streams is ~5 ms) would make stamps late.
+
+_PENDING: "queue.SimpleQueue[Tuple[Span, Any, int]]" = queue.SimpleQueue()
+_EPOCH = 0  # reset() bumps it: a launch awaited across a reset is not stamped
+_WATCHER: Optional[threading.Thread] = None  # guarded by _LOCK
+_PREV_DONE: Optional[float] = None  # watcher-owned: the last stamp
+_PREV_CPU = 0.0  # watcher-owned: process CPU seconds at the last stamp
+_PREV_GC = 0  # watcher-owned: gc pauses counted at the last stamp
+_RECENT: Deque[Span] = deque(maxlen=3)  # watcher-owned: the last launches
+_RUNG_S: Dict[tuple, Deque[float]] = {}  # watcher-owned: device_s by rung
+_GC_PAUSES = 0
+# (t, gap) of heartbeats that came late, newest last
+_LATE_BEATS: Deque[Tuple[float, float]] = deque(maxlen=256)
+
+
+def _count_gc(phase: str, info: dict) -> None:
+    global _GC_PAUSES
+    if phase == "stop":
+        _GC_PAUSES += 1
+
+
+def _heartbeat() -> None:
+    """Sleeps HEARTBEAT_S at a time and keeps the beats that came late:
+    a hold during which the HOST stood still shows here."""
+    last = time.time()
+    while True:
+        time.sleep(HEARTBEAT_S)
+        now = time.time()
+        if now - last > 2 * HEARTBEAT_S:
+            _LATE_BEATS.append((now, now - last))
+        last = now
+
+
+def _await_and_stamp(span: "Span", handle: Any, epoch: int, now=time.time) -> None:
+    try:
+        handle.block_until_ready()
+    except Exception:  # noqa: BLE001 - a deleted or failed output is ready
+        pass
+    if epoch == _EPOCH:
+        _stamp(span, now())
+
+
+def _watch() -> None:
+    while True:
+        span, handle, epoch = _PENDING.get()
+        try:
+            _await_and_stamp(span, handle, epoch)
+        except Exception:  # noqa: BLE001 - the one thread that stamps must live
+            logger.exception("dispatch watcher: stamping span %d failed", span.seq)
+
+
+def start_watcher() -> None:
+    """Start the watcher and its heartbeat (idempotent; the engine calls
+    it at init when the timeline is on)."""
+    global _WATCHER
+    with _LOCK:
+        if _WATCHER is not None or not _ENABLED:
+            return
+        watcher = _WATCHER = threading.Thread(
+            target=_watch, daemon=True, name="llm-dispatch-watcher"
+        )
+    if _count_gc not in gc.callbacks:
+        gc.callbacks.append(_count_gc)
+    threading.Thread(
+        target=_heartbeat, daemon=True, name="llm-dispatch-heartbeat"
+    ).start()
+    watcher.start()
+
+
+def stamp_pending(now=time.time) -> int:
+    """Await and stamp every queued launch on the calling thread (tests,
+    and a process that never started the watcher). ``now`` is read after
+    each output is ready."""
+    n = 0
+    while True:
+        try:
+            span, handle, epoch = _PENDING.get_nowait()
+        except queue.Empty:
+            return n
+        _await_and_stamp(span, handle, epoch, now)
+        n += 1
+
+
+def _rung(span: Span) -> tuple:
+    c = span.counters or {}
+    return (span.kind, c.get("rows_dispatched", span.rows),
+            c.get("width", 0), span.steps)
+
+
+def _stamp(span: Span, t_done: float) -> None:
+    """The output of ``span``'s launch was ready at ``t_done``."""
+    global _PREV_DONE, _PREV_CPU, _PREV_GC
+    t_enq = span.t_enq
+    prev = t_enq if _PREV_DONE is None else _PREV_DONE
+    t_done = max(t_done, prev)  # a stamp out of order: nothing is negative
+    device_s = t_done - max(prev, t_enq)
+    starved_s = max(0.0, t_enq - prev)
+    cpu, pauses = time.process_time(), _GC_PAUSES
+    history = _RUNG_S.setdefault(_rung(span), deque(maxlen=_HOLD_HISTORY))
+    held = (
+        device_s > HOLD_MIN_S
+        and len(history) >= _HOLD_MIN_SAMPLES
+        and device_s > HOLD_TIMES_MEDIAN * statistics.median(history)
     )
+    history.append(device_s)
+    with _LOCK:
+        span.device_s = device_s
+        span.starved_s = starved_s
+        span.queued_s = max(0.0, prev - t_enq)
+        span.t_done = t_done
+        mode = _CUM_MODE[_mode_of(span.kind)]
+        _CUM["device"] += device_s
+        _CUM["gap"] += starved_s
+        mode["device"] += device_s
+        mode["gap"] += starved_s
+    _M_DEVICE.labels(program=span.kind).observe(device_s, trace_id=None)
+    _M_GAP.observe(starved_s, trace_id=None)
+    _M_STARVED.inc(starved_s)
+    if held:
+        _hold_record(span, max(prev, t_enq), cpu - _PREV_CPU, pauses - _PREV_GC)
+    _RECENT.append(span)
+    _PREV_DONE, _PREV_CPU, _PREV_GC = t_done, cpu, pauses
+
+
+def _hold_record(span: Span, t0: float, cpu_s: float, gc_pauses: int) -> None:
+    """ONE stall span and one log line for a launch the device held: what
+    it was, what was enqueued before it, and what the process knows of
+    the interval (jit, the host's heartbeat, CPU against wall, gc, device
+    memory)."""
+    def rung(s: Span) -> Dict[str, Any]:
+        kind, rows, width, steps = _rung(s)
+        return {"seq": s.seq, "kind": kind, "rows_dispatched": rows,
+                "width": width, "steps": steps}
+
+    bytes_in_use = None
+    try:
+        import jax
+
+        stats = jax.local_devices()[0].memory_stats() or {}
+        bytes_in_use = stats.get("bytes_in_use")
+    except Exception:  # noqa: BLE001 - a backend without memory_stats
+        pass
+    record = {
+        "held": dict(rung(span), device_s=round(span.device_s, 6)),
+        "before": [rung(s) for s in _RECENT],
+        "jit": [e[1:] for e in _JIT_EVENTS if t0 <= e[0] <= span.t_done],
+        "heartbeat_max_gap_s": round(max(
+            (g for t, g in _LATE_BEATS if t0 <= t <= span.t_done + HEARTBEAT_S),
+            default=HEARTBEAT_S,
+        ), 6),
+        "cpu_s": round(cpu_s, 6),
+        "wall_s": round(span.t_done - (_PREV_DONE or t0), 6),
+        "gc_pauses": gc_pauses,
+        "bytes_in_use": bytes_in_use,
+    }
+    _append(Span(
+        f"device_hold:{span.kind}", "stall", threading.current_thread().name,
+        t0, 0.0, span.device_s, span.rows, counters=record,
+    ))
+    logger.warning("DEVICE HOLD: %s", record)
 
 
 # --------------------------------------------------------------------------- #
@@ -519,17 +694,18 @@ def recent_spans(limit: int = 256) -> List[Dict]:
     """Newest ``limit`` span views, newest first (the blackbox embed)."""
     with _LOCK:
         spans = list(_SPANS)[-int(limit):]
-    return [s.view() for s in reversed(spans)]
+        return [s.view() for s in reversed(spans)]
 
 
 def counters_snapshot() -> Dict[str, float]:
     """Cumulative component seconds for the engine's legacy flat
     ``metrics`` dict — the loadgen scraper deltas these over the run
-    window to build the gated ``bubble`` summary block."""
+    window to build the gated ``bubble`` summary block. ``device`` is the
+    sum of ``device_s``, ``gap`` the sum of ``starved_s``."""
     with _LOCK:
         out = {
             "timeline_spans": _CUM["spans"],
-            "timeline_device_est_seconds": round(_CUM["device"], 6),
+            "timeline_device_seconds": round(_CUM["device"], 6),
             "timeline_lock_wait_seconds": round(_CUM["lock"], 6),
             "timeline_gap_seconds": round(_CUM["gap"], 6),
             "timeline_readback_stall_seconds": round(_CUM["readback"], 6),
@@ -538,9 +714,7 @@ def counters_snapshot() -> Dict[str, float]:
         # deltas never see a key appear mid-run): the mode sums equal
         # the totals above component by component.
         for mode, cum in _CUM_MODE.items():
-            out[f"timeline_{mode}_device_est_seconds"] = round(
-                cum["device"], 6
-            )
+            out[f"timeline_{mode}_device_seconds"] = round(cum["device"], 6)
             out[f"timeline_{mode}_lock_wait_seconds"] = round(cum["lock"], 6)
             out[f"timeline_{mode}_gap_seconds"] = round(cum["gap"], 6)
             out[f"timeline_{mode}_readback_stall_seconds"] = round(
@@ -552,11 +726,9 @@ def counters_snapshot() -> Dict[str, float]:
 
 def bubble_snapshot(window_s: float = _BUBBLE_WINDOW_S) -> Dict[str, float]:
     """Rolling-window bubble decomposition. The denominator is
-    engine-ACTIVE wall (device + lock + gap + readback seconds inside
-    the window) — idle-with-no-work time is nobody's bubble — so the
-    four component ratios sum to exactly 1.0. Updates the
-    genai_engine_bubble_* gauges as a side effect (scrape-time
-    freshness, the utilization_snapshot pattern)."""
+    engine-ACTIVE wall (device + lock + starved + readback seconds
+    inside the window) — idle-with-no-work time is nobody's bubble — so
+    the four component ratios sum to exactly 1.0."""
     horizon = time.time() - window_s
     busy = lock = gap = readback = 0.0
     gaps: List[float] = []
@@ -564,33 +736,28 @@ def bubble_snapshot(window_s: float = _BUBBLE_WINDOW_S) -> Dict[str, float]:
     n = 0
     with _LOCK:
         for s in _SPANS:
-            if s.t_end < horizon or s.category == "compile":
+            if s.t_enq < horizon or s.category in ("compile", "stall"):
                 continue
             n += 1
             if s.category == "dispatch":
-                busy += s.run_s
+                busy += s.device_s
                 lock += s.lock_wait_s
-                gap += s.gap_s
-                gaps.append(s.gap_s)
+                gap += s.starved_s
+                gaps.append(s.starved_s)
                 mode_active[_mode_of(s.kind)] += (
-                    s.run_s + s.lock_wait_s + s.gap_s
+                    s.device_s + s.lock_wait_s + s.starved_s
                 )
-            elif s.category == "stall":
-                gap += s.run_s
-                mode_active[_mode_of(s.kind)] += s.run_s
-            elif s.category == "readback":
+            else:
                 readback += s.run_s
                 mode_active[_mode_of(s.kind)] += s.run_s
     active = busy + lock + gap + readback
     if active <= 0:
         return {"bubble_spans_in_window": 0}
     ratio = lambda x: round(x / active, 4)  # noqa: E731
-    gap_p95 = 0.0
-    if gaps:
-        ordered = sorted(gaps)
-        gap_p95 = ordered[
-            min(len(ordered) - 1, max(0, int(round(0.95 * (len(ordered) - 1)))))
-        ]
+    ordered = sorted(gaps)
+    gap_p95 = ordered[
+        min(len(ordered) - 1, max(0, int(round(0.95 * (len(ordered) - 1)))))
+    ] if ordered else 0.0
     out = {
         "bubble_ratio": ratio(active - busy),
         "bubble_device_ratio": ratio(busy),
@@ -607,16 +774,6 @@ def bubble_snapshot(window_s: float = _BUBBLE_WINDOW_S) -> Dict[str, float]:
     for mode, secs in mode_active.items():
         if secs > 0:
             out[f"bubble_mode_{mode}_ratio"] = ratio(secs)
-    _M_BUBBLE.set(out["bubble_ratio"])
-    _M_BUBBLE_COMPONENT.labels(component="device").set(out["bubble_device_ratio"])
-    _M_BUBBLE_COMPONENT.labels(component="lock_contention").set(
-        out["bubble_lock_ratio"]
-    )
-    _M_BUBBLE_COMPONENT.labels(component="host_gap").set(out["bubble_gap_ratio"])
-    _M_BUBBLE_COMPONENT.labels(component="readback").set(
-        out["bubble_readback_ratio"]
-    )
-    _M_BUBBLE_WINDOW.set(out["bubble_window_s"])
     return out
 
 
@@ -624,7 +781,7 @@ def bubble_snapshot(window_s: float = _BUBBLE_WINDOW_S) -> Dict[str, float]:
 # Perfetto (Chrome trace JSON) export
 
 _PID_HOST = 1
-_PID_DEVICE_EST = 2
+_PID_DEVICE = 2
 _PID_DEVICE_XPLANE = 3
 _TID_REQUESTS = 1_000_000  # flight-recorder overlay track
 
@@ -636,8 +793,8 @@ def perfetto_trace(
 ) -> Dict[str, Any]:
     """Chrome-trace JSON over span VIEWS (spans_since/recent_spans
     output): one track per tier thread on the host process, a device
-    track (host-return estimates; replaced by xplane events on real
-    TPU when ``device_events`` is given), and flight-recorder request
+    track (each launch from ``t_done - device_s`` to ``t_done``; replaced
+    by xplane events when ``device_events`` is given), and flight-recorder request
     lifecycles overlaid as instants carrying their trace ids — the join
     key to stitched router traces. Timestamps are absolute wall-clock
     microseconds, so traces from co-scraped processes align."""
@@ -659,17 +816,17 @@ def perfetto_trace(
             )
         return tid
 
-    emitted_device_est = False
+    emitted_device = False
     for view in sorted(spans, key=lambda v: v.get("t_wall", 0.0)):
         thread = view.get("thread", "?")
         tid = tid_for(thread)
         t0 = float(view.get("t_wall", 0.0))
         lock_wait = float(view.get("lock_wait_s", 0.0))
-        run = float(view.get("device_est_s", 0.0))
+        run = float(view.get("enqueue_s", view.get("duration_s", 0.0)))
         args = {
             k: view[k]
             for k in ("seq", "rows", "tokens", "steps", "path", "rids",
-                      "gap_s", "category")
+                      "starved_s", "queued_s", "jit_what", "category")
             if k in view
         }
         if lock_wait > 0:
@@ -686,18 +843,19 @@ def perfetto_trace(
              "ts": (t0 + lock_wait) * 1e6, "dur": run * 1e6,
              "args": args}
         )
-        if view.get("category") == "dispatch" and not device_events:
-            emitted_device_est = True
+        if "t_done" in view and not device_events:
+            emitted_device = True
             events.append(
-                {"ph": "X", "pid": _PID_DEVICE_EST, "tid": 1,
+                {"ph": "X", "pid": _PID_DEVICE, "tid": 1,
                  "name": view.get("kind", "?"), "cat": "device",
-                 "ts": (t0 + lock_wait) * 1e6, "dur": run * 1e6,
+                 "ts": (view["t_done"] - view["device_s"]) * 1e6,
+                 "dur": view["device_s"] * 1e6,
                  "args": {"seq": view.get("seq")}}
             )
-    if emitted_device_est:
+    if emitted_device:
         events.append(
-            {"ph": "M", "pid": _PID_DEVICE_EST, "name": "process_name",
-             "args": {"name": "device (host-return estimate)"}}
+            {"ph": "M", "pid": _PID_DEVICE, "name": "process_name",
+             "args": {"name": "device (completion stamps)"}}
         )
     if device_events:
         events.append(
@@ -738,11 +896,19 @@ def perfetto_trace(
 
 
 def reset() -> None:
-    """Drop every span and rewind the cursor/counters (tests only)."""
-    global _SEQ
+    """Drop every span, queued stamp and rung history and rewind the
+    cursor/counters (tests only)."""
+    global _SEQ, _PREV_DONE, _EPOCH
+    _EPOCH += 1
+    while not _PENDING.empty():
+        _PENDING.get_nowait()
+    _PREV_DONE = None
+    _RECENT.clear()
+    _RUNG_S.clear()
+    _JIT_EVENTS.clear()
+    _JIT_TLS.pending = None
     with _LOCK:
         _SPANS.clear()
-        _LAST_RETURN.clear()
         _SEQ = 0
         for k in _CUM:
             _CUM[k] = 0.0

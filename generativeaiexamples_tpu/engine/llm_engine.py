@@ -50,7 +50,6 @@ from generativeaiexamples_tpu.engine import prefix_cache as prefix_cache_mod
 from generativeaiexamples_tpu.engine import request_snapshot as request_snapshot_mod
 from generativeaiexamples_tpu.engine import scheduler as scheduler_mod
 from generativeaiexamples_tpu.engine import spec_decode as spec_decode_mod
-from generativeaiexamples_tpu.engine import telemetry as telemetry_mod
 from generativeaiexamples_tpu.engine.tokenizer import (
     IncrementalDecoder,
     TokenBlock,
@@ -1144,11 +1143,32 @@ class LLMEngine:
         # decode_block per dispatch) — drives the attention-window bucket.
         self._slot_pos: Dict[int, int] = {}  # guarded by self._lock
         with mesh_context(self._mesh):
-            self._tokens_dev = jnp.zeros(self.num_slots, jnp.int32)
-            self._positions_dev = jnp.zeros(self.num_slots, jnp.int32)
-            self._temps_dev = jnp.full(self.num_slots, 1.0, jnp.float32)
-            self._topps_dev = jnp.ones(self.num_slots, jnp.float32)
-            self._seeds_dev = jnp.zeros(self.num_slots, jnp.int32)
+            # The slot arrays start as what every later version of them
+            # is: OUTPUTS of a program over the engine's weights
+            # (update_slots, decode), committed and with the mesh in
+            # their type. jit keys an executable on both, so plain
+            # jnp.zeros here made the first admissions and the first
+            # decode block select other executables of update_slots and
+            # decode than the steady state's: loads on the hot path that
+            # only jit's own events show (compile_watch.py; _zero_hidden
+            # is the same cure for the extend carries).
+            def slot_state(embed):
+                zero = jnp.where(False, embed[0, 0], 0)  # reads a weight, keeps none
+
+                def full(dtype, fill):
+                    return jnp.full(self.num_slots, fill, dtype) + zero.astype(dtype)
+
+                return (full(jnp.int32, 0), full(jnp.int32, 0),
+                        full(jnp.float32, 1.0), full(jnp.float32, 1.0),
+                        full(jnp.int32, 0))
+
+            (
+                self._tokens_dev,
+                self._positions_dev,
+                self._temps_dev,
+                self._topps_dev,
+                self._seeds_dev,
+            ) = jax.jit(slot_state)(self.params["embed"])
             # Per-slot page tables, device-resident: row b lists the
             # physical pool pages backing slot b's sequence, scratch
             # (page 0) padded. Rewritten per admission wave by ONE
@@ -1220,6 +1240,9 @@ class LLMEngine:
             dispatch_timeline_mod
             if dispatch_timeline_mod.enabled() else None
         )
+        # the one thread that awaits each launch's output and stamps its
+        # span's completion (no-op when the timeline is off)
+        dispatch_timeline_mod.start_watcher()
         self._stop_ids = set(self.tokenizer.stop_ids())
         # Dispatch-loop watchdog state: _last_progress advances whenever
         # the loop completes a wait or an iteration; a hang INSIDE the
@@ -1227,24 +1250,11 @@ class LLMEngine:
         # while work is outstanding, which is the wedge signal.
         self._last_progress = time.time()  # guarded by self._lock
         self._wedged = False
-        # Live utilization telemetry (engine/telemetry.py): rolling-
-        # window MFU / HBM-roofline gauges fed by one host record per
-        # compiled-program launch, sharing the peak constants and
-        # roofline math of utils/hardware.py.
-        wbytes = hardware.streamed_weight_bytes(self.params)
-        # Per-element KV cache width for roofline accounting (float:
-        # int4 packs two values per byte — utils/hardware owns the map).
+        # Per-element KV cache width (float: int4 packs two values per
+        # byte — utils/hardware owns the map).
         self._kv_byte_width = (
             hardware.kv_bytes_per_element(cfg.kv_cache_dtype)
             if self._kv_quant else 2
-        )
-        self._telemetry = telemetry_mod.UtilizationEstimator(
-            matmul_params=(
-                self._family.count_logical_params(self.model_config)
-                - self.model_config.vocab_size * self.model_config.hidden_size
-            ),
-            weight_stream_bytes=wbytes,
-            devices=self._mesh.size,
         )
         # A replacement engine starts healthy: the module-global wedge
         # signal may still be set by a prior instance (watchdog or failed
@@ -1888,7 +1898,6 @@ class LLMEngine:
             "decode",
             jax.jit(decode_paged, donate_argnums=(1,), static_argnums=(9,)),
         )
-        # genai-lint: disable=warmup-coverage -- warmed by warmup()'s submitted dummy waves: every admission the dispatch thread runs under the warmup scope updates the slot arrays (queue-mediated, so statically invisible)
         self._update_slots_fn = wrap("update_slots", jax.jit(_update_slots))
         self._extend_fn = wrap(
             "extend",
@@ -1956,38 +1965,13 @@ class LLMEngine:
         return out
 
     def utilization_snapshot(self) -> Dict[str, float]:
-        """Rolling-window MFU / HBM-roofline view plus the compile-path
-        stats (``GET /internal/slo`` and the
+        """The compile-path stats plus the rolling bubble decomposition
+        of the dispatch timeline (``GET /internal/slo`` and the
         black-box bundles read this)."""
-        out = self._telemetry.snapshot()
-        out.update(self._compile_watch.snapshot())
+        out = self._compile_watch.snapshot()
         if self._dtl is not None:
             out.update(self._dtl.bubble_snapshot())
         return out
-
-    def _cache_read_bytes(self, window: int) -> int:
-        """KV bytes one decode step reads over the whole batch at this
-        attention window (utils/hardware.py owns the formula)."""
-        return hardware.kv_read_bytes_per_step(
-            self._kv_shape, self.num_slots, window, self._kv_byte_width
-        )
-
-    def _ragged_read_bytes(self) -> int:
-        """KV bytes one PAGED decode step reads: each live row's
-        page-rounded live length, summed over the batch (caller holds
-        the lock — reads the _slot_pos shadow)."""
-        page = self.engine_config.page_size
-        tokens = sum(
-            min(
-                kv_pages_mod.pages_for_tokens(min(p, self.max_seq_len), page)
-                * page,
-                self.max_seq_len,
-            )
-            for p in self._slot_pos.values()
-        )
-        return hardware.kv_read_bytes_ragged(
-            self._kv_shape, tokens, self._kv_byte_width
-        )
 
     def _kernel_pages_walked(self) -> Dict[str, int]:
         """What the page kernel walks at the FIRST step of the decode
@@ -2676,7 +2660,7 @@ class LLMEngine:
                         last_h, jnp.full((m,), n, jnp.int32),
                         self._zero_hidden(m),
                     )
-                self._finish_fn(
+                first = self._finish_fn(
                     self.params,
                     last_h,
                     zeros_n,
@@ -2684,7 +2668,25 @@ class LLMEngine:
                     jnp.zeros((n,), jnp.float32),
                     jnp.ones((n,), jnp.float32),
                     jnp.zeros((n,), jnp.int32),
-                ).block_until_ready()
+                )
+                # admission's slot update on first tokens of finish's
+                # kind (they carry the mesh in their type; the dummy
+                # waves below warm it on prefill's): the slot index is
+                # out of range, so the scatter drops every row
+                (
+                    self._tokens_dev,
+                    self._positions_dev,
+                    self._temps_dev,
+                    self._topps_dev,
+                    self._seeds_dev,
+                ) = self._update_slots_fn(
+                    self._tokens_dev, self._positions_dev, self._temps_dev,
+                    self._topps_dev, self._seeds_dev,
+                    jnp.full((n,), self.num_slots, jnp.int32), first,
+                    zeros_n, jnp.zeros((n,), jnp.float32),
+                    jnp.ones((n,), jnp.float32), zeros_n,
+                )
+                self._tokens_dev.block_until_ready()
             # Warm the page-table scatter at every funded-wave row
             # count (1..num_slots — _fund_paged_admissions scatters
             # exactly the funded rows, unpadded): all-zero rows
@@ -2712,19 +2714,19 @@ class LLMEngine:
             # cpu_smoke/loadgen run paid the compile (the hole PR 9
             # closed for prefill shapes, reopened by the kernel's
             # new executable family).
-            B = self.num_slots
-            zeros_i = jnp.zeros((B,), jnp.int32)
-            temps = jnp.zeros((B,), jnp.float32)
-            topps = jnp.ones((B,), jnp.float32)
-            dead = np.zeros((B,), bool)
+            # On the slot arrays themselves (their outputs dropped): jit
+            # keys an executable on an operand's kind, and fresh
+            # jnp.zeros here built one per rung that serving never ran.
+            dead = np.zeros((self.num_slots,), bool)
             rungs = (
                 [self.max_seq_len] if self._paged_kernel
                 else self._window_rungs()
             )
             for w in rungs:
                 (_, _, self._cache, slab) = self._decode_fn(
-                    self.params, self._cache, zeros_i, zeros_i,
-                    temps, topps, zeros_i, self._tables_dev, dead, w,
+                    self.params, self._cache, self._tokens_dev,
+                    self._positions_dev, self._temps_dev, self._topps_dev,
+                    self._seeds_dev, self._tables_dev, dead, w,
                 )
                 slab.block_until_ready()
 
@@ -3234,9 +3236,6 @@ class LLMEngine:
                         req.rid, "prefill_wave", bucket=bucket,
                         wave_rows=Np, live_rows=N,
                     )
-                self._telemetry.record_dispatch(
-                    "prefill", tokens=int(lengths.sum()), rows=N
-                )
                 state_fields = self._state_counters(
                     "prefill", N, int(lengths[:N].sum()), 0, resets=N
                 )
@@ -3271,6 +3270,7 @@ class LLMEngine:
                         tokens=int(lengths.sum()),
                         rids=[r.rid for r in group],
                         counters=state_fields,
+                        handle=first_tokens,
                     )
             # Inject into the device-resident batch state — dispatched, not
             # synced; token values reach the host via the reader.
@@ -3693,14 +3693,8 @@ class LLMEngine:
                         [r.rid for r in reqs] if reqs is not None else ()
                     ),
                     counters=fields,
+                    handle=sub_h,
                 )
-            self._telemetry.record_dispatch(
-                "prefill", tokens=live_tokens,
-                cache_bytes=hardware.kv_read_bytes_per_step(
-                    self._kv_shape, n, W, self._kv_byte_width
-                ),
-                rows=n_live,
-            )
             if reqs is not None and flight_recorder.enabled():
                 for i in live:
                     flight_recorder.event_rid(
@@ -3951,7 +3945,6 @@ class LLMEngine:
                 max(self._slot_pos.values(), default=0)
             )
             live_slots = list(self._slot_req)
-            ragged_bytes = self._ragged_read_bytes()
             kv_pages = (
                 self._kernel_pages_walked() if self._paged_kernel else None
             )
@@ -4007,23 +4000,6 @@ class LLMEngine:
         _M_DECODE_DISPATCHES.inc()
         path = "kernel" if self._paged_kernel else "gather"
         _M_PAGED_ATTN.labels(path=path).inc()
-        self._telemetry.record_dispatch(
-            "decode",
-            tokens=self._decode_block * len(live_slots),
-            weight_passes=self._decode_block,
-            # Charge what the serving path actually reads: the ragged
-            # kernel walks each row's live pages only
-            # (kv_read_bytes_ragged — each live row's page-rounded
-            # length), while the XLA gather reads the bucketed window
-            # for every row.
-            cache_bytes=self._decode_block * (
-                ragged_bytes if self._paged_kernel
-                else self._cache_read_bytes(window)
-            ),
-            steps=self._decode_block,
-            rows=len(live_slots),
-            path=path,
-        )
         with self._lock:
             snapshot = list(self._slot_req.items())
             for slot in list(self._slot_budget):
@@ -4040,6 +4016,7 @@ class LLMEngine:
                 path=path,
                 rids=[r.rid for _, r in snapshot],
                 counters=span_counts,
+                handle=token_slab,
             )
         # Start the device→host transfer NOW so readbacks overlap both the
         # compute of later steps and each other.
@@ -4198,19 +4175,23 @@ class LLMEngine:
                 self._cache,
                 packed,
             ) = out
+        span = None
         if _dtl is not None:
-            _dtl_run = time.perf_counter() - _dtl_t1
+            # recorded at its enqueue; its token count lands with the
+            # readback (_spec_apply_readback)
+            span = _dtl.record_span(
+                "spec",
+                t_wall=_dtl_wall,
+                lock_wait_s=_dtl_t1 - _dtl_t0,
+                run_s=time.perf_counter() - _dtl_t1,
+                rows=len(snapshot),
+                path="kernel" if self._paged_verify_kernel else "gather",
+                rids=[r.rid for _, r in snapshot],
+                handle=packed,
+            )
         _M_DECODE_STEPS.inc(1)
         _M_DECODE_DISPATCHES.inc()
         _sampler_full_rows(r for _, r in snapshot)
-        with self._lock:
-            # Dispatch-time truth: the position shadows advance at the
-            # flush, so this reads the state the verify actually ran at
-            # on both paths.
-            spec_bytes = (
-                self._ragged_read_bytes() if self._paged_verify_kernel
-                else self._cache_read_bytes(window)
-            )
         _M_PAGED_ATTN.labels(
             path="kernel" if self._paged_verify_kernel else "gather"
         ).inc()
@@ -4226,11 +4207,7 @@ class LLMEngine:
                 "snapshot": snapshot,
                 "draft_len": draft_len,
                 "prop_kind": prop.kind,
-                "spec_bytes": spec_bytes,
-                "dtl": (
-                    (_dtl_wall, _dtl_t1 - _dtl_t0, _dtl_run)
-                    if _dtl is not None else None
-                ),
+                "span": span,
                 "opt": self._spec_runahead_proposals(
                     prop, prop_rows, proposals, K
                 ),
@@ -4249,21 +4226,10 @@ class LLMEngine:
         out_np = packed_np[:, :-1]
         acc_np = packed_np[:, -1]
         _M_READBACK.labels(kind="spec").observe(readback_s, trace_id=None)
-        self._telemetry.record_readback("spec", readback_s)
         if _dtl is not None:
-            _dtl.record_span(
-                "spec",
-                t_wall=_dtl_wall,
-                lock_wait_s=_dtl_t1 - _dtl_t0,
-                run_s=_dtl_run,
-                rows=len(snapshot),
-                tokens=sum(int(acc_np[s]) + 1 for s, _ in snapshot),
-                path="kernel" if self._paged_verify_kernel else "gather",
-                rids=[r.rid for _, r in snapshot],
-            )
             _dtl.record_readback("spec", readback_s)
         self._spec_apply_readback(
-            out_np, acc_np, snapshot, draft_len, prop.kind, spec_bytes
+            out_np, acc_np, snapshot, draft_len, prop.kind, span
         )
 
     def _flush_spec_pipeline(self) -> None:
@@ -4288,29 +4254,13 @@ class LLMEngine:
         out_np = packed_np[:, :-1]
         acc_np = packed_np[:, -1]
         _M_READBACK.labels(kind="spec").observe(wait_s, trace_id=None)
-        self._telemetry.record_readback("spec", wait_s)
         _dtl = self._dtl
         if _dtl is not None:
-            if pending["dtl"] is not None:
-                wall, lock_wait, run = pending["dtl"]
-                # The verify's own span, recorded now that its token
-                # count is known but stamped with its dispatch-time
-                # wall/lock/run values.
-                _dtl.record_span(
-                    "spec",
-                    t_wall=wall,
-                    lock_wait_s=lock_wait,
-                    run_s=run,
-                    rows=len(snapshot),
-                    tokens=sum(int(acc_np[s]) + 1 for s, _ in snapshot),
-                    path="kernel" if self._paged_verify_kernel else "gather",
-                    rids=[r.rid for _, r in snapshot],
-                )
             _dtl.record_readback("spec", wait_s)
             _dtl.record_pipeline_flush(wait_s, rows=len(snapshot))
         self._spec_apply_readback(
             out_np, acc_np, snapshot, pending["draft_len"],
-            pending["prop_kind"], pending["spec_bytes"],
+            pending["prop_kind"], pending["span"],
         )
         # Reconcile the runahead drafts: the optimistic context assumed
         # FULL acceptance, and its first proposed token doubles as the
@@ -4350,7 +4300,7 @@ class LLMEngine:
         self._spec_reconcile = (confirmed, missed)
 
     def _spec_apply_readback(
-        self, out_np, acc_np, snapshot, draft_len, prop_kind, spec_bytes
+        self, out_np, acc_np, snapshot, draft_len, prop_kind, span=None
     ) -> None:
         """Apply a landed verify readback: acceptance telemetry, the
         scheduler's rolling-acceptance feed, budget/position shadows,
@@ -4359,13 +4309,8 @@ class LLMEngine:
         (one round later). The ``is req`` slot guards make the
         late-flush case safe against a row that was released — and
         possibly re-admitted — while the verify was in flight."""
-        self._telemetry.record_dispatch(
-            "spec",
-            tokens=sum(int(acc_np[s]) + 1 for s, _ in snapshot),
-            cache_bytes=spec_bytes,
-            rows=len(snapshot),
-            path="kernel" if self._paged_verify_kernel else "gather",
-        )
+        if span is not None:
+            span.tokens = sum(int(acc_np[s]) + 1 for s, _ in snapshot)
         # Rolling-acceptance feed for draft-aware scheduling (the
         # policy's tracker; zero-draft rounds carry no evidence).
         self.scheduler.record_spec_round(
@@ -4553,33 +4498,19 @@ class LLMEngine:
                 steps=self._decode_block,
                 path="kernel" if self._paged_kernel else "gather",
                 rids=[r.rid for _, r in snapshot],
+                handle=token_slab,
             )
         _M_DECODE_STEPS.inc(self._decode_block)
         _M_DECODE_DISPATCHES.inc()
         _sampler_full_rows(r for _, r in snapshot)
-        with self._lock:
-            block_bytes = (
-                self._ragged_read_bytes() if self._paged_kernel
-                else self._cache_read_bytes(window)
-            )
         path = "kernel" if self._paged_kernel else "gather"
         _M_PAGED_ATTN.labels(path=path).inc()
-        self._telemetry.record_dispatch(
-            "spec_block",
-            tokens=self._decode_block * len(snapshot),
-            weight_passes=self._decode_block,
-            cache_bytes=self._decode_block * block_bytes,
-            steps=self._decode_block,
-            rows=len(snapshot),
-            path=path,
-        )
         t0 = time.time()
         # genai-lint: disable=dispatch-readback -- allow-listed spec-block sync: the zero-draft fallback slab feeds the proposer buffers, so it must land before the next dispatch
         slab_np = np.asarray(token_slab)  # [block, batch]
         _M_READBACK.labels(kind="spec_block").observe(
             time.time() - t0, trace_id=None
         )
-        self._telemetry.record_readback("spec_block", time.time() - t0)
         if _dtl is not None:
             _dtl.record_readback("spec_block", time.time() - t0)
         with self._lock:
@@ -4632,8 +4563,6 @@ class LLMEngine:
                     return
             B = self.num_slots
             zeros_i = jnp.zeros((B,), jnp.int32)
-            temps = jnp.zeros((B,), jnp.float32)
-            topps = jnp.ones((B,), jnp.float32)
             live = np.zeros((B,), bool)
             # The verify program is shape-polymorphic over the draft
             # width, so adaptive K multiplies the warm set by its
@@ -4647,13 +4576,15 @@ class LLMEngine:
             for w in windows:
                 for kr in k_rungs:
                     draft = jnp.zeros((B, kr), jnp.int32)
-                    # tokens/positions inputs are scratch zeros (not the
-                    # device state arrays — only the caches are donated
-                    # and must be rebound from the output)
+                    # on the slot arrays themselves, as serving runs it
+                    # (jit keys an executable on an operand's kind); all
+                    # rows dead, the outputs dropped — only the caches
+                    # are donated and must be rebound from the output
                     (_, _, self._cache, packed) = self._spec_verify_fn(
-                        self.params, self._cache, zeros_i, zeros_i,
-                        temps, topps, zeros_i, draft, zeros_i, live,
-                        self._tables_dev, w,
+                        self.params, self._cache, self._tokens_dev,
+                        self._positions_dev, self._temps_dev,
+                        self._topps_dev, self._seeds_dev, draft, zeros_i,
+                        live, self._tables_dev, w,
                     )
                     packed.block_until_ready()
             if self._draft is not None:
@@ -4778,7 +4709,6 @@ class LLMEngine:
                 _M_READBACK.labels(kind=kind).observe(
                     time.time() - t0, trace_id=None
                 )
-                self._telemetry.record_readback(kind, time.time() - t0)
                 if self._dtl is not None:
                     self._dtl.record_readback(kind, time.time() - t0)
             except Exception as exc:  # noqa: BLE001

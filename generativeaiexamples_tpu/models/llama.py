@@ -481,7 +481,7 @@ def count_params(params: Params) -> int:
 def count_logical_params(cfg: LlamaConfig) -> int:
     """Parameter count from the architecture alone (independent of
     storage: int8 packs pad K/F, so counting buffer elements over- and
-    double-counts). Used for MFU math (engine/telemetry.py)."""
+    double-counts). Used for MFU math."""
     n = sum(math.prod(shape) for shape, _ in init_spec(cfg).values())
     n += cfg.num_layers * 2 * cfg.hidden_size + cfg.hidden_size  # RMSNorm weights
     return n

@@ -18,6 +18,7 @@ trigger a multi-minute engine boot.
 """
 from __future__ import annotations
 
+import asyncio
 import functools
 import json
 import time
@@ -316,13 +317,16 @@ async def profile_start_handler(request: web.Request) -> web.Response:
             log_dir = body.get("log_dir") or None
         except Exception:  # noqa: BLE001 - empty/invalid body means defaults
             pass
-    status, payload = profiling.start_profile(log_dir)
+    # off the event loop: starting a capture takes the profiler's time,
+    # and the streams are served by this loop
+    status, payload = await asyncio.to_thread(profiling.start_profile, log_dir)
     return web.json_response(payload, status=status)
 
 
 async def profile_stop_handler(request: web.Request) -> web.Response:
     """POST /internal/profile/stop — end the active capture."""
-    status, payload = profiling.stop_profile()
+    # writing the capture out takes seconds: off the event loop
+    status, payload = await asyncio.to_thread(profiling.stop_profile)
     return web.json_response(payload, status=status)
 
 

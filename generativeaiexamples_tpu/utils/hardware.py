@@ -1,7 +1,6 @@
 """Hardware peak constants + roofline/MFU arithmetic, in ONE place.
 
-The live utilization estimator (engine/telemetry.py) and the engine's
-fit planner read this math. Peaks are
+The engine's fit planner reads this math. Peaks are
 PUBLISHED per-chip numbers in one table keyed by jax's ``device_kind``,
 each with its source; the engine resolves the attached device against
 it at start-up (:func:`configure_peaks`). On the ``tpu`` backend a kind

@@ -8,7 +8,11 @@ action instead of a code change: the servers expose
 ``server/observability.py``) which call :func:`start_profile` /
 :func:`stop_profile` here, so an operator can bracket a live traffic
 window and pull the trace from ``PROFILE_LOG_DIR`` — no restart, no
-benchmark harness.
+benchmark harness. The capture is of the benchmark's kind
+(``perfbench/launcher.py``): the Python tracer is OFF and the handlers
+run the start and the stop off the server's event loop. With the tracer
+on and the call inside the loop, a capture on the chip stalled every
+stream for its whole length.
 
 Everything is gated on ``ENABLE_PROFILING`` (same pattern as
 ``ENABLE_TRACING``) and degrades gracefully: when the profiler is
@@ -56,6 +60,19 @@ def _profiler():
         return None
 
 
+def _capture_options(profiler) -> Dict[str, Any]:
+    """``start_trace`` options of an operational capture: device and
+    host tracks, no Python tracer (a jax without ``ProfileOptions``
+    takes its defaults)."""
+    make = getattr(profiler, "ProfileOptions", None)
+    if make is None:
+        return {}
+    opts = make()
+    opts.python_tracer_level = 0
+    opts.host_tracer_level = 1
+    return {"profiler_options": opts}
+
+
 # --------------------------------------------------------------------------- #
 # Capture session (process-wide: jax.profiler allows one active trace)
 
@@ -83,7 +100,7 @@ def start_profile(log_dir: Optional[str] = None) -> Tuple[int, Dict[str, Any]]:
             }
         try:
             os.makedirs(log_dir, exist_ok=True)
-            profiler.start_trace(log_dir)
+            profiler.start_trace(log_dir, **_capture_options(profiler))
         except Exception as exc:  # noqa: BLE001 - capture must not kill serving
             logger.warning("profiler start failed: %s", exc)
             return 500, {"error": f"profiler start failed: {exc}"}

@@ -123,19 +123,18 @@ def test_warmup_covers_all_lengths():
         eng.warmup(prompt_lengths=[8])
         before = eng._compile_watch.snapshot()
         assert before["compile_executables_extend"] > 0 and before["compile_executables_finish"] > 0
-        warmed = {
-            (args[2][1][0], args[2][1][1], args[8][2])
-            for (prog, (args, _)) in eng._compile_watch._seen if prog == "extend"
-        }
-        assert warmed == set(eng._extend_signatures())
-        assert {w for _, w, _ in warmed} == {8, 32}
-        # what jit itself holds: it keys an executable on more than the
-        # shapes the compile watch sees (a carry that is not committed
-        # to the device selects another one than a carry that is), so
-        # serving must not add to it either
-        jits = {name: getattr(eng, f"_{name}_fn").__wrapped__ for name in ("extend", "finish", "put_rows")}
+        # one executable a signature of the shape rule, by jit's own count
+        signatures = set(eng._extend_signatures())
+        assert before["compile_executables_extend"] == len(signatures)
+        assert {w for _, w, _ in signatures} == {8, 32}
+        # what jit itself holds: it keys an executable on more than
+        # shapes (a carry that is not committed to the device selects
+        # another one than a carry that is), so serving must not add to
+        # it either
+        jits = {name: getattr(eng, f"_{name}_fn").__wrapped__
+                for name in ("extend", "finish", "put_rows", "update_slots", "decode")}
         held = {name: fn._cache_size() for name, fn in jits.items()}
-        assert held["extend"] == len(warmed)
+        assert held["extend"] == len(signatures)
         lengths = list(range(1, 95))
         size = 0
         while lengths:

@@ -51,3 +51,16 @@ def test_tpu_hbm_limit_comes_from_the_allocator_or_fails(monkeypatch):
     assert hardware.device_hbm_bytes(_Dev("cpu", None)) == 16e9
     monkeypatch.setenv("GENAI_TPU_HBM_BYTES", "5e9")
     assert hardware.device_hbm_bytes(_Dev("tpu", None)) == 5e9
+
+
+def test_devices_scale_peaks():
+    one = hardware.mfu_ratio(1000.0, 10**9, devices=1)
+    eight = hardware.mfu_ratio(1000.0, 10**9, devices=8)
+    assert abs(one / eight - 8.0) < 1e-6
+    # the kv-read formula matches bench's inline version
+    class _Cfg:
+        num_kv_heads, head_dim, num_layers = 4, 64, 8
+
+    assert hardware.kv_read_bytes_per_step(_Cfg, 16, 256, 2) == (
+        2 * 16 * 256 * 4 * 64 * 2 * 8
+    )

@@ -472,9 +472,8 @@ def test_internal_metrics_json_view_parity_with_exposition():
     assert exposed, "exposition rendered no families"
     assert exposed == collected
     for family in (
-        "genai_engine_mfu_ratio",
-        "genai_engine_hbm_bw_ratio",
-        "genai_engine_step_time_seconds",
+        "genai_engine_dispatch_device_seconds",
+        "genai_engine_device_starved_seconds_total",
         "genai_slo_attainment_ratio",
         "genai_flight_recorder_events_total",
     ):

@@ -4,6 +4,7 @@ profiler-unavailable -> 501, and the annotation-scope no-op path when
 jax.profiler is unavailable."""
 import asyncio
 import contextlib
+import threading
 
 import pytest
 
@@ -16,16 +17,19 @@ class _FakeProfiler:
 
     def __init__(self, fail_start=False, fail_stop=False):
         self.started = []
+        self.threads = []
         self.stopped = 0
         self._fail_start = fail_start
         self._fail_stop = fail_stop
 
     def start_trace(self, log_dir):
+        self.threads.append(threading.current_thread())
         if self._fail_start:
             raise RuntimeError("no backend")
         self.started.append(log_dir)
 
     def stop_trace(self):
+        self.threads.append(threading.current_thread())
         if self._fail_stop:
             raise RuntimeError("trace write failed")
         self.stopped += 1
@@ -102,6 +106,36 @@ def test_failed_stop_keeps_session_active_for_retry(monkeypatch, tmp_path):
     assert profiling.stop_profile()[0] == 200
 
 
+def test_capture_is_the_benchmarks_kind_python_tracer_off(monkeypatch, tmp_path):
+    """With ``ProfileOptions`` (this image's jax) the capture starts as
+    perfbench/launcher.py starts its own: Python tracer off, host
+    tracer at 1; a profiler without it is started as before."""
+
+    class Options:
+        python_tracer_level = 1
+        host_tracer_level = 2
+
+    class WithOptions(_FakeProfiler):
+        ProfileOptions = Options
+
+        def start_trace(self, log_dir, profiler_options=None):
+            self.started.append((log_dir, profiler_options))
+
+    fake = WithOptions()
+    _enable(monkeypatch, fake)
+    assert profiling.start_profile(str(tmp_path))[0] == 200
+    (log_dir, opts), = fake.started
+    assert log_dir == str(tmp_path) and isinstance(opts, Options)
+    assert opts.python_tracer_level == 0 and opts.host_tracer_level == 1
+    assert profiling.stop_profile()[0] == 200
+    assert profiling._capture_options(_FakeProfiler()) == {}
+
+    import jax
+
+    real = profiling._capture_options(jax.profiler)["profiler_options"]
+    assert real.python_tracer_level == 0
+
+
 # --------------------------------------------------------------------------- #
 # annotation scope
 
@@ -159,5 +193,15 @@ def test_profile_endpoints_gate_and_conflict(monkeypatch, tmp_path):
             # double start
             assert (await client.post("/internal/profile/start")).status == 409
             assert (await client.post("/internal/profile/stop")).status == 200
+            # both calls ran OFF the event loop's thread: a capture's
+            # start and its write-out must not stall the streams
+            assert fake.threads and loop_thread not in fake.threads
 
-    asyncio.run(scenario())
+    loop_thread = None
+
+    async def on_loop():
+        nonlocal loop_thread
+        loop_thread = threading.current_thread()
+        await scenario()
+
+    asyncio.run(on_loop())

@@ -60,7 +60,6 @@ REGISTRY_MODULES = (
     "generativeaiexamples_tpu.engine.batcher",
     "generativeaiexamples_tpu.engine.embedder",
     "generativeaiexamples_tpu.engine.reranker",
-    "generativeaiexamples_tpu.engine.telemetry",
     "generativeaiexamples_tpu.retrieval.store",
     "generativeaiexamples_tpu.retrieval.bm25",
     "generativeaiexamples_tpu.chains.runtime",

@@ -145,7 +145,8 @@ GATE_METRICS: Dict[str, Dict] = {
     },
     # Dispatch-bubble attribution (engine/dispatch_timeline.py): the
     # shares decompose the run's engine-active wall (device + lock +
-    # gap + readback, summing to 1.0). bubble_ratio (everything that is
+    # gap + readback, summing to 1.0; device and gap come from the
+    # launches' completion stamps: device_s and starved_s). bubble_ratio (everything that is
     # not device time) and the lock-wait share gate with wide absolute
     # bands — host-scheduling jitter on CPU CI moves them by tens of
     # points — so only a gross attribution regression (a new serial

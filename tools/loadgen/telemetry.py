@@ -246,7 +246,7 @@ class TelemetryScraper:
         # the recorder is off, so the bubble block self-omits.
         for key in (
             "timeline_spans",
-            "timeline_device_est_seconds",
+            "timeline_device_seconds",
             "timeline_lock_wait_seconds",
             "timeline_gap_seconds",
             "timeline_readback_stall_seconds",
@@ -441,16 +441,18 @@ def bubble_from_deltas(deltas: Dict[str, float]) -> Optional[Dict]:
     block is omitted, so a baseline WITH the block flags the recorder
     silently turning off as schema drift). The shares decompose the
     run's engine-ACTIVE wall (device + lock + gap + readback component
-    seconds — engine/dispatch_timeline.py) and sum to 1.0;
+    seconds — engine/dispatch_timeline.py: ``device`` is the sum of the
+    launches' ``device_s`` from the completion stamp, ``gap`` the sum of
+    their ``starved_s``) and sum to 1.0;
     ``bubble_ratio`` is everything that is not device time, the gated
     headline next to ``lock_wait_share`` (cross-tier dispatch-lock
     contention), ``host_gap_share`` / ``readback_share`` (the two
     components the pipelined spec dispatch attacks — both gated with a
-    ``lower`` direction), and ``gap_p95_s`` (worst host gaps between
-    launches with work queued, from run-window histogram bucket
-    deltas)."""
+    ``lower`` direction), and ``gap_p95_s`` (the worst stretches the
+    device had nothing of the engine's to run before a launch, from
+    run-window histogram bucket deltas)."""
     spans = deltas.get("timeline_spans", 0.0)
-    device = deltas.get("timeline_device_est_seconds", 0.0)
+    device = deltas.get("timeline_device_seconds", 0.0)
     lock = deltas.get("timeline_lock_wait_seconds", 0.0)
     gap = deltas.get("timeline_gap_seconds", 0.0)
     readback = deltas.get("timeline_readback_stall_seconds", 0.0)
