@@ -539,6 +539,9 @@ class LLMEngine:
         # a family whose extend walk follows each row's own context is
         # given ONE window, capacity (models/registry.py)
         self._one_extend_window = not family.extend_reads_window
+        # a family that registered a walk over a packed token axis is
+        # sent its prefill waves packed (models/registry.py)
+        self._packed = family.extend_packed is not None
         if self._fixed_state:
             cfg = self._validate_fixed_state(cfg, mesh)
             self.engine_config = cfg
@@ -1067,11 +1070,15 @@ class LLMEngine:
                 "the page kernel's row cap; verify dispatches stay on "
                 "the XLA gather", verify_rows, kv_shape.num_heads,
             )
-        # A prompt's tail (the width ladder's rungs under prefill_chunk)
-        # reads through the kernel when every such width folds into
-        # sub-rows the kernel serves: ONE executable a row rung, no
-        # window rung (the walk follows each row's live pages).
-        narrow = self._chunk_widths()[:-1]
+        # A prompt's tail (the width ladder's rungs under prefill_chunk,
+        # or a packed axis shorter than a chunk) reads through the
+        # kernel when every such width folds into sub-rows the kernel
+        # serves: ONE executable a row rung, no window rung (the walk
+        # follows each row's live pages).
+        narrow = (
+            [t for t in self._packed_rungs() if t < cfg.prefill_chunk]
+            if self._packed else self._chunk_widths()[:-1]
+        )
         if narrow and all(
             page_attention.supports_geometry(
                 *geom,
@@ -1784,6 +1791,43 @@ class LLMEngine:
                 return last_h, caches, fam.read_stats(caches)
             return last_h, caches
 
+        def place_rows(last_h, dest, sub_h):
+            # Row ``dest[j]`` of the wave's carry takes ``sub_h[j]``; a
+            # ``dest`` out of range names no row. A GATHER, not
+            # ``last_h.at[dest].set(sub_h, mode="drop")``: on the chip
+            # that scatter, given rows to drop, wrote ONE live row's
+            # update over the others' (two rows of a wave then sampled
+            # their first token from the same hidden state; PERF.md
+            # section 6, PR 41; the CPU does not show it).
+            hit = dest[None, :] == jnp.arange(last_h.shape[0])[:, None]
+            return jnp.where(
+                hit.any(axis=1)[:, None],
+                sub_h[jnp.argmax(hit, axis=1)].astype(last_h.dtype), last_h,
+            )
+
+        packed_windows = tuple(self._packed_windows()) if self._packed else ()
+
+        def extend_packed(params, caches, tokens, rows, pick, last_h, tables):
+            # The packed form of the same dispatch: the wave's live
+            # tokens on ONE axis [T], row after row. ``rows`` [5, R]:
+            # each row's start on the axis, live tokens, first cache
+            # position, slot and place in the wave's carry; ``pick``:
+            # live rows and the index of the chunk's window rung. A T
+            # under a chunk reads through the page kernel where it
+            # resolved; the gather picks its window inside the program.
+            T, R = tokens.shape[0], rows.shape[1]
+            starts, counts, offsets, slots, dest = rows
+            cand, caches = fam.extend_packed(
+                params, cfg, caches, tokens, starts, counts, offsets, slots,
+                tables, page, seg=min(T, chunk), windows=packed_windows,
+                window_index=pick[1], n_rows=pick[0],
+                page_kernel=extend_kernel if T < chunk else None, **paths,
+            )
+            last_h = place_rows(last_h, jnp.where(counts > 0, dest, R), cand)
+            if n_stats:
+                return last_h, caches, fam.read_stats(caches)
+            return last_h, caches
+
         def finish_batch(params, last_h, src, lengths, temps, topps, seeds):
             # `src` maps each wave row to the row whose hidden it
             # samples: itself, or row 0 for a padding row (a copy of row
@@ -1797,7 +1841,7 @@ class LLMEngine:
             # a chunk that ran on fewer rows than the wave holds hands
             # its hidden states back (a padding row's index is out of
             # range: dropped)
-            return last_h.at[rows].set(sub_h, mode="drop")
+            return place_rows(last_h, rows, sub_h)
 
         # Speculative verify step (prompt-lookup decoding, docs/
         # spec_decode.md): score the last accepted token plus K host-
@@ -1889,7 +1933,7 @@ class LLMEngine:
 
         wrap = self._compile_watch.wrap
         # genai-lint: disable=warmup-coverage -- warmed by warmup()'s submitted dummy waves: the dispatch thread compiles every (wave, bucket) prefill rung under the warmup scope before finish_warmup arms the hot-path gate (queue-mediated, so statically invisible)
-        self._prefill_fn = wrap(
+        self._prefill_fn = None if self._packed else wrap(
             "prefill", jax.jit(prefill_batch_paged, donate_argnums=(1,))
         )
         # `window` is static: the page kernel has one full-capacity
@@ -1901,7 +1945,8 @@ class LLMEngine:
         self._update_slots_fn = wrap("update_slots", jax.jit(_update_slots))
         self._extend_fn = wrap(
             "extend",
-            jax.jit(
+            jax.jit(extend_packed, donate_argnums=(1,)) if self._packed
+            else jax.jit(
                 extend_batch_paged, donate_argnums=(1,), static_argnums=(8,)
             ),
         )
@@ -2608,8 +2653,10 @@ class LLMEngine:
     def warmup_chunked_shapes(self) -> None:
         """Compile the WHOLE chunked-prefill executable set directly:
         one extend per ``_extend_signatures`` entry (rows, width,
-        window), one finish per wave rung and one ``put_rows`` per pair
-        of a wave rung and a smaller one. Zero-valid rows make every
+        window; for a packed family one per token rung, whose carry has
+        the one row count), one finish per wave rung and one
+        ``put_rows`` per pair of a wave rung and a smaller one.
+        Zero-valid rows make every
         dispatch a value-level no-op on the caches, so this needs no
         scheduler involvement — and after it, NO prompt length can
         compile inside a request (the chunked set covers every length
@@ -2650,6 +2697,16 @@ class LLMEngine:
                     # zero-valid rows route every write to the
                     # scratch page — value-level no-ops even when
                     # slot 0's table holds stale entries
+                    if self._packed:
+                        # (no live row: every token of the axis is dead)
+                        last_h, self._cache, *_ = self._extend_fn(
+                            self.params, self._cache,
+                            jnp.zeros((width,), jnp.int32),
+                            jnp.zeros((5, n), jnp.int32),
+                            jnp.zeros((2,), jnp.int32), last_h,
+                            self._tables_dev,
+                        )
+                        continue
                     last_h, self._cache, *_ = self._extend_fn(
                         self.params, self._cache,
                         jnp.zeros((n, width), jnp.int32), zeros_n, zeros_n,
@@ -3195,10 +3252,10 @@ class LLMEngine:
         # every rung is a separate XLA executable of the whole
         # unrolled prefill (~40 s compile each),
         # and at most 3x padding costs far less than it saves.
-        Np = min(
-            self._wave_pad(N),
-            self._max_wave_rows(chunk if use_chunked else bucket),
-        )
+        # A packed family's wave has ONE row count, the cap: its rows
+        # ride one token axis, so the count shapes only the carry.
+        cap = self._max_wave_rows(chunk if use_chunked else bucket)
+        Np = cap if self._packed else min(self._wave_pad(N), cap)
         rows = group + [group[0]] * (Np - N)
         # Per-row cached lengths (prefix hits matched above): warm
         # rows skip their cached chunks in the loop below; the
@@ -3560,18 +3617,24 @@ class LLMEngine:
         what each chunk holds.
 
         Chunk k extends the rows that have tokens at offset k*C by up to
-        prefill_chunk of them. Its dispatch carries only those rows,
-        padded up the wave ladder, at the narrowest rung of the width
-        ladder that holds the longest (``_chunk_rung``): a prompt's
-        tail runs at a tail's width, over the rows that have one, and a
-        chunk no row reaches is not dispatched. Offsets stay k*C, so
-        only a row's LAST chunk can be narrow and cached prefixes stay
-        chunk- and page-aligned. The per-row last-token hidden
-        accumulates on device over the whole wave's rows (a chunk of
-        fewer rows hands its own back through ``_put_rows_fn``); one
-        finish dispatch samples the first tokens. Shapes seen by XLA:
-        ``_extend_signatures`` — all warmed by warmup_chunked_shapes, so
-        no compile can land inside a request.
+        prefill_chunk of them, and a chunk no row reaches is not
+        dispatched. Offsets stay k*C, so only a row's LAST chunk can be
+        short and cached prefixes stay chunk- and page-aligned.
+
+        A family with a packed walk (``_packed``) gets the chunk's LIVE
+        TOKENS on one axis, row after row, padded up the one token
+        ladder (``_packed_rungs``): no padded row, no padded width. Any
+        other family gets a rectangle: the rows that hold tokens, padded
+        up the wave ladder, at the narrowest rung of the width ladder
+        that holds the longest (``_chunk_rung`` decides both).
+
+        The per-row last-token hidden accumulates on device over the
+        whole wave's rows (the packed program writes each row's into
+        the carry itself; a rectangle of fewer rows hands its own back
+        through ``_put_rows_fn``); one finish dispatch samples the
+        first tokens. Shapes seen by XLA: ``_extend_signatures`` — all
+        warmed by warmup_chunked_shapes, so no compile can land inside
+        a request.
 
         ``cached`` ([Np] int32, chunk-aligned) marks each row's prefix
         rows already present in its slot cache (mapped from the prefix
@@ -3613,21 +3676,48 @@ class LLMEngine:
             if dispatched and between_chunks is not None:
                 between_chunks()
             dispatched += 1
-            # the whole wave in place where the chunk's rung is the
-            # wave's (rows without tokens here ride along dead, their
-            # carried hidden kept by the program); else the live rows
-            # first, padded with dead copies of themselves
             n_live = len(live)
-            whole = n == Np
-            pick = np.arange(Np) if whole else np.resize(live, n)
-            valid_k = valid[pick]
-            if not whole:
-                valid_k[n_live:] = 0
-            tok_k = np.zeros((n, width), np.int32)
-            seg = tokens[pick, k * C:k * C + width]
-            tok_k[:, : seg.shape[1]] = seg
-            offsets = np.full((n,), k * C, np.int32)
-            W = self._extend_window(k, width)
+            W = self._extend_window(k, C if self._packed else width)
+            if self._packed:
+                # the live rows' tokens one after the other; the rows
+                # past them start where the tokens end and hold none
+                tok_k = np.zeros((width,), np.int32)
+                rows_k = np.zeros((5, Np), np.int32)
+                at = 0
+                for j, i in enumerate(live):
+                    m = int(valid[i])
+                    tok_k[at:at + m] = tokens[i, k * C:k * C + m]
+                    rows_k[:, j] = (at, m, k * C, slots[i], i)
+                    at += m
+                rows_k[0, n_live:] = at
+                operands = (
+                    jnp.asarray(tok_k), jnp.asarray(rows_k),
+                    jnp.asarray(np.array(
+                        [n_live, self._packed_windows().index(W)], np.int32
+                    )),
+                )
+                # (the program places each row in the wave's carry itself;
+                # its window is an operand, named above by its index)
+                whole, static = True, ()
+            else:
+                # the whole wave in place where the chunk's rung is the
+                # wave's (rows without tokens here ride along dead,
+                # their carried hidden kept by the program); else the
+                # live rows first, padded with dead copies of themselves
+                whole = n == Np
+                pick = np.arange(Np) if whole else np.resize(live, n)
+                valid_k = valid[pick]
+                if not whole:
+                    valid_k[n_live:] = 0
+                tok_k = np.zeros((n, width), np.int32)
+                seg = tokens[pick, k * C:k * C + width]
+                tok_k[:, : seg.shape[1]] = seg
+                operands = (
+                    jnp.asarray(tok_k),
+                    jnp.asarray(np.full((n,), k * C, np.int32)),
+                    jnp.asarray(valid_k), jnp.asarray(slots[pick]),
+                )
+                static = (W,)
             # Each _extend_fn call donates the current cache's buffers;
             # read self._cache and rebind INSIDE the dispatch lock so
             # (a) an exception between chunk dispatches never leaves
@@ -3649,13 +3739,10 @@ class LLMEngine:
                 sub_h, self._cache, *step_stats = self._extend_fn(
                     self.params,
                     self._cache,
-                    jnp.asarray(tok_k),
-                    jnp.asarray(offsets),
-                    jnp.asarray(valid_k),
-                    jnp.asarray(slots[pick]),
+                    *operands,
                     last_h if whole else self._zero_hidden(n),
                     self._tables_dev,
-                    W,
+                    *static,
                 )
             if whole:
                 last_h = sub_h
@@ -3669,6 +3756,8 @@ class LLMEngine:
                 "rows_dispatched": n,
                 "width": width,
                 "pad_tokens": n * width - live_tokens,
+                # live rows that share one token axis (1: nothing packed)
+                "packed_rows": n_live if self._packed else 1,
             }
             fields.update(self._state_counters(
                 "prefill_chunk", n_live, live_tokens,
@@ -3818,18 +3907,55 @@ class LLMEngine:
             widths.append(widths[-1] // 4)
         return widths[::-1]
 
+    def _packed_rungs(self) -> List[int]:
+        """The ONE ladder of a packed dispatch: the token counts ``T``
+        its axis is padded to. Whole pages at 1 and 1.5 times the powers
+        of two, from one page to the most a wave's chunk can hold (the
+        row cap x ``prefill_chunk``): {128, 256, 384, 512, 768, 1024,
+        1536, 2048} at a chunk of 512 over pages of 128 under
+        ``prefill_wave_tokens`` 2048, so under a third of any dispatch
+        is padding, and the count grows with the logarithm of the wave,
+        not with rows x widths x windows."""
+        page = self.engine_config.page_size
+        C = self.engine_config.prefill_chunk
+        top = self._max_wave_rows(C) * C
+        rungs = {top}
+        n = page
+        while n < top:
+            rungs.add(n)
+            if (3 * n // 2) % page == 0 and 3 * n // 2 < top:
+                rungs.add(3 * n // 2)
+            n *= 2
+        return sorted(rungs)
+
+    def _packed_windows(self) -> List[int]:
+        """The gather windows a packed program holds, ascending: chunk
+        ``k``'s rung of ``_extend_window`` for every ``k``. The dispatch
+        names one by its index (an operand), so they multiply no
+        executables."""
+        C = self.engine_config.prefill_chunk
+        return sorted({
+            self._extend_window(k, C)
+            for k in range((self.max_seq_len + C - 1) // C)
+        })
+
     def _chunk_rung(
         self, valid: Sequence[int], n_real: int
     ) -> Optional[Tuple[List[int], int, int]]:
         """(live rows, rows dispatched, width) of one chunk of a wave,
         from what the chunk holds: the rows with tokens in THIS chunk
-        (the wave's padding rows, past ``n_real``, are never live),
-        padded up the wave ladder under the chunk's row cap, at the
-        narrowest width rung that holds the longest of them. None where
-        no row is live: such a chunk is not dispatched."""
+        (the wave's padding rows, past ``n_real``, are never live). A
+        packed family: ONE axis at the least token rung that holds the
+        live tokens. Any other: the live rows padded up the wave ladder
+        under the chunk's row cap, at the narrowest width rung that
+        holds the longest of them. None where no row is live: such a
+        chunk is not dispatched."""
         live = [i for i in range(n_real) if valid[i] > 0]
         if not live:
             return None
+        if self._packed:
+            need = sum(int(valid[i]) for i in live)
+            return live, 1, next(t for t in self._packed_rungs() if t >= need)
         rows = min(
             self._wave_pad(len(live)),
             self._max_wave_rows(self.engine_config.prefill_chunk),
@@ -3855,9 +3981,13 @@ class LLMEngine:
     def _extend_signatures(self) -> List[Tuple[int, int, int]]:
         """Every (rows, width, window) an extend dispatch can have —
         what ``_chunk_rung`` and ``_extend_window`` can produce, and
-        what warm-up compiles: no other."""
+        what warm-up compiles: no other. A packed family: (the carry's
+        rows, T, capacity) for every token rung, one program each (the
+        chunk's window is an operand of it)."""
         C = self.engine_config.prefill_chunk
         cap = self._max_wave_rows(C)
+        if self._packed:
+            return [(cap, t, self.max_seq_len) for t in self._packed_rungs()]
         chunks = range((self.max_seq_len + C - 1) // C)
         return sorted({
             (n, w, self._extend_window(k, w))

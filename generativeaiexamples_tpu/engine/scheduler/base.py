@@ -326,8 +326,10 @@ class SchedulerPolicy:
             # masks, so mixed-length backlogs fill one wave instead of
             # fragmenting into per-bucket waves. Engaged when ANY
             # claimable prompt exceeds one chunk — short-only backlogs
-            # keep the flash-kernel monolithic prefill.
-            use_chunked = any(
+            # keep the flash-kernel monolithic prefill, but for a family
+            # whose waves go out packed (one token axis, no monolithic
+            # program: short rows cost what they hold either way).
+            use_chunked = eng._packed or any(
                 eng._prefill_bucket(len(r.prompt_ids)) > chunk
                 for r in claimable
             )

@@ -1122,6 +1122,31 @@ def _paged_kernel_read(
     )
 
 
+def _window_attention(q, ck, cv, cks, cvs, tabs, positions, W: int,
+                      page_size: int) -> jax.Array:
+    """The gather read of a chunk: each row's first ``W`` cached tokens
+    gathered from the pool through its page table ``tabs`` [n, Pmax]
+    (an int8 / int4 pool dequantised by the plain formula: int -> f32,
+    scale multiply, cast) into ``_attention`` under the causal mask of
+    the queries' ``positions`` [n, C]. q: [n, C, Hq, Dh]."""
+    Pw = W // page_size
+    mask = jnp.arange(W, dtype=jnp.int32)[None, None, :] <= positions[:, :, None]
+    gk = _gather_page_window(ck, tabs, Pw, page_size)
+    gv = _gather_page_window(cv, tabs, Pw, page_size)
+    if cks is not None:
+        if ck.dtype == jnp.uint8:
+            gk, gv = unpack_int4(gk), unpack_int4(gv)
+        gk = (
+            gk.astype(jnp.float32)
+            * _gather_page_window(cks, tabs, Pw, page_size)[..., None]
+        ).astype(q.dtype)  # [n, W, Hkv, Dh]
+        gv = (
+            gv.astype(jnp.float32)
+            * _gather_page_window(cvs, tabs, Pw, page_size)[..., None]
+        ).astype(q.dtype)
+    return _attention(q, gk, gv, mask)
+
+
 def _chunk_layers_paged(
     params: Params,
     cfg: LlamaConfig,
@@ -1165,13 +1190,10 @@ def _chunk_layers_paged(
     Pmax = tables.shape[1]
     S = Pmax * page_size
     W = min(window, S)
-    Pw = W // page_size
     positions = offsets[:, None] + jnp.arange(C, dtype=jnp.int32)[None, :]
     positions = jnp.minimum(positions, S - 1)
     tok_valid = jnp.arange(C, dtype=jnp.int32)[None, :] < valid[:, None]
     h = params["embed"][tokens]
-    kv_pos = jnp.arange(W, dtype=jnp.int32)
-    mask = kv_pos[None, None, :] <= positions[:, :, None]  # [N, C, W]
     row_tables = tables[slots]  # [N, Pmax]
     phys = jnp.take_along_axis(row_tables, positions // page_size, axis=1)
     phys = jnp.where((valid > 0)[:, None], phys, 0)  # dead rows -> scratch
@@ -1223,22 +1245,9 @@ def _chunk_layers_paged(
                 new_caches.append({"k": ck, "v": cv, "ks": cks, "vs": cvs})
                 if page_kernel:
                     return kernel_read(q, ck, cv, cks, cvs), ()
-                # plain dequant math (int->f32, scale multiply, cast)
-                # over the gathered token-major window into _attention
-                gk = _gather_page_window(ck, row_tables, Pw, page_size)
-                gv = _gather_page_window(cv, row_tables, Pw, page_size)
-                if packed:
-                    gk = unpack_int4(gk)
-                    gv = unpack_int4(gv)
-                kw = (
-                    gk.astype(jnp.float32)
-                    * _gather_page_window(cks, row_tables, Pw, page_size)[..., None]
-                ).astype(q.dtype)  # [N, W, Hkv, Dh]
-                vw = (
-                    gv.astype(jnp.float32)
-                    * _gather_page_window(cvs, row_tables, Pw, page_size)[..., None]
-                ).astype(q.dtype)
-                out = _attention(q, kw, vw, mask)
+                out = _window_attention(
+                    q, ck, cv, cks, cvs, row_tables, positions, W, page_size
+                )
             else:
                 cur_k = c["k"][phys, sip]  # [N,C,Hkv,Dh]
                 cur_v = c["v"][phys, sip]
@@ -1253,17 +1262,160 @@ def _chunk_layers_paged(
                 new_caches.append({"k": ck, "v": cv})
                 if page_kernel:
                     return kernel_read(q, ck, cv), ()
-                out = _attention(
-                    q,
-                    _gather_page_window(ck, row_tables, Pw, page_size),
-                    _gather_page_window(cv, row_tables, Pw, page_size),
-                    mask,
+                out = _window_attention(
+                    q, ck, cv, None, None, row_tables, positions, W, page_size
                 )
             return out, ()
 
         h, _ = _block(h, lp, cfg, positions, attn, quant_kernel=quant_kernel, tp=tp)
 
     return h, new_caches
+
+
+def extend_layers_packed(
+    params: Params,
+    cfg: LlamaConfig,
+    tokens: jax.Array,  # [T] the wave's tokens on one axis, row after row
+    starts: jax.Array,  # [R] where each row begins on that axis (ascending)
+    counts: jax.Array,  # [R] live tokens of each row (0: a dead row)
+    offsets: jax.Array,  # [R] cache position of each row's first token
+    slots: jax.Array,  # [R] decode-slot index per row (page-table row)
+    tables: jax.Array,  # [B, Pmax] page tables for ALL slots
+    caches: list,
+    page_size: int,
+    *,
+    seg: int,
+    windows: Tuple[int, ...],
+    window_index=0,
+    n_rows=None,
+    quant_kernel: Optional[bool] = None,
+    tp=None,
+    page_kernel: Optional[str] = None,
+) -> Tuple[jax.Array, list]:
+    """Chunked prefill of one wave on a PACKED token axis: each row's
+    last live token's hidden state [R, D] (a dead row's is garbage the
+    caller drops) and the updated pools.
+
+    Row ``r`` of the wave holds tokens ``starts[r] .. starts[r] +
+    counts[r] - 1`` of the axis, at cache positions ``offsets[r] ..``;
+    a token no row claims (the gap behind a row, the axis' padded end)
+    is dead. Everything per token sees ``[1, T, ...]``: embedding,
+    norms, RoPE on each token's own position, the packed/int8 products
+    (``M = T``), the K/V quantisation and the page write, where a dead
+    token lands on the scratch page, so a page some request holds is
+    never written but by its own live tokens.
+
+    Only the attention READ is per row. Each row's queries are gathered
+    out of the axis to ``[R, seg, Hq, Dh]`` (``seg``: the most tokens a
+    row can hold, static), read against the row's own cache, and
+    gathered back. ``page_kernel`` reads all ``R`` rows through the
+    ragged kernel, folded into sub-rows under its query-row cap exactly
+    as ``_chunk_layers_paged`` folds them (a dead sub-row walks one
+    page). Otherwise the read is the gather over a static window:
+    ``windows[window_index]``, chosen INSIDE the program (``lax.switch``
+    over the few power-of-two rungs, so the window multiplies no
+    executables), over rows ``0 .. n_rows - 1`` one at a time (a
+    ``fori_loop`` to a traced bound: a wave of one live row pays one
+    row's scores), or over all ``R`` at once where ``n_rows`` is None.
+    Every query sees the keys ``_chunk_layers_paged`` gives it, through
+    the same softmax in the same dtypes."""
+    T = tokens.shape[0]
+    R = starts.shape[0]
+    quantized = "ks" in caches[0]
+    packed = quantized and caches[0]["k"].dtype == jnp.uint8
+    qfn = quantize_kv_int4 if packed else quantize_kv
+    S = tables.shape[1] * page_size
+    t = jnp.arange(T, dtype=jnp.int32)
+    tok_row = jnp.maximum(jnp.sum(t[:, None] >= starts[None, :], axis=1) - 1, 0)
+    in_row = t - starts[tok_row]
+    tok_live = (in_row >= 0) & (in_row < counts[tok_row])
+    positions = jnp.clip(offsets[tok_row] + in_row, 0, S - 1)  # [T]
+    row_tables = tables[slots]  # [R, Pmax]
+    phys = jnp.where(tok_live, row_tables[tok_row, positions // page_size], 0)
+    sip = jnp.where(tok_live, positions % page_size, t % page_size)
+    phys, sip = phys[None], sip[None]  # [1, T]
+    # the read's two index maps: a row's queries out of the axis, and
+    # each token's place among the rows' results
+    lane = jnp.arange(seg, dtype=jnp.int32)[None, :]
+    q_index = jnp.minimum(starts[:, None] + lane, T - 1)  # [R, seg]
+    row_pos = jnp.minimum(offsets[:, None] + lane, S - 1)  # [R, seg]
+    back = tok_row * seg + jnp.clip(in_row, 0, seg - 1)  # [T]
+    if page_kernel:
+        heads = cfg.num_heads // (tp.shards if tp is not None else 1)
+        fold = page_attention.query_fold(seg, heads)
+        read_tables, read_pos = row_tables, offsets
+        if fold < seg:
+            sub = fold * jnp.arange(seg // fold, dtype=jnp.int32)[None, :]
+            read_pos = jnp.where(
+                sub < counts[:, None], offsets[:, None] + sub, 0
+            ).reshape(-1)
+            read_tables = jnp.repeat(row_tables, seg // fold, axis=0)
+        # one ragged work list per dispatch, shared by every layer's read
+        work = page_attention.page_work_list(
+            read_tables, read_pos, fold, page_size
+        )
+
+    def rows_read(W):
+        def read(q_rows, pools):
+            if n_rows is None:
+                return _window_attention(
+                    q_rows, *pools, row_tables, row_pos, W, page_size
+                )
+
+            def body(r, out):
+                one = lambda x: jax.lax.dynamic_slice_in_dim(x, r, 1, 0)
+                o = _window_attention(
+                    one(q_rows), *pools, one(row_tables), one(row_pos), W,
+                    page_size,
+                )
+                return jax.lax.dynamic_update_slice_in_dim(out, o, r, 0)
+
+            return jax.lax.fori_loop(
+                0, n_rows, body, jnp.zeros_like(q_rows)
+            )
+
+        return read
+
+    new_caches = []
+    h = params["embed"][tokens[None]]  # [1, T, D]
+    for lp, c in zip(params["layers"], caches):
+        def attn(q, k, v, c=c):
+            if quantized:
+                (kq, ksn), (vq, vsn) = qfn(k), qfn(v)
+                new = {
+                    "k": c["k"].at[phys, sip].set(kq),
+                    "v": c["v"].at[phys, sip].set(vq),
+                    "ks": c["ks"].at[phys, sip].set(ksn),
+                    "vs": c["vs"].at[phys, sip].set(vsn),
+                }
+                pools = (new["k"], new["v"], new["ks"], new["vs"])
+            else:
+                new = {
+                    "k": c["k"].at[phys, sip].set(k.astype(c["k"].dtype)),
+                    "v": c["v"].at[phys, sip].set(v.astype(c["v"].dtype)),
+                }
+                pools = (new["k"], new["v"], None, None)
+            new_caches.append(new)
+            q_rows = q[0][q_index]  # [R, seg, Hq, Dh]
+            if page_kernel:
+                out = _paged_kernel_read(
+                    q_rows.reshape((-1, fold) + q_rows.shape[2:]), *pools[:2],
+                    read_tables, read_pos, *pools[2:],
+                    interpret=(page_kernel == "interpret"), tp=tp, work=work,
+                ).reshape(q_rows.shape).astype(q.dtype)
+            else:
+                out = jax.lax.switch(
+                    window_index, [rows_read(W) for W in windows],
+                    q_rows, pools,
+                )
+            return out.reshape((R * seg,) + out.shape[2:])[back][None], ()
+
+        h, _ = _block(
+            h, lp, cfg, positions[None], attn, quant_kernel=quant_kernel,
+            tp=tp,
+        )
+    last = jnp.clip(starts + counts - 1, 0, T - 1)
+    return h[0][last], new_caches
 
 
 def extend_layers_paged(
@@ -1281,21 +1433,21 @@ def extend_layers_paged(
     tp=None,
     page_kernel: Optional[str] = None,
 ) -> Tuple[jax.Array, list]:
-    """``extend_layers`` over the page pool (chunked prefill).
+    """``extend_layers`` over the page pool for a RECTANGLE of rows
+    (chunked prefill as ``[rows, width]``): the packed walk over
+    ``T = rows x width`` tokens, row ``r`` starting at ``r x width``,
+    read at the one static ``window`` over all rows at once.
 
-    ``page_kernel`` serves the read through the ragged kernel; the
-    engine passes it for the narrow rungs of the width ladder (a
-    prompt's tail), folded into sub-rows under the kernel's query-row
-    cap, and leaves a full ``prefill_chunk`` on the gather."""
-    C = tokens.shape[1]
-    h, new_caches = _chunk_layers_paged(
-        params, cfg, tokens, offsets, valid, slots, tables, caches,
-        window, page_size, quant_kernel=quant_kernel, tp=tp,
+    ``page_kernel`` serves the read through the ragged kernel, folded
+    into sub-rows under the kernel's query-row cap."""
+    N, C = tokens.shape
+    S = tables.shape[1] * page_size
+    return extend_layers_packed(
+        params, cfg, tokens.reshape(-1), C * jnp.arange(N, dtype=jnp.int32),
+        valid, offsets, slots, tables, caches, page_size, seg=C,
+        windows=(min(window, S),), quant_kernel=quant_kernel, tp=tp,
         page_kernel=page_kernel,
     )
-    last_idx = jnp.clip(valid, 1, C) - 1
-    last_h = jnp.take_along_axis(h, last_idx[:, None, None], axis=1)[:, 0]
-    return last_h, new_caches
 
 
 def verify_layers_paged(

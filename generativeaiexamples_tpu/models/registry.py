@@ -20,6 +20,15 @@ engine's paged step programs (``engine/llm_engine.py``
   tables, window, page_size, **paths) -> (hidden [N, D], caches)``;
 - ``decode_paged(params, cfg, caches, tokens, positions, live, tables,
   window, page_size, **paths) -> (logits [B, V], caches)``;
+- ``extend_packed(params, cfg, caches, tokens [T], starts, counts,
+  offsets, slots [R each], tables, page_size, *, seg, windows,
+  window_index, n_rows, **paths) -> (hidden [R, D], caches)`` or None:
+  the OPTIONAL chunk walk over a packed token axis (a wave's live tokens
+  row after row, ``models/llama.py`` ``extend_layers_packed``). A family
+  that registers one is sent its prefill waves packed, on one ladder of
+  token counts (``engine/llm_engine.py`` ``_packed_rungs``); a family
+  that registers none keeps ``[rows, width]`` dispatches of
+  ``extend_paged`` (docs/model_registry.md);
 - ``verify_paged(...)`` or None (no speculative verify program);
 - ``head(params, cfg, hidden [N, D], **paths) -> logits [N, V]``;
 - the memory plan: ``serving_memory_bytes``, ``count_logical_params``,
@@ -108,6 +117,9 @@ class ModelFamily:
     # one extend program a width, not one a power-of-two window rung
     extend_reads_window: bool = True
     read_stats: Optional[Callable[[Any], Any]] = None
+    # the chunk walk over a packed token axis; None: waves go out as
+    # [rows, width] rectangles through ``extend_paged``
+    extend_packed: Optional[Callable[..., Tuple[Any, Any]]] = None
 
 
 _FAMILIES: Dict[str, ModelFamily] = {}
@@ -174,6 +186,15 @@ def _llama_family() -> ModelFamily:
             quant_kernel=quant_kernel, tp=tp, page_kernel=page_kernel,
         )
 
+    def extend_packed(params, cfg, caches, tokens, starts, counts, offsets, slots, tables, page_size, *,
+                      seg, windows, window_index=0, n_rows=None,
+                      quant_kernel=None, tp=None, page_kernel=None, **_):
+        return llama.extend_layers_packed(
+            params, cfg, tokens, starts, counts, offsets, slots, tables, caches, page_size,
+            seg=seg, windows=windows, window_index=window_index, n_rows=n_rows,
+            quant_kernel=quant_kernel, tp=tp, page_kernel=page_kernel,
+        )
+
     def decode_paged(params, cfg, caches, tokens, positions, live, tables, window, page_size, *,
                      quant_kernel=None, tp=None, page_kernel=None, **_):
         return llama.decode_layers_paged(
@@ -199,7 +220,7 @@ def _llama_family() -> ModelFamily:
         # is what lets 8B-int8 fit a 16 GB chip
         place_params=llama.consume_split_params_layers,
         prefill_paged=prefill_paged, extend_paged=extend_paged, decode_paged=decode_paged,
-        verify_paged=verify_paged, head=head,
+        verify_paged=verify_paged, head=head, extend_packed=extend_packed,
         serving_memory_bytes=llama.serving_memory_bytes,
         count_logical_params=llama.count_logical_params,
         paged_kv_shape=lambda cfg: PagedKVShape(cfg.num_layers, cfg.num_kv_heads, cfg.head_dim, cfg.num_heads),

@@ -2,9 +2,39 @@
 decode through ``llama.forward`` in float32 (no page pool, no kernel,
 no chunking, no step program), on the weights the engine draws itself
 (``init_params_fast``, seed 0)."""
+import contextlib
+import dataclasses
 import functools
 
 import numpy as np
+
+
+@contextlib.contextmanager
+def rectangles():
+    """llama as a family WITHOUT a packed walk: an engine built inside
+    sends its waves as ``[rows, width]`` rectangles, the dispatch of
+    every family that registers none (and llama's own before the packed
+    axis): the walk the packed one is held to."""
+    from generativeaiexamples_tpu.models import registry
+
+    llama = registry.families()["llama"]
+    registry._FAMILIES["llama"] = dataclasses.replace(llama, extend_packed=None)
+    try:
+        yield
+    finally:
+        registry._FAMILIES["llama"] = llama
+
+
+def build_engine(kind: str, **cfg):
+    """An engine whose waves go out ``kind``: 'packed' (llama as
+    registered) or 'rect' (``rectangles``)."""
+    from generativeaiexamples_tpu.config import EngineConfig
+    from generativeaiexamples_tpu.engine.llm_engine import LLMEngine
+
+    with (rectangles() if kind == "rect" else contextlib.nullcontext()):
+        eng = LLMEngine(EngineConfig(**cfg))
+    assert eng._packed == (kind == "packed")
+    return eng
 
 
 @functools.lru_cache(maxsize=None)

@@ -723,15 +723,26 @@ def test_validate_config_rejects_bad_knobs():
 # The prefill_chunk span says what its dispatch computed (chunked
 # prefill's shape rule: engine/llm_engine.py _chunk_rung)
 
+def _chunk_engine(kind):
+    from greedy_reference import build_engine
+
+    return build_engine(
+        kind, model_config_name="debug-1k", max_batch_size=4, max_seq_len=256, prefill_chunk=64, page_size=16,
+        decode_block=2, dtype="float32", tensor_parallelism=1, prefix_cache_enable="off",
+    )
+
+
 @pytest.fixture(scope="module")
 def chunk_engine():
-    from generativeaiexamples_tpu.config import EngineConfig
-    from generativeaiexamples_tpu.engine.llm_engine import LLMEngine
+    eng = _chunk_engine("packed")
+    yield eng
+    eng.shutdown()
 
-    eng = LLMEngine(EngineConfig(
-        model_config_name="debug-1k", max_batch_size=4, max_seq_len=256, prefill_chunk=64, page_size=16,
-        decode_block=2, dtype="float32", tensor_parallelism=1, prefix_cache_enable="off",
-    ))
+
+@pytest.fixture(scope="module")
+def rect_engine():
+    """The same engine for a family without a packed walk (rectangles)."""
+    eng = _chunk_engine("rect")
     yield eng
     eng.shutdown()
 
@@ -748,31 +759,42 @@ def _engine_counters():
 
 
 CHUNK_WAVES = {
-    # prompt lengths of one wave -> (rows, tokens, rows_dispatched, width, pad_tokens) of each chunk span
-    "tail_on_one_of_three": ([69, 40, 10], [(3, 114, 4, 64, 142), (1, 5, 1, 16, 11)]),
-    "two_tails": ([69, 40, 90, 64], [(4, 232, 4, 64, 24), (2, 31, 4, 64, 225)]),
-    "two_narrow_tails": ([69, 70, 10], [(3, 138, 4, 64, 118), (2, 11, 4, 16, 53)]),
-    "one_row_two_full_chunks_and_a_page": ([144], [(1, 64, 1, 64, 0), (1, 64, 1, 64, 0), (1, 16, 1, 16, 0)]),
+    # prompt lengths of one wave -> (rows, tokens, rows_dispatched, width, pad_tokens, packed_rows) of each chunk span
+    "packed": {
+        # one axis a dispatch: width is its token rung of {16, 32, 48, 64, 96, 128, 192, 256}
+        "tail_on_one_of_three": ([69, 40, 10], [(3, 114, 1, 128, 14, 3), (1, 5, 1, 16, 11, 1)]),
+        "two_tails": ([69, 40, 90, 64], [(4, 232, 1, 256, 24, 4), (2, 31, 1, 32, 1, 2)]),
+        "two_narrow_tails": ([69, 70, 10], [(3, 138, 1, 192, 54, 3), (2, 11, 1, 16, 5, 2)]),
+        "one_row_two_full_chunks_and_a_page": ([144], [(1, 64, 1, 64, 0, 1), (1, 64, 1, 64, 0, 1), (1, 16, 1, 16, 0, 1)]),
+    },
+    "rect": {
+        "tail_on_one_of_three": ([69, 40, 10], [(3, 114, 4, 64, 142, 1), (1, 5, 1, 16, 11, 1)]),
+        "two_tails": ([69, 40, 90, 64], [(4, 232, 4, 64, 24, 1), (2, 31, 4, 64, 225, 1)]),
+        "two_narrow_tails": ([69, 70, 10], [(3, 138, 4, 64, 118, 1), (2, 11, 4, 16, 53, 1)]),
+        "one_row_two_full_chunks_and_a_page": ([144], [(1, 64, 1, 64, 0, 1), (1, 64, 1, 64, 0, 1), (1, 16, 1, 16, 0, 1)]),
+    },
 }
 
 
-@pytest.mark.parametrize("name", sorted(CHUNK_WAVES))
-def test_prefill_chunk_span_carries_rows_width_and_padding(chunk_engine, name):
+@pytest.mark.parametrize("name", sorted(CHUNK_WAVES["packed"]))
+@pytest.mark.parametrize("kind", sorted(CHUNK_WAVES))
+def test_prefill_chunk_span_carries_rows_width_and_padding(request, kind, name):
     from generativeaiexamples_tpu.engine.llm_engine import SamplingParams
 
-    lengths, expect = CHUNK_WAVES[name]
+    engine = request.getfixturevalue("chunk_engine" if kind == "packed" else "rect_engine")
+    lengths, expect = CHUNK_WAVES[kind][name]
     before, cursor = _engine_counters(), dtl.spans_since(0)[1]
-    with chunk_engine.hold_admissions():
+    with engine.hold_admissions():
         reqs = [
-            chunk_engine.submit([(i * 7 + n) % 250 + 1 for i in range(n)],
-                                SamplingParams(temperature=0.0, max_tokens=2))
+            engine.submit([(i * 7 + n) % 250 + 1 for i in range(n)],
+                          SamplingParams(temperature=0.0, max_tokens=2))
             for n in lengths
         ]
     for req in reqs:
         while req.out_queue.get(timeout=300) is not None:
             pass
     spans = [s for s in dtl.spans_since(cursor)[0] if s["kind"] == "prefill_chunk"]
-    got = [(s["rows"], s["tokens"], s["rows_dispatched"], s["width"], s["pad_tokens"]) for s in spans]
+    got = [(s["rows"], s["tokens"], s["rows_dispatched"], s["width"], s["pad_tokens"], s["packed_rows"]) for s in spans]
     assert got == expect
     for s in spans:
         assert s["pad_tokens"] == s["rows_dispatched"] * s["width"] - s["tokens"] >= 0
@@ -783,16 +805,23 @@ def test_prefill_chunk_span_carries_rows_width_and_padding(chunk_engine, name):
     assert grew("genai_engine_prefill_chunks_total") == len(expect)
 
 
-def test_monolithic_prefill_counts_its_padded_bucket(chunk_engine):
+@pytest.mark.parametrize("kind", ["packed", "rect"])
+def test_a_short_prompt_counts_what_its_dispatch_computed(request, kind):
+    """A prompt under a chunk: packed, its tokens' rung on the one
+    ladder (a ``prefill_chunk`` span); as a rectangle, the monolithic
+    prefill's padded bucket (a ``prefill`` span)."""
     from generativeaiexamples_tpu.engine.llm_engine import SamplingParams
 
-    before = _engine_counters()
-    list(chunk_engine.iter_ids([5] * 20, SamplingParams(temperature=0.0, max_tokens=2), timeout=300))
+    engine = request.getfixturevalue("chunk_engine" if kind == "packed" else "rect_engine")
+    before, cursor = _engine_counters(), dtl.spans_since(0)[1]
+    list(engine.iter_ids([5] * 20, SamplingParams(temperature=0.0, max_tokens=2), timeout=300))
     after = _engine_counters()
     assert after["genai_engine_prefill_tokens_total"] - before["genai_engine_prefill_tokens_total"] == 20
-    # one row of one 64-token bucket: live share 20 / 64
+    # packed: 20 live of the 32-token rung; a rectangle: one row of one 64-token bucket
     assert (after["genai_engine_extend_tokens_computed_total"]
-            - before["genai_engine_extend_tokens_computed_total"]) == 64
+            - before["genai_engine_extend_tokens_computed_total"]) == (32 if kind == "packed" else 64)
+    kinds = [s["kind"] for s in dtl.spans_since(cursor)[0] if s["kind"] in ("prefill", "prefill_chunk")]
+    assert kinds == (["prefill_chunk"] if kind == "packed" else ["prefill"])
 
 
 def test_engine_spans_are_stamped_by_the_watcher(chunk_engine):

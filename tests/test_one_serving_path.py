@@ -109,10 +109,12 @@ def test_an_engine_always_has_a_page_allocator_and_one_program_a_step_kind(engin
         assert not hasattr(engine, gone), gone
     snap = engine._compile_watch.snapshot()
     families = {k[len("compile_executables_"):] for k in snap if k.startswith("compile_executables_")}
-    # (put_rows: the tiny program that hands a chunk of fewer rows' hidden states back to its wave)
+    # (the dense family's waves go out packed: ONE prefill program kind, extend; no monolithic prefill and
+    # no put_rows, the tiny program that hands a rectangle of fewer rows' hidden states back to its wave)
     # (update_slots: a module-level function, so jit's caches of it are one a process and an engine built
     # after another finds it warm: jit reports no event and the watch, which reads jit's events, no family)
-    assert families | {"update_slots"} == {"prefill", "decode", "extend", "finish", "put_rows", "update_slots", "page_tables"}
+    assert families | {"update_slots"} == {"decode", "extend", "finish", "update_slots", "page_tables"}
+    assert engine._packed and engine._prefill_fn is None
     # the gather serves the CPU: one decode program a window rung, and
     # nothing compiled after warm-up
     assert snap["compile_executables_decode"] == len(engine._window_rungs())

@@ -335,6 +335,9 @@ def test_rows_served_together_equal_their_solo_runs(engine):
     rng = np.random.default_rng(2)
     prompts = [[int(t) for t in rng.integers(3, 500, size=n)] for n in (7, 30, 12, 21, 40)]
     greedy = SamplingParams(temperature=0.0, max_tokens=14)
+    from generativeaiexamples_tpu.engine import dispatch_timeline
+
+    since = dispatch_timeline.cursor()  # the ring is the process's: other engines' waves sit in it
     solo = [list(engine.iter_ids(p, greedy, timeout=300)) for p in prompts]
     got = [None] * len(prompts)
 
@@ -347,9 +350,7 @@ def test_rows_served_together_equal_their_solo_runs(engine):
     for t in threads:
         t.join()
     assert got == solo
-    from generativeaiexamples_tpu.engine import dispatch_timeline
-
-    waves = [s for s in dispatch_timeline.recent_spans(256) if s.get("kind") in ("prefill", "prefill_chunk")]
+    waves = [s for s in dispatch_timeline.spans_since(since)[0] if s.get("kind") in ("prefill", "prefill_chunk")]
     assert waves and all(s["rows"] <= 1 for s in waves)  # one row a wave: _max_wave_rows
 
 

@@ -13,7 +13,7 @@ from generativeaiexamples_tpu.config import EngineConfig
 from generativeaiexamples_tpu.engine.llm_engine import LLMEngine
 
 
-def make_sched(chunk=16, max_seq=128, slots=8, budget=16384, page=None):
+def make_sched(chunk=16, max_seq=128, slots=8, budget=16384, page=None, packed=False):
     eng = LLMEngine.__new__(LLMEngine)  # scheduler helpers only
     eng.engine_config = EngineConfig(
         prefill_chunk=chunk,
@@ -26,6 +26,7 @@ def make_sched(chunk=16, max_seq=128, slots=8, budget=16384, page=None):
     eng.max_seq_len = max_seq
     eng._fixed_state = False
     eng._one_extend_window = False
+    eng._packed = packed  # a family with a walk over a packed token axis (llama)
     return eng
 
 
@@ -117,8 +118,10 @@ def test_attention_window_rungs(cfg):
 
 
 # --------------------------------------------------------------------- //
-# The shape of one extend dispatch: rows and width from what the chunk
-# holds (_chunk_rung), the two ladders, and the executable set
+# The shape of one extend dispatch, from what the chunk holds
+# (_chunk_rung). A family with a packed walk: ONE token ladder
+# (_packed_rungs). Any other: rows x widths, the two ladders. And the
+# executable set of each.
 
 
 @pytest.mark.parametrize(
@@ -190,14 +193,146 @@ def test_extend_signatures_are_exactly_what_the_rule_can_produce(cfg):
         assert window == cfg["max_seq"] if width < C else window >= min(C, cfg["max_seq"])
 
 
+RECT_AT_THE_BENCHMARK_GEOMETRY = [
+    (1, 128, 4096), (1, 512, 512), (1, 512, 1024), (1, 512, 2048), (1, 512, 4096),
+    (4, 128, 4096), (4, 512, 512), (4, 512, 1024), (4, 512, 2048), (4, 512, 4096),
+]
+
+
 def test_executable_count_at_the_benchmark_geometry():
-    """Mistral's cell: rows {1, 4} x four windows at 512 and one program
-    a row rung at 128: 10 (8 before the width ladder); Phi-4-flash: 5."""
-    eng = make_sched(chunk=512, max_seq=4096, slots=64, budget=2048, page=128)
-    assert eng._extend_signatures() == [
-        (1, 128, 4096), (1, 512, 512), (1, 512, 1024), (1, 512, 2048), (1, 512, 4096),
-        (4, 128, 4096), (4, 512, 512), (4, 512, 1024), (4, 512, 2048), (4, 512, 4096),
-    ]
+    """Mistral's cell as rectangles (before the packed axis): rows
+    {1, 4} x four windows at 512 and one program a row rung at 128: 10,
+    beside two monolithic prefill rungs. Packed: one program a token
+    rung, 8, and no monolithic program: fewer, as ISSUE 41 asks."""
+    rect = make_sched(chunk=512, max_seq=4096, slots=64, budget=2048, page=128)
+    assert rect._extend_signatures() == RECT_AT_THE_BENCHMARK_GEOMETRY
+    eng = make_sched(chunk=512, max_seq=4096, slots=64, budget=2048, page=128, packed=True)
+    assert eng._packed_rungs() == [128, 256, 384, 512, 768, 1024, 1536, 2048]
+    assert eng._extend_signatures() == [(4, t, 4096) for t in eng._packed_rungs()]
+    assert eng._packed_windows() == [512, 1024, 2048, 4096]
+    monolithic = 2  # prefill_batch_paged at [1, 512] and [4, 512], which a packed family never builds
+    assert len(eng._extend_signatures()) <= len(rect._extend_signatures()) + monolithic
+
+
+PACKED_GRID = GRID + [dict(chunk=512, max_seq=4096, slots=64, budget=2048, page=128),
+                      dict(chunk=64, max_seq=256, slots=4, page=16),
+                      dict(chunk=32, max_seq=96, slots=4, page=8)]
+
+
+@pytest.mark.parametrize("cfg", PACKED_GRID)
+def test_packed_rungs_are_the_one_ladder(cfg):
+    """Whole pages at 1 and 1.5 times the powers of two, from one page to
+    the most a chunk of a wave can hold; so every token count that can
+    arise has a rung, and under a third of that rung is padding (but
+    for the first page)."""
+    eng = make_sched(packed=True, **cfg)
+    page, C = eng.engine_config.page_size, cfg["chunk"]
+    top = eng._max_wave_rows(C) * C
+    rungs = eng._packed_rungs()
+    assert rungs == sorted(set(rungs)) and rungs[0] == min(page, top) and rungs[-1] == top
+    assert all(t % page == 0 or t == top for t in rungs)
+    for t in rungs[:-1]:
+        m = t // page
+        assert m & (m - 1) == 0 or (m % 3 == 0 and (m // 3) & (m // 3 - 1) == 0)
+    for a, b in zip(rungs, rungs[1:]):
+        assert b <= 2 * a and (b - a - 1) * 3 <= b or b - a <= page  # a need of a + 1 pads under a third of b
+    # the count grows with the logarithm of the wave
+    assert len(rungs) <= 2 * max(1, (top // page)).bit_length()
+
+
+@pytest.mark.parametrize("cfg", PACKED_GRID)
+def test_packed_signatures_are_exactly_what_the_rule_can_produce(cfg):
+    """Every chunk of every wave of a packed family lands on a warmed
+    (rows, T, capacity) and names a window the program holds; the set
+    holds no other."""
+    eng = make_sched(packed=True, **cfg)
+    C = cfg["chunk"]
+    cap = eng._max_wave_rows(C)
+    windows = eng._packed_windows()
+    assert windows == sorted(set(windows)) and windows[-1] == cfg["max_seq"]
+    reachable = set()
+    for n_live in range(1, cap + 1):
+        for need in {1, C // 2 + 1, C}:
+            live, rows, width = eng._chunk_rung([need] * n_live + [0] * (cap - n_live), cap)
+            assert live == list(range(n_live)) and rows == 1
+            assert width >= n_live * need and width in eng._packed_rungs()
+            assert all(t < n_live * need for t in eng._packed_rungs() if t < width)  # the least that holds them
+            reachable.add((cap, width, cfg["max_seq"]))
+    for t in eng._packed_rungs():  # every rung is some wave's: t tokens over the fewest rows
+        rows = -(-t // C)
+        spread = [t // rows + (1 if i < t % rows else 0) for i in range(rows)]
+        assert eng._chunk_rung(spread, rows) == (list(range(rows)), 1, t)
+        reachable.add((cap, t, cfg["max_seq"]))
+    assert reachable == set(eng._extend_signatures())
+    for k in range(-(-cfg["max_seq"] // C)):
+        assert eng._extend_window(k, C) in windows
+        assert eng._extend_window(k, C) >= min((k + 1) * C, cfg["max_seq"])
+
+
+PACKED_SHAPE_CASES = [
+    # (valid of the wave's rows, real rows) -> (live rows, 1 axis, T)
+    ("tail_on_one_row", [0, 71, 0, 0], 4, ([1], 1, 128)),
+    ("tail_on_two_rows", [71, 0, 0, 71], 4, ([0, 3], 1, 256)),
+    ("tails_on_three_rows", [71, 71, 0, 71], 4, ([0, 1, 3], 1, 256)),
+    ("tails_on_four_rows", [71, 71, 71, 71], 4, ([0, 1, 2, 3], 1, 384)),
+    ("the_deck_in_one_wave", [512, 330, 458, 512], 4, ([0, 1, 2, 3], 1, 2048)),
+    ("one_short_prompt", [330, 0, 0, 0], 4, ([0], 1, 384)),
+    ("one_prompt_under_a_chunk", [458, 0, 0, 0], 4, ([0], 1, 512)),
+    ("two_short_prompts", [330, 0, 330, 0], 4, ([0, 2], 1, 768)),
+    ("three_short_prompts", [330, 330, 330, 0], 4, ([0, 1, 2], 1, 1024)),
+    ("a_page_exactly", [128, 0, 0, 0], 4, ([0], 1, 128)),
+    ("one_token", [0, 0, 1, 0], 4, ([2], 1, 128)),
+    # a wave of three: the fourth row is a copy of row 0 and never live
+    ("padding_row_is_not_live", [71, 0, 0, 71], 3, ([0], 1, 128)),
+    ("padding_rows_only", [0, 0, 0, 71], 3, None),
+    ("empty_chunk", [0, 0, 0, 0], 4, None),
+]
+
+
+@pytest.mark.parametrize("name,valid,n_real,expect", PACKED_SHAPE_CASES, ids=[c[0] for c in PACKED_SHAPE_CASES])
+def test_packed_chunk_rung_follows_the_live_tokens(name, valid, n_real, expect):
+    eng = make_sched(chunk=512, max_seq=4096, slots=64, budget=2048, packed=True)  # chat_decode_7b's geometry
+    assert eng._chunk_rung(valid, n_real) == expect
+
+
+# What the three fixed-state families' cells are sent, pinned to the
+# parent's (443b2dc): no packed walk registered, one row a wave, the
+# rectangles of before. (configuration, engine geometry of its cell in
+# perfbench/configs) -> _extend_signatures()
+FIXED_STATE_CELLS = {
+    "phi4flash": (dict(chunk=512, max_seq=4096, slots=64, budget=512, page=128),
+                  [(1, 128, 4096), (1, 512, 512), (1, 512, 1024), (1, 512, 2048), (1, 512, 4096)]),
+    "glm5next": (dict(chunk=512, max_seq=8192, slots=64, budget=512, page=128),
+                 [(1, 128, 8192), (1, 512, 8192)]),
+    "gigachat35": (dict(chunk=512, max_seq=8192, slots=64, budget=512, page=128),
+                   [(1, 128, 8192), (1, 512, 8192)]),
+}
+
+
+@pytest.mark.parametrize("name", sorted(FIXED_STATE_CELLS))
+def test_fixed_state_families_keep_their_rectangles(name):
+    from generativeaiexamples_tpu.models import registry
+
+    family = registry.families()[name]
+    assert family.fixed_state and family.extend_packed is None
+    cfg, signatures = FIXED_STATE_CELLS[name]
+    eng = make_sched(**cfg)
+    # as LLMEngine.__init__ reads them from the registry entry
+    eng._fixed_state = bool(family.fixed_state)
+    eng._one_extend_window = not family.extend_reads_window
+    eng._packed = family.extend_packed is not None
+    assert eng._extend_signatures() == signatures
+    assert eng._chunk_widths() == [128, 512]
+    for valid, expect in (([71], ([0], 1, 128)), ([128], ([0], 1, 128)), ([129], ([0], 1, 512)),
+                          ([512], ([0], 1, 512)), ([0], None)):
+        assert eng._chunk_rung(valid, 1) == expect
+    assert eng._max_wave_rows(512) == 1
+
+
+def test_only_the_dense_family_registers_a_packed_walk():
+    from generativeaiexamples_tpu.models import registry
+
+    assert {n for n, f in registry.families().items() if f.extend_packed is not None} == {"llama"}
 
 
 @pytest.mark.parametrize("chunk,max_seq", [(512, 8192), (64, 256)])
