@@ -78,6 +78,7 @@ __all__ = [
     "record_compile",
     "note_jit",
     "start_watcher",
+    "stop_watcher",
     "stamp_pending",
     "cursor",
     "spans_since",
@@ -510,6 +511,7 @@ def note_jit(program: str, what: str, seconds: float) -> None:
 # two readbacks (a slab of 64 streams is ~5 ms) would make stamps late.
 
 _PENDING: "queue.SimpleQueue[Tuple[Span, Any, int]]" = queue.SimpleQueue()
+_STOP = object()  # stop_watcher() queues it: the watcher thread ends
 _EPOCH = 0  # reset() bumps it: a launch awaited across a reset is not stamped
 _WATCHER: Optional[threading.Thread] = None  # guarded by _LOCK
 _PREV_DONE: Optional[float] = None  # watcher-owned: the last stamp
@@ -551,7 +553,10 @@ def _await_and_stamp(span: "Span", handle: Any, epoch: int, now=time.time) -> No
 
 def _watch() -> None:
     while True:
-        span, handle, epoch = _PENDING.get()
+        item = _PENDING.get()
+        if item is _STOP:
+            return
+        span, handle, epoch = item
         try:
             _await_and_stamp(span, handle, epoch)
         except Exception:  # noqa: BLE001 - the one thread that stamps must live
@@ -568,12 +573,25 @@ def start_watcher() -> None:
         watcher = _WATCHER = threading.Thread(
             target=_watch, daemon=True, name="llm-dispatch-watcher"
         )
-    if _count_gc not in gc.callbacks:
+    if _count_gc not in gc.callbacks:  # the first start of this process: the hook and the heartbeat live on
         gc.callbacks.append(_count_gc)
-    threading.Thread(
-        target=_heartbeat, daemon=True, name="llm-dispatch-heartbeat"
-    ).start()
+        threading.Thread(
+            target=_heartbeat, daemon=True, name="llm-dispatch-heartbeat"
+        ).start()
     watcher.start()
+
+
+def stop_watcher(timeout: float = 5.0) -> None:
+    """End the watcher thread (tests only): a process that has built an
+    engine keeps one for good, and it would race ``stamp_pending`` for
+    the queue and stamp a fake-clock test's launches from the wall
+    clock. The next ``start_watcher`` starts a new one."""
+    global _WATCHER
+    with _LOCK:
+        watcher, _WATCHER = _WATCHER, None
+    if watcher is not None and watcher.is_alive():
+        _PENDING.put(_STOP)
+        watcher.join(timeout)
 
 
 def stamp_pending(now=time.time) -> int:
@@ -583,10 +601,12 @@ def stamp_pending(now=time.time) -> int:
     n = 0
     while True:
         try:
-            span, handle, epoch = _PENDING.get_nowait()
+            item = _PENDING.get_nowait()
         except queue.Empty:
             return n
-        _await_and_stamp(span, handle, epoch, now)
+        if item is _STOP:  # a stopped watcher never took it
+            continue
+        _await_and_stamp(*item, now)
         n += 1
 
 

@@ -257,6 +257,19 @@ _M_STATE_KERNEL_ROWS = _REG.counter(
     "where the XLA step serves. Beside the spans' state_rows it is the "
     "share of decode steps the kernel engages on.",
 )
+_M_WINDOW_READ = _REG.counter(
+    "genai_engine_window_read_tokens_total",
+    "Ring rows the window-attention layers read for their queries "
+    "(each query's own position and the window before it, summed over "
+    "the window layers), at the one step a dispatch reports.",
+)
+_M_FULL_READ = _REG.counter(
+    "genai_engine_full_read_tokens_total",
+    "Cached tokens the full-attention layers of a model that also has "
+    "window layers read for their queries (every token up to each "
+    "query's own), at the one step a dispatch reports. Beside "
+    "window_read_tokens it is the window layers' share of attention reads.",
+)
 # a family's step stats (models/registry.py ``stat_names``) that also feed
 # a counter, by the stat's name: the engine knows mechanisms, not models
 _STAT_COUNTERS = {
@@ -266,6 +279,8 @@ _STAT_COUNTERS = {
     "dsa_context_tokens": _M_DSA_CONTEXT,
     "latent_tokens_read": _M_LATENT_READ,
     "state_kernel_rows": _M_STATE_KERNEL_ROWS,
+    "window_tokens_read": _M_WINDOW_READ,
+    "full_tokens_read": _M_FULL_READ,
 }
 _M_SSM_DISPATCHES = _REG.counter(
     "genai_engine_ssm_dispatches_total",

@@ -406,10 +406,12 @@ def route(x, lp: Params, cfg: Glm5NextConfig):
     return top.astype(jnp.int32), gates
 
 
-def moe(x, lp: Params, cfg: Glm5NextConfig, count, kernel: Optional[str]):
+def moe(x, lp: Params, cfg: Glm5NextConfig, count, kernel: Optional[str], expert_dtype=None):
     """x [N, D] -> (shared expert + held routed experts [N, D] float32,
     stats [4]: pairs held, pairs absent, experts hit, experts held) over
-    the tokens ``count`` [N] bool marks."""
+    the tokens ``count`` [N] bool marks. ``expert_dtype``: what the routed
+    experts multiply x in (None: as it comes); the router scores x as it
+    comes either way."""
     with jax.named_scope("moe_route"):
         top, gates = route(x, lp, cfg)
         E = cfg.experts_held
@@ -418,7 +420,8 @@ def moe(x, lp: Params, cfg: Glm5NextConfig, count, kernel: Optional[str]):
         local = jnp.where(held & count[:, None], local, E)  # uncounted tokens route nowhere
     with jax.named_scope("moe_experts"):
         routed, sizes = expert_ops.grouped_mlp(
-            x, local, gates, lp["we_gate_up"], lp["we_down"], limit=cfg.swiglu_limit, kernel=kernel)
+            x if expert_dtype is None else x.astype(expert_dtype), local, gates,
+            lp["we_gate_up"], lp["we_down"], limit=cfg.swiglu_limit, kernel=kernel)
         shared = swiglu_mlp(x, lp["ws_gate_up"], lp["ws_down"], cfg.swiglu_limit)
     n_held = jnp.sum(sizes)
     n_all = jnp.sum(count.astype(jnp.int32)) * cfg.num_experts_per_tok

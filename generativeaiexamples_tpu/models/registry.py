@@ -306,9 +306,35 @@ def _gigachat35_family() -> ModelFamily:
     )
 
 
+def _afmoe_family() -> ModelFamily:
+    from generativeaiexamples_tpu.models import afmoe as m
+
+    def init_paged_cache(cfg, pool_pages, page_size, num_slots, dtype, quantized=False, packed=False):
+        if quantized or packed:
+            raise ValueError("afmoe keeps its paged full layers and its window rings in bfloat16")
+        return m.init_paged_cache(cfg, pool_pages, page_size, num_slots, dtype)
+
+    return ModelFamily(
+        name="afmoe", presets=m.PRESETS, config_type=m.AfmoeConfig, fixed_state=True,
+        init_params=m.init_params_fast, init_paged_cache=init_paged_cache,
+        prefill_paged=m.prefill_paged, extend_paged=m.extend_paged, decode_paged=m.decode_paged,
+        verify_paged=None, head=lambda params, cfg, hidden, **_: m.head(params, cfg, hidden),
+        serving_memory_bytes=m.serving_memory_bytes, count_logical_params=m.count_logical_params,
+        # the full layers alone are paged, plain GQA in head-major pages
+        paged_kv_shape=lambda cfg: PagedKVShape(
+            len(cfg.layers_of("full")), cfg.num_kv_heads, cfg.head_dim, cfg.num_heads),
+        fixed_state_bytes_per_slot=m.fixed_state_bytes_per_slot,
+        # no "window_layers": window_tokens_read is a step stat here (counted on the device)
+        span_fields=lambda cfg: {"kv_readers": len(cfg.layers_of("full"))},
+        resolve_kernels=lambda cfg, kind: {"grouped_matmul": kind},
+        stat_names=m.STAT_NAMES, read_stats=m.read_stats, extend_reads_window=False,
+    )
+
+
 def _load_builtin() -> None:
     if not _FAMILIES:
         register_family(_llama_family())
         register_family(_phi4flash_family())
         register_family(_glm5next_family())
         register_family(_gigachat35_family())
+        register_family(_afmoe_family())

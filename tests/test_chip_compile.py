@@ -189,6 +189,24 @@ def test_grouped_matmul_compiles_at_7168_wide_experts(one_chip, no_persistent_ca
     assert text.count("tpu_custom_call") >= 2
 
 
+@pytest.mark.parametrize("tokens", [64, 512], ids=["decode-160-tiles-of-16", "chunk-192-tiles-of-64"])
+def test_grouped_matmul_compiles_at_128_fine_grained_experts(one_chip, no_persistent_cache, tokens):
+    """models/afmoe.py's shape: ALL 128 experts of 2048 x 1024 held, 8
+    pairs a token, no clamp (``limit`` = inf): 128 groups, up to 160
+    tiles of 16 rows a decode step, 192 of 64 a chunk."""
+    D, F, E, k = 2048, 1024, 128, 8
+
+    def s(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    def mlp(x, local, gates, w_gu, w_d):
+        return grouped_matmul.grouped_mlp(x, local, gates, w_gu, w_d, limit=float("inf"), kernel="compiled")
+
+    text = _compiled_text(mlp, s((tokens, D), jnp.bfloat16), s((tokens, k), jnp.int32), s((tokens, k), jnp.float32),
+                          s((E, D, 2 * F), jnp.bfloat16), s((E, F, D), jnp.bfloat16))
+    assert text.count("tpu_custom_call") >= 2
+
+
 def _delta_rule_shapes(sharding, key_heads, per_channel, layers=None):
     """The delta-rule step's operands at the two expert cells' shapes:
     64 slots x 64 value heads x [128, 128] float32 a layer."""

@@ -18,6 +18,10 @@ from generativeaiexamples_tpu.engine import dispatch_timeline as dtl
 
 
 def _fresh(enable=True, capacity=dtl._DEFAULT_CAPACITY):
+    # a watcher left by an engine of an earlier file of this worker (or by
+    # the watcher test below) would race stamp_pending() for the queue and
+    # stamp a fake-clock launch from the wall clock
+    dtl.stop_watcher()
     dtl.reset()
     dtl.configure(enable=enable, capacity=capacity)
 
@@ -364,6 +368,28 @@ def test_the_watcher_thread_stamps_from_the_wall_clock():
         while span.t_done is None and time.time() < deadline:
             time.sleep(0.005)
         assert span.t_done is not None and 0.04 < span.device_s < 4.0
+    finally:
+        _fresh()
+
+
+def test_fresh_stops_a_watcher_an_earlier_engine_left_running():
+    """What made two tests below depend on the run: a live watcher takes
+    a launch off the queue before ``stamp_pending`` does, now and then,
+    and stamps it from the wall clock (0.5 s here) and not the fake one."""
+    _fresh()
+    try:
+        dtl.start_watcher()
+        assert any(t.name == "llm-dispatch-watcher" and t.is_alive() for t in threading.enumerate())
+        _fresh()
+        assert not any(t.name == "llm-dispatch-watcher" and t.is_alive() for t in threading.enumerate())
+        for _ in range(50):
+            dtl.reset()
+            clock = _Clock(time.time() - 0.5)
+            span = _launch(clock, "decode", clock.t, clock.t + 0.2)
+            assert dtl.stamp_pending(clock) == 1
+            assert abs(span.device_s - 0.2) < 1e-6
+        dtl.start_watcher()  # an engine built later gets a new one
+        assert sum(t.name == "llm-dispatch-watcher" and t.is_alive() for t in threading.enumerate()) == 1
     finally:
         _fresh()
 
