@@ -845,6 +845,7 @@ class LLMEngine:
         # (K+1-wide rows), each behind its geometry probe with a LOUD
         # fallback to the XLA dequant gather.
         self._paged_kernel: Optional[str] = None
+        self._kv_pages_a_step = 1
         self._paged_verify_kernel: Optional[str] = None
         self._paged_extend_kernel: Optional[str] = None
         self._resolve_paged_kernel(cfg, model_cfg)
@@ -1052,6 +1053,16 @@ class LLMEngine:
             shards=shards,
         ):
             self._paged_kernel = kind
+            # pages of a row one grid step of paged_attention carries: the
+            # kernel's own rule over the pool's geometry. A latent pool
+            # (bytes_per_token set) is read by ops/latent_attention.py,
+            # one page a step.
+            if kv_shape.bytes_per_token is None:
+                self._kv_pages_a_step = page_attention.pool_pages_per_step(
+                    cfg.page_size, kv_shape.num_kv_heads, kv_shape.head_dim,
+                    "int8" if self._kv_quant else cfg.dtype,
+                    quantized=self._kv_quant,
+                )
             logger.info(
                 "ragged page-attention kernel serving paged decode "
                 "(%s, page_size=%d%s)", kind, cfg.page_size,
@@ -2039,14 +2050,18 @@ class LLMEngine:
         grid it replaced — ops/page_attention.page_work_list's count
         from the host's position shadow (caller holds the lock; no
         readback): a live row's pages up to its query position, one
-        scratch page per empty slot."""
+        scratch page per empty slot; and the grid steps that carry
+        them, ``_kv_pages_a_step`` pages of a row a step."""
         page = self.engine_config.page_size
         last = self.max_seq_len - 1
-        walked = sum(
-            min(p, last) // page + 1 for p in self._slot_pos.values()
-        ) + self.num_slots - len(self._slot_pos)
+        live = [min(p, last) // page + 1 for p in self._slot_pos.values()]
+        empty = self.num_slots - len(live)
+        n = self._kv_pages_a_step
         return {
-            "kv_pages_walked": walked,
+            "kv_pages_walked": sum(live) + empty,
+            # grid steps of the same walk: a step carries up to n pages
+            # of one row (kv_pages_walked / kv_page_steps = pages a step)
+            "kv_page_steps": sum(-(-m // n) for m in live) + empty,
             "kv_pages_grid": self.num_slots * self._max_pages_per_slot,
         }
 

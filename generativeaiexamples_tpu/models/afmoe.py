@@ -533,7 +533,9 @@ def decode_paged(params: Params, cfg: AfmoeConfig, caches: Caches, tokens, posit
     ring_mask = ((jnp.arange(Wn, dtype=jnp.int32)[None, :] <= pos2) | (pos2 >= Wn))[:, None, :]
     rows = jnp.arange(B, dtype=jnp.int32)[:, None]
     sip = pos2 % page_size
-    work = page_attention.page_work_list(tables, positions, 1, page_size) if page_kernel else None
+    work = page_attention.page_work_list(
+        tables, positions, 1, page_size, page_attention.pages_per_step(caches["full"][0]["k"])
+    ) if page_kernel else None
     keys_seen = jnp.where(live, positions + 1, 0)
     window_read = jnp.zeros((), jnp.int32)
     full_read = jnp.zeros((), jnp.int32)

@@ -1529,7 +1529,10 @@ def decode_layers_paged(
     mask = jnp.arange(W, dtype=jnp.int32)[None, None, :] <= pos2[:, :, None]
     # one ragged work list per step, shared by every layer's read
     work = (
-        page_attention.page_work_list(tables, positions, 1, page_size)
+        page_attention.page_work_list(
+            tables, positions, 1, page_size,
+            page_attention.pages_per_step(caches[0]["k"], caches[0].get("ks")),
+        )
         if page_kernel else None
     )
     new_caches = []

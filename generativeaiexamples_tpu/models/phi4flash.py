@@ -628,7 +628,9 @@ def decode_paged(params: Params, cfg: Phi4FlashConfig, caches: Caches, tokens, p
     rows = jnp.arange(B, dtype=jnp.int32)[:, None]
     keep = live[:, None, None]
     # one ragged work list a step, shared by the full layer and every cross layer
-    work = page_attention.page_work_list(tables, positions, 1, page_size) if page_kernel else None
+    work = page_attention.page_work_list(
+        tables, positions, 1, page_size, page_attention.pages_per_step(caches["pool"]["k"])
+    ) if page_kernel else None
 
     h = _embed(params, tokens[:, None])  # [B, 1, D]
     new = {"pool": caches["pool"], "win": [], "ssm": [], "conv": []}

@@ -7,9 +7,9 @@ dequant-gather that serves it everywhere reads a power-of-two window
 sequence, exactly the padded-window traffic the paged design exists to
 remove. This kernel is the ragged read (PAPERS.md: "Ragged Paged
 Attention" is this kernel for TPU): it walks a flat WORK LIST
-of the live (row, page) pairs only (``page_work_list``: each row's
-pages up to its last query position, flattened in row order), handed
-over by scalar prefetch, so both the cache traffic and the number of
+of the live (row, pages) groups only (``page_work_list``: each row's
+pages up to its last query position, one or two a step, flattened in
+row order), handed over by scalar prefetch, so both the cache traffic and the number of
 grid steps track each sequence's true page-rounded length
 (``utils/hardware.kv_read_bytes_ragged`` is this kernel's operand math).
 
@@ -28,7 +28,12 @@ Design:
 - **page-granular scales.** The int8 variant's per-(token, head) scales
   live page-contiguous (``[P, page, Hkv]``, engine/kv_pages.py /
   models/llama.py); they fold into the score/prob matrices after the
-  int8 dots.
+  int8 dots. A page's block arrives ``[page, Hkv]``, token on sublanes
+  and head on the first lanes of a padded tile (64 KB moved for 4 KB:
+  the pool's layout, not this kernel's to change), and the scores want
+  it as the row ``[1, page * Hkv]``: ``_scale_row`` builds that row with
+  strided lane rotates, where the plain ``reshape`` relayout, twice a
+  page, was 28 % of the kernel (PERF.md §6, PR 43).
 - **bf16 AND int8.** The ragged walk is the win, not the dequant in
   VMEM alone, so every pool dtype gets the kernel.
 - **multi-query rows.** ``q`` is ``[B, T, Hq, Dh]``: T=1 is block
@@ -39,22 +44,45 @@ Design:
   ``query_fold`` queries each (the narrow rung of chunked prefill,
   models/llama.py), a full prefill chunk stays on the XLA gather.
 
-Grid: one dimension of ``n_work = sum_b live_pages(b)`` steps, a
-DYNAMIC bound (``PrefetchScalarGridSpec`` takes a traced scalar; Mosaic
-compiles the loop with a run-time trip count). Step ``i`` DMAs pool page
-``phys[i]`` (all KV heads) for row ``row[i]``; the running softmax
-max/sum/accumulator live in VMEM scratch, reset where an item is the
-first page of its row and normalised into the row's output block where
+Grid: one dimension of ``n_work = sum_b ceil(live_pages(b) / N)`` steps,
+a DYNAMIC bound (``PrefetchScalarGridSpec`` takes a traced scalar; Mosaic
+compiles the loop with a run-time trip count). Step ``i`` carries a GROUP
+of up to ``N`` consecutive live pages of row ``row[i]``: every pool
+operand is passed once a place of the group, with index map
+``phys[i * N + n]``, so Pallas's own pipeline double-buffers ``N`` pages
+(all KV heads) a step. The body walks the group's live pages in
+ascending order through the one-page arithmetic (``page_step``), a dead
+place of a row's last group under ``pl.when`` (it names the page its
+place held a step earlier: no DMA, no arithmetic); the running softmax
+max/sum/accumulator live in VMEM scratch, reset where a step is the
+first group of its row and normalised into the row's output block where
 it is the last. The output (and query) block index is the row, so a
 block moves once per row. A row's pages stay in ascending order: the
-accumulation order, and so every output bit, is that of the
-``(B, Pmax)`` grid this walk replaced. That grid took all ``B * Pmax``
+accumulation order, and so every output bit, is that of one page a step
+and of the ``(B, Pmax)`` grid before it. That grid took all ``B * Pmax``
 steps whatever the rows held (an index-map clamp elided only a dead
 page's DMA) and cost ~0.3 us a dead step: at 64 slots x 32 pages with
 5-6 live pages a row, two thirds of the kernel's time (PERF.md, PR 27).
-A dead row (position 0 pointing at the scratch page) keeps one item, so
+A dead row (position 0 pointing at the scratch page) keeps one step, so
 its output block is still written: finite garbage that the engine
 discards, identical to the fixed kernel's contract.
+
+Why N (``pages_per_step``): a step costs ~0.4 us before its first byte
+(0.42 us with an empty body and two 131 KB page DMAs, whose bytes are
+0.32 us of HBM time) and ~0.1 us more for every operand it names, so a
+page that is cheap to move and to compute shares its step: two
+single-query bfloat16 pages a step wherever two page pairs fit
+``_STEP_BYTES``. Four a step measured no better than two at any served
+geometry (what is left is per page: a DMA's issue and wait, the softmax
+over ``[Hq, page * Hkv]``); a quantised page (four blocks a page) and a
+multi-query page (spec verify, the folded extend read: compute-heavy)
+measured slower paired. So N is 1 or 2 and follows the static shapes
+alone: no setting.
+
+Masks: the token clamp and the own-head lane mask are rebuilt on every
+page. Together they are 1.4 % of a page; building the head mask once and
+branching around the token mask on a row's inner pages measured slower
+(PERF.md §6, PR 43).
 
 The flat grid is ``arbitrary``: it gives up the ``parallel`` row
 dimension of the old grid. On v5e (one TensorCore a chip) that costs
@@ -96,89 +124,141 @@ def _unpack_nibbles(u):
     return jnp.concatenate([lo, hi], axis=-1).astype(jnp.bfloat16)
 
 
+def _scale_row(s_ref):
+    """A page's scales ``[page, Hkv]`` (token on sublanes, head on the
+    first ``Hkv`` lanes of a padded tile) as the ``[1, page * Hkv]`` row
+    the scores multiply by, column ``c = t * Hkv + h``.
+
+    Eight tokens share a vreg; a lane tile of the row holds ``128 / Hkv``
+    tokens. One STRIDED lane rotate a vreg (sublane ``s`` moves by ``s *
+    Hkv`` lanes more than its neighbour) puts every token's heads at
+    their lanes, a select keeps them, and one sublane sum a lane tile
+    (seven exact zeros and the value) collapses the eight tokens into
+    the row: 16 rotates and 8 sums for ``[128, 8]``, where the plain
+    ``reshape(1, cols)`` relayout was 0.17 us a block, twice a page, 28 %
+    of the kernel (PERF.md §6, PR 43). Geometries the rotate does not
+    tile (a head count that does not divide 128 into whole vregs) keep
+    the reshape."""
+    s = s_ref[0]
+    page, hkv = s.shape
+    cols = page * hkv
+    if _LANE % (8 * hkv) or cols % _LANE or page % 8:
+        return s.reshape(1, cols)
+    vregs = _LANE // (8 * hkv)  # 8-token vregs a lane tile of the row
+    wide = jnp.pad(s, ((0, 0), (0, _LANE - hkv)))
+    lane = lax.broadcasted_iota(jnp.int32, (8, _LANE), 1)
+    sub = lax.broadcasted_iota(jnp.int32, (8, _LANE), 0)
+    tiles = []
+    for tile in range(cols // _LANE):
+        acc = jnp.zeros((8, _LANE), jnp.float32)
+        for m in range(vregs):
+            at = 8 * (tile * vregs + m)
+            moved, shift, left = wide[at:at + 8], 8 * hkv * m, hkv
+            while left:  # the chip takes a rotate's stride modulo 8
+                moved = pltpu.roll(moved, shift, 1, stride=min(left, 7), stride_axis=0)
+                shift, left = 0, left - min(left, 7)
+            acc = jnp.where((lane - 8 * hkv * m) // hkv == sub, moved, acc)
+        tiles.append(jnp.sum(acc, axis=0, keepdims=True))
+    return jnp.concatenate(tiles, axis=1)
+
+
 def _kernel(
     row_ref, page_ref, phys_ref, pos_ref, q_ref, *refs,
     scale: float, page: int, hq: int, hkv: int, g: int,
     t: int, s_max: int, quantized: bool, packed: bool,
-    head_major: bool = False,
+    head_major: bool = False, group: int = 1,
 ):
-    if quantized:
-        k_ref, ks_ref, v_ref, vs_ref, o_ref, m_ref, l_ref, acc_ref = refs
-    else:
-        ks_ref = vs_ref = None
-        k_ref, v_ref, o_ref, m_ref, l_ref, acc_ref = refs
+    per_page = 4 if quantized else 2  # pool operands a page of the group
+    o_ref, m_ref, l_ref, acc_ref = refs[group * per_page:]
     del phys_ref  # consumed by the pool index maps only
     i = pl.program_id(0)
-    j = page_ref[i]  # logical page of row row_ref[i]; ascending per row
+    j0 = page_ref[i]  # the group's first logical page; ascending per row
     p_first = pos_ref[row_ref[i]]
     last_tok = jnp.minimum(p_first + t - 1, s_max - 1)
     rows = t * hq
     cols = page * hkv
     dh = q_ref.shape[-1]
 
-    @pl.when(j == 0)
+    @pl.when(j0 == 0)
     def _init():
         m_ref[...] = jnp.full_like(m_ref, _NEG_INF)
         l_ref[...] = jnp.zeros_like(l_ref)
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
-    # every work item is a live page: page_work_list emits none past
-    # the row's last live token
-    q = q_ref[0].reshape(rows, dh)  # [T*Hq, Dh] (leading-dim merge)
-    if packed:
-        # int4 pool: nibble-unpack to exact bf16 integers in [-7, 7]
-        # before the dot — the same exact-operand discipline as int8
-        k_cat = _unpack_nibbles(k_ref[0].reshape(cols, dh // 2))
-    else:
-        k_cat = k_ref[0].reshape(cols, dh).astype(jnp.bfloat16)
-    sc = lax.dot_general(
-        q, k_cat, (((1,), (1,)), ((), ())),
-        preferred_element_type=jnp.float32,
-    )  # [rows, page*Hkv]; column c = (token-in-page)*Hkv + kv-head
-    if quantized:
-        # page-granular K scales fold in AFTER the int8/int4 dot
-        # (small integers convert to bf16 exactly, so the MXU saw
-        # exact operands)
-        sc = sc * (ks_ref[0].reshape(1, cols) * scale)
-    else:
-        sc = sc * scale
-    col_iota = lax.broadcasted_iota(jnp.int32, (rows, cols), 1)
-    row_iota = lax.broadcasted_iota(jnp.int32, (rows, 1), 0)
-    if head_major:  # a page is [Hkv, page, Dh]: column c = kv-head * page + token
-        tok = j * page + col_iota % page
-        col_head = col_iota // page
-    else:
-        tok = j * page + col_iota // hkv
-        col_head = col_iota % hkv
-    row_head = (row_iota % hq) // g
-    # per-query-row causal clamp: query t attends <= positions + t
-    q_pos = jnp.minimum(p_first + row_iota // hq, s_max - 1)
-    live = (tok <= q_pos) & (col_head == row_head)
-    sc = jnp.where(live, sc, _NEG_INF)
+    def page_step(j, *pool):
+        """One live page into the running softmax: the arithmetic, and
+        its order, of the one-page step this group replaced."""
+        if quantized:
+            k_ref, ks_ref, v_ref, vs_ref = pool
+        else:
+            k_ref, v_ref = pool
+        q = q_ref[0].reshape(rows, dh)  # [T*Hq, Dh] (leading-dim merge)
+        if packed:
+            # int4 pool: nibble-unpack to exact bf16 integers in [-7, 7]
+            # before the dot — the same exact-operand discipline as int8
+            k_cat = _unpack_nibbles(k_ref[0].reshape(cols, dh // 2))
+        else:
+            k_cat = k_ref[0].reshape(cols, dh).astype(jnp.bfloat16)
+        sc = lax.dot_general(
+            q, k_cat, (((1,), (1,)), ((), ())),
+            preferred_element_type=jnp.float32,
+        )  # [rows, page*Hkv]; column c = (token-in-page)*Hkv + kv-head
+        if quantized:
+            # page-granular K scales fold in AFTER the int8/int4 dot
+            # (small integers convert to bf16 exactly, so the MXU saw
+            # exact operands)
+            sc = sc * (_scale_row(ks_ref) * scale)
+        else:
+            sc = sc * scale
+        col_iota = lax.broadcasted_iota(jnp.int32, (rows, cols), 1)
+        row_iota = lax.broadcasted_iota(jnp.int32, (rows, 1), 0)
+        if head_major:  # a page is [Hkv, page, Dh]: column c = kv-head * page + token
+            tok = j * page + col_iota % page
+            col_head = col_iota // page
+        else:
+            tok = j * page + col_iota // hkv
+            col_head = col_iota % hkv
+        row_head = (row_iota % hq) // g
+        # per-query-row causal clamp: query t attends <= positions + t
+        # (both masks rebuilt on every page on purpose: "Masks" above)
+        q_pos = jnp.minimum(p_first + row_iota // hq, s_max - 1)
+        live = (tok <= q_pos) & (col_head == row_head)
+        sc = jnp.where(live, sc, _NEG_INF)
 
-    m_prev = m_ref[:, :1]  # [rows, 1]
-    m_new = jnp.maximum(m_prev, jnp.max(sc, axis=1, keepdims=True))
-    prob = jnp.exp(sc - m_new)  # dead/foreign-head columns -> 0
-    alpha = jnp.exp(m_prev - m_new)
-    l_ref[...] = jnp.broadcast_to(
-        alpha * l_ref[:, :1] + jnp.sum(prob, axis=1, keepdims=True),
-        l_ref.shape,
-    )
-    m_ref[...] = jnp.broadcast_to(m_new, m_ref.shape)
-    if quantized:
-        prob = prob * vs_ref[0].reshape(1, cols)
-    if packed:
-        v_cat = _unpack_nibbles(v_ref[0].reshape(cols, dh // 2))
-    else:
-        v_cat = v_ref[0].reshape(cols, dh).astype(jnp.bfloat16)
-    out = lax.dot_general(
-        prob.astype(jnp.bfloat16), v_cat, (((1,), (0,)), ((), ())),
-        preferred_element_type=jnp.float32,
-    )  # [rows, Dh]
-    acc_ref[...] = acc_ref[...] * alpha + out
+        m_prev = m_ref[:, :1]  # [rows, 1]
+        m_new = jnp.maximum(m_prev, jnp.max(sc, axis=1, keepdims=True))
+        prob = jnp.exp(sc - m_new)  # dead/foreign-head columns -> 0
+        alpha = jnp.exp(m_prev - m_new)
+        l_ref[...] = jnp.broadcast_to(
+            alpha * l_ref[:, :1] + jnp.sum(prob, axis=1, keepdims=True),
+            l_ref.shape,
+        )
+        m_ref[...] = jnp.broadcast_to(m_new, m_ref.shape)
+        if quantized:
+            prob = prob * _scale_row(vs_ref)
+        if packed:
+            v_cat = _unpack_nibbles(v_ref[0].reshape(cols, dh // 2))
+        else:
+            v_cat = v_ref[0].reshape(cols, dh).astype(jnp.bfloat16)
+        out = lax.dot_general(
+            prob.astype(jnp.bfloat16), v_cat, (((1,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32,
+        )  # [rows, Dh]
+        acc_ref[...] = acc_ref[...] * alpha + out
 
-    # the row's last live page: its successor would start past last_tok
-    @pl.when((j + 1) * page > last_tok)
+    for n in range(group):
+        step = functools.partial(
+            page_step, j0 + n, *refs[n * per_page:(n + 1) * per_page]
+        )
+        if n == 0:
+            step()  # a group's first page is live: page_work_list emits no empty group
+        else:
+            # a dead place of the row's last group: its block holds a
+            # page some earlier place named; none of it is computed
+            pl.when((j0 + n) * page <= last_tok)(step)
+
+    # the row's last group: its successor would start past last_tok
+    @pl.when((j0 + group) * page > last_tok)
     def _finish():
         l = l_ref[:, :1]
         l = jnp.where(l == 0.0, 1.0, l)  # paranoia: never divide by 0
@@ -188,15 +268,58 @@ def _kernel(
 
 
 class PageWork(NamedTuple):
-    """The ragged work list of one attention read: item ``i < n_work[0]``
-    is logical page ``page[i]`` of row ``row[i]``, stored in pool page
-    ``phys[i]``. Rows ascend, pages ascend inside a row, every row has
-    at least one item. Entries past ``n_work`` are in-bounds padding."""
+    """The ragged work list of one attention read: step ``i < n_work[0]``
+    carries the ``N`` consecutive logical pages ``page[i] .. page[i] + N
+    - 1`` of row ``row[i]``; place ``n`` of it is stored in pool page
+    ``phys[i * N + n]``. ``N`` (pages a step) is the ratio of the two
+    lengths; at ``N = 1`` an item is one page. Rows ascend, groups ascend
+    inside a row, every row has at least one step. A DEAD place (past
+    the row's last live page, in its last group only) names the pool
+    page the same place held at the step before, so no DMA is issued for
+    it. Entries past ``n_work`` are in-bounds padding."""
 
-    n_work: jax.Array  # [1] int32
-    row: jax.Array  # [B * Pmax] int32
-    page: jax.Array  # [B * Pmax] int32
-    phys: jax.Array  # [B * Pmax] int32
+    n_work: jax.Array  # [1] int32 — steps
+    row: jax.Array  # [B * ceil(Pmax / N)] int32
+    page: jax.Array  # [B * ceil(Pmax / N)] int32 — first page of the group
+    phys: jax.Array  # [B * ceil(Pmax / N) * N] int32
+
+
+# What the pages of one grid step may move, K and V blocks together: two
+# pages of a bfloat16 4-head pool (262 KB a pair) or of a ten-head pair
+# layout (655 KB) fit; a page of a megabyte or more is bound by its
+# bytes and walks alone.
+_STEP_BYTES = 3 << 19  # 1.5 MiB
+
+
+def pages_per_step(k, k_scale=None, query_len: int = 1) -> int:
+    """Pages of one row a grid step carries (``N``, 1 or 2), from what
+    is static: the pool's dtype, the bytes a page's block specs move
+    against ``_STEP_BYTES``, and the queries a row holds. A step costs
+    ~0.4 us before its first byte, and ~0.1 us more for every operand it
+    names; a single-query bfloat16 page whose bytes and arithmetic take
+    about as long shares its step with its successor (-6 % and -12 % on
+    the two head-major reads; four pages a step measured no better than
+    two anywhere: what is left is per page). Three kinds of page keep
+    their own step because pairing them measured SLOWER: a quantised
+    page (four blocks a page, two of them scale blocks padded to 64 KB,
+    and the relayout of each: +14 % paired), a page of a multi-query
+    row (spec verify, the folded extend read: compute-heavy, +3-8 %),
+    and a page of a megabyte or more (PERF.md §6, PR 43). ``k`` /
+    ``k_scale`` are the pool arrays (or their shape structs)."""
+    pair = 2 * math.prod(k.shape[1:]) * jnp.dtype(k.dtype).itemsize
+    paired = k_scale is None and query_len == 1 and 2 * pair <= _STEP_BYTES
+    return 2 if paired else 1
+
+
+def pool_pages_per_step(page_size: int, num_kv_heads: int, head_dim: int,
+                        dtype, quantized: bool = False) -> int:
+    """``pages_per_step`` of a pool known by its geometry alone (the
+    engine's host-side step count holds no pool array); ``dtype`` is the
+    pool's own. A quantised pool's answer does not depend on its bytes,
+    so the packed int4 pool's halved rows need no case here."""
+    k = jax.ShapeDtypeStruct((1, page_size, num_kv_heads, head_dim), dtype)
+    scales = jax.ShapeDtypeStruct((1, page_size, num_kv_heads), jnp.float32)
+    return pages_per_step(k, scales if quantized else None)
 
 
 def page_work_list(
@@ -204,30 +327,45 @@ def page_work_list(
     positions: jax.Array,  # [B] int32 — first query token's position
     query_len: int,
     page_size: int,
+    group: int = 1,
 ) -> PageWork:
-    """Flatten each row's LIVE pages into one list the kernel walks.
+    """Flatten each row's LIVE pages into one list the kernel walks,
+    ``group`` (N) consecutive pages a step.
 
-    Row ``b`` holds ``n_b = min(pos_b + T - 1, S - 1) // page + 1``
-    items, so the list has ``sum(n_b)`` of them — not ``B * Pmax`` —
-    and a dead row (position 0) keeps exactly one (its scratch page),
-    which is what writes its output block. Pure ``jnp``: the paged
-    model computes it once per step and every layer's read shares it.
+    Row ``b`` holds ``n_b = min(pos_b + T - 1, S - 1) // page + 1`` live
+    pages in ``ceil(n_b / N)`` steps, so the list has ``sum`` of those —
+    not ``B * Pmax`` — and a dead row (position 0) keeps exactly one
+    (its scratch page), which is what writes its output block. Pure
+    ``jnp``: the paged model computes it once per step and every layer's
+    read shares it, at the N that ``pages_per_step(pool)`` names.
     """
     B, Pmax = tables.shape
+    N = group
     pos = positions.astype(jnp.int32)
     n = jnp.minimum(pos + query_len - 1, Pmax * page_size - 1) // page_size + 1
-    ends = jnp.cumsum(n)
-    item = jnp.arange(B * Pmax, dtype=jnp.int32)
+    groups = (n + N - 1) // N
+    ends = jnp.cumsum(groups)
+    item = jnp.arange(B * (-(-Pmax // N)), dtype=jnp.int32)
     row = jnp.minimum(
         jnp.sum(item[:, None] >= ends[None, :], axis=1, dtype=jnp.int32), B - 1
     )
-    page = jnp.minimum(item - (ends - n)[row], n[row] - 1)
-    return PageWork(
-        ends[-1:], row, page, tables.astype(jnp.int32)[row, page]
-    )
+    first = N * jnp.minimum(item - (ends - groups)[row], groups[row] - 1)
+    place = first[:, None] + jnp.arange(N, dtype=jnp.int32)[None, :]
+    live = place < n[row][:, None]
+    phys = tables.astype(jnp.int32)[row[:, None], jnp.minimum(place, Pmax - 1)]
+    if N > 1:
+        # a dead place repeats what its place held at the last step that
+        # filled it (the block index does not change: no DMA); before any
+        # did, the group's own first page
+        filled = lax.cummax(jnp.where(live, item[:, None], -1), axis=0)
+        held = jnp.take_along_axis(phys, jnp.maximum(filled, 0), axis=0)
+        phys = jnp.where(filled >= 0, held, phys[:, :1])
+    return PageWork(ends[-1:], row, first, phys.reshape(-1))
 
 
-@functools.partial(jax.jit, static_argnames=("interpret", "head_major"))
+@functools.partial(
+    jax.jit, static_argnames=("interpret", "head_major", "group")
+)
 def paged_attention(
     q: jax.Array,  # [B, T, Hq, Dh] bf16 — T query tokens per row
     k: jax.Array,  # [P, page, Hkv, Dh] int8 or bf16 page pool
@@ -240,6 +378,7 @@ def paged_attention(
     interpret: bool = False,
     work: Optional[PageWork] = None,
     head_major: bool = False,
+    group: Optional[int] = None,
 ) -> jax.Array:
     """Attention output ``[B, T, Hq, Dh]`` over each row's live pages.
 
@@ -258,9 +397,12 @@ def paged_attention(
     entries past a row's live length (they point at the scratch page)
     are never read: the work list stops at ``positions[b] + T - 1``.
 
-    ``work`` is ``page_work_list(tables, positions, T, page)``; a caller
-    that reads many layers at the same positions passes it so the list
-    is built once, otherwise it is built here.
+    ``work`` is ``page_work_list(tables, positions, T, page, N)``; a
+    caller that reads many layers at the same positions passes it so the
+    list is built once, and the kernel takes its pages a step from the
+    list's own shapes. Otherwise the list is built here, ``group`` pages
+    a step where given (the tests walk {1, 2, 4}) and
+    ``pages_per_step(k, k_scale, T)`` where not.
     """
     B, T, Hq, Dh = q.shape
     if head_major:
@@ -284,32 +426,39 @@ def paged_attention(
     scale = 1.0 / math.sqrt(Dh)
     pos = positions.astype(jnp.int32)
     if work is None:
-        work = page_work_list(tables, pos, T, page)
+        work = page_work_list(
+            tables, pos, T, page, group or pages_per_step(k, k_scale, T)
+        )
+    N = work.phys.shape[0] // work.row.shape[0]
 
-    def pool_spec():
+    def pool_spec(n):
         return pl.BlockSpec(
             (1, Hkv, page, Dh_pool) if head_major else (1, page, Hkv, Dh_pool),
-            lambda i, row, pg, phys, pos: (phys[i], 0, 0, 0),
+            lambda i, row, pg, phys, pos: (phys[i * N + n], 0, 0, 0),
         )
 
-    def scale_spec():
+    def scale_spec(n):
         return pl.BlockSpec(
-            (1, page, Hkv), lambda i, row, pg, phys, pos: (phys[i], 0, 0)
+            (1, page, Hkv), lambda i, row, pg, phys, pos: (phys[i * N + n], 0, 0)
         )
 
     def row_spec():
-        # q and the output follow the item's row: fetched / written
+        # q and the output follow the step's row: fetched / written
         # back only where the row changes
         return pl.BlockSpec(
             (1, T, Hq, Dh), lambda i, row, pg, phys, pos: (row[i], 0, 0, 0)
         )
 
-    if quantized:
-        in_specs = [row_spec(), pool_spec(), scale_spec(), pool_spec(), scale_spec()]
-        operands = (q, k, k_scale, v, v_scale)
-    else:
-        in_specs = [row_spec(), pool_spec(), pool_spec()]
-        operands = (q, k, v)
+    # each pool operand is passed once a place of the group, so Pallas's
+    # own pipeline double-buffers N pages a step
+    in_specs, operands = [row_spec()], [q]
+    for n in range(N):
+        if quantized:
+            in_specs += [pool_spec(n), scale_spec(n), pool_spec(n), scale_spec(n)]
+            operands += [k, k_scale, v, v_scale]
+        else:
+            in_specs += [pool_spec(n), pool_spec(n)]
+            operands += [k, v]
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=4,
@@ -326,7 +475,7 @@ def paged_attention(
         functools.partial(
             _kernel, scale=scale, page=page, hq=Hq, hkv=Hkv, g=G, t=T,
             s_max=S, quantized=quantized, packed=packed,
-            head_major=head_major,
+            head_major=head_major, group=N,
         ),
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((B, T, Hq, Dh), q.dtype),

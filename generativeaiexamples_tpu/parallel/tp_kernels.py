@@ -203,7 +203,8 @@ def paged_attention_tp(
     run_interpret = interpret or tp.interpret
     if work is None:
         work = page_attention.page_work_list(
-            tables, positions, q.shape[1], k.shape[1]
+            tables, positions, q.shape[1], k.shape[1],
+            page_attention.pages_per_step(k, k_scale, q.shape[1]),
         )
     # the work list replicates with the tables: every device walks the
     # same (row, page) items over its own heads

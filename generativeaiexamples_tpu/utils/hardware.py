@@ -170,9 +170,15 @@ def kv_read_bytes_ragged(model_cfg, live_tokens: int, kv_bytes: float) -> int:
     feeds the utilization estimator, so the roofline gauges charge the
     bytes the ragged kernel actually reads instead of phantom
     padded-window traffic. The kernel's TIME follows the same sum: it
-    walks one grid step per live page (ops/page_attention.page_work_list),
-    not a dense slots x max_pages grid, so bytes and steps move
-    together."""
+    walks the live pages alone (ops/page_attention.page_work_list), one
+    or two of a row a grid step, not a dense slots x max_pages grid, so
+    bytes and steps move together. The kernel fetches exactly these
+    pages: a dead place of a row's last group names the page its place
+    already holds, which moves nothing. An int8 pool's scales are in
+    ``kv_bytes`` at their logical 4 bytes a (token, head); the kernel's
+    scale DMAs move 16 times that (a ``[page, 8]`` float32 block pads to
+    128 lanes), about half again of a page's int8 bytes: the pool's
+    layout, PERF.md §7."""
     # exactly the per-step formula at batch=1 x live_tokens "window" —
     # one expression, so the fixed and paged accounting cannot drift
     return kv_read_bytes_per_step(model_cfg, 1, live_tokens, kv_bytes)

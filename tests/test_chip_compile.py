@@ -145,6 +145,46 @@ def test_paged_attention_compiles(one_chip, no_persistent_cache, T, kv_dtype):
     assert "tpu_custom_call" in text
 
 
+@pytest.mark.parametrize(
+    "T,heads,kv_dtype,head_major,n",
+    [
+        (1, (32, 8), jnp.int8, False, 1),  # Mistral's int8 token-major pool
+        (16, (32, 8), jnp.int8, False, 1),  # ... its folded extend read: 512 score rows a page
+        (1, (40, 10), jnp.bfloat16, True, 2),  # Phi-4-flash's pair layout
+        (1, (32, 4), jnp.bfloat16, True, 2),  # Trinity-Mini's full layer
+    ],
+    ids=["mistral-int8", "mistral-int8-fold16", "phi4flash-pairs", "trinity-32-4"],
+)
+def test_grouped_paged_attention_compiles_at_the_served_geometries(
+    one_chip, no_persistent_cache, T, heads, kv_dtype, head_major, n
+):
+    """Several pages of a row a grid step: every pool operand is passed
+    once a place of the group, at the pages a step the rule gives each
+    served geometry (and at four, which the tests walk)."""
+    hq, hkv = heads
+    B, pages_per_row = 16, 32
+    n_pages = B * pages_per_row + 1
+
+    def s(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    pool = s((n_pages, hkv, PAGE, DH) if head_major else (n_pages, PAGE, hkv, DH), kv_dtype)
+    scales = [s((n_pages, PAGE, hkv), jnp.float32)] * 2 if kv_dtype == jnp.int8 else []
+    assert page_attention.pages_per_step(pool, *scales[:1], query_len=T) == n
+
+    for pages in (None, 4):
+        def fn(q, k, v, tables, pos, *sc):
+            return page_attention.paged_attention(
+                q, k, v, tables, pos, *sc, head_major=head_major, group=pages
+            )
+
+        text = _compiled_text(
+            fn, s((B, T, hq, DH), jnp.bfloat16), pool, pool,
+            s((B, pages_per_row), jnp.int32), s((B,), jnp.int32), *scales,
+        )
+        assert "tpu_custom_call" in text
+
+
 @pytest.mark.parametrize("T", [512, 2048])
 def test_flash_attention_compiles(one_chip, no_persistent_cache, T):
     def s(heads):
@@ -355,6 +395,9 @@ def test_paged_attention_tp_compiles_over_four_chips(topo, no_persistent_cache):
             q, k, v, tables, pos, ks, vs, tp=tp, interpret=False
         )
 
+    # the list is built over the whole pool's geometry and walked by
+    # every device over its own two KV heads (an int8 page keeps its own step)
+    assert page_attention.pages_per_step(args[1], args[5]) == 1
     text = _compiled_text(fn, *args)
     assert "tpu_custom_call" in text
     assert "all-gather" not in text

@@ -186,6 +186,8 @@ WALK_CASES = {
     "all_rows_dead": [0, 0, 0, 0],
 }
 WALK_POOL = 40
+# pages a step of the three served geometries (ops/page_attention.pages_per_step)
+RULE = {"mistral": 1, "trinity": 2, "phi4flash": 2}
 
 
 def _walk_tables(pos, t):
@@ -217,7 +219,8 @@ def _walk_pool(rng, kind, hkv):
 @pytest.mark.parametrize("pool", ["int8", "int4", "bf16"])
 @pytest.mark.parametrize("t", [1, 4], ids=["decode", "verify4"])
 @pytest.mark.parametrize("case", sorted(WALK_CASES))
-def test_ragged_walk_matches_gather(case, t, pool, heads):
+@pytest.mark.parametrize("n", [1, 2, 4], ids=["1page", "2pages", "4pages"])
+def test_ragged_walk_matches_gather(n, case, t, pool, heads):
     hq, hkv = heads
     pos = WALK_CASES[case]
     rng = np.random.default_rng(zlib.crc32(repr((case, t, pool, heads)).encode()))
@@ -226,7 +229,9 @@ def test_ragged_walk_matches_gather(case, t, pool, heads):
     q = jnp.asarray(rng.standard_normal((len(pos), t, hq, Dh)), jnp.bfloat16)
     posj = jnp.asarray(pos, jnp.int32)
     k, v, *scales = kernel_pool
-    out = pa.paged_attention(q, k, v, tables, posj, *scales, interpret=True)
+    out = pa.paged_attention(
+        q, k, v, tables, posj, *scales, interpret=True, group=n
+    )
     assert bool(jnp.isfinite(out.astype(jnp.float32)).all())
     rk, rv, *rscales = ref_pool
     _assert_close(out, _reference(q, rk, rv, tables, posj, *rscales))
@@ -277,6 +282,225 @@ def test_shared_work_list_equals_the_one_built_inside():
     np.testing.assert_array_equal(
         np.asarray(got, np.float32), np.asarray(want, np.float32)
     )
+
+
+# several pages of a row a grid step (PageWork at N > 1)
+
+GROUP_PMAX = 12
+
+
+def _group_rows(n):
+    """First-query positions of rows holding 1, N-1, N, N+1 and 2N+1
+    live pages (T = 1), each ending in the middle of its last page."""
+    live = sorted({1, max(1, n - 1), n, n + 1, 2 * n + 1})
+    return [(m - 1) * PAGE + 3 for m in live], live
+
+
+def _group_tables(live, pmax=GROUP_PMAX):
+    tables = np.zeros((len(live), pmax), np.int32)
+    nxt = 1
+    for b, m in enumerate(live):
+        tables[b, :m] = np.arange(nxt, nxt + m)
+        nxt += m
+    return jnp.asarray(tables), nxt
+
+
+def _walk(q, pool, tables, pos, n, **kw):
+    k, v, *scales = pool
+    return np.asarray(pa.paged_attention(
+        q, k, v, tables, jnp.asarray(pos, jnp.int32), *scales,
+        interpret=True, group=n, **kw,
+    ), np.float32)
+
+
+@pytest.mark.parametrize("pool", ["int8", "int4", "bf16"])
+@pytest.mark.parametrize("n", [2, 4])
+def test_group_edges_are_bit_equal_to_one_page_a_step(n, pool):
+    """Rows of 1, N-1, N, N+1 and 2N+1 live pages: a group walks its
+    pages in ascending order through the one-page arithmetic, so every
+    row keeps the bits of the one-page-a-step walk (and the gather's
+    values)."""
+    pos, live = _group_rows(n)
+    tables, used = _group_tables(live)
+    rng = np.random.default_rng(n * 31 + len(pool))
+    if pool == "bf16":
+        kernel_pool = ref_pool = _bf16_pool(rng, used, 8)
+    elif pool == "int8":
+        kernel_pool = ref_pool = _int8_pool(rng, used, 8)
+    else:
+        kq, vq, ks, vs = _int4_pool(rng, used, 8)
+        kernel_pool, ref_pool = (kq, vq, ks, vs), (_unpack_pool(kq), _unpack_pool(vq), ks, vs)
+    q = jnp.asarray(rng.standard_normal((len(pos), 1, 32, Dh)), jnp.bfloat16)
+    one = _walk(q, kernel_pool, tables, pos, 1)
+    got = _walk(q, kernel_pool, tables, pos, n)
+    np.testing.assert_array_equal(got, one)
+    rk, rv, *rs = ref_pool
+    _assert_close(got, _reference(q, rk, rv, tables, jnp.asarray(pos, jnp.int32), *rs))
+
+
+@pytest.mark.parametrize("n", [1, 2, 4])
+@pytest.mark.parametrize(
+    "t,first,folded",
+    [
+        (4, [2 * PAGE + 3, PAGE - 2, PAGE - 1, 5 * PAGE + 6], False),  # a verify chunk
+        (8, [3, 11, 19, 27], True),  # one cache row folded into sub-rows of 8 queries
+        (8, [PAGE + 5, PAGE + 13, 2 * PAGE + 5, 0], True),  # ... from mid-page, a dead sub-row
+    ],
+    ids=["verify4", "fold8", "fold8-midpage"],
+)
+def test_chunk_with_a_first_position_inside_a_page(n, t, first, folded):
+    """A verify chunk and the folded extend read with first positions
+    in the middle of a page: query ``i`` of a row sees tokens up to
+    ``first + i`` and no further, on the page that holds ``first`` and on
+    every page after it. A token mask left off a page that needed it
+    lets a query read its successors' keys, which the gather's values
+    (and the one-page walk's bits) refuse."""
+    rng = np.random.default_rng(t * 7 + first[0])
+    if folded:  # every sub-row reads the SAME cache row through its own table copy
+        live = [(max(first) + t - 1) // PAGE + 1] * len(first)
+        tables = jnp.asarray(np.tile(1 + np.arange(GROUP_PMAX), (len(first), 1)), jnp.int32)
+        used = GROUP_PMAX + 1
+    else:
+        live = [(p + t - 1) // PAGE + 1 for p in first]
+        tables, used = _group_tables(live)
+    pool = _int8_pool(rng, used, 8)
+    # keys of distinct sizes, so a leaked future token moves the output
+    q = jnp.asarray(4 * rng.standard_normal((len(first), t, 32, Dh)), jnp.bfloat16)
+    got = _walk(q, pool, tables, first, n)
+    k, v, ks, vs = pool
+    ref = np.asarray(_reference(q, k, v, tables, jnp.asarray(first, jnp.int32), ks, vs))
+    rows = np.asarray(first) > 0
+    np.testing.assert_allclose(got[rows], ref[rows], atol=0.02)
+    np.testing.assert_array_equal(got[rows], _walk(q, pool, tables, first, 1)[rows])
+    # the control: the same read one position too far IS told apart
+    late = _walk(q, pool, tables, [p + 1 if p else 0 for p in first], n)
+    assert np.abs(late[rows] - ref[rows]).max() > 0.05
+
+
+def _head_major(pool):
+    return tuple(jnp.swapaxes(a, 1, 2) for a in pool)
+
+
+@pytest.mark.parametrize("n", [1, 2, 4])
+@pytest.mark.parametrize("heads", [(32, 4), (40, 10)], ids=["32-4", "40-10"])
+def test_head_major_pool_over_pages_a_step(heads, n):
+    """The head-major pool ``[P, Hkv, page, Dh]`` (column order ``h *
+    page + t``) at Trinity-Mini's 4 and Phi-4-flash's 10 KV heads: the
+    gather's values at every N, the one-page walk's bits."""
+    hq, hkv = heads
+    pos, live = _group_rows(4)
+    tables, used = _group_tables(live)
+    rng = np.random.default_rng(hq + n)
+    k, v = _bf16_pool(rng, used, hkv)
+    q = jnp.asarray(rng.standard_normal((len(pos), 1, hq, Dh)), jnp.bfloat16)
+    got = _walk(q, _head_major((k, v)), tables, pos, n, head_major=True)
+    _assert_close(got, _reference(q, k, v, tables, jnp.asarray(pos, jnp.int32)))
+    np.testing.assert_array_equal(
+        got, _walk(q, _head_major((k, v)), tables, pos, 1, head_major=True)
+    )
+
+
+@pytest.mark.parametrize("n", [2, 4])
+@pytest.mark.parametrize("t", [1, 4])
+@pytest.mark.parametrize("case", sorted(WALK_CASES))
+def test_grouped_work_list(case, t, n):
+    """The list at N pages a step: ``sum ceil(n_b / N)`` steps for the
+    same live pages; a row's groups start at 0, N, 2N ..; place ``k`` of
+    a step names the table entry of page ``first + k`` where that page is
+    live, and where it is not (the row's last group) the pool page the
+    same place held one step earlier, so nothing is fetched for it."""
+    pos = np.asarray(WALK_CASES[case])
+    tables = _walk_tables(list(pos), t)
+    work = pa.page_work_list(tables, jnp.asarray(pos, jnp.int32), t, PAGE, n)
+    live = np.minimum(pos + t - 1, S - 1) // PAGE + 1
+    groups = -(-live // n)
+    steps = int(work.n_work[0])
+    assert steps == groups.sum()
+    length = len(pos) * (-(-PMAX // n))
+    assert work.row.shape == work.page.shape == (length,)
+    assert work.phys.shape == (length * n,)
+    row, first = np.asarray(work.row)[:steps], np.asarray(work.page)[:steps]
+    np.testing.assert_array_equal(row, np.repeat(np.arange(len(pos)), groups))
+    np.testing.assert_array_equal(first, np.concatenate([n * np.arange(m) for m in groups]))
+    phys = np.asarray(work.phys).reshape(length, n)
+    tab = np.asarray(tables)
+    for i in range(steps):
+        for k in range(n):
+            if first[i] + k < live[row[i]]:  # ascending inside the group
+                assert phys[i, k] == tab[row[i], first[i] + k]
+            elif i and any(first[h] + k < live[row[h]] for h in range(i)):
+                assert phys[i, k] == phys[i - 1, k]  # unchanged block index: no DMA
+            else:
+                assert phys[i, k] == phys[i, 0]
+    assert int(work.row.max()) < len(pos) and int(work.phys.max()) < WALK_POOL
+
+
+@pytest.mark.parametrize("n", [2, 4])
+def test_dead_places_of_a_group_are_never_read(n):
+    """Point every dead place of the list at a pool page of NaNs: the
+    output does not change by a bit."""
+    pos, live = _group_rows(n)
+    tables, used = _group_tables(live)
+    rng = np.random.default_rng(5 + n)
+    k, v, ks, vs = _int8_pool(rng, used + 1, 8)
+    ks, vs = ks.at[used].set(jnp.nan), vs.at[used].set(jnp.nan)
+    q = jnp.asarray(rng.standard_normal((len(pos), 1, 32, Dh)), jnp.bfloat16)
+    posj = jnp.asarray(pos, jnp.int32)
+    work = pa.page_work_list(tables, posj, 1, PAGE, n)
+    want = pa.paged_attention(q, k, v, tables, posj, ks, vs, interpret=True, work=work)
+    place = np.asarray(work.page)[:, None] + np.arange(n)[None, :]
+    dead = place >= np.asarray(live)[np.asarray(work.row)][:, None]
+    assert dead[: int(work.n_work[0])].any()
+    poisoned = work._replace(
+        phys=jnp.where(jnp.asarray(dead).reshape(-1), used, work.phys)
+    )
+    got = pa.paged_attention(q, k, v, tables, posj, ks, vs, interpret=True, work=poisoned)
+    assert bool(jnp.isfinite(got.astype(jnp.float32)).all())
+    np.testing.assert_array_equal(np.asarray(got, np.float32), np.asarray(want, np.float32))
+
+
+def test_the_list_a_latent_read_builds_is_one_page_an_item():
+    """``ops/latent_attention.py`` builds its list without naming N: it
+    is the list of PR 27, one page an item, three maps of one length."""
+    from generativeaiexamples_tpu.ops import latent_attention
+
+    pos = jnp.asarray(WALK_CASES["dead_row_between_live_rows"], jnp.int32)
+    tables = _walk_tables(WALK_CASES["dead_row_between_live_rows"], 1)
+    work = pa.page_work_list(tables, pos, 1, PAGE)
+    assert latent_attention.page_work_list is pa.page_work_list
+    assert work.row.shape == work.page.shape == work.phys.shape == (4 * PMAX,)
+    n = int(work.n_work[0])
+    np.testing.assert_array_equal(
+        np.asarray(work.phys)[:n],
+        np.asarray(tables)[np.asarray(work.row)[:n], np.asarray(work.page)[:n]],
+    )
+    for a, b in zip(work, pa.page_work_list(tables, pos, 1, PAGE, group=1)):
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+
+
+@pytest.mark.parametrize(
+    "shape,dtype,scales,expect",
+    [
+        ((128, 8, 128), "int8", True, RULE["mistral"]),  # quantised: four blocks a page
+        ((4, 128, 128), "bfloat16", False, RULE["trinity"]),  # 262,144 B
+        ((10, 128, 128), "bfloat16", False, RULE["phi4flash"]),  # 655,360 B
+        ((128, 8, 64), "uint8", True, 1),  # the packed int4 pool: quantised pages walk alone
+        ((128, 32, 128), "bfloat16", False, 1),  # 2 MB a pair: bound by its bytes, walks alone
+        ((8, 2, 16), "bfloat16", False, 2),  # the tests' tiny pages
+    ],
+)
+def test_pages_a_step_follow_the_bytes_of_a_page(shape, dtype, scales, expect):
+    """N is a function of what is static alone: the pool's dtype, the
+    bytes a page moves and the queries a row holds (a quantised page and
+    a multi-query page keep their own step)."""
+    k = jax.ShapeDtypeStruct((7,) + shape, dtype)
+    s = jax.ShapeDtypeStruct((7, 128, 8), jnp.float32) if scales else None
+    assert pa.pages_per_step(k, s) == expect
+    assert pa.pages_per_step(k, s, query_len=5) == 1
+    if shape[0] == 128:  # token-major: the engine's host-side form agrees
+        page, hkv, dh = shape
+        dh = dh * 2 if dtype == "uint8" else dh
+        assert pa.pool_pages_per_step(page, hkv, dh, dtype, scales) == expect
 
 
 @pytest.mark.parametrize(
@@ -540,7 +764,8 @@ def test_decode_span_carries_pages_walked_and_the_dense_grid():
     """A served decode dispatch records what the kernel walks at its
     first step (live rows' pages up to the query position, one scratch
     page per empty slot — page_work_list's count, from the host's
-    position shadow) beside the slots x Pmax grid it replaced."""
+    position shadow) beside the slots x Pmax grid it replaced, and the
+    grid steps that carry those pages (``kv_page_steps``)."""
     from generativeaiexamples_tpu.config import EngineConfig
     from generativeaiexamples_tpu.engine import dispatch_timeline as dtl
     from generativeaiexamples_tpu.engine.llm_engine import LLMEngine, SamplingParams
@@ -568,9 +793,15 @@ def test_decode_span_carries_pages_walked_and_the_dense_grid():
         # the next block starts 4 positions on, in the fourth page
         assert [v["kv_pages_walked"] for v in decode[:2]] == [5, 6]
         tables = jnp.zeros((3, 8), jnp.int32)
+        n = eng._kv_pages_a_step
+        assert n == pa.pages_per_step(eng._cache[0]["k"]) == 2
         for pos0, v in zip((20, 24), decode):
             work = pa.page_work_list(tables, jnp.asarray([pos0, 0, 0]), 1, 8)
             assert int(work.n_work[0]) == v["kv_pages_walked"]
+            # ... and the grid steps that carry them, n pages of a row a step
+            steps = pa.page_work_list(tables, jnp.asarray([pos0, 0, 0]), 1, 8, n)
+            assert int(steps.n_work[0]) == v["kv_page_steps"] == 4  # 3 or 4 pages: 2 steps, + 2 empty slots
+            assert v["kv_page_steps"] <= v["kv_pages_walked"]
         # other kinds of span carry no such field
         assert all("kv_pages_walked" not in v for v in spans if v["kind"] != "decode")
     finally:
