@@ -221,6 +221,23 @@ _M_STATE_RESETS = _REG.counter(
     "was reset at admission, inside the prefill or first extend "
     "program; stays 0 for a model whose every layer is paged.",
 )
+_M_PREFIX_STATE_SAVES = _REG.counter(
+    "genai_engine_prefix_state_saves_total",
+    "Prefix entries whose fixed-state row was copied out of an admitting "
+    "slot, between two chunks of its prefill (a family with "
+    "state_row_keys: docs/prefix_cache.md).",
+)
+_M_PREFIX_STATE_RESTORES = _REG.counter(
+    "genai_engine_prefix_state_restores_total",
+    "Prefix hits whose saved fixed-state row was copied into the "
+    "admitted slot before its first uncached chunk, in place of the "
+    "slot's reset.",
+)
+_M_PREFIX_STATE_BYTES = _REG.counter(
+    "genai_engine_prefix_state_bytes_total",
+    "Bytes of fixed per-slot state copied between slot rows and store "
+    "rows (one row's bytes a save or a restore).",
+)
 _M_CROSS_SKIPPED = _REG.counter(
     "genai_engine_prefill_cross_skipped_tokens_total",
     "Prompt tokens for which the layers past the shared-KV layer were "
@@ -375,6 +392,10 @@ class _Request:
     # the entry — radix partial match).
     prefix_entry: Optional[object] = None
     prefix_len: int = 0
+    # the entry this request entered through, kept (unpinned) past the
+    # funding step for a family whose entries carry a fixed-state row:
+    # the row to restore from, and the ticket a deeper insert takes over
+    prefix_via: Optional[object] = None
     # Flight-recorder record captured at submit: slot release (where the
     # paged layout frees the request's pages) happens AFTER finish_rid
     # unmaps the rid, so the page_free event must reach the record
@@ -777,8 +798,17 @@ class LLMEngine:
         kv_pages_mod.validate_runtime(
             cfg.page_size, self.max_seq_len, self._pool_pages
         )
+        # A fixed-state family the prefix store can carry keeps the
+        # store's rows BEHIND the decode slots' in the same per-slot
+        # arrays: ticket k of the index is row num_slots + k, and saving
+        # or restoring a prefix's state is one row copied to another
+        # (_build_steps ``prefix_state_copy``).
+        self._state_store_rows = (
+            prefix_slots if self._fixed_state and family.state_row_keys else 0
+        )
         pool = family.init_paged_cache(
-            model_cfg, self._pool_pages, cfg.page_size, self.num_slots,
+            model_cfg, self._pool_pages, cfg.page_size,
+            self.num_slots + self._state_store_rows,
             dtype, quantized=self._kv_quant, packed=self._kv_packed,
         )
         if self._mesh.size > 1:
@@ -820,7 +850,8 @@ class LLMEngine:
         )
         if self._fixed_state:
             plan = kv_pages_mod.cache_plan(
-                self._pool_pages, cfg.page_size, self.num_slots,
+                self._pool_pages, cfg.page_size,
+                self.num_slots + self._state_store_rows,
                 paged_bytes_per_token=(
                     self._kv_shape.bytes_per_token
                     or kv_pages_mod.page_bytes(
@@ -835,11 +866,14 @@ class LLMEngine:
             )
             logger.info(
                 "fixed per-slot state beside the pool: %.1f MB a slot x "
-                "%d slots = %.2f GB (pool %.2f GB for %d paged layer(s))",
+                "(%d slots + %d prefix-store rows) = %.2f GB (pool %.2f GB "
+                "for %d paged layer(s))",
                 plan.fixed_bytes_per_slot / 1e6, self.num_slots,
+                self._state_store_rows,
                 plan.fixed_bytes / 1e9, plan.paged_bytes / 1e9,
                 self._kv_shape.num_layers,
             )
+            self._state_row_bytes = plan.fixed_bytes_per_slot
         # The ragged page kernel (ops/page_attention.py), resolved per
         # executable family: decode (single-query rows) and spec verify
         # (K+1-wide rows), each behind its geometry probe with a LOUD
@@ -907,7 +941,9 @@ class LLMEngine:
         mesh_size = mesh.size if mesh is not None else 1
         if cfg.tensor_parallelism > 1 or mesh_size > 1:
             refuse("a sharded mesh", "set tensor_parallelism=1")
-        prefix_cache_mod.require_paged_state(name, cfg)
+        prefix_cache_mod.require_paged_state(
+            name, cfg, self._family.state_row_keys
+        )
         spec_decode_mod.require_paged_state(name, cfg)
         if cfg.quantization not in ("", "none"):
             refuse(f"quantization={cfg.quantization!r} (no packed walk)",
@@ -1337,11 +1373,14 @@ class LLMEngine:
         self._prefix = prefix_cache_mod.PrefixCache(
             chunk=cfg.prefill_chunk, slots=P, max_len=self.max_seq_len,
             on_drop=self._drop_prefix_pages,
+            stateful=self._state_store_rows > 0,
         )
         logger.info(
             "prefix KV cache enabled (zero-copy): %d entries over the "
-            "shared page pool (chunk %d)",
+            "shared page pool (chunk %d)%s",
             P, cfg.prefill_chunk,
+            "; each carries a fixed-state row behind the slots'"
+            if self._state_store_rows else "",
         )
 
     def _drop_prefix_pages(self, entry) -> None:
@@ -1417,6 +1456,8 @@ class LLMEngine:
                     self._kv_alloc.retain(shared)
                 self._prefix.release(ent)
                 req.prefix_entry = None
+                if self._state_store_rows:
+                    req.prefix_via = ent
             total = kv_pages_mod.pages_needed(
                 len(req.prompt_ids), req.params.max_tokens, page,
                 self.max_seq_len, self._page_slack,
@@ -1443,6 +1484,7 @@ class LLMEngine:
                             self._prefix.release(r.prefix_entry)
                             r.prefix_entry = None
                         r.prefix_len = 0
+                        r.prefix_via = None
                         self._free_slots.append(r.slot)
                         r.slot = -1
                         self._pending.appendleft(r)
@@ -1576,10 +1618,16 @@ class LLMEngine:
         # as additional batch slots (the auto-layout gate isn't resolved
         # yet, so this can only over-estimate).
         extra_slots = _prefix_store_extra_slots(cfg)
+        rows = cfg.max_batch_size + extra_slots
+        seq = min(cfg.max_seq_len, model_cfg.max_seq_len)
+        if cfg.kv_pool_pages > 0:
+            # an explicit pool is what is paged, whatever rows x capacity
+            # would come to (a fixed state is still counted a row)
+            seq = min(seq, -(-cfg.kv_pool_pages * cfg.page_size // rows))
         est = serving_memory_bytes(
             model_cfg,
-            cfg.max_batch_size + extra_slots,
-            min(cfg.max_seq_len, model_cfg.max_seq_len),
+            rows,
+            seq,
             weight_bytes=wbytes,
             kv_bytes=kvbytes,
         )
@@ -1976,10 +2024,32 @@ class LLMEngine:
                 extend_batch_paged, donate_argnums=(1,), static_argnums=(8,)
             ),
         )
+        state_row_keys = tuple(fam.state_row_keys)
+
+        def prefix_state_copy(caches, rows):
+            # Row ``rows[0]`` of every fixed-state leaf to row ``rows[1]``
+            # (a slot's state out to a prefix entry's store row, or
+            # back): in place, the cache donated. The small second output
+            # is what the dispatch watcher awaits (nothing donates it).
+            src, dst = rows[0], rows[1]
+            new = dict(caches)
+            for key in state_row_keys:
+                new[key] = jax.tree.map(
+                    lambda leaf: leaf.at[dst].set(leaf[src]), caches[key]
+                )
+            return new, rows + 0
+
+        self._copy_state_fn = None
+        if self._state_store_rows:
+            self._copy_state_fn = wrap(
+                "prefix_state_copy",
+                jax.jit(prefix_state_copy, donate_argnums=(0,)),
+            )
         self._finish_fn = wrap("finish", jax.jit(finish_batch))
         self._put_rows_fn = wrap("put_rows", jax.jit(put_rows))
         self._zero_carries: Dict[int, object] = {}  # _zero_hidden's, by rows
         self._wave_stats: list = []  # (span fields, device counts) of a wave's chunks
+        self._wave_entries: list = []  # stateful prefix entries the wave in flight inserted
         self._spec_verify_fn = wrap(
             "spec_verify",
             jax.jit(
@@ -2720,6 +2790,11 @@ class LLMEngine:
                     self._lock.wait(timeout=0.2)
                 if not self._running:
                     return
+            if self._copy_state_fn is not None:
+                # the state copy of a prefix save / restore: a store
+                # row onto itself, a no-op on the values
+                rows = jnp.asarray(np.full((2,), self.num_slots, np.int32))
+                self._cache, _ = self._copy_state_fn(self._cache, rows)
             for n in row_rungs:
                 zeros_n = jnp.zeros((n,), jnp.int32)
                 last_h = self._zero_hidden(n)
@@ -3238,6 +3313,7 @@ class LLMEngine:
 
         chunk = self.engine_config.prefill_chunk
         records: List[object] = []
+        self._wave_entries = []  # stateful prefix entries this wave inserts
 
         # Prefix-cache matching (chunked waves only — a monolithic wave
         # means every prompt fits one chunk, below the smallest
@@ -3491,6 +3567,8 @@ class LLMEngine:
             # from _free_slots forever, their clients would hang to
             # the queue timeout, and any pinned prefix entries
             # would stay refcounted for the process lifetime.
+            for ent in self._wave_entries:
+                self._prefix.discard(ent)  # a failed admission leaves no entry
             with self._lock:
                 for req in group:
                     if self._slot_req.get(req.slot) is req:
@@ -3530,7 +3608,7 @@ class LLMEngine:
         # appends at positions >= T, never rewriting [0:cached]).
         # Skipped when the prefix is already cached at full depth
         # or every entry ticket is pinned by a live request.
-        if use_chunked and self._prefix is not None:
+        if use_chunked and self._prefix is not None and not self._state_store_rows:
             for req in group:
                 # Zero-copy insert: donate the request's own
                 # prompt pages (refcount bump) — the entry and
@@ -3543,18 +3621,75 @@ class LLMEngine:
                 ent = self._prefix.insert_entry(
                     req.prompt_ids, hint=req.params.prefix_hint
                 )
-                if ent is None:
-                    continue
-                page = self.engine_config.page_size
-                # paged_stats() reads this dict from scraper
-                # threads under the lock; the donate read takes
-                # it too (the PR 7 review pattern).
-                with self._lock:
-                    pages = list(self._slot_pages.get(req.slot, ()))
-                donated = pages[: ent.length // page]
-                self._kv_alloc.retain(donated)
-                ent.pages = list(donated)
+                if ent is not None:
+                    self._donate_prefix_pages(req, ent)
         return records
+
+    def _donate_prefix_pages(self, req: _Request, ent) -> None:
+        """A new prefix entry takes a reference on the request's own
+        prompt pages below the entry's depth."""
+        page = self.engine_config.page_size
+        # paged_stats() reads this dict from scraper threads under the
+        # lock; the donate read takes it too (the PR 7 review pattern).
+        with self._lock:
+            pages = list(self._slot_pages.get(req.slot, ()))
+        donated = pages[: ent.length // page]
+        self._kv_alloc.retain(donated)
+        ent.pages = list(donated)
+
+    def _insert_prefix_state(self, req: _Request) -> None:
+        """Between two chunks of ``req``'s admission, right after the one
+        that ends at the depth the index names for its prompt: insert
+        the entry, donate the pages below that depth and copy the slot's
+        fixed state into the entry's store row. The entry can be matched
+        once that copy is enqueued; a cancelled request inserts nothing,
+        and a wave that fails later drops what it inserted
+        (``_prefill_wave``)."""
+        if req.cancelled:
+            return
+        ent = self._prefix.insert_entry(
+            req.prompt_ids, hint=req.params.prefix_hint, via=req.prefix_via
+        )
+        if ent is None:
+            return
+        self._wave_entries.append(ent)
+        self._donate_prefix_pages(req, ent)
+        self._copy_prefix_state(
+            "save", req, req.slot, self.num_slots + ent.store_slot, ent.length
+        )
+        self._prefix.mark_ready(ent)
+
+    def _copy_prefix_state(self, what: str, req: _Request, src: int,
+                           dst: int, depth: int) -> None:
+        """Enqueue the copy of one row of every fixed-state leaf to
+        another (``what``: 'save' slot -> store row, 'restore' store row
+        -> slot), with its span and counters."""
+        import jax.numpy as jnp
+
+        _dtl = self._dtl
+        t_wall, t0 = time.time(), time.perf_counter()
+        with self._dispatch_lock, self._annotate(f"engine.prefix_state_{what}"):
+            t1 = time.perf_counter()
+            self._cache, done = self._copy_state_fn(
+                self._cache, jnp.asarray(np.array([src, dst], np.int32))
+            )
+        nbytes = self._state_row_bytes
+        store_row = (dst if what == "save" else src) - self.num_slots
+        (_M_PREFIX_STATE_SAVES if what == "save"
+         else _M_PREFIX_STATE_RESTORES).inc()
+        _M_PREFIX_STATE_BYTES.inc(nbytes)
+        flight_recorder.event_rid(
+            req.rid, f"prefix_state_{what}", store_row=store_row,
+            depth_tokens=depth, bytes=nbytes,
+        )
+        if _dtl is not None:
+            _dtl.record_span(
+                f"prefix_state_{what}", t_wall=t_wall, lock_wait_s=t1 - t0,
+                run_s=time.perf_counter() - t1, rows=1, rids=[req.rid],
+                counters={"bytes": nbytes, "store_row": store_row,
+                          "depth_tokens": depth},
+                handle=done,
+            )
 
     def _import_handoff(self, rec) -> None:
         """Decode-tier import of a prefill-tier handoff (the disagg
@@ -3625,6 +3760,7 @@ class LLMEngine:
                 req.slot = -1
                 req.t_admit = 0.0
                 req.prefix_len = 0
+                req.prefix_via = None
                 self._pending.appendleft(req)
                 self._lock.notify_all()
                 return
@@ -3694,6 +3830,23 @@ class LLMEngine:
         self._wave_stats = []
         last_h = self._zero_hidden(Np)
         dispatched = 0
+        # A family whose prefix entries carry a fixed-state row: a hit's
+        # saved state goes into its slot BEFORE its first uncached chunk
+        # (which carries the slot's state on, offsets > 0), and the
+        # state is saved right after the chunk that ends at the depth
+        # the index will name for the prompt. Device order is dispatch
+        # order, so each copy sits between the chunks around it.
+        save_at: Dict[int, int] = {}
+        if self._state_store_rows and reqs is not None and cached is not None:
+            for i, req in enumerate(reqs):
+                if cached[i] > 0 and req.prefix_via is not None:
+                    self._copy_prefix_state(
+                        "restore", req, self.num_slots + req.prefix_via.store_slot,
+                        req.slot, int(cached[i]),
+                    )
+                depth = self._prefix.cacheable_len(len(req.prompt_ids))
+                if depth > cached[i]:
+                    save_at[i] = depth
         for k in range(K):
             valid = np.clip(lengths - k * C, 0, C).astype(np.int32)
             if cached is not None:
@@ -3820,6 +3973,9 @@ class LLMEngine:
                         reqs[i].rid, "prefill_chunk", chunk=k, window=W,
                         tokens=int(valid[i]), width=width,
                     )
+            for i in live:
+                if save_at.get(i) == (k + 1) * C:
+                    self._insert_prefix_state(reqs[i])
         src = np.arange(Np, dtype=np.int32)
         src[n_real:] = 0
         first = self._finish_fn(

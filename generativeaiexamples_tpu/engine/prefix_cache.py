@@ -16,6 +16,19 @@ of that optimization for the TPU engine:
   hold the prefix's KV rows (the engine sets ``entry.pages``; this
   module never touches jax), each holding one of ``slots`` entry-count
   tickets (``store_slot``) that bound the index;
+- for a model family whose slot also holds a FIXED STATE (a recurrent
+  state beside the pages: models/registry.py ``state_row_keys``) the
+  index is built ``stateful``: ticket ``k`` is then ALSO row
+  ``num_slots + k`` of the family's per-slot arrays, where the engine
+  copies the slot's state as it stood at the entry's depth (between two
+  chunks of the admission that inserts it) and from where a hit copies
+  it back before the first uncached chunk. Such an entry serves ITS
+  depth only: a match returns the deepest depth on the prompt's path
+  that HAS an entry whose copy is enqueued (``mark_ready``), never a
+  shallower prefix of a deeper entry (its pages would be there, the
+  state at that depth is not). An insert takes over the ticket of the
+  entry its request entered through (``via``), so a conversation keeps
+  one row however many turns it has; everything else is LRU;
 - **refcounts** pinning a matched entry across the match → page-map
   window, so LRU eviction cannot drop an entry whose pages an
   admission is about to retain;
@@ -74,6 +87,11 @@ _M_SLOTS_IN_USE = _REG.gauge(
     "genai_engine_prefix_cache_slots_in_use",
     "Reserved store slots currently holding a cached prefix entry.",
 )
+_M_STATE_ROWS = _REG.gauge(
+    "genai_engine_prefix_state_rows_in_use",
+    "Store rows of a fixed-state family's per-slot arrays that hold the "
+    "state of a cached prefix (a stateful index: one row an entry).",
+)
 _M_SLOTS_CAPACITY = _REG.gauge(
     "genai_engine_prefix_cache_slots_capacity",
     "Configured prefix-cache store slot count (prefix_cache_slots).",
@@ -90,16 +108,24 @@ def metrics_snapshot() -> Dict[str, float]:
     }
 
 
-def require_paged_state(model: str, cfg) -> None:
+def require_paged_state(model: str, cfg, state_row_keys: Sequence[str] = ()) -> None:
     """A prefix hit maps cached PAGES into a new request's table and
     skips the cached chunks' prefill. A model with fixed per-slot state
     (a recurrent state, a window ring: models/registry.py) would start
-    its suffix from a state nobody saved — refuse it at engine build."""
+    its suffix from a state nobody saved, UNLESS its family names the
+    leaves that hold one row a slot (``state_row_keys``): an entry then
+    carries a copy of those rows beside its pages. A family that names
+    none (a window ring's rows are no state at one depth) is refused at
+    engine build."""
+    if state_row_keys:
+        return
     if cfg.prefix_cache_enable != "off" and cfg.prefix_cache_slots > 0:
         raise ValueError(
             f"{model} keeps a fixed per-slot state beside the page pool, "
-            "which prefix-cache reuse cannot carry (an entry holds pages, "
-            "not the recurrent state at the prefix's end); set "
+            "which prefix-cache reuse can carry only for a family that "
+            "registers state_row_keys (models/registry.py: the leaves that "
+            "hold one row a slot, copied to a store row beside an entry's "
+            "pages); this family registers none; set "
             "prefix_cache_enable='off'"
         )
 
@@ -120,22 +146,25 @@ class PrefixEntry:
     refcount is what keeps the rows alive). ``store_slot`` is the
     entry's ticket out of ``prefix_cache_slots``."""
 
-    __slots__ = ("store_slot", "length", "refs", "last_use", "node", "pages")
+    __slots__ = ("store_slot", "length", "refs", "last_use", "node", "pages", "ready")
 
-    def __init__(self, store_slot: int, length: int, node: _Node) -> None:
+    def __init__(self, store_slot: int, length: int, node: _Node, ready: bool = True) -> None:
         self.store_slot = store_slot
         self.length = length
         self.refs = 0
         self.last_use = 0
         self.node = node
         self.pages = None  # List[int] of pool pages
+        # a stateful index: False until the copy of the slot's state into
+        # the entry's store row is enqueued (``mark_ready``)
+        self.ready = ready
 
 
 class PrefixCache:
     """Radix index over chunk-aligned token prefixes → entries."""
 
     def __init__(self, chunk: int, slots: int, max_len: int,
-                 on_drop=None) -> None:
+                 on_drop=None, stateful: bool = False) -> None:
         if chunk <= 0 or slots <= 0 or max_len <= 0:
             raise ValueError(
                 f"PrefixCache needs positive chunk/slots/max_len, got "
@@ -144,6 +173,9 @@ class PrefixCache:
         self.chunk = chunk
         self.capacity = slots
         self.max_len = max_len
+        # an entry holds a fixed-state row beside its pages and serves
+        # its own depth only (module docstring)
+        self.stateful = stateful
         # Called (under the cache lock) with every entry that leaves the
         # index — LRU eviction, slot invalidation, subsumed-ancestor
         # consolidation. The paged engine hooks this to release the
@@ -159,6 +191,7 @@ class PrefixCache:
         _M_ROWS_UTIL.set(0.0)
         _M_SLOTS_IN_USE.set(0)
         _M_SLOTS_CAPACITY.set(slots)
+        _M_STATE_ROWS.set(0)
 
     # -- internals (caller holds self._lock) ---------------------------- #
     def _cap(self, n: int) -> int:
@@ -219,6 +252,30 @@ class PrefixCache:
         used = sum(e.length for e in self._entries)
         _M_ROWS_UTIL.set(used / (self.capacity * self.max_len))
         _M_SLOTS_IN_USE.set(self.capacity - len(self._free))
+        if self.stateful:
+            _M_STATE_ROWS.set(self.capacity - len(self._free))
+
+    def _deepest_ready(self, ids: Sequence[int], cap: int) -> Optional[PrefixEntry]:
+        """The deepest entry ON ``ids``' path (up to ``cap`` tokens)
+        whose state copy is enqueued. Caller holds self._lock."""
+        node, best = self._root, None
+        for key in self._spans(ids, cap):
+            node = node.children.get(key)
+            if node is None:
+                break
+            if node.entry is not None and node.entry.ready:
+                best = node.entry
+        return best
+
+    def _remove(self, entry: PrefixEntry) -> None:
+        """Take ``entry`` out of the index (not its ticket: the caller
+        frees or hands it on). Caller holds self._lock."""
+        entry.node.entry = None
+        self._entries.remove(entry)
+        if self._on_drop is not None:
+            self._on_drop(entry)
+        for hint in [h for h, e in self._hints.items() if e is entry]:
+            del self._hints[hint]
 
     def _evict_one(self) -> Optional[int]:
         """Free the LRU unpinned entry's store slot; None if every entry
@@ -229,16 +286,16 @@ class PrefixCache:
         if not victims:
             return None
         victim = min(victims, key=lambda e: e.last_use)
-        victim.node.entry = None
-        self._entries.remove(victim)
-        if self._on_drop is not None:
-            self._on_drop(victim)
-        for hint in [h for h, e in self._hints.items() if e is victim]:
-            del self._hints[hint]
-        # Prune now-useless trie branches (no entry anywhere below):
-        # partial matches resolve through subtree entries, so childless
-        # entry-less nodes can never serve one again.
-        node = victim.node
+        self._remove(victim)
+        self._prune(victim.node)
+        _M_EVICTIONS.inc()
+        return victim.store_slot
+
+    @staticmethod
+    def _prune(node: Optional[_Node]) -> None:
+        """Drop now-useless trie branches (no entry anywhere below):
+        partial matches resolve through subtree entries, so childless
+        entry-less nodes can never serve one again."""
         while (
             node is not None
             and node.parent is not None
@@ -251,8 +308,6 @@ class PrefixCache:
                     del parent.children[key]
                     break
             node = parent
-        _M_EVICTIONS.inc()
-        return victim.store_slot
 
     # -- engine-facing API ---------------------------------------------- #
     def match(self, ids: Sequence[int],
@@ -270,8 +325,11 @@ class PrefixCache:
             if cap <= 0:
                 return None
             self._tick += 1
-            node, depth = self._walk(ids, cap)
-            entry = self._subtree_entry(node) if depth > 0 else None
+            if self.stateful:
+                entry, depth = self._deepest_ready(ids, cap), cap
+            else:
+                node, depth = self._walk(ids, cap)
+                entry = self._subtree_entry(node) if depth > 0 else None
             if entry is None:
                 _M_MISSES.inc()
                 return None
@@ -323,16 +381,44 @@ class PrefixCache:
             return None
         return entry.store_slot, entry.length
 
-    def insert_entry(self, ids: Sequence[int],
-                     hint: Optional[str] = None) -> Optional[PrefixEntry]:
+    def cacheable_len(self, n: int) -> int:
+        """The depth ``insert_entry`` names for an ``n``-token prompt (0: none)."""
+        return self._cap(n)
+
+    def mark_ready(self, entry: PrefixEntry) -> None:
+        """A stateful entry's state copy is enqueued: it may be matched."""
+        with self._lock:
+            entry.ready = True
+
+    def discard(self, entry: PrefixEntry) -> None:
+        """Drop an entry its admission could not complete (no eviction
+        counted); a no-op where it already left the index."""
+        with self._lock:
+            if entry.node.entry is entry:
+                self._remove(entry)
+                self._prune(entry.node)
+                self._free.append(entry.store_slot)
+                self._update_gauge()
+
+    def insert_entry(self, ids: Sequence[int], hint: Optional[str] = None,
+                     via: Optional[PrefixEntry] = None) -> Optional[PrefixEntry]:
         """``insert`` returning the entry itself — the paged engine
         needs it to attach the donated page list (``entry.pages``)
-        instead of running a slot->store copy program."""
+        instead of running a slot->store copy program. A stateful index
+        hands back an entry that is not ``ready``: the engine enqueues
+        the state copy into its row, then calls ``mark_ready``; ``via``
+        is the entry the request entered through, whose ticket (and
+        row) the new entry takes over where it lies on the same path
+        and nobody else holds it."""
         with self._lock:
             cap = self._cap(len(ids))
             if cap <= 0:
                 return None
             have, depth = self._walk(ids, cap)
+            if self.stateful:
+                if depth >= cap and have.entry is not None:
+                    return None  # this depth has its state already
+                return self._insert_at(ids, cap, hint, self._on_path(via, ids, cap))
             sub = self._subtree_entry(have)
             if depth >= cap and sub is not None:
                 return None  # every cacheable row already served
@@ -357,47 +443,59 @@ class PrefixCache:
                 and depth * 2 >= cap
             ):
                 return None
-            node = self._root
-            subsumed: List[PrefixEntry] = []
-            for key in self._spans(ids, cap):
-                child = node.children.get(key)
-                if child is None:
-                    child = _Node(parent=node)
-                    node.children[key] = child
-                node = child
-                if child.entry is not None and child.entry.refs == 0:
-                    subsumed.append(child.entry)
-            # Consolidate unpinned ANCESTOR entries along this path: the
-            # new deeper entry serves every prefix they served (partial
-            # matching), so their slots are pure duplication — reclaim
-            # them instead of LRU-evicting other chains' preambles (a
-            # growing multi-turn conversation would otherwise fill the
-            # store with nested copies of itself). Not counted as
-            # evictions: no cached content becomes unservable.
-            for dup in subsumed:
-                dup.node.entry = None
-                self._entries.remove(dup)
-                if self._on_drop is not None:
-                    self._on_drop(dup)
-                for h in [h for h, e in self._hints.items() if e is dup]:
-                    del self._hints[h]
-                self._free.append(dup.store_slot)
-            if self._free:
-                slot = self._free.pop()
-            else:
-                slot = self._evict_one()
-                if slot is None:
-                    self._update_gauge()
-                    return None
-            self._tick += 1
-            entry = PrefixEntry(slot, cap, node)
-            entry.last_use = self._tick
-            node.entry = entry
-            self._entries.append(entry)
-            if hint:
-                self._bind_hint(hint, entry)
-            self._update_gauge()
-            return entry
+            return self._insert_at(ids, cap, hint, None)
+
+    def _on_path(self, via: Optional[PrefixEntry], ids: Sequence[int], cap: int) -> List[PrefixEntry]:
+        """``[via]`` where it is still indexed, unpinned and an ancestor
+        of ``ids``' depth ``cap``; else nothing. Caller holds self._lock."""
+        if via is None or via.refs or via.node.entry is not via or via.length >= cap:
+            return []
+        node, _ = self._walk(ids, via.length)
+        return [via] if node is via.node else []
+
+    def _insert_at(self, ids: Sequence[int], cap: int, hint: Optional[str],
+                   takeover: Optional[List[PrefixEntry]]) -> Optional[PrefixEntry]:
+        """Create the entry at depth ``cap`` of ``ids``' path. ``takeover``
+        None: every unpinned ancestor entry on the path is consolidated
+        into it; a list: exactly those. Caller holds self._lock."""
+        node = self._root
+        subsumed: List[PrefixEntry] = []
+        for key in self._spans(ids, cap):
+            child = node.children.get(key)
+            if child is None:
+                child = _Node(parent=node)
+                node.children[key] = child
+            node = child
+            if takeover is None and child.entry is not None and child.entry.refs == 0:
+                subsumed.append(child.entry)
+        if takeover is not None:
+            subsumed = takeover
+        # Consolidate unpinned ANCESTOR entries along this path: the
+        # new deeper entry serves every prefix they served (partial
+        # matching), so their slots are pure duplication — reclaim
+        # them instead of LRU-evicting other chains' preambles (a
+        # growing multi-turn conversation would otherwise fill the
+        # store with nested copies of itself). Not counted as
+        # evictions: no cached content becomes unservable.
+        for dup in subsumed:
+            self._remove(dup)
+            self._free.append(dup.store_slot)
+        if self._free:
+            slot = self._free.pop()
+        else:
+            slot = self._evict_one()
+            if slot is None:
+                self._update_gauge()
+                return None
+        self._tick += 1
+        entry = PrefixEntry(slot, cap, node, ready=not self.stateful)
+        entry.last_use = self._tick
+        node.entry = entry
+        self._entries.append(entry)
+        if hint:
+            self._bind_hint(hint, entry)
+        self._update_gauge()
+        return entry
 
     # -- introspection --------------------------------------------------- #
     def stats(self) -> Dict[str, float]:

@@ -459,11 +459,15 @@ def _embed_streams(params: Params, cfg: Glm5NextConfig, tokens):
 # KDA
 
 
-def _kda_inputs(x, conv_cat, lp: Params, cfg: Glm5NextConfig):
+def _kda_inputs(x, conv_cat, lp: Params, cfg, beta_scale: float = 1.0, lower_bound: Optional[float] = None):
     """From the normed input x [.., T, D] and the convolution's input
     ``conv_cat`` [.., T + conv - 1, 3K] (the tail, then this call's
     projections): q, k, v [.., T, H, Dk], beta [.., T, H], g [.., T, H, Dk]
-    (log decay, <= 0), the output gate [.., T, K]. float32."""
+    (log decay, <= 0), the output gate [.., T, K]. float32. ``beta`` is
+    ``beta_scale * sigmoid`` (2: the transition's eigenvalue along k may
+    be negative); ``lower_bound`` clamps the log decay (None: no clamp).
+    ``cfg`` is any configuration with the KDA sizes (``models/solaropen2.py``
+    calls this too)."""
     H, Dk, r = cfg.num_heads, cfg.kda_head_dim, cfg.kda_rank
     T = x.shape[-2]
     w = lp["conv_w"]
@@ -474,9 +478,12 @@ def _kda_inputs(x, conv_cat, lp: Params, cfg: Glm5NextConfig):
     k = k * lax.rsqrt(jnp.sum(k * k, axis=-1, keepdims=True) + 1e-6)
     small = _mm(x, lp["wbfg"])
     beta = jax.nn.sigmoid(small[..., :H])
+    if beta_scale != 1.0:
+        beta = beta_scale * beta
     f = _mm(small[..., H:H + r], lp["wf2"]) + lp["dt_bias"]
     g = -jnp.exp(lp["A_log"])[:, None] * jax.nn.softplus(f).reshape(f.shape[:-1] + (H, Dk))
-    g = jnp.maximum(g, cfg.gate_lower_bound)
+    if lower_bound is not None:
+        g = jnp.maximum(g, lower_bound)
     gate = jax.nn.sigmoid(_mm(small[..., H + r:], lp["wg2"]))
     return q, k, v, beta, g, gate
 
@@ -722,7 +729,7 @@ def _chunk_walk(params: Params, cfg: Glm5NextConfig, caches: Caches, tokens, off
                     proj = _mm(x, lp["wqkv"])
                     tail = jnp.where(started[:, None, None], old_tail.astype(jnp.float32), 0.0)
                     cat = jnp.concatenate([tail, proj], axis=1)
-                    q, k, v, beta, g, gate = _kda_inputs(x, cat, lp, cfg)
+                    q, k, v, beta, g, gate = _kda_inputs(x, cat, lp, cfg, lower_bound=cfg.gate_lower_bound)
                     beta = jnp.where(tok_valid[..., None], beta, 0.0)
                     g = jnp.where(tok_valid[..., None, None], g, 0.0)
                     S0 = jnp.where(started[:, None, None, None], old_S, 0.0).astype(jnp.float32)
@@ -837,7 +844,7 @@ def decode_paged(params: Params, cfg: Glm5NextConfig, caches: Caches, tokens, po
                     old_S, old_tail = caches["kda"][i], caches["conv"][i]
                     proj = _mm(x, lp["wqkv"])
                     cat = jnp.concatenate([old_tail.astype(jnp.float32), proj[:, None]], axis=1)
-                    q, k, v, beta, g, gate = _kda_inputs(x[:, None], cat, lp, cfg)
+                    q, k, v, beta, g, gate = _kda_inputs(x[:, None], cat, lp, cfg, lower_bound=cfg.gate_lower_bound)
                     step = (q[:, 0], k[:, 0], v[:, 0], beta[:, 0], g[:, 0])
                     keep = live[:, None, None]
                     if delta_step:
@@ -913,7 +920,7 @@ def forward_full(params: Params, cfg: Glm5NextConfig, tokens):
             def mix(x, lp=lp):
                 proj = _mm(x, lp["wqkv"])
                 cat = jnp.pad(proj, ((0, 0), (cfg.kda_conv - 1, 0), (0, 0)))
-                q, k, v, beta, g, gate = _kda_inputs(x, cat, lp, cfg)
+                q, k, v, beta, g, gate = _kda_inputs(x, cat, lp, cfg, lower_bound=cfg.gate_lower_bound)
 
                 def step(S, xs):
                     o, S = kda_step(S, *xs)

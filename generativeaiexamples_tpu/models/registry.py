@@ -61,11 +61,15 @@ that carry the same names).
 
 ``fixed_state`` declares that a slot holds state that is NOT pages (a
 recurrent state, a window ring). Everything in the engine that assumes
-"a slot's state is its pages" — prefix-cache reuse, speculative verify,
-request snapshots, the fixed / slab / scan / pipeline layouts, tensor
-parallelism, quantised weights and KV — refuses such a family at engine
-build (docs/model_registry.md); ``span_fields`` are the constant counts
-it adds to the dispatch-timeline spans.
+"a slot's state is its pages" — speculative verify, request snapshots,
+the fixed / slab / scan / pipeline layouts, tensor parallelism,
+quantised weights and KV — refuses such a family at engine build
+(docs/model_registry.md); ``span_fields`` are the constant counts it
+adds to the dispatch-timeline spans. Prefix-cache reuse is refused too
+UNLESS the family names ``state_row_keys``: the leaves of its cache that
+hold one row a slot, which a prefix entry then carries beside its pages
+(a store row saved between two chunks of an admission, copied back on a
+hit: docs/prefix_cache.md).
 
 ``llama`` registers through the same door: its presets dict is
 ``llama.PRESETS`` itself (so a preset written there at run time, as the
@@ -120,6 +124,14 @@ class ModelFamily:
     # the chunk walk over a packed token axis; None: waves go out as
     # [rows, width] rectangles through ``extend_paged``
     extend_packed: Optional[Callable[..., Tuple[Any, Any]]] = None
+    # a fixed-state family the prefix store can carry: the top-level keys
+    # of the cache pytree whose every leaf holds ONE ROW A SLOT, first
+    # axis. ``init_paged_cache`` is then asked for the decode slots plus
+    # ``prefix_cache_slots`` rows, the walks touch the rows their
+    # ``slots`` name (decode: the first ``B``), and the engine saves and
+    # restores a prefix's state by copying one row of these leaves to
+    # another. Empty: the store is refused (engine/prefix_cache.py)
+    state_row_keys: Tuple[str, ...] = ()
 
 
 _FAMILIES: Dict[str, ModelFamily] = {}
@@ -331,6 +343,33 @@ def _afmoe_family() -> ModelFamily:
     )
 
 
+def _solaropen2_family() -> ModelFamily:
+    from generativeaiexamples_tpu.models import solaropen2 as m
+
+    def init_paged_cache(cfg, pool_pages, page_size, num_slots, dtype, quantized=False, packed=False):
+        if quantized or packed:
+            raise ValueError("solaropen2 keeps its paged softmax layers and its fixed state in bfloat16")
+        return m.init_paged_cache(cfg, pool_pages, page_size, num_slots, dtype)
+
+    return ModelFamily(
+        name="solaropen2", presets=m.PRESETS, config_type=m.SolarOpen2Config, fixed_state=True,
+        init_params=m.init_params_fast, init_paged_cache=init_paged_cache,
+        prefill_paged=m.prefill_paged, extend_paged=m.extend_paged, decode_paged=m.decode_paged,
+        verify_paged=None, head=lambda params, cfg, hidden, **_: m.head(params, cfg, hidden),
+        serving_memory_bytes=m.serving_memory_bytes, count_logical_params=m.count_logical_params,
+        # the softmax layers alone are paged, plain GQA in head-major pages
+        paged_kv_shape=lambda cfg: PagedKVShape(
+            len(cfg.layers_of("full")), cfg.num_kv_heads, cfg.head_dim, cfg.num_heads),
+        fixed_state_bytes_per_slot=m.fixed_state_bytes_per_slot,
+        span_fields=lambda cfg: {"kda_layers": len(cfg.layers_of("kda")),
+                                 "kv_readers": len(cfg.layers_of("full"))},
+        resolve_kernels=lambda cfg, kind: {"grouped_matmul": kind, "delta_step": kind},
+        stat_names=m.STAT_NAMES, read_stats=m.read_stats, extend_reads_window=False,
+        # KDA's state and the convolution's tail: what a prefix entry carries
+        state_row_keys=m.STATE_ROW_KEYS,
+    )
+
+
 def _load_builtin() -> None:
     if not _FAMILIES:
         register_family(_llama_family())
@@ -338,3 +377,4 @@ def _load_builtin() -> None:
         register_family(_glm5next_family())
         register_family(_gigachat35_family())
         register_family(_afmoe_family())
+        register_family(_solaropen2_family())

@@ -84,11 +84,15 @@ def _kernel(live_ref, s_ref, q_ref, k_ref, v_ref, beta_ref, g_ref, o_ref, s_out_
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
 def delta_rule_step(S, q, k, v, beta, g, live, *, interpret: bool = False):
-    """One token a row. S [N, Hv, Dk, Dv] float32; q, k [N, Hk, Dk];
-    v [N, Hv, Dv]; beta [N, Hv]; g [N, Hv, Dk] (log decay, <= 0);
-    live [N] bool. Returns (o [N, Hv, Dv] float32, S), the new state in
-    the buffer of the old one where the caller donates it."""
-    N, Hv, Dk, Dv = S.shape
+    """One token a row. S [R, Hv, Dk, Dv] float32, R >= N; q, k
+    [N, Hk, Dk]; v [N, Hv, Dv]; beta [N, Hv]; g [N, Hv, Dk] (log decay,
+    <= 0); live [N] bool. Returns (o [N, Hv, Dv] float32, S), the new
+    state in the buffer of the old one where the caller donates it. The
+    grid walks the first N rows of S: rows past them (a prefix store's,
+    engine/prefix_cache.py) are never fetched and, the output being the
+    input's buffer, stay as they are."""
+    _, Hv, Dk, Dv = S.shape
+    N = q.shape[0]
     Hk = q.shape[1]
     ratio = Hv // Hk
     hb = head_block(Hv, Hk, Dk * Dv * S.dtype.itemsize)

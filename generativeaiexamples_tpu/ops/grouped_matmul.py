@@ -110,8 +110,18 @@ def _down_kernel(expert_ref, used_ref, a_ref, w_ref, o_ref):
         o_ref[...] = jnp.zeros_like(o_ref)
 
 
+_LANES = 128
+
+
 def _col_block(width: int, want: int) -> int:
-    return want if width % want == 0 else width
+    """Columns a weight block holds: the largest multiple of a lane tile
+    that divides ``width`` and is at most ``want`` (256 of 1280, where
+    512 does not divide ten lane tiles); the whole width where no lane
+    multiple divides it (the tiny widths of the CPU tests)."""
+    for tn in range(min(want, width) // _LANES * _LANES, 0, -_LANES):
+        if width % tn == 0:
+            return tn
+    return width
 
 
 _VMEM_LIMIT = 64 * 1024 * 1024

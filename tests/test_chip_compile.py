@@ -401,3 +401,123 @@ def test_paged_attention_tp_compiles_over_four_chips(topo, no_persistent_cache):
     text = _compiled_text(fn, *args)
     assert "tpu_custom_call" in text
     assert "all-gather" not in text
+
+
+# --------------------------------------------------------------------------- #
+# Solar-Open2 (models/solaropen2.py): experts of width 1280, a state that
+# holds the prefix store's rows behind the slots', and the whole step
+# programs at the configuration's shapes
+
+
+@pytest.mark.parametrize("tokens", [64, 512], ids=["decode-72-tiles-of-16", "chunk-104-tiles-of-64"])
+def test_grouped_matmul_compiles_at_40_experts_of_width_1280(one_chip, no_persistent_cache, tokens):
+    """1280 is ten lane tiles: the gate/up block is 256 columns (512 does
+    not divide the width; the whole width twice over would be 42 MB of
+    VMEM), the down block 1024 of 4096."""
+    D, F, E, k = 4096, 1280, 40, 8
+    assert grouped_matmul._col_block(F, 512) == 256 and grouped_matmul._col_block(D, 1024) == 1024
+
+    def s(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    def mlp(x, local, gates, w_gu, w_d):
+        return grouped_matmul.grouped_mlp(x, local, gates, w_gu, w_d, limit=float("inf"), kernel="compiled")
+
+    text = _compiled_text(mlp, s((tokens, D), jnp.bfloat16), s((tokens, k), jnp.int32), s((tokens, k), jnp.float32),
+                          s((E, D, 2 * F), jnp.bfloat16), s((E, F, D), jnp.bfloat16))
+    assert text.count("tpu_custom_call") >= 2
+
+
+def test_delta_rule_step_walks_the_slots_of_a_state_that_holds_store_rows_too(one_chip, no_persistent_cache):
+    """64 slots' rows of a [64 + 96, 64, 128, 128] float32 state advance
+    in place: the whole array is aliased from argument to result and no
+    state-sized temporary appears (the 96 store rows are never fetched)."""
+    S, q, k, v, beta, g, live = _delta_rule_shapes(one_chip, 64, True)
+    whole = jax.ShapeDtypeStruct((160,) + S.shape[1:], S.dtype, sharding=one_chip)
+    compiled = jax.jit(_delta_rule_step, donate_argnums=0).lower(whole, q, k, v, beta, g, live).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+    state = 160 * 64 * 128 * 128 * 4
+    mem = compiled.memory_analysis()
+    assert mem.alias_size_in_bytes >= state and mem.temp_size_in_bytes < state // 20
+
+
+@pytest.fixture(scope="module")
+def solaropen2_programs(one_chip):
+    """The family's walks at the benchmark configuration's shapes, on
+    shapes alone (``jax.eval_shape``): (config, params, cache, family)."""
+    import functools
+    import json
+
+    from generativeaiexamples_tpu.models import registry
+    from perfbench import arch
+
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    with open(os.path.join(root, "perfbench", "configs", "solar-open2-250b-ep8-bf16.json"), encoding="utf-8") as fh:
+        cfg = json.load(fh)
+    arch.load(cfg).register(cfg)
+    family, mc = registry.resolve(cfg["name"])
+    eng = cfg["engine"]
+    on_chip = lambda tree: jax.tree.map(  # noqa: E731
+        lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=one_chip), tree)
+    params = on_chip(jax.eval_shape(lambda: family.init_params(mc, 0, jnp.bfloat16)))
+    rows = eng["max_batch_size"] + eng["prefix_cache_slots"]
+    cache = on_chip(jax.eval_shape(functools.partial(
+        family.init_paged_cache, mc, eng["kv_pool_pages"], eng["page_size"], rows, jnp.bfloat16)))
+    return cfg, mc, family, params, cache
+
+
+def test_solaropen2_step_programs_compile_at_the_configurations_shapes(one_chip, no_persistent_cache, solaropen2_programs):
+    """Decode (a scan of ``decode_block`` steps over 64 rows) and the two
+    extend widths (one row of 512 and of 128 tokens) for the described
+    chip: every kernel in the program, the cache in place, and what the
+    program holds beside its arguments far under the 4 GB the plan leaves."""
+    cfg, mc, family, params, cache = solaropen2_programs
+    eng = cfg["engine"]
+    page, B, seq = eng["page_size"], eng["max_batch_size"], eng["max_seq_len"]
+    kernels = family.resolve_kernels(mc, "compiled")
+    i32 = lambda *shape: jax.ShapeDtypeStruct(shape, jnp.int32, sharding=one_chip)  # noqa: E731
+    tables = i32(B, seq // page)
+
+    def decode(params, caches, tokens, positions, live, tables):
+        def body(carry, _):
+            tokens, positions, caches = carry
+            logits, caches = family.decode_paged(params, mc, caches, tokens, positions, live, tables, seq, page,
+                                                 page_kernel="compiled", **kernels)
+            return (jnp.argmax(logits, -1).astype(jnp.int32), positions + 1, caches), tokens
+        return jax.lax.scan(body, (tokens, positions, caches), None, length=eng["decode_block"])
+
+    def extend(params, caches, tokens, offsets, valid, slots, tables):
+        return family.extend_paged(params, mc, caches, tokens, offsets, valid, slots, tables, seq, page, **kernels)
+
+    cache_bytes = sum(x.size * x.dtype.itemsize for x in jax.tree.leaves(cache))
+    assert cache_bytes == pytest.approx(cfg["memory_plan"]["fixed_state_bytes"] + cfg["memory_plan"]["page_pool_bytes"], rel=1e-4)
+    live = jax.ShapeDtypeStruct((B,), jnp.bool_, sharding=one_chip)
+    compiled = jax.jit(decode, donate_argnums=(1,)).lower(params, cache, i32(B), i32(B), live, tables).compile()
+    mem = compiled.memory_analysis()
+    # grouped matmul x2 and a mixer's kernel (page attention or the delta rule's step) a layer
+    assert compiled.as_text().count("tpu_custom_call") >= 3 * len(mc.layers)
+    assert mem.alias_size_in_bytes >= cache_bytes - 1024 and mem.temp_size_in_bytes < 0.5e9  # (the few counts are written anew)
+    for width in (eng["prefill_chunk"], 128):
+        compiled = jax.jit(extend, donate_argnums=(1,)).lower(
+            params, cache, i32(1, width), i32(1), i32(1), i32(1), tables).compile()
+        mem = compiled.memory_analysis()
+        assert compiled.as_text().count("tpu_custom_call") >= 2 * len(mc.layers)
+        assert mem.alias_size_in_bytes >= cache_bytes - 1024 and mem.temp_size_in_bytes < 1.0e9
+
+
+def test_the_prefix_state_copy_moves_one_row_in_place(one_chip, no_persistent_cache, solaropen2_programs):
+    """The engine's save / restore program (engine/llm_engine.py
+    ``prefix_state_copy``) over the configuration's cache: the whole
+    cache aliased, no temporary to speak of."""
+    _, _, family, _, cache = solaropen2_programs
+
+    def copy(caches, rows):
+        new = dict(caches)
+        for key in family.state_row_keys:
+            new[key] = jax.tree.map(lambda leaf: leaf.at[rows[1]].set(leaf[rows[0]]), caches[key])
+        return new, rows + 0
+
+    rows = jax.ShapeDtypeStruct((2,), jnp.int32, sharding=one_chip)
+    mem = jax.jit(copy, donate_argnums=(0,)).lower(cache, rows).compile().memory_analysis()
+    cache_bytes = sum(x.size * x.dtype.itemsize for x in jax.tree.leaves(cache))
+    assert mem.alias_size_in_bytes >= cache_bytes and mem.temp_size_in_bytes < 64 * 1024 * 1024
