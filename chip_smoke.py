@@ -238,7 +238,7 @@ def check_metrics_text(text: str, gather_explained: bool = False, before=None) -
 _KERNEL_LINE = re.compile(
     r"resolved kernel paths: quant_kernel=(\S+) "
     r"paged_kernel=(\S+) paged_verify_kernel=(\S+) "
-    r"(?:paged_extend_kernel=\S+ )?tp_kernels=(\S+) "
+    r"(?:paged_extend_kernel=\S+ )?tp_kernels=(\S+) (?:kv_scales=(\S+) )?"
     r"\(backend=(\w+), devices=(\d+)\)"
 )
 _WARMUP_LINE = re.compile(
@@ -256,16 +256,20 @@ def check_server_log(text: str, want_compiled: bool, tp: int = 1) -> dict:
     check("COMPILE ON HOT PATH" not in text, "server log reports a hot-path compile")
     m = _KERNEL_LINE.search(text)
     check(m is not None, "server log has no 'resolved kernel paths' line")
-    quant, paged, verify, tpk, backend, devices = m.groups()
+    quant, paged, verify, tpk, kv_scales, backend, devices = m.groups()
     paths = {
         "quant_kernel": quant, "paged_kernel": paged,
         "paged_verify_kernel": verify, "tp_kernels": tpk,
-        "backend": backend, "devices": int(devices),
+        "kv_scales": kv_scales, "backend": backend, "devices": int(devices),
     }
     if want_compiled:
         check(backend == "tpu", f"engine resolved on backend {backend}, not tpu")
         check(quant == "True", f"int8 matmul kernel not resolved (quant_kernel={quant})")
         check(paged == "compiled", f"page-attention kernel not compiled (paged_kernel={paged})")
+        # the full preset's int8 pool (128-token pages, 8 KV heads) tiles the
+        # lanes; only a pool whose heads are sharded keeps them token-major
+        want_scales = "lane_dense" if tp == 1 else "token_major"
+        check(kv_scales == want_scales, f"int8 pool's scale planes are {kv_scales}, not {want_scales}")
     else:
         check(paged in ("compiled", "interpret"), f"paged_kernel={paged}")
     if tp > 1:
@@ -433,9 +437,12 @@ def phase_kernels(args) -> int:
         ref = mm.int8_matmul_xla(x, q, scale)
         report(f"int8_matmul M=16 K={K} F={F}", rel(out, ref), TOL_MM, time.time() - t0)
 
-    # 2. ragged page attention over an int8 pool (the served cache),
-    #    against the XLA gather the engine falls back to
-    B, Hq, Hkv, Dh, S = (16, 32, 8, 128, 4096) if full else (2, 8, 2, 128, 256)
+    # 2. ragged page attention over an int8 pool (the served cache; its
+    #    scale planes lane-dense, as init_kv_pool stores them on one
+    #    device), against the XLA gather the engine falls back to, and
+    #    against the same kernel over token-major planes of the same
+    #    values (what a head-sharded pool keeps): the same bits
+    B, Hq, Hkv, Dh, S = (16, 32, 8, 128, 4096) if full else (2, 8, 8, 128, 256)
     page = 128 if full else 16
     Pmax = S // page
     t0 = time.time()
@@ -449,16 +456,29 @@ def phase_kernels(args) -> int:
         rng.permutation(np.arange(1, n_pages)).reshape(B, Pmax), jnp.int32
     )
     qp = jnp.asarray(rng.standard_normal((B, 1, Hq, Dh)), jnp.bfloat16)
-    out = pa.paged_attention(qp, pk, pv, tables, pos, pks, pvs, interpret=interpret)
+    dense = (n_pages,) + llama.kv_scale_plane_shape(page, Hkv)
+    check(dense[2] == 128 and dense != pks.shape, f"scale planes of {dense} are not lane-dense")
+    dks, dvs = pks.reshape(dense), pvs.reshape(dense)
+    out = pa.paged_attention(qp, pk, pv, tables, pos, dks, dvs, interpret=interpret)
 
     def gathered(buf):
         return jnp.swapaxes(llama._gather_page_window(buf, tables, Pmax, page), 1, 2)
 
+    def gathered_scales(plane):
+        return jnp.swapaxes(llama.gather_kv_scales(plane, tables, Pmax, page), 1, 2)[:, :, None, :]
+
     ref = da.decode_attention_xla(
-        qp, gathered(pk), gathered(pks)[:, :, None, :],
-        gathered(pv), gathered(pvs)[:, :, None, :], pos[:, None],
+        qp, gathered(pk), gathered_scales(dks), gathered(pv), gathered_scales(dvs), pos[:, None],
     )
     report(f"paged_attention int8 B={B} page={page} Pmax={Pmax}", rel(out, ref), TOL_ATTN, time.time() - t0)
+    t0 = time.time()
+    token_major = pa.paged_attention(qp, pk, pv, tables, pos, pks, pvs, interpret=interpret)
+    same = np.array_equal(np.asarray(out, np.float32), np.asarray(token_major, np.float32))
+    report(
+        f"paged_attention int8 lane-dense scales {dense[1:]} x{pa.pages_per_step(pk, dks)} pages a step "
+        f"== token-major scales {pks.shape[1:]} x{pa.pages_per_step(pk, pks)}, bit for bit on {B} live rows",
+        0.0 if same else float("inf"), 0.0, time.time() - t0,
+    )
 
     # 3. flash prefill against the einsum attention
     T = 2048 if full else 128
@@ -991,7 +1011,7 @@ def main() -> int:
                 f"({'warm' if entries_before else 'cold'} cache, {entries_before} -> {entries_after} entries)")
             say(f"resolved kernel paths: quant_kernel={stats['quant_kernel']} "
                 f"paged_kernel={stats['paged_kernel']} paged_verify_kernel={stats['paged_verify_kernel']} "
-                f"(backend={stats['backend']}, devices={stats['devices']})")
+                f"kv_scales={stats['kv_scales']} (backend={stats['backend']}, devices={stats['devices']})")
             say(f"peak HBM after warm-up, from memory_stats(): {stats['device_memory']}")
             history = os.path.join(OUT, "history.jsonl")
             prior = []

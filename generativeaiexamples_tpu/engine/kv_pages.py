@@ -135,7 +135,11 @@ def page_bytes(
 ) -> int:
     """HBM bytes ONE pool page represents across every layer: k+v rows
     (quantized storage adds the float32 per-(token, kv-head) scales —
-    one scale per cached row, [page_size, Hkv] per page per direction).
+    ``page_size * Hkv`` of them a page per direction, whichever way the
+    pool stores them: lane-dense ``[page_size * Hkv / 128, 128]`` or
+    token-major ``[page_size, Hkv]``, models/llama.py
+    ``kv_scale_plane_shape`` decides; the bytes are the same, only
+    the chip's padding of the token-major form is not counted here).
     This is the handoff protocol's per-page transfer accounting
     (engine/scheduler/handoff.py): what a cross-replica transport would
     put on the wire, and zero actual device traffic on the same-host

@@ -810,6 +810,13 @@ class LLMEngine:
             model_cfg, self._pool_pages, cfg.page_size,
             self.num_slots + self._state_store_rows,
             dtype, quantized=self._kv_quant, packed=self._kv_packed,
+            # shard_kv_pool below puts the pools' heads on the model axis
+            head_sharded=self._mesh.size > 1,
+        )
+        # how the quantised pool stores its scale planes, read off the
+        # pool it built (models/llama.py kv_scale_plane_shape decides)
+        self._kv_scale_plane = (
+            tuple(pool[0]["ks"].shape[1:]) if self._kv_quant else None
         )
         if self._mesh.size > 1:
             from generativeaiexamples_tpu.parallel.sharding import (
@@ -902,11 +909,12 @@ class LLMEngine:
         logger.info(
             "resolved kernel paths: quant_kernel=%s "
             "paged_kernel=%s paged_verify_kernel=%s "
-            "paged_extend_kernel=%s tp_kernels=%s%s "
+            "paged_extend_kernel=%s tp_kernels=%s kv_scales=%s%s "
             "(backend=%s, devices=%d)",
             self._quant_kernel, self._paged_kernel,
             self._paged_verify_kernel, self._paged_extend_kernel,
             f"{self._tp.shards}-way" if self._tp is not None else None,
+            self._kv_scale_layout(),
             "".join(f" {k}={v}" for k, v in sorted(self._family_kernels.items())),
             jax.default_backend(), jax.device_count(),
         )
@@ -916,6 +924,16 @@ class LLMEngine:
         self._init_spec_proposer(cfg)
         self._init_prefix_cache(cfg)
         self._init_scheduler_state(cfg)
+
+    def _kv_scale_layout(self) -> Optional[str]:
+        """The layout of the quantised pool's scale planes as built, for
+        the ``resolved kernel paths:`` line: ``lane_dense`` ([P, page *
+        Hkv / 128, 128]: 4 KB scale blocks, two int8 pages a grid step),
+        ``token_major`` ([P, page, Hkv]), None for a pool without."""
+        if self._kv_scale_plane is None:
+            return None
+        token_major = (self.engine_config.page_size, self._kv_shape.num_kv_heads)
+        return "token_major" if self._kv_scale_plane == token_major else "lane_dense"
 
     def _validate_fixed_state(self, cfg: EngineConfig, mesh) -> EngineConfig:
         """Refuse, at engine build, everything that assumes "a slot's
@@ -1096,8 +1114,9 @@ class LLMEngine:
             if kv_shape.bytes_per_token is None:
                 self._kv_pages_a_step = page_attention.pool_pages_per_step(
                     cfg.page_size, kv_shape.num_kv_heads, kv_shape.head_dim,
-                    "int8" if self._kv_quant else cfg.dtype,
-                    quantized=self._kv_quant,
+                    ("uint8" if self._kv_packed else "int8")
+                    if self._kv_quant else cfg.dtype,
+                    scale_plane=self._kv_scale_plane,
                 )
             logger.info(
                 "ragged page-attention kernel serving paged decode "
@@ -3218,9 +3237,7 @@ class LLMEngine:
             # live cache buffers nor registers with the hot-path
             # compile watch — the chaos gate's zero-post-warmup-compile
             # assertion stays about the serving executables.
-            for li, layer in enumerate(self._cache):
-                for key, arr in payload[li].items():
-                    layer[key] = layer[key].at[idx_dev].set(jnp.asarray(arr))
+            request_snapshot_mod.upload_kv_payload(self._cache, idx_dev, payload)
             self._tokens_dev = self._tokens_dev.at[slot].set(
                 int(req.emitted[-1])
             )

@@ -145,8 +145,9 @@ def _decode_array(doc: Dict[str, Any]) -> np.ndarray:
 
 
 def encode_kv_payload(layers: List[Dict[str, np.ndarray]]) -> Dict[str, Any]:
-    """Per-layer page gathers (k/v [+ks/vs] of shape
-    [pages, page_size, Hkv(, Dh)]) -> JSON-safe payload doc."""
+    """Per-layer page gathers (k/v of shape [pages, page_size, Hkv, Dh],
+    ks/vs as the pool stores a page's scales: [pages, page_size * Hkv /
+    128, 128] or [pages, page_size, Hkv]) -> JSON-safe payload doc."""
     return {
         "layers": [
             {key: _encode_array(arr) for key, arr in layer.items()}
@@ -160,6 +161,23 @@ def decode_kv_payload(doc: Dict[str, Any]) -> List[Dict[str, np.ndarray]]:
         {key: _decode_array(arr) for key, arr in layer.items()}
         for layer in doc["layers"]
     ]
+
+
+def upload_kv_payload(cache: List[Dict[str, Any]], idx_dev, payload) -> None:
+    """Write a decoded payload's pages into the per-layer pools ``cache``
+    at pool pages ``idx_dev``, in place of the dict entries (eager ops:
+    see ``_apply_restore``). A payload's scale planes may be of the other
+    layout than the pool's (a head-sharded engine keeps [page, Hkv], a
+    single device [page * Hkv / 128, 128]: models/llama.py
+    ``kv_scale_plane_shape``); the two hold a page's scales in the same
+    flat order, so a reshape to the pool's page restores either."""
+    import jax.numpy as jnp
+
+    for layer, pages in zip(cache, payload):
+        for key, arr in pages.items():
+            layer[key] = layer[key].at[idx_dev].set(
+                jnp.asarray(arr).reshape(arr.shape[:1] + layer[key].shape[1:])
+            )
 
 
 def params_doc(params: Any) -> Dict[str, Any]:
@@ -320,6 +338,11 @@ def capture(engine, req, position: int, pages: Tuple[int, ...]) -> RequestSnapsh
             # read — restore must refuse a cross-dtype snapshot, not
             # silently dequantize garbage.
             "kv_dtype": _engine_kv_dtype(engine),
+            # Which layout the payload's scale planes hold (lane_dense /
+            # token_major, models/llama.py kv_scale_plane_shape; None:
+            # no scales). A record, not a condition: the two are the
+            # same bytes a page and restore into each other.
+            "kv_scales": engine._kv_scale_layout(),
             "num_layers": mc.num_layers,
             "num_kv_heads": mc.num_kv_heads,
             "head_dim": mc.head_dim,

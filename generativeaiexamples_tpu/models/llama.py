@@ -30,8 +30,9 @@ Design notes (TPU-first):
 from __future__ import annotations
 
 import dataclasses
+import functools
 import math
-from typing import Any, Dict, Optional, Tuple
+from typing import Any, Dict, NamedTuple, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -1010,6 +1011,149 @@ def decode_layers(
 # allocator has re-issued to a live request.
 
 
+_LANES = 128  # the chip's lane width: a minor dimension below it pads to it
+
+
+def kv_scale_plane_shape(
+    page_size: int, num_kv_heads: int, head_sharded: bool = False
+) -> Tuple[int, int]:
+    """One page of a quantised pool's scale plane, as the pool stores it.
+
+    The scales of a page are ``page * Hkv`` float32 in the flat order
+    ``t * Hkv + h``: the column order of the page kernel's scores.
+    LANE-DENSE ``[page * Hkv / 128, 128]`` cuts that order into 128-lane
+    rows (element ``(r, l)`` is token ``(r * 128 + l) // Hkv``, head
+    ``l % Hkv``): one 4 KB tile a page on the chip. TOKEN-MAJOR
+    ``[page, Hkv]`` is the same bytes with ``Hkv`` as the minor
+    dimension, which the chip pads to 128 lanes (64 KB moved for 4 KB a
+    block, a relayout in the page read, a padded copy of every plane
+    around every kernel dispatch: PERF.md §6, PR 43 and PR 45). It stays
+    where the lanes cannot be had: a pool whose heads are sharded over a
+    mesh (``parallel/sharding.kv_pool_specs`` shards that dimension) and
+    a geometry whose tokens do not tile the lanes (``Hkv`` must divide
+    128, so that a token's heads never straddle two rows, and ``page *
+    Hkv`` be whole rows). This function alone decides; every reader
+    tells the two apart by the plane's static shape."""
+    cols = page_size * num_kv_heads
+    if head_sharded or _LANES % num_kv_heads or cols % _LANES:
+        return (page_size, num_kv_heads)
+    return (cols // _LANES, _LANES)
+
+
+class TokenRuns(NamedTuple):
+    """Where a walk's tokens sit in the cache, run by run: run ``w`` is
+    tokens ``starts[w] .. starts[w] + counts[w] - 1`` of the walk's
+    flattened token axis, at CONSECUTIVE cache positions ``offsets[w]
+    ..`` of the row whose page table is ``tables[w]``; ``seg`` (static)
+    bounds a run's tokens. A packed wave's rows, a rectangle's rows and
+    a decode step's single tokens are all runs. No two runs of a walk
+    share a cache row (a slot rides a dispatch once)."""
+
+    starts: Optional[jax.Array]  # [R] int32; None: run w is token w (one token a run)
+    counts: jax.Array  # [R] int32 — live tokens (0: a dead run writes nothing)
+    offsets: jax.Array  # [R] int32
+    tables: jax.Array  # [R, Pmax] int32
+    seg: int
+
+
+def write_kv_scales(
+    planes: Tuple[jax.Array, jax.Array], scales: Tuple[jax.Array, jax.Array],
+    phys: jax.Array, sip: jax.Array, runs: TokenRuns,
+) -> Tuple[jax.Array, jax.Array]:
+    """A layer's two scale planes ``(ks, vs)`` with the walk's ``scales
+    (ksn, vsn)``, each ``[..., Hkv]``, written, in either layout.
+
+    TOKEN-MAJOR: one scatter a plane of ``[Hkv]`` rows at pool page
+    ``phys [...]``, slot-in-page ``sip [...]`` (a dead token's on the
+    scratch page, where the walk points it).
+
+    LANE-DENSE: a token's scales are ``Hkv`` lanes of a 128-lane row
+    that ``128 / Hkv`` consecutive tokens share, and the chip writes
+    whole rows well and pieces of a row badly (a windowed scatter of
+    ``[1, 1, Hkv]`` updates measured 3.9 us AN UPDATE, 16 ms a layer at
+    2,048 tokens: PERF.md §6, PR 45). So the rows are rebuilt from the
+    walk's ``runs`` (``_rebuild_scale_rows``), both planes in one pass;
+    a dead token is not written at all."""
+    if planes[0].shape[2] == scales[0].shape[-1]:  # token-major
+        return tuple(p.at[phys, sip].set(s) for p, s in zip(planes, scales))
+    return _rebuild_scale_rows(*planes, *scales, *runs)
+
+
+@functools.partial(jax.jit, static_argnames=("seg",))
+def _rebuild_scale_rows(ks, vs, ksn, vsn, starts, counts, offsets, tables, seg: int):
+    """The lane-dense write. A run's tokens are consecutive, hence the
+    128-lane row holding cache positions ``p .. p + 128 / Hkv - 1`` of
+    run ``w`` is the 128 consecutive floats of the flattened scales from
+    token ``starts[w] + p - offsets[w]`` on. Every row a run touches
+    (``seg / (128 / Hkv) + 1`` at most) is cut out of the flattened
+    scales (two aligned row reads, a select between them and a lane
+    rotate in halving steps), takes the plane's old value on the lanes
+    of tokens outside the run, and goes back as ONE row: a sixteenth of
+    the updates at 8 heads, each a whole sublane. A run of one token (a
+    decode step's) needs no cut: its row is the token's scales repeated
+    along the lanes. Exact: selects only. What the op count buys: every
+    small operation costs ~2 us on the chip and a program's load time
+    grows with them, so K and V share the index arithmetic and the cut
+    (PERF.md §6, PR 45). A jit of its own: a walk calls it once a layer
+    on the same shapes, and is traced once."""
+    hkv = ksn.shape[-1]
+    rows_a_page = ks.shape[1]
+    per_row = _LANES // hkv  # tokens a 128-lane row
+    reach = (seg + per_row - 2) // per_row + 1  # rows a run of seg tokens can touch
+    lane = jnp.arange(_LANES, dtype=jnp.int32)
+    # [R, reach]: the run's rows by their index along its cache, and the
+    # run-relative token each row begins with (negative: before the run)
+    row = offsets[:, None] // per_row + jnp.arange(reach, dtype=jnp.int32)[None, :]
+    first = row * per_row - offsets[:, None]
+    token = first[..., None] + lane // hkv  # [R, reach, 128]
+    ours = (token >= 0) & (token < counts[:, None, None])
+    ours &= (row < tables.shape[1] * rows_a_page)[..., None]  # past the row's capacity: nobody's
+    page = jnp.take_along_axis(
+        tables, jnp.minimum(row // rows_a_page, tables.shape[1] - 1), axis=1
+    )
+    # a row no token of the run falls in rewrites the scratch page's row
+    # with its own old value: every index stays in range (an index out
+    # of range under mode="drop" once clobbered live updates on the
+    # chip, PERF.md §6 PR 41), and equal values make the order moot
+    page = jnp.where(ours.any(-1), page, 0)
+    sub = row % rows_a_page
+    if seg == 1:
+        # one token a run: its row is its scales repeated along the lanes
+        one = [x.reshape(-1, hkv) if starts is None else x.reshape(-1, hkv)[starts] for x in (ksn, vsn)]
+        # (a concatenate, not a tile: the tile's reshape costs a relayout copy a plane a step)
+        new = [jnp.concatenate([x] * per_row, axis=-1)[:, None, :] for x in one]  # [R, 1, 128] each
+    else:
+        # the 128 floats from token ``starts + first`` on, out of the
+        # flattened scales padded by a row in front (first < 0) and two behind
+        flat = jnp.stack([ksn.reshape(-1), vsn.reshape(-1)])
+        flat = jnp.pad(flat, ((0, 0), (_LANES, 2 * _LANES + -flat.shape[1] % _LANES)))
+        flat = flat.reshape(2, -1, _LANES)
+        if starts is None:
+            starts = jnp.arange(counts.shape[0], dtype=jnp.int32)
+        at = jnp.clip((starts[:, None] + first) * hkv + _LANES, 0, (flat.shape[1] - 2) * _LANES)
+        cut = (at % _LANES)[..., None]
+        new = jnp.where(lane >= cut, flat[:, at // _LANES], flat[:, at // _LANES + 1])
+        shift = hkv
+        while shift < _LANES:  # rotate left by the cut, a multiple of hkv, bit by bit
+            new = jnp.where((cut // shift) % 2 == 1, jnp.roll(new, -shift, axis=-1), new)
+            shift *= 2
+    return (
+        ks.at[page, sub].set(jnp.where(ours, new[0], ks[page, sub])),
+        vs.at[page, sub].set(jnp.where(ours, new[1], vs[page, sub])),
+    )
+
+
+def gather_kv_scales(
+    plane: jax.Array, tables: jax.Array, pages_w: int, page_size: int
+) -> jax.Array:
+    """Each row's first ``pages_w`` pages of a scale plane as token rows
+    ``[N, pages_w * page, Hkv]`` (the XLA gather paths' operand): both
+    layouts hold a page's scales in the flat order ``t * Hkv + h``, so
+    one reshape of the gathered pages reads either."""
+    g = plane[tables[:, :pages_w]]  # [N, pages_w, <one page of the plane>]
+    return g.reshape(g.shape[0], pages_w * page_size, -1)
+
+
 def init_kv_pool(
     cfg: LlamaConfig,
     pool: int,
@@ -1017,35 +1161,34 @@ def init_kv_pool(
     dtype: jnp.dtype = jnp.bfloat16,
     quantized: bool = False,
     packed: bool = False,
+    head_sharded: bool = False,
 ) -> list:
-    """Per-layer page pools: [pool, page_size, Hkv, Dh] token-major (the
-    int8 variant carries per-(token, head) scales [pool, page_size,
-    Hkv] — quantize_kv's values, laid out page-contiguous). ``packed`` selects the int4 pool: uint8
-    [pool, page_size, Hkv, Dh//2] holding two values per byte
-    (quantize_kv_int4's split-halves codec) with the same scale planes —
-    readers detect it by the uint8 dtype."""
+    """Per-layer page pools: [pool, page_size, Hkv, Dh] token-major. The
+    int8 variant carries quantize_kv's per-(token, head) float32 scales
+    in two planes ``ks`` / ``vs`` of ``[pool, *kv_scale_plane_shape]``:
+    lane-dense ``[pool, page_size * Hkv / 128, 128]`` unless the pool's
+    heads are to be sharded over a mesh (``head_sharded``, the engine's
+    to say) or the geometry does not tile the lanes, then ``[pool,
+    page_size, Hkv]``. Walks write and gather the planes through
+    ``write_kv_scales`` / ``gather_kv_scales`` and the page kernel reads
+    the layout off the operand's shape; none of them spells it.
+    ``packed`` selects the int4 pool: uint8 [pool, page_size, Hkv,
+    Dh//2] holding two values per byte (quantize_kv_int4's split-halves
+    codec) with the same scale planes — readers detect it by the uint8
+    dtype."""
     Hkv, Dh = cfg.num_kv_heads, cfg.head_dim
+    if packed:
+        assert Dh % 2 == 0, Dh
+        rows, dtype = (pool, page_size, Hkv, Dh // 2), jnp.uint8
+    else:
+        rows, dtype = (pool, page_size, Hkv, Dh), jnp.int8 if quantized else dtype
+    plane = (pool,) + kv_scale_plane_shape(page_size, Hkv, head_sharded)
 
     def one():
-        if packed:
-            assert Dh % 2 == 0, Dh
-            return {
-                "k": jnp.zeros((pool, page_size, Hkv, Dh // 2), jnp.uint8),
-                "v": jnp.zeros((pool, page_size, Hkv, Dh // 2), jnp.uint8),
-                "ks": jnp.zeros((pool, page_size, Hkv), jnp.float32),
-                "vs": jnp.zeros((pool, page_size, Hkv), jnp.float32),
-            }
-        if quantized:
-            return {
-                "k": jnp.zeros((pool, page_size, Hkv, Dh), jnp.int8),
-                "v": jnp.zeros((pool, page_size, Hkv, Dh), jnp.int8),
-                "ks": jnp.zeros((pool, page_size, Hkv), jnp.float32),
-                "vs": jnp.zeros((pool, page_size, Hkv), jnp.float32),
-            }
-        return {
-            "k": jnp.zeros((pool, page_size, Hkv, Dh), dtype),
-            "v": jnp.zeros((pool, page_size, Hkv, Dh), dtype),
-        }
+        layer = {"k": jnp.zeros(rows, dtype), "v": jnp.zeros(rows, dtype)}
+        if packed or quantized:
+            layer.update(ks=jnp.zeros(plane, jnp.float32), vs=jnp.zeros(plane, jnp.float32))
+        return layer
 
     return [one() for _ in range(cfg.num_layers)]
 
@@ -1079,16 +1222,20 @@ def write_prefill_pages(
     page_idx = jnp.broadcast_to(pos // page_size, (N, T))
     phys = jnp.take_along_axis(row_tables, page_idx, axis=1)  # [N, T]
     sip = jnp.broadcast_to(pos % page_size, (N, T))
+    runs = TokenRuns(
+        T * jnp.arange(N, dtype=jnp.int32), jnp.full((N,), T, jnp.int32),
+        jnp.zeros((N,), jnp.int32), row_tables, T,
+    )
     new_caches = []
     for c, (k, v) in zip(caches, kvs):
         if quantized:
             kq, ksn = qfn(k)  # [N,T,Hkv,Dh(/2)], [N,T,Hkv]
             vq, vsn = qfn(v)
+            cks, cvs = write_kv_scales((c["ks"], c["vs"]), (ksn, vsn), phys, sip, runs)
             new_caches.append({
                 "k": c["k"].at[phys, sip].set(kq),
                 "v": c["v"].at[phys, sip].set(vq),
-                "ks": c["ks"].at[phys, sip].set(ksn),
-                "vs": c["vs"].at[phys, sip].set(vsn),
+                "ks": cks, "vs": cvs,
             })
         else:
             new_caches.append({
@@ -1138,11 +1285,11 @@ def _window_attention(q, ck, cv, cks, cvs, tabs, positions, W: int,
             gk, gv = unpack_int4(gk), unpack_int4(gv)
         gk = (
             gk.astype(jnp.float32)
-            * _gather_page_window(cks, tabs, Pw, page_size)[..., None]
+            * gather_kv_scales(cks, tabs, Pw, page_size)[..., None]
         ).astype(q.dtype)  # [n, W, Hkv, Dh]
         gv = (
             gv.astype(jnp.float32)
-            * _gather_page_window(cvs, tabs, Pw, page_size)[..., None]
+            * gather_kv_scales(cvs, tabs, Pw, page_size)[..., None]
         ).astype(q.dtype)
     return _attention(q, gk, gv, mask)
 
@@ -1162,12 +1309,12 @@ def _chunk_layers_paged(
     tp=None,
     page_kernel: Optional[str] = None,
 ) -> Tuple[jax.Array, list]:
-    """``_chunk_layers`` over the page pool: identical write/masking
-    semantics, with cache coordinates routed through the page tables and
-    the attention window gathered from the pool. Dead rows (valid == 0 —
-    cached-prefix skips, finished rows, padding) write to the scratch
-    page, so shared prefix pages are NEVER written, not even value-
-    masked no-ops.
+    """``_chunk_layers`` over the page pool: the same masking, with
+    cache coordinates routed through the page tables and the attention
+    window gathered from the pool. Dead tokens (past a row's ``valid``:
+    cached-prefix skips, finished rows, padding, rejected drafts) write
+    to the scratch page, as the packed walk's do, so a page some request
+    holds is never written but by its own live tokens.
 
     ``page_kernel`` (None | 'compiled' | 'interpret') swaps the
     attention READ for the ragged Pallas kernel
@@ -1196,8 +1343,9 @@ def _chunk_layers_paged(
     h = params["embed"][tokens]
     row_tables = tables[slots]  # [N, Pmax]
     phys = jnp.take_along_axis(row_tables, positions // page_size, axis=1)
-    phys = jnp.where((valid > 0)[:, None], phys, 0)  # dead rows -> scratch
+    phys = jnp.where(tok_valid, phys, 0)  # dead tokens -> scratch
     sip = positions % page_size
+    runs = TokenRuns(C * jnp.arange(N, dtype=jnp.int32), valid, offsets, row_tables, C)
     if page_kernel:
         heads = cfg.num_heads // (tp.shards if tp is not None else 1)
         fold = page_attention.query_fold(C, heads)
@@ -1225,23 +1373,11 @@ def _chunk_layers_paged(
     for lp, c in zip(params["layers"], caches):
         def attn(q, k, v, c=c):
             if quantized:
-                # [N,C,Hkv,Dh] (int4: [N,C,Hkv,Dh//2] packed bytes —
-                # the value-mask below selects whole packed bytes, which
-                # is exact because packing never crosses the token axis)
-                kq, ksn = qfn(k)
+                kq, ksn = qfn(k)  # [N,C,Hkv,Dh(/2)], [N,C,Hkv]
                 vq, vsn = qfn(v)
-                cur_k = c["k"][phys, sip]
-                cur_v = c["v"][phys, sip]
-                cur_ks = c["ks"][phys, sip]  # [N,C,Hkv]
-                cur_vs = c["vs"][phys, sip]
-                row_k = jnp.where(tok_valid[..., None, None], kq, cur_k)
-                row_v = jnp.where(tok_valid[..., None, None], vq, cur_v)
-                row_ks = jnp.where(tok_valid[..., None], ksn, cur_ks)
-                row_vs = jnp.where(tok_valid[..., None], vsn, cur_vs)
-                ck = c["k"].at[phys, sip].set(row_k)
-                cv = c["v"].at[phys, sip].set(row_v)
-                cks = c["ks"].at[phys, sip].set(row_ks)
-                cvs = c["vs"].at[phys, sip].set(row_vs)
+                ck = c["k"].at[phys, sip].set(kq)
+                cv = c["v"].at[phys, sip].set(vq)
+                cks, cvs = write_kv_scales((c["ks"], c["vs"]), (ksn, vsn), phys, sip, runs)
                 new_caches.append({"k": ck, "v": cv, "ks": cks, "vs": cvs})
                 if page_kernel:
                     return kernel_read(q, ck, cv, cks, cvs), ()
@@ -1249,16 +1385,8 @@ def _chunk_layers_paged(
                     q, ck, cv, cks, cvs, row_tables, positions, W, page_size
                 )
             else:
-                cur_k = c["k"][phys, sip]  # [N,C,Hkv,Dh]
-                cur_v = c["v"][phys, sip]
-                row_k = jnp.where(
-                    tok_valid[..., None, None], k.astype(c["k"].dtype), cur_k
-                )
-                row_v = jnp.where(
-                    tok_valid[..., None, None], v.astype(c["v"].dtype), cur_v
-                )
-                ck = c["k"].at[phys, sip].set(row_k)
-                cv = c["v"].at[phys, sip].set(row_v)
+                ck = c["k"].at[phys, sip].set(k.astype(c["k"].dtype))
+                cv = c["v"].at[phys, sip].set(v.astype(c["v"].dtype))
                 new_caches.append({"k": ck, "v": cv})
                 if page_kernel:
                     return kernel_read(q, ck, cv), ()
@@ -1334,6 +1462,7 @@ def extend_layers_packed(
     phys = jnp.where(tok_live, row_tables[tok_row, positions // page_size], 0)
     sip = jnp.where(tok_live, positions % page_size, t % page_size)
     phys, sip = phys[None], sip[None]  # [1, T]
+    runs = TokenRuns(starts, counts, offsets, row_tables, seg)
     # the read's two index maps: a row's queries out of the axis, and
     # each token's place among the rows' results
     lane = jnp.arange(seg, dtype=jnp.int32)[None, :]
@@ -1382,11 +1511,11 @@ def extend_layers_packed(
         def attn(q, k, v, c=c):
             if quantized:
                 (kq, ksn), (vq, vsn) = qfn(k), qfn(v)
+                cks, cvs = write_kv_scales((c["ks"], c["vs"]), (ksn, vsn), phys, sip, runs)
                 new = {
                     "k": c["k"].at[phys, sip].set(kq),
                     "v": c["v"].at[phys, sip].set(vq),
-                    "ks": c["ks"].at[phys, sip].set(ksn),
-                    "vs": c["vs"].at[phys, sip].set(vsn),
+                    "ks": cks, "vs": cvs,
                 }
                 pools = (new["k"], new["v"], new["ks"], new["vs"])
             else:
@@ -1526,6 +1655,7 @@ def decode_layers_paged(
     phys = jnp.take_along_axis(tables, pos2 // page_size, axis=1)  # [B, 1]
     phys = jnp.where(live[:, None], phys, 0)
     sip = pos2 % page_size
+    runs = TokenRuns(None, live.astype(jnp.int32), positions, tables, 1)
     mask = jnp.arange(W, dtype=jnp.int32)[None, None, :] <= pos2[:, :, None]
     # one ragged work list per step, shared by every layer's read
     work = (
@@ -1543,8 +1673,7 @@ def decode_layers_paged(
                 vq, vsn = qfn(v)
                 ck = c["k"].at[phys, sip].set(kq)
                 cv = c["v"].at[phys, sip].set(vq)
-                cks = c["ks"].at[phys, sip].set(ksn)
-                cvs = c["vs"].at[phys, sip].set(vsn)
+                cks, cvs = write_kv_scales((c["ks"], c["vs"]), (ksn, vsn), phys, sip, runs)
                 new_caches.append({"k": ck, "v": cv, "ks": cks, "vs": cvs})
                 if page_kernel:
                     out = _paged_kernel_read(
@@ -1563,10 +1692,10 @@ def decode_layers_paged(
                     gk = unpack_int4(gk)
                     gv = unpack_int4(gv)
                 kd = jnp.swapaxes(gk, 1, 2).astype(jnp.float32) * jnp.swapaxes(
-                    _gather_page_window(cks, tables, Pw, page_size), 1, 2
+                    gather_kv_scales(cks, tables, Pw, page_size), 1, 2
                 )[..., None]  # [B, Hkv, W, Dh]
                 vd = jnp.swapaxes(gv, 1, 2).astype(jnp.float32) * jnp.swapaxes(
-                    _gather_page_window(cvs, tables, Pw, page_size), 1, 2
+                    gather_kv_scales(cvs, tables, Pw, page_size), 1, 2
                 )[..., None]
                 qg = q.reshape(B, 1, Hkv, G, cfg.head_dim).astype(jnp.float32)
                 sc = jnp.einsum("btkgd,bksd->bkgts", qg, kd) / math.sqrt(

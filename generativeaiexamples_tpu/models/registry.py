@@ -12,8 +12,11 @@ engine's paged step programs (``engine/llm_engine.py``
   gives, already on the device, to the layout the walks read (the
   identity by default; ``llama`` splits its stacked leaves per layer);
 - ``init_paged_cache(cfg, pool_pages, page_size, num_slots, dtype,
-  quantized, packed)``: the cache pytree (page pools and, for a
-  fixed-state family, the per-slot arrays beside them);
+  quantized, packed, head_sharded)``: the cache pytree (page pools and,
+  for a fixed-state family, the per-slot arrays beside them;
+  ``head_sharded`` says the engine will shard the pools' heads over a
+  mesh, which decides how a quantised pool stores its scale planes,
+  ``models/llama.py`` ``kv_scale_plane_shape``);
 - ``prefill_paged(params, cfg, caches, tokens, lengths, slots, tables,
   page_size, **paths) -> (logits [N, V], caches)``;
 - ``extend_paged(params, cfg, caches, tokens, offsets, valid, slots,
@@ -178,9 +181,11 @@ def family_of(cfg: Any) -> ModelFamily:
 def _llama_family() -> ModelFamily:
     from generativeaiexamples_tpu.models import llama
 
-    def init_paged_cache(cfg, pool_pages, page_size, num_slots, dtype, quantized=False, packed=False):
+    def init_paged_cache(cfg, pool_pages, page_size, num_slots, dtype, quantized=False, packed=False,
+                         head_sharded=False):
         del num_slots  # every layer's state is pages
-        return llama.init_kv_pool(cfg, pool_pages, page_size, dtype, quantized=quantized, packed=packed)
+        return llama.init_kv_pool(cfg, pool_pages, page_size, dtype, quantized=quantized, packed=packed,
+                                  head_sharded=head_sharded)
 
     def prefill_paged(params, cfg, caches, tokens, lengths, slots, tables, page_size, *,
                       use_flash=None, quant_kernel=None, tp=None, **_):
@@ -242,7 +247,7 @@ def _llama_family() -> ModelFamily:
 def _phi4flash_family() -> ModelFamily:
     from generativeaiexamples_tpu.models import phi4flash as m
 
-    def init_paged_cache(cfg, pool_pages, page_size, num_slots, dtype, quantized=False, packed=False):
+    def init_paged_cache(cfg, pool_pages, page_size, num_slots, dtype, quantized=False, packed=False, **_):
         if quantized or packed:
             raise ValueError("phi4flash keeps its one paged layer and its fixed state in bfloat16")
         return m.init_paged_cache(cfg, pool_pages, page_size, num_slots, dtype)
@@ -269,7 +274,7 @@ def _phi4flash_family() -> ModelFamily:
 def _glm5next_family() -> ModelFamily:
     from generativeaiexamples_tpu.models import glm5next as m
 
-    def init_paged_cache(cfg, pool_pages, page_size, num_slots, dtype, quantized=False, packed=False):
+    def init_paged_cache(cfg, pool_pages, page_size, num_slots, dtype, quantized=False, packed=False, **_):
         if quantized or packed:
             raise ValueError("glm5next keeps its latent pool and its fixed state in bfloat16")
         return m.init_paged_cache(cfg, pool_pages, page_size, num_slots, dtype)
@@ -295,7 +300,7 @@ def _glm5next_family() -> ModelFamily:
 def _gigachat35_family() -> ModelFamily:
     from generativeaiexamples_tpu.models import gigachat35 as m
 
-    def init_paged_cache(cfg, pool_pages, page_size, num_slots, dtype, quantized=False, packed=False):
+    def init_paged_cache(cfg, pool_pages, page_size, num_slots, dtype, quantized=False, packed=False, **_):
         if quantized or packed:
             raise ValueError("gigachat35 keeps its latent pool and its fixed state in bfloat16")
         return m.init_paged_cache(cfg, pool_pages, page_size, num_slots, dtype)
@@ -321,7 +326,7 @@ def _gigachat35_family() -> ModelFamily:
 def _afmoe_family() -> ModelFamily:
     from generativeaiexamples_tpu.models import afmoe as m
 
-    def init_paged_cache(cfg, pool_pages, page_size, num_slots, dtype, quantized=False, packed=False):
+    def init_paged_cache(cfg, pool_pages, page_size, num_slots, dtype, quantized=False, packed=False, **_):
         if quantized or packed:
             raise ValueError("afmoe keeps its paged full layers and its window rings in bfloat16")
         return m.init_paged_cache(cfg, pool_pages, page_size, num_slots, dtype)
@@ -346,7 +351,7 @@ def _afmoe_family() -> ModelFamily:
 def _solaropen2_family() -> ModelFamily:
     from generativeaiexamples_tpu.models import solaropen2 as m
 
-    def init_paged_cache(cfg, pool_pages, page_size, num_slots, dtype, quantized=False, packed=False):
+    def init_paged_cache(cfg, pool_pages, page_size, num_slots, dtype, quantized=False, packed=False, **_):
         if quantized or packed:
             raise ValueError("solaropen2 keeps its paged softmax layers and its fixed state in bfloat16")
         return m.init_paged_cache(cfg, pool_pages, page_size, num_slots, dtype)

@@ -26,14 +26,22 @@ Design:
   probability), so no lane shuffle ever reorders the interleaved
   ``t*Hkv + h`` columns.
 - **page-granular scales.** The int8 variant's per-(token, head) scales
-  live page-contiguous (``[P, page, Hkv]``, engine/kv_pages.py /
-  models/llama.py); they fold into the score/prob matrices after the
-  int8 dots. A page's block arrives ``[page, Hkv]``, token on sublanes
-  and head on the first lanes of a padded tile (64 KB moved for 4 KB:
-  the pool's layout, not this kernel's to change), and the scores want
-  it as the row ``[1, page * Hkv]``: ``_scale_row`` builds that row with
-  strided lane rotates, where the plain ``reshape`` relayout, twice a
-  page, was 28 % of the kernel (PERF.md §6, PR 43).
+  live page-contiguous in two float32 planes a layer and fold into the
+  score/prob matrices after the int8 dots, as the row ``[1, page *
+  Hkv]`` in the scores' own column order ``t * Hkv + h``. The POOL
+  decides how a page's scales are stored (models/llama.py
+  ``kv_scale_plane_shape``) and the kernel reads that off the operand's
+  static shape. LANE-DENSE ``[P, page * Hkv / 128, 128]`` (one device,
+  a geometry that tiles the lanes): a page's block is one 4 KB tile
+  whose sublanes, laid side by side, ARE the row. TOKEN-MAJOR ``[P,
+  page, Hkv]`` (a head-sharded pool's local tile, any other geometry):
+  token on sublanes and head on the first lanes of a padded tile, 64 KB
+  moved for 4 KB, and ``_scale_row`` builds the row with strided lane
+  rotates (the plain ``reshape`` relayout, twice a page, was 28 % of
+  the kernel, PERF.md §6, PR 43). Same values, same products, same
+  bits; lane-dense the kernel is 18 % shorter at one page a step and
+  the padded copy of every plane around every dispatch is gone
+  (PERF.md §6, PR 45).
 - **bf16 AND int8.** The ragged walk is the win, not the dequant in
   VMEM alone, so every pool dtype gets the kernel.
 - **multi-query rows.** ``q`` is ``[B, T, Hq, Dh]``: T=1 is block
@@ -71,13 +79,15 @@ Why N (``pages_per_step``): a step costs ~0.4 us before its first byte
 (0.42 us with an empty body and two 131 KB page DMAs, whose bytes are
 0.32 us of HBM time) and ~0.1 us more for every operand it names, so a
 page that is cheap to move and to compute shares its step: two
-single-query bfloat16 pages a step wherever two page pairs fit
-``_STEP_BYTES``. Four a step measured no better than two at any served
-geometry (what is left is per page: a DMA's issue and wait, the softmax
-over ``[Hq, page * Hkv]``); a quantised page (four blocks a page) and a
-multi-query page (spec verify, the folded extend read: compute-heavy)
-measured slower paired. So N is 1 or 2 and follows the static shapes
-alone: no setting.
+single-query pages a step wherever two page pairs fit ``_STEP_BYTES``,
+bfloat16 or quantised with lane-dense scale planes (int8 at 32/8 heads:
+0.256 -> 0.249 ms a layer, -3 %; 0.264 at four). Four a step measured
+no better than two at any served geometry (what is left is per page: a
+DMA's issue and wait, the softmax over ``[Hq, page * Hkv]``); a
+quantised page whose scale blocks are token-major (padded to 64 KB
+each) and a multi-query page (spec verify, the folded extend read:
+compute-heavy, +6-7 % paired over lane-dense planes too) measured slower
+paired. So N is 1 or 2 and follows the static shapes alone: no setting.
 
 Masks: the token clamp and the own-head lane mask are rebuilt on every
 page. Together they are 1.4 % of a page; building the head mask once and
@@ -124,24 +134,31 @@ def _unpack_nibbles(u):
     return jnp.concatenate([lo, hi], axis=-1).astype(jnp.bfloat16)
 
 
-def _scale_row(s_ref):
-    """A page's scales ``[page, Hkv]`` (token on sublanes, head on the
-    first ``Hkv`` lanes of a padded tile) as the ``[1, page * Hkv]`` row
-    the scores multiply by, column ``c = t * Hkv + h``.
+def _scale_row(s_ref, page: int, hkv: int):
+    """A page's scales as the ``[1, page * Hkv]`` row the scores multiply
+    by, column ``c = t * Hkv + h``. The block's own static shape says
+    how the pool stores them (models/llama.py ``kv_scale_plane_shape``).
 
-    Eight tokens share a vreg; a lane tile of the row holds ``128 / Hkv``
-    tokens. One STRIDED lane rotate a vreg (sublane ``s`` moves by ``s *
-    Hkv`` lanes more than its neighbour) puts every token's heads at
-    their lanes, a select keeps them, and one sublane sum a lane tile
-    (seven exact zeros and the value) collapses the eight tokens into
-    the row: 16 rotates and 8 sums for ``[128, 8]``, where the plain
-    ``reshape(1, cols)`` relayout was 0.17 us a block, twice a page, 28 %
-    of the kernel (PERF.md §6, PR 43). Geometries the rotate does not
-    tile (a head count that does not divide 128 into whole vregs) keep
-    the reshape."""
+    LANE-DENSE ``[page * Hkv / 128, 128]``: the block IS the row, cut
+    into sublanes (one 4 KB tile a page); laying the sublanes side by
+    side is all there is to do.
+
+    TOKEN-MAJOR ``[page, Hkv]`` (a head-sharded pool's local tile, a
+    geometry that does not tile 128 lanes): token on sublanes, head on
+    the first ``Hkv`` lanes of a padded tile. Eight tokens share a vreg;
+    a lane tile of the row holds ``128 / Hkv`` tokens. One STRIDED lane
+    rotate a vreg (sublane ``s`` moves by ``s * Hkv`` lanes more than
+    its neighbour) puts every token's heads at their lanes, a select
+    keeps them, and one sublane sum a lane tile (seven exact zeros and
+    the value) collapses the eight tokens into the row: 16 rotates and
+    8 sums for ``[128, 8]``, where the plain ``reshape(1, cols)``
+    relayout was 0.17 us a block, twice a page (PERF.md §6, PR 43).
+    Geometries the rotate does not tile (a head count that does not
+    divide 128 into whole vregs) keep the reshape."""
     s = s_ref[0]
-    page, hkv = s.shape
     cols = page * hkv
+    if s.shape != (page, hkv):  # lane-dense
+        return jnp.concatenate([s[r:r + 1] for r in range(s.shape[0])], axis=1)
     if _LANE % (8 * hkv) or cols % _LANE or page % 8:
         return s.reshape(1, cols)
     vregs = _LANE // (8 * hkv)  # 8-token vregs a lane tile of the row
@@ -207,7 +224,7 @@ def _kernel(
             # page-granular K scales fold in AFTER the int8/int4 dot
             # (small integers convert to bf16 exactly, so the MXU saw
             # exact operands)
-            sc = sc * (_scale_row(ks_ref) * scale)
+            sc = sc * (_scale_row(ks_ref, page, hkv) * scale)
         else:
             sc = sc * scale
         col_iota = lax.broadcasted_iota(jnp.int32, (rows, cols), 1)
@@ -235,7 +252,7 @@ def _kernel(
         )
         m_ref[...] = jnp.broadcast_to(m_new, m_ref.shape)
         if quantized:
-            prob = prob * _scale_row(vs_ref)
+            prob = prob * _scale_row(vs_ref, page, hkv)
         if packed:
             v_cat = _unpack_nibbles(v_ref[0].reshape(cols, dh // 2))
         else:
@@ -294,32 +311,40 @@ _STEP_BYTES = 3 << 19  # 1.5 MiB
 def pages_per_step(k, k_scale=None, query_len: int = 1) -> int:
     """Pages of one row a grid step carries (``N``, 1 or 2), from what
     is static: the pool's dtype, the bytes a page's block specs move
-    against ``_STEP_BYTES``, and the queries a row holds. A step costs
-    ~0.4 us before its first byte, and ~0.1 us more for every operand it
-    names; a single-query bfloat16 page whose bytes and arithmetic take
-    about as long shares its step with its successor (-6 % and -12 % on
-    the two head-major reads; four pages a step measured no better than
-    two anywhere: what is left is per page). Three kinds of page keep
-    their own step because pairing them measured SLOWER: a quantised
-    page (four blocks a page, two of them scale blocks padded to 64 KB,
-    and the relayout of each: +14 % paired), a page of a multi-query
-    row (spec verify, the folded extend read: compute-heavy, +3-8 %),
-    and a page of a megabyte or more (PERF.md §6, PR 43). ``k`` /
-    ``k_scale`` are the pool arrays (or their shape structs)."""
+    against ``_STEP_BYTES``, the shape of its scale planes and the
+    queries a row holds. A step costs ~0.4 us before its first byte,
+    and ~0.1 us more for every operand it names; a single-query page
+    whose bytes and arithmetic take about as long shares its step with
+    its successor (bfloat16: -6 % and -12 % on the two head-major
+    reads; quantised with lane-dense scale planes, 4 KB a block: -3 %;
+    four pages a step measured no better than two anywhere: what is
+    left is per page). Three kinds of page keep their own step because
+    pairing them measured SLOWER: a quantised page whose scale planes
+    are token-major (two of its four blocks padded to 64 KB, and the
+    relayout of each: +14 % paired), a page of a multi-query row (spec
+    verify, the folded extend read: compute-heavy, +3-8 %; +6-7 % over
+    lane-dense planes), and a page of a megabyte or more (PERF.md §6,
+    PR 43 and PR 45). ``k`` / ``k_scale`` are the pool arrays (or their
+    shape structs)."""
     pair = 2 * math.prod(k.shape[1:]) * jnp.dtype(k.dtype).itemsize
-    paired = k_scale is None and query_len == 1 and 2 * pair <= _STEP_BYTES
+    padded_scales = k_scale is not None and tuple(k_scale.shape[1:]) == tuple(k.shape[1:3])
+    paired = not padded_scales and query_len == 1 and 2 * pair <= _STEP_BYTES
     return 2 if paired else 1
 
 
 def pool_pages_per_step(page_size: int, num_kv_heads: int, head_dim: int,
-                        dtype, quantized: bool = False) -> int:
+                        dtype, scale_plane=None) -> int:
     """``pages_per_step`` of a pool known by its geometry alone (the
     engine's host-side step count holds no pool array); ``dtype`` is the
-    pool's own. A quantised pool's answer does not depend on its bytes,
-    so the packed int4 pool's halved rows need no case here."""
-    k = jax.ShapeDtypeStruct((1, page_size, num_kv_heads, head_dim), dtype)
-    scales = jax.ShapeDtypeStruct((1, page_size, num_kv_heads), jnp.float32)
-    return pages_per_step(k, scales if quantized else None)
+    pool's own and ``scale_plane`` a quantised pool's scale plane, one
+    page of it, as the pool stores it (``ks.shape[1:]``; None: no
+    scales). The packed int4 pool's rows hold ``head_dim // 2`` bytes."""
+    packed = jnp.dtype(dtype) == jnp.uint8
+    k = jax.ShapeDtypeStruct(
+        (1, page_size, num_kv_heads, head_dim // 2 if packed else head_dim), dtype
+    )
+    scales = scale_plane and jax.ShapeDtypeStruct((1,) + tuple(scale_plane), jnp.float32)
+    return pages_per_step(k, scales)
 
 
 def page_work_list(
@@ -372,8 +397,8 @@ def paged_attention(
     v: jax.Array,  # [P, page, Hkv, Dh]
     tables: jax.Array,  # [B, Pmax] int32 physical page ids per row
     positions: jax.Array,  # [B] int32 — FIRST query token's position
-    k_scale: Optional[jax.Array] = None,  # [P, page, Hkv] f32 (int8)
-    v_scale: Optional[jax.Array] = None,
+    k_scale: Optional[jax.Array] = None,  # f32 [P, page * Hkv / 128, 128]
+    v_scale: Optional[jax.Array] = None,  # (lane-dense) or [P, page, Hkv]
     *,
     interpret: bool = False,
     work: Optional[PageWork] = None,
@@ -422,6 +447,10 @@ def paged_attention(
         assert Dh_pool * 2 == Dh, (Dh_pool, Dh)
     else:
         assert Dh_pool == Dh, (Dh_pool, Dh)
+    if quantized:
+        assert k_scale.shape == v_scale.shape and k_scale.shape[1:] in (
+            (page, Hkv), (page * Hkv // _LANE, _LANE)
+        ), (k_scale.shape, v_scale.shape, page, Hkv)
     S = Pmax * page
     scale = 1.0 / math.sqrt(Dh)
     pos = positions.astype(jnp.int32)
@@ -438,8 +467,11 @@ def paged_attention(
         )
 
     def scale_spec(n):
+        # one page of the plane as the pool stores it: [page, Hkv], or
+        # lane-dense [page * Hkv / 128, 128] (a 4 KB block)
         return pl.BlockSpec(
-            (1, page, Hkv), lambda i, row, pg, phys, pos: (phys[i * N + n], 0, 0)
+            (1,) + k_scale.shape[1:],
+            lambda i, row, pg, phys, pos: (phys[i * N + n], 0, 0),
         )
 
     def row_spec():

@@ -166,7 +166,9 @@ def shard_draft_kv_cache(caches, mesh: Mesh, quantized: bool):
 
 def kv_pool_specs(quantized: bool) -> Dict[str, P]:
     """One layer's PAGE-POOL leaf specs (init_kv_pool layouts):
-    [P, page, Hkv, Dh] token-major, scales [P, page, Hkv]. KV heads ride
+    [P, page, Hkv, Dh] token-major, scales [P, page, Hkv] (the engine
+    builds a pool it will shard with ``head_sharded=True``, so its scale
+    planes keep the head dimension these specs shard). KV heads ride
     the model axis (the per-page gather is position-only, so every shard
     gathers its own heads' rows); pages are replicated over data —
     any slot's table may reference any page."""

@@ -184,7 +184,8 @@ def paged_attention_tp(
 
     q [B, T, Hq, Dh]; pools token-major [P, page, Hkv, Dh] (bf16/int8;
     uint8 [P, page, Hkv, Dh//2] for packed int4) with optional
-    page-granular scales [P, page, Hkv] — exactly the axes
+    page-granular scales, TOKEN-MAJOR [P, page, Hkv] (a head-sharded
+    pool never stores them lane-dense) — exactly the axes
     parallel/sharding.kv_pool_specs pins to ``model``, so each device's
     NamedSharding slice is a self-contained pool for its own KV heads.
     Page tables and positions replicate (scalar-prefetched inside the

@@ -21,6 +21,7 @@ import jax.numpy as jnp
 import pytest
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P, SingleDeviceSharding
 
+from generativeaiexamples_tpu.models import llama
 from generativeaiexamples_tpu.ops import (
     delta_rule,
     flash_attention,
@@ -122,10 +123,9 @@ def _paged_args(sharding, T, kv_dtype, head_spec=None, B=16, pages_per_row=32):
         s((B,), jnp.int32, P()),
     ]
     if quantized:
-        args += [
-            s((n_pages, PAGE, HKV), jnp.float32, P(None, None, h)),
-            s((n_pages, PAGE, HKV), jnp.float32, P(None, None, h)),
-        ]
+        # as init_kv_pool stores them: lane-dense on one chip, [P, page, Hkv] where the heads are sharded
+        plane = (n_pages,) + llama.kv_scale_plane_shape(PAGE, HKV, head_sharded=h is not None)
+        args += [s(plane, jnp.float32, P(None, None, h))] * 2
     return args
 
 
@@ -148,12 +148,13 @@ def test_paged_attention_compiles(one_chip, no_persistent_cache, T, kv_dtype):
 @pytest.mark.parametrize(
     "T,heads,kv_dtype,head_major,n",
     [
-        (1, (32, 8), jnp.int8, False, 1),  # Mistral's int8 token-major pool
+        (1, (32, 8), jnp.int8, False, 2),  # Mistral's int8 token-major pool, scale planes lane-dense [P, 8, 128]
         (16, (32, 8), jnp.int8, False, 1),  # ... its folded extend read: 512 score rows a page
+        (1, (32, 8), "int8-head-sharded", False, 1),  # ... scale planes [P, 128, 8], as a head-sharded pool keeps them
         (1, (40, 10), jnp.bfloat16, True, 2),  # Phi-4-flash's pair layout
         (1, (32, 4), jnp.bfloat16, True, 2),  # Trinity-Mini's full layer
     ],
-    ids=["mistral-int8", "mistral-int8-fold16", "phi4flash-pairs", "trinity-32-4"],
+    ids=["mistral-int8", "mistral-int8-fold16", "mistral-int8-token-major-scales", "phi4flash-pairs", "trinity-32-4"],
 )
 def test_grouped_paged_attention_compiles_at_the_served_geometries(
     one_chip, no_persistent_cache, T, heads, kv_dtype, head_major, n
@@ -168,8 +169,14 @@ def test_grouped_paged_attention_compiles_at_the_served_geometries(
     def s(shape, dtype):
         return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
 
+    head_sharded = kv_dtype == "int8-head-sharded"
+    kv_dtype = jnp.int8 if head_sharded else kv_dtype
     pool = s((n_pages, hkv, PAGE, DH) if head_major else (n_pages, PAGE, hkv, DH), kv_dtype)
-    scales = [s((n_pages, PAGE, hkv), jnp.float32)] * 2 if kv_dtype == jnp.int8 else []
+    scales = []
+    if kv_dtype == jnp.int8:
+        plane = llama.kv_scale_plane_shape(PAGE, hkv, head_sharded)
+        assert plane == ((PAGE, hkv) if head_sharded else (PAGE * hkv // 128, 128))
+        scales = [s((n_pages,) + plane, jnp.float32)] * 2
     assert page_attention.pages_per_step(pool, *scales[:1], query_len=T) == n
 
     for pages in (None, 4):

@@ -42,7 +42,7 @@ METRICS_HOT = METRICS_KERNEL + 'genai_engine_hot_path_compiles_total{program="ex
 LOG_OK = textwrap.dedent(
     """\
     2026-09-26 INFO x: ragged page-attention kernel serving paged decode (compiled, page_size=128)
-    2026-09-26 INFO x: resolved kernel paths: quant_kernel=True paged_kernel=compiled paged_verify_kernel=compiled tp_kernels=None (backend=tpu, devices=1)
+    2026-09-26 INFO x: resolved kernel paths: quant_kernel=True paged_kernel=compiled paged_verify_kernel=compiled tp_kernels=None kv_scales=lane_dense (backend=tpu, devices=1)
     2026-09-26 INFO x: Engine warmup complete for prompt lengths [512] (engine build 61.5 s, warmup 244.0 s; device memory: dev0 in_use=9.90GB peak=11.20GB limit=16.91GB)
     """
 )
@@ -74,7 +74,7 @@ def test_hot_path_compile_fails_the_metrics_check(smoke):
 
 def test_server_log_check_reads_resolved_paths(smoke):
     paths = smoke.check_server_log(LOG_OK, want_compiled=True)
-    assert paths["paged_kernel"] == "compiled"
+    assert paths["paged_kernel"] == "compiled" and paths["kv_scales"] == "lane_dense"
     assert paths["engine_build_s"] == 61.5 and paths["warmup_s"] == 244.0
     assert "peak=11.20GB" in paths["device_memory"]
 
@@ -85,11 +85,14 @@ def test_server_log_check_reads_resolved_paths(smoke):
         (lambda s: s.replace("paged_kernel=compiled", "paged_kernel=None"), "not compiled"),
         (lambda s: s.replace("quant_kernel=True", "quant_kernel=False"), "int8 matmul"),
         (lambda s: s.replace("backend=tpu", "backend=cpu"), "not tpu"),
+        (lambda s: s.replace("kv_scales=lane_dense", "kv_scales=token_major"), "scale planes"),
+        (lambda s: s.replace("kv_scales=lane_dense ", ""), "scale planes"),  # a program from before the layout had a name
         (lambda s: s + "Traceback (most recent call last):\n", "traceback"),
         (lambda s: s + "WARNING ragged page-attention kernel REFUSED this geometry\n", "REFUSED"),
         (lambda s: s + "ERROR COMPILE ON HOT PATH: extend\n", "hot-path"),
     ],
-    ids=["gather-resolved", "xla-matmul", "cpu-backend", "traceback", "refused", "hot-compile"],
+    ids=["gather-resolved", "xla-matmul", "cpu-backend", "token-major-scales", "unnamed-scales", "traceback", "refused",
+         "hot-compile"],
 )
 def test_server_log_check_fails_on(smoke, mutate, match):
     with pytest.raises(smoke.SmokeFailure, match=match):
@@ -113,7 +116,7 @@ device = {device!r}
 m.run_child = lambda phase, args, log, timeout, extra_env=None: (0, [{{"device": device}}])
 m.phase_server = lambda args, preset: dict(
     answers=5, engine_build_s=1.0, warmup_s=2.0, quant_kernel="True",
-    paged_kernel="compiled", paged_verify_kernel="compiled",
+    paged_kernel="compiled", paged_verify_kernel="compiled", kv_scales="lane_dense",
     backend=device["platform"], devices=1, device_memory="device memory: stub",
 )
 m.OUT = {out!r}

@@ -295,3 +295,112 @@ def test_metrics_snapshot_moves():
         "kv_page_allocs", "kv_page_frees", "kv_page_alloc_failures",
         "kv_prefix_pages_mapped", "kv_pages_in_use", "kv_page_utilization",
     }
+
+
+# --------------------------------------------------------------------- #
+# how a quantised pool stores a page's scales (models/llama.py
+# kv_scale_plane_shape), and every writer of them against the reader
+
+
+@pytest.mark.parametrize(
+    "page,hkv,head_sharded,plane",
+    [
+        (128, 8, False, (8, 128)),  # the benchmark's int8 cell: one 4 KB tile a page
+        (16, 8, False, (1, 128)),
+        (64, 2, False, (1, 128)),
+        (128, 8, True, (128, 8)),  # heads sharded over a mesh: the head dimension stays
+        (8, 8, False, (8, 8)),  # 64 scales a page: no whole 128-lane row
+        (16, 2, False, (16, 2)),  # the debug engines
+        (128, 10, False, (128, 10)),  # 10 heads: a token's scales would straddle two rows
+        (128, 128, False, (128, 128)),  # the two layouts coincide
+    ],
+)
+def test_scale_plane_shape_follows_the_geometry(page, hkv, head_sharded, plane):
+    import jax
+
+    from generativeaiexamples_tpu.models import llama
+
+    assert llama.kv_scale_plane_shape(page, hkv, head_sharded) == plane
+    cfg = llama.LlamaConfig(vocab_size=32, hidden_size=16, intermediate_size=16, num_layers=1,
+                            num_heads=hkv, num_kv_heads=hkv, head_dim=2, max_seq_len=page)
+    for packed in (False, True):
+        pool = jax.eval_shape(lambda: llama.init_kv_pool(
+            cfg, 5, page, quantized=True, packed=packed, head_sharded=head_sharded))[0]
+        assert pool["ks"].shape == pool["vs"].shape == (5,) + plane
+        assert pool["k"].shape[:3] == (5, page, hkv)  # the values stay token-major
+
+
+WRITER_PAGE, WRITER_PMAX, WRITER_SLOTS = 32, 4, 3
+
+
+def _writer_run(writer, caches, params, cfg):
+    """One walk that writes K/V scales; returns (what it computed, pools)."""
+    import jax.numpy as jnp
+    import numpy as np
+
+    from generativeaiexamples_tpu.models import llama
+
+    page, pmax = WRITER_PAGE, WRITER_PMAX
+    rng = np.random.default_rng(11)
+    tables = jnp.asarray(1 + np.arange(WRITER_SLOTS * pmax).reshape(WRITER_SLOTS, pmax), jnp.int32)
+    tok = lambda *shape: jnp.asarray(rng.integers(1, cfg.vocab_size, shape), jnp.int32)
+    i32 = lambda x: jnp.asarray(x, jnp.int32)
+    if writer == "prefill_wave":  # rows of 40 tokens: a page boundary inside every row
+        kvs = [tuple(jnp.asarray(rng.standard_normal((2, 40, cfg.num_kv_heads, cfg.head_dim)), jnp.float32)
+                     for _ in "kv") for _ in caches]
+        return None, llama.write_prefill_pages(caches, kvs, tables[i32([2, 0])], page)
+    if writer == "rectangular_chunk":  # the verify walk: a row across a page boundary, a half-dead row, a dead row
+        return llama.verify_layers_paged(
+            params, cfg, tok(3, 8), i32([page - 3, 5, 80]), i32([8, 4, 0]), i32([1, 0, 2]), tables, caches,
+            pmax * page, page)
+    if writer == "packed_wave":  # 32 tokens: 19 of slot 2 from 29 (into its second page), a gap, 6 of slot 0 from 0
+        return llama.extend_layers_packed(
+            params, cfg, tok(32), i32([0, 24]), i32([19, 6]), i32([29, 0]), i32([2, 0]), tables, caches, page,
+            seg=32, windows=(pmax * page,))
+    assert writer == "decode_step"  # a page's last and first token, and a dead row on the scratch page
+    return llama.decode_layers_paged(
+        params, cfg, tok(3), i32([page - 1, page, 0]), jnp.asarray([True, True, False]), tables, caches,
+        window=pmax * page, page_size=page)
+
+
+@pytest.mark.parametrize("kv", ["int8", "int4"])
+@pytest.mark.parametrize("writer", ["prefill_wave", "rectangular_chunk", "packed_wave", "decode_step"])
+def test_every_writer_of_scales_and_the_gather_reader_agree_across_layouts(writer, kv):
+    """Each walk that writes a quantised pool, over a LANE-DENSE pool and
+    over a token-major one (what a head-sharded pool keeps): the gather
+    reader returns the same scales from both on every page a request can
+    hold (rows across a page boundary, a half-dead row, a dead row),
+    nonzero exactly at the live tokens, and what the walk computed
+    through the gather read (the int4 pool's only read at this head
+    size) is the same to the bit."""
+    import jax.numpy as jnp
+    import numpy as np
+
+    from generativeaiexamples_tpu.models import llama
+
+    cfg = llama.PRESETS["debug-8dev"]  # 8 KV heads x 32-token pages: two 128-lane rows a page
+    params = llama.consume_split_params_layers(llama.init_params_fast(cfg, 0, jnp.float32))
+    pool = 1 + WRITER_SLOTS * WRITER_PMAX
+    outs = {}
+    for layout in ("lane_dense", "token_major"):
+        caches = llama.init_kv_pool(cfg, pool, WRITER_PAGE, jnp.float32, quantized=True, packed=kv == "int4",
+                                    head_sharded=layout == "token_major")
+        assert caches[0]["ks"].shape[1:] == ((2, 128) if layout == "lane_dense" else (WRITER_PAGE, 8))
+        outs[layout] = _writer_run(writer, caches, params, cfg)
+    (got, dense), (want, token_major) = outs["lane_dense"], outs["token_major"]
+    if want is not None:
+        np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
+    every_page = jnp.arange(pool, dtype=jnp.int32)[None]
+    live_tokens = {"prefill_wave": 2 * 40, "rectangular_chunk": 8 + 4, "packed_wave": 19 + 6, "decode_step": 2}[writer]
+    for a, b in zip(dense, token_major):
+        for key in ("ks", "vs"):
+            rows = np.asarray(llama.gather_kv_scales(a[key], every_page, pool, WRITER_PAGE))[0]
+            assert rows.shape == (pool * WRITER_PAGE, 8)
+            # every page a request can hold, to the bit; the scratch page
+            # takes the token-major walk's dead tokens and none of the
+            # lane-dense walk's, which rebuilds its live tokens' rows only
+            np.testing.assert_array_equal(rows[WRITER_PAGE:], np.asarray(b[key]).reshape(rows.shape)[WRITER_PAGE:])
+            assert not rows[:WRITER_PAGE].any(), key
+            assert (rows != 0).all(-1).sum() == (rows != 0).any(-1).sum() == live_tokens, key
+        for key in ("k", "v"):
+            np.testing.assert_array_equal(np.asarray(a[key]), np.asarray(b[key]))

@@ -97,7 +97,8 @@ def _live_rows(cache, prompts):
     """Each pool array's rows at the wave's live positions."""
     out = {}
     for key, buf in cache.items():
-        buf = np.asarray(buf)[1:].reshape((R, PMAX * PAGE) + buf.shape[2:])
+        # (a scale plane holds a page's scales in the flat order t * Hkv + h, in either layout)
+        buf = np.asarray(buf)[1:].reshape((R, PMAX * PAGE) + (buf.shape[2:] if buf.ndim == 4 else (-1,)))
         out[key] = [buf[i, :len(p)] for i, p in enumerate(prompts)]
     return out
 

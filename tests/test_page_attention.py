@@ -45,6 +45,20 @@ def _int8_pool(rng, pool=POOL, hkv=Hkv):
     return kq, vq, ks, vs
 
 
+# KV heads at which the tests' 8-token pages tile 128 lanes (two rows a page)
+DENSE_HKV = 32
+
+
+def _lane_dense(pool):
+    """The same pool with its scale planes as ``init_kv_pool`` stores
+    them where a page's scales tile the lanes: [P, page * Hkv / 128, 128]
+    (the same values in the same flat order ``t * Hkv + h``)."""
+    k, v, ks, vs = pool
+    plane = (ks.shape[0],) + llama.kv_scale_plane_shape(k.shape[1], k.shape[2])
+    assert plane[2] == 128 and plane != ks.shape, plane
+    return k, v, ks.reshape(plane), vs.reshape(plane)
+
+
 def _reference(q, k, v, tables, pos, ks=None, vs=None):
     """Pure-jnp gather-all-pages + position mask — the same semantics
     models/llama.py's paged XLA paths compute (f32 softmax over the
@@ -187,7 +201,7 @@ WALK_CASES = {
 }
 WALK_POOL = 40
 # pages a step of the three served geometries (ops/page_attention.pages_per_step)
-RULE = {"mistral": 1, "trinity": 2, "phi4flash": 2}
+RULE = {"mistral": 2, "trinity": 2, "phi4flash": 2}
 
 
 def _walk_tables(pos, t):
@@ -313,31 +327,40 @@ def _walk(q, pool, tables, pos, n, **kw):
     ), np.float32)
 
 
-@pytest.mark.parametrize("pool", ["int8", "int4", "bf16"])
+@pytest.mark.parametrize("pool", ["int8", "int4", "bf16", "int8-lane-dense", "int4-lane-dense"])
 @pytest.mark.parametrize("n", [2, 4])
 def test_group_edges_are_bit_equal_to_one_page_a_step(n, pool):
     """Rows of 1, N-1, N, N+1 and 2N+1 live pages: a group walks its
     pages in ascending order through the one-page arithmetic, so every
     row keeps the bits of the one-page-a-step walk (and the gather's
-    values)."""
+    values). Over LANE-DENSE scale planes (the row whose last group has
+    a dead place among them) the bits are those of the token-major
+    planes of the same values."""
     pos, live = _group_rows(n)
     tables, used = _group_tables(live)
     rng = np.random.default_rng(n * 31 + len(pool))
-    if pool == "bf16":
-        kernel_pool = ref_pool = _bf16_pool(rng, used, 8)
-    elif pool == "int8":
-        kernel_pool = ref_pool = _int8_pool(rng, used, 8)
+    kind, _, dense = pool.partition("-")
+    hkv = DENSE_HKV if dense else 8
+    if kind == "bf16":
+        kernel_pool = ref_pool = _bf16_pool(rng, used, hkv)
+    elif kind == "int8":
+        kernel_pool = ref_pool = _int8_pool(rng, used, hkv)
     else:
-        kq, vq, ks, vs = _int4_pool(rng, used, 8)
+        kq, vq, ks, vs = _int4_pool(rng, used, hkv)
         kernel_pool, ref_pool = (kq, vq, ks, vs), (_unpack_pool(kq), _unpack_pool(vq), ks, vs)
     q = jnp.asarray(rng.standard_normal((len(pos), 1, 32, Dh)), jnp.bfloat16)
     one = _walk(q, kernel_pool, tables, pos, 1)
+    if dense:
+        token_major, kernel_pool = kernel_pool, _lane_dense(kernel_pool)
+        np.testing.assert_array_equal(_walk(q, kernel_pool, tables, pos, 1), one)
+        np.testing.assert_array_equal(_walk(q, kernel_pool, tables, pos, n), _walk(q, token_major, tables, pos, n))
     got = _walk(q, kernel_pool, tables, pos, n)
     np.testing.assert_array_equal(got, one)
     rk, rv, *rs = ref_pool
     _assert_close(got, _reference(q, rk, rv, tables, jnp.asarray(pos, jnp.int32), *rs))
 
 
+@pytest.mark.parametrize("planes", ["token_major", "lane_dense"])
 @pytest.mark.parametrize("n", [1, 2, 4])
 @pytest.mark.parametrize(
     "t,first,folded",
@@ -348,7 +371,7 @@ def test_group_edges_are_bit_equal_to_one_page_a_step(n, pool):
     ],
     ids=["verify4", "fold8", "fold8-midpage"],
 )
-def test_chunk_with_a_first_position_inside_a_page(n, t, first, folded):
+def test_chunk_with_a_first_position_inside_a_page(n, t, first, folded, planes):
     """A verify chunk and the folded extend read with first positions
     in the middle of a page: query ``i`` of a row sees tokens up to
     ``first + i`` and no further, on the page that holds ``first`` and on
@@ -363,15 +386,19 @@ def test_chunk_with_a_first_position_inside_a_page(n, t, first, folded):
     else:
         live = [(p + t - 1) // PAGE + 1 for p in first]
         tables, used = _group_tables(live)
-    pool = _int8_pool(rng, used, 8)
+    pool = _int8_pool(rng, used, DENSE_HKV if planes == "lane_dense" else 8)
+    k, v, ks, vs = token_major = pool
+    if planes == "lane_dense":
+        pool = _lane_dense(pool)
     # keys of distinct sizes, so a leaked future token moves the output
     q = jnp.asarray(4 * rng.standard_normal((len(first), t, 32, Dh)), jnp.bfloat16)
     got = _walk(q, pool, tables, first, n)
-    k, v, ks, vs = pool
     ref = np.asarray(_reference(q, k, v, tables, jnp.asarray(first, jnp.int32), ks, vs))
     rows = np.asarray(first) > 0
     np.testing.assert_allclose(got[rows], ref[rows], atol=0.02)
     np.testing.assert_array_equal(got[rows], _walk(q, pool, tables, first, 1)[rows])
+    # the bits do not depend on how the pool stores a page's scales
+    np.testing.assert_array_equal(got[rows], _walk(q, token_major, tables, first, n)[rows])
     # the control: the same read one position too far IS told apart
     late = _walk(q, pool, tables, [p + 1 if p else 0 for p in first], n)
     assert np.abs(late[rows] - ref[rows]).max() > 0.05
@@ -435,15 +462,18 @@ def test_grouped_work_list(case, t, n):
     assert int(work.row.max()) < len(pos) and int(work.phys.max()) < WALK_POOL
 
 
+@pytest.mark.parametrize("planes", ["token_major", "lane_dense"])
 @pytest.mark.parametrize("n", [2, 4])
-def test_dead_places_of_a_group_are_never_read(n):
+def test_dead_places_of_a_group_are_never_read(n, planes):
     """Point every dead place of the list at a pool page of NaNs: the
     output does not change by a bit."""
     pos, live = _group_rows(n)
     tables, used = _group_tables(live)
     rng = np.random.default_rng(5 + n)
-    k, v, ks, vs = _int8_pool(rng, used + 1, 8)
+    k, v, ks, vs = _int8_pool(rng, used + 1, DENSE_HKV if planes == "lane_dense" else 8)
     ks, vs = ks.at[used].set(jnp.nan), vs.at[used].set(jnp.nan)
+    if planes == "lane_dense":
+        k, v, ks, vs = _lane_dense((k, v, ks, vs))
     q = jnp.asarray(rng.standard_normal((len(pos), 1, 32, Dh)), jnp.bfloat16)
     posj = jnp.asarray(pos, jnp.int32)
     work = pa.page_work_list(tables, posj, 1, PAGE, n)
@@ -481,20 +511,23 @@ def test_the_list_a_latent_read_builds_is_one_page_an_item():
 @pytest.mark.parametrize(
     "shape,dtype,scales,expect",
     [
-        ((128, 8, 128), "int8", True, RULE["mistral"]),  # quantised: four blocks a page
-        ((4, 128, 128), "bfloat16", False, RULE["trinity"]),  # 262,144 B
-        ((10, 128, 128), "bfloat16", False, RULE["phi4flash"]),  # 655,360 B
-        ((128, 8, 64), "uint8", True, 1),  # the packed int4 pool: quantised pages walk alone
-        ((128, 32, 128), "bfloat16", False, 1),  # 2 MB a pair: bound by its bytes, walks alone
-        ((8, 2, 16), "bfloat16", False, 2),  # the tests' tiny pages
+        ((128, 8, 128), "int8", (8, 128), RULE["mistral"]),  # lane-dense scales: 4 KB a block, pairs
+        ((128, 8, 128), "int8", (128, 8), 1),  # token-major scales (a head-sharded pool): padded blocks, walks alone
+        ((4, 128, 128), "bfloat16", None, RULE["trinity"]),  # 262,144 B
+        ((10, 128, 128), "bfloat16", None, RULE["phi4flash"]),  # 655,360 B
+        ((128, 8, 64), "uint8", (8, 128), 2),  # the packed int4 pool, lane-dense
+        ((128, 8, 64), "uint8", (128, 8), 1),  # ... token-major
+        ((128, 32, 128), "bfloat16", None, 1),  # 2 MB a pair: bound by its bytes, walks alone
+        ((8, 2, 16), "bfloat16", None, 2),  # the tests' tiny pages
     ],
 )
 def test_pages_a_step_follow_the_bytes_of_a_page(shape, dtype, scales, expect):
     """N is a function of what is static alone: the pool's dtype, the
-    bytes a page moves and the queries a row holds (a quantised page and
-    a multi-query page keep their own step)."""
+    bytes a page moves, how the pool stores a page's scales and the
+    queries a row holds (a page with padded scale blocks and a
+    multi-query page keep their own step)."""
     k = jax.ShapeDtypeStruct((7,) + shape, dtype)
-    s = jax.ShapeDtypeStruct((7, 128, 8), jnp.float32) if scales else None
+    s = jax.ShapeDtypeStruct((7,) + scales, jnp.float32) if scales else None
     assert pa.pages_per_step(k, s) == expect
     assert pa.pages_per_step(k, s, query_len=5) == 1
     if shape[0] == 128:  # token-major: the engine's host-side form agrees
@@ -760,46 +793,55 @@ def test_paged_attention_tp_int4_matches_single_device(tp_ctx):
 # the counter the walk brings: decode spans of the dispatch timeline
 
 
-def test_decode_span_carries_pages_walked_and_the_dense_grid():
+@pytest.mark.parametrize("pool", ["bfloat16", "int8-lane-dense"])
+def test_decode_span_carries_pages_walked_and_the_dense_grid(pool):
     """A served decode dispatch records what the kernel walks at its
     first step (live rows' pages up to the query position, one scratch
     page per empty slot — page_work_list's count, from the host's
     position shadow) beside the slots x Pmax grid it replaced, and the
-    grid steps that carry those pages (``kv_page_steps``)."""
+    grid steps that carry those pages (``kv_page_steps``): two pages of
+    a row a step for the bfloat16 pool and for the int8 pool whose scale
+    planes are lane-dense (the debug model's 2 KV heads at 64-token
+    pages), as the engine's ``resolved kernel paths:`` line names them."""
     from generativeaiexamples_tpu.config import EngineConfig
     from generativeaiexamples_tpu.engine import dispatch_timeline as dtl
     from generativeaiexamples_tpu.engine.llm_engine import LLMEngine, SamplingParams
 
+    page, seq, n_prompt, kv = (8, 64, 20, {}) if pool == "bfloat16" else (64, 256, 160, {"kv_cache_dtype": "int8"})
+    pmax = seq // page
     dtl.reset()
     dtl.configure(enable=True)
     eng = LLMEngine(EngineConfig(
-        model_config_name="debug", max_batch_size=3, max_seq_len=64,
-        prefill_chunk=16, decode_block=4, decode_runahead=1,
-        tensor_parallelism=1, page_size=8,
-        paged_kernel="interpret", watchdog_stall_s=0.0,
+        model_config_name="debug-1k", max_batch_size=3, max_seq_len=seq,
+        prefill_chunk=2 * page, decode_block=4, decode_runahead=1,
+        tensor_parallelism=1, page_size=page,
+        paged_kernel="interpret", watchdog_stall_s=0.0, **kv,
     ))
     try:
         assert eng._paged_kernel == "interpret"
-        prompt = list(range(5, 25))  # 20 tokens: first decode query at 20
+        assert eng._kv_scale_layout() == (None if pool == "bfloat16" else "lane_dense")
+        prompt = [5 + i % 200 for i in range(n_prompt)]  # first decode query at n_prompt
         params = SamplingParams(temperature=0.0, max_tokens=9, seed=1)
         assert len(list(eng.iter_ids(prompt, params, timeout=300))) == 9
         spans, _ = dtl.spans_since(0)
         decode = [v for v in spans if v["kind"] == "decode"]
         assert decode and all(v["path"] == "kernel" for v in decode)
         for v in decode:
-            assert v["kv_pages_grid"] == 3 * (64 // 8)
+            assert v["kv_pages_grid"] == 3 * pmax
             assert 3 <= v["kv_pages_walked"] <= v["kv_pages_grid"]
-        # one live row at position 20 (3 pages of 8) + two empty slots;
-        # the next block starts 4 positions on, in the fourth page
-        assert [v["kv_pages_walked"] for v in decode[:2]] == [5, 6]
-        tables = jnp.zeros((3, 8), jnp.int32)
+        # one live row at position n_prompt (3 pages) + two empty slots;
+        # the next block starts 4 positions on (bfloat16: in the fourth page)
+        live = [(n_prompt + 4 * i) // page + 1 for i in range(2)]
+        assert live == ([3, 4] if pool == "bfloat16" else [3, 3])
+        assert [v["kv_pages_walked"] for v in decode[:2]] == [m + 2 for m in live]
+        tables = jnp.zeros((3, pmax), jnp.int32)
         n = eng._kv_pages_a_step
-        assert n == pa.pages_per_step(eng._cache[0]["k"]) == 2
-        for pos0, v in zip((20, 24), decode):
-            work = pa.page_work_list(tables, jnp.asarray([pos0, 0, 0]), 1, 8)
+        assert n == pa.pages_per_step(eng._cache[0]["k"], eng._cache[0].get("ks")) == 2
+        for pos0, v in zip((n_prompt, n_prompt + 4), decode):
+            work = pa.page_work_list(tables, jnp.asarray([pos0, 0, 0]), 1, page)
             assert int(work.n_work[0]) == v["kv_pages_walked"]
             # ... and the grid steps that carry them, n pages of a row a step
-            steps = pa.page_work_list(tables, jnp.asarray([pos0, 0, 0]), 1, 8, n)
+            steps = pa.page_work_list(tables, jnp.asarray([pos0, 0, 0]), 1, page, n)
             assert int(steps.n_work[0]) == v["kv_page_steps"] == 4  # 3 or 4 pages: 2 steps, + 2 empty slots
             assert v["kv_page_steps"] <= v["kv_pages_walked"]
         # other kinds of span carry no such field

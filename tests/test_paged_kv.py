@@ -229,15 +229,29 @@ def test_kernel_path_serves_decode_and_verify():
         gather.shutdown()
 
 
-def test_kernel_path_int8_runs_deterministically():
-    kern = build(kv_cache_dtype="int8", paged_kernel="interpret")
+@pytest.mark.parametrize("scales", ["token_major", "lane_dense"])
+def test_kernel_path_int8_runs_deterministically(scales):
+    """... over both layouts of the pool's scale planes: the debug
+    model's 2 KV heads tile 128 lanes at 64-token pages (one row a
+    page) and do not at 8. The lane-dense engine pairs its int8 pages
+    a grid step, and a gather-served engine of the same geometry
+    (writers and gather reader through the same helpers) agrees with it
+    on the first tokens, which the kernel never touches."""
+    geometry = dict(page_size=64, prefill_chunk=64, max_seq_len=128) if scales == "lane_dense" else {}
+    kern = build(kv_cache_dtype="int8", paged_kernel="interpret", **geometry)
+    gather = build(kv_cache_dtype="int8", **geometry)
     try:
+        assert kern._kv_scale_layout() == gather._kv_scale_layout() == scales
+        assert kern._cache[0]["ks"].shape[1:] == ((1, 128) if scales == "lane_dense" else (8, 2))
+        assert kern._kv_pages_a_step == (2 if scales == "lane_dense" else 1)
         params = SamplingParams(temperature=0.0, max_tokens=12, seed=5)
         outs = collect(kern, PROMPTS, params)
         assert all(len(o) == 12 for o in outs)
         assert collect(kern, PROMPTS, params) == outs
+        assert [o[0] for o in outs] == [o[0] for o in collect(gather, PROMPTS, params)]
     finally:
         kern.shutdown()
+        gather.shutdown()
 
 
 def test_gather_serves_the_cpu():

@@ -69,6 +69,51 @@ def test_kv_payload_codec_roundtrip_bitexact(dtype):
             )
 
 
+@pytest.mark.parametrize("kv", ["int8", "int4"])
+@pytest.mark.parametrize("source", ["lane_dense", "token_major"])
+def test_a_payload_of_either_scale_layout_restores_into_a_pool_of_the_other(source, kv):
+    """A payload's scale planes are page gathers of the pool that took
+    it: lane-dense [pages, page * Hkv / 128, 128] from a single-device
+    engine, token-major [pages, page, Hkv] from a head-sharded one. The
+    two are the same bytes a page, so the upload restores either into
+    either (the payload's own shapes record which it holds)."""
+    import jax.numpy as jnp
+
+    from generativeaiexamples_tpu.models import llama
+
+    cfg, page, pool = llama.PRESETS["debug-8dev"], 16, 7  # 16 x 8 heads: one 128-lane row a page
+    rng = np.random.default_rng(3)
+
+    def build(layout):
+        caches = llama.init_kv_pool(
+            cfg, pool, page, quantized=True, packed=kv == "int4", head_sharded=layout == "token_major")
+        want = (page, cfg.num_kv_heads) if layout == "token_major" else (1, 128)
+        assert caches[0]["ks"].shape == caches[0]["vs"].shape == (pool,) + want
+        return caches
+
+    src = build(source)
+    for layer in src:
+        for key in ("ks", "vs"):
+            layer[key] = jnp.asarray(rng.uniform(1, 2, layer[key].shape), jnp.float32)
+        for key in ("k", "v"):
+            layer[key] = jnp.asarray(rng.integers(0, 100, layer[key].shape), layer[key].dtype)
+    taken = np.asarray([2, 5], np.int32)
+    doc = json.loads(json.dumps(encode_kv_payload(
+        [{key: np.asarray(buf[taken]) for key, buf in layer.items()} for layer in src])))
+    assert doc["layers"][0]["ks"]["shape"] == [2, *src[0]["ks"].shape[1:]]
+    dst = build("token_major" if source == "lane_dense" else "lane_dense")
+    into = jnp.asarray([4, 1], jnp.int32)
+    snap_mod.upload_kv_payload(dst, into, decode_kv_payload(doc))
+    tables = lambda pages: jnp.asarray([list(pages)], jnp.int32)
+    for a, b in zip(src, dst):
+        for key in ("ks", "vs"):
+            got = np.asarray(llama.gather_kv_scales(b[key], tables(into), 2, page))
+            np.testing.assert_array_equal(got, np.asarray(llama.gather_kv_scales(a[key], tables(taken), 2, page)))
+            assert got.all() and not np.asarray(b[key])[0].any()
+        for key in ("k", "v"):
+            np.testing.assert_array_equal(np.asarray(b[key][into]), np.asarray(a[key][taken]))
+
+
 def test_snapshot_doc_roundtrip_and_provenance_stamp():
     snap = _snap(kv=encode_kv_payload([{"k": np.zeros((1, 2), np.int8)}]),
                  geometry={"page_size": 8, "pages": 1})
