@@ -470,7 +470,10 @@ def phase_kernels(args) -> int:
     ref = da.decode_attention_xla(
         qp, gathered(pk), gathered_scales(dks), gathered(pv), gathered_scales(dvs), pos[:, None],
     )
-    report(f"paged_attention int8 B={B} page={page} Pmax={Pmax}", rel(out, ref), TOL_ATTN, time.time() - t0)
+    report(
+        f"paged_attention int8 B={B} page={page} Pmax={Pmax}, softmax over {pa.score_rows(Hq, Hkv)} "
+        f"of {Hq} score rows a page", rel(out, ref), TOL_ATTN, time.time() - t0,
+    )
     t0 = time.time()
     token_major = pa.paged_attention(qp, pk, pv, tables, pos, pks, pvs, interpret=interpret)
     same = np.array_equal(np.asarray(out, np.float32), np.asarray(token_major, np.float32))

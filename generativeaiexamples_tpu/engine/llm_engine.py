@@ -887,6 +887,7 @@ class LLMEngine:
         # fallback to the XLA dequant gather.
         self._paged_kernel: Optional[str] = None
         self._kv_pages_a_step = 1
+        self._kv_score_rows = 0  # set where ops/page_attention.py reads the pool
         self._paged_verify_kernel: Optional[str] = None
         self._paged_extend_kernel: Optional[str] = None
         self._resolve_paged_kernel(cfg, model_cfg)
@@ -1112,6 +1113,9 @@ class LLMEngine:
             # (bytes_per_token set) is read by ops/latent_attention.py,
             # one page a step.
             if kv_shape.bytes_per_token is None:
+                self._kv_score_rows = page_attention.score_rows(
+                    kv_shape.num_heads, kv_shape.num_kv_heads
+                )
                 self._kv_pages_a_step = page_attention.pool_pages_per_step(
                     cfg.page_size, kv_shape.num_kv_heads, kv_shape.head_dim,
                     ("uint8" if self._kv_packed else "int8")
@@ -2140,19 +2144,24 @@ class LLMEngine:
         from the host's position shadow (caller holds the lock; no
         readback): a live row's pages up to its query position, one
         scratch page per empty slot; and the grid steps that carry
-        them, ``_kv_pages_a_step`` pages of a row a step."""
+        them, ``_kv_pages_a_step`` pages of a row a step; and the rows a
+        page's softmax runs over (ops/page_attention.score_rows: a query
+        group's own KV head where the kernel folds the others away)."""
         page = self.engine_config.page_size
         last = self.max_seq_len - 1
         live = [min(p, last) // page + 1 for p in self._slot_pos.values()]
         empty = self.num_slots - len(live)
         n = self._kv_pages_a_step
-        return {
+        counts = {
             "kv_pages_walked": sum(live) + empty,
             # grid steps of the same walk: a step carries up to n pages
             # of one row (kv_pages_walked / kv_page_steps = pages a step)
             "kv_page_steps": sum(-(-m // n) for m in live) + empty,
             "kv_pages_grid": self.num_slots * self._max_pages_per_slot,
         }
+        if self._kv_score_rows:  # a latent pool is not this kernel's
+            counts["kv_score_rows"] = self._kv_score_rows
+        return counts
 
     def _stream_backlog_tokens(self) -> int:
         """Tokens the reader has emitted that no handler has written yet,

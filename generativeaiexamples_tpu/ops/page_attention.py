@@ -20,11 +20,12 @@ Design:
   the head-fused wide-dot trick runs over the MERGED ``[page*Hkv, Dh]``
   leading dims: ONE ``[rows, Dh] x [Dh, page*Hkv]`` MXU dot scores every
   (query row, token, kv head) triple — Hkv-fold redundant FLOPs on a
-  ~99%-idle MXU — and each query row's
-  own-head columns are selected by a lane mask folded into the softmax
-  masking (non-matching columns sit at -inf and underflow to exact 0
-  probability), so no lane shuffle ever reorders the interleaved
-  ``t*Hkv + h`` columns.
+  ~99%-idle MXU. Of the ``Hkv`` rows ``h * G + g`` only one, the
+  column's own head's, holds a score a query needs, so the rows are
+  FOLDED onto each other before any exponential ("Masks" below) and the
+  softmax runs over ``score_rows`` rows in the columns' own order ``t *
+  Hkv + h``; the probabilities are unfolded, foreign heads' columns
+  exact zeros, into the operand the one wide value dot takes.
 - **page-granular scales.** The int8 variant's per-(token, head) scales
   live page-contiguous in two float32 planes a layer and fold into the
   score/prob matrices after the int8 dots, as the row ``[1, page *
@@ -83,16 +84,46 @@ single-query pages a step wherever two page pairs fit ``_STEP_BYTES``,
 bfloat16 or quantised with lane-dense scale planes (int8 at 32/8 heads:
 0.256 -> 0.249 ms a layer, -3 %; 0.264 at four). Four a step measured
 no better than two at any served geometry (what is left is per page: a
-DMA's issue and wait, the softmax over ``[Hq, page * Hkv]``); a
+DMA's issue and wait, the int8 converts, the softmax); a
 quantised page whose scale blocks are token-major (padded to 64 KB
 each) and a multi-query page (spec verify, the folded extend read:
 compute-heavy, +6-7 % paired over lane-dense planes too) measured slower
 paired. So N is 1 or 2 and follows the static shapes alone: no setting.
 
-Masks: the token clamp and the own-head lane mask are rebuilt on every
-page. Together they are 1.4 % of a page; building the head mask once and
-branching around the token mask on a row's inner pages measured slower
-(PERF.md §6, PR 43).
+Masks: the own-head mask ``head(column) == head(row)`` is a fold in and
+an unfold out (``_fold``, ``_unfold``, ``softmax_folded``). In: the wide
+scores are selected by it (foreign entries exact zeros) and a query's
+``Hq`` rows are cut into slabs of ``max(G, 8)`` rows, whole vregs, added
+elementwise: one value and zeros, exact. On Mistral's 32/8 heads that is
+``[32, 1024] -> [8, 1024]``: sublane ``s`` holds group row ``s % 4`` of
+the four KV heads congruent to ``s // 4`` modulo 2 (a parity mask rides
+the token clamp); on 8 or 16 query heads a KV head a slab is one head's
+group. Between the two dots everything runs on those rows: the K-scale
+row, the token clamp, the running max, ``exp``, the running sum, the
+V-scale row. A head's columns are the lanes congruent to it modulo
+``Hkv`` (token-major) or its own lane tiles (head-major), so the max
+folds the lane tiles elementwise and then meets across lanes
+(``across_lanes``); the running sum stays a lane's own partial sum and
+meets once a row, in ``_finish``. Out: the probabilities go back under
+each of a query's slabs, selected by the same mask, cast, and ``alpha``
+is picked out of the state by one lane reduce. What it bought, kernel
+alone at Mistral's shapes (32 reads a jit, 355 live pages, int8 lane-
+dense; PERF.md §6, PR 47): 0.834 -> 0.745 us a page at two pages a step
+where the softmax knocked out altogether reads 0.650; the folded extend
+read -21 %. What a page costs after it is mostly NOT the softmax: 648 of
+its ~1,000 vector operations convert the int8 K and V to bfloat16 (a
+page is ~380 instruction bundles of which the folded softmax is ~35).
+Two things measured on the way: a sublane or lane ROTATE waits ~50
+cycles for its result, so rotates that depend on each other are paid in
+full (a four-step butterfly for the lanes' maximum read +24 % a page
+where fifteen independent rotates of the same vreg read -3 %); and the
+bundle count of the compiled body, which can be read WITHOUT the chip
+(``--xla_jf_dump_to`` with ``--xla_jf_dump_llo_text`` on a compile for
+a described device), predicts a page's time at two pages a step. The
+masks are rebuilt on every page: together they are 1.4 % of it;
+building the head mask once (a bias in PR 43, a 0 / 1 factor in VMEM
+scratch in PR 47) and branching around the token mask on a row's inner
+pages measured no faster.
 
 The flat grid is ``arbitrary``: it gives up the ``parallel`` row
 dimension of the old grid. On v5e (one TensorCore a chip) that costs
@@ -113,8 +144,9 @@ from jax.experimental.pallas import tpu as pltpu
 
 _LANE = 128
 _NEG_INF = -1e30
-# VMEM running-softmax scratch is [T*Hq, 128] f32 (m and l) plus the
-# [T*Hq, Dh] accumulator; 512 rows caps the trio near ~1 MB at Dh=128.
+# VMEM scratch holds the [T*Hq, Dh] float32 accumulator and the running
+# max and sum ([score_rows, 128] folded, [T*Hq, 128] wide); 512 rows caps
+# the trio near ~1 MB at Dh=128.
 MAX_QUERY_ROWS = 512
 
 
@@ -179,11 +211,81 @@ def _scale_row(s_ref, page: int, hkv: int):
     return jnp.concatenate(tiles, axis=1)
 
 
+_SUB = 8  # sublanes of a float32 vreg
+
+
+def score_rows(num_heads: int, num_kv_heads: int, query_len: int = 1) -> int:
+    """Rows of the matrix a page's softmax runs over. A query group
+    shares one KV head, so of the wide score matrix's rows ``t * Hq + h
+    * G + g`` only ONE KV head ``h`` holds a live value in any column.
+    The kernel cuts a query's ``Hq`` rows into slabs of ``max(G, 8)``
+    (whole vregs: a group of 8 or 16 query heads, or the ``8 / G``
+    groups that share a vreg) and adds the slabs elementwise
+    (``_fold``): ``T * max(G, 8)`` rows, every entry a score some query
+    needs, with no relayout on the way in or out. A geometry whose
+    slabs do not tile (``G`` neither divides 8 nor is a multiple of it,
+    ``Hq`` not whole slabs), one KV head (nothing to fold) and one whose
+    fold holds no fewer rows keep the wide body: ``T * Hq`` rows. The
+    kernel sizes its softmax scratch by this and the engine's decode
+    spans carry it (``kv_score_rows``)."""
+    rows = query_len * num_heads
+    g = num_heads // max(1, num_kv_heads)
+    slab = max(g, _SUB)
+    tiles = slab % g == 0 and slab % _SUB == 0 and num_heads % slab == 0
+    return query_len * slab if num_kv_heads > 1 and tiles and slab < num_heads else rows
+
+
+def _state_lanes(page: int, hkv: int, head_major: bool) -> int:
+    """Lanes of the folded softmax's running max / sum, 0 where the
+    columns of a page do not tile for it (the wide body serves those).
+    TOKEN-MAJOR (column ``t * Hkv + h``): a head's columns are the lanes
+    congruent to ``h`` modulo ``Hkv``; the state is one lane tile wide,
+    the max CLASS-REPLICATED (every lane holds its own head's value).
+    HEAD-MAJOR (column ``h * page + t``): a head's columns are its own
+    lane tiles; the state holds one tile a head, the max lane-replicated.
+    The running sum is in both a lane's own partial sum until a row's
+    last page."""
+    cols = page * hkv
+    if head_major:
+        w = min(page, _LANE)
+        return hkv * w if page % w == 0 and hkv <= w else 0
+    w = min(cols, _LANE)
+    return w if cols % w == 0 and w % hkv == 0 else 0
+
+
+def _tree(op, xs):
+    """``op`` over the list in a balanced tree."""
+    while len(xs) > 1:
+        xs = [op(*xs[i:i + 2]) if i + 1 < len(xs) else xs[i] for i in range(0, len(xs), 2)]
+    return xs[0]
+
+
+def _fold(wide, t: int, hq: int, slab: int):
+    """``[T * Hq, X] -> [T * slab, X]``: a query's ``Hq`` rows cut into
+    slabs of ``slab`` rows and added elementwise. Row ``s`` of a query's
+    slab takes wide rows ``s, s + slab, ..``: group row ``s % G`` of the
+    KV heads congruent to ``s // G`` modulo ``slab / G``."""
+    return jnp.concatenate([
+        functools.reduce(jnp.add, [
+            wide[q * hq + at:q * hq + at + slab] for at in range(0, hq, slab)
+        ]) for q in range(t)
+    ], axis=0)
+
+
+def _unfold(narrow, t: int, hq: int, slab: int):
+    """``[T * slab, X] -> [T * Hq, X]``: a query's slab under each of
+    its ``Hq / slab`` slabs of wide rows (the caller's own-head select
+    keeps one of them a column)."""
+    return jnp.concatenate([
+        narrow[q * slab:(q + 1) * slab] for q in range(t) for _ in range(hq // slab)
+    ], axis=0)
+
+
 def _kernel(
     row_ref, page_ref, phys_ref, pos_ref, q_ref, *refs,
     scale: float, page: int, hq: int, hkv: int, g: int,
     t: int, s_max: int, quantized: bool, packed: bool,
-    head_major: bool = False, group: int = 1,
+    head_major: bool = False, group: int = 1, lanes: int = 0,
 ):
     per_page = 4 if quantized else 2  # pool operands a page of the group
     o_ref, m_ref, l_ref, acc_ref = refs[group * per_page:]
@@ -195,12 +297,130 @@ def _kernel(
     rows = t * hq
     cols = page * hkv
     dh = q_ref.shape[-1]
+    # lanes > 0: the softmax runs folded, on m_ref.shape[0] = score_rows
+    # rows ("Masks" above); 0: the wide body, one row a query head
+    tile = lanes // hkv if head_major else lanes  # lanes of one state tile
+    slab = max(g, _SUB)  # rows of a query's folded scores (score_rows)
 
     @pl.when(j0 == 0)
     def _init():
         m_ref[...] = jnp.full_like(m_ref, _NEG_INF)
         l_ref[...] = jnp.zeros_like(l_ref)
         acc_ref[...] = jnp.zeros_like(acc_ref)
+
+    col = lax.broadcasted_iota(jnp.int32, (1, cols), 1)
+    row_iota = lax.broadcasted_iota(jnp.int32, (rows, 1), 0)
+    row_head = (row_iota % hq) // g
+    if head_major:  # a page is [Hkv, page, Dh]: column c = kv-head * page + token
+        col_tok, col_head = col % page, col // page
+    else:
+        col_tok, col_head = col // hkv, col % hkv
+
+    def fold_tiles(x, op):
+        """``[score_rows, cols] -> [score_rows, lanes]``: the lane tiles
+        of each head's columns under ``op`` (max or add), elementwise: a
+        lane of the result holds ``op`` over its own lane of every tile
+        (token-major: of the tokens that share its position in a tile)."""
+        tiles = [x[:, at:at + tile] for at in range(0, cols, tile)]
+        if head_major:
+            n = len(tiles) // hkv
+            return jnp.concatenate([
+                functools.reduce(op, tiles[h * n:(h + 1) * n]) for h in range(hkv)
+            ], axis=1)
+        return functools.reduce(op, tiles)
+
+    def across_lanes(r, op):
+        """``[score_rows, lanes]``, folded tiles -> the state's form
+        (``_state_lanes``), every lane ``op`` over ALL of its head's
+        columns: one lane reduce a head (head-major), or lane rotates by
+        every multiple of ``Hkv`` (token-major), all of them rotates of
+        the folded tile itself so that none waits for another: a rotate
+        costs little to issue and ~50 cycles to wait for, and a
+        butterfly of four dependent ones read +24 % a page (PERF.md §6,
+        PR 47)."""
+        if head_major:
+            reduce = jnp.max if op is jnp.maximum else jnp.sum
+            return jnp.concatenate([
+                jnp.broadcast_to(
+                    reduce(r[:, h * tile:(h + 1) * tile], axis=1, keepdims=True), (r.shape[0], tile)
+                ) for h in range(hkv)
+            ], axis=1)
+        return _tree(op, [r] + [pltpu.roll(r, k, 1) for k in range(hkv, tile, hkv)])
+
+    def by_column(state):
+        """``[score_rows, lanes] -> [score_rows, cols]``: every column
+        its own head's value."""
+        if head_major:
+            n = page // tile
+            return jnp.concatenate([
+                state[:, h * tile:(h + 1) * tile] for h in range(hkv) for _ in range(n)
+            ], axis=1)
+        return jnp.concatenate([state] * (cols // tile), axis=1)
+
+    def by_row(state):
+        """``[score_rows, lanes] -> [T * Hq, 1]``: every wide row its own
+        query head's value. Lane ``h`` of a tile holds head ``h``'s
+        (token-major: as it stands); the unfold brings a group's row to
+        its heads' rows and a lane select with one lane reduce keeps the
+        head's own (the values are >= 0: alpha, l)."""
+        if head_major:  # head h's tile onto lane h of one tile
+            lane = lax.broadcasted_iota(jnp.int32, (1, tile), 1)
+            packed_state = state[:, :tile]
+            for h in range(1, hkv):
+                packed_state = jnp.where(lane == h, state[:, h * tile:(h + 1) * tile], packed_state)
+            state = packed_state
+        lane = lax.broadcasted_iota(jnp.int32, (rows, tile), 1)
+        return jnp.max(
+            jnp.where(lane == row_head, _unfold(state, t, hq, slab), 0.0),
+            axis=1, keepdims=True,
+        )
+
+    def softmax_folded(j, sc, k_scale, v_scale):
+        """The page's probabilities ``[T * Hq, cols]`` (foreign heads'
+        columns exact zeros, V scales folded in) and ``alpha [T * Hq,
+        1]``, with every pass between the fold and the unfold on
+        ``score_rows`` rows: the scores a query needs and no others."""
+        own = col_head == row_head
+        s = _fold(jnp.where(own, sc, 0.0), t, hq, slab) * k_scale
+        nrow = lax.broadcasted_iota(jnp.int32, (t * slab, 1), 0)
+        # per-query causal clamp: query t attends <= positions + t
+        live = j * page + col_tok <= jnp.minimum(p_first + nrow // slab, s_max - 1)
+        if slab > g:  # a row holds the heads of its own class modulo slab / G
+            live &= col_head % (slab // g) == nrow % slab // g
+        s = jnp.where(live, s, _NEG_INF)
+        m_prev = m_ref[...]
+        m_new = jnp.maximum(m_prev, across_lanes(fold_tiles(s, jnp.maximum), jnp.maximum))
+        prob = jnp.exp(s - by_column(m_new))  # dead columns -> 0
+        alpha = jnp.exp(m_prev - m_new)
+        # the running sum stays a lane's own partial sum (alpha is the
+        # same on every lane of a head): the lanes meet once, in _finish
+        l_ref[...] = alpha * l_ref[...] + fold_tiles(prob, jnp.add)
+        m_ref[...] = m_new
+        if v_scale is not None:
+            prob = prob * v_scale
+        return jnp.where(own, _unfold(prob, t, hq, slab), 0.0), by_row(alpha)
+
+    def softmax_wide(j, sc, k_scale, v_scale):
+        """The same over ``[T * Hq, cols]``: the own-head lane mask
+        folded into the token clamp (foreign columns sit at -inf and
+        underflow to exact 0 probability)."""
+        sc = sc * k_scale
+        # per-query-row causal clamp: query t attends <= positions + t
+        q_pos = jnp.minimum(p_first + row_iota // hq, s_max - 1)
+        live = (j * page + col_tok <= q_pos) & (col_head == row_head)
+        sc = jnp.where(live, sc, _NEG_INF)
+        m_prev = m_ref[:, :1]  # [rows, 1]
+        m_new = jnp.maximum(m_prev, jnp.max(sc, axis=1, keepdims=True))
+        prob = jnp.exp(sc - m_new)  # dead/foreign-head columns -> 0
+        alpha = jnp.exp(m_prev - m_new)
+        l_ref[...] = jnp.broadcast_to(
+            alpha * l_ref[:, :1] + jnp.sum(prob, axis=1, keepdims=True),
+            l_ref.shape,
+        )
+        m_ref[...] = jnp.broadcast_to(m_new, m_ref.shape)
+        if v_scale is not None:
+            prob = prob * v_scale
+        return prob, alpha
 
     def page_step(j, *pool):
         """One live page into the running softmax: the arithmetic, and
@@ -220,39 +440,11 @@ def _kernel(
             q, k_cat, (((1,), (1,)), ((), ())),
             preferred_element_type=jnp.float32,
         )  # [rows, page*Hkv]; column c = (token-in-page)*Hkv + kv-head
-        if quantized:
-            # page-granular K scales fold in AFTER the int8/int4 dot
-            # (small integers convert to bf16 exactly, so the MXU saw
-            # exact operands)
-            sc = sc * (_scale_row(ks_ref, page, hkv) * scale)
-        else:
-            sc = sc * scale
-        col_iota = lax.broadcasted_iota(jnp.int32, (rows, cols), 1)
-        row_iota = lax.broadcasted_iota(jnp.int32, (rows, 1), 0)
-        if head_major:  # a page is [Hkv, page, Dh]: column c = kv-head * page + token
-            tok = j * page + col_iota % page
-            col_head = col_iota // page
-        else:
-            tok = j * page + col_iota // hkv
-            col_head = col_iota % hkv
-        row_head = (row_iota % hq) // g
-        # per-query-row causal clamp: query t attends <= positions + t
-        # (both masks rebuilt on every page on purpose: "Masks" above)
-        q_pos = jnp.minimum(p_first + row_iota // hq, s_max - 1)
-        live = (tok <= q_pos) & (col_head == row_head)
-        sc = jnp.where(live, sc, _NEG_INF)
-
-        m_prev = m_ref[:, :1]  # [rows, 1]
-        m_new = jnp.maximum(m_prev, jnp.max(sc, axis=1, keepdims=True))
-        prob = jnp.exp(sc - m_new)  # dead/foreign-head columns -> 0
-        alpha = jnp.exp(m_prev - m_new)
-        l_ref[...] = jnp.broadcast_to(
-            alpha * l_ref[:, :1] + jnp.sum(prob, axis=1, keepdims=True),
-            l_ref.shape,
-        )
-        m_ref[...] = jnp.broadcast_to(m_new, m_ref.shape)
-        if quantized:
-            prob = prob * _scale_row(vs_ref, page, hkv)
+        # page-granular scales fold in AFTER the int8/int4 dots (small
+        # integers convert to bf16 exactly, so the MXU saw exact operands)
+        k_scale = _scale_row(ks_ref, page, hkv) * scale if quantized else scale
+        v_scale = _scale_row(vs_ref, page, hkv) if quantized else None
+        prob, alpha = (softmax_folded if lanes else softmax_wide)(j, sc, k_scale, v_scale)
         if packed:
             v_cat = _unpack_nibbles(v_ref[0].reshape(cols, dh // 2))
         else:
@@ -277,7 +469,7 @@ def _kernel(
     # the row's last group: its successor would start past last_tok
     @pl.when((j0 + group) * page > last_tok)
     def _finish():
-        l = l_ref[:, :1]
+        l = by_row(across_lanes(l_ref[...], jnp.add)) if lanes else l_ref[:, :1]
         l = jnp.where(l == 0.0, 1.0, l)  # paranoia: never divide by 0
         o_ref[0] = (
             (acc_ref[...] / l).reshape(t, hq, dh).astype(o_ref.dtype)
@@ -459,6 +651,13 @@ def paged_attention(
             tables, pos, T, page, group or pages_per_step(k, k_scale, T)
         )
     N = work.phys.shape[0] // work.row.shape[0]
+    # the softmax's rows and the lanes of its running max / sum: folded
+    # onto a query group's own KV head where the rows and the columns
+    # both tile for it, else one row a query head, lane-replicated
+    n_rows = score_rows(Hq, Hkv, T)
+    lanes = _state_lanes(page, Hkv, head_major) if n_rows < T * Hq else 0
+    if not lanes:
+        n_rows = T * Hq
 
     def pool_spec(n):
         return pl.BlockSpec(
@@ -498,8 +697,8 @@ def paged_attention(
         in_specs=in_specs,
         out_specs=row_spec(),
         scratch_shapes=[
-            pltpu.VMEM((T * Hq, _LANE), jnp.float32),
-            pltpu.VMEM((T * Hq, _LANE), jnp.float32),
+            pltpu.VMEM((n_rows, lanes or _LANE), jnp.float32),
+            pltpu.VMEM((n_rows, lanes or _LANE), jnp.float32),
             pltpu.VMEM((T * Hq, Dh), jnp.float32),
         ],
     )
@@ -507,7 +706,7 @@ def paged_attention(
         functools.partial(
             _kernel, scale=scale, page=page, hq=Hq, hkv=Hkv, g=G, t=T,
             s_max=S, quantized=quantized, packed=packed,
-            head_major=head_major, group=N,
+            head_major=head_major, group=N, lanes=lanes,
         ),
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((B, T, Hq, Dh), q.dtype),

@@ -153,8 +153,9 @@ def test_paged_attention_compiles(one_chip, no_persistent_cache, T, kv_dtype):
         (1, (32, 8), "int8-head-sharded", False, 1),  # ... scale planes [P, 128, 8], as a head-sharded pool keeps them
         (1, (40, 10), jnp.bfloat16, True, 2),  # Phi-4-flash's pair layout
         (1, (32, 4), jnp.bfloat16, True, 2),  # Trinity-Mini's full layer
+        (1, (64, 8), jnp.bfloat16, True, 2),  # Solar-Open2's one softmax layer
     ],
-    ids=["mistral-int8", "mistral-int8-fold16", "mistral-int8-token-major-scales", "phi4flash-pairs", "trinity-32-4"],
+    ids=["mistral-int8", "mistral-int8-fold16", "mistral-int8-token-major-scales", "phi4flash-pairs", "trinity-32-4", "solaropen2-64-8"],
 )
 def test_grouped_paged_attention_compiles_at_the_served_geometries(
     one_chip, no_persistent_cache, T, heads, kv_dtype, head_major, n

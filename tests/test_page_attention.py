@@ -31,17 +31,17 @@ def _ragged_tables(rng):
     return jnp.asarray(tables)
 
 
-def _bf16_pool(rng, pool=POOL, hkv=Hkv):
-    k = jnp.asarray(rng.standard_normal((pool, PAGE, hkv, Dh)), jnp.bfloat16)
-    v = jnp.asarray(rng.standard_normal((pool, PAGE, hkv, Dh)), jnp.bfloat16)
+def _bf16_pool(rng, pool=POOL, hkv=Hkv, page=PAGE):
+    k = jnp.asarray(rng.standard_normal((pool, page, hkv, Dh)), jnp.bfloat16)
+    v = jnp.asarray(rng.standard_normal((pool, page, hkv, Dh)), jnp.bfloat16)
     return k, v
 
 
-def _int8_pool(rng, pool=POOL, hkv=Hkv):
-    kq = jnp.asarray(rng.integers(-127, 128, (pool, PAGE, hkv, Dh)), jnp.int8)
-    vq = jnp.asarray(rng.integers(-127, 128, (pool, PAGE, hkv, Dh)), jnp.int8)
-    ks = jnp.asarray(rng.uniform(0.005, 0.02, (pool, PAGE, hkv)), jnp.float32)
-    vs = jnp.asarray(rng.uniform(0.005, 0.02, (pool, PAGE, hkv)), jnp.float32)
+def _int8_pool(rng, pool=POOL, hkv=Hkv, page=PAGE):
+    kq = jnp.asarray(rng.integers(-127, 128, (pool, page, hkv, Dh)), jnp.int8)
+    vq = jnp.asarray(rng.integers(-127, 128, (pool, page, hkv, Dh)), jnp.int8)
+    ks = jnp.asarray(rng.uniform(0.005, 0.02, (pool, page, hkv)), jnp.float32)
+    vs = jnp.asarray(rng.uniform(0.005, 0.02, (pool, page, hkv)), jnp.float32)
     return kq, vq, ks, vs
 
 
@@ -583,11 +583,11 @@ def test_supports_geometry_interpret_relaxes_tiling_only():
 # packed int4 pools (two values per byte, split-halves codec)
 
 
-def _int4_pool(rng, pool=POOL, hkv=Hkv):
+def _int4_pool(rng, pool=POOL, hkv=Hkv, page=PAGE):
     """Quantize a random f32 pool through the engine codec: packed
-    uint8 [pool, PAGE, hkv, Dh//2] + page-granular f32 scales."""
-    kf = rng.standard_normal((pool, PAGE, hkv, Dh)).astype(np.float32)
-    vf = rng.standard_normal((pool, PAGE, hkv, Dh)).astype(np.float32)
+    uint8 [pool, page, hkv, Dh//2] + page-granular f32 scales."""
+    kf = rng.standard_normal((pool, page, hkv, Dh)).astype(np.float32)
+    vf = rng.standard_normal((pool, page, hkv, Dh)).astype(np.float32)
     kq, ks = llama.quantize_kv_int4(jnp.asarray(kf))
     vq, vs = llama.quantize_kv_int4(jnp.asarray(vf))
     return kq, vq, ks, vs
@@ -829,6 +829,9 @@ def test_decode_span_carries_pages_walked_and_the_dense_grid(pool):
         for v in decode:
             assert v["kv_pages_grid"] == 3 * pmax
             assert 3 <= v["kv_pages_walked"] <= v["kv_pages_grid"]
+            # the rows a page's softmax runs over: the debug model's 4
+            # query heads do not fill a vreg, so the wide body's
+            assert v["kv_score_rows"] == pa.score_rows(eng._kv_shape.num_heads, eng._kv_shape.num_kv_heads)
         # one live row at position n_prompt (3 pages) + two empty slots;
         # the next block starts 4 positions on (bfloat16: in the fourth page)
         live = [(n_prompt + 4 * i) // page + 1 for i in range(2)]
