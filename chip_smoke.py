@@ -242,7 +242,7 @@ _KERNEL_LINE = re.compile(
     r"\(backend=(\w+), devices=(\d+)\)"
 )
 _WARMUP_LINE = re.compile(
-    r"Engine warmup complete for prompt lengths .* "
+    r"Engine warmup complete "
     r"\(engine build ([\d.]+) s, warmup ([\d.]+) s; (device memory[^)]*)\)"
 )
 

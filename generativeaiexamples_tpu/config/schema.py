@@ -388,13 +388,14 @@ class EngineConfig(ConfigWizard):
     warmup_prompt_lengths: str = configfield(
         "warmup_prompt_lengths",
         default="",
-        help_txt="Comma-separated prompt lengths (engine tokens) the "
-        "chain-server pre-compiles at startup in a background thread. "
-        "Without warming, the first request hitting a new prompt-length "
-        "bucket stalls for a multi-minute XLA compile of the serving "
-        "graph (measured ~5 min for an 8B bucket mid-serving). For RAG "
-        "chains set this near the context-capped prompt size, e.g. "
-        "'2048,2560'.",
+        help_txt="Non-empty (comma-separated positive ints, e.g. '512'): "
+        "the chain-server pre-compiles every serving shape at startup in "
+        "a background thread. The values select nothing: the one warm "
+        "walk covers every prompt length (every prompt prefills through "
+        "the same fixed-shape chunk programs). Without warming, the first "
+        "request of each shape stalls for a multi-minute XLA compile of "
+        "the serving graph (measured ~5 min for an 8B prefill "
+        "mid-serving).",
     )
     prefix_cache_enable: str = configfield(
         "prefix_cache_enable",
@@ -513,10 +514,11 @@ class EngineConfig(ConfigWizard):
     prefill_wave_tokens: int = configfield(
         "prefill_wave_tokens",
         default=16384,
-        help_txt="Cap on rows x bucket-length per prefill admission wave. "
-        "Long-prompt waves are split so the compiled prefill's activation "
-        "footprint stays bounded (a 16 x 2560-token unrolled 8B prefill "
-        "needs >17 GB HBM and cannot compile on one v5e chip).",
+        help_txt="Cap on rows x prefill_chunk of one prefill dispatch: a "
+        "wave holds prefill_wave_tokens / prefill_chunk rows, so the "
+        "compiled extend's activation footprint stays bounded (a 16 x "
+        "2560-token unrolled 8B prefill needs >17 GB HBM and cannot "
+        "compile on one v5e chip).",
     )
     model_config_name: str = configfield(
         "model_config_name",

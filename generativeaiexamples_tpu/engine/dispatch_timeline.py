@@ -3,10 +3,9 @@ completion, and what the host and jit did meanwhile.
 
 compile_watch says what jit did and the flight recorder decomposes a
 *request's* latency; this module decomposes the *engine's* wall time.
-Every compiled-program launch with a span (prefill wave, prefill chunk,
-decode block, spec verify, spec-block fallback) is recorded at its one
-choke point, the ``_dispatch_lock``, into a bounded ring of **dispatch
-spans**: program kind, tier thread, wall clock, dispatch-lock wait, the
+Every compiled-program launch with a span (prefill chunk, decode block,
+spec verify, spec-block fallback) is recorded at its one choke point,
+the ``_dispatch_lock``, into a bounded ring of **dispatch spans**: program kind, tier thread, wall clock, dispatch-lock wait, the
 enqueue call's own length (``enqueue_s``: host time inside the lock,
 jit's work included), batch geometry, attention path, rids.
 
@@ -183,7 +182,7 @@ _CUM = {"spans": 0.0, "device": 0.0, "lock": 0.0, "gap": 0.0, "readback": 0.0}
 # is a number, not a code comment. A span's mode is classified from
 # its kind (spec verifies, their fallback blocks, and the async
 # pipeline's flush/rollback spans are 'spec'; plain decode blocks are
-# 'decode'; prefill waves/chunks and handoff stalls are 'prefill').
+# 'decode'; prefill chunks and handoff stalls are 'prefill').
 MODES = ("decode", "spec", "prefill", "other")
 _CUM_MODE: Dict[str, Dict[str, float]] = {
     m: {"device": 0.0, "lock": 0.0, "gap": 0.0, "readback": 0.0,

@@ -17,10 +17,10 @@ Architecture (TPU-first), ONE serving path:
   single dispatch via lax.scan, with sampling fused in. B is the fixed
   slot count (EngineConfig.max_batch_size); requests claim/release slots —
   XLA sees static shapes forever, no recompiles at steady state.
-- Prompts of one chunk prefill monolithically; longer ones run as
-  fixed-shape ``prefill_chunk`` extend dispatches, so the executable
-  set is bounded and a long prompt never stalls other slots' decode
-  cadence more than one chunk.
+- Every prompt prefills as fixed-shape ``prefill_chunk`` extend
+  dispatches (engine/scheduler/shapes.py decides the shapes), so the
+  executable set is bounded and a long prompt never stalls other
+  slots' decode cadence more than one chunk.
 - The decode loop runs on a dedicated thread; per-request token queues
   feed the server's SSE writers (server/api.py streams from them without
   touching the device). Host↔device traffic is one [K, B] int32 slab per
@@ -49,6 +49,7 @@ from generativeaiexamples_tpu.engine import kv_pages as kv_pages_mod
 from generativeaiexamples_tpu.engine import prefix_cache as prefix_cache_mod
 from generativeaiexamples_tpu.engine import request_snapshot as request_snapshot_mod
 from generativeaiexamples_tpu.engine import scheduler as scheduler_mod
+from generativeaiexamples_tpu.engine.scheduler import shapes as shapes_mod
 from generativeaiexamples_tpu.engine import spec_decode as spec_decode_mod
 from generativeaiexamples_tpu.engine.tokenizer import (
     IncrementalDecoder,
@@ -572,12 +573,6 @@ class LLMEngine:
         self._fixed_state = bool(family.fixed_state)
         self._span_fields = dict(family.span_fields(model_cfg))
         self._stat_names = tuple(family.stat_names)
-        # a family whose extend walk follows each row's own context is
-        # given ONE window, capacity (models/registry.py)
-        self._one_extend_window = not family.extend_reads_window
-        # a family that registered a walk over a packed token axis is
-        # sent its prefill waves packed (models/registry.py)
-        self._packed = family.extend_packed is not None
         if self._fixed_state:
             cfg = self._validate_fixed_state(cfg, mesh)
             self.engine_config = cfg
@@ -791,6 +786,23 @@ class LLMEngine:
         # and each prefix entry one full-capacity strip of pages.
         self.num_slots = cfg.max_batch_size
         self.max_seq_len = min(cfg.max_seq_len, model_cfg.max_seq_len)
+        # Every shape a dispatch can take (engine/scheduler/shapes.py).
+        # A family that registered a walk over a packed token axis is
+        # sent its prefill waves packed; one whose extend walk follows
+        # each row's own context is given ONE window, capacity
+        # (models/registry.py). ``page_kernel`` is set once resolved.
+        self.shapes = shapes_mod.ShapePlan(
+            prefill_chunk=cfg.prefill_chunk,
+            page_size=cfg.page_size,
+            max_seq_len=self.max_seq_len,
+            num_slots=self.num_slots,
+            prefill_wave_tokens=cfg.prefill_wave_tokens,
+            decode_block=max(1, cfg.decode_block),
+            fixed_state=self._fixed_state,
+            packed=family.extend_packed is not None,
+            extend_reads_window=bool(family.extend_reads_window),
+            page_kernel=False,
+        )
         prefix_slots = _prefix_store_extra_slots(cfg)
         self._pool_pages = kv_pages_mod.pool_pages(
             cfg, self.max_seq_len, prefix_slots
@@ -891,6 +903,9 @@ class LLMEngine:
         self._paged_verify_kernel: Optional[str] = None
         self._paged_extend_kernel: Optional[str] = None
         self._resolve_paged_kernel(cfg, model_cfg)
+        self.shapes = dataclasses.replace(
+            self.shapes, page_kernel=self._paged_kernel is not None
+        )
         # kernels the family brings beside the engine's own
         # (models/registry.py ``resolve_kernels``), by the same rule of
         # platform: compiled on one TPU device, interpreted on request
@@ -974,36 +989,18 @@ class LLMEngine:
             cfg = _dc.replace(cfg, tensor_parallelism=1)
         return cfg
 
-    def _draft_ladder(self) -> Tuple[List[int], List[int]]:
-        """(row rungs, chunk-window rungs) the draft-model runtime's
-        prefill dispatches may use — the target's chunked-wave ladder,
-        so draft warmup compiles exactly the shapes admission produces."""
-        C = min(self.engine_config.prefill_chunk, self.max_seq_len)
-        cap = self._max_wave_rows(C)
-        rows = sorted({min(s, cap) for s in self._wave_sizes()})
-        windows = sorted({
-            self._attention_window(min((k + 1) * C, self.max_seq_len))
-            for k in range((self.max_seq_len + C - 1) // C)
-        })
-        return rows, windows
-
     def _build_draft_runtime(self, cfg: EngineConfig):
         """Construct the resident-draft runtime (engine/spec_draft.py)
         against this engine's mesh/slots/ladders."""
         from generativeaiexamples_tpu.engine import spec_draft as spec_draft_mod
 
-        rows, windows = self._draft_ladder()
         return spec_draft_mod.DraftRuntime(
             cfg,
             mesh=self._mesh,
             compile_watch=self._compile_watch,
             dtype=self._dtype,
             sample_vocab=self._sample_vocab,
-            num_slots=self.num_slots,
-            max_seq_len=self.max_seq_len,
-            row_rungs=rows,
-            chunk_windows=windows,
-            window_rungs=self._window_rungs(),
+            shapes=self.shapes,
         )
 
     def _init_spec_proposer(self, cfg: EngineConfig) -> None:
@@ -1161,8 +1158,8 @@ class LLMEngine:
         # serves: ONE executable a row rung, no window rung (the walk
         # follows each row's live pages).
         narrow = (
-            [t for t in self._packed_rungs() if t < cfg.prefill_chunk]
-            if self._packed else self._chunk_widths()[:-1]
+            [t for t in self.shapes.packed_rungs() if t < cfg.prefill_chunk]
+            if self.shapes.packed else self.shapes.chunk_widths()[:-1]
         )
         if narrow and all(
             page_attention.supports_geometry(
@@ -1760,9 +1757,9 @@ class LLMEngine:
         return tp_cap
 
     def _build_steps(self) -> None:
-        """The compiled step programs, each built once: prefill, decode
-        block, extend (chunked prefill), finish and speculative verify
-        over per-layer weights and the page pool (docs/paged_kv.md)."""
+        """The compiled step programs, each built once: extend (a
+        prefill chunk), finish, decode block and speculative verify over
+        per-layer weights and the page pool (docs/paged_kv.md)."""
         import jax
         import jax.numpy as jnp
 
@@ -1775,13 +1772,13 @@ class LLMEngine:
         tp = self._tp
         base_key = jax.random.PRNGKey(1234)
         max_pos = self.max_seq_len - 1
-        block = self._decode_block = max(1, ecfg.decode_block)
+        block = self.shapes.decode_block
 
         # The step programs reach the model through its family alone
-        # (models/registry.py): three walks over an opaque cache pytree
+        # (models/registry.py): walks over an opaque cache pytree
         # — page pools and, for a fixed-state family, per-slot arrays
-        # the walks index by slot (reset at admission inside the prefill
-        # / first extend program, carried from chunk to chunk, untouched
+        # the walks index by slot (reset at admission inside the first
+        # extend program, carried from chunk to chunk, untouched
         # by a dead decode row) — with cache coordinates routed through
         # the per-slot page tables (one [B, Pmax] int32 operand). The
         # ragged Pallas kernel (resolved per program by
@@ -1796,23 +1793,6 @@ class LLMEngine:
         page = ecfg.page_size
         page_kernel = self._paged_kernel
         verify_kernel = self._paged_verify_kernel
-
-        def prefill_batch_paged(params, caches, tokens, lengths, slots,
-                                temps, topps, seeds, tables):
-            # Monolithic short-prompt waves: one fresh-K/V forward for
-            # the whole admission wave (it never reads a cache), then
-            # one pool scatter per layer via the page tables. `slots`
-            # may contain duplicates (admission pads N up the wave
-            # ladder by repeating row 0): duplicate rows scatter
-            # identical data, which is well-defined.
-            logits, new_caches = fam.prefill_paged(
-                params, cfg, caches, tokens, lengths, slots, tables, page,
-                use_flash=None if (self._mesh.size == 1 or tp is not None) else False,
-                **paths,
-            )
-            keys = sample_keys(base_key, seeds, lengths)
-            first = sample_tokens(logits[:, :V], keys, temps, topps)
-            return first, new_caches
 
         def decode_paged(params, caches, tokens, positions, temps, topps,
                          seeds, tables, live, window):
@@ -1857,9 +1837,9 @@ class LLMEngine:
                 )
             return tokens, positions, caches, token_slab
 
-        # Chunked prefill (VERDICT r3 #4): prompts longer than one chunk
-        # run as repeated (rows, width, W)-shaped extend dispatches — a
-        # BOUNDED executable set (_extend_signatures: row rungs x window
+        # Chunked prefill (VERDICT r3 #4): every prompt runs as
+        # repeated (rows, width, W)-shaped extend dispatches — a
+        # BOUNDED executable set (shapes.extend_signatures: row rungs x window
         # rungs at the full chunk width, row rungs alone at a narrow
         # one) covering every prompt length, so no request can hit a
         # cold-bucket compile (observed without it: p95 108 s on
@@ -1902,7 +1882,8 @@ class LLMEngine:
                 sub_h[jnp.argmax(hit, axis=1)].astype(last_h.dtype), last_h,
             )
 
-        packed_windows = tuple(self._packed_windows()) if self._packed else ()
+        packed = self.shapes.packed
+        packed_windows = tuple(self.shapes.packed_windows()) if packed else ()
 
         def extend_packed(params, caches, tokens, rows, pick, last_h, tables):
             # The packed form of the same dispatch: the wave's live
@@ -2029,10 +2010,6 @@ class LLMEngine:
         )
 
         wrap = self._compile_watch.wrap
-        # genai-lint: disable=warmup-coverage -- warmed by warmup()'s submitted dummy waves: the dispatch thread compiles every (wave, bucket) prefill rung under the warmup scope before finish_warmup arms the hot-path gate (queue-mediated, so statically invisible)
-        self._prefill_fn = None if self._packed else wrap(
-            "prefill", jax.jit(prefill_batch_paged, donate_argnums=(1,))
-        )
         # `window` is static: the page kernel has one full-capacity
         # executable, the gather one per power-of-two attention window.
         self._decode_fn = wrap(
@@ -2042,7 +2019,7 @@ class LLMEngine:
         self._update_slots_fn = wrap("update_slots", jax.jit(_update_slots))
         self._extend_fn = wrap(
             "extend",
-            jax.jit(extend_packed, donate_argnums=(1,)) if self._packed
+            jax.jit(extend_packed, donate_argnums=(1,)) if packed
             else jax.jit(
                 extend_batch_paged, donate_argnums=(1,), static_argnums=(8,)
             ),
@@ -2778,46 +2755,50 @@ class LLMEngine:
         weakref.finalize(gen, self.abort, req)
         return gen
 
-    def warmup_chunked_shapes(self) -> None:
-        """Compile the WHOLE chunked-prefill executable set directly:
-        one extend per ``_extend_signatures`` entry (rows, width,
-        window; for a packed family one per token rung, whose carry has
-        the one row count), one finish per wave rung and one
-        ``put_rows`` per pair of a wave rung and a smaller one.
-        Zero-valid rows make every
+    def _quiesce_for_warmup(self, what: str) -> bool:
+        """Wait, admissions held by the caller, until live decode and the
+        scheduler's tiers are quiet, before a warm walk dispatches from
+        ITS thread: the walk's programs donate ``self._cache`` and so
+        does the dispatch thread's ``_decode_fn`` (concurrent donation
+        is a use-after-free), and a disagg prefill wave mid-flight or an
+        un-imported handoff holds the same cache chain. False: the
+        engine stopped meanwhile."""
+        quiesce_s = float(self.engine_config.quiesce_timeout_s)
+        deadline = time.time() + quiesce_s
+        with self._lock:
+            while (
+                self._slot_req or self.scheduler.tier_busy()
+            ) and self._running:
+                if time.time() > deadline:
+                    raise TimeoutError(
+                        f"{what}: live decode did not quiesce within "
+                        f"{quiesce_s:.0f} s"
+                    )
+                self._lock.wait(timeout=0.2)
+            return self._running
+
+    def warmup(self) -> None:
+        """Pre-compile every serving shape, so that no request meets an
+        XLA compile (tens of seconds each on a cold engine).
+
+        The WHOLE prefill set directly: one extend per
+        ``shapes.extend_signatures()`` entry (rows, width, window; for a
+        packed family one per token rung, whose carry has the one row
+        count), one finish per wave rung and one ``put_rows`` per pair
+        of a wave rung and a smaller one. Zero-valid rows make every
         dispatch a value-level no-op on the caches, so this needs no
-        scheduler involvement — and after it, NO prompt length can
-        compile inside a request (the chunked set covers every length
-        up to max_seq_len).
+        scheduler involvement, and the set covers every prompt length
+        up to max_seq_len: a prompt of at most one chunk is chunk 0 of
+        the same walk. Then the page-table scatter, the decode block at
+        every window rung and, where enabled, the spec verify shapes.
         """
         import jax.numpy as jnp
 
-        signatures = self._extend_signatures()
+        signatures = self.shapes.extend_signatures()
         row_rungs = sorted({n for n, _, _ in signatures})
         with self._compile_watch.warmup_scope(), self.hold_admissions():
-            # Quiesce live decode before dispatching from THIS thread:
-            # _extend_fn donates self._cache, and the dispatch thread's
-            # _decode_fn donates the same buffers — concurrent donation
-            # is a use-after-free. With admissions held and no live
-            # slots, the dispatch thread cannot touch the cache.
-            quiesce_s = float(self.engine_config.quiesce_timeout_s)
-            deadline = time.time() + quiesce_s
-            with self._lock:
-                # The scheduler's tiers must quiesce too: a disagg
-                # prefill wave mid-flight (or an un-imported handoff)
-                # holds the donated cache chain this warm walk is about
-                # to consume from this thread.
-                while (
-                    self._slot_req or self.scheduler.tier_busy()
-                ) and self._running:
-                    if time.time() > deadline:
-                        raise TimeoutError(
-                            f"warmup_chunked_shapes: live decode did not "
-                            f"quiesce within {quiesce_s:.0f} s"
-                        )
-                    self._lock.wait(timeout=0.2)
-                if not self._running:
-                    return
+            if not self._quiesce_for_warmup("warmup"):
+                return
             if self._copy_state_fn is not None:
                 # the state copy of a prefix save / restore: a store
                 # row onto itself, a no-op on the values
@@ -2830,7 +2811,7 @@ class LLMEngine:
                     # zero-valid rows route every write to the
                     # scratch page — value-level no-ops even when
                     # slot 0's table holds stale entries
-                    if self._packed:
+                    if self.shapes.packed:
                         # (no live row: every token of the axis is dead)
                         last_h, self._cache, *_ = self._extend_fn(
                             self.params, self._cache,
@@ -2860,9 +2841,8 @@ class LLMEngine:
                     jnp.zeros((n,), jnp.int32),
                 )
                 # admission's slot update on first tokens of finish's
-                # kind (they carry the mesh in their type; the dummy
-                # waves below warm it on prefill's): the slot index is
-                # out of range, so the scatter drops every row
+                # kind (they carry the mesh in their type): the slot
+                # index is out of range, so the scatter drops every row
                 (
                     self._tokens_dev,
                     self._positions_dev,
@@ -2910,7 +2890,7 @@ class LLMEngine:
             dead = np.zeros((self.num_slots,), bool)
             rungs = (
                 [self.max_seq_len] if self._paged_kernel
-                else self._window_rungs()
+                else self.shapes.window_rungs()
             )
             for w in rungs:
                 (_, _, self._cache, slab) = self._decode_fn(
@@ -2919,44 +2899,11 @@ class LLMEngine:
                     self._seeds_dev, self._tables_dev, dead, w,
                 )
                 slab.block_until_ready()
-
-    def warmup(self, prompt_lengths: Sequence[int] = (128,)) -> None:
-        """Pre-compile prefill/decode for every serving shape.
-
-        Two families of executables exist: one prefill per (wave size,
-        prompt bucket) — admission pads waves up the _wave_sizes ladder — and one
-        decode per power-of-two attention window. A cold engine would hit
-        an XLA compile (tens of seconds) the first time each shape appears,
-        so this runs controlled dummy waves for every wave size and pushes
-        one request past each window boundary, and serving traffic never
-        sees a compile pause. With chunked prefill the long-prompt family
-        collapses to the bounded chunk set (warmup_chunked_shapes), so
-        only buckets <= one chunk warm monolithically.
-        """
-        with self._compile_watch.warmup_scope():
-            self.warmup_chunked_shapes()
-            chunk = self.engine_config.prefill_chunk
-            prompt_lengths = [t for t in prompt_lengths if t <= chunk] or [chunk]
-            for T in sorted({self._prefill_bucket(max(1, t)) for t in prompt_lengths}):
-                prompt = [5] * (T - 1)  # bucket keeps T-1..T in one shape
-                # rungs clamped the same way admission clamps them, so warmup
-                # compiles exactly the wave shapes this bucket can produce
-                cap = self._max_wave_rows(T)
-                for k in sorted({min(s, cap) for s in self._wave_sizes()}):
-                    with self.hold_admissions():
-                        reqs = [
-                            self.submit(prompt, SamplingParams(temperature=0.0, max_tokens=2))
-                            for _ in range(k)
-                        ]
-                    for req in reqs:
-                        while req.out_queue.get() is not _END:
-                            pass
-            # Spec verify executables (one per window rung) compile here so
-            # a verify dispatch never compiles inside a request. The
-            # decode rungs were warmed with dead dispatches inside
-            # warmup_chunked_shapes already.
-            if self._spec_enabled:
-                self.warmup_spec_shapes()
+        # Spec verify executables (one per window rung) compile here so
+        # a verify dispatch never compiles inside a request (a warm-up
+        # scope of their own: runtime-toggle callers run them alone).
+        if self._spec_enabled:
+            self.warmup_spec_shapes()
         # Arm hot-path compile detection: every signature compiled above
         # (plus anything later warm scopes add) is the pre-warmed rung
         # set; a first-seen signature from here on is a loud incident.
@@ -3305,20 +3252,18 @@ class LLMEngine:
     def _prefill_wave(
         self,
         admitted: List[_Request],
-        bucket: int,
-        use_chunked: bool,
         register: bool = True,
         between_chunks: Optional[Callable[[], None]] = None,
     ) -> List[object]:
         """Run one claimed wave's prefill mechanics.
 
         The wave itself was formed by the scheduler policy
-        (``SchedulerPolicy.claim_wave`` — the extracted claim logic:
-        ONE wave per call filled from the whole backlog, oldest
-        request's bucket, leftover back at the queue front; see
-        engine/scheduler/base.py). This method owns everything from
-        prefix matching through the prefill dispatches and the
-        radix-cache insert.
+        (``SchedulerPolicy.claim_wave``: ONE wave per call, the oldest
+        claimable requests up to the row cap, leftover back at the
+        queue front; see engine/scheduler/base.py). This method owns
+        everything from prefix matching through the chunk walk
+        (``_prefill_chunked``: a prompt of at most one chunk is one
+        chunk at offset zero) and the radix-cache insert.
 
         ``register=True`` (the unified policy, dispatch thread)
         registers the finished rows into the decode batch directly —
@@ -3330,24 +3275,21 @@ class LLMEngine:
         decode loop registers them in ``_import_handoff``.
 
         ``between_chunks`` is the policy's: what the dispatch thread
-        does between two chunk dispatches of a chunked wave.
+        does between two chunk dispatches of the wave.
         """
-        import jax
         import jax.numpy as jnp
 
         from generativeaiexamples_tpu.engine.scheduler import handoff as handoff_mod
 
-        chunk = self.engine_config.prefill_chunk
         records: List[object] = []
         self._wave_entries = []  # stateful prefix entries this wave inserts
 
-        # Prefix-cache matching (chunked waves only — a monolithic wave
-        # means every prompt fits one chunk, below the smallest
-        # cacheable prefix). Hoisted ahead of the paged funding step,
-        # which needs each hit's mapped length to size its reservation.
-        # Matching pins each hit entry until the funding step's
-        # refcount bump secures its pages.
-        if use_chunked and self._prefix is not None:
+        # Prefix-cache matching (a prompt under one chunk is below the
+        # smallest cacheable prefix and matches nothing). Hoisted ahead
+        # of the paged funding step, which needs each hit's mapped
+        # length to size its reservation. Matching pins each hit entry
+        # until the funding step's refcount bump secures its pages.
+        if self._prefix is not None:
             for req in admitted:
                 m = self._prefix.match(
                     req.prompt_ids, hint=req.params.prefix_hint
@@ -3365,36 +3307,31 @@ class LLMEngine:
         if not admitted:
             return records
 
-        # Cap rows x bucket per wave: the compiled prefill's activation
-        # footprint scales with total wave tokens, and an uncapped
-        # long-prompt wave can be UNCOMPILABLE (a 16 x 2560-token
-        # unrolled 8B prefill plans >17 GB on a 16 GB chip — observed
-        # as silent empty answers through the whole RAG stack). Chunked
-        # waves are inherently bounded (Np x prefill_chunk per dispatch).
-        if use_chunked:
-            bucket = max(
-                self._prefill_bucket(len(r.prompt_ids)) for r in admitted
-            )
-        group = admitted
-
-        N = len(group)
+        # The width of the wave's token array: its longest prompt in
+        # whole chunks. A dispatch is bounded whatever that is, at
+        # Np x prefill_chunk tokens.
+        shapes = self.shapes
+        bucket = shapes.prefill_bucket(
+            max(len(r.prompt_ids) for r in admitted)
+        )
+        N = len(admitted)
         # Pad up the wave-size ladder (powers of four + num_slots),
-        # repeating row 0 — each bucket then needs only the shapes
+        # repeating row 0 — the wave then needs only the shapes
         # warmup() compiles. Coarser than powers of two on purpose:
         # every rung is a separate XLA executable of the whole
-        # unrolled prefill (~40 s compile each),
+        # unrolled walk (~40 s compile each),
         # and at most 3x padding costs far less than it saves.
         # A packed family's wave has ONE row count, the cap: its rows
         # ride one token axis, so the count shapes only the carry.
-        cap = self._max_wave_rows(chunk if use_chunked else bucket)
-        Np = cap if self._packed else min(self._wave_pad(N), cap)
-        rows = group + [group[0]] * (Np - N)
+        cap = shapes.max_wave_rows()
+        Np = cap if shapes.packed else min(shapes.wave_pad(N), cap)
+        rows = admitted + [admitted[0]] * (Np - N)
         # Per-row cached lengths (prefix hits matched above): warm
         # rows skip their cached chunks in the loop below; the
         # funding step already mapped the shared pages — zero
         # device work.
         cached = None
-        if use_chunked and self._prefix is not None:
+        if self._prefix is not None:
             cached = np.zeros((Np,), np.int32)
             for i, req in enumerate(rows):
                 cached[i] = req.prefix_len
@@ -3414,53 +3351,10 @@ class LLMEngine:
                 topps[i] = req.params.top_p
                 seeds[i] = req.sampling_seed & 0x7FFFFFFF
             _M_WAVES.inc()
-            if use_chunked:
-                first_tokens = self._prefill_chunked(
-                    tokens, lengths, slots, temps, topps, seeds, cached,
-                    reqs=group, between_chunks=between_chunks,
-                )
-            else:
-                for req in group:
-                    flight_recorder.event_rid(
-                        req.rid, "prefill_wave", bucket=bucket,
-                        wave_rows=Np, live_rows=N,
-                    )
-                state_fields = self._state_counters(
-                    "prefill", N, int(lengths[:N].sum()), 0, resets=N
-                )
-                _M_EXTEND_COMPUTED.inc(Np * bucket)
-                _dtl = self._dtl
-                if _dtl is not None:
-                    _dtl_wall = time.time()
-                    _dtl_t0 = time.perf_counter()
-                    _dtl_t1 = _dtl_t0
-                with self._dispatch_lock, \
-                        self._annotate("engine.prefill_wave"):
-                    if _dtl is not None:
-                        _dtl_t1 = time.perf_counter()
-                    first_tokens, self._cache = self._prefill_fn(
-                        self.params,
-                        self._cache,
-                        jnp.asarray(tokens),
-                        jnp.asarray(lengths),
-                        jnp.asarray(slots),
-                        jnp.asarray(temps),
-                        jnp.asarray(topps),
-                        jnp.asarray(seeds),
-                        self._tables_dev,
-                    )
-                if _dtl is not None:
-                    _dtl.record_span(
-                        "prefill",
-                        t_wall=_dtl_wall,
-                        lock_wait_s=_dtl_t1 - _dtl_t0,
-                        run_s=time.perf_counter() - _dtl_t1,
-                        rows=N,
-                        tokens=int(lengths.sum()),
-                        rids=[r.rid for r in group],
-                        counters=state_fields,
-                        handle=first_tokens,
-                    )
+            first_tokens = self._prefill_chunked(
+                tokens, lengths, slots, temps, topps, seeds, cached,
+                reqs=admitted, between_chunks=between_chunks,
+            )
             # Inject into the device-resident batch state — dispatched, not
             # synced; token values reach the host via the reader.
             # Under the dispatch lock: decode dispatches consume
@@ -3491,7 +3385,7 @@ class LLMEngine:
             if (
                 self._spec_enabled
                 and spec_prop is not None
-                and any(spec_prop.eligible(r.params) for r in group)
+                and any(spec_prop.eligible(r.params) for r in admitted)
             ):
                 # Spec proposals need each draft-capable slot's
                 # first token on the host BEFORE the next dispatch
@@ -3503,7 +3397,7 @@ class LLMEngine:
                 # genai-lint: disable=dispatch-readback -- allow-listed spec sync: the next proposal needs this wave's first tokens on the host
                 first_np = np.atleast_1d(np.asarray(first_tokens))
             with self._lock:
-                for i, req in enumerate(group):
+                for i, req in enumerate(admitted):
                     T = len(req.prompt_ids)
                     req.position = T
                     spec_tokens = None
@@ -3567,7 +3461,7 @@ class LLMEngine:
                 # the first token. Device-ordered before any draft
                 # proposal for these slots; no sync.
                 eligible = np.zeros((len(rows),), bool)
-                for i, req in enumerate(group):
+                for i, req in enumerate(admitted):
                     eligible[i] = spec_prop.eligible(req.params)
                 # Dispatch lock: the draft cache is donated per
                 # dispatch too, and under disagg the decode tier's
@@ -3577,7 +3471,7 @@ class LLMEngine:
                     self._draft.prefill_wave(
                         tokens, lengths, slots, eligible
                     )
-                for i, req in enumerate(group):
+                for i, req in enumerate(admitted):
                     if eligible[i]:
                         spec_prop.on_admit(req.slot, int(lengths[i]))
                         flight_recorder.event_rid(
@@ -3596,7 +3490,7 @@ class LLMEngine:
             for ent in self._wave_entries:
                 self._prefix.discard(ent)  # a failed admission leaves no entry
             with self._lock:
-                for req in group:
+                for req in admitted:
                     if self._slot_req.get(req.slot) is req:
                         continue  # registered: the loop handler owns it
                     if req.prefix_entry is not None and self._prefix is not None:
@@ -3627,15 +3521,15 @@ class LLMEngine:
         wave_stats, self._wave_stats = self._wave_stats, []
         self._readback.put((
             "prefill", first_tokens,
-            [(i, req) for i, req in enumerate(group)], wave_stats,
+            [(i, req) for i, req in enumerate(admitted)], wave_stats,
         ))
         # Insert completed prefills back into the radix cache
         # (dispatch-ordered after the chunk loop; decode only ever
         # appends at positions >= T, never rewriting [0:cached]).
         # Skipped when the prefix is already cached at full depth
         # or every entry ticket is pinned by a live request.
-        if use_chunked and self._prefix is not None and not self._state_store_rows:
-            for req in group:
+        if self._prefix is not None and not self._state_store_rows:
+            for req in admitted:
                 # Zero-copy insert: donate the request's own
                 # prompt pages (refcount bump) — the entry and
                 # the live request share the physical rows; the
@@ -3804,7 +3698,7 @@ class LLMEngine:
             self._update_occupancy_gauges()
 
     def _prefill_chunked(self, tokens, lengths, slots, temps, topps, seeds,
-                         cached=None, reqs=None, between_chunks=None):
+                         cached, reqs, between_chunks=None):
         """Prefill a mixed-length wave as chunk dispatches shaped by
         what each chunk holds.
 
@@ -3813,22 +3707,23 @@ class LLMEngine:
         dispatched. Offsets stay k*C, so only a row's LAST chunk can be
         short and cached prefixes stay chunk- and page-aligned.
 
-        A family with a packed walk (``_packed``) gets the chunk's LIVE
-        TOKENS on one axis, row after row, padded up the one token
-        ladder (``_packed_rungs``): no padded row, no padded width. Any
+        A family with a packed walk (``shapes.packed``) gets the chunk's
+        LIVE TOKENS on one axis, row after row, padded up the one token
+        ladder (``packed_rungs``): no padded row, no padded width. Any
         other family gets a rectangle: the rows that hold tokens, padded
         up the wave ladder, at the narrowest rung of the width ladder
-        that holds the longest (``_chunk_rung`` decides both).
+        that holds the longest (``shapes.chunk_rung`` decides both).
 
         The per-row last-token hidden accumulates on device over the
         whole wave's rows (the packed program writes each row's into
         the carry itself; a rectangle of fewer rows hands its own back
         through ``_put_rows_fn``); one finish dispatch samples the
-        first tokens. Shapes seen by XLA: ``_extend_signatures`` — all
-        warmed by warmup_chunked_shapes, so no compile can land inside
-        a request.
+        first tokens. Shapes seen by XLA: ``shapes.extend_signatures()``
+        — all warmed by warmup(), so no compile can land inside a
+        request.
 
-        ``cached`` ([Np] int32, chunk-aligned) marks each row's prefix
+        ``cached`` ([Np] int32, chunk-aligned; None without a prefix store)
+        marks each row's prefix
         rows already present in its slot cache (mapped from the prefix
         store at admission): chunks below a row's cached length do not
         hold it, so a warm wave dispatches strictly fewer chunk steps
@@ -3848,9 +3743,10 @@ class LLMEngine:
         """
         import jax.numpy as jnp
 
-        C = self.engine_config.prefill_chunk
+        shapes = self.shapes
+        C = shapes.prefill_chunk
         Np, Tmax = tokens.shape
-        n_real = len(reqs) if reqs is not None else Np
+        n_real = len(reqs)
         K = (Tmax + C - 1) // C
         annotate = self._annotate
         self._wave_stats = []
@@ -3863,7 +3759,7 @@ class LLMEngine:
         # the index will name for the prompt. Device order is dispatch
         # order, so each copy sits between the chunks around it.
         save_at: Dict[int, int] = {}
-        if self._state_store_rows and reqs is not None and cached is not None:
+        if self._state_store_rows:
             for i, req in enumerate(reqs):
                 if cached[i] > 0 and req.prefix_via is not None:
                     self._copy_prefix_state(
@@ -3878,16 +3774,16 @@ class LLMEngine:
             if cached is not None:
                 valid = np.where(k * C < cached, 0, valid).astype(np.int32)
             valid[n_real:] = 0
-            shape = self._chunk_rung(valid, n_real)
-            if shape is None:
+            rung = shapes.chunk_rung(valid, n_real)
+            if rung is None:
                 continue
-            live, n, width = shape
+            live, n, width = rung
             if dispatched and between_chunks is not None:
                 between_chunks()
             dispatched += 1
             n_live = len(live)
-            W = self._extend_window(k, C if self._packed else width)
-            if self._packed:
+            W = shapes.extend_window(k, C if shapes.packed else width)
+            if shapes.packed:
                 # the live rows' tokens one after the other; the rows
                 # past them start where the tokens end and hold none
                 tok_k = np.zeros((width,), np.int32)
@@ -3902,7 +3798,7 @@ class LLMEngine:
                 operands = (
                     jnp.asarray(tok_k), jnp.asarray(rows_k),
                     jnp.asarray(np.array(
-                        [n_live, self._packed_windows().index(W)], np.int32
+                        [n_live, shapes.packed_windows().index(W)], np.int32
                     )),
                 )
                 # (the program places each row in the wave's carry itself;
@@ -3966,7 +3862,7 @@ class LLMEngine:
                 "width": width,
                 "pad_tokens": n * width - live_tokens,
                 # live rows that share one token axis (1: nothing packed)
-                "packed_rows": n_live if self._packed else 1,
+                "packed_rows": n_live if shapes.packed else 1,
             }
             fields.update(self._state_counters(
                 "prefill_chunk", n_live, live_tokens,
@@ -3987,13 +3883,11 @@ class LLMEngine:
                     run_s=time.perf_counter() - _dtl_t1,
                     rows=n_live,
                     tokens=live_tokens,
-                    rids=(
-                        [r.rid for r in reqs] if reqs is not None else ()
-                    ),
+                    rids=[r.rid for r in reqs],
                     counters=fields,
                     handle=sub_h,
                 )
-            if reqs is not None and flight_recorder.enabled():
+            if flight_recorder.enabled():
                 for i in live:
                     flight_recorder.event_rid(
                         reqs[i].rid, "prefill_chunk", chunk=k, window=W,
@@ -4048,7 +3942,7 @@ class LLMEngine:
         ``state_rows`` rows whose per-slot state advanced, ``kv_readers``
         layers that read the one paged K/V, ``window_tokens_read`` ring
         rows the window layers read (first step of a decode block) and,
-        on prefill and extend, ``cross_skipped_tokens``: tokens the
+        on extend, ``cross_skipped_tokens``: tokens the
         layers past the shared-KV layer never saw (all but one a row)."""
         if kind != "decode":
             _M_PREFILL_TOKENS.inc(tokens)  # every family: the skipped share's denominator
@@ -4071,149 +3965,10 @@ class LLMEngine:
                 _M_CROSS_SKIPPED.inc(fields["cross_skipped_tokens"])
         return fields
 
-    def _prefill_bucket(self, n: int) -> int:
-        chunk = self.engine_config.prefill_chunk
-        bucket = ((n + chunk - 1) // chunk) * chunk
-        return min(bucket, self.max_seq_len)
-
-    def _max_wave_rows(self, bucket: int) -> int:
-        """Max prefill rows for this bucket under prefill_wave_tokens.
-
-        A fixed-state family gets ONE row a wave, monolithic or chunked:
-        on the chip its chunk walk over several LIVE rows now and then
-        never ended (PERF.md section 6, PR 29: four runs of ten, cause
-        not found), every one-row wave did. No wider program is built
-        or warmed, so no setting can reach one."""
-        if self._fixed_state:
-            return 1
-        budget = self.engine_config.prefill_wave_tokens
-        return max(1, min(self.num_slots, budget // max(1, bucket)))
-
-    def _wave_sizes(self) -> List[int]:
-        """Admission-wave padding ladder + num_slots. Powers of FOUR:
-        each rung is a ~40 s compile of the whole unrolled prefill,
-        worth up to 3x padding waste."""
-        step = 4
-        sizes = []
-        n = 1
-        while n < self.num_slots:
-            sizes.append(n)
-            n *= step
-        sizes.append(self.num_slots)
-        return sizes
-
-    def _wave_pad(self, n: int) -> int:
-        for s in self._wave_sizes():
-            if s >= n:
-                return s
-        return self.num_slots
-
-    def _chunk_widths(self) -> List[int]:
-        """Width ladder of an extend dispatch, by the row ladder's rule:
-        powers of four down from ``prefill_chunk``, whole pages, never
-        under one ({128, 512} at a chunk of 512 over pages of 128). No
-        finer: every rung multiplies executables."""
-        page = self.engine_config.page_size
-        widths = [self.engine_config.prefill_chunk]
-        while widths[-1] % (4 * page) == 0:
-            widths.append(widths[-1] // 4)
-        return widths[::-1]
-
-    def _packed_rungs(self) -> List[int]:
-        """The ONE ladder of a packed dispatch: the token counts ``T``
-        its axis is padded to. Whole pages at 1 and 1.5 times the powers
-        of two, from one page to the most a wave's chunk can hold (the
-        row cap x ``prefill_chunk``): {128, 256, 384, 512, 768, 1024,
-        1536, 2048} at a chunk of 512 over pages of 128 under
-        ``prefill_wave_tokens`` 2048, so under a third of any dispatch
-        is padding, and the count grows with the logarithm of the wave,
-        not with rows x widths x windows."""
-        page = self.engine_config.page_size
-        C = self.engine_config.prefill_chunk
-        top = self._max_wave_rows(C) * C
-        rungs = {top}
-        n = page
-        while n < top:
-            rungs.add(n)
-            if (3 * n // 2) % page == 0 and 3 * n // 2 < top:
-                rungs.add(3 * n // 2)
-            n *= 2
-        return sorted(rungs)
-
-    def _packed_windows(self) -> List[int]:
-        """The gather windows a packed program holds, ascending: chunk
-        ``k``'s rung of ``_extend_window`` for every ``k``. The dispatch
-        names one by its index (an operand), so they multiply no
-        executables."""
-        C = self.engine_config.prefill_chunk
-        return sorted({
-            self._extend_window(k, C)
-            for k in range((self.max_seq_len + C - 1) // C)
-        })
-
-    def _chunk_rung(
-        self, valid: Sequence[int], n_real: int
-    ) -> Optional[Tuple[List[int], int, int]]:
-        """(live rows, rows dispatched, width) of one chunk of a wave,
-        from what the chunk holds: the rows with tokens in THIS chunk
-        (the wave's padding rows, past ``n_real``, are never live). A
-        packed family: ONE axis at the least token rung that holds the
-        live tokens. Any other: the live rows padded up the wave ladder
-        under the chunk's row cap, at the narrowest width rung that
-        holds the longest of them. None where no row is live: such a
-        chunk is not dispatched."""
-        live = [i for i in range(n_real) if valid[i] > 0]
-        if not live:
-            return None
-        if self._packed:
-            need = sum(int(valid[i]) for i in live)
-            return live, 1, next(t for t in self._packed_rungs() if t >= need)
-        rows = min(
-            self._wave_pad(len(live)),
-            self._max_wave_rows(self.engine_config.prefill_chunk),
-        )
-        need = max(int(valid[i]) for i in live)
-        width = next(w for w in self._chunk_widths() if w >= need)
-        return live, rows, width
-
-    def _extend_window(self, k: int, width: int) -> int:
-        """The static attention window of chunk ``k`` at ``width``. A
-        full chunk gathers the power-of-two window that covers it. A
-        narrow one has ONE rung, capacity: under the page kernel the
-        walk follows each row's live pages whatever the window says, and
-        on the gather 128 queries over 4096 keys cost what 512 over 1024
-        do, the least a full-width tail pays. A family whose extend walk
-        follows each row's own context (``_one_extend_window``) has that
-        one rung at every width."""
-        C = self.engine_config.prefill_chunk
-        if width < C or self._one_extend_window:
-            return self.max_seq_len
-        return self._attention_window(min((k + 1) * C, self.max_seq_len))
-
-    def _extend_signatures(self) -> List[Tuple[int, int, int]]:
-        """Every (rows, width, window) an extend dispatch can have —
-        what ``_chunk_rung`` and ``_extend_window`` can produce, and
-        what warm-up compiles: no other. A packed family: (the carry's
-        rows, T, capacity) for every token rung, one program each (the
-        chunk's window is an operand of it)."""
-        C = self.engine_config.prefill_chunk
-        cap = self._max_wave_rows(C)
-        if self._packed:
-            return [(cap, t, self.max_seq_len) for t in self._packed_rungs()]
-        chunks = range((self.max_seq_len + C - 1) // C)
-        return sorted({
-            (n, w, self._extend_window(k, w))
-            for n in {min(s, cap) for s in self._wave_sizes()}
-            for w in self._chunk_widths()
-            for k in chunks
-        })
-
     def _attention_window(self, needed: int) -> int:
-        """Power-of-two attention window (>=128) covering `needed` rows."""
-        w = 128
-        while w < needed and w < self.max_seq_len:
-            w *= 2
-        return min(w, self.max_seq_len)
+        # the one ladder delegate kept: five adapters of the benchmark
+        # call it (perfbench/arch/{phi4flash,glm5next,gigachat35,afmoe,solaropen2}.py)
+        return self.shapes.attention_window(needed)
 
     def _spec_has_draftable(self) -> bool:
         """Whether any live row could draft: proposer-eligible (greedy
@@ -4230,31 +3985,6 @@ class LLMEngine:
                 slot in self._spec_ctx and prop.eligible(req.params)
                 for slot, req in self._slot_req.items()
             )
-
-    def _decode_window(self, max_pos: int) -> int:
-        """The static attention-window rung a block-decode dispatch at
-        frontier ``max_pos`` runs with — ONE rule shared by _decode_once
-        and the spec zero-draft fallback so they cannot drift onto
-        different executables."""
-        # The ragged page kernel tracks per-slot lengths itself (its
-        # scalar-prefetched tables): one full-capacity executable
-        # instead of a ~40 s recompile at every power-of-two window
-        # crossing.
-        if self._paged_kernel:
-            return self.max_seq_len
-        return self._attention_window(max_pos + self._decode_block)
-
-    def _window_rungs(self) -> List[int]:
-        """Every power-of-two attention-window rung up to capacity —
-        the executable ladder warmup walks (one XLA program per rung
-        per compiled step family)."""
-        rungs = []
-        w = 128
-        while w < self.max_seq_len:
-            rungs.append(w)
-            w *= 2
-        rungs.append(self.max_seq_len)
-        return rungs
 
     def _decode_once(self) -> None:
         # Land any in-flight pipelined verify BEFORE choosing a path:
@@ -4282,8 +4012,8 @@ class LLMEngine:
             # Smallest power-of-two window covering every query position
             # this block can reach (positions advance by decode_block);
             # the page kernel's one full-capacity program is
-            # _decode_window's to know.
-            window = self._decode_window(
+            # decode_window's to know.
+            window = self.shapes.decode_window(
                 max(self._slot_pos.values(), default=0)
             )
             live_slots = list(self._slot_req)
@@ -4306,7 +4036,7 @@ class LLMEngine:
             # now, values when the slab is read back (_note_stats)
             span_counts.update(dict.fromkeys(self._stat_names, 0))
             for slot in self._slot_pos:
-                self._slot_pos[slot] += self._decode_block
+                self._slot_pos[slot] += self.shapes.decode_block
             self._update_occupancy_gauges()
         # Dispatch lock across read→call→rebind: the disagg prefill
         # tier's chunk dispatches consume/rebind the same donated cache
@@ -4338,14 +4068,14 @@ class LLMEngine:
                 self._cache,
                 token_slab,
             ) = out
-        _M_DECODE_STEPS.inc(self._decode_block)
+        _M_DECODE_STEPS.inc(self.shapes.decode_block)
         _M_DECODE_DISPATCHES.inc()
         path = "kernel" if self._paged_kernel else "gather"
         _M_PAGED_ATTN.labels(path=path).inc()
         with self._lock:
             snapshot = list(self._slot_req.items())
             for slot in list(self._slot_budget):
-                self._slot_budget[slot] -= self._decode_block
+                self._slot_budget[slot] -= self.shapes.decode_block
         if _dtl is not None:
             _dtl.record_span(
                 "decode",
@@ -4353,8 +4083,8 @@ class LLMEngine:
                 lock_wait_s=_dtl_t1 - _dtl_t0,
                 run_s=time.perf_counter() - _dtl_t1,
                 rows=len(live_slots),
-                tokens=self._decode_block * len(live_slots),
-                steps=self._decode_block,
+                tokens=self.shapes.decode_block * len(live_slots),
+                steps=self.shapes.decode_block,
                 path=path,
                 rids=[r.rid for _, r in snapshot],
                 counters=span_counts,
@@ -4426,7 +4156,7 @@ class LLMEngine:
             if self._paged_verify_kernel:
                 window = self.max_seq_len
             else:
-                window = self._attention_window(
+                window = self.shapes.attention_window(
                     min(max_pos_live + K + 1, self.max_seq_len)
                 )
             live = np.zeros((self.num_slots,), bool)
@@ -4803,7 +4533,7 @@ class LLMEngine:
         pre-fetched slab under its own "spec_block" kind, so the host
         values do not inject bogus ~0 s samples into the decode
         readback histogram."""
-        window = self._decode_window(max_pos_live)
+        window = self.shapes.decode_window(max_pos_live)
         _dtl = self._dtl
         if _dtl is not None:
             _dtl_wall = time.time()
@@ -4836,13 +4566,13 @@ class LLMEngine:
                 lock_wait_s=_dtl_t1 - _dtl_t0,
                 run_s=time.perf_counter() - _dtl_t1,
                 rows=len(snapshot),
-                tokens=self._decode_block * len(snapshot),
-                steps=self._decode_block,
+                tokens=self.shapes.decode_block * len(snapshot),
+                steps=self.shapes.decode_block,
                 path="kernel" if self._paged_kernel else "gather",
                 rids=[r.rid for _, r in snapshot],
                 handle=token_slab,
             )
-        _M_DECODE_STEPS.inc(self._decode_block)
+        _M_DECODE_STEPS.inc(self.shapes.decode_block)
         _M_DECODE_DISPATCHES.inc()
         _sampler_full_rows(r for _, r in snapshot)
         path = "kernel" if self._paged_kernel else "gather"
@@ -4858,9 +4588,9 @@ class LLMEngine:
         with self._lock:
             for slot, req in snapshot:
                 if slot in self._slot_budget:
-                    self._slot_budget[slot] -= self._decode_block
+                    self._slot_budget[slot] -= self.shapes.decode_block
                 if slot in self._slot_pos:
-                    self._slot_pos[slot] += self._decode_block
+                    self._slot_pos[slot] += self.shapes.decode_block
                 buf = self._spec_ctx.get(slot)
                 if buf is not None:
                     buf.extend(int(t) for t in slab_np[:, slot])
@@ -4873,7 +4603,7 @@ class LLMEngine:
         compile on a TPU). Zero-live dispatches are
         value-level no-ops on the caches, so no scheduler involvement is
         needed — but the caches are DONATED, so live decode must quiesce
-        first (same discipline as warmup_chunked_shapes). Called by
+        first (same discipline as warmup()). Called by
         warmup() when spec is enabled and by runtime-toggle callers;
         without it the first verify dispatch at each window rung would
         compile inside a request."""
@@ -4887,22 +4617,10 @@ class LLMEngine:
         if self._paged_verify_kernel:
             windows = [self.max_seq_len]
         else:
-            windows = self._window_rungs()
+            windows = self.shapes.window_rungs()
         with self._compile_watch.warmup_scope(), self.hold_admissions():
-            quiesce_s = float(self.engine_config.quiesce_timeout_s)
-            deadline = time.time() + quiesce_s
-            with self._lock:
-                while (
-                    self._slot_req or self.scheduler.tier_busy()
-                ) and self._running:
-                    if time.time() > deadline:
-                        raise TimeoutError(
-                            f"warmup_spec_shapes: live decode did not "
-                            f"quiesce within {quiesce_s:.0f} s"
-                        )
-                    self._lock.wait(timeout=0.2)
-                if not self._running:
-                    return
+            if not self._quiesce_for_warmup("warmup_spec_shapes"):
+                return
             B = self.num_slots
             zeros_i = jnp.zeros((B,), jnp.int32)
             live = np.zeros((B,), bool)
@@ -5073,7 +4791,7 @@ class LLMEngine:
                     self._note_stats(fields, np.asarray(stats))
                 continue
             if self._stat_names and extra:
-                block = self._decode_block
+                block = self.shapes.decode_block
                 self._note_stats(extra[0], values[block:].reshape(-1))
                 values = values[:block]
             self._emit_slab(values, slots)
@@ -5314,33 +5032,31 @@ def engine_wedged() -> bool:
 
 
 def start_background_warmup(engine_config: Optional[EngineConfig] = None):
-    """Build the engine singleton and pre-compile the configured
-    prompt-length buckets on a daemon thread (EngineConfig.
-    warmup_prompt_lengths / APP_ENGINE_WARMUPPROMPTLENGTHS).
+    """Build the engine singleton and pre-compile its serving shapes
+    on a daemon thread, where EngineConfig.warmup_prompt_lengths /
+    APP_ENGINE_WARMUPPROMPTLENGTHS is non-empty (the switch; its values
+    select nothing: the one warm walk covers every prompt length).
 
     Shared by the chain-server and the OpenAI-compatible facade: without
-    warming, the first request into a cold bucket stalls on a
-    multi-minute XLA compile of the serving graph (~5 min measured for
-    an 8B bucket mid-serving, BASELINE.md). Never raises — a malformed
-    config logs and returns None (warmup must not kill serving).
+    warming, the first request of each shape stalls on a multi-minute
+    XLA compile of the serving graph (~5 min measured for an 8B prefill
+    mid-serving, BASELINE.md). Never raises — a malformed config logs
+    and returns None (warmup must not kill serving).
     """
     if engine_config is None:
         from generativeaiexamples_tpu.config import get_config
 
         engine_config = get_config().engine
     raw = (getattr(engine_config, "warmup_prompt_lengths", "") or "").strip()
-    if not raw:
-        return None
-    try:
-        lengths = [int(x) for x in raw.replace(";", ",").split(",") if x.strip()]
-    except ValueError:
+    parts = [x.strip() for x in raw.replace(";", ",").split(",") if x.strip()]
+    if not all(x.isdigit() for x in parts):
         logger.warning(
             "Invalid warmup_prompt_lengths %r (want comma-separated ints); "
             "skipping warmup",
             raw,
         )
         return None
-    if not lengths:
+    if not parts:
         return None
 
     WARMUP_DONE.clear()
@@ -5357,11 +5073,11 @@ def start_background_warmup(engine_config: Optional[EngineConfig] = None):
             t0 = time.time()
             engine = get_engine(engine_config)
             t1 = time.time()
-            engine.warmup(prompt_lengths=lengths)
+            engine.warmup()
             logger.info(
-                "Engine warmup complete for prompt lengths %s "
+                "Engine warmup complete "
                 "(engine build %.1f s, warmup %.1f s; %s)",
-                lengths, t1 - t0, time.time() - t1, engine.device_memory_line(),
+                t1 - t0, time.time() - t1, engine.device_memory_line(),
             )
         except Exception:  # noqa: BLE001 - warmup must not kill serving
             # Serving continues (requests compile on demand), but loudly:

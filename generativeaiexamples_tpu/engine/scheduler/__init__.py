@@ -11,7 +11,6 @@ from generativeaiexamples_tpu.engine.scheduler.base import (  # noqa: F401
     POLICY_KINDS,
     AcceptanceTracker,
     SchedulerPolicy,
-    WavePlan,
     build_policy,
     metrics_snapshot,
     validate_config,

@@ -38,7 +38,6 @@ The policy also owns three cross-cutting scheduling decisions:
 from __future__ import annotations
 
 import collections
-import dataclasses
 from typing import Any, Dict, List, Optional
 
 from generativeaiexamples_tpu.utils import flight_recorder
@@ -102,19 +101,6 @@ def metrics_snapshot() -> Dict[str, float]:
     out = handoff_mod.metrics_snapshot()
     out["spec_draft_skips"] = _M_SPEC_DRAFT_SKIPS.value
     return out
-
-
-@dataclasses.dataclass
-class WavePlan:
-    """One admission wave the policy formed: the claimed requests (each
-    holding a slot already) plus the shape decisions the prefill
-    mechanics need. ``bucket`` is the monolithic prefill bucket (the
-    first claimable's, per the pre-extraction rule); chunked waves
-    recompute it from the admitted max inside the prefill path."""
-
-    admitted: List[Any]
-    bucket: int
-    use_chunked: bool
 
 
 class AcceptanceTracker:
@@ -181,8 +167,8 @@ class SchedulerPolicy:
     ingest-window coordination, and draft-aware gating.
 
     Subclasses implement the tier topology; the shared
-    :meth:`claim_wave` holds the wave-formation rule both policies use
-    (the exact pre-extraction ``_admit`` claim logic), so ``unified``
+    :meth:`claim_wave` holds the wave-formation rule both policies use,
+    so ``unified``
     and ``disagg`` cannot drift on HOW a wave forms — only on WHICH
     thread forms it and where registration happens.
     """
@@ -287,16 +273,17 @@ class SchedulerPolicy:
         tier_assign events here; unified is single-tier and stays
         silent (no new events on pre-existing timelines)."""
 
-    def claim_wave(self) -> Optional[WavePlan]:
+    def claim_wave(self) -> List[Any]:
         """Form ONE admission wave from the backlog, claiming slots.
 
-        This is the pre-extraction ``_admit`` claim logic, verbatim:
-        fill the wave from the WHOLE backlog grouped by prefill bucket
-        (chunked waves admit any length), dispatch only the oldest
-        request's fullest-possible wave now, push the rest back to the
-        queue front. Slot placement is the free-list pop (LIFO — the
-        warm-slot reuse order the executables were warmed under).
-        Returns None when paused or nothing is claimable.
+        The wave is the oldest claimable requests up to the row cap
+        (``engine.shapes.max_wave_rows()``), whatever their lengths:
+        every row runs the same chunk dispatches with per-row valid
+        counts, so a mixed-length backlog fills one wave. The rest goes
+        back to the queue front. Slot placement is the free-list pop
+        (LIFO — the warm-slot reuse order the executables were warmed
+        under). Returns the claimed requests, each holding a slot; none
+        when paused or nothing is claimable.
         """
         import time as _time
 
@@ -304,10 +291,9 @@ class SchedulerPolicy:
 
         eng = self.engine
         admitted: List[Any] = []
-        bucket = 0
         with eng._lock:
             if eng._paused:
-                return None
+                return admitted
             claimable: List[Any] = []
             while eng._pending and len(claimable) < len(eng._free_slots):
                 req = eng._pending.popleft()
@@ -318,57 +304,29 @@ class SchedulerPolicy:
                 req.prompt_ids = req.prompt_ids or [eng.tokenizer.bos_id]
                 claimable.append(req)
             if not claimable:
-                return None
-            bucket = eng._prefill_bucket(len(claimable[0].prompt_ids))
-            chunk = eng.engine_config.prefill_chunk
-            # Chunked waves admit ANY prompt length: every row runs the
-            # same fixed-shape chunk dispatches with per-row valid
-            # masks, so mixed-length backlogs fill one wave instead of
-            # fragmenting into per-bucket waves. Engaged when ANY
-            # claimable prompt exceeds one chunk — short-only backlogs
-            # keep the flash-kernel monolithic prefill, but for a family
-            # whose waves go out packed (one token axis, no monolithic
-            # program: short rows cost what they hold either way).
-            use_chunked = eng._packed or any(
-                eng._prefill_bucket(len(r.prompt_ids)) > chunk
-                for r in claimable
-            )
-            cap = (
-                eng._max_wave_rows(chunk)
-                if use_chunked
-                else eng._max_wave_rows(bucket)
-            )
-            leftover: List[Any] = []
-            for req in claimable:
-                if len(admitted) < cap and (
-                    use_chunked
-                    or eng._prefill_bucket(len(req.prompt_ids)) == bucket
-                ):
-                    req.slot = eng._free_slots.pop()
-                    # A page-backpressure requeue re-enters this claim
-                    # path; observe the queue wait and emit "admit" only
-                    # for the FIRST claim, or every retry would add a
-                    # cumulative overlapping sample to the histogram.
-                    first_claim = req.t_admit == 0.0
-                    req.t_admit = _time.time()
-                    if first_claim:
-                        eng_mod._M_QUEUE_WAIT.observe(
-                            req.t_admit - req.t_submit,
-                            trace_id=req.trace_hex,
-                        )
-                        flight_recorder.event_rid(
-                            req.rid, "admit", slot=req.slot,
-                            queue_wait_s=round(
-                                req.t_admit - req.t_submit, 6
-                            ),
-                        )
-                    admitted.append(req)
-                else:
-                    leftover.append(req)
-            eng._pending.extendleft(reversed(leftover))
+                return admitted
+            cap = eng.shapes.max_wave_rows()
+            for req in claimable[:cap]:
+                req.slot = eng._free_slots.pop()
+                # A page-backpressure requeue re-enters this claim
+                # path; observe the queue wait and emit "admit" only
+                # for the FIRST claim, or every retry would add a
+                # cumulative overlapping sample to the histogram.
+                first_claim = req.t_admit == 0.0
+                req.t_admit = _time.time()
+                if first_claim:
+                    eng_mod._M_QUEUE_WAIT.observe(
+                        req.t_admit - req.t_submit,
+                        trace_id=req.trace_hex,
+                    )
+                    flight_recorder.event_rid(
+                        req.rid, "admit", slot=req.slot,
+                        queue_wait_s=round(
+                            req.t_admit - req.t_submit, 6
+                        ),
+                    )
+                admitted.append(req)
+            eng._pending.extendleft(reversed(claimable[cap:]))
             eng_mod._M_QUEUE_DEPTH.set(len(eng._pending))
-            if admitted:
-                self._on_claimed(admitted)
-        if not admitted:
-            return None
-        return WavePlan(admitted=admitted, bucket=bucket, use_chunked=use_chunked)
+            self._on_claimed(admitted)
+        return admitted

@@ -240,12 +240,9 @@ class DisaggPolicy(SchedulerPolicy):
                     capacity=self.transfer.capacity,
                 )
             try:
-                plan = self.claim_wave()
-                if plan is not None:
-                    records = eng._prefill_wave(
-                        plan.admitted, plan.bucket, plan.use_chunked,
-                        register=False,
-                    )
+                admitted = self.claim_wave()
+                if admitted:
+                    records = eng._prefill_wave(admitted, register=False)
                     with self._cond:
                         for rec in records:
                             rec.t_enqueue = time.time()

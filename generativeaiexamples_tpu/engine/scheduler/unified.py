@@ -2,13 +2,12 @@
 
 ``unified`` reproduces the monolithic pre-scheduler dispatch loop
 exactly: the engine's dispatch thread forms one admission wave per
-loop pass (``claim_wave`` — the extracted ``_admit`` claim logic),
-prefills it inline, and registers the slots itself, so admission still
-alternates with decode blocks on one thread in the same order as
-before the extraction. Greedy and seeded-sampled streams are
-token-identical to the pre-scheduler engine across every layout
-(pinned by the slow identity suites — the same contract the paged and
-spec-decode migrations carried).
+loop pass (``claim_wave``), prefills it inline, and registers the
+slots itself, so admission still alternates with decode blocks on one
+thread in the same order as before the extraction. Greedy and
+seeded-sampled streams are token-identical to the pre-scheduler engine
+across every layout (pinned by the slow identity suites — the same
+contract the paged and spec-decode migrations carried).
 
 The ingest window is the decode-idle condition the PR 5 micro-batcher
 used to reach through ``LLMEngine.wait_decode_idle``: bulk side-model
@@ -41,13 +40,10 @@ class UnifiedPolicy(SchedulerPolicy):
         more block before their first token (docs/scheduler.md). A
         block writes only the scratch page for the wave's rows, which
         are not live yet, so their half-prefilled pages stay intact."""
-        plan = self.claim_wave()
-        if plan is not None:
+        admitted = self.claim_wave()
+        if admitted:
             eng = self.engine
-            eng._prefill_wave(
-                plan.admitted, plan.bucket, plan.use_chunked,
-                between_chunks=eng._decode_if_busy,
-            )
+            eng._prefill_wave(admitted, between_chunks=eng._decode_if_busy)
 
     def ingest_window(self, timeout: float) -> bool:
         """Block until no request occupies a decode slot, or ``timeout``

@@ -66,16 +66,6 @@ def resolve_draft_config(cfg):
     return llama.PRESETS[name]
 
 
-def attention_window(needed: int, max_seq_len: int) -> int:
-    """The engine's power-of-two window rule (>=128 rows), duplicated
-    here as a pure function so the runtime warms exactly the rungs its
-    dispatches pick."""
-    w = 128
-    while w < needed and w < max_seq_len:
-        w *= 2
-    return min(w, max_seq_len)
-
-
 class DraftRuntime:
     """Device half of the resident-draft proposer.
 
@@ -100,11 +90,7 @@ class DraftRuntime:
         compile_watch,
         dtype,
         sample_vocab: int,
-        num_slots: int,
-        max_seq_len: int,
-        row_rungs: Sequence[int],
-        chunk_windows: Sequence[int],
-        window_rungs: Sequence[int],
+        shapes,
     ) -> None:
         import jax
         import jax.numpy as jnp
@@ -115,8 +101,11 @@ class DraftRuntime:
         self._jnp = jnp
         self._llama = llama
         self._mesh = mesh
-        self.num_slots = num_slots
-        self.max_seq_len = max_seq_len
+        # the target's ladders (engine/scheduler/shapes.py ShapePlan):
+        # draft warm-up compiles exactly the shapes admission produces
+        self._shapes = shapes
+        self.num_slots = num_slots = shapes.num_slots
+        self.max_seq_len = max_seq_len = shapes.max_seq_len
         dcfg = self.draft_config = resolve_draft_config(cfg)
         if dcfg.max_seq_len < max_seq_len:
             raise ValueError(
@@ -139,10 +128,14 @@ class DraftRuntime:
         self._k = spec_decode_mod.effective_draft_len(cfg)
         self._c0 = self._k + 1  # catch-up width (DraftTracker invariant)
         self.tracker = spec_decode_mod.DraftTracker(self._k)
-        self._chunk = min(cfg.prefill_chunk, max_seq_len)
-        self._row_rungs = sorted(set(row_rungs))
-        self._chunk_windows = sorted(set(chunk_windows))
-        self._window_rungs = sorted(set(window_rungs))
+        C = self._chunk = min(cfg.prefill_chunk, max_seq_len)
+        cap = shapes.max_wave_rows()
+        self._row_rungs = sorted({min(s, cap) for s in shapes.wave_sizes()})
+        self._chunk_windows = sorted({
+            shapes.attention_window(min((k + 1) * C, max_seq_len))
+            for k in range((max_seq_len + C - 1) // C)
+        })
+        self._window_rungs = shapes.window_rungs()
         self._kv_quant = (
             getattr(cfg, "spec_draft_kv_dtype", "bfloat16") == "int8"
         )
@@ -206,7 +199,7 @@ class DraftRuntime:
             )
 
         wrap = compile_watch.wrap
-        self._prefill_fn = wrap(
+        self._write_prompts_fn = wrap(
             "draft_prefill",
             jax.jit(draft_prefill, donate_argnums=(1,), static_argnums=(6,)),
         )
@@ -232,12 +225,6 @@ class DraftRuntime:
     def reset(self) -> None:
         self.tracker.reset()
 
-    def _pad_rows(self, n: int) -> int:
-        for r in self._row_rungs:
-            if r >= n:
-                return r
-        return self._row_rungs[-1]
-
     # ------------------------------------------------------------------ #
     def prefill_wave(
         self,
@@ -261,7 +248,7 @@ class DraftRuntime:
         cap = self._row_rungs[-1]
         for g0 in range(0, len(rows), cap):
             grp = rows[g0:g0 + cap]
-            n = self._pad_rows(len(grp))
+            n = min(self._shapes.wave_pad(len(grp)), cap)
             tmax = int(max(lengths[i] for i in grp))
             # Pad up the rung by repeating row 0 WHOLE (tokens, length,
             # slot) — the engine's padding contract: duplicate rows
@@ -282,10 +269,10 @@ class DraftRuntime:
                 tok_k[:, : seg.shape[1]] = seg
                 valid = np.clip(lens - k * C, 0, C).astype(np.int32)
                 offsets = np.full((n,), k * C, np.int32)
-                W = attention_window(
-                    min((k + 1) * C, self.max_seq_len), self.max_seq_len
+                W = self._shapes.attention_window(
+                    min((k + 1) * C, self.max_seq_len)
                 )
-                self._caches = self._prefill_fn(
+                self._caches = self._write_prompts_fn(
                     self._params,
                     self._caches,
                     jnp.asarray(tok_k),
@@ -329,7 +316,7 @@ class DraftRuntime:
         needed = int(
             max(offsets[s] + valid[s] for s in spans) + self._k + 1
         )
-        W = attention_window(min(needed, self.max_seq_len), self.max_seq_len)
+        W = self._shapes.attention_window(min(needed, self.max_seq_len))
         t0 = time.time()
         out, self._caches = self._propose_fn(
             self._params,
@@ -371,7 +358,7 @@ class DraftRuntime:
             valid = jnp.zeros((n,), jnp.int32)
             slot_rows = jnp.zeros((n,), jnp.int32)
             for W in self._chunk_windows:
-                self._caches = self._prefill_fn(
+                self._caches = self._write_prompts_fn(
                     self._params, self._caches, tok, off, valid,
                     slot_rows, W,
                 )

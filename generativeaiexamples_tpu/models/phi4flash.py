@@ -363,7 +363,7 @@ def _pair_attention(qp, k, v, mask):
     caches hold them), mask [N, T, S] bool. The scores of every head
     exist at once ([N, Hq, T, S] float32: 0.38 GB for one row of 512
     queries over a 4096-token window); the engine sends a fixed-state
-    family one row a wave (``LLMEngine._max_wave_rows``)."""
+    family one row a wave (``ShapePlan.max_wave_rows``)."""
     N, T, Hq, Dp = qp.shape
     Hk = k.shape[1]
     q5 = qp.reshape(N, T, Hk, Hq // Hk, Dp)

@@ -18,7 +18,11 @@ engine's paged step programs (``engine/llm_engine.py``
   mesh, which decides how a quantised pool stores its scale planes,
   ``models/llama.py`` ``kv_scale_plane_shape``);
 - ``prefill_paged(params, cfg, caches, tokens, lengths, slots, tables,
-  page_size, **paths) -> (logits [N, V], caches)``;
+  page_size, **paths) -> (logits [N, V], caches)``: a REFERENCE walk, a
+  whole prompt in one program. No engine program calls it (every prompt
+  is served through ``extend_paged`` from offset zero); it is the walk a
+  chunk at offset zero is held to by the tests and by two adapters of
+  the benchmark (docs/model_registry.md);
 - ``extend_paged(params, cfg, caches, tokens, offsets, valid, slots,
   tables, window, page_size, **paths) -> (hidden [N, D], caches)``;
 - ``decode_paged(params, cfg, caches, tokens, positions, live, tables,
@@ -29,7 +33,7 @@ engine's paged step programs (``engine/llm_engine.py``
   the OPTIONAL chunk walk over a packed token axis (a wave's live tokens
   row after row, ``models/llama.py`` ``extend_layers_packed``). A family
   that registers one is sent its prefill waves packed, on one ladder of
-  token counts (``engine/llm_engine.py`` ``_packed_rungs``); a family
+  token counts (``engine/scheduler/shapes.py`` ``packed_rungs``); a family
   that registers none keeps ``[rows, width]`` dispatches of
   ``extend_paged`` (docs/model_registry.md);
 - ``verify_paged(...)`` or None (no speculative verify program);
@@ -291,7 +295,6 @@ def _glm5next_family() -> ModelFamily:
             len(cfg.layers_of("dsa")), 1, cfg.kv_lora_rank, cfg.num_heads,
             bytes_per_token=m.kv_bytes_per_token(cfg)),
         fixed_state_bytes_per_slot=m.fixed_state_bytes_per_slot,
-        span_fields=lambda cfg: {"kda_layers": len(cfg.layers_of("kda")), "index_topk": cfg.index_topk},
         resolve_kernels=lambda cfg, kind: {"grouped_matmul": kind, "delta_step": kind},
         stat_names=m.STAT_NAMES, read_stats=m.read_stats, extend_reads_window=False,
     )
@@ -366,8 +369,7 @@ def _solaropen2_family() -> ModelFamily:
         paged_kv_shape=lambda cfg: PagedKVShape(
             len(cfg.layers_of("full")), cfg.num_kv_heads, cfg.head_dim, cfg.num_heads),
         fixed_state_bytes_per_slot=m.fixed_state_bytes_per_slot,
-        span_fields=lambda cfg: {"kda_layers": len(cfg.layers_of("kda")),
-                                 "kv_readers": len(cfg.layers_of("full"))},
+        span_fields=lambda cfg: {"kv_readers": len(cfg.layers_of("full"))},
         resolve_kernels=lambda cfg, kind: {"grouped_matmul": kind, "delta_step": kind},
         stat_names=m.STAT_NAMES, read_stats=m.read_stats, extend_reads_window=False,
         # KDA's state and the convolution's tail: what a prefix entry carries

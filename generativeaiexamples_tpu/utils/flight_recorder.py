@@ -96,8 +96,8 @@ EVENT_CATALOG: Dict[str, str] = {
     "admit": "slot claimed (attrs carry the measured queue_wait_s)",
     "engine_overloaded": "submit rejected by the queue-depth cap",
     "prefix_match": "radix prefix-cache hit at admission",
-    "prefill_wave": "admission wave dispatched",
-    "prefill_chunk": "one fixed-shape chunked-prefill dispatch",
+    "prefill_chunk": "one fixed-shape prefill-chunk dispatch (every "
+    "admission's: a prompt of at most one chunk leaves one)",
     "decode_join": "request joined the decode batch",
     "decode_leave": "decode slot released",
     "first_token": "first generated token reached the reader",

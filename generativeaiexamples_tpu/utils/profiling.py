@@ -20,7 +20,7 @@ unavailable (no jax, or a backend without profiling support) the
 endpoints answer with a JSON error instead of crashing serving.
 
 :func:`annotation_scope` wraps ``jax.profiler.TraceAnnotation`` so the
-engine can label its prefill-wave and decode-block dispatches in the
+engine can label its prefill-chunk and decode-block dispatches in the
 captured trace; when profiling is disabled the factory returns a no-op
 context manager resolved once at engine init (zero per-dispatch cost).
 """

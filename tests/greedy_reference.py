@@ -33,7 +33,7 @@ def build_engine(kind: str, **cfg):
 
     with (rectangles() if kind == "rect" else contextlib.nullcontext()):
         eng = LLMEngine(EngineConfig(**cfg))
-    assert eng._packed == (kind == "packed")
+    assert eng.shapes.packed == (kind == "packed")
     return eng
 
 
@@ -102,3 +102,41 @@ def served_walk_logits(params, cfg, tokens, prompt_len, page_size=8, dtype=None)
         )
         steps.append(logits)
     return last, steps
+
+
+def reference_walk_greedy(eng, prompt, n, pad_to=64):
+    """The ``n`` greedy tokens of ``prompt`` by the family's REFERENCE
+    walks on the engine's own weights: one ``prefill_paged`` of the
+    whole prompt (the walk no engine program calls: models/registry.py;
+    padded to whole ``pad_to``s, the longest block any family's
+    recurrence walks), then one ``decode_paged`` step a token, on a
+    fresh pool of the engine's page size. No scheduler, no chunk, no
+    step program: what a served prompt of any length is held to, for
+    every family."""
+    import jax
+    import jax.numpy as jnp
+
+    fam, cfg, page = eng._family, eng.model_config, eng.engine_config.page_size
+    width = -(-len(prompt) // pad_to) * pad_to
+    per_row = -(-(width + n) // page) + 1
+    walks = eng.__dict__.setdefault("_reference_walks", {})  # jitted once an engine and shape
+    if per_row not in walks:
+        walks[per_row] = (
+            jax.jit(lambda params, caches, tokens, lengths, tables: fam.prefill_paged(
+                params, cfg, caches, tokens, lengths, jnp.zeros((1,), jnp.int32), tables, page,
+                use_flash=False)),
+            jax.jit(lambda params, caches, token, position, tables: fam.decode_paged(
+                params, cfg, caches, token, position, jnp.ones((1,), bool), tables,
+                per_row * page, page)),
+        )
+    prefill, decode = walks[per_row]
+    caches = fam.init_paged_cache(cfg, 1 + per_row, page, 1, jnp.float32)
+    tables = 1 + jnp.arange(per_row, dtype=jnp.int32).reshape(1, per_row)
+    tokens = jnp.asarray([list(prompt) + [0] * (width - len(prompt))], jnp.int32)
+    logits, caches = prefill(eng.params, caches, tokens, jnp.asarray([len(prompt)], jnp.int32), tables)
+    out = [int(np.argmax(np.asarray(logits[0, : eng._sample_vocab])))]
+    while len(out) < n:
+        position = jnp.full((1,), len(prompt) + len(out) - 1, jnp.int32)
+        logits, caches = decode(eng.params, caches, jnp.asarray(out[-1:], jnp.int32), position, tables)
+        out.append(int(np.argmax(np.asarray(logits[0, : eng._sample_vocab]))))
+    return out

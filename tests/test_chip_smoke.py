@@ -43,7 +43,7 @@ LOG_OK = textwrap.dedent(
     """\
     2026-09-26 INFO x: ragged page-attention kernel serving paged decode (compiled, page_size=128)
     2026-09-26 INFO x: resolved kernel paths: quant_kernel=True paged_kernel=compiled paged_verify_kernel=compiled tp_kernels=None kv_scales=lane_dense (backend=tpu, devices=1)
-    2026-09-26 INFO x: Engine warmup complete for prompt lengths [512] (engine build 61.5 s, warmup 244.0 s; device memory: dev0 in_use=9.90GB peak=11.20GB limit=16.91GB)
+    2026-09-26 INFO x: Engine warmup complete (engine build 61.5 s, warmup 244.0 s; device memory: dev0 in_use=9.90GB peak=11.20GB limit=16.91GB)
     """
 )
 

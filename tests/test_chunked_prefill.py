@@ -119,25 +119,24 @@ def test_warmup_covers_all_lengths(kind):
     adds an executable of any step program — the no-compile-inside-
     request property, read from the compile watch every program
     dispatches through — and warm-up compiled no extend signature the
-    shape rule cannot produce. Packed: one program a token rung and no
-    monolithic prefill program at all."""
+    shape rule cannot produce. Packed: one program a token rung.
+    Neither kind has a prefill program beside its extends."""
     cfg = dict(TINY, max_seq_len=96, prefill_chunk=32, page_size=8)
     eng = build(kind, **cfg)
     try:
-        eng.warmup(prompt_lengths=[8])
+        eng.warmup()
         before = eng._compile_watch.snapshot()
         assert before["compile_executables_extend"] > 0 and before["compile_executables_finish"] > 0
         # one executable a signature of the shape rule, by jit's own count
-        signatures = set(eng._extend_signatures())
+        signatures = set(eng.shapes.extend_signatures())
         assert before["compile_executables_extend"] == len(signatures)
         if kind == "packed":
-            assert sorted(w for _, w, _ in signatures) == eng._packed_rungs() == [8, 16, 24, 32, 48, 64, 96, 128]
+            assert sorted(w for _, w, _ in signatures) == eng.shapes.packed_rungs() == [8, 16, 24, 32, 48, 64, 96, 128]
             assert {n for n, _, _ in signatures} == {4}  # the carry's rows: the wave cap
-            assert eng._prefill_fn is None and before.get("compile_executables_prefill", 0) == 0
             assert before["compile_executables_finish"] == 1
         else:
             assert {w for _, w, _ in signatures} == {8, 32}
-            assert before["compile_executables_prefill"] > 0
+        assert not hasattr(eng, "_prefill_fn") and "compile_executables_prefill" not in before
         # what jit itself holds: it keys an executable on more than
         # shapes (a carry that is not committed to the device selects
         # another one than a carry that is), so serving must not add to
@@ -249,7 +248,7 @@ def ladder_engine(request):
     # (no prefix reuse: the waves share prompts, and the token counts
     # below are those of cold rows)
     eng = build(request.param, prefix_cache_enable="off", **LADDER)
-    assert eng._chunk_widths() == [16, 64] and eng._packed_rungs() == RUNGS
+    assert eng.shapes.chunk_widths() == [16, 64] and eng.shapes.packed_rungs() == RUNGS
     yield request.param, eng
     eng.shutdown()
 
@@ -308,7 +307,7 @@ def int8_streams():
         name: build(kind, kv_cache_dtype="int8", **dict(LADDER, page_size=page))
         for name, kind, page in (("ladder", "packed", 16), ("fixed", "packed", 64), ("rect", "rect", 16))
     }
-    assert engines["ladder"]._packed_rungs() == RUNGS and engines["fixed"]._packed_rungs() == [64, 128, 192, 256]
+    assert engines["ladder"].shapes.packed_rungs() == RUNGS and engines["fixed"].shapes.packed_rungs() == [64, 128, 192, 256]
     yield engines
     for eng in engines.values():
         eng.shutdown()
@@ -336,7 +335,7 @@ def kernel_engine(request):
     try:
         assert eng._paged_extend_kernel == "interpret"
         # one program a rung under the chunk, whatever the chunk: no window rung
-        assert [s for s in eng._extend_signatures() if s[1] < 64] == (
+        assert [s for s in eng.shapes.extend_signatures() if s[1] < 64] == (
             [(4, 16, 256), (4, 32, 256), (4, 48, 256)] if request.param == "packed"
             else [(1, 16, 256), (4, 16, 256)]
         )
@@ -377,7 +376,7 @@ def bench_engine():
 
     registry.register_preset("llama", "debug-2k", dataclasses.replace(llama.PRESETS["debug-1k"], max_seq_len=2048))
     eng = build("packed", **BENCH)
-    assert eng._packed_rungs() == [128, 256, 384, 512, 768, 1024, 1536, 2048]
+    assert eng.shapes.packed_rungs() == [128, 256, 384, 512, 768, 1024, 1536, 2048]
     yield eng
     eng.shutdown()
 
@@ -390,7 +389,7 @@ def test_packed_waves_match_the_cache_free_forward(bench_engine, first, rows):
     for p, toks in zip(prompts, _serve_wave(bench_engine, prompts)):
         assert _agrees(bench_engine, toks, _ref(tuple(p), "debug-2k")), (rows, first, len(p), toks)
     # every chunk at the least rung that holds its live tokens
-    ladder = bench_engine._packed_rungs()
+    ladder = bench_engine.shapes.packed_rungs()
     chunks = [sum(min(max(len(p) - k * 512, 0), 512) for p in prompts) for k in range(3)]
     assert bench_engine.metrics["extend_tokens_computed"] - computed0 == sum(
         next(t for t in ladder if t >= n) for n in chunks if n

@@ -183,7 +183,7 @@ def eng():
     from generativeaiexamples_tpu.engine.llm_engine import LLMEngine
 
     engine = LLMEngine(EngineConfig(**TINY))
-    engine.warmup(prompt_lengths=[16])
+    engine.warmup()
     yield engine
     engine.shutdown()
 

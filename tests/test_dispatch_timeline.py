@@ -747,7 +747,7 @@ def test_validate_config_rejects_bad_knobs():
 
 # --------------------------------------------------------------------------- #
 # The prefill_chunk span says what its dispatch computed (chunked
-# prefill's shape rule: engine/llm_engine.py _chunk_rung)
+# prefill's shape rule: engine/scheduler/shapes.py chunk_rung)
 
 def _chunk_engine(kind):
     from greedy_reference import build_engine
@@ -833,9 +833,9 @@ def test_prefill_chunk_span_carries_rows_width_and_padding(request, kind, name):
 
 @pytest.mark.parametrize("kind", ["packed", "rect"])
 def test_a_short_prompt_counts_what_its_dispatch_computed(request, kind):
-    """A prompt under a chunk: packed, its tokens' rung on the one
-    ladder (a ``prefill_chunk`` span); as a rectangle, the monolithic
-    prefill's padded bucket (a ``prefill`` span)."""
+    """A prompt under a chunk is one ``prefill_chunk`` span either way:
+    packed, its tokens' rung on the one ladder; as a rectangle, one row
+    at the width rung that holds it."""
     from generativeaiexamples_tpu.engine.llm_engine import SamplingParams
 
     engine = request.getfixturevalue("chunk_engine" if kind == "packed" else "rect_engine")
@@ -843,11 +843,11 @@ def test_a_short_prompt_counts_what_its_dispatch_computed(request, kind):
     list(engine.iter_ids([5] * 20, SamplingParams(temperature=0.0, max_tokens=2), timeout=300))
     after = _engine_counters()
     assert after["genai_engine_prefill_tokens_total"] - before["genai_engine_prefill_tokens_total"] == 20
-    # packed: 20 live of the 32-token rung; a rectangle: one row of one 64-token bucket
+    # packed: 20 live of the 32-token rung; a rectangle: one row at the 64-token width
     assert (after["genai_engine_extend_tokens_computed_total"]
             - before["genai_engine_extend_tokens_computed_total"]) == (32 if kind == "packed" else 64)
     kinds = [s["kind"] for s in dtl.spans_since(cursor)[0] if s["kind"] in ("prefill", "prefill_chunk")]
-    assert kinds == (["prefill_chunk"] if kind == "packed" else ["prefill"])
+    assert kinds == ["prefill_chunk"]
 
 
 def test_engine_spans_are_stamped_by_the_watcher(chunk_engine):

@@ -243,8 +243,7 @@ def test_prefill_wave_token_budget_bounds_dispatches():
         )
     )
     try:
-        assert eng._max_wave_rows(48) == 1
-        assert eng._max_wave_rows(16) == 4
+        assert eng.shapes.max_wave_rows() == 4
         params = SamplingParams(temperature=0.0, max_tokens=4)
         waves0 = eng.metrics.get("admission_waves", 0)
         with eng.hold_admissions():

@@ -318,7 +318,7 @@ def test_delayed_request_yields_complete_slow_capture(eng, tmp_path):
         ["admit", "decode_join", "first_token", "finish"],
     ):
         assert names.index(earlier) < names.index(later), names
-    assert "prefill_wave" in names or "prefill_chunk" in names, names
+    assert "prefill_chunk" in names and "prefill_wave" not in names, names
     assert timeline["ttft_s"] >= 0.02
     # the JSONL export carries the same chain
     exported = json.loads(path.read_text().splitlines()[0])

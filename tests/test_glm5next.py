@@ -403,13 +403,13 @@ def engine():
     from generativeaiexamples_tpu.engine.llm_engine import LLMEngine
 
     eng = LLMEngine(EngineConfig(**BASE))
-    eng.warmup([64])
+    eng.warmup()
     yield eng
     eng.shutdown()
 
 
 def test_engine_serves_every_prompt_shape_as_the_models_own_argmax(engine):
-    """Monolithic prefill (5, 64), chunked extend (100: a wide and a
+    """One chunk (5, 64), several (100: a wide and a
     narrow chunk; 150), more requests than slots one after another: every
     served token is the whole-sequence forward's argmax, through the
     interpreted kernels. Nothing compiles after warm-up."""

@@ -345,7 +345,7 @@ def _writer_run(writer, caches, params, cfg):
     tables = jnp.asarray(1 + np.arange(WRITER_SLOTS * pmax).reshape(WRITER_SLOTS, pmax), jnp.int32)
     tok = lambda *shape: jnp.asarray(rng.integers(1, cfg.vocab_size, shape), jnp.int32)
     i32 = lambda x: jnp.asarray(x, jnp.int32)
-    if writer == "prefill_wave":  # rows of 40 tokens: a page boundary inside every row
+    if writer == "reference_prefill":  # (the reference walk's writer, no engine program's) rows of 40 tokens: a page boundary inside every row
         kvs = [tuple(jnp.asarray(rng.standard_normal((2, 40, cfg.num_kv_heads, cfg.head_dim)), jnp.float32)
                      for _ in "kv") for _ in caches]
         return None, llama.write_prefill_pages(caches, kvs, tables[i32([2, 0])], page)
@@ -364,7 +364,7 @@ def _writer_run(writer, caches, params, cfg):
 
 
 @pytest.mark.parametrize("kv", ["int8", "int4"])
-@pytest.mark.parametrize("writer", ["prefill_wave", "rectangular_chunk", "packed_wave", "decode_step"])
+@pytest.mark.parametrize("writer", ["reference_prefill", "rectangular_chunk", "packed_wave", "decode_step"])
 def test_every_writer_of_scales_and_the_gather_reader_agree_across_layouts(writer, kv):
     """Each walk that writes a quantised pool, over a LANE-DENSE pool and
     over a token-major one (what a head-sharded pool keeps): the gather
@@ -391,7 +391,7 @@ def test_every_writer_of_scales_and_the_gather_reader_agree_across_layouts(write
     if want is not None:
         np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
     every_page = jnp.arange(pool, dtype=jnp.int32)[None]
-    live_tokens = {"prefill_wave": 2 * 40, "rectangular_chunk": 8 + 4, "packed_wave": 19 + 6, "decode_step": 2}[writer]
+    live_tokens = {"reference_prefill": 2 * 40, "rectangular_chunk": 8 + 4, "packed_wave": 19 + 6, "decode_step": 2}[writer]
     for a, b in zip(dense, token_major):
         for key in ("ks", "vs"):
             rows = np.asarray(llama.gather_kv_scales(a[key], every_page, pool, WRITER_PAGE))[0]

@@ -65,13 +65,13 @@ def engine():
     from generativeaiexamples_tpu.engine.llm_engine import LLMEngine
 
     eng = LLMEngine(EngineConfig(**TINY))
-    eng.warmup([16])
+    eng.warmup()
     yield eng
     eng.shutdown()
 
 
 PROMPTS = {
-    "one_chunk": [5 + i for i in range(9)],  # monolithic prefill
+    "one_chunk": [5 + i for i in range(9)],  # chunk 0 alone
     "three_chunks": [3 + (i * 7) % 200 for i in range(40)],  # chunked extend
     "chunk_boundary": [11 + (i * 5) % 300 for i in range(32)],
 }
@@ -109,15 +109,15 @@ def test_an_engine_always_has_a_page_allocator_and_one_program_a_step_kind(engin
         assert not hasattr(engine, gone), gone
     snap = engine._compile_watch.snapshot()
     families = {k[len("compile_executables_"):] for k in snap if k.startswith("compile_executables_")}
-    # (the dense family's waves go out packed: ONE prefill program kind, extend; no monolithic prefill and
+    # (the dense family's waves go out packed: ONE prefill program kind, extend, as every family's; and
     # no put_rows, the tiny program that hands a rectangle of fewer rows' hidden states back to its wave)
     # (update_slots: a module-level function, so jit's caches of it are one a process and an engine built
     # after another finds it warm: jit reports no event and the watch, which reads jit's events, no family)
     assert families | {"update_slots"} == {"decode", "extend", "finish", "update_slots", "page_tables"}
-    assert engine._packed and engine._prefill_fn is None
+    assert engine.shapes.packed and not hasattr(engine, "_prefill_fn")  # (every family: test_one_admission_path.py)
     # the gather serves the CPU: one decode program a window rung, and
     # nothing compiled after warm-up
-    assert snap["compile_executables_decode"] == len(engine._window_rungs())
+    assert snap["compile_executables_decode"] == len(engine.shapes.window_rungs())
     assert snap["compile_hot_path_total"] == 0
 
 

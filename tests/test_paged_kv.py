@@ -33,7 +33,7 @@ PREAMBLE = [(i * 7) % 90 + 2 for i in range(33)]  # 33 tokens: 32 cacheable
 PROMPTS = [
     PREAMBLE + [99],            # prefix-cache candidate
     list(range(5, 25)),         # one-chunk-plus prompt
-    [42, 43, 44],               # short (monolithic wave)
+    [42, 43, 44],               # short (one chunk)
 ]
 
 
@@ -278,7 +278,7 @@ def test_paged_warmup_compiles():
     through every program) without touching live state."""
     paged = build()
     try:
-        paged.warmup(prompt_lengths=[8, 20])
+        paged.warmup()
         params = SamplingParams(temperature=0.0, max_tokens=6, seed=1)
         out = list(paged.iter_ids(list(range(9, 30)), params, timeout=300))
         assert len(out) > 0
@@ -403,7 +403,7 @@ def test_adaptive_k_warm_ladder_no_hot_compiles():
         spec_adaptive_k="on", spec_adaptive_k_min=1,
     )
     try:
-        eng.warmup(prompt_lengths=[16])
+        eng.warmup()
         snap = eng.utilization_snapshot()
         assert snap["compile_warmup_done"] == 1.0
         executables = snap["compile_executables"]

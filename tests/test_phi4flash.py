@@ -260,7 +260,7 @@ def engine():
     from generativeaiexamples_tpu.engine.llm_engine import LLMEngine
 
     eng = LLMEngine(EngineConfig(**BASE))
-    eng.warmup([16])
+    eng.warmup()
     yield eng
     eng.shutdown()
 
@@ -271,7 +271,7 @@ def reference_margins(eng, prompt, out):
 
 
 def test_engine_serves_every_prompt_shape_as_the_models_own_argmax(engine):
-    """Monolithic prefill (5, 16), chunked extend (37: three chunks, 50:
+    """One chunk (5, 16), several (37: three chunks, 50:
     four), decode blocks past several window wraps, more requests than
     slots one after another (slot and page reuse): every served token is
     the whole-sequence forward's argmax. Nothing compiles after warm-up."""
@@ -298,7 +298,7 @@ def ladder_engine():
     from generativeaiexamples_tpu.engine.llm_engine import LLMEngine
 
     eng = LLMEngine(EngineConfig(**dict(BASE, prefill_chunk=32)))
-    eng.warmup([32])
+    eng.warmup()
     yield eng
     eng.shutdown()
 
@@ -314,8 +314,8 @@ def test_engine_serves_a_narrow_last_chunk_as_the_models_own_argmax(ladder_engin
     from generativeaiexamples_tpu.engine.llm_engine import SamplingParams
 
     eng = ladder_engine
-    assert eng._chunk_widths() == [8, 32]
-    assert [s for s in eng._extend_signatures() if s[1] == 8] == [(1, 8, eng.max_seq_len)]
+    assert eng.shapes.chunk_widths() == [8, 32]
+    assert [s for s in eng.shapes.extend_signatures() if s[1] == 8] == [(1, 8, eng.max_seq_len)]
     prompt = [int(t) for t in np.random.default_rng(n).integers(3, 500, size=n)]
     cursor = dispatch_timeline.spans_since(0)[1]
     out = list(eng.iter_ids(prompt, SamplingParams(temperature=0.0, max_tokens=10), timeout=300))
@@ -350,8 +350,8 @@ def test_rows_served_together_equal_their_solo_runs(engine):
     for t in threads:
         t.join()
     assert got == solo
-    waves = [s for s in dispatch_timeline.spans_since(since)[0] if s.get("kind") in ("prefill", "prefill_chunk")]
-    assert waves and all(s["rows"] <= 1 for s in waves)  # one row a wave: _max_wave_rows
+    waves = [s for s in dispatch_timeline.spans_since(since)[0] if s.get("kind") == "prefill_chunk"]
+    assert waves and all(s["rows"] <= 1 for s in waves)  # one row a wave: shapes.max_wave_rows
 
 
 def test_engine_counts_resets_skipped_tokens_and_state_dispatches(engine):
@@ -437,9 +437,9 @@ def test_an_answer_that_ends_on_a_stop_id_reports_what_it_delivered(engine, monk
 def test_a_fixed_state_family_is_sent_one_row_a_prefill_wave(engine):
     """Waves of several rows of the chunk walk hung the chip now and
     then (PERF.md, PR 29; cause not found): whatever
-    ``prefill_wave_tokens`` says, no program of more than one prefill
-    or extend row is built or warmed for a family with fixed state."""
+    ``prefill_wave_tokens`` says, no program of more than one extend
+    row is built or warmed for a family with fixed state."""
     assert engine.engine_config.prefill_wave_tokens >= 4 * engine.engine_config.prefill_chunk
     assert engine.num_slots > 1
-    for bucket in (1, engine.engine_config.prefill_chunk, engine.max_seq_len):
-        assert engine._max_wave_rows(bucket) == 1
+    assert engine.shapes.max_wave_rows() == 1
+    assert {n for n, _, _ in engine.shapes.extend_signatures()} == {1}

@@ -150,7 +150,7 @@ def test_annotation_scope_noop_when_profiler_unavailable(monkeypatch):
     monkeypatch.setenv("ENABLE_PROFILING", "true")
     monkeypatch.setattr(profiling, "_profiler", lambda: None)
     scope = profiling.annotation_scope()
-    with scope("engine.prefill_wave"):
+    with scope("engine.prefill_chunk"):
         pass
 
 

@@ -205,10 +205,11 @@ def test_engine_warmup_disabled_without_config(clean_app_env):
         runtime.reset_runtime()
 
 
-def test_engine_warmup_precompiles_buckets(clean_app_env):
-    """Configured warmup builds the engine singleton and drives admission
-    waves for the configured prompt-length buckets (the mid-serving
-    cold-compile stall this feature removes, BASELINE.md round 2)."""
+def test_engine_warmup_precompiles_every_shape(clean_app_env):
+    """Configured warmup builds the engine singleton and compiles its
+    whole executable set, whatever lengths the switch names (the
+    mid-serving cold-compile stall this feature removes, BASELINE.md
+    round 2): no admission wave is driven for it."""
     from generativeaiexamples_tpu.chains import runtime
     from generativeaiexamples_tpu.engine import llm_engine
     from generativeaiexamples_tpu.server.api import start_engine_warmup
@@ -224,6 +225,7 @@ def test_engine_warmup_precompiles_buckets(clean_app_env):
     runtime.reset_runtime()
     saved = llm_engine._ENGINE
     llm_engine._ENGINE = None
+    waves_before = llm_engine._M_WAVES.value
     try:
         thread = start_engine_warmup()
         assert thread is not None
@@ -231,7 +233,10 @@ def test_engine_warmup_precompiles_buckets(clean_app_env):
         assert not thread.is_alive()
         eng = llm_engine._ENGINE
         assert eng is not None
-        assert eng.metrics.get("admission_waves", 0) >= 2  # one per bucket min
+        snap = eng._compile_watch.snapshot()
+        assert snap["compile_warmup_done"] == 1
+        assert snap["compile_executables_extend"] == len(eng.shapes.extend_signatures())
+        assert llm_engine._M_WAVES.value == waves_before  # (a counter of the process, not of the engine)
     finally:
         if llm_engine._ENGINE is not None:
             llm_engine._ENGINE.shutdown()

@@ -311,7 +311,7 @@ def test_registry_resolves_the_family_and_what_it_declares():
     shape = fam.paged_kv_shape(FULL)
     assert (shape.num_layers, shape.num_kv_heads, shape.head_dim, shape.num_heads, shape.bytes_per_token) == (1, 8, 128, 64, None)
     assert fam.fixed_state_bytes_per_slot(FULL) == 13_025_280
-    assert fam.span_fields(FULL) == {"kda_layers": 3, "kv_readers": 1}
+    assert fam.span_fields(FULL) == {"kv_readers": 1}  # each one a span carries (engine _state_counters)
     # the ONE field that lets the prefix store carry this family's state; the four older fixed-state families name none
     assert fam.state_row_keys == ("kda", "conv") == m.STATE_ROW_KEYS
     assert all(not f.state_row_keys for n, f in registry.families().items() if n != "solaropen2")
@@ -345,14 +345,13 @@ def engine():
     from generativeaiexamples_tpu.engine.llm_engine import LLMEngine
 
     eng = LLMEngine(EngineConfig(**BASE))
-    eng.warmup([64])
-    eng.warmup_chunked_shapes()
+    eng.warmup()
     yield eng
     eng.shutdown()
 
 
 def test_engine_serves_every_prompt_shape_as_the_references_argmax(engine):
-    """Monolithic prefill (5, 64), chunked extend (100, 150), more
+    """One chunk (5, 64), several (100, 150), more
     requests than slots one after another: every served token is near
     the plain reference's best, through the interpreted kernels. Nothing
     compiles after warm-up."""

@@ -698,8 +698,7 @@ def test_a_decode_step_runs_between_the_chunks_of_a_wave_while_rows_decode(paged
              # by enqueue time: a pipelined verify's span is written a round late
              for s in sorted(spans, key=lambda s: s["t_wall"])
              # (the long prompt's chunks: the first request's own prefill is a
-             # one-chunk packed dispatch too, since the dense family has no
-             # monolithic prefill program)
+             # one-chunk dispatch too, as every prompt's)
              if (s["kind"] == "prefill_chunk" and b.rid in s["rids"])
              or s["kind"].startswith(("decode", "spec"))]
     k = order.index("chunk")

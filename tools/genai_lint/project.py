@@ -32,7 +32,7 @@ riding on this inherit them):
   to the enclosing function (the same assumption the intra-file rules
   make).
 - **Blind spots, by design**: calls through function-valued attributes
-  (``self._prefill_fn(...)`` dispatches a compiled program — recorded
+  (``self._extend_fn(...)`` dispatches a compiled program — recorded
   as an *attribute-call event* for warmup-coverage, never an edge);
   inheritance (the tree's classes are flat); re-exported names;
   containers of callables.

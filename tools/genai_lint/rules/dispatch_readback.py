@@ -60,7 +60,7 @@ readbacks (this is the old "host-only modules" blind spot, kept as an
 explicit boundary instead of an accident of scope).
 
 Blind spots, by design: calls through dynamic attributes
-(``self._prefill_fn(...)``) dispatch compiled programs and are async —
+(``self._extend_fn(...)``) dispatch compiled programs and are async —
 they are not edges; nested defs and lambdas are assumed to run
 off-thread (reader closures, ``Thread(target=...)`` workers), so
 neither their syncs nor their calls are attributed to the enclosing

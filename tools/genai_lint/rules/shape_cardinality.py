@@ -4,9 +4,10 @@ request-varying sizes through a ladder helper.
 Every distinct operand shape handed to a jitted program is its own XLA
 executable (tens of seconds of compile on the layered path). The stack
 therefore quantizes every request-varying dimension through a finite
-ladder — power-of-two row rungs (``batcher.row_bucket``), chunk-aligned
-prefill buckets (``_prefill_bucket``), wave padding (``_wave_pad``),
-power-of-two attention windows (``_attention_window``) — so the warm
+ladder — power-of-two row rungs (``batcher.row_bucket``) and the
+engine's ``ShapePlan`` (engine/scheduler/shapes.py: chunk-aligned
+``prefill_bucket``, ``wave_pad``, ``chunk_rung``, power-of-two
+``attention_window``) — so the warm
 executable set is bounded. The pre-PR-5 embedder broke this by passing
 raw ``len(texts)`` row counts to its jitted encoder: one executable per
 distinct document-batch size, unbounded. This rule prevents the next
@@ -41,7 +42,7 @@ from typing import List, Optional, Set
 
 from tools.genai_lint.core import Finding, SourceRule
 
-# Tokens match whole snake_case words only: `row_bucket`/`_wave_pad`
+# Tokens match whole snake_case words only: `row_bucket`/`wave_pad`
 # launder, but an unlucky substring (`round` inside `background`,
 # `workaround`) must not.
 LAUNDER_RE = re.compile(
@@ -52,7 +53,7 @@ LAUNDER_RE = re.compile(
 
 
 def _call_name(func: ast.AST) -> Optional[str]:
-    """Trailing name of a callee ('self._wave_pad' -> '_wave_pad')."""
+    """Trailing name of a callee ('self.shapes.wave_pad' -> 'wave_pad')."""
     if isinstance(func, ast.Name):
         return func.id
     if isinstance(func, ast.Attribute):

@@ -19,24 +19,24 @@ project.py):
   compile watch (a ``compile_watch``-named segment:
   ``self._compile_watch.wrap``, a ``compile_watch`` parameter/module
   alias) — including through a local alias
-  (``wrap = self._compile_watch.wrap; wrap("prefill", ...)``), the
+  (``wrap = self._compile_watch.wrap; wrap("extend", ...)``), the
   engine's idiom. An unrelated ``textwrap.wrap("...")`` is not a
   registration. The storage target is the enclosing assignment
-  (``self._prefill_fn = wrap(...)`` registers attribute
-  ``_prefill_fn`` on the enclosing class).
-- the **walkers** are every function named ``warmup``,
-  ``warmup_chunked_shapes``, or ``warmup_spec_shapes``, anywhere in
+  (``self._extend_fn = wrap(...)`` registers attribute
+  ``_extend_fn`` on the enclosing class).
+- the **walkers** are every function named ``warmup`` or
+  ``warmup_spec_shapes``, anywhere in
   the tree (``DraftRuntime.warmup`` counts exactly like
   ``LLMEngine.warmup``).
 - coverage is judged **per registration site**: a site is covered
   when some function reachable from a walker calls its storage
   attribute on the SAME class (``self._tables_fn(...)`` inside
-  ``warmup_chunked_shapes``), or — for a registration stored in a
+  ``warmup``), or — for a registration stored in a
   local — calls that local inside a reachable function. Neither an
   identically-named attribute of a different class nor a same-named
-  program registered elsewhere counts: ``DraftRuntime._prefill_fn``
-  warming itself says nothing about ``LLMEngine._prefill_fn``, and a
-  covered ``wrap("prefill", ...)`` on one class never excuses an
+  program registered elsewhere counts: ``DraftRuntime._propose_fn``
+  warming itself says nothing about an ``LLMEngine._propose_fn``, and a
+  covered ``wrap("extend", ...)`` on one class never excuses an
   uncovered one on another.
 - reachability follows the project core's edges and off-thread
   discipline; in particular the dispatch loop is NOT reachable from
@@ -63,9 +63,7 @@ from tools.genai_lint.project import (
     walk_same_thread,
 )
 
-WARMUP_WALKERS = frozenset(
-    {"warmup", "warmup_chunked_shapes", "warmup_spec_shapes"}
-)
+WARMUP_WALKERS = frozenset({"warmup", "warmup_spec_shapes"})
 
 
 def _attr_target(node: ast.Assign) -> Optional[Tuple[str, str]]:
@@ -130,9 +128,8 @@ class WarmupCoverageRule(RepoRule):
     name = "warmup-coverage"
     description = (
         "every program registered via compile_watch.wrap() is statically "
-        "reachable from a warmup walker (warmup / warmup_chunked_shapes / "
-        "warmup_spec_shapes) — the static half of the "
-        "zero-hot-path-compile contract"
+        "reachable from a warmup walker (warmup / warmup_spec_shapes) — "
+        "the static half of the zero-hot-path-compile contract"
     )
 
     def check_repo(self, root: pathlib.Path) -> List[Finding]:

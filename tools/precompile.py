@@ -4,7 +4,7 @@ The reference's NIM containers ship a model cache volume so engines
 start serving without a build step (reference:
 deploy/compose/docker-compose-nim-ms.yaml:5-6 NIM_CACHE). The TPU
 analogue is the persistent XLA compile cache: every serving executable
-(prefill waves, chunked-prefill extends, decode windows, finish/sample)
+(prefill-chunk extends, decode windows, finish/sample)
 is a pure function of SHAPES, so this tool boots the engine with
 random-init weights, runs the full warmup walk, and leaves the compiled
 artifacts in ``JAX_COMPILATION_CACHE_DIR`` — after which a real
@@ -39,12 +39,6 @@ def main(argv=None) -> int:
     ap.add_argument("--prefill-chunk", type=int, default=512)
     ap.add_argument("--decode-block", type=int, default=8)
     ap.add_argument("--tensor-parallelism", type=int, default=-1)
-    ap.add_argument(
-        "--warmup-prompt-lengths",
-        default="",
-        help="comma-separated sub-chunk buckets to warm monolithically "
-        "(longer prompts ride the bounded chunked set)",
-    )
     args = ap.parse_args(argv)
 
     # The one compile-cache rule (utils/jax_env.py): the environment's
@@ -70,12 +64,9 @@ def main(argv=None) -> int:
         )
     )
     t_boot = time.time() - t0
-    lengths = [
-        int(t) for t in args.warmup_prompt_lengths.split(",") if t.strip()
-    ] or [min(128, args.prefill_chunk)]
     try:
         t1 = time.time()
-        engine.warmup(prompt_lengths=lengths)
+        engine.warmup()
         t_warm = time.time() - t1
     finally:
         engine.shutdown()

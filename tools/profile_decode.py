@@ -80,7 +80,7 @@ def main() -> None:
             prompt, SamplingParams(temperature=0.0, max_tokens=8), timeout=900
         )
     )
-    engine.warmup(prompt_lengths=[len(prompt) + 1])
+    engine.warmup()
     # Full remaining cache budget per request, and a second wave queued
     # behind the first, so decode slots stay saturated through the whole
     # traced window (a too-small budget drains before the trace starts —
@@ -120,7 +120,7 @@ def main() -> None:
     print(f"trace: {logdir}")
     print(
         f"traced {wall_ms:.1f} ms of device activity, ~{steps} decode steps "
-        f"(block={engine._decode_block})"
+        f"(block={engine.shapes.decode_block})"
     )
     print("\n== executables (device time) ==")
     for name, us in sorted(report["executables"].items(), key=lambda x: -x[1]):
