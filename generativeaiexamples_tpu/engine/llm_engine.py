@@ -572,10 +572,28 @@ class LLMEngine:
         self._kv_shape = family.paged_kv_shape(model_cfg)
         self._fixed_state = bool(family.fixed_state)
         self._span_fields = dict(family.span_fields(model_cfg))
-        self._stat_names = tuple(family.stat_names)
+        # why a request snapshot cannot carry a request of this family
+        # (None: it can), said where one is taken (request_snapshot.py)
+        self._snapshot_refusal = None
         if self._fixed_state:
-            cfg = self._validate_fixed_state(cfg, mesh)
-            self.engine_config = cfg
+            self._snapshot_refusal = (
+                "keeps a fixed per-slot state beside the page pool, which a "
+                "request snapshot cannot carry"
+            )
+        elif not family.snapshot_pages:
+            self._snapshot_refusal = (
+                f"is of the {family.name} family, whose page pools are not "
+                "the per-layer K and V pages a request snapshot's payload "
+                "carries (snapshot_pages, models/registry.py)"
+            )
+        self._stat_names = tuple(family.stat_names)
+        if cfg.kv_cache_dtype not in ("bfloat16", "int8", "int4"):
+            raise ValueError(
+                f"kv_cache_dtype must be 'bfloat16', 'int8', or 'int4', "
+                f"got {cfg.kv_cache_dtype!r}"
+            )
+        cfg = self._validate_family(cfg, mesh)
+        self.engine_config = cfg
         self.tokenizer = tokenizer or load_tokenizer(cfg.tokenizer_path or cfg.checkpoint_path)
         # Sample only ids the tokenizer can represent: with the byte-level
         # fallback tokenizer (~260 ids) under a 128k-vocab head (random-init
@@ -588,11 +606,6 @@ class LLMEngine:
         dtype = {"bfloat16": jnp.bfloat16, "float32": jnp.float32, "float16": jnp.float16}[
             cfg.dtype
         ]
-        if cfg.kv_cache_dtype not in ("bfloat16", "int8", "int4"):
-            raise ValueError(
-                f"kv_cache_dtype must be 'bfloat16', 'int8', or 'int4', "
-                f"got {cfg.kv_cache_dtype!r}"
-            )
         if cfg.prefix_cache_enable not in ("auto", "off"):
             raise ValueError(
                 f"prefix_cache_enable must be auto|off, got "
@@ -951,41 +964,54 @@ class LLMEngine:
         token_major = (self.engine_config.page_size, self._kv_shape.num_kv_heads)
         return "token_major" if self._kv_scale_plane == token_major else "lane_dense"
 
-    def _validate_fixed_state(self, cfg: EngineConfig, mesh) -> EngineConfig:
-        """Refuse, at engine build, everything that assumes "a slot's
-        state is its pages" for a family that declares fixed per-slot
-        state (a recurrent state, a window ring: models/registry.py,
-        docs/model_registry.md). One clear error each; no silent
-        fallback to a path that would serve such a model wrongly.
-        Request snapshots are refused where they are taken (``drain``,
-        ``restore_snapshot``): the spool directory always has a default,
-        so its being set says nothing. Returns the config with
-        ``tensor_parallelism=-1`` resolved to 1 (one device serves it).
-        """
+    def _validate_family(self, cfg: EngineConfig, mesh) -> EngineConfig:
+        """Refuse, at engine build, what the model family does not
+        DECLARE it can be served with (models/registry.py,
+        docs/model_registry.md): a sharded mesh (``sharded``), a weight
+        or pool format its walks do not read (``weight_formats``,
+        ``kv_formats``), speculation without a verify walk
+        (``verify_paged``) and, for a family with fixed per-slot state,
+        a prefix store it names no state rows for (``state_row_keys``).
+        One clear error each; no silent fallback to a path that would
+        serve such a model wrongly. A family that has pages only keeps
+        the prefix store. Request snapshots are refused where they are
+        taken (``drain``, ``restore_snapshot``): the spool directory
+        always has a default, so its being set says nothing. Returns
+        the config with ``tensor_parallelism=-1`` resolved to 1 for a
+        family that cannot be sharded (one device serves it)."""
         import dataclasses as _dc
 
-        name = f"{self._family.name} model {cfg.model_config_name!r}"
+        fam = self._family
+        name = f"{fam.name} model {cfg.model_config_name!r}"
 
-        def refuse(what: str, knob: str) -> None:
+        def refuse(what: str, lacks: str, knob: str) -> None:
+            if fam.fixed_state:
+                raise ValueError(
+                    f"{name} keeps a fixed per-slot state beside the page "
+                    f"pool, which {what} cannot carry; {knob}"
+                )
             raise ValueError(
-                f"{name} keeps a fixed per-slot state beside the page "
-                f"pool, which {what} cannot carry; {knob}"
+                f"{name} cannot be served with {what}: its family "
+                f"{lacks} (models/registry.py); {knob}"
             )
 
         mesh_size = mesh.size if mesh is not None else 1
-        if cfg.tensor_parallelism > 1 or mesh_size > 1:
-            refuse("a sharded mesh", "set tensor_parallelism=1")
-        prefix_cache_mod.require_paged_state(
-            name, cfg, self._family.state_row_keys
-        )
-        spec_decode_mod.require_paged_state(name, cfg)
-        if cfg.quantization not in ("", "none"):
+        if not fam.sharded and (cfg.tensor_parallelism > 1 or mesh_size > 1):
+            refuse("a sharded mesh", "declares no sharded walk",
+                   "set tensor_parallelism=1")
+        if fam.fixed_state:
+            prefix_cache_mod.require_paged_state(name, cfg, fam.state_row_keys)
+        if fam.verify_paged is None:
+            spec_decode_mod.require_verify_walk(name, cfg, fam.fixed_state)
+        if cfg.quantization not in ("", "none", *fam.weight_formats):
             refuse(f"quantization={cfg.quantization!r} (no packed walk)",
-                   "set quantization='none'")
-        if cfg.kv_cache_dtype != "bfloat16":
+                   f"reads weight formats {list(fam.weight_formats)} "
+                   "beside plain weights", "set quantization='none'")
+        if cfg.kv_cache_dtype not in ("bfloat16", *fam.kv_formats):
             refuse(f"kv_cache_dtype={cfg.kv_cache_dtype!r}",
-                   "set kv_cache_dtype='bfloat16'")
-        if cfg.tensor_parallelism == -1:
+                   f"reads pool formats {list(fam.kv_formats)} beside "
+                   "bfloat16", "set kv_cache_dtype='bfloat16'")
+        if not fam.sharded and cfg.tensor_parallelism == -1:
             cfg = _dc.replace(cfg, tensor_parallelism=1)
         return cfg
 
@@ -1387,6 +1413,9 @@ class LLMEngine:
         tickets bounding the index at prefix_cache_slots entries.
         """
         self._prefix = None
+        # whether a row's pages or an entry's changed since the gauge of
+        # shared pages was last computed (_update_occupancy_gauges)
+        self._shared_pages_stale = False
         if cfg.prefix_cache_enable == "off" or cfg.prefix_cache_slots <= 0:
             return
         P = cfg.prefix_cache_slots
@@ -1411,6 +1440,7 @@ class LLMEngine:
         if pages and self._kv_alloc is not None:
             self._kv_alloc.release(pages)
         entry.pages = None
+        self._shared_pages_stale = True
 
     def paged_stats(self) -> Dict[str, float]:
         """Page-pool view (tests, operators): allocator occupancy plus
@@ -1532,6 +1562,7 @@ class LLMEngine:
                 # scraper threads; an unlocked insert here can blow up
                 # their .values() walk mid-iteration
                 self._slot_pages[req.slot] = pages
+                self._shared_pages_stale = True
             flight_recorder.event_rid(
                 req.rid, "page_alloc", fresh=len(fresh), shared=len(shared),
                 # which attention server this request's decode dispatches
@@ -3176,6 +3207,7 @@ class LLMEngine:
             # paged_stats() iterates this dict under the lock from
             # scraper threads (same contract as admission funding)
             self._slot_pages[slot] = list(pages)
+            self._shared_pages_stale = True
         slots_h, rows_h = self._table_stage_arrays(1)
         slots_h[0] = slot
         rows_h[0, : len(pages)] = pages
@@ -3498,6 +3530,7 @@ class LLMEngine:
                         req.prefix_entry = None
                     if req.slot >= 0:
                         pages = self._slot_pages.pop(req.slot, None)
+                        self._shared_pages_stale = True
                         if pages:
                             freed = self._kv_alloc.release(pages)
                             self._kv_alloc.observe_request_pages(
@@ -3556,6 +3589,7 @@ class LLMEngine:
         donated = pages[: ent.length // page]
         self._kv_alloc.retain(donated)
         ent.pages = list(donated)
+        self._shared_pages_stale = True
 
     def _insert_prefix_state(self, req: _Request) -> None:
         """Between two chunks of ``req``'s admission, right after the one
@@ -3640,6 +3674,7 @@ class LLMEngine:
             if req.finished:
                 if rec.slot >= 0:
                     pages = self._slot_pages.pop(rec.slot, None)
+                    self._shared_pages_stale = True
                     if pages:
                         freed = self._kv_alloc.release(pages)
                         self._kv_alloc.observe_request_pages(len(pages))
@@ -3666,6 +3701,7 @@ class LLMEngine:
                     "flat on the same-host path)", req.rid,
                 )
                 pages = self._slot_pages.pop(rec.slot, None)
+                self._shared_pages_stale = True
                 if pages:
                     # Release whatever part of the reservation is still
                     # live — the re-prefill funds a fresh one.
@@ -3869,6 +3905,10 @@ class LLMEngine:
                 n_live * min(k * C, self._span_fields.get("window", 0)),
                 resets=n_live if k == 0 else 0,
             ) or {})
+            if cached is not None and k and any(cached[i] == k * C for i in live):
+                # a row's FIRST uncached chunk after a prefix hit: the
+                # offset it read the entry's shared pages from
+                fields["prefix_depth_tokens"] = k * C
             if step_stats:
                 # the chunk's counts land in its span when the wave's
                 # first tokens are read back (_note_stats)
@@ -3937,21 +3977,25 @@ class LLMEngine:
 
     def _state_counters(self, kind: str, rows: int, tokens: int,
                         ring_tokens: int, resets: int = 0) -> Optional[Dict[str, int]]:
-        """Span fields and counters of one launch of a fixed-state
-        family (None for a family whose every layer is paged):
-        ``state_rows`` rows whose per-slot state advanced, ``kv_readers``
-        layers that read the one paged K/V, ``window_tokens_read`` ring
-        rows the window layers read (first step of a decode block) and,
-        on extend, ``cross_skipped_tokens``: tokens the
-        layers past the shared-KV layer never saw (all but one a row)."""
+        """Span fields and counters of one launch that the family's
+        declarations bring (None for a family that declares none). The
+        constant counts of ``span_fields`` go in as they are
+        (``kv_readers`` layers that read the one paged K/V,
+        ``latent_layers`` latent pools a step reads); a fixed-state
+        family adds ``state_rows`` rows whose per-slot state advanced,
+        ``window_tokens_read`` ring rows the window layers read (first
+        step of a decode block) and, on extend, ``cross_skipped_tokens``:
+        tokens the layers past the shared-KV layer never saw (all but
+        one a row)."""
         if kind != "decode":
             _M_PREFILL_TOKENS.inc(tokens)  # every family: the skipped share's denominator
-        if not self._fixed_state:
-            return None
         sf = self._span_fields
-        fields = {"state_rows": rows}
-        if "kv_readers" in sf:
-            fields["kv_readers"] = sf["kv_readers"]
+        # what the derived fields below are computed FROM is no field itself
+        fields = {k: v for k, v in sf.items()
+                  if k not in ("window", "window_layers", "last_position_only")}
+        if not self._fixed_state:
+            return fields or None
+        fields["state_rows"] = rows
         if "window_layers" in sf:
             fields["window_tokens_read"] = ring_tokens * sf["window_layers"]
         if kind == "decode":
@@ -4930,6 +4974,7 @@ class LLMEngine:
             # write only the scratch page, so re-issued pages are
             # safe immediately.
             pages = self._slot_pages.pop(slot, None)
+            self._shared_pages_stale = True
             if pages is not None:
                 freed = self._kv_alloc.release(pages)
                 self._kv_alloc.observe_request_pages(len(pages))
@@ -4972,6 +5017,14 @@ class LLMEngine:
         self._kv_alloc.set_fragmentation(
             1.0 - used / held_tokens if held_tokens else 0.0
         )
+        if self._prefix is not None and self._shared_pages_stale:
+            # pool pages a store entry holds AND a live row maps: what the
+            # store is sharing right now (recomputed only after a change)
+            self._shared_pages_stale = False
+            live = set()
+            for pages in self._slot_pages.values():
+                live.update(pages)
+            self._prefix.note_shared_pages(live)
 
 
 _REQ_IDS = itertools.count(1)

@@ -92,6 +92,11 @@ _M_STATE_ROWS = _REG.gauge(
     "Store rows of a fixed-state family's per-slot arrays that hold the "
     "state of a cached prefix (a stateful index: one row an entry).",
 )
+_M_SHARED_PAGES = _REG.gauge(
+    "genai_engine_prefix_shared_pages_in_use",
+    "Pool pages held by a prefix-store entry AND mapped by at least one "
+    "live row: what the store is sharing right now.",
+)
 _M_SLOTS_CAPACITY = _REG.gauge(
     "genai_engine_prefix_cache_slots_capacity",
     "Configured prefix-cache store slot count (prefix_cache_slots).",
@@ -496,6 +501,17 @@ class PrefixCache:
             self._bind_hint(hint, entry)
         self._update_gauge()
         return entry
+
+    def note_shared_pages(self, live_pages) -> int:
+        """Set the gauge of pool pages that an entry holds and a live row
+        maps (``live_pages``: the set of pages in live rows' tables)."""
+        with self._lock:
+            held = set()
+            for e in self._entries:
+                held.update(e.pages or ())
+        shared = len(held & live_pages)
+        _M_SHARED_PAGES.set(shared)
+        return shared
 
     # -- introspection --------------------------------------------------- #
     def stats(self) -> Dict[str, float]:

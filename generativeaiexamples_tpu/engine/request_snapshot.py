@@ -109,15 +109,16 @@ class SnapshotMismatch(SnapshotError):
 
 
 def require_paged_state(engine, what: str) -> None:
-    """A snapshot's payload is a request's pages. A model with fixed
-    per-slot state (models/registry.py) has more than that, and a
-    restore from pages alone would resume it on another tenant's
-    recurrent state — refuse to take or restore one."""
-    if getattr(engine, "_fixed_state", False):
+    """A snapshot's payload is a request's K and V pages, layer by
+    layer. A model with fixed per-slot state (models/registry.py) has
+    more than that, and a restore from pages alone would resume it on
+    another tenant's recurrent state; a family whose pools are not such
+    pages (a latent row a token) has nothing the payload could carry.
+    Refuse to take or restore one, with the engine's own reason."""
+    reason = getattr(engine, "_snapshot_refusal", None)
+    if reason:
         raise SnapshotError(
-            f"{what} refused: model {engine.engine_config.model_config_name!r} "
-            "keeps a fixed per-slot state beside the page pool, which a "
-            "request snapshot cannot carry"
+            f"{what} refused: model {engine.engine_config.model_config_name!r} {reason}"
         )
 
 

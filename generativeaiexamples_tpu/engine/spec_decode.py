@@ -197,19 +197,28 @@ class AdaptiveK:
         return self.k_max
 
 
-def require_paged_state(model: str, cfg) -> None:
-    """Speculative verify advances a row by up to K+1 positions and
-    rolls the rejected ones back by position alone — sound when a
-    slot's state is pages, not when it is a recurrent state that the
-    rejected tokens have already gone through. Refused at engine build
-    for a fixed-state model (models/registry.py)."""
-    if cfg.spec_decode_enable == "on":
+def require_verify_walk(model: str, cfg, fixed_state: bool) -> None:
+    """Speculative decoding needs the family's verify walk
+    (``verify_paged``, models/registry.py); a family that registers none
+    is refused it at engine build. For a fixed-state model the reason is
+    deeper than a missing walk: verify advances a row by up to K+1
+    positions and rolls the rejected ones back by position alone — sound
+    when a slot's state is pages, not when it is a recurrent state that
+    the rejected tokens have already gone through."""
+    if cfg.spec_decode_enable != "on":
+        return
+    if fixed_state:
         raise ValueError(
             f"{model} keeps a fixed per-slot state beside the page pool, "
             "which speculative verify cannot carry (a rejected draft "
             "token cannot be taken back out of a recurrent state); set "
             "spec_decode_enable='off'"
         )
+    raise ValueError(
+        f"{model} cannot be served with speculative decoding: its family "
+        "registers no verify walk (verify_paged is None, "
+        "models/registry.py); set spec_decode_enable='off'"
+    )
 
 
 def validate_config(cfg) -> None:

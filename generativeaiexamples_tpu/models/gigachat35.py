@@ -370,7 +370,7 @@ def yarn_mscale(factor: float, mscale: float) -> float:
     return 1.0 if factor <= 1 else 0.1 * mscale * math.log(factor) + 1.0
 
 
-def yarn_inv_freq(cfg: GigaChat35Config):
+def yarn_inv_freq(cfg):
     """YaRN's frequencies of the RoPE key's pairs: the published ones
     where a pair turns more than ``beta_fast`` times inside the original
     context, divided by ``factor`` where it turns less than ``beta_slow``
@@ -391,7 +391,7 @@ def yarn_inv_freq(cfg: GigaChat35Config):
     return jnp.asarray(out, jnp.float32)
 
 
-def rope(x, positions, cfg: GigaChat35Config):
+def rope(x, positions, cfg):
     """Interleaved RoPE (pairs 2i, 2i+1) over the whole last axis, at
     YaRN's frequencies; cos and sin unscaled (``mscale == mscale_all_dim``).
     x [.., T, (h,) dr] float32, positions [.., T]."""
@@ -509,36 +509,67 @@ def gdn_chunk(S, q, k, v, beta, g, block: int = GDN_BLOCK):
 # Latent attention
 
 
-def _mla_project(x, positions, lp: Params, cfg: GigaChat35Config):
+def _mla_project(x, positions, lp: Params, cfg, output_gate: bool = True):
     """x [.., T, D] normed -> per-head queries q_nope [.., T, H, dn] and
-    q_rope [.., T, H, dr] (rotated), the output gate [.., T, H * Dv], the
-    row to cache ``[c | k_rope | 0]`` [.., T, row]. float32."""
+    q_rope [.., T, H, dr] (rotated), the output gate [.., T, H * Dv] (None
+    for a model without one, ``output_gate`` False: ``wx`` then holds no
+    gate columns), the row to cache ``[c | k_rope | 0]`` [.., T, row].
+    float32."""
     H, ql, R = cfg.num_heads, cfg.q_lora_rank, cfg.kv_lora_rank
     dn, dr, Dv = cfg.qk_nope_head_dim, cfg.qk_rope_head_dim, cfg.v_head_dim
     xp = _mm(x, lp["wx"])
     cq = rms_norm(xp[..., :ql], lp["q_norm"], cfg.norm_eps, lp["wcq"].dtype)
-    gate = jax.nn.sigmoid(xp[..., ql:ql + H * Dv])
-    c = rms_norm(xp[..., ql + H * Dv:ql + H * Dv + R], lp["kv_norm"], cfg.norm_eps, jnp.float32)
-    k_rope = rope(xp[..., ql + H * Dv + R:], positions, cfg)
+    at = ql + (H * Dv if output_gate else 0)  # where [c_kv | k_r] starts
+    gate = jax.nn.sigmoid(xp[..., ql:at]) if output_gate else None
+    c = rms_norm(xp[..., at:at + R], lp["kv_norm"], cfg.norm_eps, jnp.float32)
+    k_rope = rope(xp[..., at + R:], positions, cfg)
     q = _mm(cq, lp["wcq"]).reshape(xp.shape[:-1] + (H, dn + dr))
     pad = jnp.zeros(c.shape[:-1] + (cfg.latent_row - R - dr,), jnp.float32)
     return q[..., :dn], rope(q[..., dn:], positions, cfg), gate, jnp.concatenate([c, k_rope, pad], axis=-1)
 
 
-def _absorb(q_nope, q_rope, lp: Params, cfg: GigaChat35Config):
+def _absorb(q_nope, q_rope, lp: Params, cfg):
     """``[W_uk^T q_nope | q_rope | 0]`` [.., H, row]: the query against a cached row."""
     qlat = jnp.einsum("...hd,hdr->...hr", q_nope.astype(lp["wuk"].dtype), lp["wuk"], preferred_element_type=jnp.float32)
     pad = jnp.zeros(qlat.shape[:-1] + (cfg.latent_row - qlat.shape[-1] - q_rope.shape[-1],), jnp.float32)
     return jnp.concatenate([qlat, q_rope, pad], axis=-1)
 
 
-def _mla_output(o, gate, lp: Params, cfg: GigaChat35Config):
-    """o [.., H, Dv] -> the mixer's output [.., D]: gated per channel, then ``W_o``."""
-    return _mm(o.reshape(o.shape[:-2] + (cfg.num_heads * cfg.v_head_dim,)) * gate, lp["wo"])
+def _mla_output(o, gate, lp: Params, cfg):
+    """o [.., H, Dv] -> the mixer's output [.., D]: gated per channel
+    (``gate`` None: no gate), then ``W_o``."""
+    o = o.reshape(o.shape[:-2] + (cfg.num_heads * cfg.v_head_dim,))
+    return _mm(o if gate is None else o * gate, lp["wo"])
+
+
+def _attend_absorbed(q_nope, q_rope, lat_pool, tables, positions, lp: Params, cfg,
+                     page_kernel: Optional[str] = None, work=None):
+    """One decode query a row against its cached rows, ABSORBED: the
+    query ``[W_uk^T q_nope | q_rope]`` against a row as it is cached, the
+    softmax's sum of the rows' first ``kv_lora_rank`` columns through
+    ``W_uv``. ``page_kernel`` ('compiled' / 'interpret') reads the pool
+    through ``ops/latent_attention.py`` over the page work list ``work``;
+    None gathers each row's whole table. q_nope [B, H, dn], q_rope
+    [B, H, dr] float32; tables [B, Pmax]; positions [B]. Returns
+    [B, H, Dv] float32."""
+    B, R = q_nope.shape[0], cfg.kv_lora_rank
+    qlat = _absorb(q_nope, q_rope, lp, cfg).astype(lat_pool.dtype)
+    if page_kernel:
+        acc = latent_attention.dense_latent_attention(
+            qlat, lat_pool, tables, positions, value_dim=R, scale=cfg.softmax_scale,
+            interpret=(page_kernel == "interpret"), work=work)
+    else:
+        S = tables.shape[1] * lat_pool.shape[1]
+        rows = lat_pool[tables].reshape(B, S, lat_pool.shape[-1])
+        sc = jnp.einsum("bhr,bsr->bhs", qlat, rows, preferred_element_type=jnp.float32) * cfg.softmax_scale
+        ok = jnp.arange(S, dtype=jnp.int32)[None, :] <= positions[:, None]
+        p = jax.nn.softmax(jnp.where(ok[:, None], sc, _NEG), axis=-1)
+        acc = jnp.einsum("bhs,bsr->bhr", p.astype(rows.dtype), rows[..., :R], preferred_element_type=jnp.float32)
+    return jnp.einsum("bhr,hrv->bhv", acc.astype(lp["wuv"].dtype), lp["wuv"], preferred_element_type=jnp.float32)
 
 
 def _attend_expanded(q_nope, q_rope, lat_pool, pages, positions, n_tokens, lp: Params,
-                     cfg: GigaChat35Config, block_pages: int = 4):
+                     cfg, block_pages: int = 4):
     """Chunk attention over a row's pages with a running softmax, in
     blocks of ``block_pages`` pages, as far as ``n_tokens`` [N] reach: ONE
     program whatever the context. Each block's per-head keys and values
@@ -746,20 +777,7 @@ def decode_paged(params: Params, cfg: GigaChat35Config, caches: Caches, tokens, 
                     lat = _write_rows(caches["lat"][i], phys, positions % page_size, row[:, 0])
                     new["lat"][i] = lat
                     latent_read = latent_read + jnp.sum(jnp.where(live, positions + 1, 0))
-                    qlat = _absorb(q_nope[:, 0], q_rope[:, 0], lp, cfg).astype(lat.dtype)
-                    if page_kernel:
-                        acc = latent_attention.dense_latent_attention(
-                            qlat, lat, tables, positions, value_dim=R, scale=cfg.softmax_scale,
-                            interpret=(page_kernel == "interpret"), work=work)
-                    else:
-                        rows = lat[tables].reshape(B, S, lat.shape[-1])
-                        sc = jnp.einsum("bhr,bsr->bhs", qlat, rows, preferred_element_type=jnp.float32) * cfg.softmax_scale
-                        ok = jnp.arange(S, dtype=jnp.int32)[None, :] <= positions[:, None]
-                        p = jax.nn.softmax(jnp.where(ok[:, None], sc, _NEG), axis=-1)
-                        acc = jnp.einsum("bhs,bsr->bhr", p.astype(rows.dtype), rows[..., :R],
-                                         preferred_element_type=jnp.float32)
-                    o = jnp.einsum("bhr,hrv->bhv", acc.astype(lp["wuv"].dtype), lp["wuv"],
-                                   preferred_element_type=jnp.float32)
+                    o = _attend_absorbed(q_nope[:, 0], q_rope[:, 0], lat, tables, positions, lp, cfg, page_kernel, work)
                     return _mla_output(o, gate[:, 0], lp, cfg)
 
         x = sublayer(x, lp, "mix", cfg, mix)

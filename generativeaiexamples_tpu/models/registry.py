@@ -66,17 +66,30 @@ tokens, and the engine writes them into the dispatch's span under these
 names (and into the counters of ``engine/llm_engine.py`` ``_STAT_COUNTERS``
 that carry the same names).
 
-``fixed_state`` declares that a slot holds state that is NOT pages (a
-recurrent state, a window ring). Everything in the engine that assumes
-"a slot's state is its pages" — speculative verify, request snapshots,
-the fixed / slab / scan / pipeline layouts, tensor parallelism,
-quantised weights and KV — refuses such a family at engine build
-(docs/model_registry.md); ``span_fields`` are the constant counts it
-adds to the dispatch-timeline spans. Prefix-cache reuse is refused too
-UNLESS the family names ``state_row_keys``: the leaves of its cache that
-hold one row a slot, which a prefix entry then carries beside its pages
-(a store row saved between two chunks of an admission, copied back on a
-hit: docs/prefix_cache.md).
+**What the engine refuses follows what a family DECLARES**
+(``engine/llm_engine.py`` ``_validate_family``, the table in
+docs/model_registry.md), one declaration a refusal:
+
+- ``fixed_state``: a slot holds state that is NOT pages (a recurrent
+  state, a window ring). Such a family gets one row a prefill wave and
+  no request snapshot, and prefix-cache reuse only if it names
+  ``state_row_keys``: the leaves of its cache that hold one row a slot,
+  which a prefix entry then carries beside its pages (a store row saved
+  between two chunks of an admission, copied back on a hit:
+  docs/prefix_cache.md). A family WITHOUT fixed state has pages only:
+  the prefix store shares them by refcount and a wave holds as many rows
+  as ``prefill_wave_tokens`` allows, whatever the pools hold.
+- ``verify_paged``: None, and speculative decoding is refused.
+- ``weight_formats`` / ``kv_formats``: the ``quantization`` and
+  ``kv_cache_dtype`` values, beside plain weights and a bfloat16 pool,
+  that the family's walks read. Whatever is not named is refused.
+- ``sharded``: the walks take a tensor-parallel mesh; False refuses one.
+- ``snapshot_pages``: the pools are the per-layer ``{"k", "v"}`` pages a
+  request snapshot's payload carries; False refuses ``drain`` /
+  ``restore_snapshot`` where they are taken.
+
+``span_fields`` are the constant counts a family adds to the
+dispatch-timeline spans.
 
 ``llama`` registers through the same door: its presets dict is
 ``llama.PRESETS`` itself (so a preset written there at run time, as the
@@ -139,6 +152,14 @@ class ModelFamily:
     # restores a prefix's state by copying one row of these leaves to
     # another. Empty: the store is refused (engine/prefix_cache.py)
     state_row_keys: Tuple[str, ...] = ()
+    # what the walks READ beside plain weights and a bfloat16 pool: values
+    # of ``quantization`` and of ``kv_cache_dtype``. Unnamed: refused
+    weight_formats: Tuple[str, ...] = ()
+    kv_formats: Tuple[str, ...] = ()
+    # the walks run over a tensor-parallel mesh (``tp=``, sharded pools)
+    sharded: bool = False
+    # the pools are per-layer {"k", "v"} pages: what a request snapshot carries
+    snapshot_pages: bool = False
 
 
 _FAMILIES: Dict[str, ModelFamily] = {}
@@ -245,6 +266,7 @@ def _llama_family() -> ModelFamily:
         serving_memory_bytes=llama.serving_memory_bytes,
         count_logical_params=llama.count_logical_params,
         paged_kv_shape=lambda cfg: PagedKVShape(cfg.num_layers, cfg.num_kv_heads, cfg.head_dim, cfg.num_heads),
+        weight_formats=("int8", "w8a8"), kv_formats=("int8", "int4"), sharded=True, snapshot_pages=True,
     )
 
 
@@ -377,6 +399,33 @@ def _solaropen2_family() -> ModelFamily:
     )
 
 
+def _kimik2_family() -> ModelFamily:
+    from generativeaiexamples_tpu.models import kimik2 as m
+
+    def init_paged_cache(cfg, pool_pages, page_size, num_slots, dtype, quantized=False, packed=False, **_):
+        if quantized or packed:
+            raise ValueError("kimik2 keeps its latent pools in bfloat16")
+        return m.init_paged_cache(cfg, pool_pages, page_size, num_slots, dtype)
+
+    return ModelFamily(
+        # pages only: no layer keeps state beside the latent pools, so the
+        # prefix store shares a prompt's pages by refcount (no state row)
+        name="kimik2", presets=m.PRESETS, config_type=m.KimiK2Config, fixed_state=False,
+        init_params=m.init_params_fast, init_paged_cache=init_paged_cache,
+        prefill_paged=m.prefill_paged, extend_paged=m.extend_paged, decode_paged=m.decode_paged,
+        verify_paged=None, head=lambda params, cfg, hidden, **_: m.head(params, cfg, hidden),
+        serving_memory_bytes=m.serving_memory_bytes, count_logical_params=m.count_logical_params,
+        # ONE head-less row a token and LAYER, the padded [c | k_rope] the
+        # pools allocate; every query head reads it as key and its first
+        # kv_lora_rank columns as value
+        paged_kv_shape=lambda cfg: PagedKVShape(
+            cfg.num_layers, 1, cfg.latent_row, cfg.num_heads, bytes_per_token=m.kv_bytes_per_token(cfg)),
+        span_fields=lambda cfg: {"latent_layers": cfg.num_layers},
+        resolve_kernels=lambda cfg, kind: {"grouped_matmul": kind},
+        stat_names=m.STAT_NAMES, read_stats=m.read_stats, extend_reads_window=False,
+    )
+
+
 def _load_builtin() -> None:
     if not _FAMILIES:
         register_family(_llama_family())
@@ -385,3 +434,4 @@ def _load_builtin() -> None:
         register_family(_gigachat35_family())
         register_family(_afmoe_family())
         register_family(_solaropen2_family())
+        register_family(_kimik2_family())

@@ -529,3 +529,77 @@ def test_the_prefix_state_copy_moves_one_row_in_place(one_chip, no_persistent_ca
     mem = jax.jit(copy, donate_argnums=(0,)).lower(cache, rows).compile().memory_analysis()
     cache_bytes = sum(x.size * x.dtype.itemsize for x in jax.tree.leaves(cache))
     assert mem.alias_size_in_bytes >= cache_bytes and mem.temp_size_in_bytes < 64 * 1024 * 1024
+
+
+# --------------------------------------------------------------------------- #
+# Kimi-K2.5 (models/kimik2.py): five latent pools a step and nothing a slot,
+# 12 experts of 2048, a table of 192 pages a row (max_seq_len 24,576)
+
+
+@pytest.fixture(scope="module")
+def kimik2_programs(one_chip):
+    """The family's walks at the benchmark configuration's shapes, on
+    shapes alone (``jax.eval_shape``): (config, model config, family, params, cache)."""
+    import functools
+    import json
+
+    from generativeaiexamples_tpu.models import registry
+    from perfbench import arch
+
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    with open(os.path.join(root, "perfbench", "configs", "kimi-k2.5-ep32-bf16.json"), encoding="utf-8") as fh:
+        cfg = json.load(fh)
+    arch.load(cfg).register(cfg)
+    family, mc = registry.resolve(cfg["name"])
+    eng = cfg["engine"]
+    on_chip = lambda tree: jax.tree.map(  # noqa: E731
+        lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=one_chip), tree)
+    params = on_chip(jax.eval_shape(lambda: family.init_params(mc, 0, jnp.bfloat16)))
+    cache = on_chip(jax.eval_shape(functools.partial(
+        family.init_paged_cache, mc, eng["kv_pool_pages"], eng["page_size"], eng["max_batch_size"], jnp.bfloat16)))
+    return cfg, mc, family, params, cache
+
+
+@pytest.mark.parametrize("program", ["decode", "extend-512", "extend-128", "extend-512-four-rows"])
+def test_kimik2_step_programs_compile_at_the_configurations_shapes(one_chip, no_persistent_cache, kimik2_programs, program):
+    """Decode (a scan of ``decode_block`` steps over 32 rows: five latent
+    reads and four grouped products a step) and the extend widths (one
+    row of 512 and of 128 tokens; four rows, what a wider
+    ``prefill_wave_tokens`` would send a pages-only family) for the
+    described chip: every kernel in the program, the five pools in place,
+    and what the program holds beside its arguments far under the 3 GB
+    the plan leaves."""
+    cfg, mc, family, params, cache = kimik2_programs
+    eng = cfg["engine"]
+    page, B, seq = eng["page_size"], eng["max_batch_size"], eng["max_seq_len"]
+    kernels = family.resolve_kernels(mc, "compiled")
+    i32 = lambda *shape: jax.ShapeDtypeStruct(shape, jnp.int32, sharding=one_chip)  # noqa: E731
+    tables = i32(B, seq // page)
+    assert seq // page == 192 and len(cache["lat"]) == 5 and set(cache) == {"lat", "stats"}
+    cache_bytes = sum(x.size * x.dtype.itemsize for x in jax.tree.leaves(cache))
+    assert cache_bytes == pytest.approx(cfg["memory_plan"]["page_pool_bytes"], rel=1e-6)
+
+    def decode(params, caches, tokens, positions, live, tables):
+        def body(carry, _):
+            tokens, positions, caches = carry
+            logits, caches = family.decode_paged(params, mc, caches, tokens, positions, live, tables, seq, page,
+                                                 page_kernel="compiled", **kernels)
+            return (jnp.argmax(logits, -1).astype(jnp.int32), positions + 1, caches), tokens
+        return jax.lax.scan(body, (tokens, positions, caches), None, length=eng["decode_block"])
+
+    def extend(params, caches, tokens, offsets, valid, slots, tables):
+        return family.extend_paged(params, mc, caches, tokens, offsets, valid, slots, tables, seq, page, **kernels)
+
+    if program == "decode":
+        live = jax.ShapeDtypeStruct((B,), jnp.bool_, sharding=one_chip)
+        compiled = jax.jit(decode, donate_argnums=(1,)).lower(params, cache, i32(B), i32(B), live, tables).compile()
+        calls, temp_limit = 5 + 2 * 4, 0.5e9  # the latent read a layer, gate|up and down an expert layer
+    else:
+        rows = 4 if program.endswith("four-rows") else 1
+        width = 128 if program == "extend-128" else eng["prefill_chunk"]
+        compiled = jax.jit(extend, donate_argnums=(1,)).lower(
+            params, cache, i32(rows, width), i32(rows), i32(rows), i32(rows), tables).compile()
+        calls, temp_limit = 2 * 4, 1.0e9 * rows  # the chunk reads the pools expanded: no latent kernel
+    mem = compiled.memory_analysis()
+    assert compiled.as_text().count("tpu_custom_call") >= calls
+    assert mem.alias_size_in_bytes >= cache_bytes - 1024 and mem.temp_size_in_bytes < temp_limit
