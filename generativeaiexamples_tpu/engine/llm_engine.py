@@ -275,6 +275,14 @@ _M_STATE_KERNEL_ROWS = _REG.counter(
     "where the XLA step serves. Beside the spans' state_rows it is the "
     "share of decode steps the kernel engages on.",
 )
+_M_LATENT_CHUNK_READS = _REG.counter(
+    "genai_engine_latent_chunk_reads_total",
+    "Latent-attention layers an extend dispatch read expanded, by path: "
+    "kernel (ops/latent_attention.py latent_chunk_read: keys, values and "
+    "scores stay in VMEM) or xla (the block loop). Beside the spans' "
+    "latent_chunk_kernel_layers; the kernel's share is its engagement.",
+    ("path",),
+)
 _M_WINDOW_READ = _REG.counter(
     "genai_engine_window_read_tokens_total",
     "Ring rows the window-attention layers read for their queries "
@@ -296,6 +304,8 @@ _STAT_COUNTERS = {
     "dsa_tokens_selected": _M_DSA_SELECTED,
     "dsa_context_tokens": _M_DSA_CONTEXT,
     "latent_tokens_read": _M_LATENT_READ,
+    "latent_chunk_kernel_layers": _M_LATENT_CHUNK_READS.labels(path="kernel"),
+    "latent_chunk_xla_layers": _M_LATENT_CHUNK_READS.labels(path="xla"),
     "state_kernel_rows": _M_STATE_KERNEL_ROWS,
     "window_tokens_read": _M_WINDOW_READ,
     "full_tokens_read": _M_FULL_READ,

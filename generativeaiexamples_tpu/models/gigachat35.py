@@ -63,7 +63,7 @@ _LANE = 128
 GDN_BLOCK = 64
 
 STAT_NAMES = ("moe_pairs_held", "moe_pairs_absent", "moe_experts_hit", "moe_experts_held",
-              "latent_tokens_read", "state_kernel_rows")
+              "latent_tokens_read", "latent_chunk_kernel_layers", "latent_chunk_xla_layers", "state_kernel_rows")
 
 
 @dataclasses.dataclass(frozen=True)
@@ -568,14 +568,33 @@ def _attend_absorbed(q_nope, q_rope, lat_pool, tables, positions, lp: Params, cf
     return jnp.einsum("bhr,hrv->bhv", acc.astype(lp["wuv"].dtype), lp["wuv"], preferred_element_type=jnp.float32)
 
 
+def latent_chunk_kind(cfg, kind: Optional[str], chunk: Optional[int] = None,
+                      page_size: Optional[int] = None) -> Optional[str]:
+    """``kind`` where ``ops/latent_attention.py`` ``latent_chunk_read``
+    tiles this configuration's widths (and a chunk width and page size,
+    where the caller knows them), else None: decided from the shapes."""
+    ok = kind and latent_attention.chunk_read_supported(
+        cfg.qk_nope_head_dim, cfg.qk_rope_head_dim, cfg.v_head_dim, cfg.kv_lora_rank, cfg.latent_row,
+        chunk, page_size)
+    return kind if ok else None
+
+
 def _attend_expanded(q_nope, q_rope, lat_pool, pages, positions, n_tokens, lp: Params,
-                     cfg, block_pages: int = 4):
+                     cfg, block_pages: int = 4, latent_chunk: Optional[str] = None, work=None):
     """Chunk attention over a row's pages with a running softmax, in
     blocks of ``block_pages`` pages, as far as ``n_tokens`` [N] reach: ONE
     program whatever the context. Each block's per-head keys and values
-    are rebuilt from its latent rows (``latent_expand``). q_nope
-    [N, T, H, dn], q_rope [N, T, H, dr] float32; pages [N, Pmax];
-    positions [N, T]. Returns [N, T, H, Dv] float32."""
+    are rebuilt from its latent rows (``latent_expand``).
+    ``latent_chunk`` ('compiled' / 'interpret') does it in
+    ``ops/latent_attention.py`` ``latent_chunk_read``, where neither they
+    nor a score leaves the chip, over the block work list ``work``; None
+    in the XLA loop below. q_nope [N, T, H, dn], q_rope [N, T, H, dr]
+    float32; pages [N, Pmax]; positions [N, T]. Returns [N, T, H, Dv]
+    float32."""
+    if latent_chunk:
+        return latent_attention.latent_chunk_read(
+            jnp.moveaxis(q_nope, 2, 1), jnp.moveaxis(q_rope, 2, 1), lat_pool, pages, positions, n_tokens,
+            lp["wuk"], lp["wuv"], scale=cfg.softmax_scale, interpret=(latent_chunk == "interpret"), work=work)
     N, T, H, _ = q_nope.shape
     R, dr, Dv = cfg.kv_lora_rank, cfg.qk_rope_head_dim, cfg.v_head_dim
     page = lat_pool.shape[1]
@@ -616,12 +635,24 @@ def _attend_expanded(q_nope, q_rope, lat_pool, pages, positions, n_tokens, lp: P
     return jnp.moveaxis(acc / jnp.where(l == 0.0, 1.0, l), 1, 2)
 
 
+def chunk_read_stats(layers: int, latent_chunk: Optional[str]):
+    """``[latent_chunk_kernel_layers, latent_chunk_xla_layers]`` of one
+    chunk walk over ``layers`` latent layers: which path read them."""
+    return jnp.asarray([layers, 0] if latent_chunk else [0, layers], jnp.int32)
+
+
+def _stats(moe_stats, latent_read, chunk_layers=(0, None), kernel_rows=0):
+    """The walk's counts in ``STAT_NAMES``' order."""
+    return jnp.concatenate([moe_stats, latent_read[None], chunk_read_stats(*chunk_layers),
+                            jnp.asarray(kernel_rows, jnp.int32)[None]]).astype(jnp.int32)
+
+
 # --------------------------------------------------------------------- //
 # The chunk walk: prefill and chunked extend
 
 
 def _chunk_walk(params: Params, cfg: GigaChat35Config, caches: Caches, tokens, offsets, valid, slots,
-                tables, page_size: int, grouped_matmul: Optional[str] = None):
+                tables, page_size: int, grouped_matmul: Optional[str] = None, latent_chunk: Optional[str] = None):
     """All layers over a chunk [N, C] per row; returns (the residual row
     of each row's last valid position [N, D], caches).
 
@@ -630,7 +661,10 @@ def _chunk_walk(params: Params, cfg: GigaChat35Config, caches: Caches, tokens, o
     slot's state on. A row with ``valid == 0`` changes nothing: its pool
     writes are dropped and its slot's state is written back as it was.
     The latent read walks each row's pages as far as its context reaches
-    whatever window the engine names: one program a chunk width."""
+    whatever window the engine names: one program a chunk width.
+    ``latent_chunk`` ('compiled' / 'interpret') reads them through
+    ``ops/latent_attention.py`` ``latent_chunk_read`` where the shapes
+    tile; else, and where None, through the XLA loop."""
     N, C = tokens.shape
     S = tables.shape[1] * page_size
     idx = jnp.arange(C, dtype=jnp.int32)
@@ -641,6 +675,10 @@ def _chunk_walk(params: Params, cfg: GigaChat35Config, caches: Caches, tokens, o
     last = jnp.clip(valid, 1, C) - 1
     row_tables = tables[slots]
     P = caches["lat"][0].shape[0] if caches["lat"] else 0
+    n_tokens = jnp.where(row_live, offsets + valid, 0)
+    latent_chunk = latent_chunk_kind(cfg, latent_chunk, C, page_size) if P else None
+    # one work list a chunk: every latent layer walks the same blocks
+    work = latent_attention.chunk_work_list(row_tables, n_tokens, page_size, P) if latent_chunk else None
 
     x = params["embed"][tokens].astype(jnp.float32)  # [N, C, D]
     new = {k: list(v) if isinstance(v, list) else v for k, v in caches.items()}
@@ -684,8 +722,8 @@ def _chunk_walk(params: Params, cfg: GigaChat35Config, caches: Caches, tokens, o
                     lat = _write_rows(caches["lat"][i], phys, positions % page_size, row)
                     new["lat"][i] = lat
                     latent_read = latent_read + jnp.sum(jnp.where(tok_valid, positions + 1, 0))
-                    o = _attend_expanded(q_nope, q_rope, lat, row_tables, positions,
-                                         jnp.where(row_live, offsets + valid, 0), lp, cfg)
+                    o = _attend_expanded(q_nope, q_rope, lat, row_tables, positions, n_tokens, lp, cfg,
+                                         latent_chunk=latent_chunk, work=work)
                     return _mla_output(o, gate, lp, cfg)
 
         x = sublayer(x, lp, "mix", cfg, mix)
@@ -693,27 +731,28 @@ def _chunk_walk(params: Params, cfg: GigaChat35Config, caches: Caches, tokens, o
         if stats is not None:
             moe_stats = moe_stats + stats
     # the block-wise recurrence advanced every state: the step kernel none
-    new["stats"] = jnp.concatenate([moe_stats, latent_read[None], jnp.zeros((1,), jnp.int32)]).astype(jnp.int32)
+    new["stats"] = _stats(moe_stats, latent_read, chunk_layers=(i_mla, latent_chunk))
     return jnp.take_along_axis(x, last[:, None, None], axis=1)[:, 0], new
 
 
 def prefill_paged(params: Params, cfg: GigaChat35Config, caches: Caches, tokens, lengths, slots, tables,
                   page_size: int, grouped_matmul: Optional[str] = None, delta_step: Optional[str] = None,
-                  **_paths):
+                  latent_chunk: Optional[str] = None, **_paths):
     """A monolithic admission wave: (last-position logits [N, V], caches)."""
     del delta_step  # the step kernel serves decode; a chunk walks block-wise
     hidden, caches = _chunk_walk(params, cfg, caches, tokens, jnp.zeros_like(lengths), lengths, slots,
-                                 tables, page_size, grouped_matmul)
+                                 tables, page_size, grouped_matmul, latent_chunk)
     return head(params, cfg, hidden), caches
 
 
 def extend_paged(params: Params, cfg: GigaChat35Config, caches: Caches, tokens, offsets, valid, slots,
                  tables, window: int, page_size: int, grouped_matmul: Optional[str] = None,
-                 delta_step: Optional[str] = None, **_paths):
+                 delta_step: Optional[str] = None, latent_chunk: Optional[str] = None, **_paths):
     """One chunk of a chunked prefill: (the residual row [N, D] of each
     row's last valid position, caches)."""
     del window, delta_step  # the latent read follows each row's own context; a chunk walks block-wise
-    return _chunk_walk(params, cfg, caches, tokens, offsets, valid, slots, tables, page_size, grouped_matmul)
+    return _chunk_walk(params, cfg, caches, tokens, offsets, valid, slots, tables, page_size, grouped_matmul,
+                       latent_chunk)
 
 
 # --------------------------------------------------------------------- //
@@ -722,12 +761,13 @@ def extend_paged(params: Params, cfg: GigaChat35Config, caches: Caches, tokens, 
 
 def decode_paged(params: Params, cfg: GigaChat35Config, caches: Caches, tokens, positions, live, tables,
                  window: Optional[int], page_size: int, page_kernel: Optional[str] = None,
-                 grouped_matmul: Optional[str] = None, delta_step: Optional[str] = None, **_paths):
+                 grouped_matmul: Optional[str] = None, delta_step: Optional[str] = None,
+                 latent_chunk: Optional[str] = None, **_paths):
     """One token per slot: (logits [B, V], caches). A dead row leaves
     every fixed state as it is and writes nothing to the pools.
     ``delta_step`` ('compiled' / 'interpret') advances the delta-rule
     state with ``ops/delta_rule.py``, in place; None with ``gdn_step``."""
-    del window
+    del window, latent_chunk  # a step reads absorbed: no chunk read
     B = tokens.shape[0]
     S = tables.shape[1] * page_size
     R = cfg.kv_lora_rank
@@ -784,6 +824,6 @@ def decode_paged(params: Params, cfg: GigaChat35Config, caches: Caches, tokens, 
         x, stats = mlp_sublayer(x, lp, mlp, cfg, live, grouped_matmul)
         if stats is not None:
             moe_stats = moe_stats + stats
-    kernel_rows = jnp.sum(live.astype(jnp.int32)) if delta_step and i_gdn else jnp.zeros((), jnp.int32)
-    new["stats"] = jnp.concatenate([moe_stats, latent_read[None], kernel_rows[None]]).astype(jnp.int32)
+    kernel_rows = jnp.sum(live.astype(jnp.int32)) if delta_step and i_gdn else 0
+    new["stats"] = _stats(moe_stats, latent_read, kernel_rows=kernel_rows)
     return head(params, cfg, x), new

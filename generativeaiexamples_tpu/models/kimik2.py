@@ -41,17 +41,18 @@ import jax
 import jax.numpy as jnp
 
 from generativeaiexamples_tpu.models.gigachat35 import (
-    _attend_absorbed, _attend_expanded, _draw, _mla_output, _mla_project, yarn_mscale,
+    _attend_absorbed, _attend_expanded, _draw, _mla_output, _mla_project, chunk_read_stats, latent_chunk_kind,
+    yarn_mscale,
 )
 from generativeaiexamples_tpu.models.glm5next import _mm, _write_rows, moe, rms_norm, swiglu_mlp
-from generativeaiexamples_tpu.ops import page_attention
+from generativeaiexamples_tpu.ops import latent_attention, page_attention
 
 Params = Dict[str, Any]
 Caches = Dict[str, Any]
 _LANE = 128
 
 STAT_NAMES = ("moe_pairs_held", "moe_pairs_absent", "moe_experts_hit", "moe_experts_held",
-              "latent_tokens_read")
+              "latent_tokens_read", "latent_chunk_kernel_layers", "latent_chunk_xla_layers")
 
 
 @dataclasses.dataclass(frozen=True)
@@ -275,8 +276,9 @@ def head(params: Params, cfg: KimiK2Config, hidden):
     return _mm(rms_norm(hidden, params["final_norm"], cfg.norm_eps), params["head"])
 
 
-def _stats(moe_stats, latent_read):
-    return jnp.concatenate([moe_stats, latent_read[None]]).astype(jnp.int32)
+def _stats(moe_stats, latent_read, chunk_layers=(0, None)):
+    """The walk's counts in ``STAT_NAMES``' order."""
+    return jnp.concatenate([moe_stats, latent_read[None], chunk_read_stats(*chunk_layers)]).astype(jnp.int32)
 
 
 # --------------------------------------------------------------------- //
@@ -284,7 +286,7 @@ def _stats(moe_stats, latent_read):
 
 
 def _chunk_walk(params: Params, cfg: KimiK2Config, caches: Caches, tokens, offsets, valid, slots,
-                tables, page_size: int, grouped_matmul: Optional[str] = None):
+                tables, page_size: int, grouped_matmul: Optional[str] = None, latent_chunk: Optional[str] = None):
     """All layers over a chunk [N, C] per row; returns (the residual row
     of each row's last valid position [N, D], caches).
 
@@ -292,7 +294,10 @@ def _chunk_walk(params: Params, cfg: KimiK2Config, caches: Caches, tokens, offse
     pages this row wrote, or pages a prefix entry shares with it. A row
     with ``valid == 0`` changes nothing: its pool writes are dropped.
     The latent read walks each row's pages as far as its context reaches
-    whatever window the engine names: one program a chunk width."""
+    whatever window the engine names: one program a chunk width.
+    ``latent_chunk`` ('compiled' / 'interpret') reads them through
+    ``ops/latent_attention.py`` ``latent_chunk_read`` where the shapes
+    tile; else, and where None, through the XLA loop."""
     N, C = tokens.shape
     S = tables.shape[1] * page_size
     idx = jnp.arange(C, dtype=jnp.int32)
@@ -305,6 +310,9 @@ def _chunk_walk(params: Params, cfg: KimiK2Config, caches: Caches, tokens, offse
     phys = jnp.take_along_axis(row_tables, positions // page_size, axis=1)
     phys = jnp.where(tok_valid, phys, P)  # padding: dropped
     n_tokens = jnp.where(row_live, offsets + valid, 0)
+    latent_chunk = latent_chunk_kind(cfg, latent_chunk, C, page_size)
+    # one work list a chunk: every layer walks the same blocks
+    work = latent_attention.chunk_work_list(row_tables, n_tokens, page_size, P) if latent_chunk else None
 
     x = params["embed"][tokens].astype(jnp.float32)  # [N, C, D]
     new = dict(caches, lat=list(caches["lat"]))
@@ -315,29 +323,33 @@ def _chunk_walk(params: Params, cfg: KimiK2Config, caches: Caches, tokens, offse
             q_nope, q_rope, _, row = _mla_project(
                 _norm(x, lp["ln_attn"], cfg), positions, lp, cfg, output_gate=False)
             lat = new["lat"][l] = _write_rows(caches["lat"][l], phys, positions % page_size, row)
-            o = _attend_expanded(q_nope, q_rope, lat, row_tables, positions, n_tokens, lp, cfg)
+            o = _attend_expanded(q_nope, q_rope, lat, row_tables, positions, n_tokens, lp, cfg,
+                                 latent_chunk=latent_chunk, work=work)
             x = x + _mla_output(o, None, lp, cfg)
         x, stats = mlp_sublayer(x, lp, mlp, cfg, tok_valid, grouped_matmul)
         if stats is not None:
             moe_stats = moe_stats + stats
-    new["stats"] = _stats(moe_stats, jnp.sum(jnp.where(tok_valid, positions + 1, 0)))
+    new["stats"] = _stats(moe_stats, jnp.sum(jnp.where(tok_valid, positions + 1, 0)), (cfg.num_layers, latent_chunk))
     return jnp.take_along_axis(x, last[:, None, None], axis=1)[:, 0], new
 
 
 def prefill_paged(params: Params, cfg: KimiK2Config, caches: Caches, tokens, lengths, slots, tables,
-                  page_size: int, grouped_matmul: Optional[str] = None, **_paths):
+                  page_size: int, grouped_matmul: Optional[str] = None, latent_chunk: Optional[str] = None,
+                  **_paths):
     """A whole prompt in one program, the REFERENCE walk: (last-position logits [N, V], caches)."""
     hidden, caches = _chunk_walk(params, cfg, caches, tokens, jnp.zeros_like(lengths), lengths, slots,
-                                 tables, page_size, grouped_matmul)
+                                 tables, page_size, grouped_matmul, latent_chunk)
     return head(params, cfg, hidden), caches
 
 
 def extend_paged(params: Params, cfg: KimiK2Config, caches: Caches, tokens, offsets, valid, slots,
-                 tables, window: int, page_size: int, grouped_matmul: Optional[str] = None, **_paths):
+                 tables, window: int, page_size: int, grouped_matmul: Optional[str] = None,
+                 latent_chunk: Optional[str] = None, **_paths):
     """One chunk of a chunked prefill: (the residual row [N, D] of each
     row's last valid position, caches)."""
     del window  # the latent read follows each row's own context
-    return _chunk_walk(params, cfg, caches, tokens, offsets, valid, slots, tables, page_size, grouped_matmul)
+    return _chunk_walk(params, cfg, caches, tokens, offsets, valid, slots, tables, page_size, grouped_matmul,
+                       latent_chunk)
 
 
 # --------------------------------------------------------------------- //
@@ -346,11 +358,11 @@ def extend_paged(params: Params, cfg: KimiK2Config, caches: Caches, tokens, offs
 
 def decode_paged(params: Params, cfg: KimiK2Config, caches: Caches, tokens, positions, live, tables,
                  window: Optional[int], page_size: int, page_kernel: Optional[str] = None,
-                 grouped_matmul: Optional[str] = None, **_paths):
+                 grouped_matmul: Optional[str] = None, latent_chunk: Optional[str] = None, **_paths):
     """One token per slot: (logits [B, V], caches). A dead row writes
     nothing to the pools. ``page_kernel`` ('compiled' / 'interpret')
     reads the pools through ``ops/latent_attention.py``; None gathers."""
-    del window
+    del window, latent_chunk  # a step reads absorbed: no chunk read
     P = caches["lat"][0].shape[0]
     phys = jnp.where(live, jnp.take_along_axis(tables, (positions // page_size)[:, None], axis=1)[:, 0], P)
     # one work list a step: every layer walks the same pages

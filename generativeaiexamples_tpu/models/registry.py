@@ -343,7 +343,9 @@ def _gigachat35_family() -> ModelFamily:
             len(cfg.layers_of("mla")), 1, cfg.latent_row, cfg.num_heads,
             bytes_per_token=m.kv_bytes_per_token(cfg)),
         fixed_state_bytes_per_slot=m.fixed_state_bytes_per_slot,
-        resolve_kernels=lambda cfg, kind: {"grouped_matmul": kind, "delta_step": kind},
+        # the chunk walk's latent read where the widths tile the chip (ops/latent_attention.py)
+        resolve_kernels=lambda cfg, kind: {"grouped_matmul": kind, "delta_step": kind,
+                                           "latent_chunk": m.latent_chunk_kind(cfg, kind)},
         stat_names=m.STAT_NAMES, read_stats=m.read_stats, extend_reads_window=False,
     )
 
@@ -421,7 +423,8 @@ def _kimik2_family() -> ModelFamily:
         paged_kv_shape=lambda cfg: PagedKVShape(
             cfg.num_layers, 1, cfg.latent_row, cfg.num_heads, bytes_per_token=m.kv_bytes_per_token(cfg)),
         span_fields=lambda cfg: {"latent_layers": cfg.num_layers},
-        resolve_kernels=lambda cfg, kind: {"grouped_matmul": kind},
+        # the chunk walk's latent read where the widths tile the chip (ops/latent_attention.py)
+        resolve_kernels=lambda cfg, kind: {"grouped_matmul": kind, "latent_chunk": m.latent_chunk_kind(cfg, kind)},
         stat_names=m.STAT_NAMES, read_stats=m.read_stats, extend_reads_window=False,
     )
 

@@ -220,6 +220,34 @@ def test_dense_latent_attention_compiles_with_a_key_wider_than_the_value(one_chi
     assert "tpu_custom_call" in text
 
 
+@pytest.mark.parametrize("rows, T", [(1, 512), (1, 128), (4, 512)], ids=["chunk-512", "chunk-128", "four-rows"])
+def test_latent_chunk_read_compiles_at_the_published_widths(one_chip, no_persistent_cache, rows, T):
+    """The chunk walk's latent read of models/kimik2.py and
+    models/gigachat35.py alone (ops/latent_attention.py
+    ``latent_chunk_read``): 64 heads of 128 | 64 against a latent of 512
+    in rows of 640 columns, a table of 192 pages a row (``max_seq_len``
+    24,576), eight pages and four heads a grid step, a run-time count of
+    steps on the grid's second axis, 64 MB of VMEM allowed."""
+    H, dn, dr, Dv, R, W, pages = 64, 128, 64, 128, 512, 640, 192
+    assert latent_attention.chunk_read_supported(dn, dr, Dv, R, W, T, PAGE)
+    assert latent_attention.chunk_block_pages(PAGE, pages) == 8 and latent_attention.chunk_heads_per_step(H) == 4
+
+    def s(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    def read(q_nope, q_rope, pool, tables, positions, n_tokens, wuk, wuv):
+        return latent_attention.latent_chunk_read(q_nope, q_rope, pool, tables, positions, n_tokens, wuk, wuv, scale=0.1351)
+
+    bf16 = jnp.bfloat16
+    compiled = jax.jit(read).lower(
+        s((rows, H, T, dn), bf16), s((rows, H, T, dr), bf16), s((6145, PAGE, W), bf16), s((rows, pages), jnp.int32),
+        s((rows, T), jnp.int32), s((rows,), jnp.int32), s((H, dn, R), bf16), s((H, R, Dv), bf16)).compile()
+    text = compiled.as_text()
+    assert "tpu_custom_call" in text and "latent_chunk_read" in text
+    # beside the arguments: the queries laid [q_nope | q_rope | 0] a head, and nothing of a score's size
+    assert compiled.memory_analysis().temp_size_in_bytes < rows * H * T * (dn + 128) * 2 + (4 << 20)
+
+
 @pytest.mark.parametrize("tokens", [64, 512], ids=["decode-16-row-tiles", "chunk-64-row-tiles"])
 def test_grouped_matmul_compiles_at_7168_wide_experts(one_chip, no_persistent_cache, tokens):
     """``row_tile`` and the column blocks were chosen at 4096 x 2048
@@ -599,7 +627,55 @@ def test_kimik2_step_programs_compile_at_the_configurations_shapes(one_chip, no_
         width = 128 if program == "extend-128" else eng["prefill_chunk"]
         compiled = jax.jit(extend, donate_argnums=(1,)).lower(
             params, cache, i32(rows, width), i32(rows), i32(rows), i32(rows), tables).compile()
-        calls, temp_limit = 2 * 4, 1.0e9 * rows  # the chunk reads the pools expanded: no latent kernel
+        # gate|up and down an expert layer, and the chunk's latent read a layer (ops/latent_attention.py
+        # ``latent_chunk_read``: keys, values and scores expanded in VMEM). The 0.33 GB a row of
+        # temporaries are not the read's: they stay
+        calls, temp_limit = 2 * 4 + 5, 1.0e9 * rows
+        assert kernels["latent_chunk"] == "compiled"
     mem = compiled.memory_analysis()
     assert compiled.as_text().count("tpu_custom_call") >= calls
     assert mem.alias_size_in_bytes >= cache_bytes - 1024 and mem.temp_size_in_bytes < temp_limit
+
+
+# --------------------------------------------------------------------------- #
+# GigaChat3.5 (models/gigachat35.py): one latent layer among four Gated DeltaNet
+# layers; the chunk walk's read of it is the same kernel at the same widths
+
+
+def test_gigachat35_extend_program_holds_the_latent_chunk_read(one_chip, no_persistent_cache):
+    """One row of 512 tokens at the benchmark configuration's shapes (a
+    table of 64 pages a row): gate|up and down of the four expert layers
+    and the ONE latent layer's chunk read, the cache in place."""
+    import functools
+    import json
+
+    from generativeaiexamples_tpu.models import registry
+    from perfbench import arch
+
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    with open(os.path.join(root, "perfbench", "configs", "gigachat3.5-432b-a28b-ep16-bf16.json"), encoding="utf-8") as fh:
+        cfg = json.load(fh)
+    arch.load(cfg).register(cfg)
+    family, mc = registry.resolve(cfg["name"])
+    eng = cfg["engine"]
+    page, B, seq = eng["page_size"], eng["max_batch_size"], eng["max_seq_len"]
+    on_chip = lambda tree: jax.tree.map(  # noqa: E731
+        lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=one_chip), tree)
+    params = on_chip(jax.eval_shape(lambda: family.init_params(mc, 0, jnp.bfloat16)))
+    cache = on_chip(jax.eval_shape(functools.partial(
+        family.init_paged_cache, mc, eng["kv_pool_pages"], page, B, jnp.bfloat16)))
+    kernels = family.resolve_kernels(mc, "compiled")
+    assert kernels["latent_chunk"] == "compiled" and len(cache["lat"]) == 1
+    i32 = lambda *shape: jax.ShapeDtypeStruct(shape, jnp.int32, sharding=one_chip)  # noqa: E731
+
+    def extend(params, caches, tokens, offsets, valid, slots, tables):
+        return family.extend_paged(params, mc, caches, tokens, offsets, valid, slots, tables, seq, page, **kernels)
+
+    compiled = jax.jit(extend, donate_argnums=(1,)).lower(
+        params, cache, i32(1, eng["prefill_chunk"]), i32(1), i32(1), i32(1), i32(B, seq // page)).compile()
+    text = compiled.as_text()
+    experts = sum(mlp == "sparse" for _, mlp in mc.layers)
+    assert text.count("tpu_custom_call") >= 2 * experts + 1 and "latent_chunk_read" in text
+    cache_bytes = sum(x.size * x.dtype.itemsize for x in jax.tree.leaves(cache))
+    mem = compiled.memory_analysis()
+    assert mem.alias_size_in_bytes >= cache_bytes - 1024 and mem.temp_size_in_bytes < 1.0e9

@@ -371,7 +371,8 @@ def test_registry_resolves_the_family_and_what_it_declares():
     # every resolved kernel path is a keyword of the walks under the SAME name: one that a walk took under
     # another name would vanish in **_paths and the XLA path would serve (found on the chip, PR 35)
     resolved = fam.resolve_kernels(cfg, "compiled")
-    assert resolved == {"grouped_matmul": "compiled", "delta_step": "compiled"}
+    # (the debug preset's widths do not tile the chip: its chunks read through XLA, tests/test_latent_attention_chunk.py)
+    assert resolved == {"grouped_matmul": "compiled", "delta_step": "compiled", "latent_chunk": None}
     for walk in (m.prefill_paged, m.extend_paged, m.decode_paged):
         assert set(resolved) <= set(inspect.signature(walk).parameters)
     assert "page_kernel" in inspect.signature(m.decode_paged).parameters  # the engine's own, which serves the latent read
@@ -411,7 +412,7 @@ def test_engine_serves_every_prompt_shape_as_the_references_argmax(engine):
     from generativeaiexamples_tpu.engine.llm_engine import SamplingParams
 
     assert engine._family.name == "gigachat35" and engine._paged_kernel == "interpret"
-    assert engine._family_kernels == {"grouped_matmul": "interpret", "delta_step": "interpret"}
+    assert engine._family_kernels == {"grouped_matmul": "interpret", "delta_step": "interpret", "latent_chunk": None}
     rng = np.random.default_rng(1)
     prompts = [[int(t) for t in rng.integers(3, 250, size=n)] for n in (5, 64, 100, 150, 9)]
     before = engine.metrics
@@ -461,6 +462,53 @@ def test_engine_reads_the_familys_counts_back_with_the_tokens(engine):
     assert step["state_kernel_rows"] == step["state_rows"] and chunk["state_kernel_rows"] == 0
     assert grew("genai_engine_state_kernel_rows_total") >= 1
     assert engine._compile_watch.snapshot().get("hot_path_compiles_total", 0) == 0
+
+
+# the latent layer at widths that tile the chip (heads and a latent of whole lane tiles), the rest as the debug preset
+LANES = dataclasses.replace(CFG, num_heads=2, kv_lora_rank=128, qk_nope_head_dim=128, qk_rope_head_dim=64, v_head_dim=128)
+
+
+def test_an_engine_at_widths_that_tile_reads_its_chunks_through_the_kernel():
+    """``ops/latent_attention.py`` ``latent_chunk_read`` interpreted, in
+    the engine's own extend programs, with the output gate applied after
+    it: a 150-token prompt in three chunks is answered as the engine with
+    every kernel off answers it; each chunk's span says the ONE latent
+    layer read through the kernel and the counter grew under
+    ``path="kernel"`` alone, under ``path="xla"`` with it off."""
+    import time
+
+    from generativeaiexamples_tpu.config import EngineConfig
+    from generativeaiexamples_tpu.engine import dispatch_timeline
+    from generativeaiexamples_tpu.engine.llm_engine import LLMEngine, SamplingParams
+    from generativeaiexamples_tpu.utils import metrics as metrics_mod
+
+    def reads(path):
+        key = f'genai_engine_latent_chunk_reads_total{{path="{path}"}} '
+        lines = [l for l in metrics_mod.get_registry().render().splitlines() if l.startswith(key)]
+        return float(lines[0].rsplit(" ", 1)[1]) if lines else 0.0
+
+    name = "gigachat35-lanes-test"
+    m.PRESETS[name] = LANES
+    prompt = [int(t) for t in np.random.default_rng(3).integers(3, 250, size=150)]
+    try:
+        assert registry.resolve(name)[0].resolve_kernels(LANES, "interpret")["latent_chunk"] == "interpret"
+        answers = {}
+        for kernel in ("interpret", "off"):
+            eng = LLMEngine(EngineConfig(**dict(BASE, model_config_name=name, paged_kernel=kernel)))
+            try:
+                assert eng._family_kernels["latent_chunk"] == (None if kernel == "off" else "interpret")
+                served, other = ("kernel", "xla") if kernel == "interpret" else ("xla", "kernel")
+                before, t0 = (reads(served), reads(other)), time.time()
+                answers[kernel] = list(eng.iter_ids(prompt, SamplingParams(temperature=0.0, max_tokens=6), timeout=600))
+                chunks = [s for s in dispatch_timeline.recent_spans(64) if s["kind"] == "prefill_chunk" and s["t_wall"] >= t0]
+                assert len(chunks) == 3
+                assert all(s[f"latent_chunk_{served}_layers"] == 1 and s[f"latent_chunk_{other}_layers"] == 0 for s in chunks)
+                assert (reads(served), reads(other)) == (before[0] + 3, before[1])
+            finally:
+                eng.shutdown()
+        assert answers["interpret"] == answers["off"] and len(answers["off"]) == 6
+    finally:
+        del m.PRESETS[name]
 
 
 REFUSED = {

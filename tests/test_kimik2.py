@@ -13,6 +13,7 @@ import dataclasses
 import functools
 import inspect
 import math
+import time
 
 import jax
 import jax.numpy as jnp
@@ -359,12 +360,14 @@ def test_registry_resolves_the_family_and_what_it_declares():
     assert (shape.num_layers, shape.num_kv_heads, shape.head_dim, shape.num_heads, shape.bytes_per_token) == (5, 1, 640, 64, 6400)
     assert fam.span_fields(FULL) == {"latent_layers": 5}
     resolved = fam.resolve_kernels(cfg, "compiled")
-    assert resolved == {"grouped_matmul": "compiled"}
+    # (the debug preset's widths do not tile the chip: its chunks read through XLA, tests/test_latent_attention_chunk.py)
+    assert resolved == {"grouped_matmul": "compiled", "latent_chunk": None}
+    assert fam.resolve_kernels(FULL, "compiled") == {"grouped_matmul": "compiled", "latent_chunk": "compiled"}
     for walk in (m.prefill_paged, m.extend_paged, m.decode_paged):
         assert set(resolved) <= set(inspect.signature(walk).parameters)
     assert "page_kernel" in inspect.signature(m.decode_paged).parameters  # the engine's own, which serves the latent read
     assert fam.stat_names == m.STAT_NAMES == ("moe_pairs_held", "moe_pairs_absent", "moe_experts_hit", "moe_experts_held",
-                                              "latent_tokens_read")
+                                              "latent_tokens_read", "latent_chunk_kernel_layers", "latent_chunk_xla_layers")
     # llama declares everything the engine had given it; the five fixed-state families nothing new
     fams = registry.families()
     assert fams["llama"].sharded and fams["llama"].snapshot_pages and fams["llama"].weight_formats == ("int8", "w8a8")
@@ -419,7 +422,7 @@ def test_engine_serves_every_prompt_shape_as_the_references_argmax(engine):
     the plain reference's argmax, through the interpreted kernels.
     Nothing compiles after warm-up; the wave may hold two rows."""
     assert engine._family.name == "kimik2" and engine._paged_kernel == "interpret" and not engine._fixed_state
-    assert engine._family_kernels == {"grouped_matmul": "interpret"}
+    assert engine._family_kernels == {"grouped_matmul": "interpret", "latent_chunk": None}
     assert engine.shapes.max_wave_rows() == 2  # follows prefill_wave_tokens, like llama's
     assert engine._spec_available is False and engine._state_store_rows == 0 and engine._copy_state_fn is None
     rng = np.random.default_rng(1)
@@ -483,6 +486,53 @@ def test_a_prefix_hit_maps_pages_and_the_answer_is_the_cold_engines(engine):
     finally:
         cold.shutdown()
     assert engine._compile_watch.snapshot().get("hot_path_compiles_total", 0) == 0
+
+
+# widths that tile the chip (heads and a latent of whole lane tiles) at a size a CPU serves: the dense and one expert layer
+LANES = dataclasses.replace(
+    CFG, layers_served=(0, 1), num_heads=2, kv_lora_rank=128, qk_nope_head_dim=128, qk_rope_head_dim=64, v_head_dim=128)
+
+
+def test_an_engine_at_widths_that_tile_reads_its_chunks_through_the_kernel():
+    """``ops/latent_attention.py`` ``latent_chunk_read`` interpreted, in
+    the engine's own extend programs: a 150-token prompt in three chunks
+    (the kernel at offsets 0, 64 and 128) is answered as the engine with
+    every kernel off answers it and as the reference does; each chunk's
+    span says both latent layers read through the kernel and the counter
+    grew under ``path="kernel"`` alone, under ``path="xla"`` with it off."""
+    from generativeaiexamples_tpu.engine import dispatch_timeline
+
+    name = "kimik2-lanes-test"
+    m.PRESETS[name] = LANES
+    reads = lambda c, path: c.get(f'genai_engine_latent_chunk_reads_total{{path="{path}"}}', 0.0)  # noqa: E731
+    prompt = [int(t) for t in np.random.default_rng(3).integers(3, 250, size=150)]
+    try:
+        assert registry.resolve(name)[0].resolve_kernels(LANES, "interpret")["latent_chunk"] == "interpret"
+        answers = {}
+        for kernel in ("interpret", "off"):
+            eng = build(model_config_name=name, paged_kernel=kernel, prefix_cache_enable="off")
+            try:
+                assert eng._family_kernels["latent_chunk"] == (None if kernel == "off" else "interpret")
+                before, t0 = counters(), time.time()
+                answers[kernel] = list(eng.iter_ids(prompt, greedy(6), timeout=600))
+                after = counters()
+                chunks = [s for s in dispatch_timeline.recent_spans(64) if s["kind"] == "prefill_chunk" and s["t_wall"] >= t0]
+                served, other = ("kernel", "xla") if kernel == "interpret" else ("xla", "kernel")
+                assert len(chunks) == 3
+                assert all(s[f"latent_chunk_{served}_layers"] == 2 and s[f"latent_chunk_{other}_layers"] == 0 for s in chunks)
+                assert reads(after, served) - reads(before, served) == 6 and reads(after, other) == reads(before, other)
+                steps = [s for s in dispatch_timeline.recent_spans(64) if s["kind"] == "decode" and s["t_wall"] >= t0]
+                assert steps and all(s["latent_chunk_kernel_layers"] == s["latent_chunk_xla_layers"] == 0 for s in steps)
+            finally:
+                eng.shutdown()
+        assert answers["interpret"] == answers["off"] and len(answers["off"]) == 6
+        params = m.init_params_fast(LANES, 0, jnp.float32)
+        cfg = dict(TINY, layers_served=[0, 1], layers=2, num_attention_heads=2, kv_lora_rank=128, qk_nope_head_dim=128,
+                   qk_rope_head_dim=64, v_head_dim=128)
+        ref = reference_logits(params, prompt + answers["interpret"], cfg=cfg)
+        assert max(float(ref[149 + j].max() - ref[149 + j][t]) for j, t in enumerate(answers["interpret"])) < 1e-4
+    finally:
+        del m.PRESETS[name]
 
 
 REFUSED = {

@@ -48,6 +48,10 @@ def _parse() -> argparse.Namespace:
     ap.add_argument("--page", type=int, default=128)
     ap.add_argument("--head-dim", type=int, default=128)
     ap.add_argument("--file", default=None, help="another page_attention.py to compile (a parent's copy)")
+    ap.add_argument("--latent-chunk", type=int, default=0, metavar="T",
+                    help="compile ops/latent_attention.py latent_chunk_read at Kimi-K2.5's widths and a chunk of T "
+                         "queries instead (one row, 192 pages); --heads-a-step 0: the kernel's own rule")
+    ap.add_argument("--heads-a-step", type=int, default=0)
     ap.add_argument("--keep", default=None, help="directory to keep the dump in")
     ap.add_argument("--compile-into", default=None, help=argparse.SUPPRESS)  # the child's job
     return ap.parse_args()
@@ -79,6 +83,17 @@ def _compile(args: argparse.Namespace, dump: str) -> None:
     def s(shape, dtype):
         return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
 
+    if args.latent_chunk:
+        from generativeaiexamples_tpu.ops import latent_attention as la
+
+        H, T, dn, dr, Dv, R, row, page, pmax = 64, args.latent_chunk, 128, 64, 128, 512, 640, args.page, 192
+        bf16 = jnp.bfloat16
+        jax.jit(lambda *a: la.latent_chunk_read(*a, scale=0.1, heads_per_step=args.heads_a_step or None)).lower(
+            s((1, H, T, dn), bf16), s((1, H, T, dr), bf16), s((6145, page, row), bf16), s((1, pmax), jnp.int32),
+            s((1, T), jnp.int32), s((1,), jnp.int32), s((H, dn, R), bf16), s((H, R, Dv), bf16),
+        ).compile()
+        return
+
     hq, hkv = args.heads
     B, pmax, P, page, dh = 64, 32, 577, args.page, args.head_dim
     dtype = jnp.int8 if args.kv == "int8" else jnp.bfloat16
@@ -105,19 +120,20 @@ def main() -> int:
     if args.compile_into:
         _compile(args, args.compile_into)
         return 0
+    kernel = "latent_chunk_read" if args.latent_chunk else "paged_attention"
     if not args.keep:  # ~1,700 files of passes: read, then thrown away
         with tempfile.TemporaryDirectory(prefix="kernel_bundles_") as scratch:
-            return _report(scratch, keep=False)
+            return _report(scratch, False, kernel)
     os.makedirs(args.keep, exist_ok=True)
-    return _report(args.keep, keep=True)
+    return _report(args.keep, True, kernel)
 
 
-def _report(dump: str, keep: bool) -> int:
+def _report(dump: str, keep: bool, kernel: str) -> int:
     child = subprocess.run(
         [sys.executable, "-m", "tools.kernel_bundles", *sys.argv[1:], "--compile-into", dump],
         capture_output=True, text=True,
     )
-    found = sorted(glob.glob(os.path.join(dump, "*paged_attention*final_bundles.txt")))
+    found = sorted(glob.glob(os.path.join(dump, f"*{kernel}*final_bundles.txt")))
     found = [f for f in found if "schedule-analysis" not in f]
     if not found:
         print(child.stderr[-2000:], file=sys.stderr)
