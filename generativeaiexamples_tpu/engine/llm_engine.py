@@ -1085,7 +1085,7 @@ class LLMEngine:
         """
         import jax
 
-        from generativeaiexamples_tpu.ops import page_attention
+        from generativeaiexamples_tpu.ops import latent_attention, page_attention
 
         mode = cfg.paged_kernel
         if mode == "off":
@@ -1141,11 +1141,17 @@ class LLMEngine:
             shards=shards,
         ):
             self._paged_kernel = kind
-            # pages of a row one grid step of paged_attention carries: the
+            # pages of a row one grid step of the read carries: the
             # kernel's own rule over the pool's geometry. A latent pool
             # (bytes_per_token set) is read by ops/latent_attention.py,
-            # one page a step.
-            if kv_shape.bytes_per_token is None:
+            # whose decode walks take the pages a step that ITS rule
+            # names (rows of head_dim columns in the engine's dtype).
+            if kv_shape.bytes_per_token is not None:
+                self._kv_pages_a_step = latent_attention.latent_pages_per_step(
+                    cfg.page_size, kv_shape.head_dim, cfg.dtype,
+                    self._max_pages_per_slot,
+                )
+            else:
                 self._kv_score_rows = page_attention.score_rows(
                     kv_shape.num_heads, kv_shape.num_kv_heads
                 )

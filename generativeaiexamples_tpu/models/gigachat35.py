@@ -53,7 +53,7 @@ import jax.numpy as jnp
 from jax import lax
 
 from generativeaiexamples_tpu.models.glm5next import _mm, _write_rows, moe, rms_norm, swiglu_mlp
-from generativeaiexamples_tpu.ops import delta_rule, latent_attention, page_attention
+from generativeaiexamples_tpu.ops import delta_rule, latent_attention
 
 Params = Dict[str, Any]
 Caches = Dict[str, Any]
@@ -773,7 +773,9 @@ def decode_paged(params: Params, cfg: GigaChat35Config, caches: Caches, tokens, 
     R = cfg.kv_lora_rank
     P = caches["lat"][0].shape[0] if caches["lat"] else 0
     phys = jnp.where(live, jnp.take_along_axis(tables, (positions // page_size)[:, None], axis=1)[:, 0], P)
-    work = page_attention.page_work_list(tables, positions, 1, page_size) if page_kernel else None
+    # one work list a step, the pages a grid step that the kernel's rule names
+    work = (latent_attention.decode_work_list(caches["lat"][0], tables, positions)
+            if page_kernel and caches["lat"] else None)
 
     x = params["embed"][tokens].astype(jnp.float32)  # [B, D]
     new = {k: list(v) if isinstance(v, list) else v for k, v in caches.items()}

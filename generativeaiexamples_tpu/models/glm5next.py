@@ -55,7 +55,7 @@ from jax import lax
 
 from generativeaiexamples_tpu.ops import delta_rule
 from generativeaiexamples_tpu.ops import grouped_matmul as expert_ops
-from generativeaiexamples_tpu.ops import latent_attention, page_attention
+from generativeaiexamples_tpu.ops import latent_attention
 
 Params = Dict[str, Any]
 Caches = Dict[str, Any]
@@ -826,7 +826,9 @@ def decode_paged(params: Params, cfg: Glm5NextConfig, caches: Caches, tokens, po
     kp = cfg.index_kpool
     P = caches["lat"][0].shape[0] if caches["lat"] else 0
     phys = jnp.where(live, jnp.take_along_axis(tables, (positions // page_size)[:, None], axis=1)[:, 0], P)
-    work = page_attention.page_work_list(tables, positions, 1, page_size) if page_kernel else None
+    # one work list a step, the pages a grid step that the kernel's rule names
+    work = (latent_attention.decode_work_list(caches["lat"][0], tables, positions)
+            if page_kernel and caches["lat"] else None)
 
     X = _embed_streams(params, cfg, tokens)  # [B, n, D]
     new = {k: list(v) if isinstance(v, list) else v for k, v in caches.items()}

@@ -45,7 +45,7 @@ from generativeaiexamples_tpu.models.gigachat35 import (
     yarn_mscale,
 )
 from generativeaiexamples_tpu.models.glm5next import _mm, _write_rows, moe, rms_norm, swiglu_mlp
-from generativeaiexamples_tpu.ops import latent_attention, page_attention
+from generativeaiexamples_tpu.ops import latent_attention
 
 Params = Dict[str, Any]
 Caches = Dict[str, Any]
@@ -365,8 +365,8 @@ def decode_paged(params: Params, cfg: KimiK2Config, caches: Caches, tokens, posi
     del window, latent_chunk  # a step reads absorbed: no chunk read
     P = caches["lat"][0].shape[0]
     phys = jnp.where(live, jnp.take_along_axis(tables, (positions // page_size)[:, None], axis=1)[:, 0], P)
-    # one work list a step: every layer walks the same pages
-    work = page_attention.page_work_list(tables, positions, 1, page_size) if page_kernel else None
+    # one work list a step, the pages a grid step that the kernel's rule names: every layer walks the same pages
+    work = latent_attention.decode_work_list(caches["lat"][0], tables, positions) if page_kernel else None
 
     x = params["embed"][tokens].astype(jnp.float32)  # [B, D]
     new = dict(caches, lat=list(caches["lat"]))

@@ -9,21 +9,34 @@ are the same bytes.
 
 Which cached tokens a query may read is decided outside (a learned
 indexer's top-k groups plus the open tail, models/glm5next.py) and
-arrives as an additive float32 ``bias`` ``[B * Pmax, 1, page]`` (0 or
--1e30; causality is folded in). The walk itself is
-``ops/page_attention.py``'s: the flat work list of live (row, page)
-pairs (``page_work_list``), running softmax state in VMEM scratch,
-reset at a row's first page and normalised into the row's output at its
-last. At the contexts one chip serves (<= 8k) reading every live page
-and masking costs less than a gather of two thousand 4 KB slabs a row:
-the selection saves score columns here, not page fetches (PERF.md).
+arrives as an additive float32 ``bias`` ``[B, Pmax * page]`` (0 or
+-1e30; causality is folded in). The walk is ``ops/page_attention.py``'s
+flat work list of each row's live pages (``page_work_list``), N
+CONSECUTIVE pages of a row a grid step (``latent_pages_per_step``, from
+the shapes; ``decode_work_list`` builds the list a step's layers share):
+each place of a step is its own block operand of the pool, a dead place
+(past the row's last live page) repeats the page its place last held so
+that nothing is fetched for it, and the step's bias is one ``[1, 1, N *
+page]`` block. The N pages are scored into ONE ``[H, N * page]`` float32
+tile: one running max, one exponential pass, one row sum and one rescale
+of the running ``[H, value]`` sum a STEP, N value products; the running
+softmax state lives in VMEM scratch, reset at a row's first step and
+normalised into the row's output at its last. One page a step was one
+dependent chain with nothing beside it to fill the issue slots, and paid
+a step's fixed cost (~0.4 us before its bytes arrive: one step's fetches
+are in flight at a time) for 0.2 us of bytes: 0.64 us a page at one page
+a step, 0.29 at eight (PERF.md section 6, PR 51). At the contexts one
+chip serves (<= 8k) reading every live page and masking costs less than
+a gather of two thousand 4 KB slabs a row: the selection saves score
+columns here, not page fetches (PERF.md).
 
-``dense_latent_attention`` is the second entry point over the same walk,
-for a latent layer that carries a decoupled RoPE key and selects nothing
-(models/gigachat35.py): the cached row is ``[c | k_rope | padding]``,
-WIDER than the value, which is its first ``value_dim`` columns; every
-cached token up to the query's own position is read, so causality comes
-from the positions the kernel already prefetches and no bias is built.
+``dense_latent_attention`` is the second entry point over the same walk
+and the same kernel body, for a latent layer that carries a decoupled
+RoPE key and selects nothing (models/gigachat35.py, models/kimik2.py):
+the cached row is ``[c | k_rope | padding]``, WIDER than the value,
+which is its first ``value_dim`` columns; every cached token up to the
+query's own position is read, so causality comes from the positions the
+kernel already prefetches and no bias is built.
 
 ``latent_chunk_read`` is the third, for the CHUNK walk of the same
 layers (models/gigachat35.py ``_attend_expanded``, which
@@ -57,35 +70,136 @@ _LANE = 128
 _NEG_INF = -1e30
 
 
-def _kernel(row_ref, page_ref, phys_ref, pos_ref, q_ref, c_ref, b_ref, o_ref, m_ref, l_ref, acc_ref,
-            *, scale: float, page: int):
+# what the pages of one decode step may hold in VMEM, one buffer of the pipeline's two
+_DECODE_STEP_BYTES = 2 << 20
+# pages a decode step walks where the bytes and the table allow. Measured on the chip, kernel alone, rows of
+# 512-640 bfloat16 columns (PERF.md section 6, PR 51): a step costs ~0.42 us before its bytes arrive and 0.2 us a
+# page, so 0.64 / 0.43 / 0.32 / 0.29 / 0.29 us a live page at 1 / 2 / 4 / 8 / 16 over contexts of 1-20 k, and
+# 1.50 / 1.39 / 1.31 / 1.34 / 1.77 over contexts of 1-8 pages, where a step's dead places still cost their arithmetic
+_DECODE_STEP_PAGES = 8
+
+
+def latent_pages_per_step(page_size: int, row_width: int, dtype, max_pages: int) -> int:
+    """Pages of one row a grid step of ``latent_attention`` /
+    ``dense_latent_attention`` walks (``N``), from what is static: the
+    page's bytes (a page of 128 rows of 640 bfloat16 columns is 163,840
+    B; eight of them twice over, the pipeline's two buffers, are 2.6 MB
+    of the scoped VMEM's 16) and the table's width, which N divides so
+    that a step's bias is one block (``latent_attention``). The model
+    files build their work list with it (``decode_work_list``) and the
+    engine counts its decode spans' ``kv_page_steps`` with it."""
+    page_bytes = page_size * row_width * jnp.dtype(dtype).itemsize
+    n = _DECODE_STEP_PAGES
+    while n > 1 and (max_pages % n or n * page_bytes > _DECODE_STEP_BYTES):
+        n //= 2
+    return n
+
+
+def decode_work_list(pool, tables, positions) -> PageWork:
+    """The list both decode reads walk over ``pool`` [P, page, W]: each
+    row's live pages up to its query's position, ``latent_pages_per_step``
+    consecutive pages an item (``ops/page_attention.py``
+    ``page_work_list``: a dead row keeps one step, a dead place repeats
+    the page its place last held). A step builds it once and every
+    layer's read shares it."""
+    _, page, W = pool.shape
+    return page_work_list(tables, positions.astype(jnp.int32), 1, page,
+                          latent_pages_per_step(page, W, pool.dtype, tables.shape[1]))
+
+
+def _decode_kernel(row_ref, first_ref, phys_ref, pos_ref, q_ref, *rest, scale: float, page: int, n: int,
+                   value_dim: int, biased: bool):
+    """One step of the decode walk: the ``n`` consecutive pages
+    ``first_ref[i] ..`` of row ``row_ref[i]``, scored into ONE ``[H, n *
+    page]`` tile: one running max, one exponential pass, one row sum and
+    one rescale of the running sum a step, ``n`` value products.
+    ``biased``: what a query may read arrives as an additive bias block
+    (a page may hold nothing selected); else every position up to the
+    query's own is read, and a step always holds one (its first page is
+    live, a dead row's holds position 0)."""
     del phys_ref
+    pages, b_ref, (o_ref, m_ref, l_ref, acc_ref) = rest[:n], rest[n] if biased else None, rest[-4:]
     i = pl.program_id(0)
-    j = page_ref[i]
+    first = first_ref[i]
     last_tok = pos_ref[row_ref[i]]
 
-    @pl.when(j == 0)
+    @pl.when(first == 0)
     def _init():
         m_ref[...] = jnp.full_like(m_ref, _NEG_INF)
         l_ref[...] = jnp.zeros_like(l_ref)
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
-    c = c_ref[0]  # [page, R]: key and value
-    sc = lax.dot_general(q_ref[0], c, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32)
-    sc = sc * scale + b_ref[0]  # [H, page] + [1, page]
+    q = q_ref[0]
+    # a place's page [page, W] is the key; its first value_dim columns are the value
+    sc = jnp.concatenate([lax.dot_general(q, p[0], (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32)
+                          for p in pages], axis=1) * scale  # [H, n * page]
+    if biased:
+        sc = sc + b_ref[0]  # [1, n * page]: 0 or -1e30, causality and the dead places folded in
+    else:
+        sc = jnp.where(first * page + lax.broadcasted_iota(jnp.int32, sc.shape, 1) <= last_tok, sc, _NEG_INF)
     m_prev = m_ref[:, :1]
     m_new = jnp.maximum(m_prev, jnp.max(sc, axis=1, keepdims=True))
-    # a page with nothing selected leaves m at -1e30: exp(sc - m) would be 1
-    prob = jnp.where(sc > 0.5 * _NEG_INF, jnp.exp(sc - m_new), 0.0)
+    prob = jnp.exp(sc - m_new)
+    if biased:
+        # a step with nothing selected leaves m at -1e30: exp(sc - m) would be 1
+        prob = jnp.where(sc > 0.5 * _NEG_INF, prob, 0.0)
     alpha = jnp.exp(m_prev - m_new)
     l_ref[...] = jnp.broadcast_to(alpha * l_ref[:, :1] + jnp.sum(prob, axis=1, keepdims=True), l_ref.shape)
     m_ref[...] = jnp.broadcast_to(m_new, m_ref.shape)
-    acc_ref[...] = acc_ref[...] * alpha + jnp.dot(prob.astype(c.dtype), c, preferred_element_type=jnp.float32)
+    prob = prob.astype(pages[0].dtype)  # rounded to the pool's dtype before the value product
+    acc_ref[...] = acc_ref[...] * alpha + sum(
+        jnp.dot(prob[:, k * page:(k + 1) * page], p[0][:, :value_dim], preferred_element_type=jnp.float32)
+        for k, p in enumerate(pages))
 
-    @pl.when((j + 1) * page > last_tok)
+    @pl.when((first + n) * page > last_tok)
     def _finish():
         l = l_ref[:, :1]
         o_ref[0] = (acc_ref[...] / jnp.where(l == 0.0, 1.0, l)).astype(o_ref.dtype)
+
+
+def _decode_walk(q, pool, bias, tables, positions, work: Optional[PageWork], *, value_dim: int, scale: float,
+                 interpret: bool, name: str):
+    """Both decode entry points: ``q`` [B, H, W] against ``pool`` [P, page,
+    W] over ``work`` (``decode_work_list`` where not given; its own
+    shapes say how many pages a step it carries)."""
+    B, H, W = q.shape
+    P, page, _ = pool.shape
+    Pmax = tables.shape[1]
+    pos = positions.astype(jnp.int32)
+    if work is None:
+        work = decode_work_list(pool, tables, pos)
+    n = work.phys.shape[0] // work.row.shape[0]
+    in_specs = [pl.BlockSpec((1, H, W), lambda i, row, first, phys, pos: (row[i], 0, 0))]
+    operands = [q]
+    # the pool once a place of the step: Pallas's own pipeline double-buffers the n pages, and a dead place
+    # names the page its place last held, so nothing is fetched for it
+    in_specs += [pl.BlockSpec((1, page, W), lambda i, row, first, phys, pos, k=k: (phys[i * n + k], 0, 0))
+                 for k in range(n)]
+    operands += [pool] * n
+    if bias is not None:
+        assert Pmax % n == 0, (Pmax, n)  # a step's bias is one block
+        in_specs.append(pl.BlockSpec(
+            (1, 1, n * page), lambda i, row, first, phys, pos: (row[i] * (Pmax // n) + first[i] // n, 0, 0)))
+        operands.append(bias.reshape(B * Pmax // n, 1, n * page))
+    return pl.pallas_call(
+        functools.partial(_decode_kernel, scale=scale, page=page, n=n, value_dim=value_dim, biased=bias is not None),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=4,
+            grid=(work.n_work[0],),
+            in_specs=in_specs,
+            out_specs=pl.BlockSpec((1, H, value_dim), lambda i, row, first, phys, pos: (row[i], 0, 0)),
+            scratch_shapes=[
+                pltpu.VMEM((H, _LANE), jnp.float32),
+                pltpu.VMEM((H, _LANE), jnp.float32),
+                pltpu.VMEM((H, value_dim), jnp.float32),
+            ],
+        ),
+        out_shape=jax.ShapeDtypeStruct((B, H, value_dim), jnp.float32),
+        compiler_params=pltpu.CompilerParams(dimension_semantics=("arbitrary",)),
+        interpret=interpret,
+        # the benchmark's readers find both kernels by "latent_attention" in the operation's name
+        name=name,
+    )(work.row, work.page, work.phys, pos, *operands)
 
 
 @functools.partial(jax.jit, static_argnames=("scale", "interpret"))
@@ -95,68 +209,8 @@ def latent_attention(q, pool, bias, tables, positions, *, scale: float, interpre
     bias [B, Pmax * page] float32, tables [B, Pmax], positions [B]
     (the query's own position; its row is already in the pool).
     Returns sum_s softmax_s(q . c_s * scale + bias_s) c_s: [B, H, R] float32."""
-    B, H, R = q.shape
-    P, page, _ = pool.shape
-    Pmax = tables.shape[1]
-    pos = positions.astype(jnp.int32)
-    if work is None:
-        work = page_work_list(tables, pos, 1, page)
-    bias3 = bias.reshape(B * Pmax, 1, page)
-    row_spec = pl.BlockSpec((1, H, R), lambda i, row, pg, phys, pos: (row[i], 0, 0))
-    return pl.pallas_call(
-        functools.partial(_kernel, scale=scale, page=page),
-        grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=4,
-            grid=(work.n_work[0],),
-            in_specs=[
-                row_spec,
-                pl.BlockSpec((1, page, R), lambda i, row, pg, phys, pos: (phys[i], 0, 0)),
-                pl.BlockSpec((1, 1, page), lambda i, row, pg, phys, pos: (row[i] * Pmax + pg[i], 0, 0)),
-            ],
-            out_specs=row_spec,
-            scratch_shapes=[
-                pltpu.VMEM((H, _LANE), jnp.float32),
-                pltpu.VMEM((H, _LANE), jnp.float32),
-                pltpu.VMEM((H, R), jnp.float32),
-            ],
-        ),
-        out_shape=jax.ShapeDtypeStruct((B, H, R), jnp.float32),
-        compiler_params=pltpu.CompilerParams(dimension_semantics=("arbitrary",)),
-        interpret=interpret,
-        name="latent_attention",
-    )(work.row, work.page, work.phys, pos, q, pool, bias3)
-
-
-def _dense_kernel(row_ref, page_ref, phys_ref, pos_ref, q_ref, c_ref, o_ref, m_ref, l_ref, acc_ref,
-                  *, scale: float, page: int, value_dim: int):
-    del phys_ref
-    i = pl.program_id(0)
-    j = page_ref[i]
-    last_tok = pos_ref[row_ref[i]]
-
-    @pl.when(j == 0)
-    def _init():
-        m_ref[...] = jnp.full_like(m_ref, _NEG_INF)
-        l_ref[...] = jnp.zeros_like(l_ref)
-        acc_ref[...] = jnp.zeros_like(acc_ref)
-
-    c = c_ref[0]  # [page, W]: the key; its first value_dim columns are the value
-    sc = lax.dot_general(q_ref[0], c, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32) * scale
-    ok = j * page + lax.broadcasted_iota(jnp.int32, sc.shape, 1) <= last_tok
-    sc = jnp.where(ok, sc, _NEG_INF)
-    m_prev = m_ref[:, :1]
-    m_new = jnp.maximum(m_prev, jnp.max(sc, axis=1, keepdims=True))
-    prob = jnp.where(ok, jnp.exp(sc - m_new), 0.0)
-    alpha = jnp.exp(m_prev - m_new)
-    l_ref[...] = jnp.broadcast_to(alpha * l_ref[:, :1] + jnp.sum(prob, axis=1, keepdims=True), l_ref.shape)
-    m_ref[...] = jnp.broadcast_to(m_new, m_ref.shape)
-    acc_ref[...] = acc_ref[...] * alpha + jnp.dot(
-        prob.astype(c.dtype), c[:, :value_dim], preferred_element_type=jnp.float32)
-
-    @pl.when((j + 1) * page > last_tok)
-    def _finish():
-        l = l_ref[:, :1]
-        o_ref[0] = (acc_ref[...] / jnp.where(l == 0.0, 1.0, l)).astype(o_ref.dtype)
+    return _decode_walk(q, pool, bias, tables, positions, work, value_dim=q.shape[2], scale=scale,
+                        interpret=interpret, name="latent_attention")
 
 
 @functools.partial(jax.jit, static_argnames=("value_dim", "scale", "interpret"))
@@ -168,32 +222,8 @@ def dense_latent_attention(q, pool, tables, positions, *, value_dim: int, scale:
     in the pool). Returns ``sum_s softmax_s(q . row_s * scale) c_s`` over
     every s <= position, c_s the row's first ``value_dim`` columns:
     [B, H, value_dim] float32."""
-    B, H, W = q.shape
-    P, page, _ = pool.shape
-    pos = positions.astype(jnp.int32)
-    if work is None:
-        work = page_work_list(tables, pos, 1, page)
-    return pl.pallas_call(
-        functools.partial(_dense_kernel, scale=scale, page=page, value_dim=value_dim),
-        grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=4,
-            grid=(work.n_work[0],),
-            in_specs=[
-                pl.BlockSpec((1, H, W), lambda i, row, pg, phys, pos: (row[i], 0, 0)),
-                pl.BlockSpec((1, page, W), lambda i, row, pg, phys, pos: (phys[i], 0, 0)),
-            ],
-            out_specs=pl.BlockSpec((1, H, value_dim), lambda i, row, pg, phys, pos: (row[i], 0, 0)),
-            scratch_shapes=[
-                pltpu.VMEM((H, _LANE), jnp.float32),
-                pltpu.VMEM((H, _LANE), jnp.float32),
-                pltpu.VMEM((H, value_dim), jnp.float32),
-            ],
-        ),
-        out_shape=jax.ShapeDtypeStruct((B, H, value_dim), jnp.float32),
-        compiler_params=pltpu.CompilerParams(dimension_semantics=("arbitrary",)),
-        interpret=interpret,
-        name="latent_attention_dense",
-    )(work.row, work.page, work.phys, pos, q, pool)
+    return _decode_walk(q, pool, None, tables, positions, work, value_dim=value_dim, scale=scale,
+                        interpret=interpret, name="latent_attention_dense")
 
 
 # --------------------------------------------------------------------- //

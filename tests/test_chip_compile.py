@@ -35,6 +35,8 @@ from generativeaiexamples_tpu.ops import (
 HIDDEN, MLP, VOCAB = 4096, 14336, 128256
 HQ, HKV, DH = 32, 8, 128
 PAGE = 128
+# pages of a row a grid step of the decode-side latent reads walks at every cell's shapes (PR 51)
+LATENT_PAGES_A_STEP = 8
 
 
 @pytest.fixture(scope="module")
@@ -202,22 +204,44 @@ def test_flash_attention_compiles(one_chip, no_persistent_cache, T):
     assert "tpu_custom_call" in text
 
 
-def test_dense_latent_attention_compiles_with_a_key_wider_than_the_value(one_chip, no_persistent_cache):
-    """The decode-side latent read of models/gigachat35.py at its published
-    widths: 64 slots x 64 heads against rows of [c 512 | k_rope 64] padded
-    to 640 columns, the value the first 512 (a slice on a lane tile)."""
-    B, H, W, R, pages = 64, 64, 640, 512, 64
+# the decode-side latent reads at the three cells' shapes: (rows, table pages, row columns, value columns, bias)
+LATENT_DECODE_CELLS = {
+    "gigachat35": (64, 64, 640, 512, False),
+    "kimik25": (32, 192, 640, 512, False),
+    "glm53flash": (64, 64, 512, 512, True),
+}
+
+
+@pytest.mark.parametrize("cell", sorted(LATENT_DECODE_CELLS))
+def test_dense_latent_attention_compiles_with_a_key_wider_than_the_value(one_chip, no_persistent_cache, cell):
+    """The decode-side latent reads at the published widths, the pages a
+    grid step that ``latent_pages_per_step`` names for each cell's shapes
+    (every place of a step its own block operand): models/gigachat35.py
+    and models/kimik2.py, 64 heads against rows of [c 512 | k_rope 64]
+    padded to 640 columns, the value the first 512 (a slice on a lane
+    tile); models/glm5next.py, rows of 512 read whole under a bias that
+    is one block a step."""
+    B, pages, W, R, biased = LATENT_DECODE_CELLS[cell]
+    H = 64
     assert page_attention.supports_geometry(PAGE, W, H, 1) and not page_attention.supports_geometry(PAGE, 576, H, 1)
+    n = latent_attention.latent_pages_per_step(PAGE, W, jnp.bfloat16, pages)
+    assert n == LATENT_PAGES_A_STEP and pages % n == 0
 
     def s(shape, dtype):
         return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
 
-    def read(q, pool, tables, positions):
+    def read(q, pool, tables, positions, *bias):
+        if bias:
+            return latent_attention.latent_attention(q, pool, *bias, tables, positions, scale=0.0625)
         return latent_attention.dense_latent_attention(q, pool, tables, positions, value_dim=R, scale=0.1053)
 
-    text = _compiled_text(read, s((B, H, W), jnp.bfloat16), s((B * pages + 1, PAGE, W), jnp.bfloat16),
-                          s((B, pages), jnp.int32), s((B,), jnp.int32))
-    assert "tpu_custom_call" in text
+    compiled = jax.jit(read).lower(
+        s((B, H, W), jnp.bfloat16), s((B * pages + 1, PAGE, W), jnp.bfloat16), s((B, pages), jnp.int32),
+        s((B,), jnp.int32), *([s((B, pages * PAGE), jnp.float32)] if biased else [])).compile()
+    text = compiled.as_text()
+    assert "tpu_custom_call" in text and ("latent_attention" if biased else "latent_attention_dense") in text
+    # the work list and its intermediates, nothing of the pool's size (0.67 GB), beside the arguments
+    assert compiled.memory_analysis().temp_size_in_bytes < 16 << 20
 
 
 @pytest.mark.parametrize("rows, T", [(1, 512), (1, 128), (4, 512)], ids=["chunk-512", "chunk-128", "four-rows"])

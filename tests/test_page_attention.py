@@ -489,9 +489,11 @@ def test_dead_places_of_a_group_are_never_read(n, planes):
     np.testing.assert_array_equal(np.asarray(got, np.float32), np.asarray(want, np.float32))
 
 
-def test_the_list_a_latent_read_builds_is_one_page_an_item():
-    """``ops/latent_attention.py`` builds its list without naming N: it
-    is the list of PR 27, one page an item, three maps of one length."""
+def test_the_list_built_without_naming_n_is_one_page_an_item_and_a_latent_read_names_its_own():
+    """A list built without naming N is the list of PR 27, one page an
+    item, three maps of one length; ``ops/latent_attention.py`` builds
+    the same function's list at the N its own rule names
+    (``decode_work_list``, PR 51)."""
     from generativeaiexamples_tpu.ops import latent_attention
 
     pos = jnp.asarray(WALK_CASES["dead_row_between_live_rows"], jnp.int32)
@@ -505,6 +507,10 @@ def test_the_list_a_latent_read_builds_is_one_page_an_item():
         np.asarray(tables)[np.asarray(work.row)[:n], np.asarray(work.page)[:n]],
     )
     for a, b in zip(work, pa.page_work_list(tables, pos, 1, PAGE, group=1)):
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+    pool = jnp.zeros((2, PAGE, 128), jnp.bfloat16)
+    n = latent_attention.latent_pages_per_step(PAGE, 128, pool.dtype, PMAX)
+    for a, b in zip(latent_attention.decode_work_list(pool, tables, pos), pa.page_work_list(tables, pos, 1, PAGE, n)):
         np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
 
 

@@ -52,6 +52,13 @@ def _parse() -> argparse.Namespace:
                     help="compile ops/latent_attention.py latent_chunk_read at Kimi-K2.5's widths and a chunk of T "
                          "queries instead (one row, 192 pages); --heads-a-step 0: the kernel's own rule")
     ap.add_argument("--heads-a-step", type=int, default=0)
+    ap.add_argument("--latent-decode", type=int, default=0, metavar="H",
+                    help="compile ops/latent_attention.py dense_latent_attention instead, H heads over rows of 640 "
+                         "columns whose first 512 are the value (Kimi-K2.5's and GigaChat3.5's: 32 rows x 192 pages); "
+                         "--pages-a-step 0: the kernel's own rule")
+    ap.add_argument("--bias", action="store_true",
+                    help="with --latent-decode: latent_attention, rows of 512 columns and a bias (GLM-5.3-Flash's: "
+                         "64 rows x 64 pages)")
     ap.add_argument("--keep", default=None, help="directory to keep the dump in")
     ap.add_argument("--compile-into", default=None, help=argparse.SUPPRESS)  # the child's job
     return ap.parse_args()
@@ -94,6 +101,24 @@ def _compile(args: argparse.Namespace, dump: str) -> None:
         ).compile()
         return
 
+    if args.latent_decode:
+        from generativeaiexamples_tpu.ops import latent_attention as la
+
+        H, page, bf16 = args.latent_decode, args.page, jnp.bfloat16
+        B, pmax, W = (64, 64, 512) if args.bias else (32, 192, 640)
+
+        def read(q, pool, tables, pos, *bias):
+            work = pa.page_work_list(tables, pos, 1, page, args.pages_a_step) if args.pages_a_step else None
+            if bias:
+                return la.latent_attention(q, pool, *bias, tables, pos, scale=0.1, work=work)
+            return la.dense_latent_attention(q, pool, tables, pos, value_dim=512, scale=0.1, work=work)
+
+        jax.jit(read).lower(
+            s((B, H, W), bf16), s((B * pmax + 1, page, W), bf16), s((B, pmax), jnp.int32), s((B,), jnp.int32),
+            *([s((B, pmax * page), jnp.float32)] if args.bias else []),
+        ).compile()
+        return
+
     hq, hkv = args.heads
     B, pmax, P, page, dh = 64, 32, 577, args.page, args.head_dim
     dtype = jnp.int8 if args.kv == "int8" else jnp.bfloat16
@@ -120,7 +145,8 @@ def main() -> int:
     if args.compile_into:
         _compile(args, args.compile_into)
         return 0
-    kernel = "latent_chunk_read" if args.latent_chunk else "paged_attention"
+    kernel = ("latent_chunk_read" if args.latent_chunk else "latent_attention" if args.latent_decode
+              else "paged_attention")
     if not args.keep:  # ~1,700 files of passes: read, then thrown away
         with tempfile.TemporaryDirectory(prefix="kernel_bundles_") as scratch:
             return _report(scratch, False, kernel)
