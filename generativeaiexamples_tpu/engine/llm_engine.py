@@ -306,6 +306,14 @@ _M_LATENT_CHUNK_READS = _REG.counter(
     "latent_chunk_kernel_layers; the kernel's share is its engagement.",
     ("path",),
 )
+_M_MSA_CHUNK_READS = _REG.counter(
+    "genai_engine_msa_chunk_reads_total",
+    "Block-sparse attention layers an extend dispatch read, by path: "
+    "kernel (ops/selected_chunk_read.py: scores, masks and "
+    "probabilities stay in VMEM) or xla (the block loop). Beside the "
+    "spans' msa_chunk_kernel_layers; the kernel's share is its engagement.",
+    ("path",),
+)
 _M_WINDOW_READ = _REG.counter(
     "genai_engine_window_read_tokens_total",
     "Ring rows the window-attention layers read for their queries "
@@ -336,6 +344,8 @@ _STAT_COUNTERS = {
     "msa_pages_live": _M_MSA_LIVE,
     "msa_blocks_scored": _M_MSA_SCORED,
     "msa_pages_pooled": _M_MSA_POOLED,
+    "msa_chunk_kernel_layers": _M_MSA_CHUNK_READS.labels(path="kernel"),
+    "msa_chunk_xla_layers": _M_MSA_CHUNK_READS.labels(path="xla"),
 }
 _M_SSM_DISPATCHES = _REG.counter(
     "genai_engine_ssm_dispatches_total",

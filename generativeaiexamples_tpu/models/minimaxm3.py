@@ -43,11 +43,19 @@ summaries, turns the selection into a work list of (row, KV head, page)
 and FETCHES ONLY THOSE (``ops/page_attention.py``
 ``selected_page_attention``; with no kernel resolved, an XLA gather of
 the same strips). The chunk walk computes the same selection for each
-of its queries and applies it as a mask over a walk of the row's pages
-in blocks of four, skipping a block of pages no query of the chunk
-selected: every query's key set is the equations', but the bytes and
-the products are a dense walk's wherever the chunk's queries between
-them cover the context (PERF.md: where a later kernel starts).
+of its queries and applies it as a MASK over a walk of the row's live
+pages: every query's key set is the equations', but hundreds of queries
+between them select nearly every page, so the bytes and the products
+are a dense walk's (PERF.md section 7: a gather a query wins only past
+~25 k of context). With ``selected_chunk`` resolved
+(``selected_chunk_kind``: a head and a page of whole lane tiles, a chunk
+that cuts into query tiles) the walk is ``ops/selected_chunk_read.py``:
+one Pallas kernel over a run-time list of live (row, query tile, block
+of four pages) items, the scores, the mask and the probabilities in
+VMEM, an item no query of the tile selected skipped. Otherwise
+``_attend_selected_blocks``, an XLA loop over blocks of four pages that
+skips a block no query of the CHUNK selected (the CPU path, widths the
+kernel declines, what the tests hold the kernel to).
 
 ``stats`` is a handful of int32 counts of the last walk.
 """
@@ -64,7 +72,7 @@ from jax import lax
 from generativeaiexamples_tpu.models.afmoe import _draw, _gqa, _heads_first, rope
 from generativeaiexamples_tpu.models.glm5next import _mm, moe, rms_norm, swiglu_mlp
 from generativeaiexamples_tpu.models.phi4flash import _write_rows
-from generativeaiexamples_tpu.ops import page_attention
+from generativeaiexamples_tpu.ops import page_attention, selected_chunk_read
 
 Params = Dict[str, Any]
 Caches = Dict[str, Any]
@@ -72,7 +80,10 @@ _HI = lax.Precision.HIGHEST
 _NEG = -1e30
 
 STAT_NAMES = ("moe_pairs_held", "moe_pairs_absent", "moe_experts_hit", "moe_experts_held",
-              "msa_pages_selected", "msa_pages_live", "msa_blocks_scored", "msa_pages_pooled")
+              "msa_pages_selected", "msa_pages_live", "msa_blocks_scored", "msa_pages_pooled",
+              # the chunk walk's read: layers by path, and of the kernel's (KV head, tile, block) items those
+              # whose block some query of the tile selected, summed over the layers (a decode step: zeros)
+              "msa_chunk_kernel_layers", "msa_chunk_xla_layers", "msa_chunk_blocks_read", "msa_chunk_blocks_live")
 
 
 @dataclasses.dataclass(frozen=True)
@@ -437,7 +448,7 @@ def _selection_stats(valid, positions, counted, done, cfg: MiniMaxM3Config):
 
 def _chunk_walk(params: Params, cfg: MiniMaxM3Config, caches: Caches, tokens, offsets, valid, slots,
                 tables, page_size: int, grouped_matmul: Optional[str] = None,
-                capture: Optional[Dict[str, Any]] = None):
+                capture: Optional[Dict[str, Any]] = None, selected_chunk: Optional[str] = None):
     """All layers over a chunk [N, C] per row; returns (the residual row
     of each row's last valid position [N, D], caches).
 
@@ -449,7 +460,9 @@ def _chunk_walk(params: Params, cfg: MiniMaxM3Config, caches: Caches, tokens, of
     (from the keys as cached), THEN scores its queries: a block the
     chunk completes is a candidate for the chunk's later queries.
     ``capture`` (a dict) receives every layer's selection, ``pages`` /
-    ``valid`` [L, N, C, Hk, K]."""
+    ``valid`` [L, N, C, Hk, K]. ``selected_chunk`` ('compiled' /
+    'interpret') reads through ``ops/selected_chunk_read.py`` where the
+    shapes tile (``selected_chunk_kind``), else the XLA loop does."""
     _check_page(cfg, page_size)
     N, C = tokens.shape
     Pmax = tables.shape[1]
@@ -470,12 +483,16 @@ def _chunk_walk(params: Params, cfg: MiniMaxM3Config, caches: Caches, tokens, of
     ends = (cand + 1) * page_size
     done = (ends > offsets[:, None]) & (ends <= n_tokens[:, None])
     done_phys = jnp.take_along_axis(row_tables, jnp.minimum(cand, Pmax - 1), axis=1)
+    selected_chunk = selected_chunk_kind(cfg, selected_chunk, C)
+    # the kernel's list of live (row, query tile, block) items: once a walk, every layer's read shares it
+    work = selected_chunk_read.chunk_work_list(row_tables, positions, n_tokens, page_size, P) if selected_chunk else None
 
     x = params["embed"][tokens].astype(jnp.float32)  # [N, C, D]
     dtype = params["embed"].dtype
     new = dict(caches, kv=list(caches["kv"]))
     moe_stats = jnp.zeros((4,), jnp.int32)
     msa_stats = jnp.zeros((4,), jnp.int32)
+    blocks_read = jnp.zeros((), jnp.int32)
     kept = []
     for l, mlp in enumerate(cfg.layers):
         lp = params["layers"][l]
@@ -489,7 +506,14 @@ def _chunk_walk(params: Params, cfg: MiniMaxM3Config, caches: Caches, tokens, of
             with jax.named_scope("msa_index"):
                 sel_pages, sel_valid = select_pages(block_scores(ix, pool["kmax"], row_tables), positions, cfg)
             kept.append((sel_pages, sel_valid))
-            o = _attend_selected_blocks(q, pool, row_tables, positions, n_tokens, sel_pages, sel_valid)
+            if selected_chunk:
+                src, n_read = selected_chunk_read.chunk_live_steps(work, sel_pages, sel_valid)
+                blocks_read = blocks_read + n_read
+                o = selected_chunk_read.selected_chunk_read(
+                    q, pool["k"], pool["v"], positions, sel_pages, sel_valid, work, src,
+                    interpret=(selected_chunk == "interpret"))
+            else:
+                o = _attend_selected_blocks(q, pool, row_tables, positions, n_tokens, sel_pages, sel_valid)
             x = x + _mm(o.reshape(N, C, -1), lp["wo"])
             msa_stats = msa_stats + _selection_stats(sel_valid, positions, tok_valid, done, cfg)
         x, stats = mlp_sublayer(x, lp, mlp, cfg, tok_valid, grouped_matmul)
@@ -497,26 +521,32 @@ def _chunk_walk(params: Params, cfg: MiniMaxM3Config, caches: Caches, tokens, of
             moe_stats = moe_stats + stats
     if capture is not None:
         capture.update(pages=jnp.stack([p for p, _ in kept]), valid=jnp.stack([v for _, v in kept]))
-    new["stats"] = jnp.concatenate([moe_stats, msa_stats]).astype(jnp.int32)
+    L = cfg.num_layers
+    if selected_chunk:
+        chunk_stats = jnp.stack([L, 0, blocks_read, L * cfg.num_kv_heads * work.n_work[0]])
+    else:
+        chunk_stats = jnp.asarray([0, L, 0, 0])
+    new["stats"] = jnp.concatenate([moe_stats, msa_stats, chunk_stats]).astype(jnp.int32)
     return jnp.take_along_axis(x, last[:, None, None], axis=1)[:, 0], new
 
 
 def prefill_paged(params: Params, cfg: MiniMaxM3Config, caches: Caches, tokens, lengths, slots, tables,
-                  page_size: int, grouped_matmul: Optional[str] = None, **_paths):
+                  page_size: int, grouped_matmul: Optional[str] = None, selected_chunk: Optional[str] = None,
+                  **_paths):
     """A whole prompt in one program, the REFERENCE walk: (last-position logits [N, V], caches)."""
     hidden, caches = _chunk_walk(params, cfg, caches, tokens, jnp.zeros_like(lengths), lengths, slots,
-                                 tables, page_size, grouped_matmul)
+                                 tables, page_size, grouped_matmul, selected_chunk=selected_chunk)
     return head(params, cfg, hidden), caches
 
 
 def extend_paged(params: Params, cfg: MiniMaxM3Config, caches: Caches, tokens, offsets, valid, slots,
                  tables, window: int, page_size: int, grouped_matmul: Optional[str] = None,
-                 capture: Optional[Dict[str, Any]] = None, **_paths):
+                 capture: Optional[Dict[str, Any]] = None, selected_chunk: Optional[str] = None, **_paths):
     """One chunk of a chunked prefill: (the residual row [N, D] of each
     row's last valid position, caches)."""
     del window  # the read follows each row's own context
     return _chunk_walk(params, cfg, caches, tokens, offsets, valid, slots, tables, page_size, grouped_matmul,
-                       capture)
+                       capture, selected_chunk)
 
 
 # --------------------------------------------------------------------- //
@@ -574,7 +604,7 @@ def decode_paged(params: Params, cfg: MiniMaxM3Config, caches: Caches, tokens, p
             moe_stats = moe_stats + stats
     if capture is not None:  # every layer's selection [L, B, Hk, K]
         capture.update(pages=jnp.stack([p for p, _ in kept]), valid=jnp.stack([v for _, v in kept]))
-    new["stats"] = jnp.concatenate([moe_stats, msa_stats]).astype(jnp.int32)
+    new["stats"] = jnp.concatenate([moe_stats, msa_stats, jnp.zeros((4,), jnp.int32)]).astype(jnp.int32)
     return head(params, cfg, x[:, 0]), new
 
 
@@ -583,6 +613,14 @@ def selected_read_kind(cfg: MiniMaxM3Config, kind: Optional[str]) -> Optional[st
     serves this geometry, else None (the XLA gather of the same strips)."""
     ok = kind and page_attention.supports_selected(
         cfg.msa_block, cfg.head_dim, cfg.num_heads, cfg.num_kv_heads, interpret=(kind == "interpret"))
+    return kind if ok else None
+
+
+def selected_chunk_kind(cfg: MiniMaxM3Config, kind: Optional[str], chunk: Optional[int] = None) -> Optional[str]:
+    """``kind`` where ``ops/selected_chunk_read.py`` tiles this
+    configuration's widths (and a chunk width, where the caller knows
+    it), else None (``_attend_selected_blocks``): decided from the shapes."""
+    ok = kind and selected_chunk_read.supported(cfg.msa_block, cfg.head_dim, cfg.num_heads, cfg.num_kv_heads, chunk)
     return kind if ok else None
 
 

@@ -456,8 +456,10 @@ def _minimaxm3_family() -> ModelFamily:
             cfg.num_layers, cfg.num_kv_heads, cfg.head_dim, cfg.num_heads,
             bytes_per_token=m.kv_bytes_per_token(cfg)),
         span_fields=lambda cfg: {"kv_readers": cfg.num_layers, "msa_pages_a_read": cfg.pages_a_read},
-        # the decode step's read of the SELECTED pages (ops/page_attention.py selected_page_attention)
-        resolve_kernels=lambda cfg, kind: {"grouped_matmul": kind, "selected_read": m.selected_read_kind(cfg, kind)},
+        # the decode step's read of the SELECTED pages (ops/page_attention.py selected_page_attention) and the
+        # chunk walk's masked read of the live ones (ops/selected_chunk_read.py), each where the widths tile
+        resolve_kernels=lambda cfg, kind: {"grouped_matmul": kind, "selected_read": m.selected_read_kind(cfg, kind),
+                                           "selected_chunk": m.selected_chunk_kind(cfg, kind)},
         stat_names=m.STAT_NAMES, read_stats=m.read_stats, extend_reads_window=False,
     )
 

@@ -731,3 +731,31 @@ def test_selected_page_read_compiles_at_the_published_widths(one_chip, no_persis
         s((B, Hq, Dh), jnp.bfloat16), s((P, Hkv, page, Dh), jnp.bfloat16), s((P, Hkv, page, Dh), jnp.bfloat16),
         s((B, Pmax), jnp.int32), s((B,), jnp.int32), s((B, Hkv, K), jnp.int32), s((B, Hkv, K), jnp.bool_)).compile()
     assert compiled.as_text().count("tpu_custom_call") == 1
+
+
+@pytest.mark.parametrize("rows, T", [(1, 512), (1, 128), (2, 512)], ids=["chunk-512", "chunk-128", "two-rows"])
+def test_selected_chunk_read_compiles_at_the_published_widths(one_chip, no_persistent_cache, rows, T):
+    """The chunk walk's block-sparse read alone (ops/selected_chunk_read.py):
+    the extend shapes of the cell, one row x 512 and x 128 queries of 64/4
+    heads of 128, 19 places a query, a table of 288 pages, a run-time count
+    of (row, tile, block) items on the grid's second axis."""
+    from generativeaiexamples_tpu.models import minimaxm3
+    from generativeaiexamples_tpu.ops import selected_chunk_read as scr
+
+    Hq, Hkv, Dh, page, P, Pmax, K = 64, 4, 128, 128, 3457, 288, 19
+    assert minimaxm3.selected_chunk_kind(minimaxm3.PRESETS["minimax-m3-ep8"], "compiled", T) == "compiled"
+    s = lambda shape, dt: jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)  # noqa: E731
+
+    def read(q, k, v, tables, positions, n_tokens, pages, valid):
+        work = scr.chunk_work_list(tables, positions, n_tokens, page, P)
+        src, _ = scr.chunk_live_steps(work, pages, valid)
+        return scr.selected_chunk_read(q, k, v, positions, pages, valid, work, src)
+
+    compiled = jax.jit(read).lower(
+        s((rows, T, Hq, Dh), jnp.bfloat16), s((P, Hkv, page, Dh), jnp.bfloat16), s((P, Hkv, page, Dh), jnp.bfloat16),
+        s((rows, Pmax), jnp.int32), s((rows, T), jnp.int32), s((rows,), jnp.int32),
+        s((rows, T, Hkv, K), jnp.int32), s((rows, T, Hkv, K), jnp.bool_)).compile()
+    text = compiled.as_text()
+    assert text.count("tpu_custom_call") == 1 and "selected_chunk_read" in text
+    # beside the arguments: the queries regrouped a KV head and the padded page numbers, nothing of a score's size
+    assert compiled.memory_analysis().temp_size_in_bytes < rows * T * (Hq * Dh * 2 + Hkv * 128 * 4) + (4 << 20)

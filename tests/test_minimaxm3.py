@@ -15,6 +15,8 @@ declares.
 import dataclasses
 import functools
 import inspect
+import logging
+import time
 
 import jax
 import jax.numpy as jnp
@@ -444,14 +446,20 @@ def test_registry_resolves_the_family_and_what_it_declares():
         ("glm5next", "glm5next-debug"), ("gigachat35", "gigachat35-debug"), ("kimik2", "kimik2-debug"),
         ("afmoe", "afmoe-debug"), ("llama", "debug-1k"))] == [True, True, True, False, False]
     assert fam.span_fields(FULL) == {"kv_readers": 5, "msa_pages_a_read": 19}
-    assert fam.resolve_kernels(cfg, "compiled") == {"grouped_matmul": "compiled", "selected_read": None}  # tiny widths do not tile
-    assert fam.resolve_kernels(cfg, "interpret") == {"grouped_matmul": "interpret", "selected_read": "interpret"}
-    assert fam.resolve_kernels(FULL, "compiled") == {"grouped_matmul": "compiled", "selected_read": "compiled"}
+    # tiny widths do not tile: the chunk walk's kernel declines them interpreted too (a page is a lane tile of its scores)
+    assert fam.resolve_kernels(cfg, "compiled") == {"grouped_matmul": "compiled", "selected_read": None, "selected_chunk": None}
+    assert fam.resolve_kernels(cfg, "interpret") == {
+        "grouped_matmul": "interpret", "selected_read": "interpret", "selected_chunk": None}
+    assert fam.resolve_kernels(FULL, "compiled") == {
+        "grouped_matmul": "compiled", "selected_read": "compiled", "selected_chunk": "compiled"}
     for walk in (m.extend_paged, m.decode_paged):
         assert {"grouped_matmul"} <= set(inspect.signature(walk).parameters)
     assert "selected_read" in inspect.signature(m.decode_paged).parameters
-    assert fam.stat_names == m.STAT_NAMES == ("moe_pairs_held", "moe_pairs_absent", "moe_experts_hit", "moe_experts_held",
-                                              "msa_pages_selected", "msa_pages_live", "msa_blocks_scored", "msa_pages_pooled")
+    assert "selected_chunk" in inspect.signature(m.extend_paged).parameters
+    assert fam.stat_names == m.STAT_NAMES == (
+        "moe_pairs_held", "moe_pairs_absent", "moe_experts_hit", "moe_experts_held",
+        "msa_pages_selected", "msa_pages_live", "msa_blocks_scored", "msa_pages_pooled",
+        "msa_chunk_kernel_layers", "msa_chunk_xla_layers", "msa_chunk_blocks_read", "msa_chunk_blocks_live")
     # the program's model modules name the family; the engine and the server name no model
     import pathlib
 
@@ -500,7 +508,7 @@ def test_engine_serves_every_prompt_shape_as_the_references_argmax(engine):
     selection biting from 24 tokens on. Nothing compiles after warm-up;
     the wave may hold two rows."""
     assert engine._family.name == "minimaxm3" and engine._paged_kernel == "interpret" and not engine._fixed_state
-    assert engine._family_kernels == {"grouped_matmul": "interpret", "selected_read": "interpret"}
+    assert engine._family_kernels == {"grouped_matmul": "interpret", "selected_read": "interpret", "selected_chunk": None}
     assert engine.shapes.max_wave_rows() == 2  # follows prefill_wave_tokens, like llama's
     assert engine._spec_available is False and engine._state_store_rows == 0 and engine._copy_state_fn is None
     rng = np.random.default_rng(1)
@@ -555,6 +563,101 @@ def test_a_prefix_hit_maps_pages_with_their_summaries_and_spans_and_counters_say
     finally:
         cold.shutdown()
     assert engine._compile_watch.snapshot().get("hot_path_compiles_total", 0) == 0
+
+
+# widths that tile the chip (a head and a page of one lane tile) at a size a CPU serves: the dense layer and four
+# expert layers, five reads a chunk as the served share has
+LANES = dataclasses.replace(CFG, layers_served=(0, 1, 2, 3, 4), head_dim=128, rotary_dim=64, msa_block=128, max_seq_len=1536)
+
+
+@pytest.mark.parametrize("chunk", [128, 256])
+def test_the_chunk_walk_through_the_kernel_is_the_xla_walk(chunk):
+    """``ops/selected_chunk_read.py`` interpreted inside the family's own
+    chunked extend: 1,100 tokens (nine blocks of 128: six candidates for
+    two places at the end, an offset off the chunk grid at the tail) give
+    the XLA walk's logits and, layer by layer, its selection."""
+    assert m.selected_chunk_kind(LANES, "interpret", chunk) == "interpret"
+    params = m.init_params_fast(LANES, 0, jnp.float32)
+    toks = np.random.default_rng(4).integers(0, LANES.vocab_size, size=(1100,))
+    page, pmax = LANES.msa_block, 12
+    tables = jnp.asarray(1 + np.arange(pmax).reshape(1, pmax), jnp.int32)
+    got = {}
+    for path in (None, "interpret"):
+        def walk(params, caches, row, off, n, path=path):
+            kept = {}
+            h, caches = m.extend_paged(params, LANES, caches, row, off, n, jnp.zeros((1,), jnp.int32), tables,
+                                       page * pmax, page, capture=kept, selected_chunk=path)
+            return h, caches, kept
+
+        walk = jax.jit(walk)
+        caches = m.init_paged_cache(LANES, 1 + pmax, page, 1, jnp.float32)
+        stats = np.zeros((len(m.STAT_NAMES),), np.int64)
+        for off in range(0, len(toks), chunk):
+            n = min(chunk, len(toks) - off)
+            row = np.zeros((1, chunk), np.int32)
+            row[0, :n] = toks[off:off + n]
+            h, caches, kept = walk(params, caches, jnp.asarray(row), jnp.asarray([off], jnp.int32), jnp.asarray([n], jnp.int32))
+            stats += np.asarray(caches["stats"])
+        got[path] = (np.asarray(m.head(params, LANES, h)[0]), jax.tree.map(np.asarray, kept), dict(zip(m.STAT_NAMES, stats)))
+    (xla, xla_kept, xla_stats), (ker, ker_kept, ker_stats) = got[None], got["interpret"]
+    assert rel(ker, xla) < TOL
+    n = len(toks) % chunk  # the last chunk's live queries [L, 1, n, Hk, K]; a padded query's row is nobody's
+    assert (ker_kept["valid"][:, :, :n] == xla_kept["valid"][:, :, :n]).all()
+    assert (np.where(xla_kept["valid"], ker_kept["pages"] == xla_kept["pages"], True)[:, :, :n]).all()
+    assert int(ker_kept["valid"][:, 0, :n].sum(-1).max()) == 5  # the selection bites: two of six candidates
+    chunks = -(-len(toks) // chunk)
+    assert (xla_stats["msa_chunk_xla_layers"], xla_stats["msa_chunk_kernel_layers"]) == (5 * chunks, 0)
+    assert (ker_stats["msa_chunk_kernel_layers"], ker_stats["msa_chunk_xla_layers"]) == (5 * chunks, 0)
+    assert xla_stats["msa_chunk_blocks_live"] == 0 and 0 < ker_stats["msa_chunk_blocks_read"] <= ker_stats["msa_chunk_blocks_live"]
+    for name in m.STAT_NAMES[:8]:
+        assert ker_stats[name] == xla_stats[name], name
+
+
+def test_an_engine_at_widths_that_tile_reads_its_chunks_through_the_kernel(caplog):
+    """The engine's own extend programs with ``paged_kernel="interpret"``
+    at widths that tile: the log line names ``selected_chunk=interpret``,
+    a 300-token prompt goes in three chunks whose spans say all five
+    layers read through the kernel and how many of its blocks were read,
+    the counter grows by five a chunk under ``path="kernel"`` and not at
+    all under ``path="xla"``, a decode span reports zeros in the four new
+    places, and the answer is the one the engine gives with every kernel off."""
+    from generativeaiexamples_tpu.engine import dispatch_timeline
+
+    name = "minimaxm3-lanes-test"
+    m.PRESETS[name] = LANES
+    reads = lambda c, path: c.get(f'genai_engine_msa_chunk_reads_total{{path="{path}"}}', 0.0)  # noqa: E731
+    prompt = [int(t) for t in np.random.default_rng(3).integers(3, 250, size=300)]
+    shape = dict(model_config_name=name, page_size=128, prefill_chunk=128, prefill_wave_tokens=128, max_seq_len=512,
+                 max_batch_size=2, prefix_cache_enable="off")
+    try:
+        answers = {}
+        for kernel in ("interpret", "off"):
+            with caplog.at_level(logging.INFO, logger="generativeaiexamples_tpu.engine.llm_engine"):
+                caplog.clear()
+                eng = build(paged_kernel=kernel, **shape)
+            try:
+                line = next(r.getMessage() for r in caplog.records if "resolved kernel paths:" in r.getMessage())
+                assert f"selected_chunk={'interpret' if kernel == 'interpret' else None}" in line
+                before, t0 = counters(), time.time()
+                answers[kernel] = list(eng.iter_ids(prompt, greedy(4), timeout=600))
+                after = counters()
+                spans = [s for s in dispatch_timeline.recent_spans(64) if s["t_wall"] >= t0]
+                chunks = [s for s in spans if s["kind"] == "prefill_chunk"]
+                served, other = ("kernel", "xla") if kernel == "interpret" else ("xla", "kernel")
+                assert len(chunks) == 3
+                assert all(s[f"msa_chunk_{served}_layers"] == 5 and s[f"msa_chunk_{other}_layers"] == 0 for s in chunks)
+                assert reads(after, served) - reads(before, served) == 15 and reads(after, other) == reads(before, other)
+                if kernel == "interpret":
+                    assert all(0 < s["msa_chunk_blocks_read"] <= s["msa_chunk_blocks_live"] for s in chunks)
+                steps = [s for s in spans if s["kind"] == "decode"]
+                assert steps and all(s[n] == 0 for s in steps for n in m.STAT_NAMES[8:])
+                assert all(s["msa_pages_selected"] > 0 for s in steps)
+                assert eng._compile_watch.snapshot().get("hot_path_compiles_total", 0) == 0
+            finally:
+                eng.shutdown()
+        assert answers["interpret"] == answers["off"] and len(answers["off"]) == 4
+    finally:
+        del m.PRESETS[name]
 
 
 REFUSED = {

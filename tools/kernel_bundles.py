@@ -16,6 +16,7 @@ Count here first, then measure on the chip.
 
     JAX_PLATFORMS=cpu python -m tools.kernel_bundles --heads 32 8 --kv int8 --pages-a-step 2
     JAX_PLATFORMS=cpu python -m tools.kernel_bundles --heads 64 8 --kv bf16 --head-major --file other/page_attention.py
+    JAX_PLATFORMS=cpu python -m tools.kernel_bundles --selected-chunk 512   # ops/selected_chunk_read.py (PR 53)
 
 Prints the kernel's total bundles, the size of every region between
 control targets (the largest is the page arithmetic; at N pages a step
@@ -59,6 +60,9 @@ def _parse() -> argparse.Namespace:
     ap.add_argument("--bias", action="store_true",
                     help="with --latent-decode: latent_attention, rows of 512 columns and a bias (GLM-5.3-Flash's: "
                          "64 rows x 64 pages)")
+    ap.add_argument("--selected-chunk", type=int, default=0, metavar="T",
+                    help="compile ops/selected_chunk_read.py instead, at MiniMax-M3's widths (64/4 heads of 128, one "
+                         "row, 288 pages, 19 places a query) and a chunk of T queries")
     ap.add_argument("--keep", default=None, help="directory to keep the dump in")
     ap.add_argument("--compile-into", default=None, help=argparse.SUPPRESS)  # the child's job
     return ap.parse_args()
@@ -98,6 +102,21 @@ def _compile(args: argparse.Namespace, dump: str) -> None:
         jax.jit(lambda *a: la.latent_chunk_read(*a, scale=0.1, heads_per_step=args.heads_a_step or None)).lower(
             s((1, H, T, dn), bf16), s((1, H, T, dr), bf16), s((6145, page, row), bf16), s((1, pmax), jnp.int32),
             s((1, T), jnp.int32), s((1,), jnp.int32), s((H, dn, R), bf16), s((H, R, Dv), bf16),
+        ).compile()
+        return
+
+    if args.selected_chunk:
+        from generativeaiexamples_tpu.ops import selected_chunk_read as scr
+
+        T, Hq, Hk, Dh, page, pmax, P, K, bf16 = args.selected_chunk, 64, 4, 128, args.page, 288, 3457, 19, jnp.bfloat16
+
+        def read(q, k, v, tables, pos, n_tokens, pages, valid):
+            work = scr.chunk_work_list(tables, pos, n_tokens, page, P)
+            return scr.selected_chunk_read(q, k, v, pos, pages, valid, work, scr.chunk_live_steps(work, pages, valid)[0])
+
+        jax.jit(read).lower(
+            s((1, T, Hq, Dh), bf16), s((P, Hk, page, Dh), bf16), s((P, Hk, page, Dh), bf16), s((1, pmax), jnp.int32),
+            s((1, T), jnp.int32), s((1,), jnp.int32), s((1, T, Hk, K), jnp.int32), s((1, T, Hk, K), jnp.bool_),
         ).compile()
         return
 
@@ -145,8 +164,8 @@ def main() -> int:
     if args.compile_into:
         _compile(args, args.compile_into)
         return 0
-    kernel = ("latent_chunk_read" if args.latent_chunk else "latent_attention" if args.latent_decode
-              else "paged_attention")
+    kernel = ("latent_chunk_read" if args.latent_chunk else "selected_chunk_read" if args.selected_chunk
+              else "latent_attention" if args.latent_decode else "paged_attention")
     if not args.keep:  # ~1,700 files of passes: read, then thrown away
         with tempfile.TemporaryDirectory(prefix="kernel_bundles_") as scratch:
             return _report(scratch, False, kernel)
