@@ -38,7 +38,16 @@ On top of the ring:
   incrementally (``?since=<cursor>``) and as Chrome-trace JSON
   (``?format=perfetto``): one track per tier thread plus a device
   track from the completion stamps, flight-recorder events overlaid;
-- recent span windows embedded in black-box bundles (utils/blackbox.py).
+- recent span windows embedded in black-box bundles (utils/blackbox.py);
+- **what a token waited behind** (PR 54): with every stamp the watcher
+  publishes the device's seconds by kind as ONE immutable tuple
+  (``DeviceClock``, ``device_clock()``); the engine's reader takes, at
+  each stream hand-off, the clock of the launch it reads back minus the
+  clock the request kept at its previous hand-off (``HandoffBlock``):
+  the gap's parts land on the decode span (``GAP_FIELDS``), in
+  ``genai_stream_handoff_gap_seconds`` and
+  ``genai_stream_handoff_gap_part_seconds_total{part}``, and a gap of
+  seconds leaves ONE ``stream_gap`` record beside the hold's.
 
 Ring semantics mirror utils/flight_recorder.py: a monotonic ``seq``
 cursor, whole-window eviction (``WINDOW_SPANS`` spans drop together), a
@@ -57,7 +66,7 @@ import statistics
 import threading
 import time
 from collections import deque
-from typing import Any, Deque, Dict, List, Optional, Sequence, Tuple
+from typing import Any, Deque, Dict, List, NamedTuple, Optional, Sequence, Tuple
 
 from generativeaiexamples_tpu.utils import metrics as metrics_mod
 from generativeaiexamples_tpu.utils.logging import get_logger
@@ -79,6 +88,10 @@ __all__ = [
     "start_watcher",
     "stop_watcher",
     "stamp_pending",
+    "DeviceClock",
+    "device_clock",
+    "clock_at",
+    "HandoffBlock",
     "cursor",
     "spans_since",
     "recent_spans",
@@ -133,6 +146,26 @@ _M_STARVED = _REG.counter(
     "genai_engine_device_starved_seconds_total",
     "Seconds the device had nothing of the engine's to run between two "
     "launches (sum of the spans' starved_s).",
+)
+
+_M_HANDOFF_GAP = _REG.histogram(
+    "genai_stream_handoff_gap_seconds",
+    "Per stream hand-off (one put of one request's tokens of one readback), "
+    "the time since that request's previous hand-off, on the reader "
+    "thread's clock: the distribution whose ~96th (decode_block 8) or "
+    "~99th (decode_block 2) percentile a client's 99.5th frame gap reads.",
+    buckets=(0.005, 0.01, 0.015, 0.025, 0.04, 0.06, 0.1, 0.15, 0.25, 0.4,
+             0.6, 1.0, 2.0, float("inf")),
+)
+_M_HANDOFF_GAP_PART = _REG.counter(
+    "genai_stream_handoff_gap_part_seconds_total",
+    "The hand-off gaps' seconds by what filled them: the device's time on "
+    "decode blocks, on extend (prefill) chunks and on everything else, the "
+    "device starved of the engine's work, and the host's rest (the gap "
+    "minus those four). Summed over every hand-off; over the sum of "
+    "genai_stream_handoff_gap_seconds it is what the mean token waits "
+    "behind.",
+    ("part",),
 )
 
 # --------------------------------------------------------------------------- #
@@ -202,6 +235,37 @@ def _mode_of(kind: str) -> str:
     return "other"
 
 
+class DeviceClock(NamedTuple):
+    """The device's seconds so far by what it ran: monotone sums over
+    the stamped launches, as ONE immutable value that ``_stamp``
+    publishes in the critical section in which it adds a launch to
+    ``_CUM_MODE``, so another thread reads a consistent set as one
+    attribute load (``device_clock``). ``extend_s`` is mode 'prefill',
+    ``other_s`` the modes 'spec' and 'other' (verifies, prefix-state
+    copies, whatever a span charges); ``launches`` counts the stamps and
+    ``seq`` is the last stamped span's."""
+
+    decode_s: float = 0.0
+    extend_s: float = 0.0
+    other_s: float = 0.0
+    starved_s: float = 0.0
+    launches: int = 0
+    seq: int = 0
+
+
+_CLOCK = DeviceClock()  # written under _LOCK (by _stamp and reset), read without
+
+# What a hand-off's gap was made of, as fields of the decode span that
+# handed the tokens over (HandoffBlock.close; docs/observability.md).
+GAP_FIELDS = (
+    "handoff_rows", "gap_s", "gap_decode_s", "gap_extend_s", "gap_other_s",
+    "gap_starved_s", "gap_host_s", "gap_launches",
+)
+_GAP_PARTS = ("decode", "extend", "other", "starved", "host")
+# resolved once: every part's series is on /metrics from the start
+_M_GAP_PARTS = tuple(_M_HANDOFF_GAP_PART.labels(part=p) for p in _GAP_PARTS)
+
+
 class Span:
     """One recorded launch/stall/readback. Appends are deque.append
     under the module lock; after that only the watcher writes, once, the
@@ -212,6 +276,7 @@ class Span:
         "seq", "kind", "category", "thread", "t_wall", "lock_wait_s",
         "run_s", "rows", "tokens", "steps", "path", "rids", "counters",
         "t_done", "device_s", "starved_s", "queued_s", "jit_s", "jit_what",
+        "clock",
     )
 
     def __init__(self, kind: str, category: str, thread: str,
@@ -235,6 +300,7 @@ class Span:
         # (decode: kv_pages_walked / kv_pages_grid; a hold: its record)
         self.counters = counters
         self.t_done: Optional[float] = None  # the watcher's stamp
+        self.clock: Optional[DeviceClock] = None  # the device clock once it holds this launch
         self.device_s = self.starved_s = self.queued_s = 0.0
         self.jit_s = 0.0
         self.jit_what: Optional[str] = None
@@ -415,6 +481,13 @@ def record_span(
     _append(span)
     if handle is not None:
         _PENDING.put((span, handle, _EPOCH))
+    if _TRACE_ANNOTATION is not None:
+        # one name on the profiler's host track, at the enqueue's return
+        # (t_enq) on this thread: a capture shows the engine's spans on
+        # its own clock and joins XLA Modules to spans by seq (seq exists
+        # only now, so this marks the enqueue's end and does not wrap it)
+        with _TRACE_ANNOTATION(f"{kind}#{span.seq}"):
+            pass
     return span
 
 
@@ -513,6 +586,8 @@ _PENDING: "queue.SimpleQueue[Tuple[Span, Any, int]]" = queue.SimpleQueue()
 _STOP = object()  # stop_watcher() queues it: the watcher thread ends
 _EPOCH = 0  # reset() bumps it: a launch awaited across a reset is not stamped
 _WATCHER: Optional[threading.Thread] = None  # guarded by _LOCK
+_STAMPED = threading.Condition(_LOCK)  # _stamp notifies it: clock_at
+_TRACE_ANNOTATION = None  # jax.profiler.TraceAnnotation once a watcher started
 _PREV_DONE: Optional[float] = None  # watcher-owned: the last stamp
 _PREV_CPU = 0.0  # watcher-owned: process CPU seconds at the last stamp
 _PREV_GC = 0  # watcher-owned: gc pauses counted at the last stamp
@@ -565,13 +640,18 @@ def _watch() -> None:
 def start_watcher() -> None:
     """Start the watcher and its heartbeat (idempotent; the engine calls
     it at init when the timeline is on)."""
-    global _WATCHER
+    global _WATCHER, _TRACE_ANNOTATION
     with _LOCK:
         if _WATCHER is not None or not _ENABLED:
             return
         watcher = _WATCHER = threading.Thread(
             target=_watch, daemon=True, name="llm-dispatch-watcher"
         )
+    if _TRACE_ANNOTATION is None:
+        try:
+            from jax.profiler import TraceAnnotation as _TRACE_ANNOTATION
+        except ImportError:  # a jax without the profiler: spans only
+            pass
     if _count_gc not in gc.callbacks:  # the first start of this process: the hook and the heartbeat live on
         gc.callbacks.append(_count_gc)
         threading.Thread(
@@ -615,32 +695,46 @@ def _rung(span: Span) -> tuple:
             c.get("width", 0), span.steps)
 
 
+def _far_longer(seconds: float, history: Deque[float]) -> bool:
+    """Whether ``seconds`` passes BOTH hold constants against the running
+    sample ``history``, which then takes it in."""
+    far = (
+        seconds > HOLD_MIN_S
+        and len(history) >= _HOLD_MIN_SAMPLES
+        and seconds > HOLD_TIMES_MEDIAN * statistics.median(history)
+    )
+    history.append(seconds)
+    return far
+
+
 def _stamp(span: Span, t_done: float) -> None:
     """The output of ``span``'s launch was ready at ``t_done``."""
-    global _PREV_DONE, _PREV_CPU, _PREV_GC
+    global _PREV_DONE, _PREV_CPU, _PREV_GC, _CLOCK
     t_enq = span.t_enq
     prev = t_enq if _PREV_DONE is None else _PREV_DONE
     t_done = max(t_done, prev)  # a stamp out of order: nothing is negative
     device_s = t_done - max(prev, t_enq)
     starved_s = max(0.0, t_enq - prev)
     cpu, pauses = time.process_time(), _GC_PAUSES
-    history = _RUNG_S.setdefault(_rung(span), deque(maxlen=_HOLD_HISTORY))
-    held = (
-        device_s > HOLD_MIN_S
-        and len(history) >= _HOLD_MIN_SAMPLES
-        and device_s > HOLD_TIMES_MEDIAN * statistics.median(history)
+    held = _far_longer(
+        device_s, _RUNG_S.setdefault(_rung(span), deque(maxlen=_HOLD_HISTORY))
     )
-    history.append(device_s)
     with _LOCK:
         span.device_s = device_s
         span.starved_s = starved_s
         span.queued_s = max(0.0, prev - t_enq)
-        span.t_done = t_done
         mode = _CUM_MODE[_mode_of(span.kind)]
         _CUM["device"] += device_s
         _CUM["gap"] += starved_s
         mode["device"] += device_s
         mode["gap"] += starved_s
+        span.clock = _CLOCK = DeviceClock(
+            _CUM_MODE["decode"]["device"], _CUM_MODE["prefill"]["device"],
+            _CUM_MODE["spec"]["device"] + _CUM_MODE["other"]["device"],
+            _CUM["gap"], _CLOCK.launches + 1, span.seq,
+        )
+        span.t_done = t_done
+        _STAMPED.notify_all()
     _M_DEVICE.labels(program=span.kind).observe(device_s, trace_id=None)
     _M_GAP.observe(starved_s, trace_id=None)
     _M_STARVED.inc(starved_s)
@@ -650,16 +744,27 @@ def _stamp(span: Span, t_done: float) -> None:
     _PREV_DONE, _PREV_CPU, _PREV_GC = t_done, cpu, pauses
 
 
-def _hold_record(span: Span, t0: float, cpu_s: float, gc_pauses: int) -> None:
-    """ONE stall span and one log line for a launch the device held: what
-    it was, what was enqueued before it, and what the process knows of
-    the interval (jit, the host's heartbeat, CPU against wall, gc, device
-    memory)."""
-    def rung(s: Span) -> Dict[str, Any]:
-        kind, rows, width, steps = _rung(s)
-        return {"seq": s.seq, "kind": kind, "rows_dispatched": rows,
-                "width": width, "steps": steps}
+def _rung_view(s: Span) -> Dict[str, Any]:
+    kind, rows, width, steps = _rung(s)
+    return {"seq": s.seq, "kind": kind, "rows_dispatched": rows,
+            "width": width, "steps": steps}
 
+
+def _stall_record(kind: str, t0: float, duration_s: float, rows: int,
+                  record: Dict[str, Any], what: str) -> None:
+    """ONE stall span and one log line for something that took far
+    longer than it ever does (a launch the device held, a stream's gap)."""
+    _append(Span(
+        kind, "stall", threading.current_thread().name,
+        t0, 0.0, duration_s, rows, counters=record,
+    ))
+    logger.warning("%s: %s", what, record)
+
+
+def _hold_record(span: Span, t0: float, cpu_s: float, gc_pauses: int) -> None:
+    """A launch the device held: what it was, what was enqueued before
+    it, and what the process knows of the interval (jit, the host's
+    heartbeat, CPU against wall, gc, device memory)."""
     bytes_in_use = None
     try:
         import jax
@@ -669,8 +774,8 @@ def _hold_record(span: Span, t0: float, cpu_s: float, gc_pauses: int) -> None:
     except Exception:  # noqa: BLE001 - a backend without memory_stats
         pass
     record = {
-        "held": dict(rung(span), device_s=round(span.device_s, 6)),
-        "before": [rung(s) for s in _RECENT],
+        "held": dict(_rung_view(span), device_s=round(span.device_s, 6)),
+        "before": [_rung_view(s) for s in _RECENT],
         "jit": [e[1:] for e in _JIT_EVENTS if t0 <= e[0] <= span.t_done],
         "heartbeat_max_gap_s": round(max(
             (g for t, g in _LATE_BEATS if t0 <= t <= span.t_done + HEARTBEAT_S),
@@ -681,11 +786,132 @@ def _hold_record(span: Span, t0: float, cpu_s: float, gc_pauses: int) -> None:
         "gc_pauses": gc_pauses,
         "bytes_in_use": bytes_in_use,
     }
-    _append(Span(
-        f"device_hold:{span.kind}", "stall", threading.current_thread().name,
-        t0, 0.0, span.device_s, span.rows, counters=record,
-    ))
-    logger.warning("DEVICE HOLD: %s", record)
+    _stall_record(f"device_hold:{span.kind}", t0, span.device_s, span.rows,
+                  record, "DEVICE HOLD")
+
+
+# --------------------------------------------------------------------------- #
+# What a token waited behind: a stream hand-off's gap, split by the
+# device clock (the engine's reader thread drives this; docs/streaming.md)
+
+_STAMP_WAIT_S = 0.05  # the longest a reader waits for its own launch's stamp
+_GAP_S: Deque[float] = deque(maxlen=_HOLD_HISTORY)  # reader-owned: each block's longest gap
+_GAP_LAUNCH_CAP = 32  # launches a stream_gap record lists (the newest)
+
+
+def device_clock() -> DeviceClock:
+    """The device's seconds by kind over every launch stamped so far."""
+    return _CLOCK
+
+
+def clock_at(span: Optional[Span]) -> DeviceClock:
+    """The device clock as of ``span``'s launch: every launch the device
+    finished up to and including it. The watcher and a reader wake on
+    the same output, so a reader that comes first WAITS for the stamp: a
+    clock taken before it would count the launch into its rows' NEXT
+    gaps. Bounded by ``_STAMP_WAIT_S`` (a launch recorded before a
+    ``reset`` is never stamped; a watcher starved of the interpreter):
+    past it, and without a span or a watcher, the clock as it stands; the
+    launch then lands in the next gap and the sums stay conserved."""
+    if span is None:
+        return _CLOCK
+    if span.clock is None:
+        with _LOCK:  # the lock _STAMPED is a condition over
+            if _WATCHER is not None:
+                _STAMPED.wait_for(lambda: span.clock is not None, _STAMP_WAIT_S)
+    return span.clock or _CLOCK
+
+
+class HandoffBlock:
+    """The stream hand-offs of ONE readback, on the reader thread: each
+    request's gap since its previous hand-off is split into the device's
+    time by kind (the device clock now minus the clock the request kept
+    then) and the host's rest. Requests that were last handed tokens by
+    the same launch share one clock OBJECT, so the difference is taken
+    once per distinct previous clock, not once per row. ``close`` writes
+    the block's row count and its LONGEST gap's parts into the span's
+    fields and the sums into ``/metrics``; nothing per row is kept."""
+
+    __slots__ = ("clock", "rows", "_fields", "_parts", "_sums", "_longest")
+
+    def __init__(self, span: Optional[Span] = None):
+        self.clock = clock_at(span)
+        # the span's fields, where its dispatch wrote the GAP_FIELDS keys
+        # (a value lands only on a key that is there: the dict keeps its
+        # size under a concurrent scrape; a prefill chunk's has none)
+        fields = span.counters if span is not None else None
+        self._fields = fields if fields and "gap_s" in fields else None
+        self.rows = 0
+        self._parts: Dict[int, Tuple[float, float, float, float]] = {}
+        self._sums = [0.0, 0.0, 0.0, 0.0, 0.0]
+        self._longest: Optional[tuple] = None
+
+    def handoff(self, req, now: float) -> None:
+        """One request's hand-off at ``now``. ``req`` keeps, between its
+        hand-offs, ``gap_clock`` (the clock of its previous one; None
+        before its first, which only starts its first gap),
+        ``gap_owed`` and ``t_last_token`` (that hand-off's time, which
+        the caller advances). The host's part is the gap minus the
+        device's four, floored at 0; what the floor cut (the previous
+        hand-off came late, so its gap already held this much of the
+        device's time as host time) is owed by the request's next gaps,
+        so over a stream every part sums to every gap."""
+        prev, req.gap_clock = req.gap_clock, self.clock
+        self.rows += 1
+        if prev is None:
+            return
+        clock = self.clock
+        parts = self._parts.get(id(prev))
+        if parts is None:
+            parts = self._parts[id(prev)] = (
+                clock.decode_s - prev.decode_s, clock.extend_s - prev.extend_s,
+                clock.other_s - prev.other_s, clock.starved_s - prev.starved_s,
+            )
+        gap_s = now - req.t_last_token
+        rest = gap_s - (parts[0] + parts[1] + parts[2] + parts[3]) + req.gap_owed
+        host_s, req.gap_owed = (rest, 0.0) if rest > 0 else (0.0, rest)
+        _M_HANDOFF_GAP.observe(gap_s, trace_id=None)
+        sums = self._sums
+        sums[0] += parts[0]
+        sums[1] += parts[1]
+        sums[2] += parts[2]
+        sums[3] += parts[3]
+        sums[4] += host_s
+        if self._longest is None or gap_s > self._longest[0]:
+            self._longest = (gap_s, parts, host_s, prev, req.rid)
+
+    def close(self) -> None:
+        fields = self._fields
+        if self._longest is None:
+            if fields is not None:
+                fields["handoff_rows"] = self.rows
+            return
+        for part, seconds in zip(_M_GAP_PARTS, self._sums):
+            part.inc(seconds)
+        gap_s, parts, host_s, prev, rid = self._longest
+        record = {
+            "handoff_rows": self.rows,
+            "gap_s": round(gap_s, 6),
+            "gap_decode_s": round(parts[0], 6),
+            "gap_extend_s": round(parts[1], 6),
+            "gap_other_s": round(parts[2], 6),
+            "gap_starved_s": round(parts[3], 6),
+            "gap_host_s": round(host_s, 6),
+            "gap_launches": self.clock.launches - prev.launches,
+        }
+        if fields is not None:
+            fields.update(record)  # one call: a scrape sees none or all of a block's values
+        if _far_longer(gap_s, _GAP_S):
+            with _LOCK:
+                launches = [
+                    dict(_rung_view(s), device_s=round(s.device_s, 6))
+                    for s in _SPANS
+                    if s.category == "dispatch" and s.t_done is not None
+                    and prev.seq < s.seq <= self.clock.seq
+                ]
+            record["launches"] = launches[-_GAP_LAUNCH_CAP:]
+            _stall_record("stream_gap", time.time() - gap_s, gap_s, self.rows,
+                          dict(record, rid=rid), "STREAM GAP")
 
 
 # --------------------------------------------------------------------------- #
@@ -917,7 +1143,7 @@ def perfetto_trace(
 def reset() -> None:
     """Drop every span, queued stamp and rung history and rewind the
     cursor/counters (tests only)."""
-    global _SEQ, _PREV_DONE, _EPOCH
+    global _SEQ, _PREV_DONE, _EPOCH, _CLOCK
     _EPOCH += 1
     while not _PENDING.empty():
         _PENDING.get_nowait()
@@ -926,9 +1152,11 @@ def reset() -> None:
     _RUNG_S.clear()
     _JIT_EVENTS.clear()
     _JIT_TLS.pending = None
+    _GAP_S.clear()
     with _LOCK:
         _SPANS.clear()
         _SEQ = 0
+        _CLOCK = DeviceClock()
         for k in _CUM:
             _CUM[k] = 0.0
         for cum in _CUM_MODE.values():

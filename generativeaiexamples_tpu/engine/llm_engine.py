@@ -32,6 +32,7 @@ from __future__ import annotations
 
 import collections
 import dataclasses
+import functools
 import itertools
 import queue
 import random
@@ -97,6 +98,15 @@ _M_HANDOFF_TOKENS = _REG.counter(
     "it is tokens a hand-off, towards decode_block when the block "
     "hand-off engages, 1 when every token wakes the stream.",
 )
+_M_WRITE_LAG = _REG.histogram(
+    "genai_stream_write_lag_seconds",
+    "From the reader's put of a stream's oldest un-taken tokens to the "
+    "handler's report that it has written their frames: the stream's leg "
+    "of a token's way (the wake-up, the decoder, the SSE write). A "
+    "client's frame gap is the engine's hand-off gap "
+    "(genai_stream_handoff_gap_seconds) plus a difference of two of these.",
+    buckets=metrics_mod.FAST_SECONDS_BUCKETS,
+)
 _M_DECODE_STEPS = _REG.counter(
     "genai_engine_decode_steps_total",
     "Decode steps executed (decode_block steps per dispatch).",
@@ -140,7 +150,12 @@ _M_PREFILL_WAIT = _REG.histogram(
 )
 _M_TOKEN_LATENCY = _REG.histogram(
     "genai_engine_token_latency_seconds",
-    "Inter-token emission interval per request (slab cadence included).",
+    "Per token, the interval since the request's previous token on the "
+    "reader's clock. Since the block hand-off (PR 30) a readback's tokens "
+    "arrive at one instant: the first of a block observes the whole gap "
+    "since the previous block and the rest observe 0, so seven of eight "
+    "(decode_block 8) or one of two observations say nothing. The gaps "
+    "between hand-offs are genai_stream_handoff_gap_seconds.",
     buckets=(0.0001, 0.00025, 0.0005, 0.001, 0.0025, 0.005, 0.01, 0.025,
              0.05, 0.1, 0.25, 0.5, 1.0, 2.5, 10.0),
 )
@@ -386,13 +401,19 @@ class _TokenQueue:
     def __init__(self) -> None:
         self._items: "collections.deque[Optional[int]]" = collections.deque()
         self._ready = threading.Condition(threading.Lock())
+        # wall time of the oldest put nobody has taken (0.0: none, or a
+        # producer that gives no time), and of the one take_all took last
+        self._t_put = 0.0
+        self.t_taken = 0.0
 
     def put(self, item: Optional[int]) -> None:
         self.put_many((item,))
 
-    def put_many(self, items: Sequence[Optional[int]]) -> None:
+    def put_many(self, items: Sequence[Optional[int]], t_put: float = 0.0) -> None:
         with self._ready:
             self._items.extend(items)
+            if not self._t_put:
+                self._t_put = t_put
             self._ready.notify()
 
     def _wait(self, timeout: Optional[float]) -> None:
@@ -409,6 +430,7 @@ class _TokenQueue:
             self._wait(timeout)
             items = list(self._items)
             self._items.clear()
+            self.t_taken, self._t_put = self._t_put, 0.0
             return items
 
 
@@ -428,6 +450,12 @@ class _Request:
     t_submit: float = 0.0
     t_admit: float = 0.0
     t_last_token: float = 0.0
+    # The device clock (engine/dispatch_timeline.py DeviceClock) at this
+    # request's previous stream hand-off; rows handed tokens by the same
+    # launch share ONE object. None before the first, and with the
+    # timeline off.
+    gap_clock: Optional[tuple] = None
+    gap_owed: float = 0.0  # <= 0: device time an earlier gap held as host time
     # Trace id (32 hex chars) active at submit time — observations for
     # this request happen on engine threads with no span stack, so the
     # exemplar context rides the request object instead.
@@ -2133,6 +2161,7 @@ class LLMEngine:
         self._put_rows_fn = wrap("put_rows", jax.jit(put_rows))
         self._zero_carries: Dict[int, object] = {}  # _zero_hidden's, by rows
         self._wave_stats: list = []  # (span fields, device counts) of a wave's chunks
+        self._wave_span = None  # of the wave's LAST chunk: its first tokens' hand-off waits for that stamp
         self._wave_entries: list = []  # stateful prefix entries the wave in flight inserted
         self._spec_verify_fn = wrap(
             "spec_verify",
@@ -2453,14 +2482,19 @@ class LLMEngine:
         )
         deadline = None if timeout is None else time.time() + timeout
 
-        def written(n: int) -> None:
+        def written(n: int, t_put: float) -> None:
             req.written = (req.written or 0) + n
+            if t_put:  # 0.0: the reader gave no time (the timeline is off)
+                _M_WRITE_LAG.observe(time.time() - t_put, trace_id=None)
+
+        t_put = 0.0  # when the reader put the oldest ids of the next hand-off
 
         taken = 0  # ids consumed since the last hand-off (one may add no text yet)
         self._streams[id(req)] = req
         try:
             while True:
                 items = _next_stream_items(req.out_queue, stall_s, deadline)
+                t_put = t_put or req.out_queue.t_taken
                 ended = items[-1] is _END
                 if ended:
                     items.pop()
@@ -2476,8 +2510,10 @@ class LLMEngine:
                 if pieces:
                     _M_HANDOFFS.inc()
                     _M_HANDOFF_TOKENS.inc(taken)
-                    yield TokenBlock(pieces, taken, written)
-                    taken = 0
+                    yield TokenBlock(
+                        pieces, taken, functools.partial(written, t_put=t_put)
+                    )
+                    taken, t_put = 0, 0.0
                 if dec.stopped:
                     return
                 if ended:
@@ -3608,6 +3644,7 @@ class LLMEngine:
         self._readback.put((
             "prefill", first_tokens,
             [(i, req) for i, req in enumerate(admitted)], wave_stats,
+            self._wave_span,
         ))
         # Insert completed prefills back into the radix cache
         # (dispatch-ordered after the chunk loop; decode only ever
@@ -3839,6 +3876,7 @@ class LLMEngine:
         K = (Tmax + C - 1) // C
         annotate = self._annotate
         self._wave_stats = []
+        self._wave_span = None
         last_h = self._zero_hidden(Np)
         dispatched = 0
         # A family whose prefix entries carry a fixed-state row: a hit's
@@ -3969,7 +4007,7 @@ class LLMEngine:
                 _start_host_copy(step_stats[0])
                 self._wave_stats.append((fields, step_stats[0]))
             if _dtl is not None:
-                _dtl.record_span(
+                self._wave_span = _dtl.record_span(
                     "prefill_chunk",
                     t_wall=_dtl_wall,
                     lock_wait_s=_dtl_t1 - _dtl_t0,
@@ -4132,6 +4170,8 @@ class LLMEngine:
             # the family's own counts of this dispatch's last step: keys
             # now, values when the slab is read back (_note_stats)
             span_counts.update(dict.fromkeys(self._stat_names, 0))
+            # what this block's tokens waited behind: likewise
+            span_counts.update(dict.fromkeys(dispatch_timeline_mod.GAP_FIELDS, 0))
             for slot in self._slot_pos:
                 self._slot_pos[slot] += self.shapes.decode_block
             self._update_occupancy_gauges()
@@ -4173,8 +4213,9 @@ class LLMEngine:
             snapshot = list(self._slot_req.items())
             for slot in list(self._slot_budget):
                 self._slot_budget[slot] -= self.shapes.decode_block
+        span = None
         if _dtl is not None:
-            _dtl.record_span(
+            span = _dtl.record_span(
                 "decode",
                 t_wall=_dtl_wall,
                 lock_wait_s=_dtl_t1 - _dtl_t0,
@@ -4192,7 +4233,7 @@ class LLMEngine:
         _start_host_copy(token_slab)
         # Blocks when decode_runahead results await readback — the only
         # backpressure on the dispatch thread.
-        self._readback.put(("decode", token_slab, snapshot, span_counts))
+        self._readback.put(("decode", token_slab, snapshot, span_counts, span))
 
     def _spec_decode_once(self) -> None:
         """One speculative verify dispatch (prompt-lookup decoding).
@@ -4356,6 +4397,7 @@ class LLMEngine:
                 rows=len(snapshot),
                 path="kernel" if self._paged_verify_kernel else "gather",
                 rids=[r.rid for _, r in snapshot],
+                counters=dict.fromkeys(_dtl.GAP_FIELDS, 0),
                 handle=packed,
             )
         _M_DECODE_STEPS.inc(1)
@@ -4508,7 +4550,7 @@ class LLMEngine:
                     buf.extend(int(t) for t in out_np[slot, :n])
             self._update_occupancy_gauges()
         # put() outside the lock (the reader needs it inside _emit)
-        self._readback.put(("spec", (out_np, acc_np), snapshot))
+        self._readback.put(("spec", (out_np, acc_np), snapshot, span))
 
     def _spec_propose(self, prop, prop_rows, reconcile):
         """This round's drafts: consume confirmed runahead drafts
@@ -4656,8 +4698,9 @@ class LLMEngine:
                     self._cache,
                     token_slab,
                 ) = out
+        span = None
         if _dtl is not None:
-            _dtl.record_span(
+            span = _dtl.record_span(
                 "spec_block",
                 t_wall=_dtl_wall,
                 lock_wait_s=_dtl_t1 - _dtl_t0,
@@ -4667,6 +4710,7 @@ class LLMEngine:
                 steps=self.shapes.decode_block,
                 path="kernel" if self._paged_kernel else "gather",
                 rids=[r.rid for _, r in snapshot],
+                counters=dict.fromkeys(_dtl.GAP_FIELDS, 0),
                 handle=token_slab,
             )
         _M_DECODE_STEPS.inc(self.shapes.decode_block)
@@ -4692,7 +4736,7 @@ class LLMEngine:
                 if buf is not None:
                     buf.extend(int(t) for t in slab_np[:, slot])
             self._update_occupancy_gauges()
-        self._readback.put(("spec_block", slab_np, snapshot))
+        self._readback.put(("spec_block", slab_np, snapshot, span))
 
     def warmup_spec_shapes(self) -> None:
         """Compile the spec verify executable at every attention-window
@@ -4837,16 +4881,18 @@ class LLMEngine:
                 # path as plain decode. Rows past their stop are skipped
                 # token-by-token, exactly like slab overrun.
                 out_np, acc_np = handle
-                for slot, req in slots:
-                    if not req.finished:
-                        self._emit(req, out_np[slot, : int(acc_np[slot]) + 1])
+                self._hand_off(
+                    ((req, out_np[slot, : int(acc_np[slot]) + 1])
+                     for slot, req in slots),
+                    *extra,
+                )
                 continue
             if kind == "spec_block":
                 # Zero-draft fallback slab, pre-fetched by the dispatch
                 # thread (which observed the real wait under
                 # kind="spec_block"): emit like a decode slab without
                 # injecting a bogus ~0 s decode-readback sample.
-                self._emit_slab(np.asarray(handle), slots)
+                self._emit_slab(np.asarray(handle), slots, *extra)
                 continue
             if kind == "drain_barrier":
                 # Drain quiesce point (the drain thread enqueues this
@@ -4879,9 +4925,12 @@ class LLMEngine:
                 continue
             if kind == "prefill":
                 values = np.atleast_1d(values)
-                for row, req in slots:
-                    if not req.finished:
-                        self._emit(req, values[row : row + 1], advance=False)
+                # a first token is a hand-off too: it starts its row's
+                # first gap, after the stamp of the wave's last chunk
+                self._hand_off(
+                    ((req, values[row : row + 1]) for row, req in slots),
+                    *extra[1:], advance=False,
+                )
                 # the wave's chunks ran before its first tokens: their
                 # counts are on the host already
                 for fields, stats in (extra[0] if extra else ()):
@@ -4891,7 +4940,7 @@ class LLMEngine:
                 block = self.shapes.decode_block
                 self._note_stats(extra[0], values[block:].reshape(-1))
                 values = values[:block]
-            self._emit_slab(values, slots)
+            self._emit_slab(values, slots, *extra[1:])
 
     def _note_stats(self, fields: Dict[str, int], values: np.ndarray) -> None:
         """A family's counts of one dispatch (models/registry.py
@@ -4905,31 +4954,52 @@ class LLMEngine:
             if counter is not None:
                 counter.inc(int(value))
 
-    def _emit_slab(self, slab: np.ndarray, slots) -> None:
+    def _hand_off(self, rows, span=None, advance: bool = True) -> None:
+        """One readback's tokens, request by request: ``rows`` yields
+        (request, its tokens); a request that finished in an earlier
+        readback overran past its stop and is skipped. With the timeline
+        on, what each row's gap since its previous hand-off was made of
+        is split against the device clock, read once the launch being
+        read back (``span``) is stamped, and the longest gap's parts land
+        in that span's fields (engine/dispatch_timeline.py
+        ``HandoffBlock``)."""
+        gaps = None if self._dtl is None else self._dtl.HandoffBlock(span)
+        for req, tokens in rows:
+            if not req.finished:
+                self._emit(req, tokens, advance, gaps)
+        if gaps is not None:
+            gaps.close()
+
+    def _emit_slab(self, slab: np.ndarray, slots, span=None) -> None:
         """A decode slab ``[block, batch]``, oldest step first, walked
         request by request: a row's tokens of one slab reach its stream
-        in ONE put. A request that finished in an earlier slab overran
-        past its stop and is skipped."""
-        for slot, req in slots:
-            if not req.finished:
-                self._emit(req, slab[:, slot])
+        in ONE put."""
+        self._hand_off(((req, slab[:, slot]) for slot, req in slots), span)
 
-    def _emit(self, req: _Request, tokens: np.ndarray, advance: bool = True) -> None:
+    def _emit(self, req: _Request, tokens: np.ndarray, advance: bool = True,
+              gaps=None) -> None:
         """Reader-thread accounting of one request's tokens of one
         readback, in order: each counted as before (position, stop ids,
         ``max_tokens``, the latency histograms), all handed to the
         stream in one put; queues _END + frees the slot. Tokens past the
         one that ends the request are dropped. ``advance=False``: a
-        prefill's first token, whose position the admission counted."""
+        prefill's first token, whose position the admission counted.
+        The tokens arrive at ONE instant: the wall clock is read once a
+        call, and ``gaps`` (the readback's ``HandoffBlock``) splits the
+        gap since the request's previous hand-off."""
         stop_ids = self._stop_ids
         block: List[Optional[int]] = []
         done = False
+        now = time.time()
+        last = req.t_last_token
+        if gaps is not None:
+            gaps.handoff(req, now)
+        req.t_last_token = now
         for token in tokens.tolist():
             if advance:
                 req.position += 1
             req.generated += 1
             req.emitted.append(token)
-            now = time.time()
             if req.generated == 1 and req.t_submit:
                 ttft = now - req.t_submit
                 _M_TTFT.observe(ttft, trace_id=req.trace_hex)
@@ -4940,11 +5010,11 @@ class LLMEngine:
                 flight_recorder.event_rid(
                     req.rid, "first_token", ttft_s=round(ttft, 6)
                 )
-            elif req.t_last_token:
-                itl = now - req.t_last_token
+            elif last:
+                itl = now - last
                 _M_TOKEN_LATENCY.observe(itl, trace_id=req.trace_hex)
                 slo_mod.observe_latency("inter_token_p95", itl)
-            req.t_last_token = now
+            last = now
             done = (
                 token in stop_ids
                 or req.generated >= req.params.max_tokens
@@ -4961,7 +5031,8 @@ class LLMEngine:
             req.finished = True
             block.append(_END)
         if block:
-            req.out_queue.put_many(block)
+            # the put's time rides along only where the gap is split too
+            req.out_queue.put_many(block, now if gaps is not None else 0.0)
         if done:
             # The reader's own count and stop reason: the eager
             # decode_leave event fires at dispatch time, before the

@@ -264,7 +264,7 @@ def test_handoff_counters_count_items_and_their_tokens():
 def _reader_stub(stop_ids=(), max_seq_len=4096):
     return types.SimpleNamespace(
         _stop_ids=set(stop_ids), max_seq_len=max_seq_len,
-        _release_q=queue.Queue(), _lock=threading.Condition(),
+        _release_q=queue.Queue(), _lock=threading.Condition(), _dtl=None,
     )
 
 
@@ -278,14 +278,15 @@ class _CountingQueue(llm_engine._TokenQueue):
         super().__init__()
         self.puts = []
 
-    def put_many(self, items):
+    def put_many(self, items, t_put=0.0):
         self.puts.append(list(items))
-        super().put_many(items)
+        super().put_many(items, t_put)
 
 
 def test_emit_slab_hands_each_request_its_tokens_in_one_put():
     stub = _reader_stub()
-    stub._emit = lambda req, tokens, advance=True: LLMEngine._emit(stub, req, tokens, advance)
+    stub._emit = lambda *args: LLMEngine._emit(stub, *args)
+    stub._hand_off = lambda *args: LLMEngine._hand_off(stub, *args)
     reqs = [_request(out_queue=_CountingQueue(), position=10) for _ in range(3)]
     reqs[1].finished = True  # overran past its stop in an earlier slab
     slab = np.arange(8 * 4).reshape(8, 4)  # [block, batch]
