@@ -46,7 +46,7 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
-from generativeaiexamples_tpu.models.glm5next import _mm, moe, rms_norm, swiglu_mlp
+from generativeaiexamples_tpu.models.glm5next import MOE_STAT_NAMES, _mm, moe, rms_norm, swiglu_mlp
 from generativeaiexamples_tpu.models.phi4flash import _write_rows
 from generativeaiexamples_tpu.ops import page_attention
 
@@ -54,8 +54,7 @@ Params = Dict[str, Any]
 Caches = Dict[str, Any]
 _NEG = -1e30
 
-STAT_NAMES = ("moe_pairs_held", "moe_pairs_absent", "moe_experts_hit", "moe_experts_held",
-              "window_tokens_read", "full_tokens_read")
+STAT_NAMES = MOE_STAT_NAMES + ("window_tokens_read", "full_tokens_read")
 
 _PUBLISHED_TYPES = ("sliding_attention", "sliding_attention", "sliding_attention", "full_attention") * 8
 
@@ -441,7 +440,7 @@ def _chunk_walk(params: Params, cfg: AfmoeConfig, caches: Caches, tokens, offset
     ring_lead = jnp.broadcast_to(slots[:, None], ring_at.shape)
     window_read = jnp.zeros((), jnp.int32)
     full_read = jnp.zeros((), jnp.int32)
-    moe_stats = jnp.zeros((4,), jnp.int32)
+    moe_stats = jnp.zeros((len(MOE_STAT_NAMES),), jnp.int32)
 
     x = embed(params, cfg, tokens)  # [N, C, D]
     new = {"full": list(caches["full"]), "win": list(caches["win"])}
@@ -539,7 +538,7 @@ def decode_paged(params: Params, cfg: AfmoeConfig, caches: Caches, tokens, posit
     keys_seen = jnp.where(live, positions + 1, 0)
     window_read = jnp.zeros((), jnp.int32)
     full_read = jnp.zeros((), jnp.int32)
-    moe_stats = jnp.zeros((4,), jnp.int32)
+    moe_stats = jnp.zeros((len(MOE_STAT_NAMES),), jnp.int32)
 
     x = embed(params, cfg, tokens[:, None])  # [B, 1, D]
     new = {"full": list(caches["full"]), "win": list(caches["win"])}

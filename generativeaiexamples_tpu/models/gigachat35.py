@@ -52,7 +52,7 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
-from generativeaiexamples_tpu.models.glm5next import _mm, _write_rows, moe, rms_norm, swiglu_mlp
+from generativeaiexamples_tpu.models.glm5next import MOE_STAT_NAMES, _mm, _write_rows, moe, rms_norm, swiglu_mlp
 from generativeaiexamples_tpu.ops import delta_rule, latent_attention
 
 Params = Dict[str, Any]
@@ -62,8 +62,8 @@ _NEG = -1e30
 _LANE = 128
 GDN_BLOCK = 64
 
-STAT_NAMES = ("moe_pairs_held", "moe_pairs_absent", "moe_experts_hit", "moe_experts_held",
-              "latent_tokens_read", "latent_chunk_kernel_layers", "latent_chunk_xla_layers", "state_kernel_rows")
+STAT_NAMES = MOE_STAT_NAMES + ("latent_tokens_read", "latent_chunk_kernel_layers", "latent_chunk_xla_layers",
+                               "state_kernel_rows")
 
 
 @dataclasses.dataclass(frozen=True)
@@ -682,7 +682,7 @@ def _chunk_walk(params: Params, cfg: GigaChat35Config, caches: Caches, tokens, o
 
     x = params["embed"][tokens].astype(jnp.float32)  # [N, C, D]
     new = {k: list(v) if isinstance(v, list) else v for k, v in caches.items()}
-    moe_stats = jnp.zeros((4,), jnp.int32)
+    moe_stats = jnp.zeros((len(MOE_STAT_NAMES),), jnp.int32)
     latent_read = jnp.zeros((), jnp.int32)
     i_gdn = i_mla = 0
     for l, (mixer, mlp) in enumerate(cfg.layers):
@@ -779,7 +779,7 @@ def decode_paged(params: Params, cfg: GigaChat35Config, caches: Caches, tokens, 
 
     x = params["embed"][tokens].astype(jnp.float32)  # [B, D]
     new = {k: list(v) if isinstance(v, list) else v for k, v in caches.items()}
-    moe_stats = jnp.zeros((4,), jnp.int32)
+    moe_stats = jnp.zeros((len(MOE_STAT_NAMES),), jnp.int32)
     latent_read = jnp.zeros((), jnp.int32)
     i_gdn = i_mla = 0
     for l, (mixer, mlp) in enumerate(cfg.layers):

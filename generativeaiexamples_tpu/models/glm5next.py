@@ -63,8 +63,10 @@ _HI = lax.Precision.HIGHEST
 _NEG = -1e30
 KDA_BLOCK = 16
 
-STAT_NAMES = ("moe_pairs_held", "moe_pairs_absent", "moe_experts_hit", "moe_experts_held",
-              "dsa_tokens_selected", "dsa_context_tokens", "state_kernel_rows")
+# what ``moe`` counts, in the order it returns them: every expert family's STAT_NAMES starts with these
+MOE_STAT_NAMES = ("moe_pairs_held", "moe_pairs_absent", "moe_experts_hit", "moe_experts_held",
+                  "moe_tiles_used", "moe_tiles_planned")
+STAT_NAMES = MOE_STAT_NAMES + ("dsa_tokens_selected", "dsa_context_tokens", "state_kernel_rows")
 
 
 @dataclasses.dataclass(frozen=True)
@@ -409,7 +411,9 @@ def route(x, lp: Params, cfg: Glm5NextConfig):
 def moe(x, lp: Params, cfg: Glm5NextConfig, count, kernel: Optional[str], expert_dtype=None,
         oai_alpha: Optional[float] = None):
     """x [N, D] -> (shared expert + held routed experts [N, D] float32,
-    stats [4]: pairs held, pairs absent, experts hit, experts held) over
+    stats [6] in ``MOE_STAT_NAMES``' order: pairs held, pairs absent,
+    experts hit, experts held, row tiles the grouped product ran, row
+    tiles its plan sized for the worst case) over
     the tokens ``count`` [N] bool marks. ``expert_dtype``: what the routed
     experts multiply x in (None: as it comes); the router scores x as it
     comes either way. ``oai_alpha``: the activation of routed and shared
@@ -427,8 +431,9 @@ def moe(x, lp: Params, cfg: Glm5NextConfig, count, kernel: Optional[str], expert
         shared = swiglu_mlp(x, lp["ws_gate_up"], lp["ws_down"], cfg.swiglu_limit, oai_alpha)
     n_held = jnp.sum(sizes)
     n_all = jnp.sum(count.astype(jnp.int32)) * cfg.num_experts_per_tok
-    stats = jnp.stack([n_held, n_all - n_held, jnp.sum((sizes > 0).astype(jnp.int32)),
-                       jnp.asarray(E, jnp.int32)]).astype(jnp.int32)
+    tiles_used, tiles_planned = expert_ops.tile_counts(sizes, top.size)
+    stats = jnp.stack([n_held, n_all - n_held, jnp.sum((sizes > 0).astype(jnp.int32)), jnp.asarray(E, jnp.int32),
+                       tiles_used, jnp.asarray(tiles_planned, jnp.int32)]).astype(jnp.int32)
     return shared + routed, stats
 
 
@@ -716,7 +721,7 @@ def _chunk_walk(params: Params, cfg: Glm5NextConfig, caches: Caches, tokens, off
 
     X = _embed_streams(params, cfg, tokens)  # [N, C, n, D]
     new = {k: list(v) if isinstance(v, list) else v for k, v in caches.items()}
-    moe_stats = jnp.zeros((4,), jnp.int32)
+    moe_stats = jnp.zeros((len(MOE_STAT_NAMES),), jnp.int32)
     dsa_stats = jnp.zeros((2,), jnp.int32)
     i_kda = i_dsa = 0
     for l, (mixer, mlp) in enumerate(cfg.layers):
@@ -834,7 +839,7 @@ def decode_paged(params: Params, cfg: Glm5NextConfig, caches: Caches, tokens, po
 
     X = _embed_streams(params, cfg, tokens)  # [B, n, D]
     new = {k: list(v) if isinstance(v, list) else v for k, v in caches.items()}
-    moe_stats = jnp.zeros((4,), jnp.int32)
+    moe_stats = jnp.zeros((len(MOE_STAT_NAMES),), jnp.int32)
     dsa_stats = jnp.zeros((2,), jnp.int32)
     i_kda = i_dsa = 0
     for l, (mixer, mlp) in enumerate(cfg.layers):

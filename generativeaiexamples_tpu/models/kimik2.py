@@ -44,15 +44,14 @@ from generativeaiexamples_tpu.models.gigachat35 import (
     _attend_absorbed, _attend_expanded, _draw, _mla_output, _mla_project, chunk_read_stats, latent_chunk_kind,
     yarn_mscale,
 )
-from generativeaiexamples_tpu.models.glm5next import _mm, _write_rows, moe, rms_norm, swiglu_mlp
+from generativeaiexamples_tpu.models.glm5next import MOE_STAT_NAMES, _mm, _write_rows, moe, rms_norm, swiglu_mlp
 from generativeaiexamples_tpu.ops import latent_attention
 
 Params = Dict[str, Any]
 Caches = Dict[str, Any]
 _LANE = 128
 
-STAT_NAMES = ("moe_pairs_held", "moe_pairs_absent", "moe_experts_hit", "moe_experts_held",
-              "latent_tokens_read", "latent_chunk_kernel_layers", "latent_chunk_xla_layers")
+STAT_NAMES = MOE_STAT_NAMES + ("latent_tokens_read", "latent_chunk_kernel_layers", "latent_chunk_xla_layers")
 
 
 @dataclasses.dataclass(frozen=True)
@@ -316,7 +315,7 @@ def _chunk_walk(params: Params, cfg: KimiK2Config, caches: Caches, tokens, offse
 
     x = params["embed"][tokens].astype(jnp.float32)  # [N, C, D]
     new = dict(caches, lat=list(caches["lat"]))
-    moe_stats = jnp.zeros((4,), jnp.int32)
+    moe_stats = jnp.zeros((len(MOE_STAT_NAMES),), jnp.int32)
     for l, mlp in enumerate(cfg.layers):
         lp = params["layers"][l]
         with jax.named_scope("latent_read"):
@@ -370,7 +369,7 @@ def decode_paged(params: Params, cfg: KimiK2Config, caches: Caches, tokens, posi
 
     x = params["embed"][tokens].astype(jnp.float32)  # [B, D]
     new = dict(caches, lat=list(caches["lat"]))
-    moe_stats = jnp.zeros((4,), jnp.int32)
+    moe_stats = jnp.zeros((len(MOE_STAT_NAMES),), jnp.int32)
     for l, mlp in enumerate(cfg.layers):
         lp = params["layers"][l]
         with jax.named_scope("latent_read"):

@@ -70,7 +70,7 @@ import jax.numpy as jnp
 from jax import lax
 
 from generativeaiexamples_tpu.models.afmoe import _draw, _gqa, _heads_first, rope
-from generativeaiexamples_tpu.models.glm5next import _mm, moe, rms_norm, swiglu_mlp
+from generativeaiexamples_tpu.models.glm5next import MOE_STAT_NAMES, _mm, moe, rms_norm, swiglu_mlp
 from generativeaiexamples_tpu.models.phi4flash import _write_rows
 from generativeaiexamples_tpu.ops import page_attention, selected_chunk_read
 
@@ -79,11 +79,11 @@ Caches = Dict[str, Any]
 _HI = lax.Precision.HIGHEST
 _NEG = -1e30
 
-STAT_NAMES = ("moe_pairs_held", "moe_pairs_absent", "moe_experts_hit", "moe_experts_held",
-              "msa_pages_selected", "msa_pages_live", "msa_blocks_scored", "msa_pages_pooled",
-              # the chunk walk's read: layers by path, and of the kernel's (KV head, tile, block) items those
-              # whose block some query of the tile selected, summed over the layers (a decode step: zeros)
-              "msa_chunk_kernel_layers", "msa_chunk_xla_layers", "msa_chunk_blocks_read", "msa_chunk_blocks_live")
+STAT_NAMES = MOE_STAT_NAMES + (
+    "msa_pages_selected", "msa_pages_live", "msa_blocks_scored", "msa_pages_pooled",
+    # the chunk walk's read: layers by path, and of the kernel's (KV head, tile, block) items those
+    # whose block some query of the tile selected, summed over the layers (a decode step: zeros)
+    "msa_chunk_kernel_layers", "msa_chunk_xla_layers", "msa_chunk_blocks_read", "msa_chunk_blocks_live")
 
 
 @dataclasses.dataclass(frozen=True)
@@ -490,7 +490,7 @@ def _chunk_walk(params: Params, cfg: MiniMaxM3Config, caches: Caches, tokens, of
     x = params["embed"][tokens].astype(jnp.float32)  # [N, C, D]
     dtype = params["embed"].dtype
     new = dict(caches, kv=list(caches["kv"]))
-    moe_stats = jnp.zeros((4,), jnp.int32)
+    moe_stats = jnp.zeros((len(MOE_STAT_NAMES),), jnp.int32)
     msa_stats = jnp.zeros((4,), jnp.int32)
     blocks_read = jnp.zeros((), jnp.int32)
     kept = []
@@ -574,7 +574,7 @@ def decode_paged(params: Params, cfg: MiniMaxM3Config, caches: Caches, tokens, p
     x = params["embed"][tokens].astype(jnp.float32)[:, None]  # [B, 1, D]
     dtype = params["embed"].dtype
     new = dict(caches, kv=list(caches["kv"]))
-    moe_stats = jnp.zeros((4,), jnp.int32)
+    moe_stats = jnp.zeros((len(MOE_STAT_NAMES),), jnp.int32)
     msa_stats = jnp.zeros((4,), jnp.int32)
     kept = []
     for l, mlp in enumerate(cfg.layers):

@@ -53,7 +53,7 @@ import jax.numpy as jnp
 from jax import lax
 
 from generativeaiexamples_tpu.models.afmoe import _attend_pages, _attn_output, _draw, _gqa, _heads_first
-from generativeaiexamples_tpu.models.glm5next import _kda_inputs, _kda_output, _mm, kda_step, moe, rms_norm
+from generativeaiexamples_tpu.models.glm5next import MOE_STAT_NAMES, _kda_inputs, _kda_output, _mm, kda_step, moe, rms_norm
 from generativeaiexamples_tpu.models.phi4flash import _write_rows
 from generativeaiexamples_tpu.ops import delta_rule, page_attention
 
@@ -63,8 +63,7 @@ _HI = lax.Precision.HIGHEST
 KDA_BLOCK = 16
 BETA_SCALE = 2.0  # kda_allow_neg_eigval
 
-STAT_NAMES = ("moe_pairs_held", "moe_pairs_absent", "moe_experts_hit", "moe_experts_held",
-              "full_tokens_read", "state_kernel_rows")
+STAT_NAMES = MOE_STAT_NAMES + ("full_tokens_read", "state_kernel_rows")
 # the top-level keys of the cache pytree whose leaves hold ONE ROW A SLOT
 # (models/registry.py ``state_row_keys``): what a prefix entry carries
 STATE_ROW_KEYS = ("kda", "conv")
@@ -420,7 +419,7 @@ def _chunk_walk(params: Params, cfg: SolarOpen2Config, caches: Caches, tokens, o
     row_tables = tables[slots]
     causal = positions[:, :, None] >= positions[:, None, :]  # [N, C, C] chunk keys
     full_read = jnp.zeros((), jnp.int32)
-    moe_stats = jnp.zeros((4,), jnp.int32)
+    moe_stats = jnp.zeros((len(MOE_STAT_NAMES),), jnp.int32)
     kda = cfg.kda
 
     x = _embed(params, tokens)  # [N, C, D]
@@ -514,7 +513,7 @@ def decode_paged(params: Params, cfg: SolarOpen2Config, caches: Caches, tokens, 
         tables, positions, 1, page_size, page_attention.pages_per_step(caches["full"][0]["k"])
     ) if page_kernel and caches["full"] else None
     full_read = jnp.zeros((), jnp.int32)
-    moe_stats = jnp.zeros((4,), jnp.int32)
+    moe_stats = jnp.zeros((len(MOE_STAT_NAMES),), jnp.int32)
     kda = cfg.kda
 
     x = _embed(params, tokens[:, None])  # [B, 1, D]

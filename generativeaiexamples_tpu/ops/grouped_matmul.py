@@ -12,10 +12,16 @@ so that every tile belongs to ONE expert. The kernel's grid is
 ``(column blocks, tiles)`` with the tile's expert handed over by scalar
 prefetch: the weight block index is ``(expert[tile], 0, column block)``,
 so consecutive tiles of one expert reuse the block in VMEM and an
-expert without a tile is never fetched. The number of tiles is static
-(``ceil(pairs / tm) + experts``, the worst case); tiles past the used
-ones repeat the last used tile's expert (no DMA) and skip their
-compute. Bytes streamed: the hit experts' matrices, once.
+expert without a tile is never fetched. The PLAN's number of tiles is
+static (``ceil(pairs / tm) + experts``: the worst case, every pair held
+and a part tile an expert), and an expert-parallel chip holds a few of a
+dispatch's pairs, so the GRID's tile axis is dynamic: it ends at
+``max(tiles_used, 1)``, a number the plan computes on the device. The
+tiles past it take no grid step and move no byte; the rows of ``a`` and
+``y`` they would have written are UNINITIALISED, and ``grouped_mlp``'s
+combine reads a row only for a held pair. Tile 0 always runs (over zero
+rows where nothing is held). Bytes streamed: the hit experts' matrices,
+once, and the used tiles' rows.
 
 Two products make the expert MLP:
 
@@ -86,32 +92,31 @@ def plan(local_expert: jax.Array, num_experts: int, tm: int) -> GroupPlan:
                      used.reshape(1).astype(jnp.int32), sizes)
 
 
-def _gate_up_kernel(expert_ref, used_ref, x_ref, wg_ref, wu_ref, o_ref, *, limit: float,
-                    oai_alpha: Optional[float] = None):
+def tile_counts(sizes: jax.Array, pairs: int):
+    """(row tiles ``plan`` lays out for held groups of ``sizes`` [E]: its
+    ``tiles_used``, [] int32; row tiles it plans for ``pairs`` pairs, all
+    held: a Python int) of the dispatch ``grouped_mlp`` would make."""
+    tm = row_tile(pairs)
+    return jnp.sum(-(-sizes // tm)).astype(jnp.int32), -(-pairs // tm) + sizes.shape[0]
+
+
+def _gate_up_kernel(expert_ref, x_ref, wg_ref, wu_ref, o_ref, *, limit: float, oai_alpha: Optional[float] = None):
     del expert_ref
-
-    @pl.when(pl.program_id(1) < used_ref[0])
-    def _():
-        x = x_ref[...]
-        g = jnp.dot(x, wg_ref[0], preferred_element_type=jnp.float32)
-        u = jnp.dot(x, wu_ref[0], preferred_element_type=jnp.float32)
-        o_ref[...] = swiglu(g, u, limit, oai_alpha).astype(o_ref.dtype)
-
-    @pl.when(pl.program_id(1) >= used_ref[0])
-    def _():
-        o_ref[...] = jnp.zeros_like(o_ref)
+    x = x_ref[...]
+    g = jnp.dot(x, wg_ref[0], preferred_element_type=jnp.float32)
+    u = jnp.dot(x, wu_ref[0], preferred_element_type=jnp.float32)
+    o_ref[...] = swiglu(g, u, limit, oai_alpha).astype(o_ref.dtype)
 
 
-def _down_kernel(expert_ref, used_ref, a_ref, w_ref, o_ref):
+def _down_kernel(expert_ref, a_ref, w_ref, o_ref):
     del expert_ref
+    o_ref[...] = jnp.dot(a_ref[...], w_ref[0], preferred_element_type=jnp.float32)
 
-    @pl.when(pl.program_id(1) < used_ref[0])
-    def _():
-        o_ref[...] = jnp.dot(a_ref[...], w_ref[0], preferred_element_type=jnp.float32)
 
-    @pl.when(pl.program_id(1) >= used_ref[0])
-    def _():
-        o_ref[...] = jnp.zeros_like(o_ref)
+def _tiles_run(tiles_used):
+    """The grid's dynamic tile bound: tile 0 runs even where nothing is
+    held, so row 0 of the output is always written."""
+    return jnp.maximum(tiles_used[0], 1)
 
 
 _LANES = 128
@@ -135,57 +140,57 @@ _VMEM_LIMIT = 64 * 1024 * 1024
 def grouped_gate_up(x, w_gu, tile_expert, tiles_used, *, tm: int, limit: float, interpret: bool = False,
                     oai_alpha: Optional[float] = None):
     """x [M, D] (rows in plan order) x w_gu [E, D, 2F] -> the SwiGLU
-    activation [M, F] in x's dtype (``oai_alpha``: ``swiglu``'s)."""
+    activation [M, F] in x's dtype (``oai_alpha``: ``swiglu``'s); rows
+    past ``max(tiles_used, 1) * tm`` are not written."""
     M, D = x.shape
     E, _, F2 = w_gu.shape
     F = F2 // 2
     tn = _col_block(F, 512)
     nb = F // tn
-    T = M // tm
     return pl.pallas_call(
         functools.partial(_gate_up_kernel, limit=limit, oai_alpha=oai_alpha),
         grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=2,
-            grid=(nb, T),
+            num_scalar_prefetch=1,
+            grid=(nb, _tiles_run(tiles_used)),
             in_specs=[
-                pl.BlockSpec((tm, D), lambda n, t, ex, used: (t, 0)),
-                pl.BlockSpec((1, D, tn), lambda n, t, ex, used: (ex[t], 0, n)),
-                pl.BlockSpec((1, D, tn), lambda n, t, ex, used: (ex[t], 0, n + nb)),
+                pl.BlockSpec((tm, D), lambda n, t, ex: (t, 0)),
+                pl.BlockSpec((1, D, tn), lambda n, t, ex: (ex[t], 0, n)),
+                pl.BlockSpec((1, D, tn), lambda n, t, ex: (ex[t], 0, n + nb)),
             ],
-            out_specs=pl.BlockSpec((tm, tn), lambda n, t, ex, used: (t, n)),
+            out_specs=pl.BlockSpec((tm, tn), lambda n, t, ex: (t, n)),
         ),
         out_shape=jax.ShapeDtypeStruct((M, F), x.dtype),
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary", "arbitrary"), vmem_limit_bytes=_VMEM_LIMIT),
         interpret=interpret,
         name="grouped_matmul_gate_up",
-    )(tile_expert, tiles_used, x, w_gu, w_gu)
+    )(tile_expert, x, w_gu, w_gu)
 
 
 @functools.partial(jax.jit, static_argnames=("tm", "interpret"))
 def grouped_down(a, w_d, tile_expert, tiles_used, *, tm: int, interpret: bool = False):
-    """a [M, F] x w_d [E, F, D] -> [M, D] float32."""
+    """a [M, F] x w_d [E, F, D] -> [M, D] float32; rows past
+    ``max(tiles_used, 1) * tm`` are not written."""
     M, F = a.shape
     E, _, D = w_d.shape
     tn = _col_block(D, 1024)
-    T = M // tm
     return pl.pallas_call(
         _down_kernel,
         grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=2,
-            grid=(D // tn, T),
+            num_scalar_prefetch=1,
+            grid=(D // tn, _tiles_run(tiles_used)),
             in_specs=[
-                pl.BlockSpec((tm, F), lambda n, t, ex, used: (t, 0)),
-                pl.BlockSpec((1, F, tn), lambda n, t, ex, used: (ex[t], 0, n)),
+                pl.BlockSpec((tm, F), lambda n, t, ex: (t, 0)),
+                pl.BlockSpec((1, F, tn), lambda n, t, ex: (ex[t], 0, n)),
             ],
-            out_specs=pl.BlockSpec((tm, tn), lambda n, t, ex, used: (t, n)),
+            out_specs=pl.BlockSpec((tm, tn), lambda n, t, ex: (t, n)),
         ),
         out_shape=jax.ShapeDtypeStruct((M, D), jnp.float32),
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary", "arbitrary"), vmem_limit_bytes=_VMEM_LIMIT),
         interpret=interpret,
         name="grouped_matmul_down",
-    )(tile_expert, tiles_used, a, w_d)
+    )(tile_expert, a, w_d)
 
 
 def swiglu(g, u, limit: float, oai_alpha: Optional[float] = None):
@@ -222,7 +227,9 @@ def grouped_mlp(x, local_expert, gates, w_gu, w_d, *, limit: float, kernel: Opti
     a = grouped_gate_up(rows, w_gu, p.tile_expert, p.tiles_used, tm=tm, limit=float(limit), interpret=interpret,
                         oai_alpha=oai_alpha)
     y = grouped_down(a, w_d, p.tile_expert, p.tiles_used, tm=tm, interpret=interpret)
-    M = y.shape[0]
-    picked = jnp.take(y, jnp.minimum(p.dest, M - 1), axis=0)  # [N, k, D]
-    g = jnp.where(p.dest < M, gates, 0.0)
+    # y's rows past the used tiles are uninitialised: an absent pair reads row 0, which tile 0 always writes,
+    # and adds 0.0 whatever that row holds (garbage times a zero gate could be NaN)
+    held = p.dest < y.shape[0]
+    picked = jnp.where(held[:, :, None], jnp.take(y, jnp.where(held, p.dest, 0), axis=0), 0.0)  # [N, k, D]
+    g = jnp.where(held, gates, 0.0)
     return jnp.sum(picked * g[:, :, None], axis=1), p.sizes

@@ -19,6 +19,7 @@ from generativeaiexamples_tpu.models import afmoe as m
 from generativeaiexamples_tpu.models import glm5next, registry
 from generativeaiexamples_tpu.ops import grouped_matmul, page_attention
 from perfbench.arch import afmoe as adapter
+from tests.expert_stats import assert_one_live_row_tiles
 from tests.perfbench.test_perfbench_afmoe import TINY
 
 
@@ -255,6 +256,7 @@ def test_prefill_then_decode_on_dirty_slots(params, sequence, kernel):
     assert stats["window_tokens_read"] == 2 * W and stats["full_tokens_read"] == T + 10
     assert stats["moe_pairs_held"] == 2 * CFG.num_experts_per_tok and stats["moe_pairs_absent"] == 0
     assert stats["moe_experts_held"] == 16 and 2 <= stats["moe_experts_hit"] <= 4
+    assert_one_live_row_tiles(m.STAT_NAMES, stats, CFG, SLOTS)
 
 
 @pytest.mark.parametrize("chunk,kernel", [(4, None), (8, "interpret"), (16, None), (32, None)])

@@ -272,11 +272,15 @@ def test_latent_chunk_read_compiles_at_the_published_widths(one_chip, no_persist
     assert compiled.memory_analysis().temp_size_in_bytes < rows * H * T * (dn + 128) * 2 + (4 << 20)
 
 
-@pytest.mark.parametrize("tokens", [64, 512], ids=["decode-16-row-tiles", "chunk-64-row-tiles"])
-def test_grouped_matmul_compiles_at_7168_wide_experts(one_chip, no_persistent_cache, tokens):
+@pytest.mark.parametrize("tokens,E", [(64, 16), (512, 16), (32, 12)],
+                         ids=["decode-16-row-tiles", "chunk-64-row-tiles", "kimi-decode-12-held"])
+def test_grouped_matmul_compiles_at_7168_wide_experts(one_chip, no_persistent_cache, tokens, E):
     """``row_tile`` and the column blocks were chosen at 4096 x 2048
-    experts; the same kernels at 7168 x 2048, 16 held, 8 pairs a token."""
-    D, F, E, k = 7168, 2048, 16, 8
+    experts; the same kernels at 7168 x 2048, 16 held, 8 pairs a token
+    (and Kimi-K2.5's decode step: 32 rows, 12 held). The grid's tile axis
+    is a run-time bound (PR 55): Mosaic must take it with the tile's
+    expert prefetched beside it."""
+    D, F, k = 7168, 2048, 8
 
     def s(shape, dtype):
         return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)

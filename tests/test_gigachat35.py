@@ -21,6 +21,7 @@ from generativeaiexamples_tpu.models import gigachat35 as m
 from generativeaiexamples_tpu.models import glm5next, registry
 from generativeaiexamples_tpu.ops import latent_attention
 from perfbench.arch import gigachat35 as giga
+from tests.expert_stats import assert_one_live_row_tiles
 from tests.perfbench.test_perfbench_gigachat35 import TINY
 
 
@@ -247,7 +248,7 @@ def test_the_shares_partial_expert_outputs_add_up_to_the_uncut_layer(params):
              "we_down": jax.random.normal(jax.random.fold_in(rng, 2), (E, F, D)) * 0.1}
     count = jnp.ones((10,), bool)
     uncut, stats = m.moe(x, dict(lp, **w_all), whole, count, None)
-    assert stats.tolist() == [40, 0, int(stats[2]), 16]
+    assert stats.tolist() == [40, 0, int(stats[2]), 16, int(stats[2]), 3 + 16]  # a 16-row tile an expert hit of ceil(40 / 16) + 16
     shared = m.swiglu_mlp(x, lp["ws_gate_up"], lp["ws_down"], CFG.swiglu_limit)
     total, held_pairs = shared, 0
     top_whole, _ = glm5next.route(x, lp, whole)
@@ -281,6 +282,7 @@ def test_prefill_then_decode_on_dirty_slots(params, sequence, kernel):
     stats = dict(zip(m.STAT_NAMES, np.asarray(caches["stats"]).tolist()))
     assert stats["latent_tokens_read"] == 60  # every cached token up to the query's own, one latent layer
     assert stats["moe_pairs_held"] + stats["moe_pairs_absent"] == 4 * 4 and stats["moe_experts_held"] == 4 * 2
+    assert_one_live_row_tiles(m.STAT_NAMES, stats, CFG, SLOTS)
 
 
 @pytest.mark.parametrize("chunk,kernel", [(16, None), (32, "interpret"), (64, None)])
@@ -288,7 +290,7 @@ def test_chunked_extend_carries_state_tails_and_pages_from_chunk_to_chunk(params
     toks, full = sequence
     logits, caches = extend(params, dirty_caches(), toks, 2, chunk, kernel, upto=100)
     assert rel(logits, full[99]) < TOL
-    assert int(caches["stats"][4]) == sum(range(100 - (100 - 1) % chunk, 101))  # the last chunk's queries, each to itself
+    assert int(caches["stats"][m.STAT_NAMES.index("latent_tokens_read")]) == sum(range(100 - (100 - 1) % chunk, 101))  # the last chunk's queries, each to itself
     for p in range(100, 106):
         logits, caches = decode(params, caches, {2: (toks[p], p)}, kernel)
         assert rel(logits[2], full[p]) < TOL, p
@@ -333,7 +335,7 @@ def test_rows_decoding_together_equal_their_solo_runs(params, sequence):
     for j in range(4):
         logits, caches = decode(params, caches, {0: (toks[40 + j], 40 + j), 2: (toks[70 + j], 70 + j)}, "interpret")
         assert rel(logits[0], full[40 + j]) < TOL and rel(logits[2], full[70 + j]) < TOL
-    assert int(caches["stats"][4]) == 44 + 74
+    assert int(caches["stats"][m.STAT_NAMES.index("latent_tokens_read")]) == 44 + 74
 
 
 def test_decode_through_the_step_kernel_equals_the_xla_step_in_logits_and_every_cache_leaf(params, sequence):

@@ -19,6 +19,7 @@ import pytest
 from generativeaiexamples_tpu.models import glm5next as m
 from generativeaiexamples_tpu.models import registry
 from generativeaiexamples_tpu.ops import grouped_matmul, latent_attention
+from tests.expert_stats import assert_one_live_row_tiles
 
 
 @pytest.fixture(autouse=True, scope="module")
@@ -250,7 +251,7 @@ def test_eight_chips_partial_expert_outputs_add_up_to_the_uncut_layer(params):
              "we_down": jax.random.normal(jax.random.fold_in(rng, 2), (16, CFG.moe_intermediate_size, CFG.hidden_size)) * 0.1}
     count = jnp.ones((10,), bool)
     uncut, stats = m.moe(x, dict(lp, **w_all), whole, count, None)
-    assert stats.tolist() == [40, 0, int(stats[2]), 16]
+    assert stats.tolist() == [40, 0, int(stats[2]), 16, int(stats[2]), 3 + 16]  # a 16-row tile an expert hit of ceil(40 / 16) + 16
     shared = m.swiglu_mlp(x, lp["ws_gate_up"], lp["ws_down"], CFG.swiglu_limit)
     total, held_pairs = shared, 0
     top_whole, _ = m.route(x, lp, whole)
@@ -284,6 +285,7 @@ def test_prefill_then_decode_past_the_selection_on_dirty_slots(params, sequence,
     stats = dict(zip(m.STAT_NAMES, np.asarray(caches["stats"]).tolist()))
     assert stats["dsa_context_tokens"] == 60 and stats["dsa_tokens_selected"] == 32 + 4  # 8 groups and the open one
     assert stats["moe_pairs_held"] + stats["moe_pairs_absent"] == 4 * 4 and stats["moe_experts_held"] == 4 * 2
+    assert_one_live_row_tiles(m.STAT_NAMES, stats, CFG, SLOTS)
 
 
 @pytest.mark.parametrize("chunk,kernel", [(16, None), (32, "interpret"), (64, None)])
@@ -384,6 +386,7 @@ def test_registry_resolves_the_family_and_what_it_declares():
     for walk in (m.prefill_paged, m.extend_paged, m.decode_paged):
         assert set(paths) <= set(inspect.signature(walk).parameters)
     assert fam.stat_names == m.STAT_NAMES and registry.family_of(CFG).name == "glm5next"
+    assert m.STAT_NAMES[:6] == m.MOE_STAT_NAMES and m.MOE_STAT_NAMES[3:] == ("moe_experts_held", "moe_tiles_used", "moe_tiles_planned")
     assert registry.resolve("phi4flash-debug")[0].stat_names == () and registry.resolve("debug")[0].resolve_kernels(None, "compiled") == {}
 
 

@@ -24,6 +24,7 @@ from generativeaiexamples_tpu.engine import kv_pages
 from generativeaiexamples_tpu.models import gigachat35, glm5next, registry
 from generativeaiexamples_tpu.models import kimik2 as m
 from perfbench.arch import kimik2 as kimi
+from tests.expert_stats import assert_one_live_row_tiles
 from tests.perfbench.test_perfbench_kimik2 import CFG as FILE, TINY, counters
 
 
@@ -225,7 +226,7 @@ def test_the_32_shares_of_a_layer_add_up_to_the_uncut_layer():
              "we_down": jax.random.normal(jax.random.fold_in(rng, 2), (E, F, D)) * 0.1}
     count = jnp.ones((10,), bool)
     uncut, stats = glm5next.moe(x, dict(lp, **w_all), whole, count, None)
-    assert stats.tolist() == [80, 0, int(stats[2]), 384]
+    assert stats.tolist() == [80, 0, int(stats[2]), 384, int(stats[2]), 5 + 384]  # a 16-row tile an expert hit of ceil(80 / 16) + 384
     shared = glm5next.swiglu_mlp(x, lp["ws_gate_up"], lp["ws_down"], cfg.swiglu_limit)
     total, held_pairs = shared, 0
     top_whole, _ = glm5next.route(x, lp, whole)
@@ -265,6 +266,7 @@ def test_one_chunk_then_decode_steps_on_dirty_pages(params, sequence, kernel):
     stats = dict(zip(m.STAT_NAMES, np.asarray(caches["stats"]).tolist()))
     assert stats["latent_tokens_read"] == 60  # every cached token up to the query's own: ONE layer's read
     assert stats["moe_pairs_held"] + stats["moe_pairs_absent"] == 2 * 4 and stats["moe_experts_held"] == 2 * 2
+    assert_one_live_row_tiles(m.STAT_NAMES, stats, CFG, SLOTS)
 
 
 @pytest.mark.parametrize("chunk,kernel", [(16, None), (32, "interpret"), (64, None)])
@@ -272,7 +274,7 @@ def test_chunked_extend_then_decode_through_the_cache(params, sequence, chunk, k
     toks, full = sequence
     logits, caches = extend(params, dirty_caches(), toks, 2, chunk, kernel, upto=100)
     assert rel(logits, full[99]) < TOL
-    assert int(caches["stats"][4]) == sum(range(100 - (100 - 1) % chunk, 101))  # the last chunk's queries, each to itself
+    assert int(caches["stats"][m.STAT_NAMES.index("latent_tokens_read")]) == sum(range(100 - (100 - 1) % chunk, 101))  # the last chunk's queries, each to itself
     for p in range(100, 106):
         logits, caches = decode(params, caches, {2: (toks[p], p)}, kernel)
         assert rel(logits[2], full[p]) < TOL, p
@@ -321,7 +323,7 @@ def test_rows_decoding_together_equal_their_solo_runs(params, sequence):
     for j in range(4):
         logits, caches = decode(params, caches, {0: (toks[40 + j], 40 + j), 2: (toks[70 + j], 70 + j)}, "interpret")
         assert rel(logits[0], full[40 + j]) < TOL and rel(logits[2], full[70 + j]) < TOL
-    assert int(caches["stats"][4]) == 44 + 74
+    assert int(caches["stats"][m.STAT_NAMES.index("latent_tokens_read")]) == 44 + 74
 
 
 def test_several_live_rows_in_one_chunk_equal_their_solo_runs(params, sequence):
@@ -366,8 +368,8 @@ def test_registry_resolves_the_family_and_what_it_declares():
     for walk in (m.prefill_paged, m.extend_paged, m.decode_paged):
         assert set(resolved) <= set(inspect.signature(walk).parameters)
     assert "page_kernel" in inspect.signature(m.decode_paged).parameters  # the engine's own, which serves the latent read
-    assert fam.stat_names == m.STAT_NAMES == ("moe_pairs_held", "moe_pairs_absent", "moe_experts_hit", "moe_experts_held",
-                                              "latent_tokens_read", "latent_chunk_kernel_layers", "latent_chunk_xla_layers")
+    assert fam.stat_names == m.STAT_NAMES == glm5next.MOE_STAT_NAMES + (
+        "latent_tokens_read", "latent_chunk_kernel_layers", "latent_chunk_xla_layers")
     # llama declares everything the engine had given it; the five fixed-state families nothing new
     fams = registry.families()
     assert fams["llama"].sharded and fams["llama"].snapshot_pages and fams["llama"].weight_formats == ("int8", "w8a8")

@@ -28,6 +28,7 @@ from generativeaiexamples_tpu.models import glm5next, registry
 from generativeaiexamples_tpu.models import minimaxm3 as m
 from generativeaiexamples_tpu.ops import grouped_matmul, page_attention
 from perfbench.arch import minimaxm3 as mm
+from tests.expert_stats import assert_one_live_row_tiles
 from tests.perfbench.test_perfbench_minimaxm3 import TINY, counters
 
 
@@ -274,7 +275,7 @@ def test_the_8_shares_of_a_layer_add_up_to_the_uncut_layer():
     count = jnp.ones((10,), bool)
     a = cfg.swiglu_alpha
     uncut, stats = glm5next.moe(x, dict(lp, **w_all), whole, count, None, oai_alpha=a)
-    assert stats.tolist() == [40, 0, int(stats[2]), 128]
+    assert stats.tolist() == [40, 0, int(stats[2]), 128, int(stats[2]), 3 + 128]  # a 16-row tile an expert hit of ceil(40 / 16) + 128
     shared = glm5next.swiglu_mlp(x, lp["ws_gate_up"], lp["ws_down"], cfg.swiglu_limit, a)
     total, held_pairs = shared, 0
     top_whole, _ = glm5next.route(x, lp, whole)
@@ -321,6 +322,7 @@ def test_one_chunk_then_decode_steps_on_dirty_pages(params, sequence, kernel):
     assert stats["msa_pages_selected"] == 5 * 2 * 3 and stats["msa_pages_live"] == 7 * 2 * 3
     assert stats["msa_blocks_scored"] == 4 * 2 * 3 and stats["msa_pages_pooled"] == 3
     assert stats["moe_pairs_held"] + stats["moe_pairs_absent"] == 2 * 4 and stats["moe_experts_held"] == 2 * 2
+    assert_one_live_row_tiles(m.STAT_NAMES, stats, CFG, SLOTS)
 
 
 @pytest.mark.parametrize("chunk,kernel", [(16, None), (32, "interpret"), (64, None)])
@@ -332,7 +334,7 @@ def test_chunked_extend_then_decode_through_the_cache(params, sequence, chunk, k
         logits, caches, _ = decode(params, caches, {2: (toks[p], p)}, kernel)
         assert rel(logits[2], full[p]) < TOL, p
         # the step at 103 writes block 12's last token: a summary a layer, and no other step writes one
-        assert int(caches["stats"][7]) == (3 if p == 103 else 0)
+        assert int(caches["stats"][m.STAT_NAMES.index("msa_pages_pooled")]) == (3 if p == 103 else 0)
 
 
 def test_the_chunk_walk_and_a_decode_step_select_the_same_blocks(params, sequence):
@@ -456,8 +458,7 @@ def test_registry_resolves_the_family_and_what_it_declares():
         assert {"grouped_matmul"} <= set(inspect.signature(walk).parameters)
     assert "selected_read" in inspect.signature(m.decode_paged).parameters
     assert "selected_chunk" in inspect.signature(m.extend_paged).parameters
-    assert fam.stat_names == m.STAT_NAMES == (
-        "moe_pairs_held", "moe_pairs_absent", "moe_experts_hit", "moe_experts_held",
+    assert fam.stat_names == m.STAT_NAMES == glm5next.MOE_STAT_NAMES + (
         "msa_pages_selected", "msa_pages_live", "msa_blocks_scored", "msa_pages_pooled",
         "msa_chunk_kernel_layers", "msa_chunk_xla_layers", "msa_chunk_blocks_read", "msa_chunk_blocks_live")
     # the program's model modules name the family; the engine and the server name no model

@@ -20,6 +20,7 @@ from generativeaiexamples_tpu.models import glm5next, registry
 from generativeaiexamples_tpu.models import solaropen2 as m
 from generativeaiexamples_tpu.ops import grouped_matmul, page_attention
 from perfbench.arch import solaropen2 as adapter
+from tests.expert_stats import assert_one_live_row_tiles
 from tests.perfbench.test_perfbench_solaropen2 import TINY
 
 
@@ -239,6 +240,7 @@ def test_prefill_then_decode_on_dirty_slots(params, sequence, kernel):
     stats = dict(zip(m.STAT_NAMES, np.asarray(caches["stats"]).tolist()))
     assert stats["moe_pairs_held"] + stats["moe_pairs_absent"] == 4 * CFG.num_experts_per_tok  # one live row, four layers
     assert stats["moe_experts_held"] == 4 * CFG.experts_held and stats["full_tokens_read"] == 48
+    assert_one_live_row_tiles(m.STAT_NAMES, stats, CFG, SLOTS)
     assert stats["state_kernel_rows"] == (1 if kernel else 0)
 
 
