@@ -14,6 +14,8 @@ import pytest
 
 from perfbench import arch, reference
 from perfbench.arch import afmoe as adapter
+from tests.perfbench.manifest_entries import assert_cell_holds, entries_of
+from tests.perfbench.manifest_entries import metric_spec as _metric
 
 ROOT = os.path.dirname(os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 BENCH = os.path.join(ROOT, "perfbench")
@@ -282,12 +284,6 @@ CHUNK = {"kind": "prefill_chunk", "rows": 1, "moe_experts_hit": 512, "moe_expert
 PARENT_SPANS = [{"kind": "decode", "rows": 60}]
 
 
-def _metric(name):
-    """A manifest name's file: its own, or its base's."""
-    own = os.path.join(BENCH, "layer_metrics", name + ".json")
-    return load(own if os.path.exists(own) else os.path.join(BENCH, "layer_metrics", name.rsplit(".", 1)[0] + ".json"))
-
-
 def _read(name, ctx):
     from perfbench import readers
 
@@ -297,16 +293,16 @@ def _read(name, ctx):
 
 def test_span_readers_and_what_a_parent_without_the_fields_gives():
     ctx = _ctx([DECODE, dict(DECODE, moe_experts_hit=500, moe_pairs_held=2000), CHUNK])
-    assert _read("moe_experts_hit_share.trinity", ctx) == pytest.approx(100 * 980 / 1024)
-    assert _read("moe_pairs_per_expert_mean.trinity", ctx) == pytest.approx(3920 / 980)
-    assert _read("window_tokens_read_mean.trinity", ctx) == pytest.approx(60 * 4 * 2048)
-    assert _read("state_rows_mean.trinity", ctx) == 60
+    assert _read("moe_experts_hit_share", ctx) == pytest.approx(100 * 980 / 1024)
+    assert _read("moe_pairs_per_expert_mean", ctx) == pytest.approx(3920 / 980)
+    assert _read("window_tokens_read_mean", ctx) == pytest.approx(60 * 4 * 2048)
+    assert _read("state_rows_mean", ctx) == 60
     assert _read("window_read_share.trinity", ctx) == pytest.approx(100 * 4 * 2048 / (4 * 2048 + 5000))
     share = _read("decode_step_roofline_share.trinity", ctx)
     want = adapter.decode_step_floor_s(CFG, ctx["peaks"], 60, 5000, 490, 60 * 4 * 2048, 60 * 5000) / 0.014 * 100
     assert share == pytest.approx(want) and 60 < share < 100
     parent = _ctx(PARENT_SPANS)
-    for name in ("moe_experts_hit_share.trinity", "moe_pairs_per_expert_mean.trinity", "window_tokens_read_mean.trinity",
+    for name in ("moe_experts_hit_share", "moe_pairs_per_expert_mean", "window_tokens_read_mean",
                  "window_read_share.trinity", "decode_step_roofline_share.trinity"):
         assert _read(name, parent) is None
 
@@ -320,8 +316,8 @@ def test_the_grouped_matmul_roofline_counts_the_experts_hit_and_stays_under_the_
     hits = 60 * block * 480 + 8 * 512
     got = _read("grouped_matmul_roofline_share.trinity", ctx)
     assert got == pytest.approx(100 * hits * 12_582_912 / 819e9 / 0.95) and got < 100
-    assert _read("grouped_matmul_busy_share.trinity", ctx) == pytest.approx(100 * 0.95 / 2.4)
-    assert _read("page_attn_busy_share.trinity", ctx) == pytest.approx(100 * 0.05 / 2.4)
+    assert _read("grouped_matmul_busy_share", ctx) == pytest.approx(100 * 0.95 / 2.4)
+    assert _read("page_attn_busy_share", ctx) == pytest.approx(100 * 0.05 / 2.4)
     assert _read("grouped_matmul_roofline_share.trinity", _ctx([DECODE], None)) is None  # an untraced run
     assert _read("grouped_matmul_roofline_share.trinity",
                  _ctx(PARENT_SPANS, dict(trace, ops_self_s={"fusion": 1.0}))) is None  # the parent
@@ -392,68 +388,52 @@ def test_configuration_engine_reference_and_memory_plan():
 
 # the per-layer entries ISSUE 42 names for the cell; a later PR may append more
 NAMED = (
-    "decode_rows_mean.trinity", "decode_step_dev_ms.trinity", "tpot_chat_p50_ms.trinity", "device_idle_share.trinity",
-    "stream_backlog_tokens_mean.trinity", "state_rows_mean.trinity", "extend_dispatch_dev_ms.trinity",
-    "page_attn_busy_share.trinity", "page_attn_pages_walked_mean.trinity", "window_tokens_read_mean.trinity",
-    "moe_experts_hit_share.trinity", "moe_pairs_per_expert_mean.trinity", "grouped_matmul_busy_share.trinity",
+    "decode_rows_mean", "decode_step_dev_ms", "tpot_chat_p50_ms", "device_idle_share",
+    "stream_backlog_tokens_mean", "state_rows_mean", "extend_dispatch_dev_ms",
+    "page_attn_busy_share", "page_attn_pages_walked_mean", "window_tokens_read_mean",
+    "moe_experts_hit_share", "moe_pairs_per_expert_mean", "grouped_matmul_busy_share",
     "grouped_matmul_roofline_share.trinity", "decode_step_roofline_share.trinity", "window_read_share.trinity",
 )
 JOINED = ("decode_step_done_ms", "extend_wide_done_ms", "extend_device_share", "device_starved_share", "device_hold_max_ms")
 
 
-def test_manifest_entries_of_the_cell_found_by_name():
-    manifest = load(os.path.join(ROOT, "BENCHMARK.json"))
+
+
+def assert_manifest_entries_of_the_cell(manifest):
     (cell,) = [w for w in manifest["workloads"] if w["name"] == CELL]
     assert (cell["config"], cell["traffic"], cell["chips"]) == ("trinity-mini-26b-a3b-bf16", "doc_reason", 1)
     assert len(cell["why"]) <= 200 and "4 window : 1 full" in cell["why"]
     (cfg,) = [c for c in manifest["configs"] if c["name"] == cell["config"]]
     assert cfg["reduced"] == CFG["reduced"] and cfg["file"].endswith(os.path.basename(CONFIG)) and cfg["source"] == CFG["source"]
-    by_name = {e["name"]: e for e in manifest["per_layer"]}
-    itl = {"tpot_chat_p50_ms.trinity", "extend_dispatch_dev_ms.trinity"}
-    for name in NAMED:  # found by name: neither their count nor their place is pinned
-        e = by_name[name]
-        assert e["workloads"] == [CELL] and e["moves"] == ("itl_p995_ms" if name in itl else "out_tok_s")
-        assert _metric(name)["reader"]  # a file the harness can read: its own or its base's
-        if "roofline" in name:
-            assert e["unit"] == "%" and name.split(".")[0].endswith("_roofline_share")
-    for name in JOINED:
-        assert CELL in by_name[name]["workloads"]
-    assert CELL not in by_name["extend_narrow_done_ms"]["workloads"]  # one chunk width
-    for e in manifest["end_to_end"]:
-        if e["name"] in ("out_tok_s", "itl_p995_ms"):
-            assert CELL in e["workloads"]
+    # found by name and cell: neither their count nor their place is pinned
+    mine = assert_cell_holds(manifest, CELL, NAMED + JOINED + ("moe_tiles_used_share",))
+    assert "extend_narrow_done_ms" not in mine  # one chunk width
+
+
+def test_manifest_entries_of_the_cell_found_by_name():
+    manifest = load(os.path.join(ROOT, "BENCHMARK.json"))
+    assert_manifest_entries_of_the_cell(manifest)
     traffic = load(os.path.join(BENCH, "traffic", "doc_reason.json"))
     assert traffic["clients"] == CFG["engine"]["max_batch_size"] and traffic["question_bytes"] == [2048, 3072, 4096]
     assert sum(1 for w in manifest["workloads"] if w["traffic"] == "doc_reason") >= 3  # three expert configurations, one traffic file
 
 
 EARLIER = {
-    "doc_reason_glm53flash": ("glm-5.3-flash-ep8-bf16", ".glm53"),
-    "doc_reason_gigachat35": ("gigachat3.5-432b-a28b-ep16-bf16", ".gigachat35"),
-    "reason_decode_phi4flash": ("phi-4-mini-flash-reasoning-bf16", ".phi4flash"),
+    "doc_reason_glm53flash": ("glm-5.3-flash-ep8-bf16", "decode_step_roofline_share.glm53"),
+    "doc_reason_gigachat35": ("gigachat3.5-432b-a28b-ep16-bf16", "decode_step_roofline_share.gigachat35"),
+    "reason_decode_phi4flash": ("phi-4-mini-flash-reasoning-bf16", "decode_step_roofline_share"),
 }
 
 
 @pytest.mark.parametrize("cell", sorted(EARLIER))
 def test_the_earlier_cells_entries_are_untouched(cell):
-    """What this PR appended changed no entry of the three cells before it."""
-    import subprocess
-
+    """The three cells before this one keep their configuration, their
+    place before it and the whole step's share each of them reads."""
     manifest = load(os.path.join(ROOT, "BENCHMARK.json"))
-    config, suffix = EARLIER[cell]
+    config, step_share = EARLIER[cell]
     (w,) = [x for x in manifest["workloads"] if x["name"] == cell]
     assert w["config"] == config and w["chips"] == 1
-    own = [e for e in manifest["per_layer"] if e.get("workloads") == [cell]]
-    assert own and all(e["name"].endswith(suffix) or "." not in e["name"] for e in own)
+    mine = entries_of(manifest, cell)
+    assert step_share in mine and {"decode_rows_mean", "device_idle_share"} <= set(mine)
+    assert not any(n.startswith("decode_step_roofline_share") for n in mine if n != step_share)  # one adapter's floor a cell
     assert manifest["workloads"].index(w) < [x["name"] for x in manifest["workloads"]].index(CELL)
-    # against the parent commit where git has one: every entry that names the cell alone is byte for byte the parent's
-    shown = subprocess.run(["git", "show", "HEAD:BENCHMARK.json"], cwd=ROOT, capture_output=True, text=True)
-    if shown.returncode != 0 or CELL in shown.stdout:
-        return
-    parent = json.loads(shown.stdout)
-    assert own == [e for e in parent["per_layer"] if e.get("workloads") == [cell]]
-    assert w == next(x for x in parent["workloads"] if x["name"] == cell)
-    for e in parent["per_layer"]:
-        now = next(x for x in manifest["per_layer"] if x["name"] == e["name"])
-        assert now["workloads"][: len(e["workloads"])] == e["workloads"] and {k: v for k, v in now.items() if k != "workloads"} == \
-            {k: v for k, v in e.items() if k != "workloads"}

@@ -12,6 +12,8 @@ import pytest
 
 from perfbench import arch, reference
 from perfbench.arch import gigachat35 as giga
+from tests.perfbench.manifest_entries import assert_cell_holds
+from tests.perfbench.manifest_entries import metric_spec as _metric
 
 ROOT = os.path.dirname(os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 BENCH = os.path.join(ROOT, "perfbench")
@@ -272,12 +274,6 @@ CHUNK = {"kind": "prefill_chunk", "rows": 1, "moe_experts_hit": 64, "moe_experts
 PARENT_SPANS = [{"kind": "decode", "rows": 60}]
 
 
-def _metric(name):
-    """A manifest name's file: its own, or its base's."""
-    own = os.path.join(BENCH, "layer_metrics", name + ".json")
-    return load(own if os.path.exists(own) else os.path.join(BENCH, "layer_metrics", name.rsplit(".", 1)[0] + ".json"))
-
-
 def _read(name, ctx):
     from perfbench import readers
 
@@ -287,15 +283,15 @@ def _read(name, ctx):
 
 def test_span_readers_and_what_a_parent_without_the_fields_gives():
     ctx = _ctx([DECODE, dict(DECODE, moe_experts_hit=50, moe_pairs_held=150), CHUNK])
-    assert _read("moe_experts_hit_share.gigachat35", ctx) == pytest.approx(100 * 90 / 128)
-    assert _read("moe_pairs_per_expert_mean.gigachat35", ctx) == pytest.approx(270 / 90)
+    assert _read("moe_experts_hit_share", ctx) == pytest.approx(100 * 90 / 128)
+    assert _read("moe_pairs_per_expert_mean", ctx) == pytest.approx(270 / 90)
     assert _read("latent_tokens_read_mean", ctx) == pytest.approx(300000)
-    assert _read("state_rows_mean.gigachat35", ctx) == 60
+    assert _read("state_rows_mean", ctx) == 60
     share = _read("decode_step_roofline_share.gigachat35", ctx)
     want = giga.decode_step_floor_s(CFG, ctx["peaks"], 60, 5000, 45) / 0.020 * 100
     assert share == pytest.approx(want) and 50 < share < 70
     parent = _ctx(PARENT_SPANS)
-    for name in ("moe_experts_hit_share.gigachat35", "moe_pairs_per_expert_mean.gigachat35", "latent_tokens_read_mean",
+    for name in ("moe_experts_hit_share", "moe_pairs_per_expert_mean", "latent_tokens_read_mean",
                  "decode_step_roofline_share.gigachat35"):
         assert _read(name, parent) is None
 
@@ -317,8 +313,8 @@ def test_kernel_roofline_readers_count_what_the_trace_saw_and_stay_under_the_pea
     assert byte_s > flop_s and got == pytest.approx(100 * byte_s / 0.04) and got < 100
     fast = dict(ctx, peaks=dict(ctx["peaks"], hbm_bytes_per_s=1e15))  # where bytes cost nothing, operations bind
     assert _read("latent_attn_roofline_share.gigachat35", fast) == pytest.approx(100 * flop_s / 0.04)
-    assert _read("latent_attn_busy_share.gigachat35", ctx) == pytest.approx(100 * 0.04 / 2.4)
-    assert _read("grouped_matmul_busy_share.gigachat35", ctx) == pytest.approx(100 * 0.4 / 2.4)
+    assert _read("latent_attn_busy_share", ctx) == pytest.approx(100 * 0.04 / 2.4)
+    assert _read("grouped_matmul_busy_share", ctx) == pytest.approx(100 * 0.4 / 2.4)
     for name in ("grouped_matmul_roofline_share.gigachat35", "latent_attn_roofline_share.gigachat35"):
         assert _read(name, _ctx([DECODE], None)) is None  # an untraced run
         assert _read(name, _ctx(PARENT_SPANS, dict(trace, ops_self_s={"fusion": 1.0}))) is None  # the parent
@@ -390,33 +386,30 @@ def test_configuration_engine_reference_and_memory_plan():
     assert 0.25 * 16.9e9 < plan["resident_bytes"] < 16.9e9
 
 
-# the cell's per-layer entries, in the order this PR appended them
+# the cell's per-layer metrics as PR 38 brought them (base names since PR 56), and what PR 56 added to the cell
 PER_LAYER = (
-    "decode_rows_mean.gigachat35", "decode_step_dev_ms.gigachat35", "decode_step_roofline_share.gigachat35",
-    "tpot_chat_p50_ms.gigachat35", "device_idle_share.gigachat35", "stream_backlog_tokens_mean.gigachat35",
-    "state_rows_mean.gigachat35", "extend_dispatch_dev_ms.gigachat35", "moe_experts_hit_share.gigachat35",
-    "moe_pairs_per_expert_mean.gigachat35", "grouped_matmul_busy_share.gigachat35",
-    "grouped_matmul_roofline_share.gigachat35", "latent_attn_busy_share.gigachat35",
+    "decode_rows_mean", "decode_step_dev_ms", "decode_step_roofline_share.gigachat35",
+    "tpot_chat_p50_ms", "device_idle_share", "stream_backlog_tokens_mean",
+    "state_rows_mean", "extend_dispatch_dev_ms", "moe_experts_hit_share",
+    "moe_pairs_per_expert_mean", "grouped_matmul_busy_share",
+    "grouped_matmul_roofline_share.gigachat35", "latent_attn_busy_share",
     "latent_attn_roofline_share.gigachat35", "latent_tokens_read_mean",
 )
+# PR 40's whole-window span metrics (not the narrow one: one chunk width) and the share of planned tiles used
+JOINED = ("decode_step_done_ms", "extend_wide_done_ms", "extend_device_share", "device_starved_share",
+          "device_hold_max_ms", "moe_tiles_used_share")
 
 
-def test_manifest_entries_of_the_cell_found_by_name():
-    manifest = load(os.path.join(ROOT, "BENCHMARK.json"))
+def assert_manifest_entries_of_the_cell(manifest):
     (cell,) = [w for w in manifest["workloads"] if w["name"] == CELL]
     assert (cell["config"], cell["traffic"], cell["chips"]) == ("gigachat3.5-432b-a28b-ep16-bf16", "doc_reason", 1)
     assert len(cell["why"]) <= 200
     (cfg,) = [c for c in manifest["configs"] if c["name"] == cell["config"]]
     assert cfg["reduced"] == CFG["reduced"] and cfg["file"].endswith(os.path.basename(CONFIG)) and cfg["source"] == CFG["source"]
-    mine = [e for e in manifest["per_layer"] if CELL in e.get("workloads", [])]
-    assert tuple(e["name"] for e in mine) == PER_LAYER and all(e["workloads"] == [CELL] for e in mine)
-    itl = {"tpot_chat_p50_ms.gigachat35", "extend_dispatch_dev_ms.gigachat35"}
-    assert all(e["moves"] == ("itl_p995_ms" if e["name"] in itl else "out_tok_s") for e in mine)
-    assert all(e["unit"] == "%" and e["name"].split(".")[0].endswith("_roofline_share") for e in mine if "roofline" in e["name"])
-    for e in manifest["end_to_end"]:
-        if e["name"] in ("out_tok_s", "itl_p995_ms"):
-            assert e["workloads"][-1] == CELL
-    for e in mine:  # every entry has a file the harness can read: its own or its base's
-        assert _metric(e["name"])["reader"]
+    assert_cell_holds(manifest, CELL, PER_LAYER + JOINED)
+
+
+def test_manifest_entries_of_the_cell_found_by_name():
+    assert_manifest_entries_of_the_cell(load(os.path.join(ROOT, "BENCHMARK.json")))
     traffic = load(os.path.join(BENCH, "traffic", "doc_reason.json"))
     assert traffic["clients"] == CFG["engine"]["max_batch_size"] and traffic["question_bytes"] == [2048, 3072, 4096]

@@ -12,6 +12,7 @@ import pytest
 
 from perfbench import arch, reference
 from perfbench.arch import glm5next as glm
+from tests.perfbench.manifest_entries import assert_cell_holds
 
 ROOT = os.path.dirname(os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 BENCH = os.path.join(ROOT, "perfbench")
@@ -322,32 +323,25 @@ def test_configuration_engine_reference_and_memory_plan():
 
 
 CELL = "doc_reason_glm53flash"
-# the cell's per-layer entries as PR 35 appended them, in that order
+# the cell's per-layer metrics as PR 35 brought them (base names since PR 56: a generic metric is ONE entry with the list of its cells)
 PER_LAYER_15 = (
-    "decode_rows_mean.glm53", "decode_step_dev_ms.glm53", "decode_step_roofline_share.glm53",
-    "tpot_chat_p50_ms.glm53", "device_idle_share.glm53", "stream_backlog_tokens_mean.glm53",
-    "state_rows_mean.glm53", "extend_dispatch_dev_ms.glm53", "moe_experts_hit_share",
+    "decode_rows_mean", "decode_step_dev_ms", "decode_step_roofline_share.glm53",
+    "tpot_chat_p50_ms", "device_idle_share", "stream_backlog_tokens_mean",
+    "state_rows_mean", "extend_dispatch_dev_ms", "moe_experts_hit_share",
     "moe_pairs_per_expert_mean", "dsa_selected_share", "grouped_matmul_busy_share",
     "grouped_matmul_roofline_share", "latent_attn_busy_share", "latent_attn_roofline_share",
 )
 
 
 def assert_manifest_entries_of_the_cell(manifest):
-    """The cell, its configuration and its entries, found by NAME: another
-    cell's entries may stand before or after them, and further entries of
-    this cell may follow anywhere after its 15."""
+    """The cell, its configuration and its entries, found by NAME and
+    CELL: the cell's set of names contains its 15, wherever they stand and
+    whatever other cells their lists hold."""
     (cell,) = [w for w in manifest["workloads"] if w["name"] == CELL]
     assert (cell["config"], cell["traffic"], cell["chips"]) == ("glm-5.3-flash-ep8-bf16", "doc_reason", 1)
     (cfg,) = [c for c in manifest["configs"] if c["name"] == cell["config"]]
     assert cfg["reduced"] == CFG["reduced"] and cfg["file"].endswith(os.path.basename(CONFIG))
-    names = [m["name"] for m in manifest["per_layer"]]
-    first = names.index(PER_LAYER_15[0])
-    assert tuple(names[first:first + 15]) == PER_LAYER_15  # contiguous, in the order they came in
-    mine = [m["name"] for m in manifest["per_layer"] if m.get("workloads") == [CELL]]
-    assert tuple(mine[:15]) == PER_LAYER_15  # what the cell gains later follows its 15
-    for e in manifest["end_to_end"]:
-        if e["name"] in ("out_tok_s", "itl_p995_ms"):
-            assert CELL in e["workloads"]
+    assert_cell_holds(manifest, CELL, PER_LAYER_15)
 
 
 def test_traffic_and_manifest_entries_are_as_the_issue_gives_them():

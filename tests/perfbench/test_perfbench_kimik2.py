@@ -15,6 +15,8 @@ import pytest
 
 from perfbench import arch, reference
 from perfbench.arch import kimik2 as adapter
+from tests.perfbench.manifest_entries import assert_cell_holds
+from tests.perfbench.manifest_entries import metric_spec as _metric
 
 ROOT = os.path.dirname(os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 BENCH = os.path.join(ROOT, "perfbench")
@@ -213,12 +215,6 @@ TRACE = {"devices": 1, "busy_s": 2.4, "window_s": 2.5,
          "modules": {"jit_decode_paged": {"count": 40, "total_s": 1.1}, "jit_extend_batch_paged": {"count": 12, "total_s": 1.2}}}
 
 
-def _metric(name):
-    """A manifest name's file: its own, or its base's."""
-    own = os.path.join(BENCH, "layer_metrics", name + ".json")
-    return load(own if os.path.exists(own) else os.path.join(BENCH, "layer_metrics", name.rsplit(".", 1)[0] + ".json"))
-
-
 def _read(name, ctx):
     from perfbench import readers
 
@@ -229,17 +225,17 @@ def _read(name, ctx):
 def test_span_readers_and_what_a_parent_without_the_fields_gives():
     ctx = _ctx([DECODE, dict(DECODE, moe_experts_hit=25, moe_pairs_held=34), CHUNK, dict(CHUNK, prefix_depth_tokens=4096),
                 {k: v for k, v in CHUNK.items() if k != "prefix_depth_tokens"}])
-    assert _read("moe_experts_hit_share.kimik25", ctx) == pytest.approx(100 * 48 / 96)
-    assert _read("moe_pairs_per_expert_mean.kimik25", ctx) == pytest.approx(64 / 48)
-    assert _read("latent_tokens_read_mean.kimik25", ctx) == 30 * 8001
-    assert _read("decode_rows_mean.kimik25", ctx) == 30
-    assert _read("extend_prefix_depth_mean.kimik25", ctx) == pytest.approx((9216 + 4096) / 2)  # the chunks after a hit alone
+    assert _read("moe_experts_hit_share", ctx) == pytest.approx(100 * 48 / 96)
+    assert _read("moe_pairs_per_expert_mean", ctx) == pytest.approx(64 / 48)
+    assert _read("latent_tokens_read_mean", ctx) == 30 * 8001
+    assert _read("decode_rows_mean", ctx) == 30
+    assert _read("extend_prefix_depth_mean", ctx) == pytest.approx((9216 + 4096) / 2)  # the chunks after a hit alone
     share = _read("decode_step_roofline_share.kimik25", ctx)
     want = adapter.decode_step_floor_s(CFG, ctx["peaks"], 30, 8000, 24) / 0.014 * 100
     assert share == pytest.approx(want) and 40 < share < 100
     parent = _ctx(PARENT_SPANS)
-    for name in ("moe_experts_hit_share.kimik25", "moe_pairs_per_expert_mean.kimik25", "latent_tokens_read_mean.kimik25",
-                 "decode_step_roofline_share.kimik25", "extend_prefix_depth_mean.kimik25"):
+    for name in ("moe_experts_hit_share", "moe_pairs_per_expert_mean", "latent_tokens_read_mean",
+                 "decode_step_roofline_share.kimik25", "extend_prefix_depth_mean"):
         assert _read(name, parent) is None
 
 
@@ -252,8 +248,8 @@ def test_kernel_roofline_readers_count_what_the_trace_saw_and_stay_under_the_pea
     by_bytes = steps * (30 * 63 + 2) * 5 * 163_840 / 819e9
     by_ops = 2 * 64 * steps * 30 * 8001 * 5 * 1088 / 197e12
     assert by_bytes > by_ops and got == pytest.approx(100 * by_bytes / 0.45) and got < 100
-    assert _read("latent_attn_busy_share.kimik25", ctx) == pytest.approx(100 * 0.45 / 2.4)
-    assert _read("grouped_matmul_busy_share.kimik25", ctx) == pytest.approx(100 * 0.5 / 2.4)
+    assert _read("latent_attn_busy_share", ctx) == pytest.approx(100 * 0.45 / 2.4)
+    assert _read("grouped_matmul_busy_share", ctx) == pytest.approx(100 * 0.5 / 2.4)
     bare = dict(TRACE, ops_self_s={"fusion": 1.0}, modules={"jit_decode_paged": {"count": 40, "total_s": 1.1}})
     for name in ("grouped_matmul_roofline_share", "latent_attn_roofline_share"):
         assert _read(name + ".kimik25", _ctx([DECODE], None)) is None  # an untraced run
@@ -368,35 +364,30 @@ JOINED = ("decode_step_done_ms", "extend_wide_done_ms", "extend_narrow_done_ms",
           "device_starved_share", "device_hold_max_ms")
 
 
-def test_manifest_entries_of_the_cell_found_by_name():
-    manifest = load(os.path.join(ROOT, "BENCHMARK.json"))
+
+
+def assert_manifest_entries_of_the_cell(manifest):
     (cell,) = [w for w in manifest["workloads"] if w["name"] == CELL]
     assert (cell["config"], cell["traffic"], cell["chips"]) == ("kimi-k2.5-ep32-bf16", "agent_sessions", 1)
     assert len(cell["why"]) <= 200 and "shared latent pages" in cell["why"] and "deployment 21.3" in cell["why"]
     (cfg,) = [c for c in manifest["configs"] if c["name"] == cell["config"]]
     assert cfg["reduced"] == CFG["reduced"] and cfg["file"].endswith(os.path.basename(CONFIG)) and cfg["source"] == CFG["source"]
     assert len(cfg["why"]) <= 200
-    by_name = {e["name"]: e for e in manifest["per_layer"]}
-    itl = {"tpot_chat_p50_ms.kimik25", "extend_dispatch_dev_ms.kimik25"}
-    for base in GENERIC + OWN + DATA:  # found by name: neither their count nor their place is pinned
-        name = base + ".kimik25"
-        e = by_name[name]
-        assert e["workloads"] == [CELL] and e["moves"] == ("itl_p995_ms" if name in itl else "out_tok_s")
-        assert _metric(name)["reader"]  # a file the harness can read: its own or its base's
-        assert os.path.exists(os.path.join(BENCH, "layer_metrics", name + ".json")) == (base in OWN)
-        if "roofline" in name:
-            assert e["unit"] == "%" and base.endswith("_roofline_share")
-    assert _metric("extend_prefix_depth_mean.kimik25") == {
+    own = tuple(base + ".kimik25" for base in OWN)  # an adapter's reader: a file under the suffixed name
+    # found by name and cell: neither their count nor their place is pinned
+    assert_cell_holds(manifest, CELL, GENERIC + own + DATA + JOINED + ("moe_tiles_used_share",))
+    for name in GENERIC + own + DATA:
+        assert os.path.exists(os.path.join(BENCH, "layer_metrics", name + ".json"))
+    assert _metric("extend_prefix_depth_mean") == {
         "name": "extend_prefix_depth_mean", "reader": "span_mean",
         "params": {"kind": "prefill_chunk", "field": "prefix_depth_tokens"}}
-    for name in JOINED:
-        assert CELL in by_name[name]["workloads"]
-    for e in manifest["end_to_end"]:
-        if e["name"] in ("out_tok_s", "itl_p995_ms"):
-            assert CELL in e["workloads"]
     assert len(manifest["workloads"]) >= 7 and all(w["chips"] == 1 for w in manifest["workloads"])
     # every metric that moves what the cell reports lists its cells: none is left to every cell by default
     assert all("workloads" in e for e in manifest["per_layer"] if e["moves"] in ("out_tok_s", "itl_p995_ms"))
+
+
+def test_manifest_entries_of_the_cell_found_by_name():
+    assert_manifest_entries_of_the_cell(load(os.path.join(ROOT, "BENCHMARK.json")))
 
 
 def test_the_traffic_file_is_as_the_issue_gives_it():

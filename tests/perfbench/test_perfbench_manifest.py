@@ -5,6 +5,8 @@ import re
 
 import pytest
 
+from tests.perfbench.manifest_entries import assert_cell_holds
+
 ROOT = os.path.dirname(os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 BENCH = os.path.join(ROOT, "perfbench")
 NAME = re.compile(r"^[A-Za-z0-9_][A-Za-z0-9_.\-]{0,63}$")
@@ -25,7 +27,7 @@ MANIFESTS = {
 M = load(MANIFESTS["benchmark"])
 CELLS = [w["name"] for w in M["workloads"]]
 E2E = [m["name"] for m in M["end_to_end"]]
-LAYER = [m["name"] for m in M["per_layer"]]
+PAIRS = [(m["name"], cell) for m in M["per_layer"] for cell in m.get("workloads", CELLS)]
 
 
 def cells_of(metric, manifest=M):
@@ -100,23 +102,117 @@ def test_every_configuration_is_used_and_at_most_a_quarter_of_cells_take_four_ch
     assert len(set(pairs)) == len(pairs)
 
 
-@pytest.mark.parametrize("metric", LAYER)
-def test_per_layer_metric_has_a_reader_file_and_moves_a_metric_its_cells_report(metric):
+@pytest.mark.parametrize("metric,cell", PAIRS, ids=[f"{n}-{c}" for n, c in PAIRS])
+def test_per_layer_metric_has_a_reader_file_and_moves_a_metric_its_cells_report(metric, cell):
+    """One case a (metric, cell) READING, so that merging the copies of a
+    metric into one entry with the list of its cells loses no case."""
     from perfbench import readers
-
-    m = next(x for x in M["per_layer"] if x["name"] == metric)
     from perfbench.run import layer_metric_file
 
+    m = next(x for x in M["per_layer"] if x["name"] == metric)
+    assert "workloads" in m  # no list would mean every cell, the next PR's too
     spec = load(layer_metric_file(metric))
     assert spec["name"] in (metric, metric.rsplit(".", 1)[0])
     # a key of READERS, or module:function of a module under the manifest's paths
     assert callable(readers.resolve(spec["reader"], [os.path.join(ROOT, p) for p in M["paths"]]))
     target = next(x for x in M["end_to_end"] if x["name"] == m["moves"])
-    assert set(cells_of(m)) <= set(cells_of(target))
-    same_layer = {x["layer"] for x in M["per_layer"]}
-    assert m["layer"] in same_layer
+    assert cell in CELLS and cell in cells_of(target)  # the cell reports the end-to-end metric the entry moves
+    assert m["workloads"] == sorted(set(m["workloads"]), key=CELLS.index)  # each cell once, in the manifest's order
     if "roofline" in metric or "mfu" in metric:
         assert m["unit"] == "%"
+
+
+CHAT, PHI, GLM, GIGA, TRINITY, SOLAR, KIMI, MINIMAX = ALL_EIGHT = (
+    "chat_decode_7b", "reason_decode_phi4flash", "doc_reason_glm53flash", "doc_reason_gigachat35",
+    "doc_reason_trinitymini", "chat_sessions_solaropen2", "agent_sessions_kimik25", "agent_files_minimaxm3")
+# the 163 (metric, cell) readings of the manifest before PR 56 merged its 128 entries into one a metric
+# (a copy `<base>.<suffix>` without a file of its own stands under its base name): a merge may lose none of them
+PAIRS_BEFORE_THE_MERGE = {
+    "decode_rows_mean": ALL_EIGHT,
+    "decode_step_dev_ms": ALL_EIGHT,
+    "tpot_chat_p50_ms": ALL_EIGHT,
+    "page_attn_busy_share": (CHAT, PHI, TRINITY, SOLAR),
+    "int8_matmul_busy_share": (CHAT,),
+    "decode_step_roofline_share": (CHAT, PHI),
+    "device_idle_share": ALL_EIGHT,
+    "extend_dispatch_dev_ms": (CHAT, GLM, GIGA, TRINITY, SOLAR, KIMI, MINIMAX),
+    "page_attn_pages_walked_mean": (CHAT, PHI, TRINITY, SOLAR),
+    "itl_p99_ms": (CHAT,),
+    "state_rows_mean": (PHI, GLM, GIGA, TRINITY, SOLAR),
+    "window_tokens_read_mean": (PHI, TRINITY),
+    "prefill_cross_skipped_share": (PHI,),
+    "stream_backlog_tokens_mean": ALL_EIGHT,
+    "extend_pad_tokens_mean": (CHAT,),
+    "decode_step_roofline_share.glm53": (GLM,),
+    "moe_experts_hit_share": (GLM, GIGA, TRINITY, SOLAR, KIMI, MINIMAX),
+    "moe_pairs_per_expert_mean": (GLM, GIGA, TRINITY, SOLAR, KIMI, MINIMAX),
+    "dsa_selected_share": (GLM,),
+    "grouped_matmul_busy_share": (GLM, GIGA, TRINITY, SOLAR, KIMI, MINIMAX),
+    "grouped_matmul_roofline_share": (GLM,),
+    "latent_attn_busy_share": (GLM, GIGA, KIMI),
+    "latent_attn_roofline_share": (GLM,),
+    "decode_step_roofline_share.gigachat35": (GIGA,),
+    "grouped_matmul_roofline_share.gigachat35": (GIGA,),
+    "latent_attn_roofline_share.gigachat35": (GIGA,),
+    "latent_tokens_read_mean": (GIGA, KIMI),
+    "decode_step_done_ms": (CHAT, PHI, GLM, TRINITY, SOLAR, KIMI),
+    "extend_wide_done_ms": (CHAT, PHI, GLM, TRINITY, SOLAR, KIMI),
+    "extend_narrow_done_ms": (CHAT, PHI, SOLAR, KIMI),
+    "extend_device_share": (CHAT, PHI, GLM, TRINITY, SOLAR, KIMI),
+    "device_starved_share": (CHAT, PHI, GLM, TRINITY, SOLAR, KIMI),
+    "device_hold_max_ms": (CHAT, PHI, GLM, TRINITY, SOLAR, KIMI),
+    "grouped_matmul_roofline_share.trinity": (TRINITY,),
+    "decode_step_roofline_share.trinity": (TRINITY,),
+    "window_read_share.trinity": (TRINITY,),
+    "prefix_reused_token_share.solaropen2": (SOLAR,),
+    "prefix_state_copy_roofline_share.solaropen2": (SOLAR,),
+    "prefix_state_copy_device_share.solaropen2": (SOLAR,),
+    "decode_step_roofline_share.solaropen2": (SOLAR,),
+    "grouped_matmul_roofline_share.solaropen2": (SOLAR,),
+    "delta_step_roofline_share.solaropen2": (SOLAR,),
+    "page_attn_roofline_share.solaropen2": (SOLAR,),
+    "latent_attn_roofline_share.kimik25": (KIMI,),
+    "grouped_matmul_roofline_share.kimik25": (KIMI,),
+    "decode_step_roofline_share.kimik25": (KIMI,),
+    "prefix_reused_token_share.kimik25": (KIMI,),
+    "extend_prefix_depth_mean": (KIMI, MINIMAX),
+    "grouped_matmul_roofline_share.minimaxm3": (MINIMAX,),
+    "decode_step_roofline_share.minimaxm3": (MINIMAX,),
+    "prefix_reused_token_share.minimaxm3": (MINIMAX,),
+    "msa_selected_page_share": (MINIMAX,),
+    "msa_read_busy_share": (MINIMAX,),
+    "msa_index_busy_share": (MINIMAX,),
+    "msa_read_roofline_share": (MINIMAX,),
+    "gap_tail_extend_share": ALL_EIGHT,
+}
+
+
+def test_the_manifest_still_holds_every_reading_it_held_before_the_merge():
+    assert sum(len(cells) for cells in PAIRS_BEFORE_THE_MERGE.values()) == 163
+    held = set(PAIRS)
+    lost = [(name, cell) for name, cells in PAIRS_BEFORE_THE_MERGE.items() for cell in cells if (name, cell) not in held]
+    assert not lost
+    assert len(M["per_layer"]) <= 128 and len(PAIRS) >= 163
+
+
+# the dense family's cell has no adapter test of its own: what PRs 24-41 brought it stands here
+FIRST_CELL = (
+    "decode_rows_mean", "decode_step_dev_ms", "tpot_chat_p50_ms", "page_attn_busy_share", "int8_matmul_busy_share",
+    "decode_step_roofline_share", "device_idle_share", "extend_dispatch_dev_ms", "page_attn_pages_walked_mean",
+    "itl_p99_ms", "stream_backlog_tokens_mean", "extend_pad_tokens_mean", "decode_step_done_ms", "extend_wide_done_ms",
+    "extend_narrow_done_ms", "extend_device_share", "device_starved_share", "device_hold_max_ms", "gap_tail_extend_share",
+)
+
+
+def assert_manifest_entries_of_the_cell(manifest):
+    (cell,) = [w for w in manifest["workloads"] if w["name"] == CHAT]
+    assert (cell["config"], cell["traffic"], cell["chips"]) == ("mistral-7b-v0.3-int8", "chat_decode", 1)
+    mine = assert_cell_holds(manifest, CHAT, FIRST_CELL)
+    assert not any(n.startswith(("moe_", "grouped_matmul", "latent_", "state_rows")) for n in mine)  # no expert, no state
+
+
+def test_the_first_cells_entries_are_found_by_name_and_cell():
+    assert_manifest_entries_of_the_cell(M)
 
 
 def test_a_suffixed_metric_name_reads_the_file_of_its_base_name():

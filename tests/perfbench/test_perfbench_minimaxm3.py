@@ -16,6 +16,8 @@ import pytest
 
 from perfbench import arch, reference
 from perfbench.arch import minimaxm3 as adapter
+from tests.perfbench.manifest_entries import assert_cell_holds
+from tests.perfbench.manifest_entries import metric_spec as _metric
 
 ROOT = os.path.dirname(os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 BENCH = os.path.join(ROOT, "perfbench")
@@ -331,12 +333,6 @@ TRACE = {"devices": 1, "busy_s": 2.4, "window_s": 2.5,
          "modules": {"jit_decode_paged": {"count": 40, "total_s": 1.1}, "jit_extend_batch_paged": {"count": 12, "total_s": 1.2}}}
 
 
-def _metric(name):
-    """A manifest name's file: its own, or its base's."""
-    own = os.path.join(BENCH, "layer_metrics", name + ".json")
-    return load(own if os.path.exists(own) else os.path.join(BENCH, "layer_metrics", name.rsplit(".", 1)[0] + ".json"))
-
-
 def _read(name, ctx):
     from perfbench import readers
 
@@ -347,17 +343,17 @@ def _read(name, ctx):
 def test_span_readers_and_what_a_parent_without_the_fields_gives():
     ctx = _ctx([DECODE, dict(DECODE, moe_experts_hit=26, moe_pairs_held=12), CHUNK, dict(CHUNK, prefix_depth_tokens=4096),
                 {k: v for k, v in CHUNK.items() if k != "prefix_depth_tokens"}])
-    assert _read("moe_experts_hit_share.minimaxm3", ctx) == pytest.approx(100 * 50 / 128)
-    assert _read("moe_pairs_per_expert_mean.minimaxm3", ctx) == pytest.approx(20 / 50)
+    assert _read("moe_experts_hit_share", ctx) == pytest.approx(100 * 50 / 128)
+    assert _read("moe_pairs_per_expert_mean", ctx) == pytest.approx(20 / 50)
     assert _read("msa_selected_page_share", ctx) == pytest.approx(100 * 19 / 120)  # decode spans alone
-    assert _read("decode_rows_mean.minimaxm3", ctx) == 15
-    assert _read("extend_prefix_depth_mean.minimaxm3", ctx) == pytest.approx((9216 + 4096) / 2)
+    assert _read("decode_rows_mean", ctx) == 15
+    assert _read("extend_prefix_depth_mean", ctx) == pytest.approx((9216 + 4096) / 2)
     share = _read("decode_step_roofline_share.minimaxm3", ctx)
     want = adapter.decode_step_floor_s(CFG, ctx["peaks"], 15, 119.5 * 128, 25, 15 * 5 * 4 * 19) / 0.010 * 100
     assert share == pytest.approx(want) and 40 < share < 100
     parent = _ctx(PARENT_SPANS)
-    for name in ("moe_experts_hit_share.minimaxm3", "moe_pairs_per_expert_mean.minimaxm3", "msa_selected_page_share",
-                 "decode_step_roofline_share.minimaxm3", "extend_prefix_depth_mean.minimaxm3"):
+    for name in ("moe_experts_hit_share", "moe_pairs_per_expert_mean", "msa_selected_page_share",
+                 "decode_step_roofline_share.minimaxm3", "extend_prefix_depth_mean"):
         assert _read(name, parent) is None
 
 
@@ -371,7 +367,7 @@ def test_kernel_roofline_readers_count_what_the_trace_saw_and_stay_under_the_pea
     by_bytes, by_ops = strips * 65_536 / 819e9, strips * 128 * 16 * 128 * 4 / 197e12
     assert by_bytes > by_ops and got == pytest.approx(100 * by_bytes / 0.2) and got < 100
     assert _read("msa_read_busy_share", ctx) == pytest.approx(100 * 0.2 / 2.4)
-    assert _read("grouped_matmul_busy_share.minimaxm3", ctx) == pytest.approx(100 * 0.5 / 2.4)
+    assert _read("grouped_matmul_busy_share", ctx) == pytest.approx(100 * 0.5 / 2.4)
     bare = dict(TRACE, ops_self_s={"fusion": 1.0}, modules={"jit_decode_paged": {"count": 40, "total_s": 1.1}})
     for name in ("grouped_matmul_roofline_share.minimaxm3", "msa_read_roofline_share"):
         assert _read(name, _ctx([DECODE], None)) is None  # an untraced run
@@ -486,31 +482,30 @@ OWN = ("grouped_matmul_roofline_share", "decode_step_roofline_share", "prefix_re
 NEW = ("msa_selected_page_share", "msa_read_busy_share", "msa_index_busy_share", "msa_read_roofline_share")
 
 
-def test_manifest_entries_of_the_cell_found_by_name():
-    manifest = load(os.path.join(ROOT, "BENCHMARK.json"))
+# PR 40's whole-window span metrics (both chunk widths) and the share of planned tiles used: joined in PR 56
+JOINED = ("decode_step_done_ms", "extend_wide_done_ms", "extend_narrow_done_ms", "extend_device_share",
+          "device_starved_share", "device_hold_max_ms", "moe_tiles_used_share")
+
+
+def assert_manifest_entries_of_the_cell(manifest):
     (cell,) = [w for w in manifest["workloads"] if w["name"] == CELL]
     assert (cell["config"], cell["traffic"], cell["chips"]) == ("minimax-m3-ep8-bf16", "agent_files", 1)
     assert len(cell["why"]) <= 200 and "more host" in cell["why"] and "attention sees more than its share" in cell["why"]
     (cfg,) = [c for c in manifest["configs"] if c["name"] == cell["config"]]
     assert cfg["reduced"] == CFG["reduced"] and cfg["file"].endswith(os.path.basename(CONFIG)) and cfg["source"] == CFG["source"]
     assert len(cfg["why"]) <= 200
-    by_name = {e["name"]: e for e in manifest["per_layer"]}
-    itl = {"tpot_chat_p50_ms.minimaxm3", "extend_dispatch_dev_ms.minimaxm3"}
-    for name in [b + ".minimaxm3" for b in GENERIC + OWN] + list(NEW):  # found by name: neither count nor place is pinned
-        e = by_name[name]
-        assert e["workloads"] == [CELL] and e["moves"] == ("itl_p995_ms" if name in itl else "out_tok_s")
-        assert set(e) == {"name", "unit", "better", "source", "layer", "moves", "workloads"}
-        assert _metric(name)["reader"]  # a file the harness can read: its own or its base's
-        own = os.path.exists(os.path.join(BENCH, "layer_metrics", name + ".json"))
-        assert own == (name in NEW or name.rsplit(".", 1)[0] in OWN)
-        if "roofline" in name:
-            assert e["unit"] == "%" and name.split(".")[0].endswith("_roofline_share")
-    for e in manifest["end_to_end"]:
-        if e["name"] in ("out_tok_s", "itl_p995_ms"):
-            assert e["workloads"][-1] == CELL or CELL in e["workloads"]
+    own = tuple(base + ".minimaxm3" for base in OWN)  # an adapter's reader: a file under the suffixed name
+    # found by name and cell: neither count nor place is pinned
+    assert_cell_holds(manifest, CELL, GENERIC + own + NEW + JOINED)
+    for name in GENERIC + own + NEW:
+        assert os.path.exists(os.path.join(BENCH, "layer_metrics", name + ".json"))
     assert all(w["chips"] == 1 for w in manifest["workloads"])
     assert all("workloads" in e for e in manifest["per_layer"] if e["moves"] in ("out_tok_s", "itl_p995_ms"))
     assert len(json.dumps(manifest)) < 64 * 1024
+
+
+def test_manifest_entries_of_the_cell_found_by_name():
+    assert_manifest_entries_of_the_cell(load(os.path.join(ROOT, "BENCHMARK.json")))
 
 
 def test_the_traffic_file_is_as_the_issue_gives_it():

@@ -3,17 +3,17 @@ row tiles that a chunk's traffic used, from the two span fields the
 expert families report since PR 55 (``moe_tiles_used``,
 ``moe_tiles_planned``), through the generic reader the benchmark has.
 
-The metric is DATA over ``perfbench.arch.glm5next:span_ratio``; its
-specification stands here because the manifest holds its 128 per-layer
-metrics already (``PERF.md`` section 7, "Opened by PR 55"): a
-``benchmark`` PR that frees an entry adds this dictionary as
-``perfbench/layer_metrics/moe_tiles_used_share.json``.
+The metric is DATA over ``perfbench.arch.glm5next:span_ratio``. PR 55
+kept its specification here because the manifest was full; since PR 56
+(one entry a metric) it is ``perfbench/layer_metrics/moe_tiles_used_share.json``
+and an entry of the six expert cells, and this dictionary pins both.
 """
 import os
 
 import pytest
 
 from perfbench import readers
+from tests.perfbench.manifest_entries import entries_of, metric_spec, real
 
 ROOT = os.path.dirname(os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 ROOTS = [os.path.join(ROOT, "perfbench"), os.path.join(ROOT, "tests", "perfbench")]
@@ -43,3 +43,14 @@ def test_the_share_of_planned_tiles_used_reads_through_the_generic_span_ratio(sp
     read = readers.resolve(SPEC["reader"], ROOTS)
     got = read({"spans": spans}, dict(SPEC["params"], kind=kind))
     assert got is None if want is None else got == pytest.approx(want, rel=1e-12)
+
+
+def test_the_file_and_the_entry_are_as_pr_55_left_them_for_this_pr():
+    assert metric_spec(SPEC["name"]) == SPEC
+    manifest = real()
+    (entry,) = [e for e in manifest["per_layer"] if e["name"] == SPEC["name"]]
+    assert {k: entry[k] for k in ("unit", "better", "source", "layer", "moves")} == {
+        "unit": "%", "better": "lower", "source": "program_span", "layer": "step programs", "moves": "out_tok_s"}
+    # the cells that hold experts: those that read the share of experts hit, and no other
+    expert = [w["name"] for w in manifest["workloads"] if "moe_experts_hit_share" in entries_of(manifest, w["name"])]
+    assert entry["workloads"] == expert and len(expert) >= 6

@@ -12,6 +12,7 @@ import pytest
 
 from perfbench import reference
 from perfbench.arch import phi4flash as phi
+from tests.perfbench.manifest_entries import assert_cell_holds, real
 
 ROOT = os.path.dirname(os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 BENCH = os.path.join(ROOT, "perfbench")
@@ -339,3 +340,25 @@ def test_configuration_file_holds_the_catalogs_keys_and_cuts_nothing():
     assert "APP_ENGINE_IGNOREEOS" not in env
     grow = {c["metric"] for c in cfg["correct"]["counters_must_grow"]}
     assert grow == {"genai_engine_state_slot_resets_total", "genai_engine_prefill_cross_skipped_tokens_total"}
+
+
+CELL = "reason_decode_phi4flash"
+# the cell's per-layer metrics as PRs 29 and 30 brought them (base names since PR 56) and PR 40's whole-window span metrics
+PER_LAYER = (
+    "decode_rows_mean", "decode_step_dev_ms", "decode_step_roofline_share", "tpot_chat_p50_ms", "device_idle_share",
+    "page_attn_busy_share", "page_attn_pages_walked_mean", "state_rows_mean", "window_tokens_read_mean",
+    "prefill_cross_skipped_share", "stream_backlog_tokens_mean",
+    "decode_step_done_ms", "extend_wide_done_ms", "extend_narrow_done_ms", "extend_device_share",
+    "device_starved_share", "device_hold_max_ms",
+)
+
+
+def assert_manifest_entries_of_the_cell(manifest):
+    (cell,) = [w for w in manifest["workloads"] if w["name"] == CELL]
+    assert (cell["config"], cell["traffic"], cell["chips"]) == ("phi-4-mini-flash-reasoning-bf16", "reason_decode", 1)
+    mine = assert_cell_holds(manifest, CELL, PER_LAYER)
+    assert "extend_dispatch_dev_ms" not in mine  # the family's extend programs are not named jit_extend*
+
+
+def test_manifest_entries_of_the_cell_found_by_name():
+    assert_manifest_entries_of_the_cell(real())

@@ -15,6 +15,8 @@ import pytest
 
 from perfbench import arch, reference
 from perfbench.arch import solaropen2 as adapter
+from tests.perfbench.manifest_entries import assert_cell_holds
+from tests.perfbench.manifest_entries import metric_spec as _metric
 
 ROOT = os.path.dirname(os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 BENCH = os.path.join(ROOT, "perfbench")
@@ -230,12 +232,6 @@ TRACE = {"devices": 1, "busy_s": 2.4, "window_s": 2.5,
                      "jit_prefix_state_copy": {"count": 12, "total_s": 0.0006}}}
 
 
-def _metric(name):
-    """A manifest name's file: its own, or its base's."""
-    own = os.path.join(BENCH, "layer_metrics", name + ".json")
-    return load(own if os.path.exists(own) else os.path.join(BENCH, "layer_metrics", name.rsplit(".", 1)[0] + ".json"))
-
-
 def _read(name, ctx):
     from perfbench import readers
 
@@ -245,14 +241,14 @@ def _read(name, ctx):
 
 def test_span_readers_and_what_a_parent_without_the_fields_gives():
     ctx = _ctx([DECODE, dict(DECODE, moe_experts_hit=100, moe_pairs_held=200), CHUNK])
-    assert _read("moe_experts_hit_share.solaropen2", ctx) == pytest.approx(100 * 220 / 320)
-    assert _read("moe_pairs_per_expert_mean.solaropen2", ctx) == pytest.approx(440 / 220)
-    assert _read("state_rows_mean.solaropen2", ctx) == 60
+    assert _read("moe_experts_hit_share", ctx) == pytest.approx(100 * 220 / 320)
+    assert _read("moe_pairs_per_expert_mean", ctx) == pytest.approx(440 / 220)
+    assert _read("state_rows_mean", ctx) == 60
     share = _read("decode_step_roofline_share.solaropen2", ctx)
     want = adapter.decode_step_floor_s(CFG, ctx["peaks"], 60, 4500, 110, 60 * 4500) / 0.013 * 100
     assert share == pytest.approx(want) and 50 < share < 100
     parent = _ctx(PARENT_SPANS)
-    for name in ("moe_experts_hit_share.solaropen2", "moe_pairs_per_expert_mean.solaropen2",
+    for name in ("moe_experts_hit_share", "moe_pairs_per_expert_mean",
                  "decode_step_roofline_share.solaropen2"):
         assert _read(name, parent) is None
 
@@ -269,8 +265,8 @@ def test_kernel_roofline_readers_count_the_bytes_the_trace_saw_and_stay_under_th
     got = _read("prefix_state_copy_roofline_share.solaropen2", ctx)
     assert got == pytest.approx(100 * 12 * 2 * 13_025_280 / 819e9 / 0.0006) and got < 100
     assert _read("prefix_state_copy_device_share.solaropen2", ctx) == pytest.approx(100 * 0.0006 / 2.4)
-    assert _read("grouped_matmul_busy_share.solaropen2", ctx) == pytest.approx(100 * 0.8 / 2.4)
-    assert _read("page_attn_busy_share.solaropen2", ctx) == pytest.approx(100 * 0.3 / 2.4)
+    assert _read("grouped_matmul_busy_share", ctx) == pytest.approx(100 * 0.8 / 2.4)
+    assert _read("page_attn_busy_share", ctx) == pytest.approx(100 * 0.3 / 2.4)
     bare = dict(TRACE, ops_self_s={"fusion": 1.0}, modules={"jit_decode_paged": {"count": 80, "total_s": 1.7}})
     for name in ("grouped_matmul_roofline_share", "delta_step_roofline_share", "page_attn_roofline_share",
                  "prefix_state_copy_roofline_share", "prefix_state_copy_device_share"):
@@ -379,31 +375,26 @@ JOINED = ("decode_step_done_ms", "extend_wide_done_ms", "extend_narrow_done_ms",
           "device_starved_share", "device_hold_max_ms")
 
 
-def test_manifest_entries_of_the_cell_found_by_name():
-    manifest = load(os.path.join(ROOT, "BENCHMARK.json"))
+
+
+def assert_manifest_entries_of_the_cell(manifest):
     (cell,) = [w for w in manifest["workloads"] if w["name"] == CELL]
     assert (cell["config"], cell["traffic"], cell["chips"]) == ("solar-open2-250b-ep8-bf16", "chat_sessions", 1)
     assert len(cell["why"]) <= 200 and "saved KDA state" in cell["why"]
     (cfg,) = [c for c in manifest["configs"] if c["name"] == cell["config"]]
     assert cfg["reduced"] == CFG["reduced"] and cfg["file"].endswith(os.path.basename(CONFIG)) and cfg["source"] == CFG["source"]
     assert len(cfg["why"]) <= 200
-    by_name = {e["name"]: e for e in manifest["per_layer"]}
-    itl = {"tpot_chat_p50_ms.solaropen2", "extend_dispatch_dev_ms.solaropen2"}
-    for base in GENERIC + OWN:  # found by name: neither their count nor their place is pinned
-        name = base + ".solaropen2"
-        e = by_name[name]
-        assert e["workloads"] == [CELL] and e["moves"] == ("itl_p995_ms" if name in itl else "out_tok_s")
-        assert _metric(name)["reader"]  # a file the harness can read: its own or its base's
-        assert os.path.exists(os.path.join(BENCH, "layer_metrics", name + ".json")) == (base in OWN)
-        if "roofline" in name:
-            assert e["unit"] == "%" and base.endswith("_roofline_share")
-    for name in JOINED:
-        assert CELL in by_name[name]["workloads"]
-    for e in manifest["end_to_end"]:
-        if e["name"] in ("out_tok_s", "itl_p995_ms"):
-            assert CELL in e["workloads"]
+    own = tuple(base + ".solaropen2" for base in OWN)  # an adapter's reader: a file under the suffixed name
+    # found by name and cell: neither their count nor their place is pinned
+    assert_cell_holds(manifest, CELL, GENERIC + own + JOINED + ("moe_tiles_used_share",))
+    for name in GENERIC + own:
+        assert os.path.exists(os.path.join(BENCH, "layer_metrics", name + ".json"))
     # every metric that moves what the cell reports lists its cells: none is left to every cell by default
     assert all("workloads" in e for e in manifest["per_layer"] if e["moves"] in ("out_tok_s", "itl_p995_ms"))
+
+
+def test_manifest_entries_of_the_cell_found_by_name():
+    assert_manifest_entries_of_the_cell(load(os.path.join(ROOT, "BENCHMARK.json")))
 
 
 def test_the_traffic_file_is_as_the_issue_gives_it():
