@@ -399,9 +399,13 @@ def history_to_messages(chat_history) -> List[Tuple[str, str]]:
 
 def llm_settings(kwargs: dict) -> dict:
     """Extract generation settings the chains forward to the backend
-    (temperature/top_p/max_tokens/stop — server.py:270-274)."""
+    (temperature/top_p/max_tokens/stop — server.py:270-274), and
+    ``ignore_eos`` where a request set it (absent otherwise: a backend
+    that never heard of it is called as before)."""
     out = {}
     for key in ("temperature", "top_p", "max_tokens", "stop"):
         if key in kwargs and kwargs[key] is not None:
             out[key] = kwargs[key]
+    if kwargs.get("ignore_eos"):
+        out["ignore_eos"] = True
     return out

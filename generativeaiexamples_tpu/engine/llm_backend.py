@@ -35,13 +35,15 @@ class LLMBackend:
         stop: Sequence[str] = (),
         prefix_hint: Optional[str] = None,
         spec_decode: Optional[bool] = None,
+        ignore_eos: bool = False,
     ) -> Generator[str, None, None]:
         """``prefix_hint`` names the chain/session this request belongs
         to, feeding the engine's prefix KV cache (advisory — backends
         without one ignore it). ``spec_decode`` is the per-request
         speculative-decoding override (None follows the engine config,
         False opts out); like prefix_hint it is engine-local scheduling
-        advice that non-engine backends ignore."""
+        advice that non-engine backends ignore. ``ignore_eos`` makes a
+        stop id an ordinary token: the answer ends at ``max_tokens``."""
         raise NotImplementedError
 
     def complete(self, messages: Messages, **kwargs) -> str:
@@ -55,7 +57,7 @@ class TPULLMBackend(LLMBackend):
         self._engine = engine or get_engine()
 
     def stream_chat(self, messages, temperature=0.2, top_p=0.7, max_tokens=1024,
-                    stop=(), prefix_hint=None, spec_decode=None):
+                    stop=(), prefix_hint=None, spec_decode=None, ignore_eos=False):
         from generativeaiexamples_tpu.engine.llm_engine import SamplingParams
         from generativeaiexamples_tpu.engine.tokenizer import render_chat_cached
 
@@ -67,6 +69,7 @@ class TPULLMBackend(LLMBackend):
             stop=tuple(stop or ()),
             prefix_hint=prefix_hint,
             spec_decode=spec_decode,
+            ignore_eos=bool(ignore_eos),
         )
         # Per-request deadline (bound to this thread by the server):
         # the remaining budget becomes the engine stream timeout, so a
@@ -97,7 +100,7 @@ class RemoteLLMBackend(LLMBackend):
         self._timeout = timeout
 
     def stream_chat(self, messages, temperature=0.2, top_p=0.7, max_tokens=1024,
-                    stop=(), prefix_hint=None, spec_decode=None):
+                    stop=(), prefix_hint=None, spec_decode=None, ignore_eos=False):
         # prefix_hint/spec_decode are engine-local scheduling advice; the
         # OpenAI wire format has no field for them, so the remote
         # backend drops both.
@@ -114,6 +117,8 @@ class RemoteLLMBackend(LLMBackend):
         }
         if stop:
             payload["stop"] = list(stop)
+        if ignore_eos:
+            payload["ignore_eos"] = True
         deadline = resilience.get_current_deadline()
         timeout = self._timeout
         if deadline is not None:
@@ -153,7 +158,7 @@ class EchoLLMBackend(LLMBackend):
     """Streams the last user message back word-by-word (tests)."""
 
     def stream_chat(self, messages, temperature=0.2, top_p=0.7, max_tokens=1024,
-                    stop=(), prefix_hint=None, spec_decode=None):
+                    stop=(), prefix_hint=None, spec_decode=None, ignore_eos=False):
         last_user = next((c for r, c in reversed(list(messages)) if r == "user"), "")
 
         def gen():

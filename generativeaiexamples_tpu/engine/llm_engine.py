@@ -342,6 +342,31 @@ _M_FULL_READ = _REG.counter(
     "query's own), at the one step a dispatch reports. Beside "
     "window_read_tokens it is the window layers' share of attention reads.",
 )
+_M_EVA_WINDOW_READ = _REG.counter(
+    "genai_engine_eva_window_tokens_read_total",
+    "Exact keys the queries of a chunked-linearized-attention model read "
+    "from their slots' open-window buffers (each query's own position and "
+    "its window before it, summed over the layers), at the one step a "
+    "dispatch reports.",
+)
+_M_EVA_SUMMARIES_READ = _REG.counter(
+    "genai_engine_eva_summaries_read_total",
+    "Chunk summaries of closed windows the same queries read from their "
+    "pages, summed over the layers: beside eva_window_tokens_read, the "
+    "compressed past's share of the rows a read covers.",
+)
+_M_EVA_SUMMARIES_WRITTEN = _REG.counter(
+    "genai_engine_eva_summaries_written_total",
+    "Chunk summaries written to the page pool (a chunk of an extend "
+    "completed them, or a decode step wrote a chunk's last token), "
+    "summed over the layers.",
+)
+_M_EVA_WINDOWS_CLOSED = _REG.counter(
+    "genai_engine_eva_windows_closed_total",
+    "Windows that closed (a row wrote its window's last token: its buffer "
+    "restarts and the window's pages become visible), summed over the "
+    "layers.",
+)
 # a family's step stats (models/registry.py ``stat_names``) that also feed
 # a counter, by the stat's name: the engine knows mechanisms, not models
 _STAT_COUNTERS = {
@@ -361,6 +386,10 @@ _STAT_COUNTERS = {
     "msa_pages_pooled": _M_MSA_POOLED,
     "msa_chunk_kernel_layers": _M_MSA_CHUNK_READS.labels(path="kernel"),
     "msa_chunk_xla_layers": _M_MSA_CHUNK_READS.labels(path="xla"),
+    "eva_window_tokens_read": _M_EVA_WINDOW_READ,
+    "eva_summaries_read": _M_EVA_SUMMARIES_READ,
+    "eva_summaries_written": _M_EVA_SUMMARIES_WRITTEN,
+    "eva_windows_closed": _M_EVA_WINDOWS_CLOSED,
 }
 _M_SSM_DISPATCHES = _REG.counter(
     "genai_engine_ssm_dispatches_total",
@@ -369,6 +398,9 @@ _M_SSM_DISPATCHES = _REG.counter(
     "'step' (decode: one fused update a step).",
     ("path",),
 )
+
+_NO_STOP_IDS: frozenset = frozenset()  # what ends a request that set ``ignore_eos``
+
 
 @dataclasses.dataclass
 class SamplingParams:
@@ -388,6 +420,11 @@ class SamplingParams:
     # row), True is advisory (a no-op when the engine has spec off).
     # Only greedy (temperature<=0) rows ever draft.
     spec_decode: Optional[bool] = None
+    # A stop id is an ORDINARY token: it reaches the stream and the
+    # request ends at ``max_tokens`` (or at its slot's capacity). Under a
+    # byte-level vocabulary of a few hundred ids a sampled answer would
+    # otherwise end wherever chance draws one of the stop ids.
+    ignore_eos: bool = False
 
 
 class _TokenQueue:
@@ -4987,7 +5024,7 @@ class LLMEngine:
         The tokens arrive at ONE instant: the wall clock is read once a
         call, and ``gaps`` (the readback's ``HandoffBlock``) splits the
         gap since the request's previous hand-off."""
-        stop_ids = self._stop_ids
+        stop_ids = _NO_STOP_IDS if req.params.ignore_eos else self._stop_ids
         block: List[Optional[int]] = []
         done = False
         now = time.time()

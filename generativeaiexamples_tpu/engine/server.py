@@ -183,6 +183,7 @@ class ModelServer:
             stop=tuple(stop),
             seed=int(body.get("seed", 0) or 0),
             spec_decode=spec,
+            ignore_eos=body.get("ignore_eos") is True,
         )
 
     async def chat_completions(self, request: web.Request) -> web.StreamResponse:

@@ -464,6 +464,34 @@ def _minimaxm3_family() -> ModelFamily:
     )
 
 
+def _evabyte_family() -> ModelFamily:
+    from generativeaiexamples_tpu.models import evabyte as m
+
+    def init_paged_cache(cfg, pool_pages, page_size, num_slots, dtype, quantized=False, packed=False, **_):
+        if quantized or packed:
+            raise ValueError("evabyte keeps its window buffers and its summary pages in bfloat16")
+        return m.init_paged_cache(cfg, pool_pages, page_size, num_slots, dtype)
+
+    return ModelFamily(
+        name="evabyte", presets=m.PRESETS, config_type=m.EvaByteConfig, fixed_state=True,
+        init_params=m.init_params_fast, init_paged_cache=init_paged_cache,
+        prefill_paged=m.prefill_paged, extend_paged=m.extend_paged, decode_paged=m.decode_paged,
+        verify_paged=None, head=lambda params, cfg, hidden, **_: m.head(params, cfg, hidden),
+        serving_memory_bytes=m.serving_memory_bytes, count_logical_params=m.count_logical_params,
+        # what is paged is a K and a V SUMMARY row a chunk of tokens, every layer: a page of ``page_size``
+        # tokens is ``page_size / chunk_size`` rows, which ``bytes_per_token`` says and the head sizes do not
+        paged_kv_shape=lambda cfg: PagedKVShape(
+            cfg.num_layers, cfg.num_heads, cfg.head_dim, cfg.num_heads, bytes_per_token=m.kv_bytes_per_token(cfg)),
+        # the open window's exact K and V, restarted at every window boundary
+        fixed_state_bytes_per_slot=m.fixed_state_bytes_per_slot,
+        span_fields=lambda cfg: {"eva_layers": cfg.num_layers, "eva_window": cfg.window_size,
+                                 "eva_chunk": cfg.chunk_size},
+        # the decode step's one-softmax read of buffer and pages (ops/eva_read.py)
+        resolve_kernels=lambda cfg, kind: {"eva_read": m.eva_read_kind(cfg, kind)},
+        stat_names=m.STAT_NAMES, read_stats=m.read_stats, extend_reads_window=False,
+    )
+
+
 def _load_builtin() -> None:
     if not _FAMILIES:
         register_family(_llama_family())
@@ -474,3 +502,4 @@ def _load_builtin() -> None:
         register_family(_solaropen2_family())
         register_family(_kimik2_family())
         register_family(_minimaxm3_family())
+        register_family(_evabyte_family())

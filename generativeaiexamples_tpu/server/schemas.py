@@ -10,7 +10,7 @@ from __future__ import annotations
 from typing import List, Optional
 
 import bleach
-from pydantic import BaseModel, Field, field_validator
+from pydantic import BaseModel, Field, StrictBool, field_validator
 
 MAX_CONTENT_LEN = 131072
 
@@ -49,6 +49,11 @@ class Prompt(BaseModel):
     # capacity (max_seq_len - prompt), whatever is asked here.
     max_tokens: int = Field(1024, ge=0, le=32768)
     stop: List[str] = Field(default=[], max_length=256)
+    # Additive (non-reference): a stop id is an ordinary token and the
+    # answer ends at max_tokens (or its slot's capacity). For byte-level
+    # models, where a sampled answer would otherwise end wherever chance
+    # draws one of the two stop ids. A boolean and nothing that looks like one.
+    ignore_eos: StrictBool = Field(default=False)
     # Additive (non-reference): per-request deadline budget override in
     # milliseconds; the X-Request-Deadline-Ms header wins over this, the
     # resilience.request_deadline_ms config default applies when absent.

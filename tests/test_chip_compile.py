@@ -763,3 +763,26 @@ def test_selected_chunk_read_compiles_at_the_published_widths(one_chip, no_persi
     assert text.count("tpu_custom_call") == 1 and "selected_chunk_read" in text
     # beside the arguments: the queries regrouped a KV head and the padded page numbers, nothing of a score's size
     assert compiled.memory_analysis().temp_size_in_bytes < rows * T * (Hq * Dh * 2 + Hkv * 128 * 4) + (4 << 20)
+
+
+def test_eva_decode_read_compiles_at_the_published_widths(one_chip, no_persistent_cache):
+    """The decode step's one-softmax read of buffer and pages alone
+    (ops/eva_read.py), for the described chip, at the cell's shapes: 24
+    rows of 32 heads of 128 over buffers of 2,048 rows and a pool of
+    3,073 pages of 8 summary rows (two halves of the heads a 32-bit
+    word), a table of 160 pages a row, a dynamic number of steps."""
+    from generativeaiexamples_tpu.ops import eva_read
+
+    B, H, Dh, W, page, rows, P, Pmax = 24, 32, 128, 2048, 128, 8, 3073, 160
+    s = lambda shape, dt: jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)  # noqa: E731
+    assert eva_read.supports(H, Dh, W)
+
+    def read(q, wk, wv, sk, sv, tables, positions):
+        work = eva_read.work_list(tables, positions, W, page)
+        return eva_read.eva_decode_read(q, wk, wv, sk, sv, work, window=W)
+
+    compiled = jax.jit(read).lower(
+        s((B, H, Dh), jnp.bfloat16), s((B, W, H * Dh), jnp.bfloat16), s((B, W, H * Dh), jnp.bfloat16),
+        s((P, rows, H * Dh // 2), jnp.uint32), s((P, rows, H * Dh // 2), jnp.uint32),
+        s((B, Pmax), jnp.int32), s((B,), jnp.int32)).compile()
+    assert compiled.as_text().count("tpu_custom_call") == 1
