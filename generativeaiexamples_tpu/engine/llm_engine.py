@@ -367,6 +367,15 @@ _M_EVA_WINDOWS_CLOSED = _REG.counter(
     "restarts and the window's pages become visible), summed over the "
     "layers.",
 )
+_M_EVA_CHUNK_READS = _REG.counter(
+    "genai_engine_eva_chunk_reads_total",
+    "Chunked-linearized-attention layers an extend dispatch read, by "
+    "path: kernel (ops/eva_read.py eva_chunk_read: buffer and summary "
+    "pages read in place, scores, masks and probabilities stay in VMEM) "
+    "or xla (the gathered, concatenated, masked read). Beside the spans' "
+    "eva_chunk_kernel_layers; the kernel's share is its engagement.",
+    ("path",),
+)
 # a family's step stats (models/registry.py ``stat_names``) that also feed
 # a counter, by the stat's name: the engine knows mechanisms, not models
 _STAT_COUNTERS = {
@@ -390,6 +399,8 @@ _STAT_COUNTERS = {
     "eva_summaries_read": _M_EVA_SUMMARIES_READ,
     "eva_summaries_written": _M_EVA_SUMMARIES_WRITTEN,
     "eva_windows_closed": _M_EVA_WINDOWS_CLOSED,
+    "eva_chunk_kernel_layers": _M_EVA_CHUNK_READS.labels(path="kernel"),
+    "eva_chunk_xla_layers": _M_EVA_CHUNK_READS.labels(path="xla"),
 }
 _M_SSM_DISPATCHES = _REG.counter(
     "genai_engine_ssm_dispatches_total",

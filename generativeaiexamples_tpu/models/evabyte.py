@@ -35,7 +35,18 @@ walk that writes the chunk's last token (the chunk walk from its own
 keys, a decode step from the buffer's last ``C`` rows); a row reads the
 first ``(W / page_size) (t // W)`` pages of its table, so the pages of
 the open window are written as its chunks complete and never read before
-it closes. ``stats`` holds four int32 counts of the last walk.
+it closes. ``stats`` holds ``STAT_NAMES``' int32 counts of the last walk.
+
+**One resolved kind, two reads** (``eva_read``: ``compiled`` /
+``interpret`` / None). A decode step reads through
+``ops/eva_read.py`` ``eva_decode_read``, a chunk walk through
+``eva_chunk_read`` where its shapes tile too (``chunk_read_in_kernel``):
+the chunk's keys go to the buffer first and are then buffer rows under a
+causal bound, so both reads have two sources, read in place. Where no
+kernel serves, ``eva_decode_read_xla`` and ``_attend`` read the same keys
+through XLA: what the tests hold the kernels to, not a second serving
+path. ``eva_chunk_kernel_layers`` / ``eva_chunk_xla_layers`` in the stats
+say which body read a chunk.
 
 **What the walks ask of the engine**: ``page_size`` divides ``W`` and is
 a multiple of ``C``; a chunk of an extend lies inside ONE window (the
@@ -61,7 +72,8 @@ Params = Dict[str, Any]
 Caches = Dict[str, Any]
 _NEG = -1e30
 
-STAT_NAMES = ("eva_window_tokens_read", "eva_summaries_read", "eva_summaries_written", "eva_windows_closed")
+STAT_NAMES = ("eva_window_tokens_read", "eva_summaries_read", "eva_summaries_written", "eva_windows_closed",
+              "eva_chunk_kernel_layers", "eva_chunk_xla_layers")
 
 
 @dataclasses.dataclass(frozen=True)
@@ -266,6 +278,22 @@ def _write_summaries(pool, k, v, done, page, row, lp: Params, cfg: EvaByteConfig
             "v": pool["v"].at[at_page, row].set(eva_ops.pack_rows(vbar), mode="drop")}
 
 
+def _write_rows(buf, rows, slots, base, keep):
+    """A chunk's ``rows`` [N, T, H * Dh] into the buffers ``buf`` [slots,
+    W, H * Dh]: row ``n``'s into slot ``slots[n]`` from buffer row
+    ``base[n]`` on (the chunk lies inside one window: ``base + T <= W``),
+    ONE slab a row; where ``keep`` [N, T] is False (a padding token) the
+    buffer keeps what it held. A scatter of the rows one by one under
+    ``mode="drop"`` cost 157 us a buffer on the chip, 2.5 ms a chunk of
+    eight layers, for 4 MB written (PERF.md section 6, PR 58)."""
+    N, T, HD = rows.shape
+    for n in range(N):
+        start = (slots[n], base[n], jnp.int32(0))
+        held = jax.lax.dynamic_slice(buf, start, (1, T, HD))
+        buf = jax.lax.dynamic_update_slice(buf, jnp.where(keep[n][None, :, None], rows[n][None], held), start)
+    return buf
+
+
 def _attend(q, keys, vals, seen, head_groups: int = 4):
     """q [N, T, H, Dh] against keys / vals [N, S, H, Dh] under the mask
     ``seen`` [N, T, S], one softmax a query and head: [N, T, H * Dh]
@@ -300,24 +328,41 @@ def head(params: Params, cfg: EvaByteConfig, hidden, all_heads: bool = False):
     return _mm(norm(hidden, params["final_norm"], cfg), w)
 
 
-def _stats(cfg: EvaByteConfig, window_read, summaries_read, written, closed):
-    return (jnp.stack([window_read, summaries_read, written, closed]) * cfg.num_layers).astype(jnp.int32)
+def _stats(cfg: EvaByteConfig, window_read, summaries_read, written, closed, chunk_kernel: Optional[bool] = None):
+    """``STAT_NAMES`` of one walk, every count summed over the layers;
+    ``chunk_kernel`` says which body read a CHUNK walk's attention (None:
+    a decode step, neither)."""
+    chunk = [jnp.int32(chunk_kernel is True), jnp.int32(chunk_kernel is False)]
+    return (jnp.stack([window_read, summaries_read, written, closed] + chunk) * cfg.num_layers).astype(jnp.int32)
 
 
 # --------------------------------------------------------------------- //
 # The chunk walk: prefill and chunked extend
 
 
+def chunk_read_in_kernel(cfg: EvaByteConfig, eva_read: Optional[str], T: int, page_size: int) -> bool:
+    """Whether a chunk walk of ``T`` tokens reads through
+    ``ops/eva_read.py`` ``eva_chunk_read``: the family's resolved
+    ``eva_read`` kind governs both reads, and the chunk's shapes must tile
+    too (interpreted: any size)."""
+    return eva_read == "interpret" or (
+        eva_read == "compiled" and eva_ops.chunk_supports(T, page_size // cfg.chunk_size))
+
+
 def _chunk_walk(params: Params, cfg: EvaByteConfig, caches: Caches, tokens, offsets, valid, slots, tables,
-                page_size: int):
+                page_size: int, eva_read: Optional[str] = None):
     """All layers over a chunk [N, T] a row that lies inside ONE window;
     returns (the residual row of each row's last valid position [N, D],
     caches). A query sees, under one softmax, the buffer's rows below the
     chunk (``offsets % W`` of them), the chunk's own keys up to itself and
     the summaries in the row's pages of every closed window. The chunk's
     keys and values go to the buffer, and its completed chunks' summaries
-    to the window's pages. XLA throughout. A row with ``valid == 0``
-    changes nothing: its writes are dropped."""
+    to the window's pages. Where ``eva_read`` resolved and the shapes tile
+    (``chunk_read_in_kernel``) the buffer is written FIRST and the read is
+    one Pallas kernel over two sources, the chunk's own keys ordinary
+    buffer rows under a causal bound (``eva_chunk_read``); otherwise
+    ``_attend`` reads the three sources through XLA. A row with ``valid ==
+    0`` changes nothing: its writes are dropped."""
     N, T = tokens.shape
     W, C, H, Dh = cfg.window_size, cfg.chunk_size, cfg.num_heads, cfg.head_dim
     _check_pages(cfg, page_size)
@@ -334,14 +379,19 @@ def _chunk_walk(params: Params, cfg: EvaByteConfig, caches: Caches, tokens, offs
     closed = (offsets // W) * cfg.chunks_a_window  # summaries a query of this chunk sees
     row_tables = tables[slots]  # [N, Pmax]
     n_sum = row_tables.shape[1] * rpp
-    seen = jnp.concatenate([
-        jnp.broadcast_to((jnp.arange(W, dtype=jnp.int32)[None, :] < base[:, None])[:, None, :], (N, T, W)),
-        jnp.broadcast_to((idx[:, None] >= idx[None, :])[None], (N, T, T)),
-        jnp.broadcast_to((jnp.arange(n_sum, dtype=jnp.int32)[None, :] < closed[:, None])[:, None, :], (N, T, n_sum)),
-    ], axis=2)  # [N, T, W + T + n_sum]
+    in_kernel = chunk_read_in_kernel(cfg, eva_read, T, page_size)
+    if in_kernel:
+        work = eva_ops.chunk_work_list(tables, slots, offsets, valid, T, W, page_size,
+                                       caches["win"][0]["k"].shape[0], caches["sum"][0]["k"].shape[0])
+    else:
+        seen = jnp.concatenate([
+            jnp.broadcast_to((jnp.arange(W, dtype=jnp.int32)[None, :] < base[:, None])[:, None, :], (N, T, W)),
+            jnp.broadcast_to((idx[:, None] >= idx[None, :])[None], (N, T, T)),
+            jnp.broadcast_to((jnp.arange(n_sum, dtype=jnp.int32)[None, :] < closed[:, None])[:, None, :],
+                             (N, T, n_sum)),
+        ], axis=2)  # [N, T, W + T + n_sum]
     # the buffer takes the chunk's valid tokens; a padding token is dropped
-    win_at = jnp.where(tok_valid, base[:, None] + idx[None, :], W)
-    win_lead = jnp.broadcast_to(slots[:, None], win_at.shape)
+    to_buffer = functools.partial(_write_rows, slots=slots, base=base, keep=tok_valid)
     # chunk j of the extend chunk is complete where its last token is valid
     cj = jnp.arange(T // C, dtype=jnp.int32)
     chunk_pos = offsets[:, None] + cj[None, :] * C  # [N, T / C] first position of each chunk
@@ -355,6 +405,7 @@ def _chunk_walk(params: Params, cfg: EvaByteConfig, caches: Caches, tokens, offs
         jnp.sum(n_tok * closed),
         jnp.sum(chunk_done),
         jnp.sum((valid > 0) & (base + valid == W)),
+        chunk_kernel=in_kernel,
     )
 
     x = params["embed"][tokens].astype(jnp.float32)  # [N, T, D]
@@ -364,14 +415,17 @@ def _chunk_walk(params: Params, cfg: EvaByteConfig, caches: Caches, tokens, offs
         with jax.named_scope("eva_chunk_attn"):
             q, k, v = _project(norm(x, lp["n1"], cfg), positions, lp, cfg, dtype)
             win, pool = caches["win"][l], caches["sum"][l]
-            sk, sv = (eva_ops.unpack_rows(pool[n][row_tables], H).reshape(N, n_sum, H, Dh) for n in ("k", "v"))
-            keys = jnp.concatenate([win["k"][slots].reshape(N, W, H, Dh), k, sk.astype(dtype)], axis=1)
-            vals = jnp.concatenate([win["v"][slots].reshape(N, W, H, Dh), v, sv.astype(dtype)], axis=1)
-            x = x + _mm(_attend(q, keys, vals, seen), lp["wo"])
-            new["win"][l] = {
-                "k": win["k"].at[win_lead, win_at].set(k.reshape(N, T, H * Dh), mode="drop"),
-                "v": win["v"].at[win_lead, win_at].set(v.reshape(N, T, H * Dh), mode="drop"),
-            }
+            wk, wv = to_buffer(win["k"], k.reshape(N, T, H * Dh)), to_buffer(win["v"], v.reshape(N, T, H * Dh))
+            new["win"][l] = {"k": wk, "v": wv}
+            if in_kernel:  # over the buffer as WRITTEN: the chunk's own keys are rows under the read's causal bound
+                o = eva_ops.eva_chunk_read(q.reshape(N, T, H * Dh), wk, wv, pool["k"], pool["v"], work, num_heads=H,
+                                           interpret=(eva_read == "interpret"))
+            else:  # over the buffer as it WAS, the chunk's keys beside it
+                sk, sv = (eva_ops.unpack_rows(pool[n][row_tables], H).reshape(N, n_sum, H, Dh) for n in ("k", "v"))
+                keys = jnp.concatenate([win["k"][slots].reshape(N, W, H, Dh), k, sk.astype(dtype)], axis=1)
+                vals = jnp.concatenate([win["v"][slots].reshape(N, W, H, Dh), v, sv.astype(dtype)], axis=1)
+                o = _attend(q, keys, vals, seen)
+            x = x + _mm(o, lp["wo"])
             new["sum"][l] = _write_summaries(pool, k.reshape(N, T // C, C, H, Dh), v.reshape(N, T // C, C, H, Dh),
                                              chunk_done, sum_page, sum_row, lp, cfg)
         x = _mlp(x, lp, cfg)
@@ -380,7 +434,7 @@ def _chunk_walk(params: Params, cfg: EvaByteConfig, caches: Caches, tokens, offs
 
 
 def prefill_paged(params: Params, cfg: EvaByteConfig, caches: Caches, tokens, lengths, slots, tables,
-                  page_size: int, **_paths):
+                  page_size: int, eva_read: Optional[str] = None, **_paths):
     """A REFERENCE walk, a whole prompt in one program: (last-position
     logits [N, V], caches). The prompt is walked a window at a time (a
     chunk walk lies inside one window); the logits are those of each
@@ -397,16 +451,16 @@ def prefill_paged(params: Params, cfg: EvaByteConfig, caches: Caches, tokens, le
         piece = jnp.pad(piece, ((0, 0), (0, pad)))
         n = jnp.clip(lengths - start, 0, width)
         h, caches = _chunk_walk(params, cfg, caches, piece, jnp.full_like(lengths, start), n, slots, tables,
-                                page_size)
+                                page_size, eva_read)
         hidden = jnp.where((n > 0)[:, None], h, hidden)
     return head(params, cfg, hidden), caches
 
 
 def extend_paged(params: Params, cfg: EvaByteConfig, caches: Caches, tokens, offsets, valid, slots, tables,
-                 window: int, page_size: int, **_paths):
+                 window: int, page_size: int, eva_read: Optional[str] = None, **_paths):
     """One chunk of a chunked prefill: (the residual row [N, D] of each row's last valid position, caches)."""
     del window  # the read follows each row's own window and pages
-    return _chunk_walk(params, cfg, caches, tokens, offsets, valid, slots, tables, page_size)
+    return _chunk_walk(params, cfg, caches, tokens, offsets, valid, slots, tables, page_size, eva_read)
 
 
 # --------------------------------------------------------------------- //

@@ -786,3 +786,34 @@ def test_eva_decode_read_compiles_at_the_published_widths(one_chip, no_persisten
         s((P, rows, H * Dh // 2), jnp.uint32), s((P, rows, H * Dh // 2), jnp.uint32),
         s((B, Pmax), jnp.int32), s((B,), jnp.int32)).compile()
     assert compiled.as_text().count("tpu_custom_call") == 1
+
+
+@pytest.mark.parametrize("rows, T", [(1, 512), (1, 128), (2, 2048)], ids=["chunk-512", "chunk-128", "two-rows-a-window"])
+def test_eva_chunk_read_compiles_at_the_published_widths(one_chip, no_persistent_cache, rows, T):
+    """The chunk walk's one-softmax read of buffer and pages alone
+    (ops/eva_read.py ``eva_chunk_read``), for the described chip, at the
+    cell's shapes: one row x 512 queries (and a tail's 128, and the
+    reference walk's whole window) of 32 heads of 128, 24 buffers of
+    2,048 rows, 3,073 pages of 8 summary rows, a table of 160 pages, a
+    run-time count of steps."""
+    from generativeaiexamples_tpu.models import evabyte
+    from generativeaiexamples_tpu.ops import eva_read
+
+    H, Dh, W, page, slots, P, Pmax = 32, 128, 2048, 128, 24, 3073, 160
+    cfg = evabyte.PRESETS["evabyte-6.5b-pp4"]
+    assert evabyte.chunk_read_in_kernel(cfg, evabyte.eva_read_kind(cfg, "compiled"), T, page)
+    assert not evabyte.chunk_read_in_kernel(cfg, "compiled", 64, page)  # a chunk of no whole lane tiles: _attend
+    s = lambda shape, dt: jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)  # noqa: E731
+
+    def read(q, wk, wv, sk, sv, tables, slot, offsets, valid):
+        work = eva_read.chunk_work_list(tables, slot, offsets, valid, T, W, page, slots, P)
+        return eva_read.eva_chunk_read(q, wk, wv, sk, sv, work, num_heads=H)
+
+    compiled = jax.jit(read).lower(
+        s((rows, T, H * Dh), jnp.bfloat16), s((slots, W, H * Dh), jnp.bfloat16), s((slots, W, H * Dh), jnp.bfloat16),
+        s((P, 8, H * Dh // 2), jnp.uint32), s((P, 8, H * Dh // 2), jnp.uint32), s((slots, Pmax), jnp.int32),
+        s((rows,), jnp.int32), s((rows,), jnp.int32), s((rows,), jnp.int32)).compile()
+    text = compiled.as_text()
+    assert text.count("tpu_custom_call") == 1 and "eva_chunk_read" in text
+    # beside the arguments: the work list and its index arithmetic, nothing of a score's size ([32, T, keys] float32)
+    assert compiled.memory_analysis().temp_size_in_bytes < (4 << 20)

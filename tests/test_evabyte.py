@@ -60,15 +60,15 @@ def fresh_cache(dtype=jnp.float32):
     return m.init_paged_cache(CFG, 1 + SLOTS * PMAX, PAGE, SLOTS, dtype)
 
 
-def extend(params, caches, toks, slot, width, start=0):
-    """Chunked extend of ``toks`` (positions ``start ..``) into ``slot``; returns (all-head logits of the last
-    position, caches)."""
+def extend(params, caches, toks, slot, width, start=0, path=None):
+    """Chunked extend of ``toks`` (positions ``start ..``) into ``slot``, the chunk walk's read through ``path``;
+    returns (all-head logits of the last position, caches)."""
     slots = jnp.asarray([slot], jnp.int32)
     for s in range(0, len(toks), width):
         n = min(width, len(toks) - s)
         piece = jnp.asarray(np.pad(np.asarray(toks[s:s + n], np.int32), (0, width - n))[None])
         hidden, caches = m.extend_paged(params, CFG, caches, piece, jnp.asarray([start + s], jnp.int32),
-                                        jnp.asarray([n], jnp.int32), slots, TABLES, 0, PAGE)
+                                        jnp.asarray([n], jnp.int32), slots, TABLES, 0, PAGE, eva_read=path)
     return np.asarray(m.head(params, CFG, hidden, all_heads=True))[0], caches
 
 
@@ -145,7 +145,7 @@ def test_extend_then_decode_is_the_reference_on_all_logits(params, prompt, steps
     toks = np.random.default_rng(prompt).integers(0, CFG.vocab_size, size=(prompt + steps,))
     ref = reference_logits(params, toks)
     assert ref.shape == (prompt + steps, V_ALL)
-    logits, caches = extend(params, fresh_cache(), toks[:prompt], slot=1, width=16)
+    logits, caches = extend(params, fresh_cache(), toks[:prompt], slot=1, width=16, path=path)
     assert rel_err(logits, ref[prompt - 1]) < TOL
     for j in range(steps):
         logits, caches = decode(params, caches, toks[prompt + j], prompt + j, slot=1, path=path)
@@ -153,14 +153,16 @@ def test_extend_then_decode_is_the_reference_on_all_logits(params, prompt, steps
 
 
 @pytest.mark.parametrize("width", [8, 16, 32])
-def test_extend_in_chunks_of_any_width_equals_extend_whole(params, width):
-    """The widths an engine can send (whole pages that divide the window) against the whole prompt in one
-    program (``prefill_paged``), and the caches they leave: the same buffer rows and the same summaries."""
+@pytest.mark.parametrize("path", [None, "interpret"], ids=["xla_read", "kernel_read"])
+def test_extend_in_chunks_of_any_width_equals_extend_whole(params, width, path):
+    """The widths an engine can send (whole pages that divide the window), their reads through ``path``, against
+    the whole prompt in one program (``prefill_paged``, its reads through XLA), and the caches they leave: the
+    same buffer rows and the same summaries."""
     T = 2 * W + 21
     toks = np.random.default_rng(9).integers(0, CFG.vocab_size, size=(T,))
     whole, cache_whole = m.prefill_paged(params, CFG, fresh_cache(), jnp.asarray(toks[None], jnp.int32),
                                          jnp.asarray([T]), jnp.asarray([2]), TABLES, PAGE)
-    logits, caches = extend(params, fresh_cache(), toks, slot=2, width=width)
+    logits, caches = extend(params, fresh_cache(), toks, slot=2, width=width, path=path)
     np.testing.assert_allclose(logits[: CFG.vocab_size], np.asarray(whole)[0], atol=2e-5)
     for l in range(CFG.num_layers):
         np.testing.assert_allclose(np.asarray(caches["win"][l]["k"][2, : T % W]),
@@ -219,6 +221,97 @@ def test_the_kernel_reads_what_the_xla_read_reads():
     assert np.array_equal(np.asarray(eva_read.unpack_rows(eva_read.pack_rows(x), H)), np.asarray(x, np.float32))
 
 
+# (offsets, valid) of two rows (slots 2 and 0) walked as one chunk of 16 queries
+CHUNK_CASES = {
+    "at_zero_and_inside_the_first_window": ([0, 16], [16, 16]),
+    "at_a_window_boundary_and_three_windows_deep": ([2 * W, 3 * W + 16], [16, 16]),
+    "partly_padding": ([W + 8, 4 * W], [11, 3]),
+    "one_row_with_nothing_valid": ([3 * W + 8, 8], [0, 16]),
+}
+
+
+@pytest.mark.parametrize("tiles", [(None, None), (8, 8)], ids=["one_tile", "tiles_of_8"])
+@pytest.mark.parametrize("case", sorted(CHUNK_CASES))
+def test_the_chunk_kernel_reads_what_attend_reads(case, tiles):
+    """``eva_chunk_read`` over the buffer AFTER the chunk's keys went into it, against ``_attend`` over buffer,
+    chunk and gathered summaries: the same to float32 rounding on every valid query, with the buffer's rows past
+    the chunk (a former tenant's) and the pages of the OPEN window and past it poisoned with large values. A
+    padding query may read the stale rows: its output feeds nothing and is finite."""
+    rng = np.random.default_rng(7)
+    H, Dh, T, N = CFG.num_heads, CFG.head_dim, 16, 2
+    offsets, valid = (jnp.asarray(x, jnp.int32) for x in CHUNK_CASES[case])
+    slots = jnp.asarray([2, 0], jnp.int32)
+    base, n_closed = np.asarray(offsets) % W, np.asarray(offsets) // W
+    q, k, v = (jnp.asarray(rng.normal(size=(N, T, H, Dh)), jnp.float32) for _ in range(3))
+    win = {n: rng.normal(size=(SLOTS, W, H * Dh)).astype(np.float32) for n in ("k", "v")}
+    pool = {n: np.array(eva_read.pack_rows(jnp.asarray(rng.normal(size=(1 + SLOTS * PMAX, PAGE // C, H, Dh)),
+                                                      jnp.float32))) for n in ("k", "v")}
+    poison = np.asarray(eva_read.pack_rows(jnp.full((PAGE // C, H, Dh), 1e4)))
+    for r in range(N):
+        for n in ("k", "v"):
+            win[n][int(slots[r]), base[r] + int(valid[r]):] = 1e4  # stale rows past the chunk's valid tokens
+            pool[n][np.asarray(TABLES[int(slots[r]), n_closed[r] * (W // PAGE):])] = poison  # the open window's pages on
+    win, pool = jax.tree.map(jnp.asarray, (win, pool))
+    # what _chunk_walk hands _attend: the buffer below the chunk, the chunk's own keys, every page of the table
+    idx = jnp.arange(T)
+    n_sum = PMAX * (PAGE // C)
+    seen = jnp.concatenate([
+        jnp.broadcast_to((jnp.arange(W)[None, :] < base[:, None])[:, None, :], (N, T, W)),
+        jnp.broadcast_to((idx[:, None] >= idx[None, :])[None], (N, T, T)),
+        jnp.broadcast_to((jnp.arange(n_sum)[None, :] < (n_closed * (W // C))[:, None])[:, None, :], (N, T, n_sum)),
+    ], axis=2)
+    gathered = {n: eva_read.unpack_rows(pool[n][TABLES[slots]], H).reshape(N, n_sum, H, Dh) for n in ("k", "v")}
+    want = m._attend(q, jnp.concatenate([win["k"][slots].reshape(N, W, H, Dh), k, gathered["k"]], axis=1),
+                     jnp.concatenate([win["v"][slots].reshape(N, W, H, Dh), v, gathered["v"]], axis=1), seen)
+    # the kernel's side: the chunk's valid tokens written first, as _chunk_walk writes them
+    at = jnp.where(idx[None, :] < valid[:, None], jnp.asarray(base)[:, None] + idx[None, :], W)
+    lead = jnp.broadcast_to(slots[:, None], at.shape)
+    wk = win["k"].at[lead, at].set(k.reshape(N, T, H * Dh), mode="drop")
+    wv = win["v"].at[lead, at].set(v.reshape(N, T, H * Dh), mode="drop")
+    tq, tw = tiles
+    work = eva_read.chunk_work_list(TABLES, slots, offsets, valid, T, W, PAGE, SLOTS, 1 + SLOTS * PMAX,
+                                    query_tile=tq, window_tile=tw)
+    got = eva_read.eva_chunk_read(q.reshape(N, T, H * Dh), wk, wv, pool["k"], pool["v"], work, num_heads=H,
+                                  interpret=True, query_tile=tq, window_tile=tw)
+    got, want = np.asarray(got), np.asarray(want)
+    assert np.isfinite(got).all()
+    for r in range(N):
+        np.testing.assert_allclose(got[r, : int(valid[r])], want[r, : int(valid[r])], atol=3e-6)
+
+
+def test_the_chunk_work_list_is_a_count_made_by_hand():
+    """Two rows of 16 queries in query tiles of 8 over buffer tiles of 8 rows. Row 0 (slot 2) at offset 2 W + 8,
+    all valid: tile 0 sees buffer rows 0 .. 15 (two buffer tiles), tile 1 rows 0 .. 23 (three; the fourth lies
+    wholly above it), each then one step a CLOSED window (two); none for the open window's pages. Row 1 (slot 0)
+    at offset 3, 5 valid: tile 0 sees rows 0 .. 7 (one buffer tile), tile 1 has no valid query and walks buffer
+    tile 0 alone; no window has closed."""
+    work = eva_read.chunk_work_list(TABLES, jnp.asarray([2, 0]), jnp.asarray([2 * W + 8, 3]), jnp.asarray([16, 5]),
+                                    16, W, PAGE, SLOTS, 1 + SLOTS * PMAX, query_tile=8, window_tile=8)
+    n = int(work.n_work[0])
+    assert n == (2 + 2) + (3 + 2) + 1 + 1
+    steps = lambda name: getattr(work, name)[:n].tolist()  # noqa: E731
+    assert steps("row") == [0] * 9 + [1] * 2 and steps("slot") == [2] * 9 + [0] * 2
+    assert steps("qtile") == [0] * 4 + [1] * 5 + [0, 1]
+    is_sum = [f // 4 % 2 for f in steps("flags")]
+    assert is_sum == [0, 0, 1, 1] + [0, 0, 0, 1, 1] + [0, 0]
+    assert [t for t, s in zip(steps("tile"), is_sum) if not s] == [0, 1] + [0, 1, 2] + [0, 0]
+    assert [f % 2 for f in steps("flags")] == [1, 0, 0, 0] + [1, 0, 0, 0, 0] + [1, 1]  # a tile's first step
+    assert [f // 2 % 2 for f in steps("flags")] == [0, 0, 0, 1] + [0, 0, 0, 0, 1] + [1, 1]  # and its last
+    # the causal compare only where a buffer tile's last row lies past the tile's first query's own row: query tile 0
+    # of row 0 starts at buffer row 8, so buffer tile 0 is seen whole and tile 1 is not; query tile 1 starts at 16;
+    # row 1 starts at buffer row 3 (inside tile 0) and its second query tile at 11 (past it)
+    assert [f // 8 for f in steps("flags")] == [0, 1, 0, 0] + [0, 0, 1, 0, 0] + [1, 0]
+    # a summary step names the four pages of its closed window; a window step keeps the pages of the step before
+    ppw = W // PAGE
+    phys = np.asarray(work.phys).reshape(-1, ppw)[:n]
+    first, second = np.asarray(TABLES[2, :ppw]), np.asarray(TABLES[2, ppw:2 * ppw])
+    assert [p.tolist() for p in phys[2:4]] == [first.tolist(), second.tolist()]
+    assert [p.tolist() for p in phys[7:9]] == [first.tolist(), second.tolist()]
+    assert (phys[:2] == 0).all() and (phys[4:7] == second).all() and (phys[9:] == second).all()
+    open_pages = set(np.asarray(TABLES[2, 2 * ppw:]).tolist()) | set(np.asarray(TABLES[0]).tolist())
+    assert not open_pages & set(phys.reshape(-1).tolist())
+
+
 # --------------------------------------------------------------------- //
 # The served precision, and the wrong forms it must tell apart
 
@@ -240,7 +333,7 @@ def served_bf16():
     rows = {}
     for T in DEPTHS:
         toks = np.random.default_rng(T).integers(0, CFG.vocab_size, size=(T + 1,))
-        _, caches = extend(params, fresh_cache(jnp.bfloat16), toks[:T], slot=1, width=16)
+        _, caches = extend(params, fresh_cache(jnp.bfloat16), toks[:T], slot=1, width=16, path="interpret")
         rows[T] = (toks, decode(params, caches, toks[T], T, slot=1, path="interpret")[0])
     return params, rows
 
@@ -344,25 +437,32 @@ def test_engine_serves_every_depth_as_the_references_argmax(engine):
     assert engine._compile_watch.snapshot().get("hot_path_compiles_total", 0) == 0
 
 
+def eva_counters():
+    """The ``genai_engine_eva_*`` lines of ``/metrics``' registry, by name and labels."""
+    from generativeaiexamples_tpu.utils import metrics as metrics_mod
+
+    out = {}
+    for line in metrics_mod.get_registry().render().splitlines():
+        if line.startswith("genai_engine_eva_") and " " in line:
+            k, v = line.rsplit(" ", 1)
+            out[k] = float(v)
+    return out
+
+
+def chunk_reads(counts, path):
+    return counts.get(f'genai_engine_eva_chunk_reads_total{{path="{path}"}}', 0.0)
+
+
 def test_engine_reads_the_four_counts_back_and_they_are_a_count_made_by_hand(engine):
     from generativeaiexamples_tpu.engine import dispatch_timeline
     from generativeaiexamples_tpu.engine.llm_engine import SamplingParams
-    from generativeaiexamples_tpu.utils import metrics as metrics_mod
 
-    def read():
-        out = {}
-        for line in metrics_mod.get_registry().render().splitlines():
-            if line.startswith("genai_engine_eva_") and " " in line:
-                k, v = line.rsplit(" ", 1)
-                out[k] = float(v)
-        return out
-
-    before, cursor = read(), dispatch_timeline.cursor()
+    before, cursor = eva_counters(), dispatch_timeline.cursor()
     # 75 prompt tokens: chunks of 32, 32 and 11 (padded to a chunk of 32 or of 8 + ...), then 25 decode steps:
     # positions 75 .. 99, which close the window at 95
     list(engine.iter_ids(list(range(3, 38)) + list(range(3, 38)) + list(range(3, 8)),
                          SamplingParams(temperature=0.0, max_tokens=26, ignore_eos=True), timeout=600))
-    grew = {k: v - before.get(k, 0.0) for k, v in read().items()}
+    grew = {k: v - before.get(k, 0.0) for k, v in eva_counters().items()}
     L, per_window = CFG.num_layers, W // C
     spans = [s for s in dispatch_timeline.spans_since(cursor)[0] if "eva_window_tokens_read" in s]
     chunks = [s for s in spans if s["kind"] == "prefill_chunk"]
@@ -386,7 +486,37 @@ def test_engine_reads_the_four_counts_back_and_they_are_a_count_made_by_hand(eng
     assert grew["genai_engine_eva_summaries_written_total"] >= 18 * L
     assert grew["genai_engine_eva_window_tokens_read_total"] >= L * (2 * sum(range(1, 33)) + sum(range(1, 12)))
     assert grew["genai_engine_eva_summaries_read_total"] >= L * (32 + 22) * per_window
+    # which body read the chunks: under ``interpret`` every layer of every chunk went through the kernel
+    # (ops/eva_read.py eva_chunk_read), a decode step through neither
+    assert [(s["eva_chunk_kernel_layers"], s["eva_chunk_xla_layers"]) for s in chunks] == [(L, 0)] * 3
+    assert all(s["eva_chunk_kernel_layers"] == s["eva_chunk_xla_layers"] == 0 for s in steps)
+    after = eva_counters()
+    assert chunk_reads(after, "kernel") - chunk_reads(before, "kernel") == 3 * L
+    assert chunk_reads(after, "xla") == chunk_reads(before, "xla")
     assert engine._compile_watch.snapshot().get("hot_path_compiles_total", 0) == 0
+
+
+def test_with_the_kernel_off_the_chunks_read_through_attend_and_the_counter_says_so():
+    """The same family with ``paged_kernel="off"``: ``eva_read`` resolves to None, the chunk walk reads through
+    ``_attend``, every layer of a chunk counts under ``path="xla"`` and none under ``path="kernel"``."""
+    from generativeaiexamples_tpu.config import EngineConfig
+    from generativeaiexamples_tpu.engine import dispatch_timeline
+    from generativeaiexamples_tpu.engine.llm_engine import LLMEngine, SamplingParams
+
+    eng = LLMEngine(EngineConfig(**dict(BASE, paged_kernel="off", max_batch_size=1, max_seq_len=64)))
+    try:
+        assert eng._family_kernels == {"eva_read": None}
+        before, cursor = eva_counters(), dispatch_timeline.cursor()
+        out = list(eng.iter_ids(list(range(3, 38)) + [5, 6, 7, 8, 9], SamplingParams(temperature=0.0, max_tokens=2),
+                                timeout=600))  # 40 tokens: chunks of 32 and 8
+        after = eva_counters()
+        chunks = [s for s in dispatch_timeline.spans_since(cursor)[0] if s["kind"] == "prefill_chunk"]
+        L = CFG.num_layers
+        assert len(out) <= 2 and [(s["eva_chunk_kernel_layers"], s["eva_chunk_xla_layers"]) for s in chunks] == [(0, L)] * 2
+        assert chunk_reads(after, "xla") - chunk_reads(before, "xla") == 2 * L
+        assert chunk_reads(after, "kernel") == chunk_reads(before, "kernel")
+    finally:
+        eng.shutdown()
 
 
 def test_with_ignore_eos_a_sampled_stop_id_is_a_frame_and_the_answer_ends_on_its_budget(engine, monkeypatch):

@@ -17,6 +17,7 @@ Count here first, then measure on the chip.
     JAX_PLATFORMS=cpu python -m tools.kernel_bundles --heads 32 8 --kv int8 --pages-a-step 2
     JAX_PLATFORMS=cpu python -m tools.kernel_bundles --heads 64 8 --kv bf16 --head-major --file other/page_attention.py
     JAX_PLATFORMS=cpu python -m tools.kernel_bundles --selected-chunk 512   # ops/selected_chunk_read.py (PR 53)
+    JAX_PLATFORMS=cpu python -m tools.kernel_bundles --eva-chunk 512        # ops/eva_read.py eva_chunk_read (PR 58)
 
 Prints the kernel's total bundles, the size of every region between
 control targets (the largest is the page arithmetic; at N pages a step
@@ -63,6 +64,10 @@ def _parse() -> argparse.Namespace:
     ap.add_argument("--selected-chunk", type=int, default=0, metavar="T",
                     help="compile ops/selected_chunk_read.py instead, at MiniMax-M3's widths (64/4 heads of 128, one "
                          "row, 288 pages, 19 places a query) and a chunk of T queries")
+    ap.add_argument("--eva-chunk", type=int, default=0, metavar="T",
+                    help="compile ops/eva_read.py eva_chunk_read instead, at EvaByte's widths (32 heads of 128, 24 "
+                         "buffers of 2,048 rows, 3,073 pages of 8 summary rows, one row, a table of 160 pages) and a "
+                         "chunk of T queries")
     ap.add_argument("--keep", default=None, help="directory to keep the dump in")
     ap.add_argument("--compile-into", default=None, help=argparse.SUPPRESS)  # the child's job
     return ap.parse_args()
@@ -120,6 +125,22 @@ def _compile(args: argparse.Namespace, dump: str) -> None:
         ).compile()
         return
 
+    if args.eva_chunk:
+        from generativeaiexamples_tpu.ops import eva_read
+
+        T, H, Dh, W, slots, P, pmax, bf16 = args.eva_chunk, 32, 128, 2048, 24, 3073, 160, jnp.bfloat16
+
+        def read(q, wk, wv, sk, sv, tables, slot, offsets, valid):
+            work = eva_read.chunk_work_list(tables, slot, offsets, valid, T, W, args.page, slots, P)
+            return eva_read.eva_chunk_read(q, wk, wv, sk, sv, work, num_heads=H)
+
+        jax.jit(read).lower(
+            s((1, T, H * Dh), bf16), s((slots, W, H * Dh), bf16), s((slots, W, H * Dh), bf16),
+            s((P, 8, H * Dh // 2), jnp.uint32), s((P, 8, H * Dh // 2), jnp.uint32), s((slots, pmax), jnp.int32),
+            s((1,), jnp.int32), s((1,), jnp.int32), s((1,), jnp.int32),
+        ).compile()
+        return
+
     if args.latent_decode:
         from generativeaiexamples_tpu.ops import latent_attention as la
 
@@ -165,7 +186,7 @@ def main() -> int:
         _compile(args, args.compile_into)
         return 0
     kernel = ("latent_chunk_read" if args.latent_chunk else "selected_chunk_read" if args.selected_chunk
-              else "latent_attention" if args.latent_decode else "paged_attention")
+              else "eva_chunk_read" if args.eva_chunk else "latent_attention" if args.latent_decode else "paged_attention")
     if not args.keep:  # ~1,700 files of passes: read, then thrown away
         with tempfile.TemporaryDirectory(prefix="kernel_bundles_") as scratch:
             return _report(scratch, False, kernel)
